@@ -1,0 +1,235 @@
+"""Compile the flagship's whole programs for a described TPU v5e, without
+the chip: the step before any chip call (on-chip-measurement guide §2.3).
+
+    JAX_PLATFORMS=cpu python benchmarks/compile_for_chip.py            # all
+    JAX_PLATFORMS=cpu python benchmarks/compile_for_chip.py train4 tp4
+
+libtpu is installed on the CPU rig, so the chip's own compiler runs here and
+raises what the chip would raise: a vector layout Mosaic refuses, a kernel
+that outgrows VMEM, a program that does not fit 16 GB, a custom call nothing
+can partition. `tests/test_tpu_compile.py` keeps the single kernels in
+tier-1 (~10 s); these whole steps take a few minutes and stay out of it.
+Nothing runs, so nothing here is a result or a time of the device. Programs:
+
+    train   the train step of chip_smoke.py (flash + fused norms, unrolled),
+            batch 8 x seq 1024, one chip
+    train4  the same step sharded fsdp=2 x tp=2 over the 2x2 host
+    bf16 | int8 | fused
+            prefill (buckets 32/64/128), pack and the paged decode step at
+            max_slots=8, block 16: bf16 gather, int8 gather, int8 fused
+    tp4     prefill and paged step of a tp=4 replica (bf16 gather)
+
+Each line: how many Mosaic kernels (`tpu_custom_call`) and collectives the
+compiler emitted, and the bytes one device needs (temporaries + arguments).
+The run fails if anything is refused, needs more than the chip has, or lacks
+a kernel where one is expected.
+"""
+
+from __future__ import annotations
+
+import collections
+import os
+import re
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import flax.linen as nn  # noqa: E402
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec  # noqa: E402
+
+from tf_yarn_tpu import training  # noqa: E402
+from tf_yarn_tpu.models import common, decode_engine as de  # noqa: E402
+from tf_yarn_tpu.models.transformer import (  # noqa: E402
+    Transformer,
+    TransformerConfig,
+)
+from tf_yarn_tpu.ops import _rowwise  # noqa: E402
+from tf_yarn_tpu.parallel import mesh as mesh_lib  # noqa: E402
+from tf_yarn_tpu.parallel import sharding as sharding_lib  # noqa: E402
+
+FLAGSHIP = dict(
+    vocab_size=32000, d_model=1024, n_layers=8, n_heads=16, n_kv_heads=8,
+    d_ff=4096, max_seq_len=2048, remat=False, scan_layers=False,
+)
+BATCH, SEQ, SLOTS, BLOCK = 8, 1024, 8, 16
+HBM_BYTES = 16e9  # one v5e chip
+GIB = 2 ** 30
+_COLLECTIVE = re.compile(
+    r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+    r"(?:-start)?\("
+)
+
+
+def _is_none(x) -> bool:
+    return x is None
+
+
+def _abstract(avals, shardings):
+    """ShapeDtypeStructs placed by the matching tree of shardings: there
+    is no device to hold an array, so every argument is a shape."""
+    return jax.tree_util.tree_map(
+        lambda a, s: None if a is None
+        else jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        avals, shardings, is_leaf=_is_none,
+    )
+
+
+def report(name, compiled, began, want_kernels) -> None:
+    memory = compiled.memory_analysis()
+    text = compiled.as_text()
+    kernels = text.count("tpu_custom_call")
+    need = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+    print(
+        f"{name}: compiled ({time.monotonic() - began:.0f}s on this host), "
+        f"{kernels} Mosaic kernels, collectives "
+        f"{dict(collections.Counter(_COLLECTIVE.findall(text)))}, "
+        f"temp {memory.temp_size_in_bytes / GIB:.2f} GiB + args "
+        f"{memory.argument_size_in_bytes / GIB:.2f} GiB per device",
+        flush=True,
+    )
+    if want_kernels and not kernels:
+        raise AssertionError(f"{name}: no Mosaic kernel in the program")
+    if need > HBM_BYTES:
+        raise AssertionError(f"{name}: needs {need / GIB:.1f} GiB per device")
+
+
+def compile_train(devices, axes) -> None:
+    mesh = mesh_lib.build_mesh(mesh_lib.MeshSpec(**axes), devices)
+    model = Transformer(TransformerConfig(
+        **FLAGSHIP, attention_impl="flash", fused_norms=True))
+    optimizer = common.adamw_with_decay_mask(3e-4)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    tokens = jax.ShapeDtypeStruct((BATCH, SEQ), jnp.int32)
+
+    def init(unbox):
+        def init_state(rng, tokens):
+            variables = model.init(rng, tokens)
+            if unbox:
+                variables = sharding_lib.unbox_params(variables)
+            return training.TrainState(
+                np.int32(0), variables, optimizer.init(variables))
+        return init_state
+
+    # As training.train_and_evaluate places its state: shardings from the
+    # boxed (annotated) tree, applied to the unboxed one.
+    shardings = training._named_shardings(
+        mesh, jax.eval_shape(init(False), rng, tokens))
+    state = _abstract(jax.eval_shape(init(True), rng, tokens), shardings)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        tokens.shape, tokens.dtype, sharding=mesh_lib.batch_sharding(mesh, 1))}
+    key = jax.ShapeDtypeStruct(
+        (2,), jnp.uint32, sharding=mesh_lib.replicated_sharding(mesh))
+    step = training.build_train_step(model, common.lm_loss, optimizer)
+    began = time.monotonic()
+    with mesh, mesh_lib.use_mesh(mesh):
+        compiled = jax.jit(
+            step, donate_argnums=(0,), out_shardings=(shardings, None)
+        ).lower(state, batch, key).compile()
+    report(f"train step {axes or 'one chip'}", compiled, began, True)
+
+
+def compile_serving(name, devices, kv_cache_dtype, attention, tp) -> None:
+    mesh = mesh_lib.build_mesh(mesh_lib.MeshSpec(tp=tp), devices)
+    replicated = NamedSharding(mesh, PartitionSpec())
+    config = TransformerConfig(**FLAGSHIP, kv_cache_dtype=kv_cache_dtype)
+    model = Transformer(config)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    tokens = jax.ShapeDtypeStruct((1, 8), jnp.int32)
+    params = _abstract(
+        jax.eval_shape(lambda r, t: nn.meta.unbox(model.init(r, t)),
+                       rng, tokens),
+        sharding_lib.tree_shardings(
+            mesh, jax.eval_shape(model.init, rng, tokens)),
+    )
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=replicated)
+
+    max_blocks = config.max_seq_len // BLOCK
+    prefill = de.build_prefill_fn(model)
+    row = jax.eval_shape(prefill, params, arg(jnp.int32, 1, 1))[0]
+    pool_avals = de.paged_pool_avals(
+        row, SLOTS * max_blocks + 1, BLOCK, config.max_seq_len)
+    pool_shardings = jax.tree_util.tree_map(
+        lambda aval, row_leaf: None if aval is None else NamedSharding(
+            mesh, de.pool_partition_spec(
+                tuple(row_leaf.shape), config.max_seq_len, tp)),
+        pool_avals, row, is_leaf=_is_none,
+    )
+    pool = _abstract(pool_avals, pool_shardings)
+    int8 = kv_cache_dtype == "int8"
+
+    for bucket in (32, 64, 128):
+        prompt = arg(jnp.int32, 1, bucket)
+        cache_avals = jax.eval_shape(prefill, params, prompt)[0]
+        cache_shardings = jax.tree_util.tree_map(
+            lambda a: NamedSharding(mesh, de.kv_partition_spec(
+                tuple(a.shape), config.max_seq_len, tp)), cache_avals)
+        began = time.monotonic()
+        compiled = jax.jit(
+            prefill, out_shardings=(cache_shardings, replicated)
+        ).lower(params, prompt).compile()
+        report(f"{name} prefill[{bucket}]", compiled, began, int8)
+        began = time.monotonic()
+        compiled = jax.jit(
+            de.build_pack_prefill_fn(model, BLOCK, bucket),
+            donate_argnums=(0,), out_shardings=pool_shardings,
+        ).lower(pool, arg(jnp.int32, -(-bucket // BLOCK)),
+                _abstract(cache_avals, cache_shardings)).compile()
+        report(f"{name} pack[{bucket}]", compiled, began, False)
+
+    tables, lengths = arg(jnp.int32, SLOTS, max_blocks), arg(jnp.int32, SLOTS)
+    rngs, active = arg(jnp.uint32, SLOTS, 2), arg(jnp.bool_, SLOTS)
+    began = time.monotonic()
+    if attention == "gather":
+        compiled = jax.jit(
+            de.build_paged_step_fn(model, BLOCK, 0.0, None, None),
+            donate_argnums=(1, 5),
+            out_shardings=(pool_shardings, replicated, replicated),
+        ).lower(params, pool, tables, lengths, arg(jnp.int32, SLOTS), rngs,
+                active).compile()
+    else:
+        compiled = jax.jit(
+            de.build_paged_spec_step_fn(
+                model, BLOCK, 1, 0.0, None, None, decode_attention="fused"),
+            donate_argnums=(1, 7),
+            out_shardings=(pool_shardings,) + (replicated,) * 3,
+        ).lower(params, pool, tables, lengths, arg(jnp.int32, SLOTS, 1),
+                lengths, lengths, rngs, active).compile()
+    report(f"{name} paged step", compiled, began, int8)
+
+
+def main(argv) -> int:
+    jax.config.update("jax_enable_compilation_cache", False)
+    # The code under test asks jax.default_backend(), sees this host's CPU
+    # and would pick interpret mode; steer it onto the chip's branch here,
+    # in the script, not through an option of the program.
+    _rowwise.default_interpret = lambda: False
+    topology = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+    one, four = list(topology.devices[:1]), list(topology.devices)
+    programs = {
+        "train": lambda: compile_train(one, {}),
+        "train4": lambda: compile_train(four, {"fsdp": 2, "tp": 2}),
+        "bf16": lambda: compile_serving("bf16 gather", one, "bf16",
+                                        "gather", 1),
+        "int8": lambda: compile_serving("int8 gather", one, "int8",
+                                        "gather", 1),
+        "fused": lambda: compile_serving("int8 fused", one, "int8",
+                                         "fused", 1),
+        "tp4": lambda: compile_serving("tp=4 bf16 gather", four, "bf16",
+                                       "gather", 4),
+    }
+    for name in argv or list(programs):
+        programs[name]()
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -307,7 +307,7 @@ def test_jaxpr_engine_flags_host_callback_in_hot_path():
     entry = EntryPoint("test.chatty", build)
     findings, counts = check_entry(entry)
     assert [f.code for f in findings] == ["TYA103"]
-    assert counts.get("debug_callback") == 1
+    assert counts.get("debug_print") == 1  # what jax.debug.print traces to
 
 
 def test_jaxpr_engine_default_entries_clean_on_this_build():

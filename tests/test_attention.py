@@ -235,19 +235,23 @@ def test_transformer_with_ring_attention_trains():
     assert np.isfinite(metrics["loss"])
 
 
-def test_flash_attention_partitions_batch_under_pjit():
-    """Under a dp mesh the flash kernels run per batch shard (forward
-    AND the custom_vjp backward) instead of XLA replicating the opaque
-    custom calls — attention keeps scaling with chips."""
+@pytest.mark.parametrize("layout", [{"dp": 8}, {"dp": 4, "tp": 2}])
+def test_flash_attention_runs_per_shard_under_the_run_mesh(layout):
+    """Under the run's mesh the flash kernels run on each device's own
+    shard (forward AND the custom_vjp backward) instead of XLA
+    replicating the opaque custom calls: the batch splits over the data
+    axes and, where both head counts divide, the heads over tp — GQA
+    groups staying with their KV heads."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from tf_yarn_tpu.ops.attention import attention
     from tf_yarn_tpu.ops.flash_attention import flash_attention
-    from tf_yarn_tpu.parallel.mesh import select_devices
+    from tf_yarn_tpu.parallel import mesh as mesh_lib
 
-    devices = select_devices(8, platform="cpu")
-    mesh = Mesh(np.array(devices).reshape(8), ("dp",))
+    devices = mesh_lib.select_devices(8, platform="cpu")
+    mesh = Mesh(np.array(devices).reshape(tuple(layout.values())),
+                tuple(layout))
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(8, 64, 4, 16).astype(np.float32))
     k = jnp.asarray(rng.randn(8, 64, 2, 16).astype(np.float32))
@@ -255,20 +259,29 @@ def test_flash_attention_partitions_batch_under_pjit():
     sh = NamedSharding(mesh, P("dp", None, None, None))
     qs, ks, vs = (jax.device_put(t, sh) for t in (q, k, v))
 
-    out = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))(
-        qs, ks, vs)
-    # Spec normalization differs across jax builds (P("dp") vs
-    # P("dp", None, ...)): assert the batch dim is the sharded one.
+    def loss(fn):
+        # Weighted, so that every head's gradient differs.
+        w = jnp.arange(4, dtype=jnp.float32)[None, None, :, None] + 1.0
+        return lambda q, k, v: (fn(q, k, v) * w).sum()
+
+    with mesh_lib.use_mesh(mesh):
+        out = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))(
+            qs, ks, vs)
+        grads = jax.jit(jax.grad(loss(
+            lambda q, k, v: flash_attention(q, k, v, causal=True)),
+            argnums=(0, 1, 2)))(qs, ks, vs)
     assert out.sharding.spec[0] == "dp", out.sharding
+    assert grads[0].sharding.spec[0] == "dp", grads[0].sharding
+    if "tp" in layout:
+        assert out.sharding.spec[2] == "tp", out.sharding
     ref = attention(q, k, v, impl="xla", causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2)
-
-    grad = jax.jit(jax.grad(
-        lambda q: flash_attention(q, ks, vs, causal=True).sum()))(qs)
-    assert grad.sharding.spec[0] == "dp", grad.sharding
-    gref = jax.grad(
-        lambda q: attention(q, k, v, impl="xla", causal=True).sum())(q)
-    np.testing.assert_allclose(np.asarray(grad), np.asarray(gref), atol=2e-2)
+    grefs = jax.grad(loss(
+        lambda q, k, v: attention(q, k, v, impl="xla", causal=True)),
+        argnums=(0, 1, 2))(q, k, v)
+    for grad, gref in zip(grads, grefs):
+        np.testing.assert_allclose(
+            np.asarray(grad), np.asarray(gref), atol=2e-2)
 
 
 @pytest.mark.parametrize("causal", [True, False])

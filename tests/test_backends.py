@@ -274,3 +274,148 @@ def test_driver_feeds_lost_tasks_to_fake_backend():
     _note_lost_to_backend(
         ExplodingBackend(), RunFailed("x", lost_tasks=["worker:0"])
     )
+
+
+# -- one process for each chip ------------------------------------------------
+
+def _chip_services(**chips):
+    """{task_type: (instances, chips_per_host)} -> services."""
+    return {
+        name: ServiceSpec(module="m", instances=n, chips_per_host=c)
+        for name, (n, c) in chips.items()
+    }
+
+
+def _assign(monkeypatch, host_chips, services):
+    from tf_yarn_tpu import backends
+
+    monkeypatch.delenv("TPU_YARN_PLATFORM", raising=False)  # a chip host
+    monkeypatch.setattr(backends, "local_chip_count", lambda: host_chips)
+    assigned = LocalBackend()._assign_chips(services)
+    return {key.to_kv_str(): env for key, env in assigned.items()}
+
+
+def test_chip_tasks_get_disjoint_chips_in_task_order(monkeypatch):
+    envs = _assign(monkeypatch, 4, _chip_services(
+        serving=(4, 1), router=(1, 0)))
+    assert [envs[f"serving:{i}"]["TPU_VISIBLE_CHIPS"] for i in range(4)] \
+        == ["0", "1", "2", "3"]
+    for i in range(4):
+        # Visibility alone is refused by libtpu: each process must also
+        # be told it is a whole one-chip slice.
+        assert envs[f"serving:{i}"]["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert envs[f"serving:{i}"]["TPU_PROCESS_BOUNDS"] == "1,1,1"
+
+
+def test_a_cpu_task_gets_no_chip(monkeypatch):
+    envs = _assign(monkeypatch, 4, _chip_services(
+        serving=(1, 1), router=(1, 0)))
+    assert envs["router:0"] == {
+        "TPU_YARN_PLATFORM": "cpu", "JAX_PLATFORMS": "cpu"}
+
+
+def test_a_pair_of_chips_starts_on_an_even_chip(monkeypatch):
+    envs = _assign(monkeypatch, 4, _chip_services(
+        worker=(1, 1), serving=(1, 2)))
+    assert envs["worker:0"]["TPU_VISIBLE_CHIPS"] == "0"
+    assert envs["serving:0"]["TPU_VISIBLE_CHIPS"] == "2,3"
+    assert envs["serving:0"]["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+
+
+def test_a_task_that_takes_the_whole_host_is_left_alone(monkeypatch):
+    assert _assign(monkeypatch, 4, _chip_services(worker=(1, 4))) \
+        == {"worker:0": {}}
+    assert _assign(monkeypatch, 1, _chip_services(worker=(1, 1))) \
+        == {"worker:0": {}}
+
+
+def test_oversubscribing_the_host_is_refused(monkeypatch):
+    import pytest
+
+    with pytest.raises(ValueError, match="more TPU chips than its 4"):
+        _assign(monkeypatch, 4, _chip_services(serving=(4, 1), prefill=(1, 1)))
+    with pytest.raises(ValueError, match="more TPU chips than its 0"):
+        _assign(monkeypatch, 0, _chip_services(worker=(1, 1)))
+    with pytest.raises(ValueError, match="1, 2 or all 4 chips"):
+        _assign(monkeypatch, 4, _chip_services(worker=(1, 3)))
+
+
+def test_cpu_by_name_takes_no_chip(monkeypatch):
+    """The CPU rig: TPU_YARN_PLATFORM=cpu, from the driver's environment
+    or the task's own, runs a chip task on virtual devices."""
+    from tf_yarn_tpu import backends
+
+    def no_count():
+        raise AssertionError("must not look for chips")
+
+    monkeypatch.setattr(backends, "local_chip_count", no_count)
+    monkeypatch.setenv("TPU_YARN_PLATFORM", "cpu")
+    assert LocalBackend()._assign_chips(_chip_services(worker=(2, 4))) == {}
+    monkeypatch.delenv("TPU_YARN_PLATFORM")
+    services = _chip_services(worker=(2, 4))
+    services["worker"].env["TPU_YARN_PLATFORM"] = "cpu"
+    assert LocalBackend()._assign_chips(services) == {}
+
+
+def test_run_on_tpu_carries_chips_per_host_to_the_backend():
+    from tf_yarn_tpu.client import _setup_task_env
+    from tf_yarn_tpu.topologies import fleet_topology
+
+    services = _setup_task_env(
+        fleet_topology(nb_replicas=3, chips_per_host=1), "h:1", "/tmp/x", 0,
+        {}, None, "",
+    )
+    assert services["serving"].chips_per_host == 1
+    assert services["router"].chips_per_host == 0
+
+
+def test_children_see_their_own_chips_and_one_compile_cache(
+    monkeypatch, tmp_path
+):
+    """Real children: what each task's environment holds when it starts,
+    and the compile cache each would use — the same one, whatever its
+    working directory."""
+    import json
+    import os
+
+    from tf_yarn_tpu import backends, compile_cache
+
+    monkeypatch.delenv("TPU_YARN_PLATFORM", raising=False)
+    monkeypatch.delenv(compile_cache.ENV_CACHE_DIR, raising=False)
+    monkeypatch.setattr(backends, "local_chip_count", lambda: 4)
+    (tmp_path / "dump_env.py").write_text(
+        "import json, os\n"
+        "from tf_yarn_tpu import compile_cache\n"
+        "keys = ('TPU_VISIBLE_CHIPS', 'TPU_YARN_PLATFORM', 'JAX_PLATFORMS')\n"
+        "out = {k: os.environ.get(k) for k in keys}\n"
+        "out['cache'] = compile_cache.export()\n"
+        "out['cwd'] = os.getcwd()\n"
+        "path = os.path.join(os.environ['DUMP_DIR'],\n"
+        "                    os.environ['TPU_YARN_TASK'].replace(':', '-'))\n"
+        "json.dump(out, open(path, 'w'))\n"
+    )
+    (tmp_path / "shipped.txt").write_text("x")  # moves the tasks' cwd
+    env = {"DUMP_DIR": str(tmp_path),
+           "PYTHONPATH": f"{tmp_path}{os.pathsep}{os.environ['PYTHONPATH']}"}
+    handle = LocalBackend().launch({
+        "serving": ServiceSpec(
+            module="dump_env", instances=2, env=env, chips_per_host=1,
+            files={"shipped.txt": str(tmp_path / "shipped.txt")}),
+        "router": ServiceSpec(module="dump_env", instances=1, env=env),
+    }, str(tmp_path / "logs"))
+    deadline = time.time() + 60
+    while handle.status() == RUNNING and time.time() < deadline:
+        time.sleep(0.1)
+    assert handle.status() == SUCCEEDED, handle.logs()
+    assert set(handle.pids()) == {"serving:0", "serving:1", "router:0"}
+    seen = {name: json.loads((tmp_path / name).read_text())
+            for name in ("serving-0", "serving-1", "router-0")}
+    assert seen["serving-0"]["TPU_VISIBLE_CHIPS"] == "0"
+    assert seen["serving-1"]["TPU_VISIBLE_CHIPS"] == "1"
+    assert seen["serving-0"]["TPU_YARN_PLATFORM"] is None  # a chip task
+    assert seen["router-0"]["TPU_VISIBLE_CHIPS"] is None
+    assert seen["router-0"]["TPU_YARN_PLATFORM"] == "cpu"
+    assert seen["router-0"]["JAX_PLATFORMS"] == "cpu"
+    assert seen["serving-0"]["cwd"] != seen["router-0"]["cwd"]
+    assert {s["cache"] for s in seen.values()} \
+        == {compile_cache.CHECKOUT_CACHE_DIR}

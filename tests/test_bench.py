@@ -1,219 +1,49 @@
-"""bench.py prior-round lookup: numeric round ordering + exclusion of
-the current round's own (uncommitted) file (ADVICE r5 item 1)."""
+"""The measurement scripts measure the chip or nothing: without a TPU
+they fail and say why, and the CPU rig is used only when asked for by
+name — then every line says so."""
 
 import json
 import os
+import subprocess
+import sys
 
-import bench
+import pytest
 
-
-def _write_round(tmp_path, n, value, unit="samples/sec/chip (cpu-fallback)"):
-    path = tmp_path / f"BENCH_r{n}.json"
-    path.write_text(json.dumps({"parsed": {"value": value, "unit": unit}}))
-    return path.name
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_prior_round_sorts_by_parsed_round_number(tmp_path, monkeypatch):
-    # Lexically "BENCH_r2.json" > "BENCH_r10.json": glob order would pick
-    # round 2 as "newest". Parsed-number order must pick round 10.
-    _write_round(tmp_path, 2, 2.0)
-    _write_round(tmp_path, 10, 10.0)
-    monkeypatch.setattr(bench, "_REPO", str(tmp_path))
-    monkeypatch.setattr(bench, "_uncommitted_bench_files", lambda: set())
-    assert bench._prior_round_cpu_value() == ("BENCH_r10.json", 10.0)
-
-
-def test_prior_round_excludes_current_rounds_own_file(tmp_path, monkeypatch):
-    # A re-run within round 10 sees its own file on disk; comparing
-    # against it would mute the cross-round drift signal.
-    _write_round(tmp_path, 9, 9.0)
-    _write_round(tmp_path, 10, 10.0)
-    monkeypatch.setattr(bench, "_REPO", str(tmp_path))
-    monkeypatch.setattr(
-        bench, "_uncommitted_bench_files", lambda: {"BENCH_r10.json"}
+def _run(script, *args):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("TPU_YARN_PLATFORM", None)  # what a user's shell looks like
+    return subprocess.run(
+        [sys.executable, os.path.join(_REPO, script), *args],
+        capture_output=True, text=True, env=env, timeout=300,
     )
-    assert bench._prior_round_cpu_value() == ("BENCH_r9.json", 9.0)
 
 
-def test_prior_round_skips_non_cpu_fallback_units(tmp_path, monkeypatch):
-    _write_round(tmp_path, 3, 3.0)
-    _write_round(tmp_path, 4, 4.0, unit="samples/sec/chip (tpu, flash)")
-    monkeypatch.setattr(bench, "_REPO", str(tmp_path))
-    monkeypatch.setattr(bench, "_uncommitted_bench_files", lambda: set())
-    assert bench._prior_round_cpu_value() == ("BENCH_r3.json", 3.0)
+@pytest.mark.parametrize(
+    "script,args", [("bench.py", ()), ("benchmarks/run.py", ("serve",))]
+)
+def test_measurement_without_a_chip_is_an_error(script, args):
+    proc = _run(script, *args)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == "", "no result line without a device"
+    assert "started for a TPU chip" in proc.stderr
+    assert "TPU_YARN_PLATFORM=cpu" in proc.stderr  # how to ask for the rig
 
 
-def test_prior_round_none_when_no_candidates(tmp_path, monkeypatch):
-    monkeypatch.setattr(bench, "_REPO", str(tmp_path))
-    monkeypatch.setattr(bench, "_uncommitted_bench_files", lambda: set())
-    assert bench._prior_round_cpu_value() is None
+def test_cpu_by_name_labels_every_line():
+    proc = _run("benchmarks/run.py", "ici_allreduce", "--cpu")
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["tpu"] is False
+    assert (line["platform"], line["device_kind"]) == ("cpu", "cpu")
+    assert line["n_devices"] >= 1
 
 
-def test_uncommitted_detection_outside_git_repo(tmp_path, monkeypatch):
-    # Outside a git repo the helper must degrade to "nothing excluded",
-    # not crash the bench.
-    monkeypatch.setattr(bench, "_REPO", str(tmp_path))
-    assert bench._uncommitted_bench_files() == set()
-
-
-def test_uncommitted_detection_in_real_repo():
-    # In THIS repo: a scratch BENCH_r file is untracked, so it is
-    # excluded; committed rounds are not.
-    scratch = os.path.join(bench._REPO, "BENCH_r999.json")
-    with open(scratch, "w") as fh:
-        json.dump({}, fh)
-    try:
-        uncommitted = bench._uncommitted_bench_files()
-        assert "BENCH_r999.json" in uncommitted
-        assert "BENCH_r01.json" not in uncommitted
-    finally:
-        os.unlink(scratch)
-
-
-def test_stale_fields_carry_fleet_observability_numbers(tmp_path, monkeypatch):
-    # The fleet section's observability-plane numbers (scrape-merged
-    # TTFT p95, monitor scrape cost) must survive as last_tpu_fleet_*
-    # stale carries, and their absence (an older table) must not break
-    # the carry of the classic fields.
-    table = {
-        "rows": [{"samples_per_sec_per_chip": 1.0, "variant": "base"}],
-        "git_commit": "abc1234",
-        "measured_at": "2026-08-01T00:00:00Z",
-        "fleet": {
-            "rows": {
-                "r2": {
-                    "tokens_per_sec": 42.0,
-                    "ttft_p95_ms": 12.5,
-                    "fleet_ttft_p95_ms": 11.0,
-                    "monitor_scrape_wall_ms": 3.25,
-                },
-                "r1": {"tokens_per_sec": 21.0, "ttft_p95_ms": 10.0},
-            },
-            "scaling_r2_vs_r1": 2.0,
-        },
-    }
-    path = tmp_path / "BENCH_AB.json"
-    path.write_text(json.dumps(table))
-    monkeypatch.setattr(bench, "_AB_PATH", str(path))
-    fields = bench._stale_tpu_fields()
-    assert fields["last_tpu_fleet_r2_tokens_per_sec"] == 42.0
-    assert fields["last_tpu_fleet_r2_merged_ttft_p95_ms"] == 11.0
-    assert fields["last_tpu_fleet_r2_monitor_scrape_wall_ms"] == 3.25
-    assert fields["last_tpu_fleet_scaling_r2_vs_r1"] == 2.0
-    # The r1 row predates the observability plane: classic carry only.
-    assert fields["last_tpu_fleet_r1_tokens_per_sec"] == 21.0
-    assert "last_tpu_fleet_r1_merged_ttft_p95_ms" not in fields
-
-
-def test_stale_fields_carry_fleet_autoscale_ab(tmp_path, monkeypatch):
-    # The elastic A/B (static vs autoscaled fleet) is a TPU capacity
-    # claim: its per-arm violation rates, the delta, and the stream
-    # bit-identity flag must survive CPU reruns as stale carries.
-    table = {
-        "rows": [{"samples_per_sec_per_chip": 1.0, "variant": "base"}],
-        "git_commit": "abc1234",
-        "measured_at": "2026-08-01T00:00:00Z",
-        "fleet": {
-            "rows": {},
-            "autoscale": {
-                "rows": {
-                    "static": {
-                        "slo_violation_rate": 0.2,
-                        "ttft_p95_ms": 310.0,
-                    },
-                    "autoscaled": {
-                        "slo_violation_rate": 0.05,
-                        "ttft_p95_ms": 180.0,
-                        "scale_events": 1,
-                    },
-                },
-                "violation_delta": 0.15,
-                "streams_match": True,
-            },
-        },
-    }
-    path = tmp_path / "BENCH_AB.json"
-    path.write_text(json.dumps(table))
-    monkeypatch.setattr(bench, "_AB_PATH", str(path))
-    fields = bench._stale_tpu_fields()
-    assert (
-        fields["last_tpu_fleet_autoscale_static_slo_violation_rate"] == 0.2
-    )
-    assert fields["last_tpu_fleet_autoscale_static_ttft_p95_ms"] == 310.0
-    assert (
-        fields["last_tpu_fleet_autoscale_autoscaled_slo_violation_rate"]
-        == 0.05
-    )
-    assert fields["last_tpu_fleet_autoscale_violation_delta"] == 0.15
-    assert fields["last_tpu_fleet_autoscale_streams_match"] is True
-
-
-def test_stale_fields_carry_serve_disagg_ab(tmp_path, monkeypatch):
-    # The disaggregated-prefill A/B is a TPU latency claim: both rows'
-    # TTFT p95, the ratio, the stream bit-identity flag, and the
-    # fp-vs-int8 wire ratio must survive CPU reruns as stale carries.
-    table = {
-        "rows": [{"samples_per_sec_per_chip": 1.0, "variant": "base"}],
-        "git_commit": "abc1234",
-        "measured_at": "2026-08-01T00:00:00Z",
-        "serve": {
-            "disagg": {
-                "rows": {
-                    "local": {"ttft_p95_ms": 95.0},
-                    "offloaded": {
-                        "ttft_p95_ms": 61.0,
-                        "streams_match_local": True,
-                        "ships": 4,
-                        "shipped_blocks": 512,
-                    },
-                },
-                "ttft_p95_ratio": 0.642,
-                "wire_bytes_fp_over_int8": 3.1,
-            },
-        },
-    }
-    path = tmp_path / "BENCH_AB.json"
-    path.write_text(json.dumps(table))
-    monkeypatch.setattr(bench, "_AB_PATH", str(path))
-    fields = bench._stale_tpu_fields()
-    assert fields["last_tpu_serve_disagg_local_ttft_p95_ms"] == 95.0
-    assert fields["last_tpu_serve_disagg_offloaded_ttft_p95_ms"] == 61.0
-    assert fields["last_tpu_serve_disagg_ttft_p95_ratio"] == 0.642
-    assert fields["last_tpu_serve_disagg_wire_bytes_fp_over_int8"] == 3.1
-    assert fields["last_tpu_serve_disagg_streams_match_local"] is True
-
-
-def test_stale_fields_tolerate_missing_disagg_section(tmp_path, monkeypatch):
-    # Older tables predate the disaggregated-prefill A/B: the carry
-    # must neither crash nor invent disagg fields.
-    table = {
-        "rows": [{"samples_per_sec_per_chip": 1.0, "variant": "base"}],
-        "serve": {
-            "chunked": {
-                "rows": {"chunked": {"itl_p95_ms": 5.0, "ttft_p95_ms": 7.0}},
-            },
-        },
-    }
-    path = tmp_path / "BENCH_AB.json"
-    path.write_text(json.dumps(table))
-    monkeypatch.setattr(bench, "_AB_PATH", str(path))
-    fields = bench._stale_tpu_fields()
-    assert fields["last_tpu_serve_chunked_chunked_itl_p95_ms"] == 5.0
-    assert not any("disagg" in key for key in fields)
-
-
-def test_stale_fields_tolerate_missing_autoscale_section(
-    tmp_path, monkeypatch
-):
-    # Older tables predate the elastic A/B: the carry must neither
-    # crash nor invent autoscale fields.
-    table = {
-        "rows": [{"samples_per_sec_per_chip": 1.0, "variant": "base"}],
-        "fleet": {"rows": {"r1": {"tokens_per_sec": 21.0}}},
-    }
-    path = tmp_path / "BENCH_AB.json"
-    path.write_text(json.dumps(table))
-    monkeypatch.setattr(bench, "_AB_PATH", str(path))
-    fields = bench._stale_tpu_fields()
-    assert fields["last_tpu_fleet_r1_tokens_per_sec"] == 21.0
-    assert not any("autoscale" in key for key in fields)
+def test_bench_has_no_fallback_left():
+    with open(os.path.join(_REPO, "bench.py")) as fh:
+        source = fh.read()
+    for gone in ("forcing CPU", "_attempt_unwedge", "_probe_backend_alive",
+                 "last_tpu_", "cpu-fallback"):
+        assert gone not in source

@@ -21,7 +21,7 @@ def test_allreduce_and_gather_helpers():
         gathered = collectives.all_gather(s, "dp", gather_axis=0)
         return total, gathered
 
-    total, gathered = collectives.shard_map(
+    total, gathered = jax.shard_map(
         body, mesh=mesh, in_specs=P("dp", None),
         out_specs=(P("dp", None), P("dp", None)), check_vma=False,
     )(x)
@@ -33,7 +33,7 @@ def test_allreduce_and_gather_helpers():
 def test_ring_shift():
     mesh = _mesh8()
     x = np.arange(8, dtype=np.float32).reshape(8, 1)
-    out = collectives.shard_map(
+    out = jax.shard_map(
         lambda s: collectives.ring_shift(s, "dp", 1),
         mesh=mesh, in_specs=P("dp", None), out_specs=P("dp", None),
         check_vma=False,

@@ -33,9 +33,9 @@ def test_peak_flops_table(kind, expected):
     assert flops_lib.peak_flops_per_chip(_FakeDevice(kind)) == expected
 
 
-def test_peak_flops_env_override(monkeypatch):
-    monkeypatch.setenv(flops_lib.ENV_PEAK_FLOPS, "1.5e14")
-    assert flops_lib.peak_flops_per_chip(_FakeDevice("cpu")) == 1.5e14
+def test_peak_flops_unknown_tpu_kind_is_an_error():
+    with pytest.raises(ValueError, match="TPU v9"):
+        flops_lib.peak_flops_per_chip(_FakeDevice("TPU v9"))
 
 
 def test_batch_counts():

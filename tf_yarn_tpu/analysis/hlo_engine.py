@@ -596,7 +596,7 @@ def run(
         missing = [r for r in entry.requires if r not in caps]
         if missing:
             skipped.append(
-                f"{entry.name}: this jax build lacks {', '.join(missing)}"
+                f"{entry.name}: this process lacks {', '.join(missing)}"
             )
             skipped_names.append(entry.name)
             continue
@@ -619,7 +619,7 @@ def run(
         missing = [r for r in entry.requires if r not in caps]
         if missing:
             skipped.append(
-                f"{entry.name}: this jax build lacks {', '.join(missing)}"
+                f"{entry.name}: this process lacks {', '.join(missing)}"
             )
             continue
         _route(check_churn(entry), entry.allow)

@@ -39,8 +39,8 @@ _COLLECTIVE_PRIMITIVES = {
     "reduce_scatter", "psum_scatter", "axis_index",
 }
 _HOST_CALLBACK_PRIMITIVES = {
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "outside_call", "device_put",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback", "outside_call", "device_put",
 }
 
 
@@ -56,10 +56,9 @@ class EntryPoint:
     attention has no business reducing over `tp`). `hot` marks per-step
     code where a host callback is a finding, not a curiosity.
     `requires` names runtime capabilities (see `capabilities()`) the
-    entry needs: on an installation lacking them the entry is *skipped*
-    with a visible notice, not failed — the checker verifies this
-    codebase, not the host's jax build (the CPU test rig's jax predates
-    `Shardy` sharding rules; the TPU image does not).
+    entry needs: where they are lacking the entry is *skipped* with a
+    visible notice, not failed — a one-device process cannot lower a
+    tp=2 program.
     """
 
     name: str
@@ -76,12 +75,10 @@ class EntryPoint:
 
 
 def capabilities() -> frozenset:
-    """Runtime jax capabilities, probed once per process."""
+    """What this process can lower, probed once."""
     global _CAPABILITIES
     if _CAPABILITIES is not None:
         return _CAPABILITIES
-    import inspect
-
     import jax
 
     caps = set()
@@ -91,28 +88,6 @@ def capabilities() -> frozenset:
     # skip those entries with a notice instead of failing them.
     if len(jax.devices()) >= 2:
         caps.add("multi_device")
-    if hasattr(jax, "shard_map"):
-        caps.add("jax.shard_map")
-    else:
-        try:
-            # Older builds: parallel.collectives.shard_map falls back to
-            # the experimental module, so the capability is still real.
-            from jax.experimental.shard_map import shard_map  # noqa: F401
-
-            caps.add("jax.shard_map")
-        except ImportError:
-            pass
-    try:
-        from jax.experimental.custom_partitioning import (
-            custom_partitioning,
-        )
-
-        if "sharding_rule" in inspect.signature(
-            custom_partitioning.def_partition
-        ).parameters:
-            caps.add("custom_partitioning.sharding_rule")
-    except ImportError:
-        pass
     _CAPABILITIES = frozenset(caps)
     return _CAPABILITIES
 
@@ -131,7 +106,7 @@ def _walk_jaxpr(jaxpr) -> Iterable:
 
 
 def _nested_jaxprs(value) -> Iterable:
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
@@ -238,7 +213,7 @@ def run(
         missing = [r for r in entry.requires if r not in caps]
         if missing:
             skipped.append(
-                f"{entry.name}: this jax build lacks {', '.join(missing)}"
+                f"{entry.name}: this process lacks {', '.join(missing)}"
             )
             continue
         entry_findings, counts = check_entry(entry)
@@ -311,10 +286,6 @@ def _ops_entries() -> List[EntryPoint]:
 
         return roundtrip, (_f32(8, 128),), {}
 
-    # The fused norms partition via Shardy sharding rules where the build
-    # has them, and via the infer_sharding_from_operands fallback
-    # elsewhere (make_sharded_op) — the registration traces on both, so
-    # these entries are no longer capability-gated.
     return [
         EntryPoint("ops.attention.xla_attention", attention_xla),
         EntryPoint("ops.rmsnorm.rmsnorm", rmsnorm),

@@ -64,6 +64,9 @@ class ServiceSpec:
     # Extra files shipped into each task's working directory, name -> local
     # path (the reference's `files` upload, client.py:337-344).
     files: Dict[str, str] = field(default_factory=dict)
+    # TPU chips every instance takes on its host (TaskSpec.chips_per_host);
+    # 0 is a CPU task.
+    chips_per_host: int = 0
 
 
 class ClusterHandle(ABC):
@@ -168,6 +171,49 @@ class _LocalHandle(ClusterHandle):
     def logs(self) -> Dict[str, str]:
         return {key.to_kv_str(): path for key, path in self._log_files.items()}
 
+    def pids(self) -> Dict[str, int]:
+        """task "type:id" -> process id, e.g. to send one task the
+        SIGTERM a TPU VM sends before it preempts (tf_yarn_tpu.preemption)."""
+        return {key.to_kv_str(): proc.pid for key, proc in self._procs.items()}
+
+
+def local_chip_count() -> int:
+    """TPU chips this host has, counted from their device files (one
+    `/dev/vfio/<n>` or `/dev/accel<n>` per chip). The launcher must not
+    ask JAX: a process that has initialised the TPU backend holds the
+    chips, and no child could take them."""
+    import glob
+
+    return len(glob.glob("/dev/vfio/[0-9]*")) or len(
+        glob.glob("/dev/accel[0-9]*")
+    )
+
+
+def chip_env(first_chip: int, n_chips: int, host_chips: int) -> Dict[str, str]:
+    """The libtpu variables that give one process chips
+    ``first_chip .. first_chip + n_chips - 1`` of this host and hide the
+    rest, so that several chip tasks can share the host. Established on
+    a four-chip (2x2) v5e host with libtpu 0.0.34 (docs/Operations.md
+    "Several chip tasks on one host"): visibility alone is refused — the
+    processes meet on libtpu's lock file — unless each process is also
+    told it is a whole slice of that many chips. A task that takes the
+    whole host needs none of the variables."""
+    if n_chips == host_chips:
+        return {}
+    bounds = {1: "1,1,1", 2: "1,2,1"}.get(n_chips)
+    if bounds is None:
+        raise ValueError(
+            f"a task can take 1, 2 or all {host_chips} chips of this host, "
+            f"not {n_chips}: libtpu gives a process a rectangle of chips"
+        )
+    return {
+        "TPU_VISIBLE_CHIPS": ",".join(
+            str(chip) for chip in range(first_chip, first_chip + n_chips)
+        ),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": bounds,
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
 
 class LocalBackend(SliceBackend):
     """Run every task instance as a local subprocess.
@@ -175,6 +221,14 @@ class LocalBackend(SliceBackend):
     The per-task command is ``python -m <module>`` with identity/coordinator
     env vars — the same contract `_env.gen_task_module` defines for every
     backend (reference container command: _env.py:10-24).
+
+    One process for each chip: a task whose spec reserves chips
+    (``chips_per_host`` > 0) is given exactly those, assigned in task
+    order, and a topology that wants more than the host has is refused
+    before anything starts. A task that reserves none runs on the CPU
+    (``TPU_YARN_PLATFORM=cpu``). Setting ``TPU_YARN_PLATFORM=cpu`` for a
+    chip task — the CPU test rig does — runs it on virtual CPU devices
+    and takes no chip.
     """
 
     is_remote = False
@@ -182,16 +236,57 @@ class LocalBackend(SliceBackend):
     def __init__(self, python: Optional[str] = None) -> None:
         self._python = python or sys.executable
 
+    def _assign_chips(
+        self, services: Dict[str, ServiceSpec]
+    ) -> Dict[TaskKey, Dict[str, str]]:
+        """Platform and chip variables per task instance."""
+        wanted: List[Tuple[TaskKey, int]] = []
+        out: Dict[TaskKey, Dict[str, str]] = {}
+        for task_type, spec in services.items():
+            platform = spec.env.get(
+                "TPU_YARN_PLATFORM", os.environ.get("TPU_YARN_PLATFORM")
+            )
+            for task_id in range(spec.instances):
+                key = TaskKey(task_type, task_id)
+                if not spec.chips_per_host:
+                    out[key] = {
+                        "TPU_YARN_PLATFORM": "cpu", "JAX_PLATFORMS": "cpu",
+                    }
+                elif platform != "cpu":
+                    wanted.append((key, spec.chips_per_host))
+        if not wanted:
+            return out
+        host_chips = local_chip_count()
+        next_chip = 0
+        for key, n_chips in wanted:
+            # A pair starts on an even chip: "0,1" and "2,3" are the
+            # rectangles that were tried.
+            first = -(-next_chip // n_chips) * n_chips
+            next_chip = first + n_chips
+            if next_chip > host_chips:
+                raise ValueError(
+                    f"this topology asks one host for more TPU chips than "
+                    f"its {host_chips} "
+                    f"({', '.join(f'{k.to_kv_str()}={n}' for k, n in wanted)})"
+                    "; LocalBackend runs every task here. Use fewer or "
+                    "smaller chip tasks, SshBackend over more hosts, or "
+                    "TPU_YARN_PLATFORM=cpu for the virtual-device rig"
+                )
+            out[key] = chip_env(first, n_chips, host_chips)
+        return out
+
     def launch(
         self, services: Dict[str, ServiceSpec], log_dir: str
     ) -> _LocalHandle:
         os.makedirs(log_dir, exist_ok=True)
+        chip_envs = self._assign_chips(services)
         procs: Dict[TaskKey, subprocess.Popen] = {}
         log_files: Dict[TaskKey, str] = {}
         for task_type, spec in services.items():
             for task_id in range(spec.instances):
                 key = TaskKey(task_type, task_id)
                 env = dict(os.environ)
+                env.update(chip_envs.get(key, {}))
                 env.update(spec.env)
                 env[constants.ENV_TASK_KEY] = key.to_kv_str()
                 workdir = None

@@ -1,4 +1,4 @@
-"""Throughput measurement harness (BASELINE.md's measurement surface).
+"""Throughput measurement harness.
 
 One timed jitted-train-step loop shared by bench.py (the driver's single
 headline metric) and benchmarks/run.py (the per-config BASELINE.json
@@ -62,7 +62,12 @@ def measure_throughput(
     import jax
     import numpy as np
 
-    from tf_yarn_tpu.parallel.mesh import MeshSpec, build_mesh, select_devices
+    from tf_yarn_tpu.parallel.mesh import (
+        MeshSpec,
+        build_mesh,
+        select_devices,
+        use_mesh,
+    )
     from tf_yarn_tpu.utils import flops as flops_lib
     from tf_yarn_tpu.parallel.sharding import tree_shardings, unbox_params
     from tf_yarn_tpu.training import TrainState, build_train_step
@@ -100,16 +105,11 @@ def measure_throughput(
     # sharding.unbox_params); out_shardings are explicit NamedShardings.
     state = jax.jit(init_state, out_shardings=shardings)(rng, placed)
 
-    with mesh:
+    with mesh, use_mesh(mesh):
         step_core = build_train_step(model, loss_fn, optimizer)
 
         # The measured loop runs *inside* one jitted program (lax.scan over
-        # `steps` train steps). Two reasons: (a) per-execution dispatch
-        # overhead — substantial on relayed/remote TPU backends — amortizes
-        # to noise; (b) sync is a scalar device_get of the last loss, which
-        # forces the whole chain on every backend (block_until_ready is
-        # advisory-only on some experimental platforms and would time
-        # dispatch, not compute).
+        # `steps` train steps), so one dispatch covers the timed region.
         def run_steps(state, batch, rng):
             def body(carry, _):
                 state, rng = carry
@@ -121,7 +121,7 @@ def measure_throughput(
             )
             return state, losses[-1]
 
-        t0 = time.time()
+        t0 = time.perf_counter()
         run_fn = jax.jit(
             run_steps, donate_argnums=(0,), out_shardings=(shardings, None)
         ).lower(state, placed, rng).compile()
@@ -134,14 +134,13 @@ def measure_throughput(
                 model, batch, compiled=run_fn, n_devices=len(devices)
             )
         # Warmup call (also verifies the donated-state round trip).
-        state, loss = run_fn(state, placed, rng)
-        float(jax.device_get(loss))
-        compile_time = time.time() - t0
+        state, loss = jax.block_until_ready(run_fn(state, placed, rng))
+        compile_time = time.perf_counter() - t0
 
-        t0 = time.time()
-        state, loss = run_fn(state, placed, rng)
-        final_loss = float(jax.device_get(loss))
-        elapsed = time.time() - t0
+        t0 = time.perf_counter()
+        state, loss = jax.block_until_ready(run_fn(state, placed, rng))
+        elapsed = time.perf_counter() - t0
+        final_loss = float(loss)
 
     samples_per_sec = steps * batch_size / elapsed
     result = {

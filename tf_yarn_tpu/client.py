@@ -164,6 +164,7 @@ def _setup_task_env(
             nb_proc=spec.nb_proc_per_worker,
             pre_script_hook=pre_script_hook,
             files=dict(files or {}),
+            chips_per_host=spec.chips_per_host,
         )
     return services
 

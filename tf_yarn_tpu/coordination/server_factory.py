@@ -1,8 +1,12 @@
-"""Pick the best available coordination server: native coordd, else Python.
+"""Start the run's coordination server: native coordd, else Python.
 
 The native server (tf_yarn_tpu/native/coordd.cc) speaks the same wire
-protocol as :class:`~tf_yarn_tpu.coordination.kv.KVServer`; the driver
-prefers it when its binary has been built (`make -C tf_yarn_tpu/native`).
+protocol as :class:`~tf_yarn_tpu.coordination.kv.KVServer`. Its binary
+is never committed: `native_binary` builds it from the committed source
+with the committed Makefile whenever it is missing or older than the
+source, so which server a run uses follows from the checkout and the
+host's toolchain, never from a file left on disk. ``TPU_YARN_COORDD=
+python`` asks for the Python server by name.
 """
 
 from __future__ import annotations
@@ -18,7 +22,10 @@ from tf_yarn_tpu.coordination.kv import KVClient, KVServer
 
 _logger = logging.getLogger(__name__)
 
-NATIVE_BINARY = os.path.join(os.path.dirname(__file__), "..", "native", "coordd")
+NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "native")
+)
+NATIVE_BINARY = os.path.join(NATIVE_DIR, "coordd")
 
 
 class NativeServer:
@@ -55,12 +62,40 @@ def _free_port(host: str) -> int:
         return sock.getsockname()[1]
 
 
+def native_binary() -> Optional[str]:
+    """Path of an up-to-date coordd, built now if need be; None where
+    the host cannot build it (no make or compiler, read-only install)."""
+    import fcntl
+
+    try:
+        # One build at a time: concurrent drivers share the checkout.
+        with open(os.path.join(NATIVE_DIR, "Makefile")) as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            build = subprocess.run(
+                ["make", "-C", NATIVE_DIR, "coordd"],
+                capture_output=True, text=True, timeout=300,
+            )
+    except (OSError, subprocess.TimeoutExpired) as exc:
+        _logger.warning("cannot build native coordd: %s", exc)
+        return None
+    if build.returncode != 0:
+        _logger.warning(
+            "building native coordd failed (rc=%d): %s",
+            build.returncode, build.stderr.strip()[-500:],
+        )
+        return None
+    return NATIVE_BINARY
+
+
 def start_native_server(host: str = "127.0.0.1") -> Optional[NativeServer]:
-    binary = os.path.abspath(NATIVE_BINARY)
-    if not os.path.exists(binary):
+    binary = native_binary()
+    if binary is None:
         return None
     port = _free_port(host)
-    proc = subprocess.Popen([binary, host, str(port)])
+    # Its one line of greeting would land in the driver's own output.
+    proc = subprocess.Popen(
+        [binary, host, str(port)], stdout=subprocess.DEVNULL
+    )
     client = KVClient(f"{host}:{port}", connect_timeout=1.0)
     for _ in range(50):
         try:
@@ -83,3 +118,8 @@ def start_best_server(host: str = "127.0.0.1"):
         if native is not None:
             return native
     return KVServer(host).start()
+
+
+def server_kind(server) -> str:
+    """What `start_best_server` returned, for run reports."""
+    return "coordd" if isinstance(server, NativeServer) else "python"

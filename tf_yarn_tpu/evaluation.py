@@ -31,6 +31,7 @@ from tf_yarn_tpu import checkpoint as ckpt_lib
 from tf_yarn_tpu import event
 from tf_yarn_tpu import fs as fs_lib
 from tf_yarn_tpu.experiment import as_core_experiment
+from tf_yarn_tpu.parallel import mesh as mesh_lib
 from tf_yarn_tpu.tasks import _bootstrap
 from tf_yarn_tpu.training import build_eval_step, evaluate
 from tf_yarn_tpu.utils import mlflow
@@ -102,12 +103,9 @@ def continuous_eval(
         idle_timeout_secs = float(
             os.environ.get("TPU_YARN_EVAL_IDLE_TIMEOUT", DEFAULT_IDLE_TIMEOUT_SECS)
         )
-    platform = os.environ.get("TPU_YARN_PLATFORM")
-    if platform:  # evaluator is a CPU side-car; don't touch the slice's chips
-        try:
-            jax.config.update("jax_platforms", platform)
-        except Exception:  # pragma: no cover - backends already initialized
-            _logger.debug("jax_platforms narrowing skipped", exc_info=True)
+    # The launcher starts the evaluator as a CPU side-car
+    # (TPU_YARN_PLATFORM=cpu): only that backend may start here.
+    mesh_lib.select_devices()
     core = as_core_experiment(experiment)
     if not core.model_dir:
         raise ValueError("continuous evaluation needs an experiment model_dir")

@@ -763,9 +763,14 @@ EXPERIMENT_TYPES = (
 
 
 def run_experiment(runtime, experiment: Any) -> None:
-    """Entry used by tasks/worker.py."""
+    """Entry used by the task programs (tasks/worker.py, serving.py,
+    rank.py)."""
     from tf_yarn_tpu import telemetry
+    from tf_yarn_tpu.parallel import mesh as mesh_lib
 
+    # Whatever the experiment, it runs on the platform this process was
+    # started for, or not at all.
+    mesh_lib.select_devices()
     task = runtime.task if runtime is not None else "local"
     try:
         # Root span: the whole experiment body nests under it in the

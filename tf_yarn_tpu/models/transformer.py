@@ -54,10 +54,9 @@ class TransformerConfig:
     moe_experts: int = 0
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
-    # Fused pallas RMSNorm (ops/rmsnorm.py). Partition-aware: under pjit
-    # the kernel runs per shard (ops/_rowwise.sharded_rowwise), rows
-    # sharded freely, feature dim replicated. Opt-in — measured +~10%
-    # step time single-chip as part of the flash+fused+unroll variant.
+    # Fused pallas RMSNorm (ops/rmsnorm.py). Under the train loop's mesh
+    # the kernel runs on each device's own rows (ops/_rowwise.per_shard),
+    # feature dim whole. Opt-in.
     fused_norms: bool = False
     # KV-cache storage for autoregressive decode: "bf16" (exact) or
     # "int8" (per-row symmetric quantization via ops/quantize.py — halves

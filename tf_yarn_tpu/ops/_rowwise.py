@@ -1,17 +1,18 @@
-"""Shared scaffolding for row-wise pallas norms (rmsnorm, layernorm).
+"""Shared scaffolding for the pallas kernels: row-wise blocking for the
+norms, and `per_shard`, which runs any kernel on each device's own shard
+under the run's mesh.
 
-Both kernels reduce over the last dim only, so they share the same
-blocking: flatten leading dims to rows, tile rows into VMEM blocks (gcd
-fallback keeps the grid small on almost-divisible shapes), broadcast the
-[d]-shaped parameter vectors to every block. Keeping this in one place
-means a fix to the mechanics (block sizing, interpret default) lands in
-every kernel at once. groupnorm blocks per batch element (its reduction
-spans the spatial dims too) and intentionally does not use this.
+rmsnorm and layernorm reduce over the last dim only, so they share the
+same blocking: flatten leading dims to rows, tile rows into VMEM blocks
+(gcd fallback keeps the grid small on almost-divisible shapes), broadcast
+the [d]-shaped parameter vectors to every block. Keeping this in one
+place means a fix to the mechanics (block sizing, interpret default)
+lands in every kernel at once. groupnorm blocks per batch element (its
+reduction spans the spatial dims too) and uses only `per_shard`.
 """
 
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -70,134 +71,103 @@ def rowwise_call(kernel, x, vectors, block_rows: int, interpret: bool,
     return out.reshape(orig_shape)
 
 
-def make_sharded_op(local_fn, rule: str, need_replication: tuple,
-                    make_shardings):
-    """Wrap a local computation in `custom_partitioning` so pjit runs the
-    pallas kernel per shard instead of treating the custom call as
-    unpartitionable (which would replicate/gather the activation).
+def run_mesh():
+    """The mesh a kernel should be mapped over: the one the train loop
+    registered (parallel.mesh.current_mesh), or None where there is
+    nothing to map — no mesh, one device, or a caller that is already
+    per-shard (inside its own shard_map)."""
+    from tf_yarn_tpu.parallel.mesh import current_mesh
 
-    `rule`/`need_replication` feed the Shardy propagation rule
-    (need_replication factors MUST be listed in rule-introduction
-    order); `make_shardings(mesh, arg_shapes, result_shape) ->
-    (arg_shardings, out_shardings)` is the policy deciding what each
-    shard actually sees — XLA inserts a reshard when the observed
-    sharding differs (e.g. a user's pjit put `tp` on a dim the kernel's
-    reduction spans). Used by the fused norms (rows shard, feature
-    replicated) and flash attention (batch shards, all else replicated).
+    mesh = current_mesh()
+    if mesh is None or mesh.devices.size == 1:
+        return None
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return mesh
 
-    Differentiation never reaches the primitive: callers keep it inside
-    a custom_vjp forward whose backward recomputes locally. The wrapped
-    op is NOT vmappable (custom_partitioning has no batching rule) —
-    unnecessary here, since the kernels accept arbitrary leading dims
-    natively; reshape instead of vmap.
+
+def dividing(mesh, axes, dim: int):
+    """`axes` (mesh axis names) as a PartitionSpec entry for a dimension
+    of size `dim`: the axes themselves where their joint size divides
+    it, else None — a dimension that cannot be split stays whole on
+    every device, which is slower and still right."""
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    axes = tuple(a for a in axes if sizes.get(a, 1) > 1)
+    joint = math.prod(sizes[a] for a in axes)
+    if not axes or dim % joint:
+        return None
+    return axes if len(axes) > 1 else axes[0]
+
+
+def per_shard(local_fn, args, in_specs, out_specs):
+    """Run a pallas kernel on each device's own shard of `args`.
+
+    XLA cannot partition a Mosaic custom call: left alone under pjit it
+    gathers the whole activation onto every device first. `shard_map`
+    over the run's mesh (`run_mesh`) hands each device its shard
+    instead; `in_specs(mesh)` / `out_specs(mesh)` say how the operands
+    and results lie, and an operand that arrives otherwise is resharded
+    by XLA. (The first version wrapped the kernels in
+    `custom_partitioning`; libtpu's compiler has no partitioner for it —
+    "Custom emitter for CustomSPMDPartitioning not found" — so nothing
+    sharded ever compiled for a TPU.)
+
+    Differentiation never reaches the shard_map: callers keep it inside
+    a custom_vjp forward whose backward is another `per_shard` call plus
+    plain XLA reductions, so cross-shard sums (dscale, dbias) are
+    pjit's to insert.
     """
-    import inspect
-
-    from jax.experimental.custom_partitioning import custom_partitioning
-
-    @custom_partitioning
-    def wrapped(*args):
+    mesh = run_mesh()
+    if mesh is None:
         return local_fn(*args)
-
-    def partition(mesh, arg_shapes, result_shape):
-        arg_shs, out_shs = make_shardings(mesh, arg_shapes, result_shape)
-        return mesh, local_fn, out_shs, arg_shs
-
-    if "sharding_rule" in inspect.signature(
-        custom_partitioning.def_partition
-    ).parameters:
-        # Shardy builds: the einsum-like rule drives propagation.
-        wrapped.def_partition(
-            partition=partition,
-            sharding_rule=rule,
-            need_replication_factors=need_replication,
-        )
-    else:
-        # GSPMD builds (no sharding_rule kwarg): propagation comes from
-        # the infer callback instead — the result sharding is whatever
-        # make_shardings derives from the observed operand shardings,
-        # which encodes the same policy the rule states declaratively.
-        def infer_sharding(mesh, arg_shapes, result_shape):
-            _, out_shs = make_shardings(mesh, arg_shapes, result_shape)
-            return out_shs
-
-        wrapped.def_partition(
-            partition=partition,
-            infer_sharding_from_operands=infer_sharding,
-        )
-    return wrapped
+    return jax.shard_map(
+        local_fn, mesh=mesh, in_specs=in_specs(mesh),
+        out_specs=out_specs(mesh), check_vma=False,
+    )(*args)
 
 
-def padded_spec(shape, sharding) -> list:
-    """The operand's PartitionSpec as a full-rank list (trailing dims
-    None-padded)."""
-    return list(sharding.spec) + [None] * (len(shape) - len(sharding.spec))
+def rows_spec(mesh, shape):
+    """How a [..., d] activation lies on the mesh: the leading (batch)
+    dim over the data axes, a second (sequence) dim over `sp`, the
+    feature dim whole — row-wise kernels reduce over it."""
+    from jax.sharding import PartitionSpec
+
+    from tf_yarn_tpu.parallel.mesh import AXIS_SP, BATCH_AXES
+
+    spec = [None] * len(shape)
+    if len(shape) >= 2:
+        spec[0] = dividing(mesh, BATCH_AXES, shape[0])
+    if len(shape) >= 3:
+        spec[1] = dividing(mesh, (AXIS_SP,), shape[1])
+    return PartitionSpec(*spec)
 
 
-def sharded_rowwise(local_fn, n_vectors: int, n_rows: int = 1):
-    """Partition-aware row-wise op: rows shard freely, the feature
-    (last) dim and the [d] parameter vectors must be replicated.
-    `n_rows` > 1 admits extra x-shaped operands (a backward pass's
-    cotangent) sharded identically to x."""
-    from jax.sharding import NamedSharding, PartitionSpec
+def batch_spec(mesh, shape):
+    """Only the leading (batch) dim split, over the data axes."""
+    from jax.sharding import PartitionSpec
 
-    def make_shardings(mesh, arg_shapes, result_shape):
-        spec = padded_spec(arg_shapes[0].shape, arg_shapes[0].sharding)
-        x_sh = NamedSharding(mesh, PartitionSpec(*spec[:-1], None))
-        vec_sh = NamedSharding(mesh, PartitionSpec(None))
-        return (x_sh,) * n_rows + (vec_sh,) * n_vectors, x_sh
+    from tf_yarn_tpu.parallel.mesh import BATCH_AXES
 
-    operand_rule = ", ".join(["... d"] * n_rows + ["d"] * n_vectors)
-    return make_sharded_op(
-        local_fn,
-        rule=f"{operand_rule} -> ... d",
-        need_replication=("d",),
-        make_shardings=make_shardings,
-    )
+    spec = [None] * len(shape)
+    if len(shape) >= 2:  # a [c] parameter vector stays whole everywhere
+        spec[0] = dividing(mesh, BATCH_AXES, shape[0])
+    return PartitionSpec(*spec)
 
 
-def sharded_batch_only(local_fn, rule: str, need_replication: tuple):
-    """Partition-aware op where ONLY the leading (batch) dim shards:
-    every operand and result leads with it; all other dims replicate."""
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    def make_shardings(mesh, arg_shapes, result_shape):
-        first = padded_spec(arg_shapes[0].shape, arg_shapes[0].sharding)
-        batch_axis = first[0] if first else None
-
-        def batch_sh(shape):
-            if len(shape) <= 1:
-                # Parameter vectors don't carry a batch dim: replicate.
-                return NamedSharding(mesh, PartitionSpec(None))
-            return NamedSharding(
-                mesh, PartitionSpec(batch_axis, *([None] * (len(shape) - 1))))
-
-        arg_shs = tuple(batch_sh(a.shape) for a in arg_shapes)
-        if isinstance(result_shape, (list, tuple)):
-            out_shs = tuple(batch_sh(r.shape) for r in result_shape)
-        else:
-            out_shs = batch_sh(result_shape.shape)
-        return arg_shs, out_shs
-
-    return make_sharded_op(
-        local_fn, rule=rule, need_replication=need_replication,
-        make_shardings=make_shardings,
-    )
-
-
-@functools.lru_cache(maxsize=None)
-def sharded_rowwise_call(kernel_factory, kernel_args, n_vectors: int,
-                         block_rows: int, interpret: bool,
-                         n_rows: int = 1):
-    """Cached partition-aware rowwise op. `kernel_factory(*kernel_args)`
-    builds the pallas kernel body; all keys must be hashable (floats,
-    ints, bools), so each distinct config creates exactly one
-    custom_partitioning primitive for the process lifetime."""
-    kernel = kernel_factory(*kernel_args)
+def sharded_rowwise_call(kernel, block_rows: int, interpret: bool, x,
+                         vectors, row_operands=()):
+    """`rowwise_call` on each device's rows: x and the x-shaped
+    `row_operands` (a backward pass's cotangent) split by `rows_spec`,
+    which leaves the [d] parameter `vectors` whole everywhere."""
+    n_rows = 1 + len(row_operands)
+    args = (x, *row_operands, *vectors)
 
     def local_fn(x, *rest):
-        extra, vectors = rest[: n_rows - 1], rest[n_rows - 1:]
-        return rowwise_call(kernel, x, vectors, block_rows, interpret,
-                            row_operands=extra)
+        return rowwise_call(kernel, x, rest[n_rows - 1:], block_rows,
+                            interpret, row_operands=rest[:n_rows - 1])
 
-    return sharded_rowwise(local_fn, n_vectors, n_rows=n_rows)
+    return per_shard(
+        local_fn, args,
+        lambda mesh: tuple(rows_spec(mesh, a.shape) for a in args),
+        lambda mesh: rows_spec(mesh, x.shape),
+    )

@@ -68,7 +68,12 @@ def _decode_kernel(length_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
     # Positions of this kv block; everything at/after `length` is dead
     # (cache slots not yet written).
     pos = ki * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-    live_row = pos < length  # (1, block_k)
+    live_row = pos < length  # (1, block_k): masks the logits' lanes
+    # The same mask down the sublanes, for v's rows. Built from its own
+    # iota: Mosaic cannot reshape a lane-major bool vector into a column.
+    live_col = ki * block_k + lax.broadcasted_iota(
+        jnp.int32, (block_k, 1), 0
+    ) < length  # (block_k, 1)
 
     @pl.when(ki * block_k < length)
     def _step():
@@ -82,7 +87,6 @@ def _decode_kernel(length_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
             # zeroed in v, not just masked in the logits: p is 0 there but
             # pad garbage in the f32 scales can be NaN, and 0 * NaN = NaN
             # in the p @ v accumulation.
-            live_col = live_row[0][:, None]  # (block_k, 1)
             k_f = k_blk.astype(jnp.float32) * scale_k
             v_f = jnp.where(
                 live_col, v_blk.astype(jnp.float32) * scale_v, 0.0
@@ -224,6 +228,9 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, ks_ref,
         jnp.int32, (1, block_size), 1
     )
     live_row = pos < length  # (1, block_size)
+    live_col = ki * block_size + lax.broadcasted_iota(
+        jnp.int32, (block_size, 1), 0
+    ) < length  # (block_size, 1), in its own layout (see _decode_kernel)
 
     @pl.when(ki * block_size < length)
     def _step():
@@ -232,7 +239,6 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, ks_ref,
             v_blk = v_ref[0, :, h * head_dim:(h + 1) * head_dim]
             scale_k = ks_ref[0, :, h:h + 1]  # (sb, 1): broadcasts sb=1
             scale_v = vs_ref[0, :, h:h + 1]
-            live_col = live_row[0][:, None]
             k_f = k_blk.astype(jnp.float32) * scale_k
             v_f = jnp.where(
                 live_col, v_blk.astype(jnp.float32) * scale_v, 0.0

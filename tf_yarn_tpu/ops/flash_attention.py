@@ -37,6 +37,7 @@ chip entirely, use ring attention over `sp`.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -444,23 +445,40 @@ def _flash_backward(
 
 
 # ---------------------------------------------------------------------------
-# Partition awareness: under pjit the kernels run per batch shard
+# Under the run's mesh the kernels run on each device's own shard
 # ---------------------------------------------------------------------------
 #
-# Without a sharding rule XLA treats the pallas custom calls as
-# unpartitionable and REPLICATES q/k/v on every device (measured:
-# out sharding collapses to PartitionSpec() under a dp mesh) — attention
-# would stop scaling with chips. The wrappers shard the batch dim and
-# replicate seq/head/feature (conservative: dp/fsdp layouts, the common
-# case; head-sharded tp attention should use the xla/ring/ulysses impls).
-# Differentiation never reaches the primitives: they live inside the
-# custom_vjp below. LSE residuals cross the boundary as [B, H, S, L] so
-# every operand/result leads with the batch dim the rule shards.
+# XLA treats the pallas custom calls as unpartitionable and, left alone,
+# REPLICATES q/k/v on every device — attention would stop scaling with
+# chips. `_rowwise.per_shard` maps the kernels over the run's mesh
+# instead: the batch dim over the data axes, and the head dim over `tp`
+# where both head counts divide (the layout the column-parallel q/k/v
+# projections produce — contiguous blocks of query heads land with their
+# own KV heads, so GQA groups stay together); the sequence stays whole
+# (ring/ulysses shard it). Differentiation never reaches the shard_map:
+# it lives inside the custom_vjp below. LSE residuals cross the boundary
+# as [B, H, S, L] so that they split like everything else.
 
 
-@functools.lru_cache(maxsize=None)
-def _sharded_flash_fwd(causal, softmax_scale, block_q, block_k, interpret,
-                       save_residuals):
+def _qkv_spec(mesh, query_shape, key_shape, heads_dim: int = 2):
+    """PartitionSpec of a [B, S, H, D] operand (heads_dim=2) or the
+    [B, H, S, L] residual (heads_dim=1) under `mesh`."""
+    from jax.sharding import PartitionSpec
+
+    from tf_yarn_tpu.ops._rowwise import dividing
+    from tf_yarn_tpu.parallel.mesh import AXIS_TP, BATCH_AXES
+
+    n_heads, n_kv = query_shape[2], key_shape[2]
+    heads = dividing(mesh, (AXIS_TP,), math.gcd(n_heads, n_kv))
+    spec = [dividing(mesh, BATCH_AXES, query_shape[0]), None, None, None]
+    spec[heads_dim] = heads
+    return PartitionSpec(*spec)
+
+
+def _sharded_flash_fwd(query, key, value, causal, softmax_scale, block_q,
+                       block_k, interpret, save_residuals):
+    from tf_yarn_tpu.ops._rowwise import per_shard
+
     def local_fn(query, key, value):
         out, lse = _flash_forward(
             query, key, value, causal, softmax_scale, block_q, block_k,
@@ -471,21 +489,23 @@ def _sharded_flash_fwd(causal, softmax_scale, block_q, block_k, interpret,
         b, _, n_heads, _ = query.shape
         return out, lse.reshape(b, n_heads, *lse.shape[1:])
 
-    # need_replication must list factors in rule-introduction order
-    # (b=0, s, h, d, then t, k from the key operand, then l).
-    if save_residuals:
-        rule = "b s h d, b t k d, b t k d -> b s h d, b h s l"
-        repl = ("s", "h", "d", "t", "k", "l")
-    else:
-        rule = "b s h d, b t k d, b t k d -> b s h d"
-        repl = ("s", "h", "d", "t", "k")
-    from tf_yarn_tpu.ops._rowwise import sharded_batch_only
+    def out_specs(mesh):
+        qkv = _qkv_spec(mesh, query.shape, key.shape)
+        if not save_residuals:
+            return qkv
+        return qkv, _qkv_spec(mesh, query.shape, key.shape, heads_dim=1)
 
-    return sharded_batch_only(local_fn, rule, repl)
+    return per_shard(
+        local_fn, (query, key, value),
+        lambda mesh: (_qkv_spec(mesh, query.shape, key.shape),) * 3,
+        out_specs,
+    )
 
 
-@functools.lru_cache(maxsize=None)
-def _sharded_flash_bwd(causal, softmax_scale, block_q, block_k, interpret):
+def _sharded_flash_bwd(query, key, value, out, lse4, g, causal,
+                       softmax_scale, block_q, block_k, interpret):
+    from tf_yarn_tpu.ops._rowwise import per_shard
+
     def local_fn(query, key, value, out, lse4, g):
         b, h = lse4.shape[0], lse4.shape[1]
         lse = lse4.reshape(b * h, *lse4.shape[2:])
@@ -494,11 +514,15 @@ def _sharded_flash_bwd(causal, softmax_scale, block_q, block_k, interpret):
             causal, softmax_scale, block_q, block_k, interpret,
         )
 
-    rule = ("b s h d, b t k d, b t k d, b s h d, b h s l, b s h d "
-            "-> b s h d, b t k d, b t k d")
-    from tf_yarn_tpu.ops._rowwise import sharded_batch_only
+    def in_specs(mesh):
+        qkv = _qkv_spec(mesh, query.shape, key.shape)
+        lse = _qkv_spec(mesh, query.shape, key.shape, heads_dim=1)
+        return (qkv, qkv, qkv, qkv, lse, qkv)
 
-    return sharded_batch_only(local_fn, rule, ("s", "h", "d", "t", "k", "l"))
+    return per_shard(
+        local_fn, (query, key, value, out, lse4, g), in_specs,
+        lambda mesh: (_qkv_spec(mesh, query.shape, key.shape),) * 3,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -511,66 +535,28 @@ def _sharded_flash_bwd(causal, softmax_scale, block_q, block_k, interpret):
 )
 def _flash(query, key, value, causal, softmax_scale, block_q, block_k, interpret):
     return _sharded_flash_fwd(
-        causal, softmax_scale, block_q, block_k, interpret, False
-    )(query, key, value)
+        query, key, value, causal, softmax_scale, block_q, block_k,
+        interpret, False,
+    )
 
 
 def _flash_fwd(query, key, value, causal, softmax_scale, block_q, block_k, interpret):
     out, lse4 = _sharded_flash_fwd(
-        causal, softmax_scale, block_q, block_k, interpret, True
-    )(query, key, value)
+        query, key, value, causal, softmax_scale, block_q, block_k,
+        interpret, True,
+    )
     return out, (query, key, value, out, lse4)
 
 
 def _flash_bwd(causal, softmax_scale, block_q, block_k, interpret, residuals, g):
     query, key, value, out, lse4 = residuals
     return _sharded_flash_bwd(
-        causal, softmax_scale, block_q, block_k, interpret
-    )(query, key, value, out, lse4, g)
+        query, key, value, out, lse4, g, causal, softmax_scale, block_q,
+        block_k, interpret,
+    )
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
-
-
-# Local (non-partition-aware) twin of _flash: identical math, but the
-# kernels are invoked directly instead of through custom_partitioning.
-# For callers that are ALREADY per-shard — e.g. ulysses attention calls
-# flash inside its own shard_map, where each shard is one device and the
-# partition wrapper is dead weight (and custom_partitioning primitives
-# cannot be staged under shard_map on every jax build).
-
-
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
-)
-def _flash_local(query, key, value, causal, softmax_scale, block_q, block_k,
-                 interpret):
-    out, _ = _flash_forward(
-        query, key, value, causal, softmax_scale, block_q, block_k,
-        interpret, save_residuals=False,
-    )
-    return out
-
-
-def _flash_local_fwd(query, key, value, causal, softmax_scale, block_q,
-                     block_k, interpret):
-    out, lse = _flash_forward(
-        query, key, value, causal, softmax_scale, block_q, block_k,
-        interpret, save_residuals=True,
-    )
-    return out, (query, key, value, out, lse)
-
-
-def _flash_local_bwd(causal, softmax_scale, block_q, block_k, interpret,
-                     residuals, g):
-    query, key, value, out, lse = residuals
-    return _flash_backward(
-        query, key, value, out, lse, g,
-        causal, softmax_scale, block_q, block_k, interpret,
-    )
-
-
-_flash_local.defvjp(_flash_local_fwd, _flash_local_bwd)
 
 
 def flash_attention(
@@ -583,14 +569,12 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     interpret: Optional[bool] = None,
-    partition_aware: bool = True,
 ) -> jax.Array:
     """Blockwise (flash) attention, differentiable via pallas backward
     kernels that recompute probabilities from the saved log-sum-exp.
-
-    ``partition_aware=False`` skips the custom_partitioning wrappers and
-    calls the kernels directly — for callers that are already per-shard
-    (inside their own shard_map, where every shard is one device).
+    Under the run's mesh the kernels run on each device's own shard
+    (`_rowwise.per_shard`); a caller that is already per-shard, inside
+    its own shard_map, gets them directly.
 
     Default blocks are 512x512 (clamped to the sequence): measured on
     v5e, 128x128 tiles are grid-overhead-bound — 512 is ~1.8x faster at
@@ -609,7 +593,6 @@ def flash_attention(
         from tf_yarn_tpu.ops._rowwise import default_interpret
 
         interpret = default_interpret()
-    fn = _flash if partition_aware else _flash_local
-    return fn(
+    return _flash(
         query, key, value, causal, softmax_scale, block_q, block_k, interpret
     )

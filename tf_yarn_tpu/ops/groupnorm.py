@@ -60,22 +60,23 @@ def groupnorm_reference(x, scale, bias, groups: int, eps: float = 1e-5):
             + bias.astype(jnp.float32)).astype(x.dtype)
 
 
-def _slab_group_stats(x2d, assign, groups: int, eps: float):
-    """(mean_c, inv_c) per channel for one [HW, C] slab — the in-kernel
+def _slab_group_stats(x2d, assign, spread, groups: int, eps: float):
+    """(mean_c, inv_c), each [1, C], for one [HW, C] slab — the in-kernel
     stats definition, shared by the forward and dx kernels. Per-channel
-    sums fold into per-group stats via the assignment matmul (lane dim
-    stays C) and broadcast back with its transpose."""
+    sums fold into per-group stats via the [C, G] assignment matmul (lane
+    dim stays C) and broadcast back through its [G, C] twin. Every
+    operand stays 2-D: Mosaic has no matmul for a rank-1 side."""
     hw, c = x2d.shape
     n = jnp.float32(hw * (c // groups))
-    mean_g = (jnp.sum(x2d, axis=0) @ assign) / n  # [G]
+    mean_g = _dot(jnp.sum(x2d, axis=0, keepdims=True), assign) / n  # [1, G]
     # One-pass variance can round negative under f32 cancellation (large
     # mean, tiny spread: ulp at 1e6 is ~0.06); clamp like flax's
     # use_fast_variance path or rsqrt(negative) poisons the slab with NaN.
     var_g = jnp.maximum(
-        (jnp.sum(x2d * x2d, axis=0) @ assign) / n - mean_g * mean_g, 0.0)
+        _dot(jnp.sum(x2d * x2d, axis=0, keepdims=True), assign) / n
+        - mean_g * mean_g, 0.0)
     inv_g = jax.lax.rsqrt(var_g + eps)
-    # Broadcast group stats back onto channels: [G] @ [G, C].
-    return mean_g @ assign.T, inv_g @ assign.T
+    return _dot(mean_g, spread), _dot(inv_g, spread)
 
 
 def _groupnorm_kernel(x_ref, scale_ref, bias_ref, o_ref, *,
@@ -83,10 +84,9 @@ def _groupnorm_kernel(x_ref, scale_ref, bias_ref, o_ref, *,
     x = x_ref[...].astype(jnp.float32)  # [1, HW, C] block: one batch elem
     hw, c = x.shape[-2], x.shape[-1]
     x2d = x.reshape(hw, c)
-    # One-hot channel->group assignment, built from iota (no gathers).
-    assign = _group_assign(c, groups)  # [C, G]
-    mean_c, inv_c = _slab_group_stats(x2d, assign, groups, eps)
-    y = (x2d - mean_c[None, :]) * inv_c[None, :]
+    assign, spread = _group_assign(c, groups)
+    mean_c, inv_c = _slab_group_stats(x2d, assign, spread, groups, eps)
+    y = (x2d - mean_c) * inv_c
     y = y * scale_ref[...].astype(jnp.float32)[None, :]
     y = y + bias_ref[...].astype(jnp.float32)[None, :]
     o_ref[...] = y.reshape(x.shape).astype(o_ref.dtype)
@@ -116,35 +116,43 @@ def _groupnorm_local(x, scale, bias, groups, eps, interpret):
     return out.reshape(x.shape)
 
 
-@functools.lru_cache(maxsize=None)
-def _sharded_groupnorm(ndim: int, groups: int, eps: float, interpret: bool):
-    """Partition-aware wrapper: the batch dim shards freely (each shard
-    norms its own images), spatial + channel dims must be replicated —
-    the per-(batch, group) reduction spans them. One primitive per
-    (ndim, groups, eps, interpret) config for the process lifetime."""
-    from tf_yarn_tpu.ops._rowwise import sharded_batch_only
+def _per_batch_shard(local_fn, *args):
+    """`local_fn` on each device's own images: the batch dim splits over
+    the data axes (each shard norms its own images), spatial + channel
+    dims stay whole — the per-(batch, group) reduction spans them."""
+    from tf_yarn_tpu.ops._rowwise import batch_spec, per_shard
 
-    def local_fn(x, scale, bias):
-        return _groupnorm_local(x, scale, bias, groups, eps, interpret)
-
-    dims = " ".join(f"s{i}" for i in range(ndim - 2))
-    return sharded_batch_only(
-        local_fn,
-        rule=f"b {dims} c, c, c -> b {dims} c",
-        need_replication=tuple(f"s{i}" for i in range(ndim - 2)) + ("c",),
+    return per_shard(
+        local_fn, args,
+        lambda mesh: tuple(batch_spec(mesh, a.shape) for a in args),
+        lambda mesh: batch_spec(mesh, args[0].shape),
     )
 
 
 def _groupnorm_forward(x, scale, bias, groups, eps, interpret):
-    return _sharded_groupnorm(x.ndim, groups, eps, interpret)(x, scale, bias)
+    return _per_batch_shard(
+        lambda x, scale, bias: _groupnorm_local(
+            x, scale, bias, groups, eps, interpret),
+        x, scale, bias,
+    )
 
 
 def _group_assign(c: int, groups: int):
-    """[C, G] one-hot channel->group assignment (iota, no gathers)."""
+    """One-hot channel<->group maps built from iota (no gathers, no
+    in-kernel transpose): ([C, G] folding channels into groups, [G, C]
+    spreading group values back over their channels)."""
     cg = c // groups
-    chan = jax.lax.broadcasted_iota(jnp.int32, (c, groups), 0)
-    grp = jax.lax.broadcasted_iota(jnp.int32, (c, groups), 1)
-    return (chan // cg == grp).astype(jnp.float32)
+
+    def one_hot(shape, chan_dim):
+        chan = jax.lax.broadcasted_iota(jnp.int32, shape, chan_dim)
+        grp = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - chan_dim)
+        return (chan // cg == grp).astype(jnp.float32)
+
+    return one_hot((c, groups), 0), one_hot((groups, c), 1)
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
 def _groupnorm_bwd_dx_kernel(x_ref, g_ref, scale_ref, o_ref, *,
@@ -154,13 +162,16 @@ def _groupnorm_bwd_dx_kernel(x_ref, g_ref, scale_ref, o_ref, *,
     x2d = x.reshape(hw, c)
     g2d = g_ref[...].astype(jnp.float32).reshape(hw, c)
     gs = g2d * scale_ref[...].astype(jnp.float32)[None, :]
-    assign = _group_assign(c, groups)
+    assign, spread = _group_assign(c, groups)
     n = jnp.float32(hw * (c // groups))
-    mean_c, inv_c = _slab_group_stats(x2d, assign, groups, eps)
-    norm = (x2d - mean_c[None, :]) * inv_c[None, :]
-    m1_c = ((jnp.sum(gs, axis=0) @ assign) / n) @ assign.T
-    m2_c = ((jnp.sum(gs * norm, axis=0) @ assign) / n) @ assign.T
-    dx = inv_c[None, :] * (gs - m1_c[None, :] - norm * m2_c[None, :])
+    mean_c, inv_c = _slab_group_stats(x2d, assign, spread, groups, eps)
+    norm = (x2d - mean_c) * inv_c
+
+    def group_mean(t):  # [HW, C] -> its group's mean on every channel
+        return _dot(_dot(jnp.sum(t, axis=0, keepdims=True), assign) / n,
+                    spread)
+
+    dx = inv_c * (gs - group_mean(gs) - norm * group_mean(gs * norm))
     o_ref[...] = dx.reshape(x.shape).astype(o_ref.dtype)
 
 
@@ -184,25 +195,6 @@ def _groupnorm_bwd_dx_local(x, g, scale, groups, eps, interpret):
     return out.reshape(x.shape)
 
 
-@functools.lru_cache(maxsize=None)
-def _sharded_groupnorm_bwd_dx(ndim: int, groups: int, eps: float,
-                              interpret: bool):
-    """Partition-aware dx: batch shards (each shard differentiates its
-    own images), spatial + channel replicated — same policy as forward,
-    with the cotangent as a second batch-led operand."""
-    from tf_yarn_tpu.ops._rowwise import sharded_batch_only
-
-    def local_fn(x, g, scale):
-        return _groupnorm_bwd_dx_local(x, g, scale, groups, eps, interpret)
-
-    dims = " ".join(f"s{i}" for i in range(ndim - 2))
-    return sharded_batch_only(
-        local_fn,
-        rule=f"b {dims} c, b {dims} c, c -> b {dims} c",
-        need_replication=tuple(f"s{i}" for i in range(ndim - 2)) + ("c",),
-    )
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _groupnorm(x, scale, bias, groups, eps, interpret, kernel_bwd):
     return _groupnorm_forward(x, scale, bias, groups, eps, interpret)
@@ -221,8 +213,11 @@ def _groupnorm_bwd(groups, eps, interpret, kernel_bwd, residuals, g):
             x, scale, bias,
         )
         return vjp(g)
-    dx = _sharded_groupnorm_bwd_dx(x.ndim, groups, eps, interpret)(
-        x, g, scale)
+    dx = _per_batch_shard(
+        lambda x, g, scale: _groupnorm_bwd_dx_local(
+            x, g, scale, groups, eps, interpret),
+        x, g, scale,
+    )
     # dscale/dbias: cross-batch sums, XLA-fused (auto-psum under pjit).
     b, c = x.shape[0], x.shape[-1]
     norm = _norm32(x, groups, eps).reshape(b, -1, c)

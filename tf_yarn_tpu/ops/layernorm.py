@@ -49,13 +49,13 @@ def _make_layernorm_kernel(eps: float):
 
 
 def _layernorm_forward(x, scale, bias, eps, block_rows, interpret):
-    # Partition-aware: under pjit the kernel runs on each shard's rows
-    # (ops/_rowwise.sharded_rowwise); plain rowwise pallas elsewhere.
+    # Under the run's mesh the kernel runs on each device's own rows
+    # (ops/_rowwise.per_shard); plain rowwise pallas elsewhere.
     from tf_yarn_tpu.ops._rowwise import sharded_rowwise_call
 
     return sharded_rowwise_call(
-        _make_layernorm_kernel, (eps,), 2, block_rows, interpret
-    )(x, scale, bias)
+        _make_layernorm_kernel(eps), block_rows, interpret, x, (scale, bias)
+    )
 
 
 def _layernorm_bwd_dx_kernel(x_ref, g_ref, scale_ref, o_ref, *, eps: float):
@@ -96,9 +96,9 @@ def _layernorm_bwd(eps, block_rows, interpret, kernel_bwd, residuals, g):
     from tf_yarn_tpu.ops._rowwise import sharded_rowwise_call
 
     dx = sharded_rowwise_call(
-        _make_layernorm_bwd_dx_kernel, (eps,), 1, block_rows, interpret,
-        n_rows=2,
-    )(x, g, scale)
+        _make_layernorm_bwd_dx_kernel(eps), block_rows, interpret, x,
+        (scale,), row_operands=(g,),
+    )
     x32 = x.astype(jnp.float32)
     g32 = g.astype(jnp.float32)
     mean = jnp.mean(x32, axis=-1, keepdims=True)

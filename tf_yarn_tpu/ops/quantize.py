@@ -2,14 +2,12 @@
 
 The quantization pattern from the TPU kernel playbook (/opt/skills/guides/
 pallas_guide.md §Patterns: Quantization Kernels): per-row abs-max scales,
-int8 values, optional stochastic rounding via the on-chip PRNG (TPU only —
-interpret mode rounds to nearest). Useful for int8 activation/weight
-compression of checkpoints and comms.
+int8 values rounded to nearest. The int8 KV cache's write path
+(models/transformer.py).
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -17,29 +15,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _quantize_kernel(x_ref, values_ref, scales_ref, *, stochastic: bool,
-                     seed: int):
+def _quantize_kernel(x_ref, values_ref, scales_ref):
     x = x_ref[...].astype(jnp.float32)
     abs_max = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.maximum(abs_max, 1e-8) / 127.0
-    scaled = x / scale
-    if stochastic:
-        from jax.experimental.pallas import tpu as pltpu
-
-        pltpu.prng_seed(seed + pl.program_id(0))
-        bits = pltpu.bitcast(pltpu.prng_random_bits(scaled.shape), jnp.uint32)
-        values = pltpu.stochastic_round(scaled, bits, target_dtype=jnp.int8)
-    else:
-        values = jnp.clip(jnp.round(scaled), -127, 127).astype(jnp.int8)
-    values_ref[...] = values
+    values_ref[...] = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
     scales_ref[...] = scale.astype(jnp.float32)
 
 
 def quantize_int8(
     x: jax.Array,
     *,
-    stochastic: Optional[bool] = None,
-    seed: int = 0,
     block_rows: int = 256,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -50,10 +36,6 @@ def quantize_int8(
         from tf_yarn_tpu.ops._rowwise import default_interpret
 
         interpret = default_interpret()
-    if stochastic is None:
-        stochastic = False  # deterministic by default; opt in on TPU
-    if stochastic and interpret:
-        raise ValueError("stochastic rounding needs the TPU PRNG (interpret=False)")
     orig_shape = x.shape
     d = orig_shape[-1]
     rows = 1
@@ -67,7 +49,7 @@ def quantize_int8(
     if rows % block_rows:
         block_rows = math.gcd(rows, block_rows)
     values, scales = pl.pallas_call(
-        functools.partial(_quantize_kernel, stochastic=stochastic, seed=seed),
+        _quantize_kernel,
         grid=(rows // block_rows,),
         in_specs=[pl.BlockSpec((block_rows, d), lambda i: (i, 0))],
         out_specs=(

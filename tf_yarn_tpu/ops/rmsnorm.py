@@ -9,9 +9,9 @@ vjp ``dx = r·(g·s) − x·r³·mean(g·s·x)`` keeps both rowwise reductions
 in VMEM, reading x and g once and writing dx once. dx is row-local
 given the replicated scale, so it shards under the SAME rowwise rule as
 the forward. dscale = Σ_rows g·x·r is a cross-row (and under pjit
-cross-shard) reduction, left to an XLA fusion — jnp.sum over the
-sharded rows inserts the psum, which a custom_partitioning kernel
-cannot (no axis context in its lower_fn). kernel_bwd=False keeps the
+cross-shard) reduction, left to an XLA fusion outside the per-shard
+kernel — jnp.sum over the sharded rows inserts the psum. kernel_bwd=False
+keeps the
 recompute-through-reference vjp for A/B (docs/Performance.md derives
 the expected gap).
 """
@@ -43,13 +43,13 @@ def _make_rmsnorm_kernel(eps: float):
 
 
 def _rmsnorm_forward(x, scale, eps: float, block_rows: int, interpret: bool):
-    # Partition-aware: under pjit the kernel runs on each shard's rows
-    # (ops/_rowwise.sharded_rowwise); plain rowwise pallas elsewhere.
+    # Under the run's mesh the kernel runs on each device's own rows
+    # (ops/_rowwise.per_shard); plain rowwise pallas elsewhere.
     from tf_yarn_tpu.ops._rowwise import sharded_rowwise_call
 
     return sharded_rowwise_call(
-        _make_rmsnorm_kernel, (eps,), 1, block_rows, interpret
-    )(x, scale)
+        _make_rmsnorm_kernel(eps), block_rows, interpret, x, (scale,)
+    )
 
 
 def _rmsnorm_bwd_dx_kernel(x_ref, g_ref, scale_ref, o_ref, *, eps: float):
@@ -82,9 +82,9 @@ def _rmsnorm_bwd(eps, block_rows, interpret, kernel_bwd, residuals, g):
     from tf_yarn_tpu.ops._rowwise import sharded_rowwise_call
 
     dx = sharded_rowwise_call(
-        _make_rmsnorm_bwd_dx_kernel, (eps,), 1, block_rows, interpret,
-        n_rows=2,
-    )(x, g, scale)
+        _make_rmsnorm_bwd_dx_kernel(eps), block_rows, interpret, x,
+        (scale,), row_operands=(g,),
+    )
     x32 = x.astype(jnp.float32)
     g32 = g.astype(jnp.float32)
     r = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)

@@ -3,8 +3,7 @@
 The data-plane primitives that replace the reference's Horovod/Gloo rings
 and NCCL (SURVEY.md §2.4): thin, named wrappers over XLA collectives so
 user code inside shard_map reads like the intent, plus the allreduce
-bandwidth microbench that is one of this repo's two north-star metrics
-(BASELINE.md).
+bandwidth microbench (ROADMAP.md A0 wants ICI in the peaks table).
 """
 
 from __future__ import annotations
@@ -16,25 +15,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-
-
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """`jax.shard_map` across jax versions: new builds expose it at the
-    top level (``check_vma``); older ones only under
-    ``jax.experimental.shard_map`` where the flag is ``check_rep``.
-    Every shard_map in this repo routes through here so the version seam
-    lives in one place."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
 
 
 def all_reduce_mean(x, axis_name: str):
@@ -71,8 +51,7 @@ def allreduce_bandwidth(
     """Measure allreduce algorithmic bandwidth over all local devices.
 
     Returns {gbps, elapsed_s, size_mb, n_devices}. Algorithmic bandwidth =
-    2*(n-1)/n * bytes / time (ring allreduce cost model) — the number the
-    BASELINE.md north-star table tracks for ICI.
+    2*(n-1)/n * bytes / time (ring allreduce cost model).
     """
     from jax.sharding import Mesh, NamedSharding
 
@@ -92,24 +71,21 @@ def allreduce_bandwidth(
     def one(s):
         return jax.lax.psum(s, axis) * (1.0 / max(n, 1))
 
-    # All `iters` reductions chain inside ONE jitted program, synced by a
-    # scalar fetch: per-execution dispatch overhead stays out of the
-    # measurement, and the fetch forces completion on backends where
-    # block_until_ready is advisory (remote relays).
+    # All `iters` reductions chain inside ONE jitted program, so one
+    # dispatch covers the whole timed region.
     @jax.jit
     def run(x):
         def body(_, acc):
-            return shard_map(
+            return jax.shard_map(
                 one, mesh=mesh, in_specs=P(axis, None),
                 out_specs=P(axis, None), check_vma=False,
             )(acc)
         return jax.lax.fori_loop(0, iters, body, x)
 
-    float(run(x)[0, 0])  # compile + warm
-    t0 = time.time()
-    out = run(x)
-    float(out[0, 0])
-    elapsed = (time.time() - t0) / iters
+    jax.block_until_ready(run(x))  # compile + warm
+    t0 = time.perf_counter()
+    jax.block_until_ready(run(x))
+    elapsed = (time.perf_counter() - t0) / iters
     msg_bytes = msg_elems * 4
     algo_factor = 2 * (n - 1) / n if n > 1 else 1.0
     gbps = algo_factor * msg_bytes / elapsed / 1e9

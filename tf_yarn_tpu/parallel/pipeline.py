@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from tf_yarn_tpu.parallel.collectives import shard_map
 from tf_yarn_tpu.parallel.mesh import AXIS_PP
 
 
@@ -144,7 +143,7 @@ def pipeline_apply(
     fn = functools.partial(
         _pipeline_shard, stage_fn, axis=AXIS_PP, n_micro=num_microbatches
     )
-    out = shard_map(
+    out = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(params_spec, x_spec),

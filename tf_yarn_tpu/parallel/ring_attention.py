@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from tf_yarn_tpu.parallel.collectives import shard_map
 from tf_yarn_tpu.parallel.mesh import (
     AXIS_SP,
     AXIS_TP,
@@ -158,7 +157,7 @@ def ring_attention_sharded(
     fn = functools.partial(
         ring_attention, causal=causal, softmax_scale=softmax_scale
     )
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec),

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from tf_yarn_tpu.parallel.collectives import shard_map
 from tf_yarn_tpu.parallel.mesh import (
     AXIS_SP,
     AXIS_TP,
@@ -91,11 +90,10 @@ def ulysses_attention(
     if inner == "flash":
         from tf_yarn_tpu.ops.flash_attention import flash_attention
 
-        # Already per-shard here (inside ulysses' own shard_map): call
-        # the kernels directly, not the custom_partitioning wrapper.
+        # Already per-shard here (inside ulysses' own shard_map), which
+        # the kernels' wrapper sees for itself.
         out = flash_attention(q, k, v, causal=causal,
-                              softmax_scale=softmax_scale,
-                              partition_aware=False)
+                              softmax_scale=softmax_scale)
     else:
         out = xla_attention(q, k, v, causal=causal,
                             softmax_scale=softmax_scale)
@@ -133,7 +131,7 @@ def ulysses_attention_sharded(
         ulysses_attention, causal=causal, softmax_scale=softmax_scale,
         inner=inner,
     )
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec),

@@ -468,11 +468,12 @@ def run_prefill(experiment, runtime=None) -> dict:
         )
     telemetry.enable_env_jsonl(telemetry_task)
     fs_lib.check_model_dir_placement(experiment.model_dir)
+    from tf_yarn_tpu.parallel import mesh as mesh_lib
+
+    mesh_lib.select_devices()  # the platform this task was started for
     mesh = None
     mesh_spec = getattr(experiment, "mesh_spec", None)
     if mesh_spec is not None and mesh_spec.total_devices > 1:
-        from tf_yarn_tpu.parallel import mesh as mesh_lib
-
         with telemetry.span("prefill/build_mesh",
                             devices=mesh_spec.total_devices):
             mesh = mesh_lib.build_mesh(

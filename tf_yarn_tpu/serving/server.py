@@ -47,7 +47,8 @@ from typing import Optional
 
 import numpy as np
 
-from tf_yarn_tpu import telemetry
+from tf_yarn_tpu import compile_cache, telemetry
+from tf_yarn_tpu.parallel.mesh import device_report
 from tf_yarn_tpu.serving.request import (
     DEFAULT_TIER,
     QueueFull,
@@ -234,6 +235,8 @@ def _make_handler(scheduler: SlotScheduler, slo_evaluator=None,
                 payload = {
                     "schema_version": telemetry.STATS_SCHEMA_VERSION,
                     **scheduler.stats(),
+                    "device": device_report(),
+                    "compile_cache": compile_cache.stats(),
                     "signals": telemetry.signals_block(
                         prefixes=("serving/", "slo/", "telemetry/"),
                     ),

@@ -15,7 +15,7 @@ import sys
 from contextlib import contextmanager
 from typing import Iterator, List, NamedTuple, Optional
 
-from tf_yarn_tpu import _task_commons, event
+from tf_yarn_tpu import _task_commons, compile_cache, event
 from tf_yarn_tpu.coordination.kv import KVClient
 from tf_yarn_tpu.topologies import TaskInstance, TaskKey
 
@@ -32,6 +32,7 @@ class TaskRuntime(NamedTuple):
 
 def init_runtime(need_cluster: bool = True) -> TaskRuntime:
     _task_commons.setup_logging()
+    _logger.info("compile cache at %s", compile_cache.enable())
     kv = _task_commons.connect_kv()
     task_key = _task_commons.get_task_key()
     task = task_key.to_kv_str()

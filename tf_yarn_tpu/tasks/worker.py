@@ -58,15 +58,6 @@ def _maybe_init_jax_distributed(runtime: _bootstrap.TaskRuntime) -> None:
     platform = os.environ.get("TPU_YARN_PLATFORM")
     if platform:  # narrow backend selection before any distributed setup
         jax.config.update("jax_platforms", platform)
-    if platform == "cpu":
-        # Multi-process CPU (the test rig): cross-process collectives need
-        # an explicit transport on jax builds whose default is "none"
-        # ("Multiprocess computations aren't implemented on the CPU
-        # backend"); newer builds already default to gloo.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):  # pragma: no cover - old/new jax
-            _logger.debug("cpu collectives config skipped", exc_info=True)
     try:
         jax.distributed.initialize(
             coordinator_address=addr,

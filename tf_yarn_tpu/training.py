@@ -582,6 +582,13 @@ def train_and_evaluate(
     broadcasts) matches the reference's `_execute_dispatched_function`
     surface (tf_task_common.py:38-74) so run Metrics keep working.
     """
+    # The loop registers its mesh for the mesh-aware ops; it must not
+    # outlive the run.
+    with mesh_lib.use_mesh(mesh_lib.current_mesh()):
+        return _train_and_evaluate(core, runtime, devices)
+
+
+def _train_and_evaluate(core: CoreExperiment, runtime, devices):
     # Telemetry identity for this run: the launcher task when present
     # ("worker:0"), a stable local name otherwise. TPU_YARN_TRACE=<dir>
     # writes trace_<task>.json (Chrome trace_event) on exit — see
@@ -743,8 +750,7 @@ def train_and_evaluate(
             ).compile()
 
         # steps_per_loop > 1: a second executable scanning a whole block of
-        # steps over stacked batches, so per-step dispatch (a real cost on
-        # remote/relayed backends, and non-zero everywhere) amortizes away.
+        # steps over stacked batches, so per-step dispatch amortizes away.
         steps_per_loop = max(1, params_cfg.steps_per_loop)
         # Cadences that actually surface to the host this run (mirrors the
         # trigger conditions in the loop below).

@@ -13,7 +13,6 @@ hand-maintained 6*N*T formula.
 from __future__ import annotations
 
 import logging
-import os
 from typing import Optional
 
 _logger = logging.getLogger(__name__)
@@ -32,38 +31,27 @@ _PEAK_BF16_FLOPS = (
     ("v2", 45e12),
 )
 
-ENV_PEAK_FLOPS = "TPU_YARN_PEAK_FLOPS_PER_CHIP"
-
-
 def peak_flops_per_chip(device) -> Optional[float]:
-    """Peak bf16 FLOP/s of `device`, or None for non-TPU/unknown kinds.
-    Override with TPU_YARN_PEAK_FLOPS_PER_CHIP (e.g. for new chips)."""
-    override = os.environ.get(ENV_PEAK_FLOPS)
-    if override:
-        try:
-            return float(override)
-        except ValueError:
-            _logger.warning(
-                "ignoring malformed %s=%r (want a number, e.g. 1.97e14)",
-                ENV_PEAK_FLOPS, override,
-            )
+    """Peak bf16 FLOP/s of `device`. None off the TPU (the CPU rig
+    reports no utilization); a TPU kind the table does not know is an
+    error — a utilization over a guessed peak is not a measurement."""
     kind = getattr(device, "device_kind", "").lower()
     if "tpu" not in kind:
         return None
     for pattern, flops in _PEAK_BF16_FLOPS:
         if pattern in kind:
             return flops
-    return None
+    raise ValueError(
+        f"no peak FLOP/s on record for TPU kind {device.device_kind!r}; "
+        "add it, with its source, to utils/flops._PEAK_BF16_FLOPS"
+    )
 
 
 def compiled_flops(compiled) -> Optional[float]:
     """FLOPs of one execution of an AOT-compiled jax function (per
     device, post-partitioning), from XLA's cost analysis."""
     try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returned [dict]
-            cost = cost[0] if cost else {}
-        flops = cost.get("flops")
+        flops = compiled.cost_analysis().get("flops")
         return float(flops) if flops else None
     except Exception as exc:  # cost analysis is best-effort on all backends
         _logger.debug("cost_analysis unavailable: %s", exc)

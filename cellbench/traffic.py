@@ -1,0 +1,112 @@
+"""One general generator of traffic: the list of requests is a pure
+function of (traffic file, vocabulary, seed, seconds).
+
+What a run measures is fixed by the file and `--seconds` alone: how many
+requests are due, which of them are members of the time-to-first-token
+set, and the histogram of their lengths. Lengths are not drawn: they are
+the stratified quantiles ((i + 1/2) / n) of the stated distributions. The
+seed decides the token ids, which length meets which arrival, and the gaps
+between arrivals (n sorted uniform draws on the span: a Poisson process
+given its count).
+
+Groups, each with its own strata, so that each group's lengths are the same
+under every seed:
+  lead   requests due in [-lead_in_s, 0): they occupy the server when the
+         window opens, and are paid in set-up;
+  member the first `member_share` of the requests due in [0, seconds);
+  tail   the rest of the window: load, and gaps between tokens, but due too
+         near the close for a first token to be counted on.
+A closed loop has one group, `list`: `list_length` requests in list order,
+which callers take one after another; none is due at a time. The list is made
+of blocks of `block` requests, each block a whole set of strata in an order
+of its own, so that however far a run gets through the list, it has met
+nearly the same lengths. The order of lengths in the list comes from the
+file's `order_seed` and not from `--seed`: a batch job is the same job under
+every seed, which changes the token ids (and the weights) alone. (Drawn from
+`--seed`, which long prompts happen to hold a slot during the window moves
+tokens/s by several percent from seed to seed.)
+"""
+
+from __future__ import annotations
+
+import json
+import math
+from statistics import NormalDist
+
+import numpy as np
+
+
+def load(path: str) -> dict:
+    with open(path) as fh:
+        return json.load(fh)
+
+
+def strata(dist: dict, n: int) -> list:
+    """The n stratified quantiles of a clipped distribution, ascending."""
+    if dist["dist"] != "lognormal":
+        raise ValueError(f"unknown distribution {dist['dist']!r}")
+    normal = NormalDist()
+    out = []
+    for i in range(n):
+        z = normal.inv_cdf((i + 0.5) / n)
+        value = dist["median"] * math.exp(dist["sigma"] * z)
+        out.append(int(round(min(max(value, dist["min"]), dist["max"]))))
+    return out
+
+
+def counts(traffic: dict, seconds: float) -> dict:
+    """Sizes of the groups: a function of the file and the seconds alone."""
+    if "rate_per_s" not in traffic:
+        return {"list": int(traffic["list_length"])}
+    n = int(round(traffic["rate_per_s"] * seconds))
+    num, den = traffic.get("member_share", [2, 3])
+    members = n * num // den
+    lead = int(round(traffic["rate_per_s"] * traffic.get("lead_in_s", 0)))
+    return {"lead": lead, "member": members, "tail": n - members}
+
+
+def _arrivals(rng, n: int, start: float, end: float) -> list:
+    return sorted(float(x) for x in start + (end - start) * rng.random(n))
+
+
+def requests(traffic: dict, vocab: int, seed: int, seconds: float) -> list:
+    """[{index, group, due_s, prompt, max_new_tokens}], in due (or list)
+    order. `due_s` counts from the opening of the window; None in a closed
+    loop."""
+    rng = np.random.default_rng([int(seed), 0x63656C6C])
+    sizes = counts(traffic, seconds)
+    out = []
+    if "list" in sizes:
+        due = {"list": [None] * sizes["list"]}
+    else:
+        window = _arrivals(rng, sizes["member"] + sizes["tail"], 0.0, seconds)
+        due = {
+            "lead": _arrivals(rng, sizes["lead"],
+                              -float(traffic.get("lead_in_s", 0)), 0.0),
+            "member": window[:sizes["member"]],
+            "tail": window[sizes["member"]:],
+        }
+    for group, times in due.items():
+        n = len(times)
+        block, order = n, rng
+        if group == "list":
+            block = int(traffic.get("block", n))
+            order = np.random.default_rng(
+                [int(traffic["order_seed"]), 0x6F726472])
+        prompts, outputs = [], []
+        for start in range(0, n, max(block, 1)):
+            size = min(block, n - start)
+            prompts.extend(order.permutation(strata(traffic["prompt_tokens"], size)))
+            outputs.extend(order.permutation(strata(traffic["output_tokens"], size)))
+        for when, n_prompt, n_out in zip(times, prompts, outputs):
+            out.append({
+                "index": len(out), "group": group, "due_s": when,
+                "prompt": rng.integers(0, vocab, int(n_prompt)).tolist(),
+                "max_new_tokens": int(n_out),
+            })
+    return out
+
+
+def histogram(items: list, group: str) -> list:
+    """Sorted prompt lengths of one group: what must not move with the seed."""
+    return sorted(len(r["prompt"]) for r in items if r["group"] == group)

@@ -1,0 +1,529 @@
+"""What the serving path says about its own time (docs/Observability.md,
+docs/Serving.md "Where a tick's time goes").
+
+* The scheduler's thread is always under a named span, and `serving/step`
+  is tiled by launch / sync / emit.
+* Every request leaves one `serving/request` record under the caller's id
+  (and one `serving/admission` if it took a slot), whatever way it was
+  admitted and however it ended, and its parts add up to its time to a
+  first token.
+* `/stats` counts work where it happens; `POST /debug/profile` is the one
+  profiler hook.
+"""
+
+import glob
+import http.client
+import json
+import os
+import threading
+import time
+
+import pytest
+
+from tests.test_chunked_prefill import FakePagedWindowedEngine
+from tests.test_serving import (
+    FakeEngine,
+    FakePagedEngine,
+    _drive,
+    _post,
+    _tiny_serving_stack,
+)
+from tf_yarn_tpu import telemetry
+from tf_yarn_tpu.serving import (
+    QueueFull,
+    SamplingParams,
+    ServingServer,
+    SlotScheduler,
+)
+from tf_yarn_tpu.telemetry import spans as spans_lib
+
+
+@pytest.fixture(autouse=True)
+def _clean_ring():
+    telemetry.get_tracer().clear()
+    yield
+
+
+def _records(name):
+    return [s for s in telemetry.get_tracer().records() if s.name == name]
+
+
+def _by_request(name):
+    out = {}
+    for span in _records(name):
+        out.setdefault(span.args["request_id"], []).append(span)
+    return out
+
+
+# --------------------------------------------------------------------------
+# (a) spans have identity
+# --------------------------------------------------------------------------
+
+def test_span_ids_nest_across_threads_and_records_sit_on_no_stack():
+    tracer = spans_lib.Tracer()
+    seen = {}
+
+    both_alive = threading.Barrier(2)  # or one ident may serve both
+
+    def work(tag):
+        both_alive.wait(timeout=30)
+        with tracer.span(f"{tag}/outer") as outer:
+            with tracer.span("inner") as first:
+                pass
+            with tracer.span("inner") as second:
+                with tracer.span("leaf") as leaf:
+                    pass
+        seen[tag] = (outer, first, second, leaf)
+        both_alive.wait(timeout=30)
+
+    threads = [threading.Thread(target=work, args=(t,)) for t in "ab"]
+    for thread in threads:
+        thread.start()
+    for thread in threads:
+        thread.join()
+    record = tracer.record("life", spans_lib.now() - 1.0, 1.0, request_id="x")
+    ids = [s.id for s in tracer.records()]
+    assert len(set(ids)) == len(ids) == 9
+    for outer, first, second, leaf in seen.values():
+        assert outer.parent_id is None and outer.parent is None
+        # Two spans of one name under one parent are told apart by id.
+        assert first.parent_id == second.parent_id == outer.id
+        assert first.id != second.id and first.parent == outer.name
+        assert leaf.parent_id == second.id and leaf.parent == "inner"
+        assert leaf.thread_id == outer.thread_id
+    assert seen["a"][0].thread_id != seen["b"][0].thread_id
+    assert record.depth == spans_lib.RECORD_DEPTH and record.parent_id is None
+    assert record.thread_name == spans_lib.RECORD_THREAD
+    assert record.duration == 1.0 and record.args == {"request_id": "x"}
+    payload = record.to_json()
+    assert payload["id"] == record.id and payload["parent_id"] is None
+    event = next(e for e in tracer.chrome_events() if e.get("name") == "leaf")
+    assert {"id", "parent_id", "depth"} <= set(event["args"])
+
+
+# --------------------------------------------------------------------------
+# (b) the scheduler's thread is always under a span
+# --------------------------------------------------------------------------
+
+TOP_LEVEL = {"serving/control_ops", "serving/tick", "serving/publish",
+             "serving/idle_wait"}
+
+
+def test_scheduler_thread_spans_tile_its_time_and_step_children_tile_step():
+    engine = FakeEngine()
+    real_step = engine.step
+
+    def slow_step(*args, **kwargs):  # a step worth measuring: 2 ms
+        time.sleep(0.002)
+        return real_step(*args, **kwargs)
+
+    engine.step = slow_step
+    scheduler = SlotScheduler(engine, params=None, max_slots=2)
+    scheduler.start()
+    try:
+        responses = [
+            scheduler.submit([i + 1] * (3 + i),
+                             SamplingParams(max_new_tokens=40))
+            for i in range(4)
+        ]
+        for response in responses:
+            response.result(timeout=60)
+        time.sleep(0.12)  # two idle waits
+    finally:
+        scheduler.close()
+    records = telemetry.get_tracer().records()
+    tid = next(s.thread_id for s in records if s.name == "serving/tick")
+    mine = sorted((s for s in records if s.thread_id == tid),
+                  key=lambda s: s.start)
+    top = [s for s in mine if s.depth == 0]
+    assert {s.name for s in top} == TOP_LEVEL
+    gaps = sum(max(0.0, b.start - (a.start + a.duration))
+               for a, b in zip(top, top[1:]))
+    run = top[-1].start + top[-1].duration - top[0].start
+    assert gaps < 0.01 * run, (gaps, run)
+
+    steps = [s for s in mine if s.name == "serving/step"]
+    assert len(steps) >= 80
+    by_parent = {}
+    for span in mine:
+        by_parent.setdefault(span.parent_id, []).append(span)
+    for step in steps:
+        children = by_parent[step.id]
+        assert [c.name for c in children] == [
+            "serving/step_launch", "serving/step_sync", "serving/step_emit"]
+        assert step.args["tick"] == next(
+            s for s in top if s.id == step.parent_id).args["tick"]
+    inside = sum(c.duration for step in steps for c in by_parent[step.id])
+    total = sum(step.duration for step in steps)
+    # The acceptance's own number: the three parts add up to the step
+    # within 0.2 ms (what is left is the spans' own enter and exit).
+    assert 0 <= (total - inside) / len(steps) < 0.2e-3, (inside, total)
+    # Every token and every retirement is accounted to an emit span.
+    emits = [s for s in mine if s.name == "serving/step_emit"]
+    assert sum(s.args["tokens"] for s in emits) == 4 * 40
+    assert sum(s.args["retired"] for s in emits) == 4
+    # The tick histogram still observes the tick span, nothing wider.
+    hist = telemetry.get_registry().histogram("serving/tick_seconds")
+    assert hist.count >= len(steps)
+
+
+def test_engine_step_args_span_sits_under_launch():
+    _model, _params, _engine, scheduler = _tiny_serving_stack(
+        max_slots=2, kv_layout="paged", block_size=8)
+    response = scheduler.submit([5, 6, 7], SamplingParams(max_new_tokens=3))
+    _drive(scheduler, [response])
+    records = telemetry.get_tracer().records()
+    launches = {s.id for s in records if s.name == "serving/step_launch"}
+    args = [s for s in records if s.name == "decode_engine/step_args"]
+    calls = [s for s in records if s.name == "decode_engine/paged_step"]
+    assert len(args) == len(calls) == len(launches) >= 3
+    assert all(s.parent_id in launches for s in args + calls)
+    # The compile of the first step is inside step_args, not the call.
+    compiles = [s for s in records if s.name == "decode_engine/compile"
+                and s.args["kind"] == "paged_step"]
+    assert compiles and compiles[0].parent == "decode_engine/step_args"
+
+
+# --------------------------------------------------------------------------
+# (c) one id and one record per request
+# --------------------------------------------------------------------------
+
+def _check_parts(record):
+    args = record.args
+    parts = args["queue_wait_ms"] + args["prefill_ms"] + args["replay_ms"]
+    assert parts == pytest.approx(args["ttft_ms"], abs=1e-6)
+    assert 0 <= args["ttft_ms"] <= record.duration * 1e3 + 1e-6
+
+
+def test_every_finish_reason_leaves_one_record_under_the_callers_id():
+    engine = FakeEngine()
+    scheduler = SlotScheduler(engine, params=None, max_slots=1,
+                              queue_capacity=2)
+    # `length`, and `eos` at the first emission (sum of prompt % 97).
+    length = scheduler.submit([1, 2, 3, 4, 5, 6],
+                              SamplingParams(max_new_tokens=3),
+                              trace_id="r-length")
+    eos = scheduler.submit([7, 8], SamplingParams(max_new_tokens=9,
+                                                  eos_token=15),
+                           trace_id="r-eos")
+    # Refused: the queue holds two, the slot none yet.
+    with pytest.raises(QueueFull):
+        scheduler.submit([9], SamplingParams(max_new_tokens=2),
+                         trace_id="r-refused")
+    with pytest.raises(ValueError):
+        scheduler.submit([9], SamplingParams(max_new_tokens=2,
+                                             temperature=0.7),
+                         trace_id="r-unservable")
+    _drive(scheduler, [length, eos])
+    assert length.finish_reason == "length" and eos.finish_reason == "eos"
+    # Deadline in the queue (never admitted), and in a slot.
+    blocker = scheduler.submit([1] * 5, SamplingParams(max_new_tokens=500),
+                               trace_id="r-slot-deadline", timeout_s=0.05)
+    queued = scheduler.submit([2, 3], SamplingParams(max_new_tokens=2),
+                              trace_id="r-queue-deadline", timeout_s=0.02)
+    scheduler.tick()
+    time.sleep(0.06)
+    _drive(scheduler, [blocker, queued])
+    # No id from the caller: the program's own.
+    anonymous = scheduler.submit([4, 4], SamplingParams(max_new_tokens=1))
+    _drive(scheduler, [anonymous])
+
+    requests = _by_request("serving/request")
+    admissions = _by_request("serving/admission")
+    first_tokens = _by_request("serving/first_token")
+    assert all(len(v) == 1 for v in requests.values())
+    assert all(len(v) == 1 for v in admissions.values())
+    finishes = {k: v[0].args["finish"] for k, v in requests.items()}
+    own = str(anonymous.request.id)
+    assert finishes == {
+        "r-length": "length", "r-eos": "eos", "r-refused": "refused",
+        "r-unservable": "refused", "r-slot-deadline": "deadline",
+        "r-queue-deadline": "deadline", own: "length",
+    }
+    assert set(admissions) == {"r-length", "r-eos", "r-slot-deadline", own}
+    assert set(first_tokens) == set(admissions)
+    for rid in ("r-length", "r-eos", own):
+        _check_parts(requests[rid][0])
+        assert first_tokens[rid][0].duration == 0.0
+        assert first_tokens[rid][0].args["ttft_ms"] == pytest.approx(
+            requests[rid][0].args["ttft_ms"])
+    done = requests["r-length"][0].args
+    # Prompt of 6: bucket 4 through the prefill program, 2 replayed.
+    assert (done["prompt_tokens"], done["prefilled"], done["hit_tokens"],
+            done["replayed"], done["emitted"], done["slot"]) == \
+        (6, 4, 0, 2, 3, 0)
+    admitted = admissions["r-length"][0]
+    assert admitted.duration == 0.0 and admitted.args["replay"] == 2
+    assert admitted.args["queue_wait_ms"] == pytest.approx(
+        done["queue_wait_ms"])
+    never = requests["r-queue-deadline"][0].args
+    assert never["ttft_ms"] is None and never["slot"] is None
+    assert never["queue_wait_ms"] == pytest.approx(
+        requests["r-queue-deadline"][0].duration * 1e3)
+    # The record spans submit -> finish on the tracer's clock.
+    record = requests["r-slot-deadline"][0]
+    assert record.args["emitted"] > 0 and record.duration >= 0.05
+    prefill = next(s for s in _records("serving/prefill")
+                   if s.args["request_id"] == "r-length")
+    assert prefill.args["request"] == length.request.id
+
+
+def test_prefix_hit_and_chunked_prefill_keep_every_request_under_its_id():
+    # Prefix hit: the second request shares the first's 8-token block
+    # prefix and opens no serving/prefill span — a join by order fails.
+    engine = FakePagedEngine()
+    scheduler = SlotScheduler(engine, params=None, max_slots=2,
+                              kv_layout="paged", block_size=4,
+                              max_seq_len=32)
+    shared = [3, 1, 4, 1, 5, 9, 2, 6]
+    first = scheduler.submit(shared + [5, 3], SamplingParams(max_new_tokens=2),
+                             trace_id="hit-a")
+    _drive(scheduler, [first])
+    second = scheduler.submit(shared + [7, 7, 7],
+                              SamplingParams(max_new_tokens=2),
+                              trace_id="hit-b")
+    _drive(scheduler, [second])
+    assert len(_records("serving/prefill")) == 1
+    admissions = _by_request("serving/admission")
+    assert admissions["hit-a"][0].args["prefilled"] == 8
+    hit = admissions["hit-b"][0].args
+    assert (hit["hit_tokens"], hit["prefilled"], hit["replay"]) == (8, 0, 3)
+    assert scheduler.stats()["prefilled_tokens"] == 8
+    # prefill_tokens keeps its meaning: prompt tokens replayed by the step.
+    assert scheduler.stats()["prefill_tokens"] == 2 + 3
+
+    # Chunked prefill: no blocking prefill at all, on any request.
+    telemetry.get_tracer().clear()
+    chunked = SlotScheduler(FakePagedWindowedEngine(), params=None,
+                            max_slots=2, kv_layout="paged", block_size=4,
+                            max_seq_len=32, prefill_chunk=4)
+    responses = [
+        chunked.submit([i + 1] * (6 + i), SamplingParams(max_new_tokens=3),
+                       trace_id=f"chunk-{i}")
+        for i in range(3)
+    ]
+    _drive(chunked, responses, max_ticks=500)
+    assert not _records("serving/prefill")
+    admissions = _by_request("serving/admission")
+    requests = _by_request("serving/request")
+    assert set(admissions) == set(requests) == {
+        "chunk-0", "chunk-1", "chunk-2"}
+    for i in range(3):
+        args = requests[f"chunk-{i}"][0].args
+        assert len(requests[f"chunk-{i}"]) == 1
+        assert (args["prefilled"], args["replayed"], args["emitted"]) == \
+            (0, 6 + i, 3)
+        _check_parts(requests[f"chunk-{i}"][0])
+    assert chunked.stats()["prefilled_tokens"] == 0
+
+
+# --------------------------------------------------------------------------
+# (d) counters where the work happens
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_kv_token_steps_equals_a_hand_count(layout):
+    if layout == "dense":
+        scheduler = SlotScheduler(FakeEngine(), params=None, max_slots=2)
+    else:
+        scheduler = SlotScheduler(
+            FakePagedEngine(), params=None, max_slots=2, kv_layout="paged",
+            block_size=4, max_seq_len=32, prefix_cache_capacity=0)
+    # Prompt 6 -> 4 prefilled, 2 replayed, 3 emitted: steps read caches of
+    # 4, 5 (replay; the second emits), 6, 7 (decode) tokens = 4 steps.
+    # Prompt 3 -> 0 prefilled: steps read 0, 1, 2 (replay), 3 (decode).
+    a = scheduler.submit([1, 2, 3, 4, 5, 6], SamplingParams(max_new_tokens=3))
+    b = scheduler.submit([7, 8, 9], SamplingParams(max_new_tokens=2))
+    _drive(scheduler, [a, b])
+    stats = scheduler.stats()
+    assert stats["slot_steps"] == 4 + 4
+    assert stats["kv_token_steps"] == (4 + 5 + 6 + 7) + (0 + 1 + 2 + 3)
+    assert stats["prefilled_tokens"] == 4
+    assert stats["prefill_tokens"] == 2 + 3   # replayed through the step
+    assert stats["decode_tokens"] == 3 + 2
+
+
+def test_slow_steps_are_counted_with_their_launch_and_sync():
+    engine = FakeEngine()
+    real_step = engine.step
+    calls = {"n": 0}
+
+    def step(*args, **kwargs):
+        calls["n"] += 1
+        time.sleep(0.1 if calls["n"] == 20 else 0.005)
+        return real_step(*args, **kwargs)
+
+    engine.step = step
+    scheduler = SlotScheduler(engine, params=None, max_slots=1)
+    response = scheduler.submit([1, 2], SamplingParams(max_new_tokens=30))
+    _drive(scheduler, [response])
+    stats = scheduler.stats()
+    assert stats["slow_steps"] >= 1  # a loaded machine may add its own
+    slowest = stats["slowest_step"]
+    assert slowest["tick"] == 20 and slowest["ms"] >= 100
+    # The fake engine's call is all of it: the host was stuck before the
+    # dispatch returned, not under the sync or after it.
+    assert slowest["launch_ms"] >= 100 > slowest["sync_ms"] + slowest["emit_ms"]
+    assert stats["slow_step_seconds"] >= slowest["ms"] / 1e3 - 1e-5
+    step_span = next(s for s in _records("serving/step")
+                     if s.args["tick"] == 20)
+    assert step_span.duration * 1e3 == pytest.approx(slowest["ms"])
+
+
+# --------------------------------------------------------------------------
+# the profiler hook
+# --------------------------------------------------------------------------
+
+def _post_json(port, path, body, timeout=120):
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        conn.request("POST", path, json.dumps(body),
+                     {"Content-Type": "application/json"})
+        response = conn.getresponse()
+        return response.status, json.loads(response.read())
+    finally:
+        conn.close()
+
+
+def test_debug_profile_writes_an_xplane_and_refuses_a_second(tmp_path,
+                                                              monkeypatch):
+    monkeypatch.delenv("TPU_YARN_PROFILE", raising=False)
+    _model, _params, _engine, scheduler = _tiny_serving_stack(max_slots=2)
+    scheduler.start()
+    server = ServingServer(scheduler, "127.0.0.1", 0,
+                           profile_dir=str(tmp_path / "profile"))
+    server.start()
+    results = {}
+    try:
+        def capture():
+            results["first"] = _post_json(
+                server.port, "/debug/profile", {"seconds": 1.5})
+
+        thread = threading.Thread(target=capture)
+        thread.start()
+        deadline = time.monotonic() + 30
+        while not telemetry.profile.active():
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        status, payload = _post_json(
+            server.port, "/debug/profile", {"seconds": 0.1})
+        assert status == 409 and "running" in payload["error"]
+        # Model steps while the capture runs are annotated by tick.
+        status, _headers, _raw = _post(
+            server.port, {"prompt": [1, 2, 3], "max_new_tokens": 4})
+        assert status == 200
+        thread.join(timeout=120)
+    finally:
+        server.stop()
+        scheduler.close()
+    status, payload = results["first"]
+    assert status == 200, payload
+    assert payload["dir"] == str(tmp_path / "profile")
+    assert payload["seconds"] >= 1.5
+    assert glob.glob(os.path.join(
+        payload["dir"], "plugins", "profile", "*", "*.xplane.pb"))
+    assert not telemetry.profile.active()
+    span = _records("telemetry/profile")[0]
+    assert span.args["sync_perf_s"] == payload["sync_perf_s"]
+    assert span.start <= payload["sync_perf_s"] <= span.start + span.duration
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(
+        payload["dir"], "plugins", "profile", "*", "*.xplane.pb"))[-1]
+    names = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name in (telemetry.profile.SYNC_ANNOTATION,
+                                  "serving/step"):
+                    names.setdefault(event.name, []).append(
+                        dict(event.stats))
+    assert len(names[telemetry.profile.SYNC_ANNOTATION]) == 1
+    ticks = [int(stats["tick"]) for stats in names["serving/step"]]
+    assert len(ticks) >= 4 and ticks == sorted(ticks)
+
+
+def test_debug_profile_without_a_directory_answers_409(monkeypatch):
+    monkeypatch.delenv("TPU_YARN_PROFILE", raising=False)
+    scheduler = SlotScheduler(FakeEngine(), params=None, max_slots=1)
+    server = ServingServer(scheduler, "127.0.0.1", 0)
+    server.start()
+    try:
+        status, payload = _post_json(server.port, "/debug/profile", {})
+    finally:
+        server.stop()
+        scheduler.close()
+    assert status == 409 and "TPU_YARN_PROFILE" in payload["error"]
+
+
+def test_submit_span_carries_the_programs_id_where_none_came():
+    scheduler = SlotScheduler(FakeEngine(), params=None, max_slots=1)
+    scheduler.start()
+    server = ServingServer(scheduler, "127.0.0.1", 0)
+    server.start()
+    try:
+        status, _headers, raw = _post(
+            server.port, {"prompt": [1, 2], "max_new_tokens": 2})
+    finally:
+        server.stop()
+        scheduler.close()
+    assert status == 200
+    own = str(json.loads(raw)["request_id"])
+    submit = _records("serving/submit")[0]
+    assert submit.args["request_id"] == own
+    assert {own} == set(_by_request("serving/request")) == \
+        set(_by_request("serving/admission"))
+
+
+# --------------------------------------------------------------------------
+# (e) scopes on the model's device operations
+# --------------------------------------------------------------------------
+
+SCOPES = ("embed", "norm", "attention/qkv", "attention/rope",
+          "attention/kv_write", "attention/kv_gather", "attention/repeat_kv",
+          "attention/scores", "attention/values", "attention/out", "mlp",
+          "lm_head", "sample")
+
+
+def test_paged_step_operations_carry_every_scope():
+    import jax
+    import jax.numpy as jnp
+
+    from tf_yarn_tpu.models import decode_engine, transformer
+
+    cfg = transformer.TransformerConfig.tiny(
+        scan_layers=False, remat=False, max_seq_len=32, dtype=jnp.float32,
+        n_heads=4, n_kv_heads=2)
+    model = transformer.Transformer(cfg)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    import flax.linen as nn
+
+    params = nn.meta.unbox(params)
+    row = decode_engine._decode_cache_aval(model, params)
+    pool = decode_engine.paged_pool_avals(row, 9, 4, cfg.max_seq_len)
+    step = decode_engine.build_paged_step_fn(model, 4, 0.0, None, None)
+    slots = 2
+    lowered = jax.jit(step).lower(
+        params, pool,
+        jax.ShapeDtypeStruct((slots, cfg.max_seq_len // 4), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots, 2), jnp.uint32),
+        jax.ShapeDtypeStruct((slots,), bool),
+    )
+    import re
+
+    from cellbench import scopes
+
+    text = lowered.as_text(debug_info=True)
+    paths = {scopes.path_of(name)
+             for name in re.findall(r'loc\("(jit\(step\)[^"]*)"', text)}
+    for scope in SCOPES:
+        assert any(scopes.under(path, tuple(scope.split("/")))
+                   for path in paths), scope
+    # Flax's own module path stands around them; transforms wrap the rest
+    # (`vmap(attention/kv_gather)`), which the reader's paths see through.
+    assert any(scopes.under(path, ("layer_0", "block", "attn", "attention",
+                                   "scores")) for path in paths)

@@ -637,6 +637,12 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._prefill_tokens", _ADVISORY),
                 ("scheduler._decode_tokens", _ADVISORY),
                 ("scheduler._peak_streams", _ADVISORY),
+                ("scheduler._prefilled_tokens", _ADVISORY),
+                ("scheduler._kv_token_steps", _ADVISORY),
+                ("scheduler._slot_steps", _ADVISORY),
+                ("scheduler._slow_steps", _ADVISORY),
+                ("scheduler._slow_step_seconds", _ADVISORY),
+                ("scheduler._slowest_step", _ADVISORY),
                 ("prefix.hits", _ADVISORY),
                 ("prefix.misses", _ADVISORY),
             ),
@@ -648,6 +654,12 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._prefill_tokens", _ADVISORY),
                 ("scheduler._decode_tokens", _ADVISORY),
                 ("scheduler._peak_streams", _ADVISORY),
+                ("scheduler._prefilled_tokens", _ADVISORY),
+                ("scheduler._kv_token_steps", _ADVISORY),
+                ("scheduler._slot_steps", _ADVISORY),
+                ("scheduler._slow_steps", _ADVISORY),
+                ("scheduler._slow_step_seconds", _ADVISORY),
+                ("scheduler._slowest_step", _ADVISORY),
                 ("scheduler._suspends", _ADVISORY),
                 ("scheduler._resumes", _ADVISORY),
                 ("scheduler._swap_out_blocks", _ADVISORY),

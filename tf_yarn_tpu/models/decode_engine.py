@@ -435,7 +435,10 @@ def _gather_slot_cache(pool, row_aval, table, length, max_seq_len):
         ax = _seq_axis(aval.shape, max_seq_len)
         return jnp.take(pool_leaf, table, axis=ax).reshape(aval.shape)
 
-    return jax.tree_util.tree_map(leaf, pool, row_aval, is_leaf=_is_none)
+    with jax.named_scope("attention/kv_gather"):
+        return jax.tree_util.tree_map(
+            leaf, pool, row_aval, is_leaf=_is_none
+        )
 
 
 def build_paged_step_fn(model, block_size: int, temperature: float,
@@ -484,7 +487,10 @@ def build_paged_step_fn(model, block_size: int, temperature: float,
                     return None
                 return jax.lax.dynamic_slice_in_dim(leaf, length, 1, axis=ax)
 
-            rows = jax.tree_util.tree_map(new_row, state["cache"], row_aval)
+            with jax.named_scope("attention/kv_write"):
+                rows = jax.tree_util.tree_map(
+                    new_row, state["cache"], row_aval
+                )
             return emitted, rng, rows
 
         emitted, rngs, rows = jax.vmap(
@@ -509,9 +515,10 @@ def build_paged_step_fn(model, block_size: int, temperature: float,
                 )
             return pool_leaf
 
-        pool_out = jax.tree_util.tree_map(
-            write, pool, rows, row_aval, is_leaf=_is_none
-        )
+        with jax.named_scope("attention/kv_write"):
+            pool_out = jax.tree_util.tree_map(
+                write, pool, rows, row_aval, is_leaf=_is_none
+            )
         return pool_out, emitted, rngs
 
     return step
@@ -649,7 +656,10 @@ def build_paged_spec_step_fn(model, block_size: int, width: int,
                     leaf, length, width, axis=ax
                 )
 
-            rows = jax.tree_util.tree_map(new_rows, state["cache"], row_aval)
+            with jax.named_scope("attention/kv_write"):
+                rows = jax.tree_util.tree_map(
+                    new_rows, state["cache"], row_aval
+                )
             return emitted, count, rng, rows
 
         emitted, counts, rngs, rows = jax.vmap(one_slot)(
@@ -689,9 +699,10 @@ def build_paged_spec_step_fn(model, block_size: int, width: int,
                     )
             return pool_leaf
 
-        pool_out = jax.tree_util.tree_map(
-            write, pool, rows, row_aval, is_leaf=_is_none
-        )
+        with jax.named_scope("attention/kv_write"):
+            pool_out = jax.tree_util.tree_map(
+                write, pool, rows, row_aval, is_leaf=_is_none
+            )
         return pool_out, emitted, counts, rngs
 
     return spec_step
@@ -1309,28 +1320,31 @@ class DecodeEngine:
         program (build_step_fn). Compiled once per (grid size, sampling
         config, params fingerprint); the KV grid and the per-slot rng
         buffer are donated. Returns (slot_cache, emitted [S], rngs)."""
-        params = self._place_params(params)
-        tokens = jnp.asarray(tokens, jnp.int32)
-        rngs = jnp.asarray(rngs, jnp.uint32)
-        sample_mask = jnp.asarray(sample_mask, bool)
-        fp = self._params_fingerprint(params)
-        slots = int(tokens.shape[0])
-        step_key = (slots, float(temperature), top_k, top_p, fp)
-        step_fn = build_step_fn(self.model, temperature, top_k, top_p)
-        step_args = (params, slot_cache, tokens, rngs, sample_mask)
-        out_shardings = None
-        if self.mesh is not None:
-            out_shardings = (
-                self._shardings_of(slot_cache), self._rep_sharding,
-                self._rep_sharding,
+        # Everything the host does before the device has anything to do
+        # (docs/Serving.md "Where a tick's time goes").
+        with telemetry.span("decode_engine/step_args"):
+            params = self._place_params(params)
+            tokens = jnp.asarray(tokens, jnp.int32)
+            rngs = jnp.asarray(rngs, jnp.uint32)
+            sample_mask = jnp.asarray(sample_mask, bool)
+            fp = self._params_fingerprint(params)
+            slots = int(tokens.shape[0])
+            step_key = (slots, float(temperature), top_k, top_p, fp)
+            step_fn = build_step_fn(self.model, temperature, top_k, top_p)
+            step_args = (params, slot_cache, tokens, rngs, sample_mask)
+            out_shardings = None
+            if self.mesh is not None:
+                out_shardings = (
+                    self._shardings_of(slot_cache), self._rep_sharding,
+                    self._rep_sharding,
+                )
+            compiled = self._compiled(
+                self._step, step_key, "step",
+                lambda: self._jit(
+                    step_fn, step_args, donate=(1, 3),
+                    out_shardings=out_shardings,
+                ).lower(*step_args).compile(),
             )
-        compiled = self._compiled(
-            self._step, step_key, "step",
-            lambda: self._jit(
-                step_fn, step_args, donate=(1, 3),
-                out_shardings=out_shardings,
-            ).lower(*step_args).compile(),
-        )
         with telemetry.span("decode_engine/step", slots=slots):
             return compiled(*step_args)
 
@@ -1354,29 +1368,30 @@ class DecodeEngine:
         drafts changing every tick never recompiles. The KV grid and the
         rng buffer are donated. Returns (slot_cache, emitted [S, W],
         counts [S], rngs)."""
-        params = self._place_params(params)
-        tokens = jnp.asarray(tokens, jnp.int32)
-        n_known = jnp.asarray(n_known, jnp.int32)
-        eos_ids = jnp.asarray(eos_ids, jnp.int32)
-        rngs = jnp.asarray(rngs, jnp.uint32)
-        active = jnp.asarray(active, bool)
-        slots, width = (int(tokens.shape[0]), int(tokens.shape[1]))
-        fp = self._params_fingerprint(params)
-        key = ("spec", slots, width, float(temperature), top_k, top_p, fp)
-        fn = build_spec_step_fn(self.model, width, temperature, top_k, top_p)
-        args = (params, slot_cache, tokens, n_known, eos_ids, rngs, active)
-        out_shardings = None
-        if self.mesh is not None:
-            out_shardings = (
-                self._shardings_of(slot_cache), self._rep_sharding,
-                self._rep_sharding, self._rep_sharding,
+        with telemetry.span("decode_engine/step_args"):
+            params = self._place_params(params)
+            tokens = jnp.asarray(tokens, jnp.int32)
+            n_known = jnp.asarray(n_known, jnp.int32)
+            eos_ids = jnp.asarray(eos_ids, jnp.int32)
+            rngs = jnp.asarray(rngs, jnp.uint32)
+            active = jnp.asarray(active, bool)
+            slots, width = (int(tokens.shape[0]), int(tokens.shape[1]))
+            fp = self._params_fingerprint(params)
+            key = ("spec", slots, width, float(temperature), top_k, top_p, fp)
+            fn = build_spec_step_fn(self.model, width, temperature, top_k, top_p)
+            args = (params, slot_cache, tokens, n_known, eos_ids, rngs, active)
+            out_shardings = None
+            if self.mesh is not None:
+                out_shardings = (
+                    self._shardings_of(slot_cache), self._rep_sharding,
+                    self._rep_sharding, self._rep_sharding,
+                )
+            compiled = self._compiled(
+                self._spec_step, key, "spec_step",
+                lambda: self._jit(
+                    fn, args, donate=(1, 5), out_shardings=out_shardings,
+                ).lower(*args).compile(),
             )
-        compiled = self._compiled(
-            self._spec_step, key, "spec_step",
-            lambda: self._jit(
-                fn, args, donate=(1, 5), out_shardings=out_shardings,
-            ).lower(*args).compile(),
-        )
         with telemetry.span("decode_engine/spec_step", slots=slots,
                             width=width):
             return compiled(*args)
@@ -1555,32 +1570,33 @@ class DecodeEngine:
         fingerprint); tables/lengths/tokens are traced, so per-tick
         table changes never recompile. The pool and the rng buffer are
         donated. Returns (pool, emitted [S], rngs)."""
-        params = self._place_params(params)
-        tables = jnp.asarray(tables, jnp.int32)
-        lengths = jnp.asarray(lengths, jnp.int32)
-        tokens = jnp.asarray(tokens, jnp.int32)
-        rngs = jnp.asarray(rngs, jnp.uint32)
-        sample_mask = jnp.asarray(sample_mask, bool)
-        slots = int(tokens.shape[0])
-        key = (slots, tuple(tables.shape), block_size, float(temperature),
-               top_k, top_p, self._params_fingerprint(params),
-               self._tree_fingerprint(pool))
-        step_fn = build_paged_step_fn(
-            self.model, block_size, temperature, top_k, top_p
-        )
-        args = (params, pool, tables, lengths, tokens, rngs, sample_mask)
-        out_shardings = None
-        if self.mesh is not None:
-            out_shardings = (
-                self._shardings_of(pool), self._rep_sharding,
-                self._rep_sharding,
+        with telemetry.span("decode_engine/step_args"):
+            params = self._place_params(params)
+            tables = jnp.asarray(tables, jnp.int32)
+            lengths = jnp.asarray(lengths, jnp.int32)
+            tokens = jnp.asarray(tokens, jnp.int32)
+            rngs = jnp.asarray(rngs, jnp.uint32)
+            sample_mask = jnp.asarray(sample_mask, bool)
+            slots = int(tokens.shape[0])
+            key = (slots, tuple(tables.shape), block_size, float(temperature),
+                   top_k, top_p, self._params_fingerprint(params),
+                   self._tree_fingerprint(pool))
+            step_fn = build_paged_step_fn(
+                self.model, block_size, temperature, top_k, top_p
             )
-        compiled = self._compiled(
-            self._paged_step, key, "paged_step",
-            lambda: self._jit(
-                step_fn, args, donate=(1, 5), out_shardings=out_shardings,
-            ).lower(*args).compile(),
-        )
+            args = (params, pool, tables, lengths, tokens, rngs, sample_mask)
+            out_shardings = None
+            if self.mesh is not None:
+                out_shardings = (
+                    self._shardings_of(pool), self._rep_sharding,
+                    self._rep_sharding,
+                )
+            compiled = self._compiled(
+                self._paged_step, key, "paged_step",
+                lambda: self._jit(
+                    step_fn, args, donate=(1, 5), out_shardings=out_shardings,
+                ).lower(*args).compile(),
+            )
         with telemetry.span("decode_engine/paged_step", slots=slots):
             return compiled(*args)
 
@@ -1617,37 +1633,38 @@ class DecodeEngine:
                 "decode_attention='gather' (XLA shards the gather "
                 "path), or tp=1"
             )
-        params = self._place_params(params)
-        tables = jnp.asarray(tables, jnp.int32)
-        lengths = jnp.asarray(lengths, jnp.int32)
-        tokens = jnp.asarray(tokens, jnp.int32)
-        n_known = jnp.asarray(n_known, jnp.int32)
-        eos_ids = jnp.asarray(eos_ids, jnp.int32)
-        rngs = jnp.asarray(rngs, jnp.uint32)
-        active = jnp.asarray(active, bool)
-        slots, width = (int(tokens.shape[0]), int(tokens.shape[1]))
-        key = ("paged_spec", slots, width, tuple(tables.shape), block_size,
-               decode_attention, float(temperature), top_k, top_p,
-               self._params_fingerprint(params),
-               self._tree_fingerprint(pool))
-        fn = build_paged_spec_step_fn(
-            self.model, block_size, width, temperature, top_k, top_p,
-            decode_attention=decode_attention,
-        )
-        args = (params, pool, tables, lengths, tokens, n_known, eos_ids,
-                rngs, active)
-        out_shardings = None
-        if self.mesh is not None:
-            out_shardings = (
-                self._shardings_of(pool), self._rep_sharding,
-                self._rep_sharding, self._rep_sharding,
+        with telemetry.span("decode_engine/step_args"):
+            params = self._place_params(params)
+            tables = jnp.asarray(tables, jnp.int32)
+            lengths = jnp.asarray(lengths, jnp.int32)
+            tokens = jnp.asarray(tokens, jnp.int32)
+            n_known = jnp.asarray(n_known, jnp.int32)
+            eos_ids = jnp.asarray(eos_ids, jnp.int32)
+            rngs = jnp.asarray(rngs, jnp.uint32)
+            active = jnp.asarray(active, bool)
+            slots, width = (int(tokens.shape[0]), int(tokens.shape[1]))
+            key = ("paged_spec", slots, width, tuple(tables.shape), block_size,
+                   decode_attention, float(temperature), top_k, top_p,
+                   self._params_fingerprint(params),
+                   self._tree_fingerprint(pool))
+            fn = build_paged_spec_step_fn(
+                self.model, block_size, width, temperature, top_k, top_p,
+                decode_attention=decode_attention,
             )
-        compiled = self._compiled(
-            self._paged_spec_step, key, "paged_spec_step",
-            lambda: self._jit(
-                fn, args, donate=(1, 7), out_shardings=out_shardings,
-            ).lower(*args).compile(),
-        )
+            args = (params, pool, tables, lengths, tokens, n_known, eos_ids,
+                    rngs, active)
+            out_shardings = None
+            if self.mesh is not None:
+                out_shardings = (
+                    self._shardings_of(pool), self._rep_sharding,
+                    self._rep_sharding, self._rep_sharding,
+                )
+            compiled = self._compiled(
+                self._paged_spec_step, key, "paged_spec_step",
+                lambda: self._jit(
+                    fn, args, donate=(1, 7), out_shardings=out_shardings,
+                ).lower(*args).compile(),
+            )
         with telemetry.span("decode_engine/paged_spec_step", slots=slots,
                             width=width):
             return compiled(*args)

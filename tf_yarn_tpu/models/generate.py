@@ -28,38 +28,39 @@ import jax.numpy as jnp
 def _sample(logits, rng, temperature: float, top_k: Optional[int],
             top_p: Optional[float] = None):
     """logits [B, V] -> token ids [B]."""
-    if temperature == 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    logits = logits / temperature
-    if top_k is not None or top_p is not None:
-        # One descending sort serves both filters — a second full-vocab
-        # sort per decode token would double the hot-path sort cost.
-        sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
-        if top_k is not None:
-            # top_k >= vocab keeps everything; unclamped it would index
-            # past the sorted row's end.
-            k = max(1, min(int(top_k), logits.shape[-1]))
-            kth = sorted_desc[:, k - 1][:, None]
-            logits = jnp.where(logits < kth, -1e30, logits)
-            # Mirror the mask in sorted space so top_p renormalizes over
-            # the top_k-filtered distribution (value-based: ties at the
-            # threshold survive in both views).
-            sorted_desc = jnp.where(sorted_desc < kth, -1e30, sorted_desc)
-        if top_p is not None:
-            # Nucleus sampling: keep the smallest probability-sorted
-            # prefix whose mass reaches top_p; the keep-mask scatters
-            # back by comparing each logit to the cutoff logit
-            # (sort+cumsum, no gather/scatter ops — XLA-clean).
-            probs = jax.nn.softmax(sorted_desc, axis=-1)
-            cumulative = jnp.cumsum(probs, axis=-1)
-            # Positions strictly past the nucleus; the first token
-            # always stays (cumulative >= top_p only AFTER including it).
-            in_nucleus = cumulative - probs < top_p
-            cutoff_idx = jnp.maximum(jnp.sum(in_nucleus, axis=-1) - 1, 0)
-            cutoff = jnp.take_along_axis(
-                sorted_desc, cutoff_idx[:, None], axis=-1)
-            logits = jnp.where(logits < cutoff, -1e30, logits)
-    return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        if temperature == 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        logits = logits / temperature
+        if top_k is not None or top_p is not None:
+            # One descending sort serves both filters — a second full-vocab
+            # sort per decode token would double the hot-path sort cost.
+            sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
+            if top_k is not None:
+                # top_k >= vocab keeps everything; unclamped it would index
+                # past the sorted row's end.
+                k = max(1, min(int(top_k), logits.shape[-1]))
+                kth = sorted_desc[:, k - 1][:, None]
+                logits = jnp.where(logits < kth, -1e30, logits)
+                # Mirror the mask in sorted space so top_p renormalizes over
+                # the top_k-filtered distribution (value-based: ties at the
+                # threshold survive in both views).
+                sorted_desc = jnp.where(sorted_desc < kth, -1e30, sorted_desc)
+            if top_p is not None:
+                # Nucleus sampling: keep the smallest probability-sorted
+                # prefix whose mass reaches top_p; the keep-mask scatters
+                # back by comparing each logit to the cutoff logit
+                # (sort+cumsum, no gather/scatter ops — XLA-clean).
+                probs = jax.nn.softmax(sorted_desc, axis=-1)
+                cumulative = jnp.cumsum(probs, axis=-1)
+                # Positions strictly past the nucleus; the first token
+                # always stays (cumulative >= top_p only AFTER including it).
+                in_nucleus = cumulative - probs < top_p
+                cutoff_idx = jnp.maximum(jnp.sum(in_nucleus, axis=-1) - 1, 0)
+                cutoff = jnp.take_along_axis(
+                    sorted_desc, cutoff_idx[:, None], axis=-1)
+                logits = jnp.where(logits < cutoff, -1e30, logits)
+        return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
 def generate(

@@ -107,17 +107,25 @@ def _partitioned(names):
     return lambda init: nn.with_partitioning(init, names)
 
 
+# Scopes on the model's device operations (docs/Observability.md "Device
+# operations by scope"): `jax.named_scope` only adds to the operations'
+# metadata, so a profile can say "attention/scores" where the operation's
+# own name is its shape. The names are read by cellbench's step_*_share
+# metrics; Flax adds the module path (layer_3/block/attn/...) around them.
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotary embedding over the last dim of [B, S, H, D]."""
-    d = x.shape[-1]
-    freqs = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = positions[:, :, None, None].astype(jnp.float32) * freqs  # [B,S,1,D/2]
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    rx1 = x1 * cos - x2 * sin
-    rx2 = x2 * cos + x1 * sin
-    out = jnp.stack([rx1, rx2], axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
+    with jax.named_scope("attention/rope"):
+        d = x.shape[-1]
+        freqs = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        angles = positions[:, :, None, None].astype(jnp.float32) * freqs  # [B,S,1,D/2]
+        cos, sin = jnp.cos(angles), jnp.sin(angles)
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        rx1 = x1 * cos - x2 * sin
+        rx2 = x2 * cos + x1 * sin
+        out = jnp.stack([rx1, rx2], axis=-1).reshape(x.shape)
+        return out.astype(x.dtype)
 
 
 class RMSNorm(nn.Module):
@@ -130,15 +138,16 @@ class RMSNorm(nn.Module):
             "scale", _partitioned((None,))(nn.initializers.ones), (x.shape[-1],),
             cfg.param_dtype,
         )
-        if cfg.fused_norms:
-            from tf_yarn_tpu.ops.rmsnorm import rmsnorm
+        with jax.named_scope("norm"):
+            if cfg.fused_norms:
+                from tf_yarn_tpu.ops.rmsnorm import rmsnorm
 
-            return rmsnorm(x, scale, eps=cfg.norm_eps).astype(cfg.dtype)
-        x32 = x.astype(jnp.float32)
-        norm = x32 * jax.lax.rsqrt(
-            jnp.mean(x32 * x32, axis=-1, keepdims=True) + cfg.norm_eps
-        )
-        return (norm * scale.astype(jnp.float32)).astype(cfg.dtype)
+                return rmsnorm(x, scale, eps=cfg.norm_eps).astype(cfg.dtype)
+            x32 = x.astype(jnp.float32)
+            norm = x32 * jax.lax.rsqrt(
+                jnp.mean(x32 * x32, axis=-1, keepdims=True) + cfg.norm_eps
+            )
+            return (norm * scale.astype(jnp.float32)).astype(cfg.dtype)
 
 
 class LoraDense(nn.Module):
@@ -198,12 +207,13 @@ class Attention(nn.Module):
         cfg = self.config
         decode = self.decode
         b, s, _ = x.shape
-        q = LoraDense(cfg.n_heads * cfg.head_dim, (EMBED, HEADS), cfg, name="wq")(x)
-        k = LoraDense(cfg.n_kv_heads * cfg.head_dim, (EMBED, KV), cfg, name="wk")(x)
-        v = LoraDense(cfg.n_kv_heads * cfg.head_dim, (EMBED, KV), cfg, name="wv")(x)
-        q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
-        k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-        v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        with jax.named_scope("attention/qkv"):
+            q = LoraDense(cfg.n_heads * cfg.head_dim, (EMBED, HEADS), cfg, name="wq")(x)
+            k = LoraDense(cfg.n_kv_heads * cfg.head_dim, (EMBED, KV), cfg, name="wk")(x)
+            v = LoraDense(cfg.n_kv_heads * cfg.head_dim, (EMBED, KV), cfg, name="wv")(x)
+            q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
+            k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
         if decode and paged_ctx is not None:
             # Fused paged decode: the serving engine passed the int8 KV
             # block pool (kv_pool collection) + per-slot (tables,
@@ -258,9 +268,10 @@ class Attention(nn.Module):
             k = rope(k, positions, cfg.rope_theta)
 
             def _append(var, fresh):
-                var.value = jax.lax.dynamic_update_slice(
-                    var.value, fresh, (0, idx, 0, 0)
-                )
+                with jax.named_scope("attention/kv_write"):
+                    var.value = jax.lax.dynamic_update_slice(
+                        var.value, fresh, (0, idx, 0, 0)
+                    )
 
             if int8_cache:
                 # Per-(position, head) rows over head_dim (ops/quantize.py
@@ -316,8 +327,9 @@ class Attention(nn.Module):
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
             out = attention(q, k, v, impl=cfg.attention_impl, causal=True)
-        out = out.reshape(b, s, cfg.n_heads * cfg.head_dim)
-        return LoraDense(cfg.d_model, (HEADS, EMBED), cfg, name="wo")(out)
+        with jax.named_scope("attention/out"):
+            out = out.reshape(b, s, cfg.n_heads * cfg.head_dim)
+            return LoraDense(cfg.d_model, (HEADS, EMBED), cfg, name="wo")(out)
 
     def _fused_paged_decode(self, q, k, v, paged_ctx):
         """Decode attention straight off the paged int8 KV pool: rope at
@@ -377,11 +389,12 @@ class Attention(nn.Module):
         def scatter(var, rows):
             # Pool leaves keep the slot-row cache's vestigial batch-1
             # axis: [1, NB, bs, Hkv, *].
-            pool = var.value[0]
-            rows = rows.reshape((slots * width,) + rows.shape[2:])
-            pool = pool.at[blocks, offsets].set(rows.astype(pool.dtype))
-            var.value = pool[None]
-            return pool
+            with jax.named_scope("attention/kv_write"):
+                pool = var.value[0]
+                rows = rows.reshape((slots * width,) + rows.shape[2:])
+                pool = pool.at[blocks, offsets].set(rows.astype(pool.dtype))
+                var.value = pool[None]
+                return pool
 
         key_pool = scatter(pool_vars["cached_key"], k_q)
         value_pool = scatter(pool_vars["cached_value"], v_q)
@@ -416,13 +429,13 @@ class Block(nn.Module):
         x = x + Attention(cfg, self.decode, name="attn")(
             RMSNorm(cfg, name="attn_norm")(x), positions, paged_ctx
         )
-        if cfg.moe_experts > 0:
-            from tf_yarn_tpu.models.moe import MoEMlp
+        normed = RMSNorm(cfg, name="mlp_norm")(x)
+        with jax.named_scope("mlp"):
+            if cfg.moe_experts > 0:
+                from tf_yarn_tpu.models.moe import MoEMlp
 
-            x = x + MoEMlp(cfg, name="moe")(RMSNorm(cfg, name="mlp_norm")(x))
-        else:
-            x = x + SwiGLU(cfg, name="mlp")(RMSNorm(cfg, name="mlp_norm")(x))
-        return x
+                return x + MoEMlp(cfg, name="moe")(normed)
+            return x + SwiGLU(cfg, name="mlp")(normed)
 
 
 class _ScanBody(nn.Module):
@@ -503,7 +516,8 @@ class Transformer(nn.Module):
             (cfg.vocab_size, cfg.d_model),
             cfg.param_dtype,
         )
-        x = embedding.astype(cfg.dtype)[tokens]
+        with jax.named_scope("embed"):
+            x = embedding.astype(cfg.dtype)[tokens]
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape
         )
@@ -529,7 +543,10 @@ class Transformer(nn.Module):
         )
         if return_hidden:
             return x
-        return jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype)).astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            return jnp.einsum(
+                "bsd,dv->bsv", x, head.astype(cfg.dtype)
+            ).astype(jnp.float32)
 
     def _gpipe_layers(self, x, positions):
         """Layer stack under the overlapped GPipe schedule

@@ -26,8 +26,9 @@ import jax.numpy as jnp
 def _repeat_kv(key: jax.Array, value: jax.Array, n_rep: int):
     if n_rep == 1:
         return key, value
-    key = jnp.repeat(key, n_rep, axis=2)
-    value = jnp.repeat(value, n_rep, axis=2)
+    with jax.named_scope("attention/repeat_kv"):
+        key = jnp.repeat(key, n_rep, axis=2)
+        value = jnp.repeat(value, n_rep, axis=2)
     return key, value
 
 
@@ -53,26 +54,30 @@ def xla_attention(
     _, s_kv, n_kv, _ = key.shape
     key, value = _repeat_kv(key, value, n_heads // n_kv)
     scale = softmax_scale if softmax_scale is not None else head_dim**-0.5
-    logits = jnp.einsum("bqhd,bkhd->bhqk", query, key) * scale
-    logits = logits.astype(jnp.float32)
-    neg_inf = jnp.finfo(jnp.float32).min
-    if causal:
-        q_pos = jnp.arange(s_q)[:, None] + segment_offset
-        k_pos = jnp.arange(s_kv)[None, :]
-        mask = q_pos >= k_pos
-        logits = jnp.where(mask[None, None], logits, neg_inf)
-    if key_padding_mask is not None:
-        keep = key_padding_mask.astype(bool)[:, None, None, :]  # [B,1,1,Skv]
-        logits = jnp.where(keep, logits, neg_inf)
-    probs = jax.nn.softmax(logits, axis=-1).astype(query.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, value)
-    if key_padding_mask is not None:
-        # A fully-padded row (no real keys) would otherwise get a
-        # silent uniform softmax over finfo.min logits — finite garbage.
-        # Zero those rows' outputs instead: [B,1,1,1] broadcast over
-        # out's [B,S,H,D].
-        has_any_key = jnp.any(keep, axis=-1)[..., None]
-        out = jnp.where(has_any_key, out, jnp.zeros((), out.dtype))
+    # Scopes, not spans: metadata on the device operations, so a profile
+    # names scores, softmax and values apart (docs/Observability.md).
+    with jax.named_scope("attention/scores"):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", query, key) * scale
+        logits = logits.astype(jnp.float32)
+        neg_inf = jnp.finfo(jnp.float32).min
+        if causal:
+            q_pos = jnp.arange(s_q)[:, None] + segment_offset
+            k_pos = jnp.arange(s_kv)[None, :]
+            mask = q_pos >= k_pos
+            logits = jnp.where(mask[None, None], logits, neg_inf)
+        if key_padding_mask is not None:
+            keep = key_padding_mask.astype(bool)[:, None, None, :]  # [B,1,1,Skv]
+            logits = jnp.where(keep, logits, neg_inf)
+        probs = jax.nn.softmax(logits, axis=-1).astype(query.dtype)
+    with jax.named_scope("attention/values"):
+        out = jnp.einsum("bhqk,bkhd->bqhd", probs, value)
+        if key_padding_mask is not None:
+            # A fully-padded row (no real keys) would otherwise get a
+            # silent uniform softmax over finfo.min logits — finite
+            # garbage. Zero those rows' outputs instead: [B,1,1,1]
+            # broadcast over out's [B,S,H,D].
+            has_any_key = jnp.any(keep, axis=-1)[..., None]
+            out = jnp.where(has_any_key, out, jnp.zeros((), out.dtype))
     return out
 
 

@@ -25,6 +25,8 @@ import threading
 import time
 from typing import Deque, Iterator, List, Optional, Tuple
 
+from tf_yarn_tpu.telemetry import spans
+
 # finish_reason values a Response can end with.
 FINISH_EOS = "eos"            # the model emitted the request's eos token
 FINISH_LENGTH = "length"      # max_new_tokens generated
@@ -108,6 +110,9 @@ class Request:
     tier: str = DEFAULT_TIER
     id: int = dataclasses.field(default_factory=lambda: next(_REQUEST_IDS))
     submitted_at: float = dataclasses.field(default_factory=time.monotonic)
+    # The same instant on the span tracer's clock: where this request's
+    # `serving/request` record starts (deadlines stay on monotonic).
+    submitted_clock: float = dataclasses.field(default_factory=spans.now)
     # Cross-task trace id (the router's X-Request-Id): joins this
     # request's scheduler trace-ring entries and spans to the router's
     # span for the same HTTP request. None for untraced callers.
@@ -126,6 +131,13 @@ class Request:
     @property
     def tier_rank(self) -> int:
         return _TIER_RANK[self.tier]
+
+    @property
+    def public_id(self) -> str:
+        """The one id this request's spans and records carry as
+        `request_id`: the caller's X-Request-Id, or the program's own id
+        where none came."""
+        return self.trace_id or str(self.id)
 
     @property
     def deadline(self) -> Optional[float]:

@@ -60,6 +60,7 @@ from __future__ import annotations
 
 import collections
 import logging
+import statistics
 import threading
 import time
 from typing import Deque, Dict, List, Optional, Tuple
@@ -67,6 +68,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from tf_yarn_tpu import telemetry
+from tf_yarn_tpu.telemetry import spans
 from tf_yarn_tpu.models.spec import make_drafter, plan_window
 from tf_yarn_tpu.serving.paging import (
     TRASH_BLOCK,
@@ -97,6 +99,16 @@ _logger = logging.getLogger(__name__)
 # deadline-expiry latency for queued-but-idle states.
 IDLE_POLL_S = 0.05
 
+# A model step is "slow" when it takes more than SLOW_STEP_FACTOR times
+# the running median of the last SLOW_STEP_WINDOW steps (judged once
+# SLOW_STEP_MIN_HISTORY of them exist).
+SLOW_STEP_FACTOR = 2.0
+SLOW_STEP_WINDOW = 256
+SLOW_STEP_MIN_HISTORY = 8
+# `finish` of the `serving/request` record of a request `submit` turned
+# away (queue full, tier cap, unservable): it never had a Response.
+REFUSED = "refused"
+
 KV_LAYOUTS = ("dense", "paged")
 DECODE_ATTENTION = ("gather", "fused")
 
@@ -113,7 +125,9 @@ class _Slot:
 
     __slots__ = ("request", "response", "pending", "last_token", "emitted",
                  "blocks", "context", "prompt_filled", "registered_blocks",
-                 "last_emit_at")
+                 "last_emit_at", "kv_len", "prefilled", "hit_tokens",
+                 "replay", "queue_wait_s", "prefill_s", "admitted_clock",
+                 "replay_s")
 
     def __init__(self, request: Request, response: Response,
                  pending: List[int], blocks: Optional[List[int]] = None):
@@ -140,6 +154,22 @@ class _Slot:
         # monotonic time of the last token push — the inter-token
         # latency histogram's reference point.
         self.last_emit_at: Optional[float] = None
+        # Tokens with valid KV in this slot's cache (the paged layout's
+        # `_lengths` row, kept for the dense layout too): what a
+        # length-aware attention would have to read.
+        self.kv_len = self.prompt_filled
+        # The request's time to its first token, in the parts
+        # `_record_admission` and `_observe_ttft` fill in (span clock):
+        # tokens through the blocking prefill program / taken from the
+        # prefix cache / left to replay, submit -> admission, admission
+        # (its blocking prefill), admission's end -> first token.
+        self.prefilled = 0
+        self.hit_tokens = 0
+        self.replay = len(self.pending)
+        self.queue_wait_s = 0.0
+        self.prefill_s = 0.0
+        self.admitted_clock = 0.0
+        self.replay_s: Optional[float] = None
 
 
 class _Suspended:
@@ -345,6 +375,17 @@ class SlotScheduler:
         self._spec_accepted = 0
         self._prefill_tokens = 0
         self._decode_tokens = 0
+        # Work counters where the work happens (/stats, docs/Serving.md).
+        self._prefilled_tokens = 0
+        self._kv_token_steps = 0
+        self._slot_steps = 0
+        self._step_seconds: Deque[float] = collections.deque(
+            maxlen=SLOW_STEP_WINDOW)
+        self._slow_steps = 0
+        self._slow_step_seconds = 0.0
+        self._slowest_step: Optional[Dict] = None
+        # The last step's (launch, sync, emit) spans, for the tally.
+        self._step_parts: Tuple = ()
         kv_host_blocks = int(kv_host_blocks or 0)
         if kv_host_blocks < 0:
             raise ValueError(
@@ -405,6 +446,7 @@ class SlotScheduler:
         self._lifecycle = threading.Lock()
         self._thread: Optional[threading.Thread] = None
         self._registry = telemetry.get_registry()
+        self._tracer = telemetry.get_tracer()
         # max context the model's KV cache can hold, when the engine
         # exposes a config (the fake engines in tests need not) or the
         # caller says so explicitly.
@@ -503,6 +545,23 @@ class SlotScheduler:
         request's tier cap — is at capacity (backpressure). `trace_id`
         (the router's X-Request-Id) tags this request's trace-ring
         entries so one id joins router span → queue wait → ticks."""
+        began = spans.now()
+        try:
+            return self._submit(prompt, params, priority, timeout_s, tier,
+                                trace_id)
+        except Exception:
+            # Turned away (unservable, queue full, tier at its cap):
+            # still one `serving/request` record under the caller's id.
+            self._tracer.record(
+                "serving/request", began, spans.now() - began,
+                request_id=trace_id, finish=REFUSED,
+                prompt_tokens=len(prompt) if hasattr(prompt, "__len__")
+                else None,
+            )
+            raise
+
+    def _submit(self, prompt, params, priority, timeout_s, tier,
+                trace_id) -> Response:
         params = params or SamplingParams(
             temperature=self.temperature, top_k=self.top_k, top_p=self.top_p
         )
@@ -578,11 +637,16 @@ class SlotScheduler:
     def tick(self) -> bool:
         """One scheduling round; returns whether any work happened (the
         loop idles when it returns False)."""
-        self._run_control_ops()
+        # The scheduler's thread is always under a named span: control
+        # ops, the tick, what follows it, the idle wait (docs/Serving.md
+        # "Where a tick's time goes").
+        with telemetry.span("serving/control_ops"):
+            self._run_control_ops()
         now = time.monotonic()
         admitted: List[int] = []
         retired: List = []
-        with telemetry.span("serving/tick") as tick_span:
+        tick_no = self._ticks + 1  # its number, if any work happens
+        with telemetry.span("serving/tick", tick=tick_no) as tick_span:
             with telemetry.span("serving/retire"):
                 self._retire_deadlines(now, retired)
             if self._suspended:
@@ -591,13 +655,32 @@ class SlotScheduler:
             with telemetry.span("serving/admit"):
                 self._admit(now, admitted)
             active = [s for s in range(self.max_slots) if self._slots[s]]
-            accepts = None
+            accepts = step_span = None
             if active:
-                with telemetry.span("serving/step", active=len(active)):
+                # The annotation (only while a capture started through
+                # telemetry.profile runs) puts the tick's number into the
+                # profiler's own host plane, beside jit_step's executions.
+                with telemetry.span(
+                    "serving/step", active=len(active), tick=tick_no
+                ) as step_span, telemetry.profile.annotation(
+                    "serving/step", tick=tick_no
+                ):
                     if self._windowed:
                         accepts = self._step_spec(active, retired)
                     else:
                         self._step(active, retired)
+        with telemetry.span("serving/publish"):
+            return self._publish(
+                tick_span, step_span, active, admitted, retired, accepts
+            )
+
+    def _publish(self, tick_span, step_span, active, admitted, retired,
+                 accepts) -> bool:
+        """What a tick leaves behind once `serving/tick` has closed: the
+        slow-step tally, the tick histogram, the trace ring's entry, the
+        gauges."""
+        if step_span is not None:
+            self._note_step_seconds(step_span.duration, self._ticks + 1)
         worked = bool(active or admitted or retired)
         streams = len([s for s in self._slots if s is not None]) \
             + len(self._suspended)
@@ -689,6 +772,7 @@ class SlotScheduler:
         """A request that dies without ever occupying a slot."""
         self._tier_dec(response.request)
         response._finish(reason)
+        self._record_request(response.request, reason)
         self._registry.counter(
             "serving/requests_completed_total", reason=reason
         ).inc()
@@ -710,6 +794,7 @@ class SlotScheduler:
             self._host_store.pop(entry.request.id)
         self._tier_dec(entry.request)
         entry.state.response._finish(reason)
+        self._record_request(entry.request, reason, entry.state)
         retired.append((entry.request.id, reason))
         self._registry.counter(
             "serving/requests_completed_total", reason=reason
@@ -747,8 +832,15 @@ class SlotScheduler:
             else:
                 self._admit_dense(request, response, now, admitted)
 
-    def _record_admission(self, slot: int, request: Request,
-                          now: float, admitted: List[int]) -> None:
+    def _record_admission(self, slot: int, state: _Slot, now: float,
+                          admitted: List[int], began: float,
+                          prefilled: int = 0, hit_tokens: int = 0) -> None:
+        """Every admission path ends here (dense, paged, prefix hit,
+        chunked). `began` is the span clock when the request took its
+        slot, before any blocking prefill: the zero-length
+        `serving/admission` record stands at that instant, and the
+        request's wait in the queue ends there."""
+        request = state.request
         self._registry.histogram("serving/queue_wait_seconds").observe(
             now - request.submitted_at
         )
@@ -758,22 +850,39 @@ class SlotScheduler:
         self._rngs[slot] = _prng_key(request.params.seed)
         admitted.append(request.id)
         self._registry.counter("serving/requests_admitted_total").inc()
+        state.prefilled = prefilled
+        state.hit_tokens = hit_tokens
+        state.queue_wait_s = began - request.submitted_clock
+        state.admitted_clock = spans.now()
+        state.prefill_s = state.admitted_clock - began
+        self._prefilled_tokens += prefilled
+        self._tracer.record(
+            "serving/admission", began, 0.0,
+            request_id=request.public_id, slot=slot,
+            prompt_tokens=len(request.prompt), prefilled=prefilled,
+            hit_tokens=hit_tokens, replay=state.replay,
+            queue_wait_ms=state.queue_wait_s * 1e3,
+            prefill_ms=state.prefill_s * 1e3,
+        )
 
     def _admit_dense(self, request: Request, response: Response,
                      now: float, admitted: List[int]) -> None:
         slot = self._free.popleft()
+        began = spans.now()
         if self._chunked:
             # Chunked prefill: no blocking prefill program at all. The
             # slot starts from a zeroed cache_index and the WHOLE prompt
             # queues as pending replay — the windowed tick consumes it
             # prefill_chunk tokens at a time, interleaved with decode.
             self._cache = self.engine.evict_slot(self._cache, slot)
-            self._slots[slot] = _Slot(request, response, list(request.prompt))
-            self._record_admission(slot, request, now, admitted)
+            state = _Slot(request, response, list(request.prompt))
+            self._slots[slot] = state
+            self._record_admission(slot, state, now, admitted, began)
             return
         prefill_len = self.engine.slot_prefill_len(len(request.prompt))
         with telemetry.span(
-            "serving/prefill", request=request.id, prefill=prefill_len
+            "serving/prefill", request=request.id,
+            request_id=request.public_id, prefill=prefill_len,
         ):
             if prefill_len > 0:
                 row_cache, _logits = self.engine.prefill(
@@ -789,10 +898,10 @@ class SlotScheduler:
                 # must start from a ZEROED cache_index, not whatever
                 # the previous occupant left behind.
                 self._cache = self.engine.evict_slot(self._cache, slot)
-        self._slots[slot] = _Slot(
-            request, response, list(request.prompt[prefill_len:])
-        )
-        self._record_admission(slot, request, now, admitted)
+        state = _Slot(request, response, list(request.prompt[prefill_len:]))
+        self._slots[slot] = state
+        self._record_admission(slot, state, now, admitted, began,
+                               prefilled=prefill_len)
 
     def _admit_paged(self, request: Request, response: Response,
                      now: float, admitted: List[int]) -> bool:
@@ -819,6 +928,8 @@ class SlotScheduler:
             return False
         blocks = hit_ids + owned
         slot = self._free.popleft()
+        began = spans.now()
+        prefilled = 0
         if hit_tokens:
             prefill_len = hit_tokens
             self._registry.counter("serving/prefix_cache_hits_total").inc()
@@ -830,9 +941,10 @@ class SlotScheduler:
             # completed whole block with the prefix cache as it fills.
             prefill_len = 0
         else:
-            prefill_len = self.engine.slot_prefill_len(len(prompt))
+            prefill_len = prefilled = self.engine.slot_prefill_len(len(prompt))
             with telemetry.span(
-                "serving/prefill", request=request.id, prefill=prefill_len
+                "serving/prefill", request=request.id,
+                request_id=request.public_id, prefill=prefill_len,
             ):
                 if prefill_len > 0:
                     row_cache, _logits = self.engine.prefill(
@@ -860,7 +972,8 @@ class SlotScheduler:
         # starts past them.
         state.registered_blocks = prefill_len // self._block_size
         self._slots[slot] = state
-        self._record_admission(slot, request, now, admitted)
+        self._record_admission(slot, state, now, admitted, began,
+                               prefilled=prefilled, hit_tokens=hit_tokens)
         return True
 
     # -- host-tier swap: suspend / resume ------------------------------------
@@ -1254,72 +1367,89 @@ class SlotScheduler:
         }
 
     def _step(self, active: List[int], retired: List) -> None:
-        tokens = np.zeros((self.max_slots,), np.int32)
-        mask = np.zeros((self.max_slots,), bool)
-        for slot in active:
-            state = self._slots[slot]
-            if state.pending:
-                tokens[slot] = state.pending[0]
-                mask[slot] = len(state.pending) == 1
-            else:
-                tokens[slot] = state.last_token
-                mask[slot] = True
-        if self.kv_layout == "paged":
-            self._pool, emitted, rngs = self.engine.paged_step(
-                self.params, self._pool, self._tables, self._lengths,
-                tokens, self._rngs, mask,
-                block_size=self._block_size,
-                temperature=self.temperature, top_k=self.top_k,
-                top_p=self.top_p,
-            )
-        else:
-            self._cache, emitted, rngs = self.engine.step(
-                self.params, self._cache, tokens, self._rngs, mask,
-                temperature=self.temperature, top_k=self.top_k,
-                top_p=self.top_p,
-            )
-        # The tick's one host sync: every slot's token in one transfer.
-        emitted = np.asarray(emitted)
-        # np.array (copy): admissions write PRNGKey rows into this
-        # buffer, and np.asarray of a device array is read-only.
-        self._rngs = np.array(rngs)
-        now = time.monotonic()
-        prefill_tokens = 0
-        decode_tokens = 0
-        for slot in active:
-            state = self._slots[slot]
+        # Three spans tile the step: building the inputs and the engine's
+        # call (the device starts somewhere inside), the one host sync
+        # (the host waits for the device), the per-slot bookkeeping
+        # after it (the device waits for the host).
+        with telemetry.span("serving/step_launch") as launch_span:
+            self._count_step(active)
+            tokens = np.zeros((self.max_slots,), np.int32)
+            mask = np.zeros((self.max_slots,), bool)
+            for slot in active:
+                state = self._slots[slot]
+                if state.pending:
+                    tokens[slot] = state.pending[0]
+                    mask[slot] = len(state.pending) == 1
+                else:
+                    tokens[slot] = state.last_token
+                    mask[slot] = True
             if self.kv_layout == "paged":
-                # Every active slot consumed one token this tick (a
-                # replayed prompt token or its fed-back emission) and
-                # wrote its K/V at the old length.
-                self._lengths[slot] += 1
-            sampled = bool(mask[slot])
-            if state.pending:
-                state.pending.popleft()
-                state.prompt_filled += 1
-                prefill_tokens += 1
-            if not sampled:
-                continue
-            token = int(emitted[slot])
-            state.last_token = token
-            state.emitted += 1
-            decode_tokens += 1
-            first = state.response.first_token_at is None
-            state.response._push(token)
-            if first:
-                self._observe_ttft(state)
-            elif state.last_emit_at is not None:
-                self._registry.histogram(
-                    "serving/inter_token_latency_ms"
-                ).observe((now - state.last_emit_at) * 1e3)
-            state.last_emit_at = now
-            self._registry.counter("serving/tokens_generated_total").inc()
-            eos = state.request.params.eos_token
-            if eos is not None and token == eos:
-                self._retire(slot, FINISH_EOS, retired)
-            elif state.emitted >= state.request.params.max_new_tokens:
-                self._retire(slot, FINISH_LENGTH, retired)
-        self._account_tokens(prefill_tokens, decode_tokens)
+                self._pool, emitted, rngs = self.engine.paged_step(
+                    self.params, self._pool, self._tables, self._lengths,
+                    tokens, self._rngs, mask,
+                    block_size=self._block_size,
+                    temperature=self.temperature, top_k=self.top_k,
+                    top_p=self.top_p,
+                )
+            else:
+                self._cache, emitted, rngs = self.engine.step(
+                    self.params, self._cache, tokens, self._rngs, mask,
+                    temperature=self.temperature, top_k=self.top_k,
+                    top_p=self.top_p,
+                )
+        with telemetry.span("serving/step_sync") as sync_span:
+            # The tick's one host sync: every slot's token in one transfer.
+            emitted = np.asarray(emitted)
+            # np.array (copy): admissions write PRNGKey rows into this
+            # buffer, and np.asarray of a device array is read-only.
+            self._rngs = np.array(rngs)
+            # Freed here, under this span: left to the function's return
+            # the device buffer's release took 0.5-0.8 ms a tick on a v5e
+            # inside `serving/step` and under none of its children.
+            del rngs
+        with telemetry.span("serving/step_emit") as emit_span:
+            self._step_parts = (launch_span, sync_span, emit_span)
+            was_retired = len(retired)
+            now = time.monotonic()
+            prefill_tokens = 0
+            decode_tokens = 0
+            for slot in active:
+                state = self._slots[slot]
+                if self.kv_layout == "paged":
+                    # Every active slot consumed one token this tick (a
+                    # replayed prompt token or its fed-back emission) and
+                    # wrote its K/V at the old length.
+                    self._lengths[slot] += 1
+                state.kv_len += 1
+                sampled = bool(mask[slot])
+                if state.pending:
+                    state.pending.popleft()
+                    state.prompt_filled += 1
+                    prefill_tokens += 1
+                if not sampled:
+                    continue
+                token = int(emitted[slot])
+                state.last_token = token
+                state.emitted += 1
+                decode_tokens += 1
+                first = state.response.first_token_at is None
+                state.response._push(token)
+                if first:
+                    self._observe_ttft(state)
+                elif state.last_emit_at is not None:
+                    self._registry.histogram(
+                        "serving/inter_token_latency_ms"
+                    ).observe((now - state.last_emit_at) * 1e3)
+                state.last_emit_at = now
+                eos = state.request.params.eos_token
+                if eos is not None and token == eos:
+                    self._retire(slot, FINISH_EOS, retired)
+                elif state.emitted >= state.request.params.max_new_tokens:
+                    self._retire(slot, FINISH_LENGTH, retired)
+            self._account_tokens(prefill_tokens, decode_tokens)
+            emit_span.args.update(
+                tokens=decode_tokens, retired=len(retired) - was_retired
+            )
 
     def _observe_ttft(self, state) -> None:
         # The unlabeled histogram is the back-compat aggregate; the
@@ -1330,6 +1460,84 @@ class SlotScheduler:
         self._registry.histogram(
             "serving/ttft_seconds", tier=state.request.tier
         ).observe(ttft)
+        # The same wait on the span clock, in its three parts: a request
+        # still decoding when someone reads the spans has no
+        # `serving/request` record yet, and its first token is long past.
+        clock = spans.now()
+        state.replay_s = clock - state.admitted_clock
+        self._tracer.record(
+            "serving/first_token", clock, 0.0,
+            request_id=state.request.public_id,
+            **self._ttft_parts_ms(state),
+        )
+
+    @staticmethod
+    def _ttft_parts_ms(state: _Slot) -> Dict:
+        """queue wait + blocking prefill + replay = time to first token
+        (ms, span clock); the last two None before a first token."""
+        parts = {"queue_wait_ms": state.queue_wait_s * 1e3,
+                 "prefill_ms": state.prefill_s * 1e3,
+                 "replay_ms": None, "ttft_ms": None}
+        if state.replay_s is not None:
+            parts["replay_ms"] = state.replay_s * 1e3
+            parts["ttft_ms"] = (state.queue_wait_s + state.prefill_s
+                                + state.replay_s) * 1e3
+        return parts
+
+    def _record_request(self, request: Request, reason: str,
+                        state: Optional[_Slot] = None,
+                        slot: Optional[int] = None) -> None:
+        """The one `serving/request` record of a request the scheduler
+        accepted, at its end (every finish reason, admitted or not):
+        submit -> finish on the span clock, under the caller's id."""
+        clock = spans.now()
+        life = clock - request.submitted_clock
+        if state is None:  # died in the queue: all of its life was wait
+            parts = {"queue_wait_ms": life * 1e3, "prefill_ms": 0.0,
+                     "replay_ms": None, "ttft_ms": None, "prefilled": 0,
+                     "hit_tokens": 0, "replayed": 0, "emitted": 0}
+        else:
+            parts = dict(
+                self._ttft_parts_ms(state), prefilled=state.prefilled,
+                hit_tokens=state.hit_tokens,
+                replayed=state.replay - len(state.pending),
+                emitted=state.emitted,
+            )
+        self._tracer.record(
+            "serving/request", request.submitted_clock, life,
+            request_id=request.public_id, finish=reason,
+            prompt_tokens=len(request.prompt), slot=slot, **parts,
+        )
+
+    def _count_step(self, active: List[int]) -> None:
+        """Before a model step: what it will read. `kv_token_steps` over
+        `slot_steps` is the mean live KV length a slot-step attends
+        over, against the `max_seq_len` the dense view holds."""
+        self._slot_steps += len(active)
+        self._kv_token_steps += sum(
+            self._slots[slot].kv_len for slot in active
+        )
+
+    def _note_step_seconds(self, seconds: float, tick: int) -> None:
+        """Single model steps of many times the usual length decide
+        whole runs (PERF.md): count them, and keep the longest with its
+        parts, which say whether the host was stuck before the dispatch,
+        the device slow under the sync, or the host stuck after it."""
+        history = self._step_seconds
+        if len(history) >= SLOW_STEP_MIN_HISTORY and \
+                seconds > SLOW_STEP_FACTOR * statistics.median(history):
+            self._slow_steps += 1
+            self._slow_step_seconds += seconds
+            if self._slowest_step is None or \
+                    seconds * 1e3 > self._slowest_step["ms"]:
+                launch, sync, emit = self._step_parts
+                self._slowest_step = {
+                    "tick": tick, "ms": seconds * 1e3,
+                    "launch_ms": launch.duration * 1e3,
+                    "sync_ms": sync.duration * 1e3,
+                    "emit_ms": emit.duration * 1e3,
+                }
+        history.append(seconds)
 
     def _step_spec(self, active: List[int], retired: List) -> Dict[int, int]:
         """The windowed tick: ONE compiled program advances every slot a
@@ -1348,128 +1556,136 @@ class SlotScheduler:
         contract. Returns {request id: tokens emitted} for the trace
         ring.
         """
-        width = self._window_width
-        tokens = np.full((self.max_slots, width), -1, np.int32)
-        n_known = np.zeros((self.max_slots,), np.int32)
-        eos_ids = np.full((self.max_slots,), -1, np.int32)
-        mask = np.zeros((self.max_slots,), bool)
-        consumed: Dict[int, int] = {}
-        proposed: Dict[int, int] = {}
-        budget = self.prefill_budget_per_tick
-        order = active
-        if budget is not None and len(active) > 1:
-            # Rotate who claims prefill budget first each tick so a
-            # burst of long prompts shares it fairly.
-            pivot = self._ticks % len(active)
-            order = active[pivot:] + active[:pivot]
-        for slot in order:
-            state = self._slots[slot]
-            need = min(len(state.pending), width)
-            if budget is not None and need > 0:
-                if need > budget:
-                    # Paused this tick (over budget): stays masked off —
-                    # the free-slot convention.
-                    consumed[slot] = 0
-                    proposed[slot] = 0
-                    continue
-                budget -= need
-            max_emit = state.request.params.max_new_tokens - state.emitted
-            window, known, n_prop = plan_window(
-                state.pending, state.last_token, width, max_emit,
-                state.context, self._drafter, max_drafts=self.spec_k,
-            )
-            tokens[slot] = window
-            n_known[slot] = known
-            eos = state.request.params.eos_token
-            eos_ids[slot] = -1 if eos is None else eos
-            mask[slot] = True
-            consumed[slot] = need
-            proposed[slot] = n_prop
-        if self.kv_layout == "paged":
-            self._pool, emitted, counts, rngs = self.engine.paged_spec_step(
-                self.params, self._pool, self._tables, self._lengths,
-                tokens, n_known, eos_ids, self._rngs, mask,
-                block_size=self._block_size,
-                temperature=self.temperature, top_k=self.top_k,
-                top_p=self.top_p,
-                decode_attention=self.decode_attention,
-            )
-        else:
-            self._cache, emitted, counts, rngs = self.engine.spec_step(
-                self.params, self._cache, tokens, n_known, eos_ids,
-                self._rngs, mask,
-                temperature=self.temperature, top_k=self.top_k,
-                top_p=self.top_p,
-            )
-        # The tick's host sync: every slot's window + counts at once.
-        emitted = np.asarray(emitted)
-        counts = np.asarray(counts)
-        self._rngs = np.array(rngs)
-        now = time.monotonic()
-        prefill_tokens = 0
-        decode_tokens = 0
-        accepts: Dict[int, int] = {}
-        for slot in active:
-            state = self._slots[slot]
-            for _ in range(consumed[slot]):
-                state.pending.popleft()
-            state.prompt_filled += consumed[slot]
-            prefill_tokens += consumed[slot]
-            n = int(counts[slot])
-            decode_tokens += n
-            if self.kv_layout == "paged":
-                # Valid rows this tick: the replayed prefix + the
-                # emitted tokens; rejected window rows beyond stay dead.
-                self._lengths[slot] += int(n_known[slot]) + n
-                if self._chunked and consumed[slot]:
-                    self._register_chunk_prefix(state)
-            if proposed[slot]:
-                accepted_drafts = min(max(n - 1, 0), proposed[slot])
-                self._spec_proposed += proposed[slot]
-                self._spec_accepted += accepted_drafts
-                self._registry.counter(
-                    "serving/spec_proposed_tokens_total"
-                ).inc(proposed[slot])
-                if accepted_drafts:
-                    self._registry.counter(
-                        "serving/spec_accepted_tokens_total"
-                    ).inc(accepted_drafts)
-            if n:
-                accepts[state.request.id] = n
-                self._registry.histogram(
-                    "serving/accepted_tokens_per_step"
-                ).observe(n)
-            for j in range(n):
-                token = int(emitted[slot, j])
-                state.last_token = token
-                state.emitted += 1
-                state.context.append(token)
-                first = state.response.first_token_at is None
-                state.response._push(token)
-                if first:
-                    self._observe_ttft(state)
-                elif state.last_emit_at is not None:
-                    # Tokens landing in the same tick (accepted drafts)
-                    # record a ~0 gap — they really do arrive together.
-                    self._registry.histogram(
-                        "serving/inter_token_latency_ms"
-                    ).observe((now - state.last_emit_at) * 1e3)
-                state.last_emit_at = now
-                self._registry.counter(
-                    "serving/tokens_generated_total"
-                ).inc()
+        with telemetry.span("serving/step_launch") as launch_span:
+            self._count_step(active)
+            width = self._window_width
+            tokens = np.full((self.max_slots, width), -1, np.int32)
+            n_known = np.zeros((self.max_slots,), np.int32)
+            eos_ids = np.full((self.max_slots,), -1, np.int32)
+            mask = np.zeros((self.max_slots,), bool)
+            consumed: Dict[int, int] = {}
+            proposed: Dict[int, int] = {}
+            budget = self.prefill_budget_per_tick
+            order = active
+            if budget is not None and len(active) > 1:
+                # Rotate who claims prefill budget first each tick so a
+                # burst of long prompts shares it fairly.
+                pivot = self._ticks % len(active)
+                order = active[pivot:] + active[:pivot]
+            for slot in order:
+                state = self._slots[slot]
+                need = min(len(state.pending), width)
+                if budget is not None and need > 0:
+                    if need > budget:
+                        # Paused this tick (over budget): stays masked off —
+                        # the free-slot convention.
+                        consumed[slot] = 0
+                        proposed[slot] = 0
+                        continue
+                    budget -= need
+                max_emit = state.request.params.max_new_tokens - state.emitted
+                window, known, n_prop = plan_window(
+                    state.pending, state.last_token, width, max_emit,
+                    state.context, self._drafter, max_drafts=self.spec_k,
+                )
+                tokens[slot] = window
+                n_known[slot] = known
                 eos = state.request.params.eos_token
-                if eos is not None and token == eos:
-                    self._retire(slot, FINISH_EOS, retired)
-                    break
-                if state.emitted >= state.request.params.max_new_tokens:
-                    self._retire(slot, FINISH_LENGTH, retired)
-                    break
-        if self._spec_proposed:
-            self._registry.gauge("serving/spec_accept_rate").set(
-                self._spec_accepted / self._spec_proposed
+                eos_ids[slot] = -1 if eos is None else eos
+                mask[slot] = True
+                consumed[slot] = need
+                proposed[slot] = n_prop
+            if self.kv_layout == "paged":
+                self._pool, emitted, counts, rngs = self.engine.paged_spec_step(
+                    self.params, self._pool, self._tables, self._lengths,
+                    tokens, n_known, eos_ids, self._rngs, mask,
+                    block_size=self._block_size,
+                    temperature=self.temperature, top_k=self.top_k,
+                    top_p=self.top_p,
+                    decode_attention=self.decode_attention,
+                )
+            else:
+                self._cache, emitted, counts, rngs = self.engine.spec_step(
+                    self.params, self._cache, tokens, n_known, eos_ids,
+                    self._rngs, mask,
+                    temperature=self.temperature, top_k=self.top_k,
+                    top_p=self.top_p,
+                )
+        with telemetry.span("serving/step_sync") as sync_span:
+            # The tick's host sync: every slot's window + counts at once.
+            emitted = np.asarray(emitted)
+            counts = np.asarray(counts)
+            self._rngs = np.array(rngs)
+            del rngs  # the device buffer goes under this span (see _step)
+        with telemetry.span("serving/step_emit") as emit_span:
+            self._step_parts = (launch_span, sync_span, emit_span)
+            was_retired = len(retired)
+            now = time.monotonic()
+            prefill_tokens = 0
+            decode_tokens = 0
+            accepts: Dict[int, int] = {}
+            for slot in active:
+                state = self._slots[slot]
+                for _ in range(consumed[slot]):
+                    state.pending.popleft()
+                state.prompt_filled += consumed[slot]
+                prefill_tokens += consumed[slot]
+                n = int(counts[slot])
+                decode_tokens += n
+                state.kv_len += int(n_known[slot]) + n
+                if self.kv_layout == "paged":
+                    # Valid rows this tick: the replayed prefix + the
+                    # emitted tokens; rejected window rows beyond stay dead.
+                    self._lengths[slot] += int(n_known[slot]) + n
+                    if self._chunked and consumed[slot]:
+                        self._register_chunk_prefix(state)
+                if proposed[slot]:
+                    accepted_drafts = min(max(n - 1, 0), proposed[slot])
+                    self._spec_proposed += proposed[slot]
+                    self._spec_accepted += accepted_drafts
+                    self._registry.counter(
+                        "serving/spec_proposed_tokens_total"
+                    ).inc(proposed[slot])
+                    if accepted_drafts:
+                        self._registry.counter(
+                            "serving/spec_accepted_tokens_total"
+                        ).inc(accepted_drafts)
+                if n:
+                    accepts[state.request.id] = n
+                    self._registry.histogram(
+                        "serving/accepted_tokens_per_step"
+                    ).observe(n)
+                for j in range(n):
+                    token = int(emitted[slot, j])
+                    state.last_token = token
+                    state.emitted += 1
+                    state.context.append(token)
+                    first = state.response.first_token_at is None
+                    state.response._push(token)
+                    if first:
+                        self._observe_ttft(state)
+                    elif state.last_emit_at is not None:
+                        # Tokens landing in the same tick (accepted drafts)
+                        # record a ~0 gap — they really do arrive together.
+                        self._registry.histogram(
+                            "serving/inter_token_latency_ms"
+                        ).observe((now - state.last_emit_at) * 1e3)
+                    state.last_emit_at = now
+                    eos = state.request.params.eos_token
+                    if eos is not None and token == eos:
+                        self._retire(slot, FINISH_EOS, retired)
+                        break
+                    if state.emitted >= state.request.params.max_new_tokens:
+                        self._retire(slot, FINISH_LENGTH, retired)
+                        break
+            if self._spec_proposed:
+                self._registry.gauge("serving/spec_accept_rate").set(
+                    self._spec_accepted / self._spec_proposed
+                )
+            self._account_tokens(prefill_tokens, decode_tokens)
+            emit_span.args.update(
+                tokens=decode_tokens, retired=len(retired) - was_retired
             )
-        self._account_tokens(prefill_tokens, decode_tokens)
         return accepts
 
     def _register_chunk_prefix(self, state: _Slot) -> None:
@@ -1519,6 +1735,7 @@ class SlotScheduler:
             getattr(state.request, "tier", DEFAULT_TIER)
         )
         state.response._finish(reason)
+        self._record_request(state.request, reason, state, slot)
         retired.append((state.request.id, reason))
         self._registry.counter(
             "serving/requests_completed_total", reason=reason
@@ -1554,7 +1771,8 @@ class SlotScheduler:
                 self._fail_inflight(FINISH_ERROR)
                 continue
             if not worked:
-                self._work.wait(IDLE_POLL_S)
+                with telemetry.span("serving/idle_wait"):
+                    self._work.wait(IDLE_POLL_S)
                 self._work.clear()
 
     def _fail_inflight(self, reason: str) -> None:
@@ -1624,8 +1842,17 @@ class SlotScheduler:
             "decode_attention": self.decode_attention,
             "prefill_chunk": self.prefill_chunk,
             "prefill_budget_per_tick": self.prefill_budget_per_tick,
+            # prefill_tokens: prompt tokens REPLAYED through the step
+            # program (one a tick, or a chunk); prefilled_tokens: prompt
+            # tokens through the blocking prefill programs.
             "prefill_tokens": self._prefill_tokens,
             "decode_tokens": self._decode_tokens,
+            "prefilled_tokens": self._prefilled_tokens,
+            "kv_token_steps": self._kv_token_steps,
+            "slot_steps": self._slot_steps,
+            "slow_steps": self._slow_steps,
+            "slow_step_seconds": round(self._slow_step_seconds, 6),
+            "slowest_step": self._slowest_step,
             "peak_streams": self._peak_streams,
             "retire_rate_per_s": round(self._estimator.retire_rate(), 4),
         }

@@ -25,6 +25,11 @@ framework and the protocol is deliberately tiny:
   base64 ndarray leaves — int8 pools ship quantized); POST installs a
   peer's export into the local pool + prefix cache. Paged layout only
   (409 otherwise).
+* ``POST /debug/profile`` — body ``{"seconds": S}``: one XLA profiler
+  capture of S seconds (at most 120) through `telemetry.profile`, into
+  ``TPU_YARN_PROFILE`` or ``<model_dir>/profile``; answers ``{"dir",
+  "sync_perf_s", "seconds"}`` once the trace is written, 409 while
+  another capture runs (docs/Observability.md).
 
 `run_serving` is the task program body (tasks/serving.py): restore the
 checkpoint exactly as batch inference does, build the shared
@@ -38,6 +43,7 @@ from __future__ import annotations
 import base64
 import json
 import logging
+import os
 import socket
 import threading
 import time
@@ -122,8 +128,10 @@ class ServingServer:
     client never blocks admissions."""
 
     def __init__(self, scheduler: SlotScheduler, host: str = "127.0.0.1",
-                 port: int = 0, *, slo_evaluator=None, prefill_client=None):
-        handler = _make_handler(scheduler, slo_evaluator, prefill_client)
+                 port: int = 0, *, slo_evaluator=None, prefill_client=None,
+                 profile_dir: Optional[str] = None):
+        handler = _make_handler(scheduler, slo_evaluator, prefill_client,
+                                profile_dir)
         self._httpd = ThreadingHTTPServer((host, port), handler)
         self._httpd.daemon_threads = True
         self._lifecycle = threading.Lock()
@@ -165,7 +173,7 @@ class ServingServer:
 
 
 def _make_handler(scheduler: SlotScheduler, slo_evaluator=None,
-                  prefill_client=None):
+                  prefill_client=None, profile_dir: Optional[str] = None):
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
 
@@ -277,6 +285,9 @@ def _make_handler(scheduler: SlotScheduler, slo_evaluator=None,
                     return
                 self._json(200, result)
                 return
+            if self.path == "/debug/profile":
+                self._profile()
+                return
             if self.path != "/v1/generate":
                 self._json(404, {"error": f"unknown path {self.path}"})
                 return
@@ -331,7 +342,7 @@ def _make_handler(scheduler: SlotScheduler, slo_evaluator=None,
                 with telemetry.span(
                     "serving/submit", request_id=trace_id,
                     prompt_tokens=len(prompt),
-                ):
+                ) as submit_span:
                     response = scheduler.submit(
                         prompt, params,
                         priority=int(body.get("priority", 0)),
@@ -339,6 +350,10 @@ def _make_handler(scheduler: SlotScheduler, slo_evaluator=None,
                         tier=str(body.get("tier", DEFAULT_TIER)),
                         trace_id=trace_id,
                     )
+                    # The program's own id where the caller sent none:
+                    # one id on every span and record of this request.
+                    submit_span.args["request_id"] = \
+                        response.request.public_id
             except QueueFull as exc:
                 # Backpressure crosses the wire as a 429 + Retry-After:
                 # the client sheds or retries, the server never buffers
@@ -396,6 +411,32 @@ def _make_handler(scheduler: SlotScheduler, slo_evaluator=None,
             }, headers=(
                 (("X-Request-Id", trace_id),) if trace_id else ()
             ))
+
+        def _profile(self):
+            directory = os.environ.get(telemetry.profile.PROFILE_ENV) \
+                or profile_dir
+            if not directory:
+                self._json(409, {"error": (
+                    "nowhere to write a profile: set "
+                    f"{telemetry.profile.PROFILE_ENV} or serve from a "
+                    "model_dir"
+                )})
+                return
+            try:
+                length = int(self.headers.get("Content-Length", "0"))
+                body = json.loads(self.rfile.read(length) or b"{}")
+                seconds = float(body.get("seconds", 1.0))
+            except (TypeError, ValueError) as exc:
+                self._json(400, {"error": f"bad request: {exc}"})
+                return
+            try:
+                # On this connection's thread: the answer comes when the
+                # trace is on disk, and the scheduler never waits for it.
+                result = telemetry.profile.capture(directory, seconds)
+            except telemetry.profile.ProfileBusy as exc:
+                self._json(409, {"error": str(exc)})
+                return
+            self._json(200, result)
 
     return Handler
 
@@ -507,6 +548,8 @@ def run_serving(experiment, runtime=None) -> dict:
     server = ServingServer(
         scheduler, experiment.host, experiment.port,
         slo_evaluator=slo_evaluator, prefill_client=prefill_client,
+        profile_dir=(os.path.join(experiment.model_dir, "profile")
+                     if experiment.model_dir else None),
     )
     scheduler.start()
     endpoint = server.start()

@@ -7,6 +7,9 @@ One subsystem behind every observability surface in the framework
   tracing with a ring buffer, a JSONL sink, and a Chrome/Perfetto
   ``trace_event`` exporter (``TPU_YARN_TRACE=<dir>`` →
   ``trace_<task>.json``).
+* :mod:`~tf_yarn_tpu.telemetry.profile` — the one start/stop of the
+  XLA profiler (train loop window, serving ``POST /debug/profile``),
+  tied to the span clock by a ``tf_yarn_tpu/clock_sync`` annotation.
 * :mod:`~tf_yarn_tpu.telemetry.registry` — process-global
   counters/gauges/histograms with labels, snapshot-able as a dict and
   flushed to the log, MLflow, and the coordination KV store.
@@ -31,6 +34,7 @@ from tf_yarn_tpu.telemetry.exposition import (  # noqa: F401
     render_prometheus,
     signals_block,
 )
+from tf_yarn_tpu.telemetry import profile  # noqa: F401
 from tf_yarn_tpu.telemetry.heartbeat import Heartbeat  # noqa: F401
 from tf_yarn_tpu.telemetry.registry import (  # noqa: F401
     Counter,
@@ -82,6 +86,7 @@ __all__ = [
     "get_registry",
     "get_tracer",
     "parse_slo",
+    "profile",
     "render_prometheus",
     "signals_block",
     "span",

@@ -30,6 +30,7 @@ instrumented call site).
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import logging
 import os
@@ -46,6 +47,18 @@ TRACE_BUFFER_ENV = "TPU_YARN_TRACE_BUFFER"
 DEFAULT_CAPACITY = 100_000
 
 _clock = time.perf_counter  # monotonic; patchable in tests
+_SPAN_IDS = itertools.count(1)  # next() is atomic under the GIL
+
+# Depth and thread of a span inserted by `Tracer.record`: it sat on no
+# thread's stack, so "the deepest span over an instant" never picks it
+# over a span that did.
+RECORD_DEPTH = -1
+RECORD_THREAD = "records"
+
+
+def now() -> float:
+    """The tracer's clock: what `Tracer.record` start times are on."""
+    return _clock()
 
 
 class Span:
@@ -54,15 +67,21 @@ class Span:
     after the block (the train loop's interval breakdown does)."""
 
     __slots__ = ("name", "category", "args", "start", "duration",
-                 "thread_id", "thread_name", "depth", "parent")
+                 "thread_id", "thread_name", "depth", "parent", "id",
+                 "parent_id")
 
     def __init__(self, name: str, category: str, args: Dict[str, Any],
-                 depth: int, parent: Optional[str]) -> None:
+                 depth: int, parent: Optional["Span"]) -> None:
         self.name = name
         self.category = category
         self.args = args
         self.depth = depth
-        self.parent = parent
+        # The enclosing span on this thread: its name (as ever) and its
+        # id, unique in the process, so that two spans of one name are
+        # told apart.
+        self.parent = parent.name if parent is not None else None
+        self.parent_id = parent.id if parent is not None else None
+        self.id = next(_SPAN_IDS)
         thread = threading.current_thread()
         self.thread_id = thread.ident or 0
         self.thread_name = thread.name
@@ -79,6 +98,8 @@ class Span:
             "thread": self.thread_name,
             "depth": self.depth,
             "parent": self.parent,
+            "id": self.id,
+            "parent_id": self.parent_id,
             "args": self.args,
         }
 
@@ -130,7 +151,22 @@ class Tracer:
         """Context manager timing its body; yields the :class:`Span`."""
         return _SpanContext(self, name, category, args)
 
-    def _stack(self) -> List[str]:
+    def record(self, name: str, start: float, duration: float,
+               category: str = "host", **args: Any) -> Span:
+        """Insert a finished span whose life crossed ticks and threads
+        (a request from submit to finish). `start` is a reading of this
+        module's clock (:func:`now`), never `time.monotonic()`. The span
+        belongs to no thread's stack: `RECORD_DEPTH`, no parent, and the
+        `RECORD_THREAD` row of the Chrome export."""
+        span = Span(name, category, args, depth=RECORD_DEPTH, parent=None)
+        span.start = start
+        span.duration = duration
+        span.thread_id = 0
+        span.thread_name = RECORD_THREAD
+        self._append(span)
+        return span
+
+    def _stack(self) -> List[Span]:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
@@ -140,7 +176,7 @@ class Tracer:
         stack = self._stack()
         span = Span(name, category, args, depth=len(stack),
                     parent=stack[-1] if stack else None)
-        stack.append(name)
+        stack.append(span)
         return span
 
     def _end(self, span: Span, error: bool = False) -> None:
@@ -148,8 +184,11 @@ class Tracer:
         if error:
             span.args = dict(span.args, error=True)
         stack = self._stack()
-        if stack and stack[-1] == span.name:
+        if stack and stack[-1] is span:
             stack.pop()
+        self._append(span)
+
+    def _append(self, span: Span) -> None:
         with self._lock:
             self._buffer.append(span)
             sinks = list(self._sinks)
@@ -220,7 +259,8 @@ class Tracer:
                 "dur": span.duration * 1e6,
                 "pid": pid,
                 "tid": span.thread_id,
-                "args": dict(span.args, depth=span.depth),
+                "args": dict(span.args, depth=span.depth, id=span.id,
+                             parent_id=span.parent_id),
             })
         meta = [
             {"name": "thread_name", "ph": "M", "pid": pid, "tid": tid,

@@ -441,7 +441,7 @@ class _ProfileWindow:
     """
 
     def __init__(self):
-        self.dir = os.environ.get("TPU_YARN_PROFILE")
+        self.dir = os.environ.get(telemetry.profile.PROFILE_ENV)
         self.start_step = 0
         self.stop_step = None
         self.active = False
@@ -490,26 +490,20 @@ class _ProfileWindow:
         in_window = next_step >= self.start_step and (
             self.stop_step is None or next_step < self.stop_step)
         if in_window and not self.active:
-            from jax import profiler
-
-            profiler.start_trace(self.dir)
+            telemetry.profile.start(self.dir, python_tracer=True)
             self.active = True
-            _logger.info("profiler capture started (step %d) -> %s",
-                         next_step, self.dir)
+            _logger.info("profiler capture started at step %d", next_step)
         elif self.active and not in_window:
             self.stop(state)
 
     def stop(self, state=None) -> None:
         if not self.active:
             return
-        from jax import profiler
-
         if state is not None:
             # Flush in-flight device work so the trace covers it.
             jax.block_until_ready(state.params)
-        profiler.stop_trace()
+        telemetry.profile.stop()
         self.active = False
-        _logger.info("profiler trace written to %s", self.dir)
 
 
 class _UploadingTbWriter:

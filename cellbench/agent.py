@@ -19,31 +19,27 @@ The parent reaches the door at 127.0.0.1:<agent_port>:
 
 from __future__ import annotations
 
+import importlib
 import json
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 
-def model_config(sizes: dict, context: int, overrides: dict):
-    """The program's configuration at the file's sizes. What the file does
-    not name stays at the program's default."""
-    from tf_yarn_tpu.models.transformer import TransformerConfig
-
-    if sizes["num_attention_heads"] * sizes["head_dim"] != sizes["hidden_size"]:
-        raise ValueError("the program derives head_dim from hidden_size")
-    return TransformerConfig(
-        vocab_size=sizes["vocab_size"], d_model=sizes["hidden_size"],
-        n_layers=sizes["num_hidden_layers"],
-        n_heads=sizes["num_attention_heads"],
-        n_kv_heads=sizes["num_key_value_heads"],
-        d_ff=sizes["intermediate_size"], max_seq_len=context,
-        rope_theta=float(sizes["rope_theta"]),
-        norm_eps=float(sizes["rms_norm_eps"]), **overrides,
-    )
+def adapter(config: dict):
+    """`cellbench/programs/<config["program"]>.py`: `model(config, context,
+    overrides)` builds the program's model, `plain_name(path)` names a leaf
+    of its tree as the configuration's weight table does."""
+    return importlib.import_module("cellbench.programs." + config["program"])
 
 
-def program_variables(model, sizes: dict, seed: int):
+def build_model(config: dict):
+    """The program's model of a configuration, as its adapter builds it."""
+    return adapter(config).model(
+        config, config["serving"]["context"], config.get("model", {}))
+
+
+def program_variables(model, config: dict, seed: int):
     """The seeded weights in the program's own tree and types."""
     import jax
     import jax.numpy as jnp
@@ -57,16 +53,10 @@ def program_variables(model, sizes: dict, seed: int):
         jax.ShapeDtypeStruct((1, 8), jnp.int32),
     ))
 
-    def plain_name(path):
-        keys = [getattr(k, "key", str(k)) for k in path]
-        layer = next((int(k.split("_")[1]) for k in keys
-                      if k.startswith("layer_")), None)
-        name = keys[-1] if keys[-1] in ("embedding", "lm_head") else keys[-2]
-        return name, layer
-
+    plain_name = adapter(config).plain_name
     leaves = jax.tree_util.tree_leaves_with_path(abstract)
     dtypes = {plain_name(path)[0]: leaf.dtype for path, leaf in leaves}
-    flat = weights_lib.make(sizes, seed, dtypes)
+    flat = weights_lib.make(config, seed, dtypes)
 
     def place(path, leaf):
         name, layer = plain_name(path)
@@ -86,23 +76,22 @@ def serving_experiment(spec: dict):
     """Built in the task: the ServingExperiment of one configuration."""
     from tf_yarn_tpu import inference, telemetry
     from tf_yarn_tpu.experiment import ServingExperiment
-    from tf_yarn_tpu.models.transformer import Transformer
 
     Door(spec["agent_port"], spec["trace_dir"]).start()
-    model = Transformer(model_config(
-        spec["sizes"], spec["serving"]["context"], spec.get("model", {})))
+    config = spec["config"]
+    model = build_model(config)
 
     def seeded(model_dir, step):
         with telemetry.span("cellbench/seeded_weights"):
             import jax
 
-            variables = program_variables(model, spec["sizes"], spec["seed"])
+            variables = program_variables(model, config, spec["seed"])
             jax.block_until_ready(variables)
         return variables, 0
 
     # run_serving has no other way in for parameters than a checkpoint.
     inference._restore_params = seeded
-    serving = {k: v for k, v in spec["serving"].items() if k != "context"}
+    serving = {k: v for k, v in config["serving"].items() if k != "context"}
     return ServingExperiment(
         model=model, model_dir=spec["run_dir"], host="127.0.0.1",
         port=spec["port"], **serving,

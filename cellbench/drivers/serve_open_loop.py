@@ -7,13 +7,14 @@ from cellbench import serve
 
 
 def run(cell: dict) -> dict:
-    def offer(calls, port, opened, stop):
+    def offer(requests, calls, port, window, stop):
         def one(call):
-            call.due = opened + call.request["due_s"]
+            call.due = window[0] + call.request["due_s"]
             serve.sleep_until(call.due)
             if not stop.is_set():
                 call.send(port, stop)
 
+        calls.extend(serve.Call(request) for request in requests)
         return [serve.start_thread(one, call) for call in calls]
 
     return serve.run_cell(cell, offer)

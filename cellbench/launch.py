@@ -15,6 +15,7 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CACHE_DIR = os.path.join(ROOT, "cellbench_cache")
+ASK_AGAIN_S = 3.0  # between two looks at whether a SIGTERM was heard
 
 
 def task_env(run_dir: str) -> dict:
@@ -85,15 +86,38 @@ class Launch:
                         out.append(f"\n--- {name}\n" + "".join(fh.readlines()[-n:]))
         return "".join(out)
 
-    def stop(self, timeout=120.0):
-        for pid in self.backend.handle.pids().values():
-            with contextlib.suppress(ProcessLookupError):
-                os.kill(pid, signal.SIGTERM)
-        self._thread.join(timeout=timeout)
+    def stop(self, port=None, timeout=120.0):
+        """SIGTERM to every task, then wait. The signal can miss the program's
+        handler (PERF.md, Open questions: the kernel may hand it to a thread
+        other than the main one, which alone runs Python's handlers and sleeps
+        in an untimed join). So while the server at `port` still answers
+        `/healthz` with "ok", which reads the flag that the handler sets, the
+        signal is sent again. `self.stopping` keeps how often it was sent."""
+        pids = self.backend.handle.pids()
+        self.stopping = {"pids": pids, "sent": 0}
+        deadline, heard = time.monotonic() + timeout, False
+        while self._thread.is_alive() and time.monotonic() < deadline:
+            if not heard:
+                for pid in pids.values():
+                    with contextlib.suppress(ProcessLookupError):
+                        os.kill(pid, signal.SIGTERM)
+                self.stopping["sent"] += 1
+            self._thread.join(timeout=ASK_AGAIN_S)
+            heard = port is None or draining(port)
         if self._thread.is_alive():
-            raise RuntimeError(f"the task did not stop within {timeout:.0f}s")
+            raise RuntimeError(
+                f"the task did not stop within {timeout:.0f}s of SIGTERM, "
+                f"sent {self.stopping['sent']} times to {pids}")
         if self.error is not None:
             raise self.error
+
+
+def draining(port) -> bool:
+    """Whether the server's SIGTERM handler has run: it drains, or is gone."""
+    try:
+        return http_json(port, "GET", "/healthz", timeout=5.0)["status"] != "ok"
+    except (OSError, http.client.HTTPException, RuntimeError, ValueError):
+        return True
 
 
 def free_port() -> int:

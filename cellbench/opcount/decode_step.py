@@ -12,7 +12,7 @@ for it, and its share says so.
 """
 
 from cellbench.readers._spans import live_tokens_per_step
-from cellbench.weights import shapes
+from cellbench.weights import table
 
 ITEMSIZE = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1,
             "float8_e4m3fn": 1, "float8_e5m2": 1, "int4": 0.5}
@@ -36,7 +36,7 @@ def count(run):
     layers, hd = sizes["num_hidden_layers"], sizes["head_dim"]
     kv_heads, heads = sizes["num_key_value_heads"], sizes["num_attention_heads"]
     weight_bytes = weight_elements = 0
-    for name, (shape, std) in shapes(sizes).items():
+    for name, (shape, std) in table(sizes).items():
         if std is None or name == "embedding":
             continue
         item = _itemsize(live, shape)
@@ -53,7 +53,7 @@ def count(run):
         return None
     kv_item = ITEMSIZE[max(kv, key=lambda a: _elements(a["shape"]))["dtype"]]
     row = layers * 2 * kv_heads * hd * kv_item  # one token's keys and values
-    embed_item = _itemsize(live, shapes(sizes)["embedding"][0]) or 4
+    embed_item = _itemsize(live, table(sizes)["embedding"][0]) or 4
     return {
         "bytes": weight_bytes + (tokens + slots) * row
         + slots * sizes["hidden_size"] * embed_item,

@@ -5,7 +5,9 @@
 Everything that belongs to one cell is found by name: the workload's entry
 in BENCHMARK.json names a configuration (its `file`) and a traffic mix
 (`cellbench/traffic/<traffic>.json`), the traffic names its driver
-(`cellbench/drivers/<driver>.py`), and each per-layer metric has a file
+(`cellbench/drivers/<driver>.py`), the configuration names its weight table,
+its program adapter and its reference (`cellbench/weight_tables/`,
+`programs/`, `reference/`), and each per-layer metric has a file
 `cellbench/metrics/<name>.json` that names its reader
 (`cellbench/readers/<reader>.py`) and the reader's arguments; a metric
 `<quantity>.<cell suffix>` without a file of its own takes

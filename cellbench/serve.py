@@ -37,7 +37,7 @@ class Call:
     def __init__(self, request: dict):
         self.request = request
         self.due = None       # when it was due to be sent (open loop)
-        self.sent = None
+        self.sent = None      # when it was sent (closed loop: taken from the list)
         self.arrivals = []    # one per token
         self.tokens = []
         self.status = "unsent"  # ok | refused | failed | cut
@@ -49,7 +49,8 @@ class Call:
             "max_new_tokens": self.request["max_new_tokens"],
             "stream": True,
         })
-        self.sent = time.perf_counter()
+        if self.sent is None:
+            self.sent = time.perf_counter()
         conn = http.client.HTTPConnection("127.0.0.1", port, timeout=600.0)
         try:
             conn.request("POST", "/v1/generate", body, {
@@ -182,28 +183,33 @@ def run_check(cell: dict, samples: list, lower=None) -> dict:
     return result
 
 
+def task_spec(cell: dict, port: int, agent_port: int) -> dict:
+    """What the task is handed (agent.serving_experiment): the whole
+    configuration file, since an adapter may need its lists and strings."""
+    return {
+        "config": cell["config"], "seed": cell["seed"], "port": port,
+        "agent_port": agent_port, "run_dir": cell["run_dir"],
+        "trace_dir": os.path.join(cell["run_dir"], "profile"),
+    }
+
+
 def run_cell(cell: dict, offer) -> dict:
-    """`offer(calls, port, opened, stop)` starts the threads that send the
-    requests and returns them; everything else is the same for every mix."""
+    """`offer(requests, calls, port, (opened, closed), stop)` starts the
+    threads that send the requests, puts a `Call` into `calls` for each one it
+    takes, and returns the threads; everything else is the same for every
+    mix."""
     from tf_yarn_tpu.topologies import NodeLabel, TaskSpec
 
     config, traffic = cell["config"], cell["traffic"]
     seconds = float(cell["seconds"])
     requests = traffic_lib.requests(
         traffic, config["vocab_size"], cell["seed"], seconds)
-    calls = [Call(r) for r in requests]
+    calls = []
     run_dir = cell["run_dir"]
     shutil.rmtree(run_dir, ignore_errors=True)
     os.makedirs(run_dir)
     port, agent_port = launch_lib.free_port(), launch_lib.free_port()
-    spec = {
-        "sizes": {k: v for k, v in config.items()
-                  if isinstance(v, (int, float)) and not isinstance(v, bool)},
-        "serving": config["serving"], "model": config.get("model", {}),
-        "seed": cell["seed"], "port": port,
-        "agent_port": agent_port, "run_dir": run_dir,
-        "trace_dir": os.path.join(run_dir, "profile"),
-    }
+    spec = task_spec(cell, port, agent_port)
     from cellbench import agent
 
     task = (TaskSpec(instances=1, chips_per_host=cell["chips"],
@@ -233,7 +239,7 @@ def run_cell(cell: dict, offer) -> dict:
         opened = time.perf_counter() + float(traffic.get("lead_in_s", 0)) \
             + OPEN_MARGIN_S
         closed = opened + seconds
-        threads = offer(calls, port, opened, stop)
+        threads = offer(requests, calls, port, (opened, closed), stop)
         sleep_until(opened)
         launch.check()
         stats_open = server("GET", "/stats")
@@ -256,12 +262,10 @@ def run_cell(cell: dict, offer) -> dict:
                 if cell["require_chip"]:  # only a CPU rehearsal has no device plane
                     raise
         stop.set()
-        launch.stop()
+        launch.stop(port)
         task_log = launch.log_tail(400)
     for thread in threads:
         thread.join(timeout=30)
-    if "list_length" in traffic and calls[-1].sent is not None:
-        raise RuntimeError("the request list ran out: make list_length longer")
     client = client_numbers(calls, (opened, closed), seconds)
     samples = pick_sample(calls, closed, cell["seed"],
                           config["check"]["sample"],
@@ -296,6 +300,18 @@ def run_cell(cell: dict, offer) -> dict:
         "config": config, "traffic": traffic, "task_log": task_log,
         "run_dir": run_dir,
         "notes": {"check_s": time.perf_counter() - check_began,
+                  "sent": len(calls),
+                  "sent_after_close": sum(c.sent >= closed for c in calls
+                                          if c.sent is not None),
+                  "stopping": launch.stopping,
+                  # a run that reads far off says why: model steps over twice
+                  # the median, as the program counts them (0 before PR 25)
+                  "slow_steps_in_window": stats_close.get("slow_steps", 0)
+                  - stats_open.get("slow_steps", 0),
+                  "slow_step_seconds_in_window":
+                  stats_close.get("slow_step_seconds", 0.0)
+                  - stats_open.get("slow_step_seconds", 0.0),
+                  "slowest_step": stats_close.get("slowest_step"),
                   "sampled_requests": len(samples),
                   "member_prompt_lengths": client["member_prompt_lengths"]},
     }

@@ -36,7 +36,7 @@ def _altered_token_experiment(spec):
     altered where the step produces it."""
     from tf_yarn_tpu.models.decode_engine import DecodeEngine
 
-    sound, vocab = DecodeEngine.paged_step, spec["sizes"]["vocab_size"]
+    sound, vocab = DecodeEngine.paged_step, spec["config"]["vocab_size"]
 
     def broken(self, *args, **kwargs):
         pool, emitted, rngs = sound(self, *args, **kwargs)

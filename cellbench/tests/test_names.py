@@ -1,12 +1,18 @@
 """BENCHMARK.json and the files it names keep to the contract's letters and
 lengths, and every per-layer metric sits in cells that report what it moves."""
 
+import hashlib
 import importlib
 import json
 import os
+import pickle
 import re
+import sys
+import types
 
-from cellbench import run
+import numpy as np
+
+from cellbench import agent, check, run, serve, weights
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 HERE = os.path.join(ROOT, "cellbench")
@@ -53,6 +59,11 @@ def test_every_cell_is_found_by_name():
         with open(os.path.join(HERE, "traffic", cell["traffic"] + ".json")) as fh:
             mix = json.load(fh)
         importlib.import_module("cellbench.drivers." + mix["driver"])
+        sizes = run.load_json(ROOT, configs[cell["config"]]["file"])
+        assert set(weights.table(sizes)) >= {"embedding"}
+        assert callable(agent.adapter(sizes).model)
+        assert callable(agent.adapter(sizes).plain_name)
+        importlib.import_module("cellbench.reference." + sizes["check"]["reference"])
     pairs = [(c["config"], c["traffic"]) for c in BENCH["workloads"]]
     assert len(pairs) == len(set(pairs))
     used = {c["config"] for c in BENCH["workloads"]}
@@ -118,3 +129,113 @@ def test_an_unknown_device_kind_is_an_error():
         peaks = json.load(fh)
     for kind, row in peaks.items():
         assert row["source"] and row["bf16_flops_per_s"] > 0 and row["hbm_bytes_per_s"] > 0
+
+
+# -- an architecture is found by name: weight table and program adapter ------
+
+with open(os.path.join(HERE, "tests", "data", "tiny_serve.json")) as fh:
+    TINY = json.load(fh)
+with open(os.path.join(HERE, "tests", "data", "as_before.json")) as fh:
+    AS_BEFORE = json.load(fh)
+
+
+def _sha256(leaves):
+    digest = hashlib.sha256()
+    for leaf in leaves:
+        digest.update(np.asarray(leaf, np.float32).tobytes())
+    return digest.hexdigest()
+
+
+def _flat(made):
+    return [leaf for value in made.values()
+            for leaf in (value if isinstance(value, list) else [value])]
+
+
+def test_seeded_leaves_are_what_they_were():
+    """Recorded on the tree before the table moved to a file of its own
+    (PR 28): every leaf of the tiny configuration, bit for bit."""
+    import jax.numpy as jnp
+
+    was = AS_BEFORE["leaves"]
+    made = weights.make(TINY, AS_BEFORE["seed"])
+    assert list(made) == list(was["per_name"])  # the order is the key's index
+    for name, value in made.items():
+        leaves = value if isinstance(value, list) else [value]
+        assert _sha256(leaves)[:16] == was["per_name"][name], name
+    assert _sha256(_flat(made)) == was["sha256"]
+    kept = weights.make(TINY, AS_BEFORE["seed"],
+                        {"wq": jnp.bfloat16, "embedding": jnp.bfloat16})
+    assert kept["wq"][0].dtype == jnp.bfloat16
+    assert _sha256(_flat(kept)) == was["sha256_wq_and_embedding_bfloat16"]
+
+
+def test_the_comparison_reads_a_stored_sample_as_it_did():
+    reference = importlib.import_module(
+        "cellbench.reference." + TINY["check"]["reference"])
+    made = weights.make(TINY, AS_BEFORE["seed"])
+    for lower, was in ((None, AS_BEFORE["check"]), ("int8", AS_BEFORE["check_int8"])):
+        now = check.summary(check.gaps(reference, made, TINY,
+                                       AS_BEFORE["samples"], lower=lower))
+        assert now["tokens"] == was["tokens"]
+        for name, value in was.items():
+            assert abs(now[name] - value) <= 1e-6 * max(1.0, abs(value)), name
+
+
+def _module(name, **members):
+    module = types.ModuleType(name)
+    vars(module).update(members)
+    return module
+
+
+def test_a_table_names_its_initialisers(monkeypatch):
+    """What a state-space layer needs: positive rates uniform in the
+    logarithm, a constant, and a function of the table's own."""
+    import jax
+
+    def ramp(key, shape):
+        return jax.numpy.arange(shape[0], dtype="float32")
+
+    monkeypatch.setitem(sys.modules, "cellbench.weight_tables.other", _module(
+        "cellbench.weight_tables.other", SINGLE=("skip", "ramp"),
+        shapes=lambda config: {
+            "rate": ((config["layers"], 64), ("log_uniform", 0.001, 0.1)),
+            "skip": ((8,), ("constant", 1.0)),
+            "proj": ((config["layers"], 8, 4), 0.5),
+            "ramp": ((5,), ramp),
+        }))
+    config = {"weights": "other", "layers": 3}
+    made = weights.make(config, 3_000_000_019)
+    assert len(made["rate"]) == 3 and made["rate"][0].shape == (64,)
+    rates = np.stack(made["rate"])
+    assert (rates >= 0.001).all() and (rates <= 0.1).all()
+    assert rates.min() < 0.003 and rates.max() > 0.03  # spread over the decades
+    assert not (rates[0] == rates[1]).all()
+    assert (np.asarray(made["skip"]) == 1.0).all()
+    assert made["proj"][2].shape == (8, 4)
+    assert list(np.asarray(made["ramp"])) == [0, 1, 2, 3, 4]
+    again = weights.make(config, 3_000_000_019)
+    assert (np.stack(again["rate"]) == rates).all()
+    assert (np.stack(weights.make(config, 3_000_000_020)["rate"]) != rates).any()
+
+
+def test_the_whole_configuration_reaches_the_adapter(monkeypatch):
+    """Lists and strings too, through the pickle that carries the task's
+    function: `layer_types` decides what the adapter builds."""
+    built = []
+    monkeypatch.setitem(sys.modules, "cellbench.programs.other", _module(
+        "cellbench.programs.other",
+        model=lambda config, context, overrides: built.append(
+            (config, context, overrides)) or "the model",
+        plain_name=lambda path: ("leaf", None)))
+    config = dict(TINY, program="other", layer_types=["mamba", "attention"],
+                  position_embedding_type="nope")
+    spec = serve.task_spec({"config": config, "seed": 5, "run_dir": "/nowhere"},
+                           1, 2)
+    spec = pickle.loads(pickle.dumps(spec))
+    assert agent.build_model(spec["config"]) == "the model"
+    (seen, context, overrides), = built
+    assert seen["layer_types"] == ["mamba", "attention"]
+    assert seen["position_embedding_type"] == "nope"
+    assert seen["serving"] == TINY["serving"] and context == 128
+    assert overrides == {"scan_layers": False}
+    assert agent.adapter(spec["config"]).plain_name(()) == ("leaf", None)

@@ -16,11 +16,12 @@ under every seed:
   member the first `member_share` of the requests due in [0, seconds);
   tail   the rest of the window: load, and gaps between tokens, but due too
          near the close for a first token to be counted on.
-A closed loop has one group, `list`: `list_length` requests in list order,
-which callers take one after another; none is due at a time. The list is made
-of blocks of `block` requests, each block a whole set of strata in an order
-of its own, so that however far a run gets through the list, it has met
-nearly the same lengths. The order of lengths in the list comes from the
+A closed loop has one group, `list`: requests in list order, which callers
+take one after another; none is due at a time. The list has no end: it is
+made block by block as callers take it, each block `block` requests, a whole
+set of strata in an order of its own, so that however far a run gets through
+the list, it has met nearly the same lengths, and however fast the program
+is, the list does not run out. The order of lengths in the list comes from the
 file's `order_seed` and not from `--seed`: a batch job is the same job under
 every seed, which changes the token ids (and the weights) alone. (Drawn from
 `--seed`, which long prompts happen to hold a slot during the window moves
@@ -55,9 +56,8 @@ def strata(dist: dict, n: int) -> list:
 
 
 def counts(traffic: dict, seconds: float) -> dict:
-    """Sizes of the groups: a function of the file and the seconds alone."""
-    if "rate_per_s" not in traffic:
-        return {"list": int(traffic["list_length"])}
+    """Sizes of an open loop's groups: a function of the file and the seconds
+    alone. (A closed loop's list has no size: see `requests`.)"""
     n = int(round(traffic["rate_per_s"] * seconds))
     num, den = traffic.get("member_share", [2, 3])
     members = n * num // den
@@ -69,42 +69,55 @@ def _arrivals(rng, n: int, start: float, end: float) -> list:
     return sorted(float(x) for x in start + (end - start) * rng.random(n))
 
 
-def requests(traffic: dict, vocab: int, seed: int, seconds: float) -> list:
-    """[{index, group, due_s, prompt, max_new_tokens}], in due (or list)
-    order. `due_s` counts from the opening of the window; None in a closed
-    loop."""
+def requests(traffic: dict, vocab: int, seed: int, seconds: float):
+    """[{index, group, due_s, prompt, max_new_tokens}], in due order, with
+    `due_s` counted from the opening of the window; for a closed loop (no
+    `rate_per_s`) an iterator over its list that never ends, with `due_s`
+    None."""
     rng = np.random.default_rng([int(seed), 0x63656C6C])
+    if "rate_per_s" not in traffic:
+        return _listed(traffic, vocab, rng)
     sizes = counts(traffic, seconds)
+    window = _arrivals(rng, sizes["member"] + sizes["tail"], 0.0, seconds)
+    due = {
+        "lead": _arrivals(rng, sizes["lead"],
+                          -float(traffic.get("lead_in_s", 0)), 0.0),
+        "member": window[:sizes["member"]],
+        "tail": window[sizes["member"]:],
+    }
     out = []
-    if "list" in sizes:
-        due = {"list": [None] * sizes["list"]}
-    else:
-        window = _arrivals(rng, sizes["member"] + sizes["tail"], 0.0, seconds)
-        due = {
-            "lead": _arrivals(rng, sizes["lead"],
-                              -float(traffic.get("lead_in_s", 0)), 0.0),
-            "member": window[:sizes["member"]],
-            "tail": window[sizes["member"]:],
-        }
     for group, times in due.items():
-        n = len(times)
-        block, order = n, rng
-        if group == "list":
-            block = int(traffic.get("block", n))
-            order = np.random.default_rng(
-                [int(traffic["order_seed"]), 0x6F726472])
-        prompts, outputs = [], []
-        for start in range(0, n, max(block, 1)):
-            size = min(block, n - start)
-            prompts.extend(order.permutation(strata(traffic["prompt_tokens"], size)))
-            outputs.extend(order.permutation(strata(traffic["output_tokens"], size)))
+        prompts = rng.permutation(strata(traffic["prompt_tokens"], len(times)))
+        outputs = rng.permutation(strata(traffic["output_tokens"], len(times)))
         for when, n_prompt, n_out in zip(times, prompts, outputs):
-            out.append({
-                "index": len(out), "group": group, "due_s": when,
-                "prompt": rng.integers(0, vocab, int(n_prompt)).tolist(),
-                "max_new_tokens": int(n_out),
-            })
+            out.append(_request(len(out), group, when, n_prompt, n_out,
+                                rng, vocab))
     return out
+
+
+def _listed(traffic: dict, vocab: int, rng):
+    """A closed loop's list, block after block. Block k draws the order of
+    its prompt strata, then of its output strata, from `order_seed`; token
+    ids come from `--seed`, request by request in list order."""
+    block = int(traffic["block"])
+    order = np.random.default_rng([int(traffic["order_seed"]), 0x6F726472])
+    prompt_strata = strata(traffic["prompt_tokens"], block)
+    output_strata = strata(traffic["output_tokens"], block)
+    index = 0
+    while True:
+        prompts = order.permutation(prompt_strata)
+        outputs = order.permutation(output_strata)
+        for n_prompt, n_out in zip(prompts, outputs):
+            yield _request(index, "list", None, n_prompt, n_out, rng, vocab)
+            index += 1
+
+
+def _request(index, group, due_s, n_prompt, n_out, rng, vocab) -> dict:
+    return {
+        "index": index, "group": group, "due_s": due_s,
+        "prompt": rng.integers(0, vocab, int(n_prompt)).tolist(),
+        "max_new_tokens": int(n_out),
+    }
 
 
 def histogram(items: list, group: str) -> list:

@@ -155,12 +155,11 @@ def compile_serving(name, devices, kv_cache_dtype, attention, tp) -> None:
     prefill = de.build_prefill_fn(model)
     row = jax.eval_shape(prefill, params, arg(jnp.int32, 1, 1))[0]
     pool_avals = de.paged_pool_avals(
-        row, SLOTS * max_blocks + 1, BLOCK, config.max_seq_len)
+        model, row, SLOTS * max_blocks + 1, BLOCK)
     pool_shardings = jax.tree_util.tree_map(
-        lambda aval, row_leaf: None if aval is None else NamedSharding(
-            mesh, de.pool_partition_spec(
-                tuple(row_leaf.shape), config.max_seq_len, tp)),
-        pool_avals, row, is_leaf=_is_none,
+        lambda aval, row_leaf, lay: None if aval is None else NamedSharding(
+            mesh, de.pool_partition_spec(tuple(row_leaf.shape), lay, tp)),
+        pool_avals, row, de.cache_layout(model, row), is_leaf=_is_none,
     )
     pool = _abstract(pool_avals, pool_shardings)
     int8 = kv_cache_dtype == "int8"
@@ -169,8 +168,9 @@ def compile_serving(name, devices, kv_cache_dtype, attention, tp) -> None:
         prompt = arg(jnp.int32, 1, bucket)
         cache_avals = jax.eval_shape(prefill, params, prompt)[0]
         cache_shardings = jax.tree_util.tree_map(
-            lambda a: NamedSharding(mesh, de.kv_partition_spec(
-                tuple(a.shape), config.max_seq_len, tp)), cache_avals)
+            lambda a, lay: NamedSharding(mesh, de.kv_partition_spec(
+                tuple(a.shape), lay, tp)),
+            cache_avals, de.cache_layout(model, cache_avals))
         began = time.monotonic()
         compiled = jax.jit(
             prefill, out_shardings=(cache_shardings, replicated)

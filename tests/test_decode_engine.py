@@ -554,11 +554,12 @@ def test_paged_pool_layout_and_hbm_accounting():
     assert half_bytes < dense_bytes
     # aval helper agrees with the concrete pool
     avals = paged_pool_avals(
+        model,
         jax.eval_shape(
             build_prefill_fn(model), params,
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         )[0],
-        slots * (max_seq // bs) + 1, bs, max_seq,
+        slots * (max_seq // bs) + 1, bs,
     )
     concrete = jax.tree_util.tree_leaves(full)
     abstract = [a for a in jax.tree_util.tree_leaves(avals)]
@@ -580,7 +581,7 @@ def test_paged_step_traces_with_zero_host_syncs():
         jax.ShapeDtypeStruct((1, 1), jnp.int32),
     )[0]
     bs = 8
-    pool = paged_pool_avals(row, 9, bs, model.config.max_seq_len)
+    pool = paged_pool_avals(model, row, 9, bs)
     slots, mb = 2, model.config.max_seq_len // bs
     fn = build_paged_step_fn(model, bs, temperature=1.0, top_k=4, top_p=0.9)
     closed = jax.make_jaxpr(fn)(
@@ -620,3 +621,37 @@ def test_engine_validates_like_generate():
     prompt = jnp.asarray([[1, 2, 3]], jnp.int32)
     out = engine.generate(params, prompt, 0)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(prompt))
+
+
+def test_placed_tree_is_remembered_by_its_leaves_not_by_its_shapes():
+    """A server hands the same placed tree to every step: the engine
+    walks it once. A hit needs the very leaves (held weakly), so a
+    tree of new arrays of the same shapes, or one of host arrays that
+    placing had to upload, is placed and fingerprinted again."""
+    model, params = _model_and_params()
+    engine = _engine(model)
+    placed, fp = engine._placed(params)
+    assert fp == engine._params_fingerprint(params)
+    walks = []
+    engine._place_params = lambda tree, inner=engine._place_params: (
+        walks.append(1), inner(tree))[1]
+    again, fp_again = engine._placed(params)
+    assert again is params and fp_again == fp and not walks
+    # The same leaves under a new dict: still a hit, the caller's tree.
+    shallow = jax.tree_util.tree_map(lambda leaf: leaf, params)
+    assert engine._placed(shallow)[0] is shallow and not walks
+    # New arrays of the same shapes and values: placed again.
+    twin = jax.tree_util.tree_map(lambda leaf: leaf + 0, params)
+    assert engine._placed(twin)[1] == fp and len(walks) == 1
+    assert engine._placed(twin)[0] is twin and len(walks) == 1
+    # Host arrays are uploaded by every placing, so never remembered.
+    host = jax.tree_util.tree_map(np.asarray, params)
+    for count in (2, 3):
+        out, _ = engine._placed(host)
+        assert len(walks) == count
+        assert all(isinstance(leaf, jax.Array)
+                   for leaf in jax.tree_util.tree_leaves(out))
+    # And the remembered tree's arrays are not kept alive by it.
+    del twin
+    assert engine._placed_seen is None or any(
+        ref() is None for ref in engine._placed_seen[1])

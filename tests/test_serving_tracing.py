@@ -502,7 +502,7 @@ def test_paged_step_operations_carry_every_scope():
 
     params = nn.meta.unbox(params)
     row = decode_engine._decode_cache_aval(model, params)
-    pool = decode_engine.paged_pool_avals(row, 9, 4, cfg.max_seq_len)
+    pool = decode_engine.paged_pool_avals(model, row, 9, 4)
     step = decode_engine.build_paged_step_fn(model, 4, 0.0, None, None)
     slots = 2
     lowered = jax.jit(step).lower(

@@ -505,9 +505,7 @@ def _decode_entries() -> List[EntryPoint]:
 
         model, params, _prompt, _cache = _engine_avals()
         row = _decode_cache_aval(model, params)
-        pool = paged_pool_avals(
-            row, num_blocks, block_size, model.config.max_seq_len
-        )
+        pool = paged_pool_avals(model, row, num_blocks, block_size)
         max_blocks = model.config.max_seq_len // block_size
         tables = jax.ShapeDtypeStruct((slots, max_blocks), jnp.int32)
         lengths = jax.ShapeDtypeStruct((slots,), jnp.int32)
@@ -603,9 +601,7 @@ def _decode_entries() -> List[EntryPoint]:
         )
         block_size, slots, width = 8, 2, 3
         row = _decode_cache_aval(model, params)
-        pool = paged_pool_avals(
-            row, 9, block_size, model.config.max_seq_len
-        )
+        pool = paged_pool_avals(model, row, 9, block_size)
         max_blocks = model.config.max_seq_len // block_size
         fn = build_paged_spec_step_fn(
             model, block_size, width, temperature=0.0, top_k=None,
@@ -708,6 +704,7 @@ def _decode_entries() -> List[EntryPoint]:
             build_paged_step_fn,
             build_prefill_fn,
             build_step_fn,
+            cache_layout,
             kv_partition_spec,
             paged_pool_avals,
             pool_partition_spec,
@@ -736,15 +733,15 @@ def _decode_entries() -> List[EntryPoint]:
         if paged:
             block_size = 8
             row = _decode_cache_aval(model, params)
-            pool = paged_pool_avals(row, 9, block_size, max_seq)
+            pool = paged_pool_avals(model, row, 9, block_size)
             pool_sh = jax.tree_util.tree_map(
-                lambda aval, r: (
+                lambda aval, r, lay: (
                     None if aval is None else NamedSharding(
-                        mesh,
-                        pool_partition_spec(tuple(r.shape), max_seq, tp),
+                        mesh, pool_partition_spec(tuple(r.shape), lay, tp),
                     )
                 ),
-                pool, row, is_leaf=lambda x: x is None,
+                pool, row, cache_layout(model, row),
+                is_leaf=lambda x: x is None,
             )
             max_blocks = max_seq // block_size
             fn = jax.jit(
@@ -779,10 +776,10 @@ def _decode_entries() -> List[EntryPoint]:
             row,
         )
         grid_sh = jax.tree_util.tree_map(
-            lambda aval: NamedSharding(
-                mesh, kv_partition_spec(tuple(aval.shape), max_seq, tp)
+            lambda aval, lay: NamedSharding(
+                mesh, kv_partition_spec(tuple(aval.shape), lay, tp)
             ),
-            grid,
+            grid, cache_layout(model, grid),
         )
         fn = jax.jit(
             build_step_fn(model, temperature=0.0, top_k=None, top_p=None),
@@ -820,6 +817,7 @@ def _decode_entries() -> List[EntryPoint]:
         from tf_yarn_tpu.models.decode_engine import (
             build_prefill_fn,
             build_spec_step_fn,
+            cache_layout,
             kv_partition_spec,
         )
         from tf_yarn_tpu.models.transformer import (
@@ -841,7 +839,6 @@ def _decode_entries() -> List[EntryPoint]:
         )
         param_sh = sharding_lib.tree_shardings(mesh, abstract)
         params = sharding_lib.unbox_params(abstract)
-        max_seq = config.max_seq_len
         slots, width = 2, 8
         row = jax.eval_shape(
             build_prefill_fn(model), params,
@@ -854,10 +851,10 @@ def _decode_entries() -> List[EntryPoint]:
             row,
         )
         grid_sh = jax.tree_util.tree_map(
-            lambda aval: NamedSharding(
-                mesh, kv_partition_spec(tuple(aval.shape), max_seq, tp)
+            lambda aval, lay: NamedSharding(
+                mesh, kv_partition_spec(tuple(aval.shape), lay, tp)
             ),
-            grid,
+            grid, cache_layout(model, grid),
         )
         fn = jax.jit(
             build_spec_step_fn(

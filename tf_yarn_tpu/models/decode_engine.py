@@ -67,16 +67,30 @@ top_k / top_p are baked into the traced program) key the cache.
   a slot's blocks; int8 KV composes transparently (the pool stores
   whatever leaves the model's cache has — int8 values + scales
   included).
+
+* **Cache leaves by kind.** A model says what each leaf of its decode
+  cache is (`model.cache_leaf_kinds()`: leaf name -> kind): ``paged`` by
+  token (keys, values: the pool above, with the leaf's sequence axis
+  given from the end of its shape), held once a ``slot`` (a recurrent
+  state, a convolution's tail: an array `[max_slots, ...]` beside the
+  pool, `make_slot_state`), or the slot's ``index``. A model with slot
+  leaves is stepped by `paged_state_step`: ONE call of the model over all
+  slots' tokens (`slots=True`; only its attention maps over slots), the
+  state read and written in place, and what the expert layers counted
+  returned beside the tokens. `write_slot_state` puts a prefill's final
+  state (or zeros) into a slot at admission.
 """
 
 from __future__ import annotations
 
 import logging
 import threading
+import weakref
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tf_yarn_tpu import telemetry
 from tf_yarn_tpu.models.generate import _sample
@@ -281,25 +295,26 @@ def build_step_fn(model, temperature: float, top_k: Optional[int],
 # the emitted ints are the contract, and the tests pin them.
 
 
-def _index_leaf_value(cache, max_seq_len: int):
-    """The slot's pre-apply position, read from any index leaf (a cache
-    leaf with no seq axis; all index leaves carry the same scalar)."""
-    for leaf in jax.tree_util.tree_leaves(cache):
-        if _seq_axis(leaf.shape, max_seq_len) is None:
+def _index_leaf_value(cache, layout):
+    """The slot's pre-apply position, read from any index leaf (all index
+    leaves carry the same scalar)."""
+    for leaf, lay in zip(jax.tree_util.tree_leaves(cache),
+                         jax.tree_util.tree_leaves(layout)):
+        if lay.kind == INDEX:
             return leaf.reshape(-1)[0].astype(jnp.int32)
     raise ValueError("cache has no index leaf — unknown cache layout")
 
 
-def _with_index(cache, new_index, max_seq_len: int):
+def _with_index(cache, new_index, layout):
     """Rewrite every index leaf to `new_index` (the accepted length),
-    leaving KV leaves untouched."""
+    leaving the other leaves untouched."""
 
-    def leaf(value):
-        if _seq_axis(value.shape, max_seq_len) is None:
+    def leaf(value, lay):
+        if lay.kind == INDEX:
             return jnp.full(value.shape, new_index, value.dtype)
         return value
 
-    return jax.tree_util.tree_map(leaf, cache)
+    return jax.tree_util.tree_map(leaf, cache, layout)
 
 
 def build_spec_step_fn(model, width: int, temperature: float,
@@ -330,12 +345,12 @@ def build_spec_step_fn(model, width: int, temperature: float,
     compile-key dimension, fixed per grid, so chunking adds zero
     recompiles.
     """
-    max_seq_len = model.config.max_seq_len
-
     def spec_step(params, slot_cache, tokens, n_known, eos_ids, rngs,
                   active):
         def one_slot(cache, toks, known, eos_id, rng, act):
-            idx = _index_leaf_value(cache, max_seq_len)
+            layout = cache_layout(model, cache)
+            _refuse_slot_state(layout, "the speculative / chunked window")
+            idx = _index_leaf_value(cache, layout)
             logits, state = model.apply(
                 {**params, "cache": cache}, toks[None, :], decode=True,
                 mutable=["cache"],
@@ -345,7 +360,7 @@ def build_spec_step_fn(model, width: int, temperature: float,
                 temperature, top_k, top_p,
             )
             n_valid = jnp.where(act, known + count, 0)
-            cache = _with_index(state["cache"], idx + n_valid, max_seq_len)
+            cache = _with_index(state["cache"], idx + n_valid, layout)
             return cache, emitted, count, rng
 
         return jax.vmap(one_slot)(
@@ -359,19 +374,77 @@ def build_spec_step_fn(model, width: int, temperature: float,
 # Paged KV layout: pool avals + the compiled gather/scatter programs
 # --------------------------------------------------------------------------
 
-def _seq_axis(shape: Tuple[int, ...], max_seq_len: int) -> Optional[int]:
-    """Index of the cache leaf's sequence axis (the one sized
-    max_seq_len), or None for non-KV leaves (cache_index). Raises on an
-    ambiguous layout — a config where some other cache dimension equals
-    max_seq_len needs a different block_size/max_seq_len split, not a
-    silent guess."""
-    matches = [i for i, dim in enumerate(shape) if dim == max_seq_len]
-    if len(matches) > 1:
+PAGED, SLOT, INDEX = "paged", "slot", "index"
+
+
+class LeafLayout:
+    """What one cache leaf is: its kind, its name, and for a paged leaf
+    the sequence axis of the batch-1 row (a non-negative index). A plain
+    object, so that a tree of them has one leaf a cache leaf."""
+
+    __slots__ = ("kind", "axis", "name")
+
+    def __init__(self, kind: str, axis: Optional[int], name: str):
+        self.kind, self.axis, self.name = kind, axis, name
+
+    def __repr__(self):
+        return f"LeafLayout({self.kind!r}, {self.axis!r}, {self.name!r})"
+
+
+def _leaf_name(path) -> str:
+    return str(getattr(path[-1], "key", getattr(path[-1], "name", path[-1])))
+
+
+def cache_layout(model, row_aval):
+    """A tree like `row_aval` (the batch-1 decode cache) of `LeafLayout`s,
+    from what the model declares (`cache_leaf_kinds`: leaf name -> (kind,
+    sequence axis from the end of the shape)). A leaf the model does not
+    name, or a paged leaf whose declared axis is not `max_seq_len` long,
+    is an error: nothing is guessed."""
+    declare = getattr(model, "cache_leaf_kinds", None)
+    if declare is None:
         raise ValueError(
-            f"ambiguous KV cache leaf {shape}: {len(matches)} axes equal "
-            f"max_seq_len={max_seq_len}; the paged layout needs exactly one"
+            f"{type(model).__name__} does not declare its cache leaves "
+            "(cache_leaf_kinds(): leaf name -> ('paged', sequence axis from "
+            "the end) | ('slot', None) | ('index', None)); the paged "
+            "layout does not guess them"
         )
-    return matches[0] if matches else None
+    kinds = declare()
+    max_seq_len = model.config.max_seq_len
+
+    def leaf(path, aval):
+        name = _leaf_name(path)
+        if name not in kinds:
+            raise ValueError(
+                f"cache leaf {name!r} {tuple(aval.shape)} is not among "
+                f"those {type(model).__name__}.cache_leaf_kinds() names: "
+                f"{sorted(kinds)}"
+            )
+        kind, axis = kinds[name]
+        if kind not in (PAGED, SLOT, INDEX):
+            raise ValueError(f"cache leaf {name!r}: unknown kind {kind!r}")
+        if kind != PAGED:
+            return LeafLayout(kind, None, name)
+        axis = len(aval.shape) + axis if axis < 0 else axis
+        if not 0 <= axis < len(aval.shape) or aval.shape[axis] != max_seq_len:
+            raise ValueError(
+                f"cache leaf {name!r} {tuple(aval.shape)} is declared paged "
+                f"along axis {axis}, which is not max_seq_len={max_seq_len} "
+                "long"
+            )
+        return LeafLayout(PAGED, axis, name)
+
+    return jax.tree_util.tree_map_with_path(leaf, row_aval)
+
+
+def slot_state_names(layout) -> Tuple[str, ...]:
+    """Names of the leaves held once a slot, in tree order, without
+    repeats: empty for a model whose whole cache is paged."""
+    names = []
+    for leaf in jax.tree_util.tree_leaves(layout):
+        if leaf.kind == SLOT and leaf.name not in names:
+            names.append(leaf.name)
+    return tuple(names)
 
 
 def _decode_cache_aval(model, params):
@@ -383,31 +456,28 @@ def _decode_cache_aval(model, params):
     )[0]
 
 
-def paged_pool_avals(row_aval, num_blocks: int, block_size: int,
-                     max_seq_len: int):
-    """The pool pytree's avals: every KV leaf's seq axis becomes
-    (num_blocks, block_size); index leaves (no seq axis) become None —
-    per-slot positions travel as the step's `lengths` argument instead
-    of living in the cache."""
+def paged_pool_avals(model, row_aval, num_blocks: int, block_size: int):
+    """The pool pytree's avals, by the layout the model declares
+    (`cache_layout`): every paged leaf's seq axis becomes (num_blocks,
+    block_size); index leaves become None — per-slot positions travel as
+    the step's `lengths` argument instead of living in the cache — and so
+    do leaves held once a slot, which live beside the pool
+    (`make_slot_state`)."""
+    max_seq_len = model.config.max_seq_len
     if max_seq_len % block_size:
         raise ValueError(
             f"block_size={block_size} must divide max_seq_len={max_seq_len}"
         )
 
-    def leaf(aval):
-        ax = _seq_axis(aval.shape, max_seq_len)
-        if ax is None:
-            if not jnp.issubdtype(aval.dtype, jnp.integer):
-                raise ValueError(
-                    f"cache leaf {aval.shape}/{aval.dtype} has no "
-                    f"max_seq_len={max_seq_len} axis and is not an index "
-                    "leaf — unknown cache layout for paging"
-                )
+    def leaf(aval, lay):
+        if lay.kind != PAGED:
             return None
+        ax = lay.axis
         shape = aval.shape[:ax] + (num_blocks, block_size) + aval.shape[ax + 1:]
         return jax.ShapeDtypeStruct(shape, aval.dtype)
 
-    return jax.tree_util.tree_map(leaf, row_aval)
+    return jax.tree_util.tree_map(
+        leaf, row_aval, cache_layout(model, row_aval))
 
 
 def _is_none(x) -> bool:
@@ -420,7 +490,7 @@ def _is_named_sharding(sharding) -> bool:
     return isinstance(sharding, NamedSharding)
 
 
-def _gather_slot_cache(pool, row_aval, table, length, max_seq_len):
+def _gather_slot_cache(pool, row_aval, layout, table, length):
     """One slot's dense cache view: KV leaves gathered from the pool by
     the block table (and reshaped back to the dense seq axis), index
     leaves filled with the slot's length. Values beyond `length` are
@@ -429,15 +499,14 @@ def _gather_slot_cache(pool, row_aval, table, length, max_seq_len):
     to a dense slot cache where it matters (bit-identity relies on
     this)."""
 
-    def leaf(pool_leaf, aval):
+    def leaf(pool_leaf, aval, lay):
         if pool_leaf is None:
             return jnp.full(aval.shape, length, aval.dtype)
-        ax = _seq_axis(aval.shape, max_seq_len)
-        return jnp.take(pool_leaf, table, axis=ax).reshape(aval.shape)
+        return jnp.take(pool_leaf, table, axis=lay.axis).reshape(aval.shape)
 
     with jax.named_scope("attention/kv_gather"):
         return jax.tree_util.tree_map(
-            leaf, pool, row_aval, is_leaf=_is_none
+            leaf, pool, row_aval, layout, is_leaf=_is_none
         )
 
 
@@ -461,15 +530,13 @@ def build_paged_step_fn(model, block_size: int, temperature: float,
     length 0, so their (meaningless) write lands in the reserved trash
     block 0 and can never corrupt a live slot.
     """
-    max_seq_len = model.config.max_seq_len
-
     def step(params, pool, tables, lengths, tokens, rngs, sample_mask):
         row_aval = _decode_cache_aval(model, params)
+        layout = cache_layout(model, row_aval)
+        _refuse_slot_state(layout, "paged_step (use paged_state_step)")
 
         def one_slot(table, length, token, rng, do_sample):
-            cache = _gather_slot_cache(
-                pool, row_aval, table, length, max_seq_len
-            )
+            cache = _gather_slot_cache(pool, row_aval, layout, table, length)
             logits, state = model.apply(
                 {**params, "cache": cache}, token[None, None], decode=True,
                 mutable=["cache"],
@@ -481,45 +548,152 @@ def build_paged_step_fn(model, block_size: int, temperature: float,
             emitted = jnp.where(do_sample, sampled, token)
             rng = jnp.where(do_sample, next_rng, rng)
 
-            def new_row(leaf, aval):
-                ax = _seq_axis(aval.shape, max_seq_len)
-                if ax is None:
-                    return None
-                return jax.lax.dynamic_slice_in_dim(leaf, length, 1, axis=ax)
-
             with jax.named_scope("attention/kv_write"):
-                rows = jax.tree_util.tree_map(
-                    new_row, state["cache"], row_aval
-                )
+                rows = _new_rows(state["cache"], layout, length, 1)
             return emitted, rng, rows
 
         emitted, rngs, rows = jax.vmap(
             one_slot, in_axes=(0, 0, 0, 0, 0)
         )(tables, lengths, tokens, rngs, sample_mask)
-
-        slots = tables.shape[0]
-
-        def write(pool_leaf, slot_rows, aval):
-            if pool_leaf is None:
-                return None
-            ax = _seq_axis(aval.shape, max_seq_len)
-            for s in range(slots):
-                block = tables[s, lengths[s] // block_size]
-                offset = lengths[s] % block_size
-                update = jnp.expand_dims(slot_rows[s], ax)
-                starts = [jnp.asarray(0, jnp.int32)] * pool_leaf.ndim
-                starts[ax] = block
-                starts[ax + 1] = offset
-                pool_leaf = jax.lax.dynamic_update_slice(
-                    pool_leaf, update.astype(pool_leaf.dtype), tuple(starts)
-                )
-            return pool_leaf
-
         with jax.named_scope("attention/kv_write"):
-            pool_out = jax.tree_util.tree_map(
-                write, pool, rows, row_aval, is_leaf=_is_none
+            pool_out = _append_rows(
+                pool, rows, layout, tables, lengths, block_size
             )
         return pool_out, emitted, rngs
+
+    return step
+
+
+def _refuse_slot_state(layout, feature: str):
+    """A program that carries keys and values only may not run a model
+    that also holds state once a slot: it would be silently wrong."""
+    names = slot_state_names(layout)
+    if names:
+        raise ValueError(
+            f"{feature} carries no per-slot state, and the model holds "
+            f"{', '.join(names)} once a slot"
+        )
+
+
+def _new_rows(cache, layout, length, width: int):
+    """The `width` rows a call just wrote at `length` into each paged leaf
+    of one slot's dense cache view; None for the other leaves."""
+
+    def leaf(value, lay):
+        if lay.kind != PAGED:
+            return None
+        return jax.lax.dynamic_slice_in_dim(value, length, width,
+                                            axis=lay.axis)
+
+    return jax.tree_util.tree_map(leaf, cache, layout)
+
+
+def _append_rows(pool, rows, layout, tables, lengths, block_size: int):
+    """Scatter every slot's one new row (`rows`: `_new_rows` under a map
+    over slots) into block `table[length // block_size]` at offset
+    `length % block_size` of each pool leaf."""
+    slots = tables.shape[0]
+
+    def write(pool_leaf, slot_rows, lay):
+        if pool_leaf is None:
+            return None
+        ax = lay.axis
+        for s in range(slots):
+            block = tables[s, lengths[s] // block_size]
+            offset = lengths[s] % block_size
+            update = jnp.expand_dims(slot_rows[s], ax)
+            starts = [jnp.asarray(0, jnp.int32)] * pool_leaf.ndim
+            starts[ax] = block
+            starts[ax + 1] = offset
+            pool_leaf = jax.lax.dynamic_update_slice(
+                pool_leaf, update.astype(pool_leaf.dtype), tuple(starts)
+            )
+        return pool_leaf
+
+    return jax.tree_util.tree_map(
+        write, pool, rows, layout, is_leaf=_is_none
+    )
+
+
+def build_paged_state_step_fn(model, block_size: int, temperature: float,
+                              top_k: Optional[int], top_p: Optional[float],
+                              with_logits: bool = False):
+    """The paged step of a model that also holds state once a slot
+    (`cache_layout`: `slot` leaves — a recurrent state, a convolution's
+    tail):
+
+        fn(params, pool, state, tables, lengths, tokens, rngs, sample_mask)
+            -> (pool, state, emitted [S], rngs, counts)
+
+    The model is called ONCE, over all slots' tokens together
+    (`slots=True`: tokens [S, 1], every cache leaf with a leading slot
+    axis), so that its expert layers see the step's tokens as one batch;
+    only its attention maps over slots, around the cache view gathered
+    from the pool. `state` holds the slot leaves as `[S, ...]` arrays
+    (None elsewhere); they are read and written in place (donated). A
+    free slot runs along on whatever its state holds and writes its row to
+    the trash block; admission overwrites both (`write_slot_state`).
+    `counts` stacks what the model's layers counted into `moe_stats` for
+    the active slots (table row not all trash) — `[layers, 1 + held
+    experts]`: assignments, then tokens that reached each held expert —
+    and rides back with `emitted`. Sampling and the RNG discipline are
+    `build_paged_step_fn`'s. `with_logits` appends the step's logits [S, V]
+    to what is returned (the tests compare them with a reference).
+    """
+
+    def step(params, pool, state, tables, lengths, tokens, rngs, sample_mask):
+        row_aval = _decode_cache_aval(model, params)
+        layout = cache_layout(model, row_aval)
+
+        def view(lay, pool_leaf, state_leaf, aval):
+            if lay.kind == SLOT:
+                return state_leaf
+            if lay.kind == INDEX:
+                return jax.vmap(
+                    lambda length: jnp.full(aval.shape, length, aval.dtype)
+                )(lengths)
+            return jax.vmap(
+                lambda table: jnp.take(
+                    pool_leaf, table, axis=lay.axis).reshape(aval.shape)
+            )(tables)
+
+        with jax.named_scope("attention/kv_gather"):
+            cache = jax.tree_util.tree_map(
+                view, layout, pool, state, row_aval
+            )
+        active = tables[:, 0] != 0
+        logits, new = model.apply(
+            {**params, "cache": cache}, tokens[:, None], decode=True,
+            slots=True, count_mask=active, mutable=["cache", "moe_stats"],
+        )
+
+        def sample(row_logits, token, rng, do_sample):
+            next_rng, sample_key = jax.random.split(rng)
+            sampled = _sample(
+                row_logits[None], sample_key, temperature, top_k, top_p
+            )[0]
+            return (jnp.where(do_sample, sampled, token),
+                    jnp.where(do_sample, next_rng, rng))
+
+        emitted, rngs = jax.vmap(sample)(
+            logits[:, -1], tokens, rngs, sample_mask)
+        with jax.named_scope("attention/kv_write"):
+            rows = jax.vmap(
+                lambda slot_cache, length: _new_rows(
+                    slot_cache, layout, length, 1)
+            )(new["cache"], lengths)
+            pool_out = _append_rows(
+                pool, rows, layout, tables, lengths, block_size
+            )
+        state_out = jax.tree_util.tree_map(
+            lambda lay, value: value if lay.kind == SLOT else None,
+            layout, new["cache"],
+        )
+        counted = jax.tree_util.tree_leaves(new.get("moe_stats", {}))
+        counts = jnp.stack(counted) if counted \
+            else jnp.zeros((0, 0), jnp.int32)
+        out = (pool_out, state_out, emitted, rngs, counts)
+        return out + (logits[:, -1],) if with_logits else out
 
     return step
 
@@ -598,8 +772,6 @@ def build_paged_spec_step_fn(model, block_size: int, width: int,
             f"decode_attention must be one of {DECODE_ATTENTION_MODES}, "
             f"got {decode_attention!r}"
         )
-    max_seq_len = model.config.max_seq_len
-
     if decode_attention == "fused":
         if getattr(model.config, "kv_cache_dtype", None) != "int8":
             raise ValueError(
@@ -633,12 +805,12 @@ def build_paged_spec_step_fn(model, block_size: int, width: int,
     def spec_step(params, pool, tables, lengths, tokens, n_known,
                   eos_ids, rngs, active):
         row_aval = _decode_cache_aval(model, params)
+        layout = cache_layout(model, row_aval)
+        _refuse_slot_state(layout, "the speculative / chunked window")
         blocks_per_slot = tables.shape[1]
 
         def one_slot(table, length, toks, known, eos_id, rng, act):
-            cache = _gather_slot_cache(
-                pool, row_aval, table, length, max_seq_len
-            )
+            cache = _gather_slot_cache(pool, row_aval, layout, table, length)
             logits, state = model.apply(
                 {**params, "cache": cache}, toks[None, :], decode=True,
                 mutable=["cache"],
@@ -648,18 +820,8 @@ def build_paged_spec_step_fn(model, block_size: int, width: int,
                 temperature, top_k, top_p,
             )
 
-            def new_rows(leaf, aval):
-                ax = _seq_axis(aval.shape, max_seq_len)
-                if ax is None:
-                    return None
-                return jax.lax.dynamic_slice_in_dim(
-                    leaf, length, width, axis=ax
-                )
-
             with jax.named_scope("attention/kv_write"):
-                rows = jax.tree_util.tree_map(
-                    new_rows, state["cache"], row_aval
-                )
+                rows = _new_rows(state["cache"], layout, length, width)
             return emitted, count, rng, rows
 
         emitted, counts, rngs, rows = jax.vmap(one_slot)(
@@ -668,10 +830,10 @@ def build_paged_spec_step_fn(model, block_size: int, width: int,
 
         slots = tables.shape[0]
 
-        def write(pool_leaf, slot_rows, aval):
+        def write(pool_leaf, slot_rows, lay):
             if pool_leaf is None:
                 return None
-            ax = _seq_axis(aval.shape, max_seq_len)
+            ax = lay.axis
             for s in range(slots):
                 for w in range(width):
                     pos = lengths[s] + w
@@ -701,7 +863,7 @@ def build_paged_spec_step_fn(model, block_size: int, width: int,
 
         with jax.named_scope("attention/kv_write"):
             pool_out = jax.tree_util.tree_map(
-                write, pool, rows, row_aval, is_leaf=_is_none
+                write, pool, rows, layout, is_leaf=_is_none
             )
         return pool_out, emitted, counts, rngs
 
@@ -718,16 +880,15 @@ def build_pack_prefill_fn(model, block_size: int, prefill_len: int):
     `block_ids` values are traced (different slots reuse one compiled
     program); `prefill_len` is static (one program per prefill bucket).
     """
-    max_seq_len = model.config.max_seq_len
     n_pack = -(-prefill_len // block_size)
 
     def pack(pool, block_ids, row_cache):
-        def leaf(pool_leaf, row_leaf):
+        layout = cache_layout(model, row_cache)
+
+        def leaf(pool_leaf, row_leaf, lay):
             if pool_leaf is None:
                 return None
-            ax = _seq_axis(row_leaf.shape, max_seq_len)
-            if ax is None:
-                return pool_leaf
+            ax = lay.axis
             for j in range(n_pack):
                 width = min(block_size, prefill_len - j * block_size)
                 chunk = jax.lax.slice_in_dim(
@@ -746,7 +907,7 @@ def build_pack_prefill_fn(model, block_size: int, prefill_len: int):
             return pool_leaf
 
         return jax.tree_util.tree_map(
-            leaf, pool, row_cache, is_leaf=_is_none
+            leaf, pool, row_cache, layout, is_leaf=_is_none
         )
 
     return pack
@@ -766,16 +927,16 @@ def build_extract_blocks_fn(model, row_aval):
     pool swaps as quantized bytes. Pure gather: no host callbacks
     (TYA103), so the only host hop is the caller's `device_get`.
     """
-    max_seq_len = model.config.max_seq_len
+    layout = cache_layout(model, row_aval)
+    _refuse_slot_state(layout, "extract_blocks (suspend, /v1/blocks export)")
 
     def extract(pool, block_ids):
-        def leaf(pool_leaf, aval):
+        def leaf(pool_leaf, lay):
             if pool_leaf is None:
                 return None
-            ax = _seq_axis(aval.shape, max_seq_len)
-            return jnp.take(pool_leaf, block_ids, axis=ax)
+            return jnp.take(pool_leaf, block_ids, axis=lay.axis)
 
-        return jax.tree_util.tree_map(leaf, pool, row_aval,
+        return jax.tree_util.tree_map(leaf, pool, layout,
                                       is_leaf=_is_none)
 
     return extract
@@ -793,13 +954,14 @@ def build_inject_blocks_fn(model, row_aval):
     re-injected (prefix-cache hits re-attached by lookup, padding) are
     aimed at the trash block, whose content is garbage by contract.
     """
-    max_seq_len = model.config.max_seq_len
+    layout = cache_layout(model, row_aval)
+    _refuse_slot_state(layout, "inject_blocks (resume, /v1/blocks import)")
 
     def inject(pool, block_ids, payload):
-        def leaf(pool_leaf, aval, pay_leaf):
+        def leaf(pool_leaf, lay, pay_leaf):
             if pool_leaf is None:
                 return None
-            ax = _seq_axis(aval.shape, max_seq_len)
+            ax = lay.axis
             for j in range(block_ids.shape[0]):
                 chunk = jax.lax.slice_in_dim(pay_leaf, j, j + 1, axis=ax)
                 starts = [jnp.asarray(0, jnp.int32)] * pool_leaf.ndim
@@ -809,7 +971,7 @@ def build_inject_blocks_fn(model, row_aval):
                 )
             return pool_leaf
 
-        return jax.tree_util.tree_map(leaf, pool, row_aval, payload,
+        return jax.tree_util.tree_map(leaf, pool, layout, payload,
                                       is_leaf=_is_none)
 
     return inject
@@ -865,47 +1027,36 @@ def tree_nbytes_per_device(tree) -> int:
 # logic changes.
 
 
-def kv_partition_spec(shape: Tuple[int, ...], max_seq_len: int, tp: int):
+def kv_partition_spec(shape: Tuple[int, ...], lay: LeafLayout, tp: int):
     """PartitionSpec for a DENSE cache leaf (prefill row, slot row, or
-    slot grid — the rule anchors on the seq axis, so the extra leading
-    slot/layer axes need no special casing)."""
-    from jax.sharding import PartitionSpec
-
-    from tf_yarn_tpu.parallel.mesh import AXIS_TP
-
-    if tp <= 1:
-        return PartitionSpec()
-    ax = _seq_axis(shape, max_seq_len)
-    if ax is None:
-        return PartitionSpec()
-    heads = ax + 1
-    if heads >= len(shape) or shape[heads] % tp:
-        return PartitionSpec()
-    spec = [None] * len(shape)
-    spec[heads] = AXIS_TP
-    return PartitionSpec(*spec)
+    slot grid) whose `lay` is its leaf of `cache_layout` over the SAME
+    tree — the model declares the seq axis from the end of the shape, so
+    extra leading slot/layer axes need no special casing. Leaves that are
+    not paged by token stay replicated."""
+    return _heads_over_tp(len(shape), shape, lay, tp, shift=0)
 
 
-def pool_partition_spec(row_shape: Tuple[int, ...], max_seq_len: int,
+def pool_partition_spec(row_shape: Tuple[int, ...], lay: LeafLayout,
                         tp: int):
     """The same heads-axis rule for a PAGED pool leaf, whose seq axis
     was split into (num_blocks, block_size) — computed from the dense
-    ROW leaf's shape (the pool shape cannot anchor on max_seq_len), with
-    every axis after the split shifted one right."""
+    ROW leaf's shape and layout, with every axis after the split shifted
+    one right."""
+    return _heads_over_tp(len(row_shape) + 1, row_shape, lay, tp, shift=1)
+
+
+def _heads_over_tp(ndim: int, shape, lay: LeafLayout, tp: int, shift: int):
     from jax.sharding import PartitionSpec
 
     from tf_yarn_tpu.parallel.mesh import AXIS_TP
 
-    if tp <= 1:
+    if tp <= 1 or lay.kind != PAGED:
         return PartitionSpec()
-    ax = _seq_axis(row_shape, max_seq_len)
-    if ax is None:
+    heads = lay.axis + 1
+    if heads >= len(shape) or shape[heads] % tp:
         return PartitionSpec()
-    heads = ax + 1
-    if heads >= len(row_shape) or row_shape[heads] % tp:
-        return PartitionSpec()
-    spec = [None] * (len(row_shape) + 1)
-    spec[heads + 1] = AXIS_TP
+    spec = [None] * ndim
+    spec[heads + shift] = AXIS_TP
     return PartitionSpec(*spec)
 
 
@@ -1004,6 +1155,7 @@ class DecodeEngine:
         self._decode: Dict[tuple, Any] = {}
         self._step: Dict[tuple, Any] = {}
         self._lock = threading.Lock()
+        self._placed_seen = None  # _placed: (treedef, weak leaves, fp)
         self.stats = {
             "calls": 0,
             "prefill_compiles": 0,
@@ -1056,6 +1208,27 @@ class DecodeEngine:
         self._insert_jit = jax.jit(_insert, donate_argnums=(0,))
         self._evict_jit = jax.jit(_evict, donate_argnums=(0,))
 
+        # Per-slot state beside the block pool (write_slot_state): the
+        # same splices over a tree that is None where a leaf is paged.
+        def _write_state(state, rows, slot):
+            return jax.tree_util.tree_map(
+                lambda buf, r: None if buf is None
+                else jax.lax.dynamic_update_index_in_dim(
+                    buf, r.astype(buf.dtype), slot, 0),
+                state, rows, is_leaf=_is_none,
+            )
+
+        def _zero_state(state, slot):
+            return jax.tree_util.tree_map(
+                lambda buf: None if buf is None
+                else jax.lax.dynamic_update_index_in_dim(
+                    buf, jnp.zeros(buf.shape[1:], buf.dtype), slot, 0),
+                state, is_leaf=_is_none,
+            )
+
+        self._write_state_jit = jax.jit(_write_state, donate_argnums=(0,))
+        self._zero_state_jit = jax.jit(_zero_state, donate_argnums=(0,))
+
     # -- bucket selection --------------------------------------------------
 
     def select_buckets(self, batch: int, prompt_len: int) -> Tuple[int, int]:
@@ -1078,6 +1251,31 @@ class DecodeEngine:
         return hash((treedef, tuple(
             (tuple(leaf.shape), str(leaf.dtype)) for leaf in leaves
         )))
+
+    def _placed(self, params):
+        """(`_place_params(params)`, its fingerprint), remembered for the
+        tree seen last: a server hands the same placed tree to every
+        step, and walking its leaves again each tick kept a v5e's host
+        for 6.5 ms of a 36 ms tick with the device idle (PERF.md, PR 29).
+        Remembered only when placing changed no leaf, so a hit hands the
+        caller's own tree back; the leaves are held weakly, so an id is
+        never taken for another array's."""
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        seen = self._placed_seen
+        if seen is not None and seen[0] == treedef \
+                and len(seen[1]) == len(leaves) \
+                and all(ref() is leaf for ref, leaf in zip(seen[1], leaves)):
+            return params, seen[2]
+        placed = self._place_params(params)
+        fp = self._params_fingerprint(placed)
+        if all(a is b for a, b in
+               zip(leaves, jax.tree_util.tree_leaves(placed))):
+            try:
+                self._placed_seen = (
+                    treedef, [weakref.ref(leaf) for leaf in leaves], fp)
+            except TypeError:  # a leaf no weak reference can hold
+                self._placed_seen = None
+        return placed, fp
 
     # -- tensor-parallel placement -----------------------------------------
 
@@ -1148,15 +1346,12 @@ class DecodeEngine:
         tp (kv_partition_spec)."""
         from jax.sharding import NamedSharding
 
-        max_seq_len = self.model.config.max_seq_len
         return jax.tree_util.tree_map(
-            lambda aval: NamedSharding(
+            lambda aval, lay: NamedSharding(
                 self.mesh,
-                kv_partition_spec(
-                    tuple(aval.shape), max_seq_len, self.tp_degree
-                ),
+                kv_partition_spec(tuple(aval.shape), lay, self.tp_degree),
             ),
-            avals,
+            avals, cache_layout(self.model, avals),
         )
 
     # -- compile cache -----------------------------------------------------
@@ -1323,11 +1518,10 @@ class DecodeEngine:
         # Everything the host does before the device has anything to do
         # (docs/Serving.md "Where a tick's time goes").
         with telemetry.span("decode_engine/step_args"):
-            params = self._place_params(params)
+            params, fp = self._placed(params)
             tokens = jnp.asarray(tokens, jnp.int32)
             rngs = jnp.asarray(rngs, jnp.uint32)
             sample_mask = jnp.asarray(sample_mask, bool)
-            fp = self._params_fingerprint(params)
             slots = int(tokens.shape[0])
             step_key = (slots, float(temperature), top_k, top_p, fp)
             step_fn = build_step_fn(self.model, temperature, top_k, top_p)
@@ -1369,14 +1563,13 @@ class DecodeEngine:
         rng buffer are donated. Returns (slot_cache, emitted [S, W],
         counts [S], rngs)."""
         with telemetry.span("decode_engine/step_args"):
-            params = self._place_params(params)
+            params, fp = self._placed(params)
             tokens = jnp.asarray(tokens, jnp.int32)
             n_known = jnp.asarray(n_known, jnp.int32)
             eos_ids = jnp.asarray(eos_ids, jnp.int32)
             rngs = jnp.asarray(rngs, jnp.uint32)
             active = jnp.asarray(active, bool)
             slots, width = (int(tokens.shape[0]), int(tokens.shape[1]))
-            fp = self._params_fingerprint(params)
             key = ("spec", slots, width, float(temperature), top_k, top_p, fp)
             fn = build_spec_step_fn(self.model, width, temperature, top_k, top_p)
             args = (params, slot_cache, tokens, n_known, eos_ids, rngs, active)
@@ -1419,8 +1612,7 @@ class DecodeEngine:
         params = self._place_params(params)
         row_avals = _decode_cache_aval(self.model, params)
         avals = paged_pool_avals(
-            row_avals, num_blocks, block_size,
-            self.model.config.max_seq_len,
+            self.model, row_avals, num_blocks, block_size
         )
 
         def build():
@@ -1434,23 +1626,139 @@ class DecodeEngine:
             return build()
         # Sharded pool: every block's kv-heads axis splits over tp, so
         # each device holds 1/tp of EVERY block (pool_partition_spec —
-        # the pool shape itself cannot anchor on max_seq_len, the row
-        # aval supplies the axis).
+        # the row aval and its declared layout supply the axis).
         from jax.sharding import NamedSharding
 
-        max_seq_len = self.model.config.max_seq_len
         shardings = jax.tree_util.tree_map(
-            lambda aval, row: (
+            lambda aval, row, lay: (
                 None if aval is None else NamedSharding(
                     self.mesh,
                     pool_partition_spec(
-                        tuple(row.shape), max_seq_len, self.tp_degree
+                        tuple(row.shape), lay, self.tp_degree
                     ),
                 )
             ),
-            avals, row_avals, is_leaf=_is_none,
+            avals, row_avals, cache_layout(self.model, row_avals),
+            is_leaf=_is_none,
         )
         return jax.jit(build, out_shardings=shardings)()
+
+    # -- state held once a slot ---------------------------------------------
+
+    def slot_state_leaves(self, params) -> Tuple[str, ...]:
+        """Names of the cache leaves the model holds once a slot (a
+        recurrent state, a convolution's tail); empty for a model whose
+        whole cache is paged by token. Abstract: nothing runs."""
+        if getattr(self.model, "cache_leaf_kinds", None) is None:
+            return ()
+        params = self._place_params(params)
+        return slot_state_names(
+            cache_layout(self.model, _decode_cache_aval(self.model, params))
+        )
+
+    def make_slot_state(self, params, max_slots: int):
+        """Zeroed per-slot state beside the block pool: every `slot` leaf
+        of the model's decode cache as `[max_slots, *row shape]`, None for
+        the paged and index leaves."""
+        if self.mesh is not None:
+            raise ValueError(
+                "per-slot state is not placed on a tensor-parallel mesh "
+                f"yet: {', '.join(self.slot_state_leaves(params))} would "
+                "need a sharding rule of their own"
+            )
+        params = self._place_params(params)
+        row_avals = _decode_cache_aval(self.model, params)
+        return jax.tree_util.tree_map(
+            lambda aval, lay: (
+                jnp.zeros((max_slots,) + aval.shape, aval.dtype)
+                if lay.kind == SLOT else None
+            ),
+            row_avals, cache_layout(self.model, row_avals),
+        )
+
+    def write_slot_state(self, state, slot: int, row_cache=None):
+        """Put a prefilled batch-1 cache's `slot` leaves (its final state)
+        into row `slot` of the state arrays, or zeros where nothing was
+        prefilled, so that a reused slot never runs on its predecessor's
+        state. `state` is donated: use the return."""
+        with telemetry.span("decode_engine/state_write", slot=slot,
+                            zero=row_cache is None):
+            if row_cache is None:
+                return self._zero_state_jit(
+                    state, jnp.asarray(slot, jnp.int32))
+            rows = jax.tree_util.tree_map(
+                lambda held, row: None if held is None else row,
+                state, row_cache, is_leaf=_is_none,
+            )
+            return self._write_state_jit(
+                state, rows, jnp.asarray(slot, jnp.int32))
+
+    def paged_state_step(
+        self,
+        params,
+        pool,
+        state,
+        tables,
+        lengths,
+        tokens,
+        rngs,
+        sample_mask,
+        block_size: int,
+        temperature: float = 0.0,
+        top_k: Optional[int] = None,
+        top_p: Optional[float] = None,
+    ):
+        """`paged_step` for a model with per-slot state
+        (build_paged_state_step_fn): one call of the model over all slots'
+        tokens; the pool, the state and the rng buffer are donated.
+        Returns (pool, state, emitted [S], rngs, counts)."""
+        slots = int(jnp.shape(tokens)[0])
+        compiled, args = self._paged_program(
+            self._paged_step, "paged_step",
+            ("state", slots, tuple(jnp.shape(tables)), block_size,
+             float(temperature), top_k, top_p),
+            lambda: build_paged_state_step_fn(
+                self.model, block_size, temperature, top_k, top_p),
+            params, (pool, state),
+            ((tables, jnp.int32), (lengths, jnp.int32), (tokens, jnp.int32),
+             (rngs, jnp.uint32), (sample_mask, bool)),
+            donate=(1, 2, 6), replicated_outs=3,
+        )
+        with telemetry.span("decode_engine/paged_step", slots=slots):
+            return compiled(*args)
+
+    def _paged_program(self, programs, stat, key, build, params, trees,
+                       host, donate, replicated_outs):
+        """What the host does before the device has anything to do, for
+        every paged step alike (span `decode_engine/step_args`): place
+        the params, upload the tick's host arrays (`host`: (value, dtype)
+        pairs, after the device-resident `trees` in the program's
+        arguments), key the compile cache by `key` + the params' and the
+        trees' fingerprints, and compile on a miss (`build()` makes the
+        step function; the trees come back first among its outputs, then
+        `replicated_outs` small ones). Returns (compiled, args)."""
+        with telemetry.span("decode_engine/step_args"):
+            params, fp = self._placed(params)
+            # Host arrays go to the program as they are: it uploads them
+            # in its own call, which costs a tenth of five `jnp.asarray`s.
+            args = (params,) + tuple(trees) + tuple(
+                jnp.asarray(value, dtype) if isinstance(value, jax.Array)
+                else np.asarray(value, dtype) for value, dtype in host)
+            key = key + (fp,) + tuple(
+                self._tree_fingerprint(tree) for tree in trees)
+            out_shardings = None
+            if self.mesh is not None:
+                out_shardings = tuple(
+                    self._shardings_of(tree) for tree in trees
+                ) + (self._rep_sharding,) * replicated_outs
+            compiled = self._compiled(
+                programs, key, stat,
+                lambda: self._jit(
+                    build(), args, donate=donate,
+                    out_shardings=out_shardings,
+                ).lower(*args).compile(),
+            )
+        return compiled, args
 
     def max_blocks_per_slot(self, block_size: int) -> int:
         """Block-table width: a slot grown to max_seq_len holds exactly
@@ -1570,33 +1878,18 @@ class DecodeEngine:
         fingerprint); tables/lengths/tokens are traced, so per-tick
         table changes never recompile. The pool and the rng buffer are
         donated. Returns (pool, emitted [S], rngs)."""
-        with telemetry.span("decode_engine/step_args"):
-            params = self._place_params(params)
-            tables = jnp.asarray(tables, jnp.int32)
-            lengths = jnp.asarray(lengths, jnp.int32)
-            tokens = jnp.asarray(tokens, jnp.int32)
-            rngs = jnp.asarray(rngs, jnp.uint32)
-            sample_mask = jnp.asarray(sample_mask, bool)
-            slots = int(tokens.shape[0])
-            key = (slots, tuple(tables.shape), block_size, float(temperature),
-                   top_k, top_p, self._params_fingerprint(params),
-                   self._tree_fingerprint(pool))
-            step_fn = build_paged_step_fn(
-                self.model, block_size, temperature, top_k, top_p
-            )
-            args = (params, pool, tables, lengths, tokens, rngs, sample_mask)
-            out_shardings = None
-            if self.mesh is not None:
-                out_shardings = (
-                    self._shardings_of(pool), self._rep_sharding,
-                    self._rep_sharding,
-                )
-            compiled = self._compiled(
-                self._paged_step, key, "paged_step",
-                lambda: self._jit(
-                    step_fn, args, donate=(1, 5), out_shardings=out_shardings,
-                ).lower(*args).compile(),
-            )
+        slots = int(jnp.shape(tokens)[0])
+        compiled, args = self._paged_program(
+            self._paged_step, "paged_step",
+            (slots, tuple(jnp.shape(tables)), block_size, float(temperature),
+             top_k, top_p),
+            lambda: build_paged_step_fn(
+                self.model, block_size, temperature, top_k, top_p),
+            params, (pool,),
+            ((tables, jnp.int32), (lengths, jnp.int32), (tokens, jnp.int32),
+             (rngs, jnp.uint32), (sample_mask, bool)),
+            donate=(1, 5), replicated_outs=2,
+        )
         with telemetry.span("decode_engine/paged_step", slots=slots):
             return compiled(*args)
 
@@ -1633,46 +1926,30 @@ class DecodeEngine:
                 "decode_attention='gather' (XLA shards the gather "
                 "path), or tp=1"
             )
-        with telemetry.span("decode_engine/step_args"):
-            params = self._place_params(params)
-            tables = jnp.asarray(tables, jnp.int32)
-            lengths = jnp.asarray(lengths, jnp.int32)
-            tokens = jnp.asarray(tokens, jnp.int32)
-            n_known = jnp.asarray(n_known, jnp.int32)
-            eos_ids = jnp.asarray(eos_ids, jnp.int32)
-            rngs = jnp.asarray(rngs, jnp.uint32)
-            active = jnp.asarray(active, bool)
-            slots, width = (int(tokens.shape[0]), int(tokens.shape[1]))
-            key = ("paged_spec", slots, width, tuple(tables.shape), block_size,
-                   decode_attention, float(temperature), top_k, top_p,
-                   self._params_fingerprint(params),
-                   self._tree_fingerprint(pool))
-            fn = build_paged_spec_step_fn(
+        slots, width = (int(dim) for dim in jnp.shape(tokens))
+        compiled, args = self._paged_program(
+            self._paged_spec_step, "paged_spec_step",
+            ("paged_spec", slots, width, tuple(jnp.shape(tables)),
+             block_size, decode_attention, float(temperature), top_k, top_p),
+            lambda: build_paged_spec_step_fn(
                 self.model, block_size, width, temperature, top_k, top_p,
-                decode_attention=decode_attention,
-            )
-            args = (params, pool, tables, lengths, tokens, n_known, eos_ids,
-                    rngs, active)
-            out_shardings = None
-            if self.mesh is not None:
-                out_shardings = (
-                    self._shardings_of(pool), self._rep_sharding,
-                    self._rep_sharding, self._rep_sharding,
-                )
-            compiled = self._compiled(
-                self._paged_spec_step, key, "paged_spec_step",
-                lambda: self._jit(
-                    fn, args, donate=(1, 7), out_shardings=out_shardings,
-                ).lower(*args).compile(),
-            )
+                decode_attention=decode_attention),
+            params, (pool,),
+            ((tables, jnp.int32), (lengths, jnp.int32), (tokens, jnp.int32),
+             (n_known, jnp.int32), (eos_ids, jnp.int32),
+             (rngs, jnp.uint32), (active, bool)),
+            donate=(1, 7), replicated_outs=3,
+        )
         with telemetry.span("decode_engine/paged_spec_step", slots=slots,
                             width=width):
             return compiled(*args)
 
     def _tree_fingerprint(self, tree) -> int:
+        # Every tick, for the pool and the state: the dtype hashes as it
+        # is (its name took 8 us a leaf to build).
         leaves = jax.tree_util.tree_leaves(tree)
         return hash(tuple(
-            (tuple(leaf.shape), str(leaf.dtype)) for leaf in leaves
+            (tuple(leaf.shape), leaf.dtype) for leaf in leaves
         ))
 
     # -- compiled-artifact introspection -----------------------------------

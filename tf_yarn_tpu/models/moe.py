@@ -6,9 +6,17 @@ shapes), expert FFNs are batched matmuls with the expert axis annotated
 ("expert" → ep in parallel.sharding.LOGICAL_RULES), so XLA places one
 expert group per ep shard and inserts the all-to-alls itself. No analog
 exists in the reference (SURVEY.md §2.5: expert parallelism — NO).
+
+`DroplessMoE` is the other equation, the one served hybrid models use
+(models/hybrid.py): a router over all experts of the deployment in float32,
+the `top_k` largest, a softmax over those alone, no capacity and no dropped
+token, plus a shared expert every token passes. It is told which experts
+this chip holds and returns their part of the sum.
 """
 
 from __future__ import annotations
+
+from typing import Any
 
 import flax.linen as nn
 import jax
@@ -99,3 +107,114 @@ class MoEMlp(nn.Module):
 
         self.sow("intermediates", "moe_aux_loss", aux_loss)
         return combined.reshape(b, s, d).astype(cfg.dtype)
+
+
+class DroplessMoE(nn.Module):
+    """`moe(x) = sum_i g_i W_out,i (silu(a_i) * b_i)`, `[a_i | b_i] = x W_in,i`
+    over the `top_k` experts of largest router logit, `g = softmax` over
+    those `top_k` logits alone; plus `shared(x)`, one SwiGLU of width
+    `d_shared` that every token passes (0 = none).
+
+    The router is as wide as the deployment (`num_experts`), and this chip
+    holds `num_experts_here` of them starting at `expert_offset`: the sum
+    runs over the chosen experts that are held here, and the rest of it is
+    the other chips'. Nothing stands in for them: with fewer than all
+    experts held, the result is this chip's addend of the all-reduce.
+
+    One function of x [T, D], for prefill and decode alike, so a serving
+    step hands it every slot's token together. Held experts are computed
+    for all T tokens and weighted by a [T, held] matrix that is zero where
+    a token did not choose the expert: no token is dropped and no
+    [T, E, C] dispatch tensor exists. At decode (T = slots) that streams
+    each held expert's matrices once, which is what the step is bound by.
+
+    `count_mask` [T] marks the tokens whose routing is counted into the
+    mutable `moe_stats` collection (`counts` [1 + held]: their assignments
+    over all experts, then the tokens that reached each held expert);
+    without that collection nothing is counted.
+    """
+
+    num_experts: int
+    num_experts_here: int
+    top_k: int
+    d_expert: int
+    d_shared: int = 0
+    expert_offset: int = 0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, count_mask=None):
+        t, d = x.shape
+        held, width = self.num_experts_here, self.d_expert
+        if not 0 < held <= self.num_experts - self.expert_offset:
+            raise ValueError(
+                f"experts [{self.expert_offset}, {self.expert_offset + held})"
+                f" are not among the router's {self.num_experts}"
+            )
+        normal = nn.initializers.lecun_normal()
+
+        with jax.named_scope("moe/router"):
+            router = self.param(
+                "router", _partitioned((EMBED, None))(normal),
+                (d, self.num_experts), self.param_dtype,
+            )
+            # float32 at full precision: which ten are largest decides
+            # whole expert outputs, not a rounding.
+            logits = jnp.einsum(
+                "td,de->te", x.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            top_logits, top_index = jax.lax.top_k(logits, self.top_k)
+            gates = jax.nn.softmax(top_logits, axis=-1)
+        with jax.named_scope("moe/dispatch"):
+            # weights [T, held]: a token's gate for each held expert it
+            # chose, zero elsewhere.
+            local = top_index - self.expert_offset
+            chose = local[:, :, None] == jnp.arange(held)[None, None, :]
+            weights = jnp.sum(jnp.where(chose, gates[:, :, None], 0.0), axis=1)
+            if count_mask is not None:
+                # [1 + held]: the counted tokens' assignments over all the
+                # deployment's experts, then those that reached each held one.
+                counted = chose & count_mask[:, None, None]
+                self.sow("moe_stats", "counts", jnp.concatenate([
+                    jnp.sum(count_mask, dtype=jnp.int32)[None] * self.top_k,
+                    jnp.sum(counted, axis=(0, 1), dtype=jnp.int32)]))
+        with jax.named_scope("moe/experts"):
+            w_in = self.param(
+                "w_in", _partitioned((EXPERT, EMBED, MLP))(normal),
+                (held, d, 2 * width), self.param_dtype,
+            )
+            w_out = self.param(
+                "w_out", _partitioned((EXPERT, MLP, EMBED))(normal),
+                (held, width, d), self.param_dtype,
+            )
+            hidden = jnp.einsum("td,edf->etf", x, w_in.astype(self.dtype),
+                                preferred_element_type=jnp.float32)
+            act = nn.silu(hidden[..., :width]) * hidden[..., width:]
+        with jax.named_scope("moe/combine"):
+            # The gate goes onto the activation, so that the weighted sum
+            # over experts is the contraction of one matmul.
+            act = (act * weights.T[:, :, None]).astype(self.dtype)
+            out = jnp.einsum("etf,efd->td", act, w_out.astype(self.dtype),
+                             preferred_element_type=jnp.float32)
+        if self.d_shared:
+            with jax.named_scope("moe/shared"):
+                s_in = self.param(
+                    "shared_in", _partitioned((EMBED, MLP))(normal),
+                    (d, 2 * self.d_shared), self.param_dtype,
+                )
+                s_out = self.param(
+                    "shared_out", _partitioned((MLP, EMBED))(normal),
+                    (self.d_shared, d), self.param_dtype,
+                )
+                hidden = jnp.einsum("td,df->tf", x, s_in.astype(self.dtype),
+                                    preferred_element_type=jnp.float32)
+                act = nn.silu(hidden[:, :self.d_shared]) \
+                    * hidden[:, self.d_shared:]
+                out = out + jnp.einsum(
+                    "tf,fd->td", act.astype(self.dtype),
+                    s_out.astype(self.dtype),
+                    preferred_element_type=jnp.float32,
+                )
+        return out.astype(self.dtype)

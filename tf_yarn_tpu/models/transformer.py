@@ -69,6 +69,12 @@ class TransformerConfig:
     # naive layer-sharded scan. Requires scan_layers=True, n_layers % pp
     # == 0, batch % microbatches == 0; train-path only (no decode/MoE).
     gpipe_microbatches: int = 0
+    # Attention without positional encoding (`use_rope=False`) and with a
+    # softmax scale other than head_dim**-0.5 (`attention_scale`): what a
+    # hybrid model's attention layers ask for (models/hybrid.py). The
+    # defaults are the llama recipe.
+    use_rope: bool = True
+    attention_scale: Optional[float] = None
 
     @property
     def head_dim(self) -> int:
@@ -101,6 +107,17 @@ class TransformerConfig:
         )
         defaults.update(overrides)
         return cls(**defaults)
+
+
+# The decode cache `Attention` keeps, by leaf name: (kind, sequence axis
+# from the end of the shape). Shared by every model built on `Attention`.
+CACHE_LEAF_KINDS = {
+    "cached_key": ("paged", -3),
+    "cached_value": ("paged", -3),
+    "cached_key_scale": ("paged", -3),
+    "cached_value_scale": ("paged", -3),
+    "cache_index": ("index", None),
+}
 
 
 def _partitioned(names):
@@ -239,6 +256,12 @@ class Attention(nn.Module):
                     "'bf16' or 'int8'"
                 )
             int8_cache = cfg.kv_cache_dtype == "int8"
+            if int8_cache and cfg.attention_scale is not None:
+                raise NotImplementedError(
+                    "the int8 decode-attention kernel scales by "
+                    "head_dim**-0.5; attention_scale needs kv_cache_dtype="
+                    "'bf16'"
+                )
             cache_shape = (b, cfg.max_seq_len, cfg.n_kv_heads, cfg.head_dim)
             store_dtype = jnp.int8 if int8_cache else cfg.dtype
             cached_k = self.variable(
@@ -264,8 +287,9 @@ class Attention(nn.Module):
             idx = cache_index.value
             positions = idx + jnp.arange(s, dtype=jnp.int32)[None, :]
             positions = jnp.broadcast_to(positions, (b, s))
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+            if cfg.use_rope:
+                q = rope(q, positions, cfg.rope_theta)
+                k = rope(k, positions, cfg.rope_theta)
 
             def _append(var, fresh):
                 with jax.named_scope("attention/kv_write"):
@@ -321,12 +345,15 @@ class Attention(nn.Module):
                 else:
                     key_all, value_all = cached_k.value, cached_v.value
                 out = xla_attention(
-                    q, key_all, value_all, causal=True, segment_offset=idx
+                    q, key_all, value_all, causal=True, segment_offset=idx,
+                    softmax_scale=cfg.attention_scale,
                 )
         else:
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-            out = attention(q, k, v, impl=cfg.attention_impl, causal=True)
+            if cfg.use_rope:
+                q = rope(q, positions, cfg.rope_theta)
+                k = rope(k, positions, cfg.rope_theta)
+            out = attention(q, k, v, impl=cfg.attention_impl, causal=True,
+                            softmax_scale=cfg.attention_scale)
         with jax.named_scope("attention/out"):
             out = out.reshape(b, s, cfg.n_heads * cfg.head_dim)
             return LoraDense(cfg.d_model, (HEADS, EMBED), cfg, name="wo")(out)
@@ -342,6 +369,12 @@ class Attention(nn.Module):
         ``lengths``); tables/lengths ride as the ``paged_ctx`` call
         argument, broadcast across layers."""
         cfg = self.config
+        if not cfg.use_rope or cfg.attention_scale is not None:
+            raise NotImplementedError(
+                "the fused paged decode path applies rope and scales by "
+                "head_dim**-0.5; use_rope=False / attention_scale need "
+                "decode_attention='gather'"
+            )
         if cfg.kv_cache_dtype != "int8":
             raise ValueError(
                 "the fused paged decode path reads an int8 pool "
@@ -498,6 +531,14 @@ class Transformer(nn.Module):
     """
 
     config: TransformerConfig
+
+    def cache_leaf_kinds(self):
+        """What each leaf of the decode cache is to the serving engine
+        (models/decode_engine.py "Cache leaves by kind"): keys, values and
+        their int8 scales are paged by token, the sequence axis third from
+        the end of [..., seq, kv_heads, head_dim | 1]; `cache_index` is
+        the slot's position."""
+        return CACHE_LEAF_KINDS
 
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True,

@@ -82,7 +82,7 @@ def xla_attention(
 
 
 def attention(query, key, value, *, impl: str = "xla", causal: bool = True,
-              key_padding_mask=None):
+              key_padding_mask=None, softmax_scale: float | None = None):
     """Dispatch to the configured backend. `key_padding_mask` is an
     xla-impl feature (the flash/ring/ulysses kernels have no arbitrary-
     mask path — their masking is structural/causal); passing one there
@@ -93,6 +93,11 @@ def attention(query, key, value, *, impl: str = "xla", causal: bool = True,
             f"key_padding_mask is not supported by attention impl "
             f"{impl!r}; use impl='xla' for padded-batch encoders (or "
             "strip padding before a kernel impl)"
+        )
+    if softmax_scale is not None and impl in known[1:]:
+        raise NotImplementedError(
+            f"softmax_scale is not supported by attention impl {impl!r} "
+            "(the kernels scale by head_dim**-0.5); use impl='xla'"
         )
     if impl == "flash":
         from tf_yarn_tpu.ops.flash_attention import flash_attention
@@ -115,4 +120,5 @@ def attention(query, key, value, *, impl: str = "xla", causal: bool = True,
             "use xla | flash | ring | ulysses | ulysses_flash"
         )
     return xla_attention(query, key, value, causal=causal,
-                         key_padding_mask=key_padding_mask)
+                         key_padding_mask=key_padding_mask,
+                         softmax_scale=softmax_scale)

@@ -260,6 +260,18 @@ class SlotScheduler:
     max in-system requests (queued + active + suspended); a tier at
     its cap rejects with QueueFull (HTTP 429), keeping batch floods
     from ever crowding the interactive tier's queue.
+
+    Per-slot state (docs/Serving.md "State held once a slot"): a model
+    whose cache has leaves held once a slot (a recurrent state; the
+    engine's ``slot_state_leaves``) gets arrays ``[max_slots, ...]``
+    beside the block pool. Admission writes the prefill's final state, or
+    zeros, into the slot before its first replayed token, and the step
+    reads and writes the state in place. Everything that moves keys and
+    values WITHOUT the state steps aside by name: the prefix cache
+    neither registers nor hits (``prefix_skipped_stateful`` counts), and
+    the dense layout, the host swap tier, chunked prefill, the
+    speculative / fused window, tensor parallelism and prefix export /
+    import are refused with an error naming the feature and the leaves.
     """
 
     def __init__(
@@ -320,6 +332,11 @@ class SlotScheduler:
         self.engine = engine
         self.params = params
         self.max_slots = max_slots
+        # Cache leaves the model holds once a slot (fake engines in tests
+        # have none to name).
+        leaves_of = getattr(engine, "slot_state_leaves", None)
+        self._state_leaves: Tuple[str, ...] = tuple(
+            leaves_of(params)) if leaves_of else ()
         self.temperature = float(temperature)
         self.top_k = top_k
         self.top_p = top_p
@@ -459,6 +476,17 @@ class SlotScheduler:
         # A request the pool could not cover yet: admitted before the
         # queue on the next tick, once retirements free blocks.
         self._held: Optional[Tuple[Request, Response]] = None
+        self._state = None
+        self._state_bytes = 0
+        self._state_resets = 0
+        self._prefix_skipped_stateful = 0
+        self._prefix_capacity = int(prefix_cache_capacity or 0)
+        # What the expert layers counted (a model with `moe_stats`).
+        self._moe = {"assignments": 0, "assignments_here": 0,
+                     "layer_steps": 0, "experts_touched": 0,
+                     "load_max_sum": 0, "load_mean_sum": 0.0}
+        if self._state_leaves:
+            self._refuse_for_state(kv_host_blocks)
 
         if kv_layout == "paged":
             if self._max_seq_len is None:
@@ -492,6 +520,18 @@ class SlotScheduler:
             self._lengths = np.zeros((max_slots,), np.int32)
             self._cache = None
             kv_bytes = _cache_nbytes(self._pool)
+            if self._state_leaves:
+                try:
+                    self._state = engine.make_slot_state(params, max_slots)
+                except Exception as exc:
+                    raise RuntimeError(
+                        "serving cannot start: no room for the state of "
+                        f"{max_slots} slots "
+                        f"({', '.join(self._state_leaves)}) beside "
+                        f"{kv_bytes} bytes of KV pool: "
+                        f"{type(exc).__name__}: {exc}"
+                    ) from exc
+                self._state_bytes = _cache_nbytes(self._state)
         else:
             self._cache = engine.make_slot_cache(params, max_slots)
             self._block_size = None
@@ -513,6 +553,61 @@ class SlotScheduler:
             "serving/kv_cache_hbm_bytes_per_device", layout=kv_layout
         ).set(self._kv_bytes_per_device)
         self._registry.gauge("serving/tp_degree").set(self.tp_degree)
+        self._registry.gauge("serving/state_hbm_bytes").set(self._state_bytes)
+        if self._state is not None:
+            self._prove_state_step()
+
+    def _refuse_for_state(self, kv_host_blocks: int) -> None:
+        """A model with state held once a slot: every feature that would
+        move or replay keys and values without that state is refused here,
+        by name, before anything is allocated."""
+        leaves = ", ".join(self._state_leaves)
+        refused = {
+            "kv_layout='dense'": self.kv_layout != "paged",
+            "the host swap tier (kv_host_blocks: suspend / resume)":
+                kv_host_blocks > 0,
+            "chunked prefill (prefill_chunk)": self._chunked,
+            "the speculative step (spec_k)": self.spec_k > 0,
+            "decode_attention='fused'": self.decode_attention == "fused",
+            "tensor-parallel decode": self.tp_degree > 1,
+        }
+        for feature, asked in refused.items():
+            if asked:
+                raise ValueError(
+                    f"{feature} does not carry the state this model holds "
+                    f"once a slot ({leaves}) and would serve wrong tokens; "
+                    "it is refused until it does (docs/Serving.md \"State "
+                    "held once a slot\")"
+                )
+
+    def _prove_state_step(self) -> None:
+        """Compile and run the step once on the empty grid, so that state
+        slots that do not fit, or a step the compiler refuses, stop the
+        server at start-up with the reason — not every request with
+        `error` under a /healthz that says ok. Free slots' rows go to the
+        trash block and admission rewrites a slot's state, so the run
+        leaves nothing behind."""
+        import jax
+
+        try:
+            self._pool, self._state, emitted, _rngs, _counts = \
+                self.engine.paged_state_step(
+                    self.params, self._pool, self._state, self._tables,
+                    self._lengths, np.zeros((self.max_slots,), np.int32),
+                    self._rngs, np.zeros((self.max_slots,), bool),
+                    block_size=self._block_size,
+                    temperature=self.temperature, top_k=self.top_k,
+                    top_p=self.top_p,
+                )
+            jax.block_until_ready(emitted)
+        except Exception as exc:
+            raise RuntimeError(
+                "serving cannot start: the paged step of a model with "
+                f"per-slot state ({', '.join(self._state_leaves)}; "
+                f"{self._state_bytes} bytes of state for {self.max_slots} "
+                f"slots beside {self._kv_bytes} bytes of KV pool) did not "
+                f"compile or run: {type(exc).__name__}: {exc}"
+            ) from exc
 
     # -- submission (any thread) -------------------------------------------
 
@@ -914,7 +1009,13 @@ class SlotScheduler:
         # The step consuming the LAST prompt token samples the first
         # generated token, so at most len(prompt) - 1 tokens may come
         # from the prefix cache.
-        hit_tokens, hit_ids = self._prefix.lookup(prompt, len(prompt) - 1)
+        if self._state is not None:
+            # A hit would hand over keys and values and no state: the
+            # prefix cache stands aside (neither hits nor registers).
+            hit_tokens, hit_ids = 0, []
+        else:
+            hit_tokens, hit_ids = self._prefix.lookup(
+                prompt, len(prompt) - 1)
         if hit_ids:
             # Protect the matched blocks before any eviction can run.
             self._blocks.retain(hit_ids)
@@ -946,6 +1047,7 @@ class SlotScheduler:
                 "serving/prefill", request=request.id,
                 request_id=request.public_id, prefill=prefill_len,
             ):
+                row_cache = None
                 if prefill_len > 0:
                     row_cache, _logits = self.engine.prefill(
                         self.params,
@@ -957,10 +1059,21 @@ class SlotScheduler:
                         np.asarray(blocks[:n_pack], np.int32),
                         row_cache, prefill_len, self._block_size,
                     )
-                    # Offer the full-block prefix for sharing; the
-                    # partial tail block stays private (the replay
-                    # writes it).
-                    self._prefix.register(prompt, prefill_len, blocks)
+                    if self._state is None:
+                        # Offer the full-block prefix for sharing; the
+                        # partial tail block stays private (the replay
+                        # writes it).
+                        self._prefix.register(prompt, prefill_len, blocks)
+                if self._state is not None:
+                    # The prefill's final state, or zeros where nothing
+                    # was prefilled, before the first replayed token: a
+                    # reused slot never runs on its predecessor's state.
+                    self._state = self.engine.write_slot_state(
+                        self._state, slot, row_cache
+                    )
+                    self._state_resets += 1
+                    if self._prefix_capacity:
+                        self._prefix_skipped_stateful += 1
         self._tables[slot, :] = 0
         self._tables[slot, :len(blocks)] = blocks
         self._lengths[slot] = prefill_len
@@ -1186,6 +1299,13 @@ class SlotScheduler:
                 "prefix warm start needs kv_layout='paged' — the dense "
                 "layout has no block pool or prefix cache to transfer"
             )
+        if self._state_leaves:
+            raise ValueError(
+                f"prefix {kind} (/v1/blocks) ships keys and values and not "
+                "the state this model holds once a slot "
+                f"({', '.join(self._state_leaves)}); it is refused until it "
+                "does"
+            )
         op = _ControlOp(kind, arg)
         with self._control_lock:
             self._control.append(op)
@@ -1383,7 +1503,17 @@ class SlotScheduler:
                 else:
                     tokens[slot] = state.last_token
                     mask[slot] = True
-            if self.kv_layout == "paged":
+            counts = None
+            if self._state is not None:
+                self._pool, self._state, emitted, rngs, counts = \
+                    self.engine.paged_state_step(
+                        self.params, self._pool, self._state, self._tables,
+                        self._lengths, tokens, self._rngs, mask,
+                        block_size=self._block_size,
+                        temperature=self.temperature, top_k=self.top_k,
+                        top_p=self.top_p,
+                    )
+            elif self.kv_layout == "paged":
                 self._pool, emitted, rngs = self.engine.paged_step(
                     self.params, self._pool, self._tables, self._lengths,
                     tokens, self._rngs, mask,
@@ -1397,12 +1527,21 @@ class SlotScheduler:
                     temperature=self.temperature, top_k=self.top_k,
                     top_p=self.top_p,
                 )
+            # Asked for now, so that the copies to the host follow the
+            # program with no word from the host in between: one wait
+            # under `serving/step_sync` in place of one a result.
+            for result in (emitted, rngs, counts):
+                if hasattr(result, "copy_to_host_async"):  # a device array
+                    result.copy_to_host_async()
         with telemetry.span("serving/step_sync") as sync_span:
             # The tick's one host sync: every slot's token in one transfer.
             emitted = np.asarray(emitted)
             # np.array (copy): admissions write PRNGKey rows into this
             # buffer, and np.asarray of a device array is read-only.
             self._rngs = np.array(rngs)
+            if counts is not None:
+                # Ready with the tokens: the same program returned them.
+                counts = np.asarray(counts)
             # Freed here, under this span: left to the function's return
             # the device buffer's release took 0.5-0.8 ms a tick on a v5e
             # inside `serving/step` and under none of its children.
@@ -1413,6 +1552,7 @@ class SlotScheduler:
             now = time.monotonic()
             prefill_tokens = 0
             decode_tokens = 0
+            gaps = self._registry.histogram("serving/inter_token_latency_ms")
             for slot in active:
                 state = self._slots[slot]
                 if self.kv_layout == "paged":
@@ -1437,9 +1577,7 @@ class SlotScheduler:
                 if first:
                     self._observe_ttft(state)
                 elif state.last_emit_at is not None:
-                    self._registry.histogram(
-                        "serving/inter_token_latency_ms"
-                    ).observe((now - state.last_emit_at) * 1e3)
+                    gaps.observe((now - state.last_emit_at) * 1e3)
                 state.last_emit_at = now
                 eos = state.request.params.eos_token
                 if eos is not None and token == eos:
@@ -1447,9 +1585,25 @@ class SlotScheduler:
                 elif state.emitted >= state.request.params.max_new_tokens:
                     self._retire(slot, FINISH_LENGTH, retired)
             self._account_tokens(prefill_tokens, decode_tokens)
+            if counts is not None and counts.size:
+                self._count_experts(counts)
             emit_span.args.update(
                 tokens=decode_tokens, retired=len(retired) - was_retired
             )
+
+    def _count_experts(self, counts: np.ndarray) -> None:
+        """One step's `[layers, 1 + held experts]`: the active slots'
+        assignments over all the deployment's experts, then the tokens
+        that reached each expert held here. A layer-step is one expert
+        layer in one step."""
+        load = counts[:, 1:]
+        tally = self._moe
+        tally["assignments"] += int(counts[:, 0].sum())
+        tally["assignments_here"] += int(load.sum())
+        tally["layer_steps"] += int(load.shape[0])
+        tally["experts_touched"] += int((load > 0).sum())
+        tally["load_max_sum"] += int(load.max(axis=1).sum())
+        tally["load_mean_sum"] += float(load.mean(axis=1).sum())
 
     def _observe_ttft(self, state) -> None:
         # The unlabeled histogram is the back-compat aggregate; the
@@ -1865,6 +2019,19 @@ class SlotScheduler:
             "inflight": tier_inflight,
             "caps": dict(self.tier_caps),
         }
+        if self._state is not None:
+            snap["state_leaves"] = list(self._state_leaves)
+            snap["state_bytes"] = self._state_bytes
+            snap["state_resets"] = self._state_resets
+            snap["prefix_skipped_stateful"] = self._prefix_skipped_stateful
+        if self._moe["layer_steps"]:
+            tally = self._moe
+            snap.update({"moe_" + key: value for key, value in tally.items()})
+            snap["moe_load_max_over_mean"] = round(
+                tally["load_max_sum"] / tally["load_mean_sum"], 4
+            ) if tally["load_mean_sum"] else None
+            snap["moe_experts_touched_per_layer_step"] = round(
+                tally["experts_touched"] / tally["layer_steps"], 4)
         if self._windowed:
             snap["spec"] = {
                 "proposed_tokens": self._spec_proposed,

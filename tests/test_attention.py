@@ -1,6 +1,7 @@
 """Attention-backend correctness: flash (pallas, interpret on CPU) and
 ring (shard_map over sp) must match the XLA reference exactly enough."""
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -324,3 +325,250 @@ def test_key_padding_mask_rejected_on_kernel_impls():
     for impl in ("flash", "ring", "ulysses"):
         with pytest.raises(NotImplementedError, match="key_padding_mask"):
             attention(q, k, v, impl=impl, key_padding_mask=mask)
+
+
+# --------------------------------------------------------------------------
+# The GQA contraction of `xla_attention`: query heads viewed as
+# [H_kv, rep] against K/V as the cache holds them, no repeated copy.
+# --------------------------------------------------------------------------
+
+def _repeat_reference(q, k, v, *, causal, offset=0, mask=None, scale=None):
+    """Plain float32 attention with the KV heads repeated explicitly: query
+    head h reads KV head h // rep."""
+    q, k, v = (np.asarray(x, np.float32) for x in (q, k, v))
+    rep = q.shape[2] // k.shape[2]
+    k, v = np.repeat(k, rep, axis=2), np.repeat(v, rep, axis=2)
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    logits = np.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    keep = np.ones(logits.shape, bool)
+    if causal:
+        q_pos = np.arange(q.shape[1])[:, None] + offset
+        keep &= (q_pos >= np.arange(k.shape[1])[None, :])[None, None]
+    if mask is not None:
+        keep &= np.asarray(mask, bool)[:, None, None, :]
+    logits = np.where(keep, logits, -np.inf)
+    top = np.max(logits, axis=-1, keepdims=True)
+    weights = np.exp(logits - np.where(np.isfinite(top), top, 0.0))
+    total = weights.sum(axis=-1, keepdims=True)
+    probs = np.divide(weights, total, out=np.zeros_like(weights),
+                      where=total > 0)  # a row with no key at all: zeros
+    return np.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _padding(batch, s_kv):
+    """Row 0 whole, row 1 with a padded tail, the last row fully padded."""
+    mask = np.ones((batch, s_kv), bool)
+    mask[1, s_kv - 5:] = False
+    mask[-1] = False
+    return mask
+
+
+# name -> (S_q, S_kv, keyword arguments of xla_attention)
+_GQA_CASES = {
+    "decode": (1, 24, dict(causal=True, segment_offset=17)),
+    "prefill_against_cache": (5, 24, dict(causal=True, segment_offset=7)),
+    "square_causal": (16, 16, dict(causal=True)),
+    "square_full": (16, 16, dict(causal=False)),
+    "padding_mask": (16, 16, dict(causal=False,
+                                  key_padding_mask=_padding(3, 16))),
+    "causal_padding_mask": (16, 16, dict(causal=True,
+                                         key_padding_mask=_padding(3, 16))),
+    "softmax_scale": (16, 16, dict(causal=True, softmax_scale=0.05)),
+}
+
+
+def _gqa_inputs(s_q, s_kv, rep, dtype, seed=30):
+    """8 query heads, all different, over 8 // rep KV heads, all different:
+    a wrong (group, member) order reads another head's keys and fails."""
+    rng = np.random.RandomState(seed)
+    mk = lambda *shape: jnp.asarray(rng.randn(*shape).astype(np.float32), dtype)
+    return mk(3, s_q, 8, 16), mk(3, s_kv, 8 // rep, 16), mk(3, s_kv, 8 // rep, 16)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("rep", [1, 4, 8])
+@pytest.mark.parametrize("case", sorted(_GQA_CASES))
+def test_xla_attention_matches_explicit_repeat(case, rep, dtype):
+    s_q, s_kv, kwargs = _GQA_CASES[case]
+    q, k, v = _gqa_inputs(s_q, s_kv, rep, dtype)
+    out = jax.jit(lambda q, k, v: xla_attention(q, k, v, **kwargs))(q, k, v)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    ref = _repeat_reference(
+        q, k, v, causal=kwargs["causal"],
+        offset=kwargs.get("segment_offset", 0),
+        mask=kwargs.get("key_padding_mask"),
+        scale=kwargs.get("softmax_scale"))
+    # float32: only the order of the sums differs (16- and 24-term dots of
+    # unit normals), a few ulps of values up to ~3. bfloat16: the same
+    # mathematics with the logits, the probabilities and the output each
+    # rounded to an 8-bit mantissa (2**-8 relative): logits reach ~4 here,
+    # so a logit is off by up to 0.016 and a weight by 1.6 %; a wrong head
+    # order is off by ~1.
+    atol = 1e-5 if dtype == jnp.float32 else 4e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32), ref, atol=atol,
+                               rtol=0)
+    if "key_padding_mask" in kwargs:
+        assert not np.asarray(out, np.float32)[-1].any()  # fully padded row
+
+
+@pytest.mark.parametrize("rep", [2, 4, 8])
+def test_gqa_head_order_is_group_major(rep):
+    """KV head g holds the constant g + 1 as its values, so query head h
+    must come out as exactly h // rep + 1 whatever its scores are."""
+    q, k, _ = _gqa_inputs(1, 24, rep, jnp.float32)
+    n_kv = 8 // rep
+    v = jnp.broadcast_to(
+        jnp.arange(1, n_kv + 1, dtype=jnp.float32)[None, None, :, None],
+        k.shape)
+    out = xla_attention(q, k, v, causal=True, segment_offset=23)
+    want = np.broadcast_to(
+        (np.arange(8) // rep + 1.0)[None, None, :, None], out.shape)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-6)
+
+
+@pytest.mark.parametrize("rep", [1, 4, 8])
+def test_xla_attention_gqa_gradients_match_explicit_repeat(rep):
+    """Square causal GQA, the training shape: gradients with respect to q, k
+    and v against the explicit-repeat mathematics in jax.numpy (its dK and
+    dV sum the rep members of a group; the contraction does it inside)."""
+    q, k, v = _gqa_inputs(16, 16, rep, jnp.float32)
+
+    def repeated(q, k, v):
+        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+        mask = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool))
+        probs = jax.nn.softmax(
+            jnp.where(mask[None, None], logits, jnp.finfo(jnp.float32).min))
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+    loss = lambda fn: lambda q, k, v: (
+        lambda o: (o * jnp.cos(o)).sum())(fn(q, k, v))  # non-trivial cotangent
+    grads = jax.grad(
+        loss(lambda q, k, v: xla_attention(q, k, v, causal=True)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(repeated), argnums=(0, 1, 2))(q, k, v)
+    for got, ref in zip(grads, want):
+        assert got.shape == ref.shape
+        # float32 on both sides; only the order of the sums differs.
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
+
+
+# --------------------------------------------------------------------------
+# Structural guard: in the serving steps' jaxprs nothing but the logits and
+# the probabilities has both the query's head count and the cache's length.
+# --------------------------------------------------------------------------
+
+# Sizes no other dimension of the tiny models takes, so a match is a match:
+# 10 query heads over 2 KV heads (rep 5), a 48-token cache, head_dim 8.
+_G_HEADS, _G_KV, _G_SEQ, _G_DIM = 10, 2, 48, 8
+_G_REP = _G_HEADS // _G_KV
+_G_SLOTS, _G_BLOCK = 3, 4
+
+
+def _all_shapes(jaxpr):
+    """(primitive, shape) of every intermediate, nested jaxprs included."""
+    from tf_yarn_tpu.analysis.jaxpr_engine import _walk_jaxpr
+
+    for eqn in _walk_jaxpr(jaxpr):
+        for var in eqn.outvars:
+            if hasattr(var.aval, "shape"):
+                yield eqn.primitive.name, tuple(var.aval.shape)
+
+
+def _head_count_over_cache(jaxpr):
+    """Intermediates shaped by the query's head count (whole, or as
+    [H_kv, rep]) and the cache's length that are not logits-shaped
+    ([..., H_kv, rep, S_q, S_kv], no head_dim)."""
+    found = []
+    for name, shape in _all_shapes(jaxpr):
+        grouped = any(shape[i:i + 2] == (_G_KV, _G_REP)
+                      for i in range(len(shape) - 1))
+        if _G_SEQ not in shape or not (grouped or _G_HEADS in shape):
+            continue
+        if shape[-1] == _G_SEQ and _G_DIM not in shape:
+            continue  # logits, mask, probabilities
+        found.append((name, shape))
+    return found
+
+
+def _paged_avals(model):
+    """(params, pool, row, the step's five host arrays) as shapes: 3 slots
+    of 48 tokens in blocks of 4."""
+    from tf_yarn_tpu.models import decode_engine
+
+    params = nn.meta.unbox(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))
+    params = {"params": params["params"]}
+    row = decode_engine._decode_cache_aval(model, params)
+    per_slot = _G_SEQ // _G_BLOCK
+    pool = decode_engine.paged_pool_avals(
+        model, row, _G_SLOTS * per_slot + 1, _G_BLOCK)
+    host = (jax.ShapeDtypeStruct((_G_SLOTS, per_slot), jnp.int32),  # tables
+            jax.ShapeDtypeStruct((_G_SLOTS,), jnp.int32),           # lengths
+            jax.ShapeDtypeStruct((_G_SLOTS,), jnp.int32),           # tokens
+            jax.ShapeDtypeStruct((_G_SLOTS, 2), jnp.uint32),        # rngs
+            jax.ShapeDtypeStruct((_G_SLOTS,), bool))                # mask
+    return params, pool, row, host
+
+
+def _paged_step_jaxpr():
+    from tf_yarn_tpu.models import decode_engine, transformer
+
+    model = transformer.Transformer(transformer.TransformerConfig.tiny(
+        scan_layers=False, remat=False, max_seq_len=_G_SEQ, dtype=jnp.float32,
+        d_model=_G_HEADS * _G_DIM, n_heads=_G_HEADS, n_kv_heads=_G_KV))
+    params, pool, _, host = _paged_avals(model)
+    step = decode_engine.build_paged_step_fn(model, _G_BLOCK, 0.0, None, None)
+    return jax.make_jaxpr(step)(params, pool, *host).jaxpr
+
+
+def _paged_state_step_jaxpr():
+    from tf_yarn_tpu.models import decode_engine, hybrid
+
+    model = hybrid.HybridLM(hybrid.HybridConfig(
+        vocab_size=64, d_model=_G_HEADS * _G_DIM, max_seq_len=_G_SEQ,
+        layer_types=("mamba", "attention"), n_heads=_G_HEADS,
+        n_kv_heads=_G_KV, mamba_heads=4, mamba_head_dim=40, mamba_d_state=16,
+        mamba_chunk=8, num_experts=4, num_experts_here=4,
+        experts_per_token=2, d_expert=24, d_shared=24, dtype=jnp.float32,
+        param_dtype=jnp.float32))
+    params, pool, row, host = _paged_avals(model)
+    state = jax.tree_util.tree_map(
+        lambda lay, aval: jax.ShapeDtypeStruct(
+            (_G_SLOTS,) + tuple(aval.shape), aval.dtype)
+        if lay.kind == decode_engine.SLOT else None,
+        decode_engine.cache_layout(model, row), row)
+    step = decode_engine.build_paged_state_step_fn(
+        model, _G_BLOCK, 0.0, None, None)
+    return jax.make_jaxpr(step)(params, pool, state, *host).jaxpr
+
+
+def _repeated_attention_jaxpr():
+    """What the guard is for: the contraction over an explicit repeat."""
+    from tf_yarn_tpu.ops.attention import _repeat_kv
+
+    def repeated(q, k, v):
+        k, v = _repeat_kv(k, v, _G_REP)
+        probs = jax.nn.softmax(jnp.einsum("bqhd,bkhd->bhqk", q, k))
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+    kv = jax.ShapeDtypeStruct((_G_SLOTS, _G_SEQ, _G_KV, _G_DIM), jnp.float32)
+    return jax.make_jaxpr(repeated)(
+        jax.ShapeDtypeStruct((_G_SLOTS, 1, _G_HEADS, _G_DIM), jnp.float32),
+        kv, kv).jaxpr
+
+
+@pytest.mark.parametrize("program,copies", [
+    (_paged_step_jaxpr, False),
+    (_paged_state_step_jaxpr, False),
+    (_repeated_attention_jaxpr, True),  # the guard sees what it guards
+], ids=["paged_step", "hybrid_paged_state_step", "explicit_repeat"])
+def test_no_query_head_copy_of_the_cache_view(program, copies):
+    jaxpr = program()
+    shapes = [shape for _, shape in _all_shapes(jaxpr)]
+    # The program is the one meant: it has the logits, grouped.
+    assert copies or any(
+        shape[-4:] == (_G_KV, _G_REP, 1, _G_SEQ) for shape in shapes)
+    found = _head_count_over_cache(jaxpr)
+    assert bool(found) == copies, found

@@ -1011,6 +1011,14 @@ def test_fleet_observability_plane_end_to_end():
             t.start()
         for t in warm:
             t.join(timeout=600)
+        # The router observes a request after it has answered it: let the
+        # four warm observations land before the registry is emptied, or
+        # a late one is counted with the eight below.
+        routed = metrics.histogram("fleet/routed_request_seconds",
+                                   path="/v1/generate", outcome="ok")
+        deadline = time.monotonic() + 30
+        while routed.count < len(warm) and time.monotonic() < deadline:
+            time.sleep(0.01)
         metrics.clear()
 
         # Spy on the shared TTFT histogram: every raw server-side TTFT

@@ -481,9 +481,8 @@ def test_submit_span_carries_the_programs_id_where_none_came():
 # --------------------------------------------------------------------------
 
 SCOPES = ("embed", "norm", "attention/qkv", "attention/rope",
-          "attention/kv_write", "attention/kv_gather", "attention/repeat_kv",
-          "attention/scores", "attention/values", "attention/out", "mlp",
-          "lm_head", "sample")
+          "attention/kv_write", "attention/kv_gather", "attention/scores",
+          "attention/values", "attention/out", "mlp", "lm_head", "sample")
 
 
 def test_paged_step_operations_carry_every_scope():

@@ -52,32 +52,39 @@ def xla_attention(
     """
     b, s_q, n_heads, head_dim = query.shape
     _, s_kv, n_kv, _ = key.shape
-    key, value = _repeat_kv(key, value, n_heads // n_kv)
+    # GQA without a copy: query head h is member h % rep of group h // rep
+    # (the order `_repeat_kv` gives), so the heads view as [H_kv, rep] and
+    # contract against K/V as the cache holds them. Only the logits carry
+    # both the query's head count and the keys' length.
+    rep = n_heads // n_kv
+    grouped = query.reshape(b, s_q, n_kv, rep, head_dim)
     scale = softmax_scale if softmax_scale is not None else head_dim**-0.5
     # Scopes, not spans: metadata on the device operations, so a profile
     # names scores, softmax and values apart (docs/Observability.md).
     with jax.named_scope("attention/scores"):
-        logits = jnp.einsum("bqhd,bkhd->bhqk", query, key) * scale
+        logits = jnp.einsum("bqgrd,bkgd->bgrqk", grouped, key) * scale
         logits = logits.astype(jnp.float32)
         neg_inf = jnp.finfo(jnp.float32).min
         if causal:
             q_pos = jnp.arange(s_q)[:, None] + segment_offset
             k_pos = jnp.arange(s_kv)[None, :]
             mask = q_pos >= k_pos
-            logits = jnp.where(mask[None, None], logits, neg_inf)
+            logits = jnp.where(mask[None, None, None], logits, neg_inf)
         if key_padding_mask is not None:
-            keep = key_padding_mask.astype(bool)[:, None, None, :]  # [B,1,1,Skv]
+            # [B,1,1,1,Skv] over the logits' [B,Hkv,rep,S,Skv].
+            keep = key_padding_mask.astype(bool)[:, None, None, None, :]
             logits = jnp.where(keep, logits, neg_inf)
         probs = jax.nn.softmax(logits, axis=-1).astype(query.dtype)
     with jax.named_scope("attention/values"):
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, value)
+        out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, value)
         if key_padding_mask is not None:
             # A fully-padded row (no real keys) would otherwise get a
             # silent uniform softmax over finfo.min logits — finite
-            # garbage. Zero those rows' outputs instead: [B,1,1,1]
-            # broadcast over out's [B,S,H,D].
-            has_any_key = jnp.any(keep, axis=-1)[..., None]
+            # garbage. Zero those rows' outputs instead: [B,1,1,1,1]
+            # broadcast over out's [B,S,Hkv,rep,D].
+            has_any_key = jnp.any(keep, axis=-1, keepdims=True)
             out = jnp.where(has_any_key, out, jnp.zeros((), out.dtype))
+        out = out.reshape(b, s_q, n_heads, head_dim)
     return out
 
 

@@ -251,7 +251,7 @@ def bench_flagship_train(failures: list):
         "measured_at": time.strftime("%Y-%m-%dT%H:%M:%S%z"),
         "rows": table,
     }
-    for section in ("decode", "long_context", "serve", "fleet", "rank",
+    for section in ("decode", "long_context", "fleet", "rank",
                     "bert_base", "resnet50", "vit_base"):
         if previous.get(section):
             ab[section] = {
@@ -286,124 +286,6 @@ def bench_flagship_train(failures: list):
     except Exception as exc:
         _log(f"decode bench FAILED: {type(exc).__name__}: {exc}")
         failures.append("decode")
-    try:
-        serve = suite.bench_serve(tpu=True, tp=True, chunked=True,
-                                  overload=True, disagg=True)
-        ab["serve"] = serve
-        _write_ab(ab)
-        # Online-serving headline pair: continuous-batching
-        # throughput + tail TTFT, with the static-batching baseline
-        # alongside (same engine, same trace — policy-only delta).
-        for policy in ("continuous", "static"):
-            result[f"serve_{policy}_tokens_per_sec"] = (
-                serve[policy]["tokens_per_sec"]
-            )
-            result[f"serve_{policy}_ttft_p95_ms"] = (
-                serve[policy]["ttft_p95_ms"]
-            )
-        # KV-layout A/B: slots-per-GB-HBM is the concurrency-per-
-        # chip lever paged/int8 exist for (same trace, same slots).
-        for layout in ("dense", "paged", "paged_int8"):
-            row = (serve.get("layouts") or {}).get(layout) or {}
-            if "tokens_per_sec" in row:
-                result[f"serve_{layout}_tokens_per_sec"] = row[
-                    "tokens_per_sec"
-                ]
-                result[f"serve_{layout}_slots_per_gb_hbm"] = row.get(
-                    "slots_per_gb_hbm"
-                )
-        for key in ("paged_vs_dense_slots_per_gb",
-                    "paged_int8_vs_dense_slots_per_gb"):
-            if key in serve:
-                result[f"serve_{key}"] = serve[key]
-        # Speculative decoding A/B: exact vs k ∈ {2, 4} on the
-        # repeated-structure trace — tokens/s and accepted-tokens
-        # per step are the per-token latency lever's evidence.
-        for row_name, row in (
-            (serve.get("spec") or {}).get("rows") or {}
-        ).items():
-            if isinstance(row, dict) and "tokens_per_sec" in row:
-                result[f"serve_spec_{row_name}_tokens_per_sec"] = row[
-                    "tokens_per_sec"
-                ]
-                result[
-                    f"serve_spec_{row_name}_accepted_tokens_per_step"
-                ] = row.get("accepted_tokens_per_step")
-        # Tensor-parallel A/B: tokens/s per tp degree plus the
-        # per-device KV residency ratio (the capacity-per-chip
-        # claim; on a 1-chip rig the section records its skip note).
-        tp_ab = serve.get("tp") or {}
-        for row_name, row in (tp_ab.get("rows") or {}).items():
-            if isinstance(row, dict) and "tokens_per_sec" in row:
-                result[f"serve_tp_{row_name}_tokens_per_sec"] = row[
-                    "tokens_per_sec"
-                ]
-                result[
-                    f"serve_tp_{row_name}_kv_hbm_bytes_per_device"
-                ] = row.get("kv_hbm_bytes_per_device")
-        if "kv_per_device_ratio" in tp_ab:
-            result["serve_tp_kv_per_device_ratio"] = tp_ab[
-                "kv_per_device_ratio"
-            ]
-        # Chunked-prefill A/B: blocking vs chunked admission on the
-        # bimodal trace — inter-token-latency p95 is the no-stall
-        # claim (TTFT p95 rides along), streams must match.
-        chunked_ab = serve.get("chunked") or {}
-        for row_name, row in (chunked_ab.get("rows") or {}).items():
-            if isinstance(row, dict) and "itl_p95_ms" in row:
-                result[f"serve_chunked_{row_name}_itl_p95_ms"] = row[
-                    "itl_p95_ms"
-                ]
-                result[f"serve_chunked_{row_name}_ttft_p95_ms"] = (
-                    row.get("ttft_p95_ms")
-                )
-        if "itl_p95_ratio" in chunked_ab:
-            result["serve_chunked_itl_p95_ratio"] = chunked_ab[
-                "itl_p95_ratio"
-            ]
-        # KV-oversubscription A/B: hold-until-free vs suspend-to-
-        # host on the overload trace — peak streams is the capacity
-        # claim, interactive TTFT p95 the SLO it must not cost,
-        # streams_match_hold the bit-identity evidence.
-        overload_ab = serve.get("overload") or {}
-        for row_name, row in (overload_ab.get("rows") or {}).items():
-            if isinstance(row, dict) and "peak_streams" in row:
-                result[f"serve_overload_{row_name}_peak_streams"] = (
-                    row["peak_streams"]
-                )
-                result[
-                    f"serve_overload_{row_name}_interactive_ttft_p95_ms"
-                ] = row.get("interactive_ttft_p95_ms")
-        for key in ("peak_streams_ratio", "interactive_ttft_p95_ratio"):
-            if key in overload_ab:
-                result[f"serve_overload_{key}"] = overload_ab[key]
-        suspend_row = (overload_ab.get("rows") or {}).get(
-            "suspend") or {}
-        for key in ("suspends", "resumes", "streams_match_hold"):
-            if key in suspend_row:
-                result[f"serve_overload_{key}"] = suspend_row[key]
-        # Disaggregated-prefill A/B: offloaded vs local TTFT p95 on
-        # the bimodal trace through a real prefill replica over
-        # HTTP; streams_match_local is the bit-identity evidence
-        # and the fp-vs-int8 ratio the wire saving.
-        disagg_ab = serve.get("disagg") or {}
-        for row_name, row in (disagg_ab.get("rows") or {}).items():
-            if isinstance(row, dict) and "ttft_p95_ms" in row:
-                result[f"serve_disagg_{row_name}_ttft_p95_ms"] = row[
-                    "ttft_p95_ms"
-                ]
-        for key in ("ttft_p95_ratio", "wire_bytes_fp_over_int8"):
-            if key in disagg_ab:
-                result[f"serve_disagg_{key}"] = disagg_ab[key]
-        offloaded_row = (disagg_ab.get("rows") or {}).get(
-            "offloaded") or {}
-        for key in ("streams_match_local", "ships", "shipped_blocks"):
-            if key in offloaded_row:
-                result[f"serve_disagg_{key}"] = offloaded_row[key]
-        _log(f"serve: {serve}")
-    except Exception as exc:
-        _log(f"serve bench FAILED: {type(exc).__name__}: {exc}")
-        failures.append("serve")
     try:
         fleet = suite.bench_fleet(tpu=True)
         ab["fleet"] = fleet

@@ -524,708 +524,6 @@ def _spec_decode_ab(tpu: bool, ks=(2, 4)):
     }
 
 
-def _tp_serve_ab(tpu: bool, tp=2):
-    """Tensor-parallel decode A/B on ONE seeded Poisson trace: the same
-    requests serve through a tp=1 engine and a tp=`tp` engine (weights
-    placed by the logical rules, paged KV pool sharded by kv-heads),
-    reporting tokens/s and the per-DEVICE resident KV bytes. Streams
-    are asserted identical across rows — sharding is a placement
-    change, not a sampler change. On the CPU rig the tp "devices" are
-    threads contending on one socket, so the SPEED ratio there is NOT
-    evidence; the per-device HBM accounting is (the claim tp exists
-    for: a model bigger than one chip serving online)."""
-    import time
-
-    import flax.linen as nn
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tf_yarn_tpu import inference
-    from tf_yarn_tpu.models.decode_engine import DecodeEngine
-    from tf_yarn_tpu.models.transformer import Transformer, TransformerConfig
-    from tf_yarn_tpu.parallel.mesh import MeshSpec, build_mesh, select_devices
-
-    from tf_yarn_tpu.serving import SamplingParams, SlotScheduler
-
-    devices = select_devices()
-    if len(devices) < tp:
-        return {
-            "skipped": (
-                f"needs {tp} devices, have {len(devices)} — set "
-                f"TPU_YARN_VIRTUAL_DEVICES={tp} (or run on a slice) "
-                "before jax initializes"
-            ),
-        }
-    if tpu:
-        config = TransformerConfig(
-            vocab_size=32000, d_model=1024, n_layers=8, n_heads=16,
-            n_kv_heads=8, d_ff=4096, max_seq_len=2048, remat=False,
-            scan_layers=False,
-        )
-        n_requests, max_slots, prompt_len, max_new = 16, 8, 64, 128
-        block_size = 16
-    else:
-        # f32 on the CPU rig: a random-init bf16 model's logits sit on
-        # a ~1e-3 grid, so greedy near-ties flip under ANY reduction
-        # regrouping (sharded or not — the paged-vs-legacy tests pin
-        # f32 for the same reason); f32 keeps the match flag meaningful.
-        config = TransformerConfig.tiny(
-            scan_layers=False, max_seq_len=128, dtype=jnp.float32,
-        )
-        n_requests, max_slots, prompt_len, max_new = 6, 4, 12, 24
-        block_size = 8
-    model = Transformer(config)
-    rng = np.random.RandomState(11)
-    params = nn.meta.unbox(
-        model.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, prompt_len), jnp.int32)
-        )
-    )
-    prompts = [
-        rng.randint(0, config.vocab_size, (prompt_len,)).tolist()
-        for _ in range(n_requests)
-    ]
-    worst_tokens = prompt_len + max_new - 1
-    num_blocks = max_slots * (-(-worst_tokens // block_size)) + 1
-
-    def run_row(degree):
-        mesh = None
-        row_params = params
-        if degree > 1:
-            mesh = build_mesh(MeshSpec(tp=degree), devices[:degree])
-            row_params = inference.shard_restored_params(
-                model, params, mesh
-            )
-        engine = DecodeEngine(model, mesh=mesh)
-        scheduler = SlotScheduler(
-            engine, row_params, max_slots=max_slots,
-            queue_capacity=n_requests, kv_layout="paged",
-            block_size=block_size, num_blocks=num_blocks,
-        )
-        scheduler.start()
-        try:
-            scheduler.submit(
-                prompts[0], SamplingParams(max_new_tokens=2)
-            ).result(timeout=600)
-            t0 = time.perf_counter()
-            responses = [
-                scheduler.submit(p, SamplingParams(max_new_tokens=max_new))
-                for p in prompts
-            ]
-            streams = [r.result(timeout=600) for r in responses]
-            wall = time.perf_counter() - t0
-            stats = scheduler.stats()
-            return streams, {
-                "tp": degree,
-                "tokens_per_sec": round(n_requests * max_new / wall, 2),
-                "wall_s": round(wall, 3),
-                "kv_hbm_bytes": stats["kv_cache_hbm_bytes"],
-                "kv_hbm_bytes_per_device": stats[
-                    "kv_cache_hbm_bytes_per_device"
-                ],
-            }
-        finally:
-            scheduler.close()
-
-    base_streams, base_row = run_row(1)
-    tp_streams, tp_row = run_row(tp)
-    tp_row["streams_match_tp1"] = tp_streams == base_streams
-    return {
-        "requests": n_requests,
-        "max_slots": max_slots,
-        "prompt_len": prompt_len,
-        "max_new_tokens": max_new,
-        "rows": {"tp1": base_row, f"tp{tp}": tp_row},
-        "kv_per_device_ratio": (
-            round(
-                tp_row["kv_hbm_bytes_per_device"]
-                / base_row["kv_hbm_bytes_per_device"], 3
-            )
-            if base_row["kv_hbm_bytes_per_device"] else None
-        ),
-        "note": (
-            "CPU-rig tokens/s ratios are socket contention, not "
-            "evidence; the per-device KV accounting is the claim"
-        ),
-    }
-
-
-def _chunked_serve_ab(tpu: bool):
-    """Blocking vs chunked admission prefill A/B on ONE seeded Poisson
-    trace with a BIMODAL prompt mix — short decode-bound requests
-    streaming tokens while occasional long prompts (2k tokens on TPU
-    shapes) arrive. Blocking admission runs the whole prompt's prefill
-    inside the tick, so every resident decode stream stalls for it;
-    chunked admission replays the prompt in fixed windows under a
-    per-tick budget, so decode slots advance every tick. The rows
-    report TTFT p95 AND inter-token-latency p95 (the pooled per-request
-    gap series — the long-prompt stall shows up as ITL tail, which is
-    the metric chunking exists to flatten), and the chunked row asserts
-    its streams bit-identical to blocking (chunking is a scheduling
-    change, not a sampler change)."""
-    import time
-
-    import flax.linen as nn
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tf_yarn_tpu.models.decode_engine import DecodeEngine
-    from tf_yarn_tpu.models.transformer import Transformer, TransformerConfig
-    from tf_yarn_tpu.parallel.mesh import select_devices
-    from tf_yarn_tpu.serving import SamplingParams, SlotScheduler
-
-    select_devices()
-    if tpu:
-        config = TransformerConfig(
-            vocab_size=32000, d_model=1024, n_layers=8, n_heads=16,
-            n_kv_heads=8, d_ff=4096, max_seq_len=2560, remat=False,
-            scan_layers=False,
-        )
-        n_short, n_long, mean_gap_s = 24, 4, 0.02
-        short_len, short_new = 32, 192
-        long_len, long_new = 2048, 16
-        block_size, max_slots = 16, 8
-        chunk, budget = 256, 256
-    else:
-        # f32 on the CPU rig: chunked replays the prompt through the
-        # windowed program instead of the prefill program, so bf16
-        # greedy near-ties could flip on reduction regrouping alone
-        # (same reason _tp_serve_ab pins f32) — f32 keeps the
-        # streams_match_blocking flag meaningful.
-        config = TransformerConfig.tiny(
-            scan_layers=False, max_seq_len=128, dtype=jnp.float32,
-        )
-        n_short, n_long, mean_gap_s = 8, 2, 0.005
-        short_len, short_new = 6, 16
-        long_len, long_new = 48, 4
-        block_size, max_slots = 8, 4
-        chunk, budget = 8, 8
-    model = Transformer(config)
-    rng = np.random.RandomState(13)
-    params = nn.meta.unbox(
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    )
-    engine = DecodeEngine(model)
-
-    # One seeded Poisson trace, bimodal: mostly short decode-bound
-    # requests with long prompts salted through the middle of the run
-    # (a long prompt arriving while decode streams are live is the
-    # scenario under test).
-    n_requests = n_short + n_long
-    arrivals = np.cumsum(rng.exponential(mean_gap_s, n_requests))
-    long_at = set(
-        rng.choice(np.arange(2, n_requests), n_long, replace=False).tolist()
-    )
-    requests = []
-    for i in range(n_requests):
-        length, max_new = (
-            (long_len, long_new) if i in long_at else (short_len, short_new)
-        )
-        requests.append((
-            float(arrivals[i]),
-            rng.randint(0, config.vocab_size, (length,)).tolist(),
-            max_new,
-        ))
-    total_tokens = sum(m for _, _, m in requests)
-    worst_tokens = long_len + long_new - 1
-    num_blocks = max_slots * (-(-worst_tokens // block_size)) + 1
-
-    def run_row(chunked: bool):
-        kwargs = dict(
-            kv_layout="paged", block_size=block_size, num_blocks=num_blocks,
-        )
-        if chunked:
-            kwargs.update(
-                prefill_chunk=chunk, prefill_budget_per_tick=budget,
-            )
-        scheduler = SlotScheduler(
-            engine, params, max_slots=max_slots,
-            queue_capacity=n_requests, **kwargs,
-        )
-        scheduler.start()
-        try:
-            # Warmup: compile both prompt shapes' admission path + the
-            # row's step program outside the timed window.
-            for length in (short_len, long_len):
-                scheduler.submit(
-                    [1] * length, SamplingParams(max_new_tokens=2)
-                ).result(timeout=600)
-            t0 = time.perf_counter()
-            responses = []
-            for offset, prompt, max_new in requests:
-                lag = t0 + offset - time.perf_counter()
-                if lag > 0:
-                    time.sleep(lag)
-                responses.append((scheduler.submit(
-                    prompt, SamplingParams(max_new_tokens=max_new)
-                ), offset))
-            streams = [r.result(timeout=600) for r, _ in responses]
-            wall = time.perf_counter() - t0
-            # TTFT against the trace's arrival time; ITL pooled over
-            # every request's consecutive-arrival gaps.
-            ttfts = [
-                (response.first_token_at - t0) - offset
-                for response, offset in responses
-            ]
-            gaps = [
-                gap
-                for response, _ in responses
-                for gap in response.inter_token_gaps_s()
-            ]
-            stats = scheduler.stats()
-            return streams, {
-                "prefill_chunk": stats["prefill_chunk"],
-                "prefill_budget_per_tick": stats["prefill_budget_per_tick"],
-                "tokens_per_sec": round(total_tokens / wall, 2),
-                "wall_s": round(wall, 3),
-                "ttft_p95_ms": round(
-                    1000 * float(np.percentile(ttfts, 95)), 2),
-                "itl_p95_ms": round(
-                    1000 * float(np.percentile(gaps, 95)), 2),
-                "itl_max_ms": round(1000 * max(gaps), 2),
-                "prefill_tokens": stats["prefill_tokens"],
-                "decode_tokens": stats["decode_tokens"],
-            }
-        finally:
-            scheduler.close()
-
-    blocking_streams, blocking_row = run_row(chunked=False)
-    chunked_streams, chunked_row = run_row(chunked=True)
-    chunked_row["streams_match_blocking"] = (
-        chunked_streams == blocking_streams
-    )
-    return {
-        "requests": n_requests,
-        "long_prompts": n_long,
-        "max_slots": max_slots,
-        "short": {"prompt_len": short_len, "max_new_tokens": short_new},
-        "long": {"prompt_len": long_len, "max_new_tokens": long_new},
-        "rows": {"blocking": blocking_row, "chunked": chunked_row},
-        "itl_p95_ratio": (
-            round(chunked_row["itl_p95_ms"] / blocking_row["itl_p95_ms"], 3)
-            if blocking_row["itl_p95_ms"] else None
-        ),
-        "note": (
-            "itl_p95/itl_max carry the claim: blocking admission stalls "
-            "live decode streams for the long prompt's whole prefill; "
-            "chunking bounds the stall at one window per tick. On the "
-            "CPU rig the width-W window multiplies per-tick FLOPs on a "
-            "serial core, so the ITL ratio there is NOT evidence (same "
-            "caveat as the tp rows) — on TPU shapes the window is "
-            "memory-bound like the exact step and the ratio is the "
-            "claim; streams_match_blocking is evidence on both"
-        ),
-    }
-
-
-def _disagg_serve_ab(tpu: bool):
-    """Local vs DISAGGREGATED prefill A/B on the same bimodal Poisson
-    trace as `_chunked_serve_ab`: short decode-bound requests streaming
-    while occasional long prompts arrive. The local row prefills every
-    prompt on the decode replica; the offloaded row ships each
-    above-threshold prompt to a real PrefillServer over HTTP first
-    (PrefillClient two-stage dispatch), so admission's prefix hit skips
-    the shipped span. Rows report TTFT p95; the offloaded row asserts
-    its streams bit-identical to local (the shipped blocks hold the
-    exact KV local prefill would compute) and counts ships/blocks. The
-    fp-vs-int8 wire-bytes ratio rides along: the SAME long prompt
-    exported through an fp worker vs an int8 worker — int8 blocks ride
-    the wire as int8, the ~3x transfer saving."""
-    import dataclasses
-    import json as json_lib
-    import time
-
-    import flax.linen as nn
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tf_yarn_tpu.models.decode_engine import DecodeEngine
-    from tf_yarn_tpu.models.transformer import Transformer, TransformerConfig
-    from tf_yarn_tpu.parallel.mesh import select_devices
-    from tf_yarn_tpu.serving import SamplingParams, SlotScheduler
-    from tf_yarn_tpu.serving.prefill import (
-        PrefillClient,
-        PrefillServer,
-        PrefillTierConfig,
-        PrefillWorker,
-    )
-    from tf_yarn_tpu.serving.server import encode_block_wire
-
-    select_devices()
-    if tpu:
-        config = TransformerConfig(
-            vocab_size=32000, d_model=1024, n_layers=8, n_heads=16,
-            n_kv_heads=8, d_ff=4096, max_seq_len=2560, remat=False,
-            scan_layers=False,
-        )
-        n_short, n_long, mean_gap_s = 24, 4, 0.02
-        short_len, short_new = 32, 192
-        long_len, long_new = 2048, 16
-        block_size, max_slots = 16, 8
-        offload_threshold = 256
-    else:
-        # f32 for the same reason _chunked_serve_ab pins it: the
-        # streams_match_local bit must reflect scheduling, not bf16
-        # near-tie flips.
-        config = TransformerConfig.tiny(
-            scan_layers=False, max_seq_len=128, dtype=jnp.float32,
-        )
-        n_short, n_long, mean_gap_s = 8, 2, 0.005
-        short_len, short_new = 6, 16
-        long_len, long_new = 48, 4
-        block_size, max_slots = 8, 4
-        offload_threshold = 16
-    model = Transformer(config)
-    rng = np.random.RandomState(13)
-    params = nn.meta.unbox(
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    )
-    engine = DecodeEngine(model)
-
-    # The bimodal trace (same construction as _chunked_serve_ab): long
-    # prompts salted through the middle of a short-request stream.
-    n_requests = n_short + n_long
-    arrivals = np.cumsum(rng.exponential(mean_gap_s, n_requests))
-    long_at = set(
-        rng.choice(np.arange(2, n_requests), n_long, replace=False).tolist()
-    )
-    requests = []
-    for i in range(n_requests):
-        length, max_new = (
-            (long_len, long_new) if i in long_at else (short_len, short_new)
-        )
-        requests.append((
-            float(arrivals[i]),
-            rng.randint(0, config.vocab_size, (length,)).tolist(),
-            max_new,
-        ))
-    worst_tokens = long_len + long_new - 1
-    # Room for active slots AND the imported prefix entries the shipped
-    # long prompts land as (they stay evictable but count while hot).
-    num_blocks = (
-        max_slots * (-(-worst_tokens // block_size))
-        + n_long * (-(-long_len // block_size)) + 1
-    )
-
-    def run_row(client_factory=None):
-        scheduler = SlotScheduler(
-            engine, params, max_slots=max_slots,
-            queue_capacity=n_requests, kv_layout="paged",
-            block_size=block_size, num_blocks=num_blocks,
-        )
-        client = client_factory(scheduler) if client_factory else None
-        scheduler.start()
-        try:
-            for length in (short_len, long_len):
-                warm = [1] * length
-                if client is not None:
-                    client.maybe_ship(warm)
-                scheduler.submit(
-                    warm, SamplingParams(max_new_tokens=2)
-                ).result(timeout=600)
-            t0 = time.perf_counter()
-            responses = []
-            for offset, prompt, max_new in requests:
-                lag = t0 + offset - time.perf_counter()
-                if lag > 0:
-                    time.sleep(lag)
-                if client is not None:
-                    # The server-side hook: pull KV blocks from the
-                    # prefill tier BEFORE submitting.
-                    client.maybe_ship(prompt)
-                responses.append((scheduler.submit(
-                    prompt, SamplingParams(max_new_tokens=max_new)
-                ), offset))
-            streams = [r.result(timeout=600) for r, _ in responses]
-            wall = time.perf_counter() - t0
-            ttfts = [
-                (response.first_token_at - t0) - offset
-                for response, offset in responses
-            ]
-            stats = scheduler.stats()
-            row = {
-                "wall_s": round(wall, 3),
-                "ttft_p95_ms": round(
-                    1000 * float(np.percentile(ttfts, 95)), 2),
-                "prefill_tokens": stats["prefill_tokens"],
-                "prefix_cache_hit_rate": (
-                    stats.get("prefix_cache", {}).get("hit_rate")
-                ),
-            }
-            if client is not None:
-                row.update(client.stats())
-            return streams, row
-        finally:
-            scheduler.close()
-
-    local_streams, local_row = run_row()
-
-    worker = PrefillWorker(
-        engine, params, block_size=block_size,
-        num_blocks=num_blocks,
-    )
-    server = PrefillServer(worker)
-    server.start()
-    try:
-        offloaded_streams, offloaded_row = run_row(
-            lambda scheduler: PrefillClient(
-                PrefillTierConfig(
-                    offload_threshold=offload_threshold,
-                    endpoint=server.endpoint,
-                ),
-                scheduler, block_size=block_size,
-            )
-        )
-        offloaded_row["streams_match_local"] = (
-            offloaded_streams == local_streams
-        )
-
-        # fp-vs-int8 wire size on ONE long prompt: an int8 worker's
-        # quantized blocks ride the wire as int8.
-        long_prompt = next(
-            prompt for _, prompt, _ in requests if len(prompt) == long_len
-        )
-        fp_bytes = len(json_lib.dumps(encode_block_wire(
-            worker.prefill_prompt(long_prompt)
-        )))
-        int8_model = Transformer(dataclasses.replace(
-            config, kv_cache_dtype="int8"
-        ))
-        int8_worker = PrefillWorker(
-            DecodeEngine(int8_model), params, block_size=block_size,
-            num_blocks=num_blocks,
-        )
-        int8_bytes = len(json_lib.dumps(encode_block_wire(
-            int8_worker.prefill_prompt(long_prompt)
-        )))
-    finally:
-        server.stop()
-
-    return {
-        "requests": n_requests,
-        "long_prompts": n_long,
-        "max_slots": max_slots,
-        "offload_threshold": offload_threshold,
-        "short": {"prompt_len": short_len, "max_new_tokens": short_new},
-        "long": {"prompt_len": long_len, "max_new_tokens": long_new},
-        "rows": {"local": local_row, "offloaded": offloaded_row},
-        "ttft_p95_ratio": (
-            round(
-                offloaded_row["ttft_p95_ms"] / local_row["ttft_p95_ms"], 3
-            )
-            if local_row["ttft_p95_ms"] else None
-        ),
-        "wire_bytes_fp_over_int8": (
-            round(fp_bytes / int8_bytes, 2) if int8_bytes else None
-        ),
-        "note": (
-            "On the CPU rig both tiers share one socket, so the "
-            "offloaded row pays the long prefill AND the hop serially — "
-            "its TTFT ratio is scheduling evidence only, not the claim; "
-            "on real disaggregated hardware the prefill burst leaves "
-            "the decode replica entirely. streams_match_local and the "
-            "int8 wire ratio are evidence on both rigs"
-        ),
-    }
-
-
-def _overload_serve_ab(tpu: bool):
-    """Hold-until-free vs suspend-to-host A/B on ONE seeded Poisson
-    OVERLOAD trace: batch-tier streams saturate a device pool sized for
-    two of them (working set ~= 3x the pool), then interactive-tier
-    requests arrive mid-run. Hold-until-free (kv_host_blocks=0) parks
-    the interactive arrivals in the queue until a batch stream retires;
-    suspend-to-host (kv_host_blocks = 2x the device pool) swaps the
-    youngest batch stream's KV blocks to host RAM and admits the
-    interactive request in the same tick, resuming the parked stream —
-    bit-identically — once the pool frees. Both tiers get the SAME
-    block footprint (prompt + budget spanning equal whole blocks) so
-    peak_streams isolates the scheduling policy: the hold row tops out
-    at pool/footprint streams, the suspend row carries pool/footprint
-    active PLUS the suspended tier on top. interactive_ttft_p95 is the
-    SLO the displacement buys; streams_match_hold asserts suspension is
-    a scheduling change, not a sampler change (greedy f32 on the CPU
-    rig for exactly the reason _chunked_serve_ab pins f32)."""
-    import time
-
-    import flax.linen as nn
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tf_yarn_tpu.models.decode_engine import DecodeEngine
-    from tf_yarn_tpu.models.transformer import Transformer, TransformerConfig
-    from tf_yarn_tpu.parallel.mesh import select_devices
-    from tf_yarn_tpu.serving import SamplingParams, SlotScheduler
-
-    select_devices()
-    if tpu:
-        config = TransformerConfig(
-            vocab_size=32000, d_model=1024, n_layers=8, n_heads=16,
-            n_kv_heads=8, d_ff=4096, max_seq_len=512, remat=False,
-            scan_layers=False,
-        )
-        n_batch, n_inter = 8, 6
-        batch_len, batch_new = 256, 128     # ceil(383/16) = 24 blocks
-        inter_len, inter_new = 128, 256     # same 24-block footprint
-        block_size, max_slots = 16, 8
-        batch_gap_s, inter_gap_s, inter_at_s = 0.02, 0.05, 0.3
-    else:
-        config = TransformerConfig.tiny(
-            scan_layers=False, max_seq_len=64, dtype=jnp.float32,
-        )
-        n_batch, n_inter = 6, 4
-        batch_len, batch_new = 9, 24        # ceil(32/8) = 4 blocks
-        inter_len, inter_new = 5, 28        # same 4-block footprint
-        block_size, max_slots = 8, 4
-        batch_gap_s, inter_gap_s, inter_at_s = 0.005, 0.04, 0.08
-    model = Transformer(config)
-    rng = np.random.RandomState(23)
-    params = nn.meta.unbox(
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    )
-    footprint = -(-(batch_len + batch_new - 1) // block_size)
-    assert footprint == -(-(inter_len + inter_new - 1) // block_size)
-    # Device pool: exactly TWO streams' residency. The trace's working
-    # set (in-system demand at peak) is ~3x that — the oversubscription
-    # regime the host tier exists for.
-    num_blocks = 2 * footprint + 1
-    host_blocks = 2 * num_blocks  # the 2x-device-pool acceptance point
-
-    batch_arrivals = np.cumsum(rng.exponential(batch_gap_s, n_batch))
-    inter_arrivals = inter_at_s + np.cumsum(
-        rng.exponential(inter_gap_s, n_inter)
-    )
-    requests = sorted(
-        [
-            (
-                float(batch_arrivals[i]),
-                rng.randint(0, config.vocab_size, (batch_len,)).tolist(),
-                batch_new, "batch",
-            )
-            for i in range(n_batch)
-        ] + [
-            (
-                float(inter_arrivals[i]),
-                rng.randint(0, config.vocab_size, (inter_len,)).tolist(),
-                inter_new, "interactive",
-            )
-            for i in range(n_inter)
-        ],
-        key=lambda r: r[0],
-    )
-    total_tokens = sum(m for _, _, m, _ in requests)
-
-    def run_row(kv_host_blocks: int):
-        engine = DecodeEngine(model)
-        scheduler = SlotScheduler(
-            engine, params, max_slots=max_slots,
-            queue_capacity=len(requests), kv_layout="paged",
-            block_size=block_size, num_blocks=num_blocks,
-            kv_host_blocks=kv_host_blocks,
-        )
-        scheduler.start()
-        try:
-            # Warmup: two batch streams fill the pool, then an
-            # interactive arrival displaces one — compiling both prompt
-            # buckets, the step program, AND (suspend row) the
-            # extract/inject swap programs outside the timed window.
-            # TTFT must measure scheduling, not XLA.
-            warm = [
-                scheduler.submit(
-                    [1] * batch_len,
-                    SamplingParams(max_new_tokens=batch_new), tier="batch",
-                )
-                for _ in range(2)
-            ]
-            warm_deadline = time.monotonic() + 600
-            while (scheduler.stats()["active_slots"] < 2
-                   and time.monotonic() < warm_deadline):
-                time.sleep(0.005)
-            warm.append(scheduler.submit(
-                [1] * inter_len, SamplingParams(max_new_tokens=inter_new),
-                tier="interactive",
-            ))
-            for response in warm:
-                response.result(timeout=600)
-            t0 = time.perf_counter()
-            responses = []
-            for offset, prompt, max_new, tier in requests:
-                lag = t0 + offset - time.perf_counter()
-                if lag > 0:
-                    time.sleep(lag)
-                responses.append((scheduler.submit(
-                    prompt, SamplingParams(max_new_tokens=max_new),
-                    tier=tier,
-                ), offset, tier))
-            streams = [r.result(timeout=600) for r, _, _ in responses]
-            wall = time.perf_counter() - t0
-            inter_ttfts = [
-                (response.first_token_at - t0) - offset
-                for response, offset, tier in responses
-                if tier == "interactive"
-            ]
-            stats = scheduler.stats()
-            swap = stats.get("swap", {})
-            return streams, {
-                "kv_host_blocks": kv_host_blocks,
-                "goodput_tokens_per_sec": round(total_tokens / wall, 2),
-                "wall_s": round(wall, 3),
-                "interactive_ttft_p95_ms": round(
-                    1000 * float(np.percentile(inter_ttfts, 95)), 2),
-                "peak_streams": stats["peak_streams"],
-                "suspends": swap.get("suspends", 0),
-                "resumes": swap.get("resumes", 0),
-                "swap_out_blocks": swap.get("swap_out_blocks", 0),
-                "swap_in_blocks": swap.get("swap_in_blocks", 0),
-            }
-        finally:
-            scheduler.close()
-
-    hold_streams, hold_row = run_row(kv_host_blocks=0)
-    suspend_streams, suspend_row = run_row(kv_host_blocks=host_blocks)
-    suspend_row["streams_match_hold"] = suspend_streams == hold_streams
-    return {
-        "requests": len(requests),
-        "interactive_requests": n_inter,
-        "max_slots": max_slots,
-        "block_size": block_size,
-        "device_num_blocks": num_blocks,
-        "blocks_per_request": footprint,
-        "batch": {"prompt_len": batch_len, "max_new_tokens": batch_new},
-        "interactive": {
-            "prompt_len": inter_len, "max_new_tokens": inter_new,
-        },
-        "rows": {"hold": hold_row, "suspend": suspend_row},
-        "peak_streams_ratio": (
-            round(
-                suspend_row["peak_streams"] / hold_row["peak_streams"], 3
-            ) if hold_row["peak_streams"] else None
-        ),
-        "interactive_ttft_p95_ratio": (
-            round(
-                suspend_row["interactive_ttft_p95_ms"]
-                / hold_row["interactive_ttft_p95_ms"], 3
-            ) if hold_row["interactive_ttft_p95_ms"] else None
-        ),
-        "note": (
-            "peak_streams_ratio and interactive_ttft_p95_ratio carry "
-            "the claim: with host blocks at 2x the device pool the "
-            "suspend row holds the displaced batch tier IN the system "
-            "(peak_streams ~= 2x hold) while interactive TTFT drops to "
-            "one displacement tick instead of one batch stream's "
-            "remaining decode; streams_match_hold is the bit-identity "
-            "evidence. CPU-rig wall/goodput numbers are NOT speed "
-            "evidence (serial-core arithmetic, same caveat as the tp "
-            "and chunked rows) — the stream counts, swap counters, and "
-            "TTFT ordering are the scheduling evidence"
-        ),
-    }
-
-
 def bench_decode(tpu: bool, spec: bool = False):
     """Autoregressive decode throughput (tokens/sec), bf16 vs int8 KV
     cache. Decode steps are scanned inside ONE jitted program, so the
@@ -1347,255 +645,11 @@ def bench_decode(tpu: bool, spec: bool = False):
     return out
 
 
-def bench_serve(tpu: bool, tp: bool = False, chunked: bool = False,
-                overload: bool = False, disagg: bool = False):
-    """Online-serving A/B matrix under ONE seeded Poisson arrival trace:
-
-    * **policy** — continuous batching (freed slots re-admitted next
-      tick) vs static batching (admissions wait for the whole batch to
-      drain), same dense grid: the scheduling-policy delta.
-    * **KV layout** — dense per-slot caches vs the paged block pool
-      (sized BELOW dense-equivalent) vs paged + int8 KV, all continuous:
-      the memory-engineering delta. Each layout row reports resident KV
-      HBM and slots-per-GB — the concurrency-per-chip lever paged/int8
-      exist to multiply — alongside throughput and tail TTFT to show the
-      capacity is not bought with latency."""
-    import time
-
-    import flax.linen as nn
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tf_yarn_tpu.models.decode_engine import DecodeEngine
-    from tf_yarn_tpu.models.transformer import Transformer, TransformerConfig
-    from tf_yarn_tpu.parallel.mesh import select_devices
-    from tf_yarn_tpu.serving import SamplingParams, SlotScheduler
-
-    select_devices()
-    if tpu:
-        base_cfg = dict(
-            vocab_size=32000, d_model=1024, n_layers=8, n_heads=16,
-            n_kv_heads=8, d_ff=4096, max_seq_len=2048, remat=False,
-            scan_layers=False,
-        )
-        config = TransformerConfig(**base_cfg)
-        n_requests, max_slots, mean_gap_s = 32, 8, 0.02
-        prompt_lens, max_new_range = (64, 128, 256), (32, 256)
-        block_size = 16
-    else:
-        base_cfg = dict(scan_layers=False, max_seq_len=64)
-        config = TransformerConfig.tiny(**base_cfg)
-        n_requests, max_slots, mean_gap_s = 12, 4, 0.005
-        prompt_lens, max_new_range = (5, 9, 14), (2, 16)
-        block_size = 8
-    model = Transformer(config)
-    rng = np.random.RandomState(0)
-    params = nn.meta.unbox(
-        model.init(
-            jax.random.PRNGKey(0),
-            jnp.zeros((1, max(prompt_lens)), jnp.int32),
-        )
-    )
-
-    # One seeded Poisson trace shared by every policy and layout.
-    gaps = rng.exponential(mean_gap_s, n_requests)
-    arrivals = np.cumsum(gaps)
-    requests = [
-        (
-            float(arrivals[i]),
-            rng.randint(0, config.vocab_size,
-                        rng.choice(prompt_lens)).tolist(),
-            int(rng.randint(*max_new_range)),
-        )
-        for i in range(n_requests)
-    ]
-    total_tokens = sum(m for _, _, m in requests)
-    # Paged pool sized to the trace's worst-case concurrent residency
-    # (every slot holding its longest possible request), NOT to
-    # max_slots full contexts — the HBM the dense layout wastes on
-    # padding is exactly the gap between these two numbers.
-    worst_tokens = max(prompt_lens) + max_new_range[1] - 1
-    paged_blocks = max_slots * (-(-worst_tokens // block_size)) + 1
-
-    def run_policy(continuous: bool, run_model=None,
-                   scheduler_kwargs=None):
-        engine = DecodeEngine(run_model if run_model is not None else model)
-        scheduler = SlotScheduler(
-            engine, params, max_slots=max_slots,
-            queue_capacity=n_requests, **(scheduler_kwargs or {}),
-        )
-        scheduler.start()
-        try:
-            # Warmup: compile every prompt bucket's prefill + the step
-            # program outside the timed window (a warm server's steady
-            # state) — TTFT must measure scheduling, not XLA.
-            for length in prompt_lens:
-                scheduler.submit(
-                    [1] * length, SamplingParams(max_new_tokens=2)
-                ).result(timeout=300)
-            responses = []
-            t0 = time.perf_counter()
-            if continuous:
-                for offset, prompt, max_new in requests:
-                    lag = t0 + offset - time.perf_counter()
-                    if lag > 0:
-                        time.sleep(lag)
-                    responses.append((scheduler.submit(
-                        prompt, SamplingParams(max_new_tokens=max_new)
-                    ), offset))
-                for response, _ in responses:
-                    response.result(timeout=600)
-            else:
-                # Static batching: the next group is submitted only when
-                # the previous one fully drained — a freed slot idles.
-                for start in range(0, n_requests, max_slots):
-                    group = requests[start:start + max_slots]
-                    lag = t0 + group[-1][0] - time.perf_counter()
-                    if lag > 0:
-                        time.sleep(lag)
-                    batch = [
-                        (scheduler.submit(
-                            prompt, SamplingParams(max_new_tokens=max_new)
-                        ), offset)
-                        for offset, prompt, max_new in group
-                    ]
-                    for response, _ in batch:
-                        response.result(timeout=600)
-                    responses.extend(batch)
-            wall = time.perf_counter() - t0
-            # TTFT measured against the trace's arrival time, not the
-            # submit call — static batching's queue wait must count.
-            ttfts = sorted(
-                (response.first_token_at - t0) - offset
-                for response, offset in responses
-            )
-            stats = scheduler.stats()
-            kv_bytes = stats["kv_cache_hbm_bytes"]
-            return {
-                "tokens_per_sec": round(total_tokens / wall, 2),
-                "wall_s": round(wall, 3),
-                "ttft_mean_ms": round(
-                    1000 * sum(ttfts) / len(ttfts), 2),
-                "ttft_p95_ms": round(
-                    1000 * ttfts[int(0.95 * (len(ttfts) - 1))], 2),
-                "step_compiles": engine.stats["step_compiles"]
-                + engine.stats["paged_step_compiles"],
-                "kv_hbm_bytes": kv_bytes,
-                "slots_per_gb_hbm": round(
-                    max_slots / (kv_bytes / 2**30), 2) if kv_bytes else None,
-                "prefix_cache_hit_rate": (
-                    stats.get("prefix_cache", {}).get("hit_rate")
-                ),
-            }
-        finally:
-            scheduler.close()
-
-    continuous = run_policy(continuous=True)
-    static = run_policy(continuous=False)
-    speedup = (
-        round(continuous["tokens_per_sec"] / static["tokens_per_sec"], 3)
-        if static["tokens_per_sec"] else None
-    )
-
-    # KV-layout A/B (all continuous): dense is the run above; paged
-    # shrinks the pool below dense-equivalent; paged_int8 halves the
-    # bytes per cached token on top.
-    paged_kwargs = dict(
-        kv_layout="paged", block_size=block_size, num_blocks=paged_blocks,
-    )
-    layouts = {"dense": continuous}
-    try:
-        layouts["paged"] = run_policy(
-            continuous=True, scheduler_kwargs=paged_kwargs
-        )
-    except Exception as exc:  # noqa: BLE001 - record, keep benching
-        layouts["paged"] = {"error": f"{type(exc).__name__}: {exc}"[:160]}
-    try:
-        int8_model = Transformer(
-            TransformerConfig(**base_cfg, kv_cache_dtype="int8")
-            if tpu else TransformerConfig.tiny(
-                **base_cfg, kv_cache_dtype="int8")
-        )
-        layouts["paged_int8"] = run_policy(
-            continuous=True, run_model=int8_model,
-            scheduler_kwargs=paged_kwargs,
-        )
-    except Exception as exc:  # noqa: BLE001
-        layouts["paged_int8"] = {
-            "error": f"{type(exc).__name__}: {exc}"[:160]
-        }
-    ratios = {}
-    dense_spg = continuous.get("slots_per_gb_hbm")
-    for name in ("paged", "paged_int8"):
-        spg = layouts[name].get("slots_per_gb_hbm")
-        if spg and dense_spg:
-            ratios[f"{name}_vs_dense_slots_per_gb"] = round(
-                spg / dense_spg, 2
-            )
-    # Speculative decoding A/B (exact vs k ∈ {2, 4} on one seeded
-    # repeated-structure trace): the per-token latency lever riding on
-    # the same serving stack.
-    try:
-        spec = _spec_decode_ab(tpu)
-    except Exception as exc:  # noqa: BLE001 - record, keep benching
-        spec = {"error": f"{type(exc).__name__}: {exc}"[:160]}
-    out = {
-        "requests": n_requests,
-        "max_slots": max_slots,
-        "total_tokens": total_tokens,
-        "block_size": block_size,
-        "paged_num_blocks": paged_blocks,
-        "continuous": continuous,
-        "static": static,
-        "continuous_vs_static_speedup": speedup,
-        "layouts": layouts,
-        "spec": spec,
-        **ratios,
-    }
-    if tp:
-        # Tensor-parallel A/B (`serve --tp`): tp=1 vs tp=2 on the same
-        # seeded trace; the per-device KV accounting is the evidence,
-        # CPU-rig speed ratios are not (see _tp_serve_ab).
-        try:
-            out["tp"] = _tp_serve_ab(tpu)
-        except Exception as exc:  # noqa: BLE001 - record, keep benching
-            out["tp"] = {"error": f"{type(exc).__name__}: {exc}"[:160]}
-    if chunked:
-        # Chunked-prefill A/B (`serve --chunked`): blocking vs chunked
-        # admission on one bimodal Poisson trace; ITL p95 is the claim.
-        try:
-            out["chunked"] = _chunked_serve_ab(tpu)
-        except Exception as exc:  # noqa: BLE001 - record, keep benching
-            out["chunked"] = {"error": f"{type(exc).__name__}: {exc}"[:160]}
-    if overload:
-        # KV-oversubscription A/B (`serve --overload`): hold-until-free
-        # vs suspend-to-host on one seeded overload trace; the
-        # peak-streams ratio and interactive TTFT are the claim.
-        try:
-            out["overload"] = _overload_serve_ab(tpu)
-        except Exception as exc:  # noqa: BLE001 - record, keep benching
-            out["overload"] = {
-                "error": f"{type(exc).__name__}: {exc}"[:160]
-            }
-    if disagg:
-        # Disaggregated-prefill A/B (`serve --disagg`): local vs
-        # offloaded prefill on the bimodal trace; streams_match_local
-        # and the fp-vs-int8 wire ratio are the claim.
-        try:
-            out["disagg"] = _disagg_serve_ab(tpu)
-        except Exception as exc:  # noqa: BLE001 - record, keep benching
-            out["disagg"] = {
-                "error": f"{type(exc).__name__}: {exc}"[:160]
-            }
-    return out
-
-
 def bench_fleet(tpu: bool, replica_counts=(1, 2, 4), n_requests=None,
                 autoscale=False):
-    """Fleet mode of the serve bench: aggregate tokens/s and TTFT p95
-    vs replica count under the SAME seeded Poisson arrival trace,
-    driven end-to-end through the fleet ROUTER (tf_yarn_tpu/fleet/):
+    """The serving stack behind the fleet router: aggregate tokens/s and
+    TTFT p95 vs replica count under the SAME seeded Poisson arrival
+    trace, driven end-to-end through the fleet ROUTER (tf_yarn_tpu/fleet/):
     N real serving stacks (scheduler + HTTP frontend) advertise into an
     in-process KV, the replica registry probes them healthy, and every
     request streams through the router's ``/v1/generate`` passthrough —
@@ -1664,7 +718,7 @@ def bench_fleet(tpu: bool, replica_counts=(1, 2, 4), n_requests=None,
             tpu, engine, params, config, max_slots, n_requests)
     n_requests = n_requests or default_requests
 
-    # The bench_serve seeded Poisson trace, shared by every fleet size.
+    # One seeded Poisson trace, shared by every fleet size.
     gaps = rng.exponential(mean_gap_s, n_requests)
     arrivals = np.cumsum(gaps)
     requests = [
@@ -1963,7 +1017,7 @@ def _bench_fleet_autoscale(tpu, engine, params, config, max_slots,
             scheduler = SlotScheduler(
                 engine, params, max_slots=ab_slots,
                 queue_capacity=max(64, n_requests),
-                kv_layout="paged", block_size=block_size,
+                block_size=block_size,
                 prefix_cache_capacity=64,
             )
             scheduler.start()
@@ -2390,7 +1444,6 @@ CONFIGS = {
     "llama_lora": bench_llama_lora,
     "long_context": bench_long_context,
     "decode": bench_decode,
-    "serve": bench_serve,
     "fleet": bench_fleet,
     "rank": bench_rank,
     "ici_allreduce": bench_ici_allreduce,
@@ -2411,17 +1464,6 @@ def main() -> None:
         help="decode config: add the exact-vs-speculative (spec_k) A/B",
     )
     parser.add_argument(
-        "--tp", action="store_true",
-        help="serve config: add the tp=1 vs tp=2 tensor-parallel A/B",
-    )
-    parser.add_argument(
-        "--chunked", action="store_true",
-        help=(
-            "serve config: add the blocking-vs-chunked admission "
-            "prefill A/B (bimodal trace, TTFT + inter-token-latency p95)"
-        ),
-    )
-    parser.add_argument(
         "--autoscale", action="store_true",
         help=(
             "fleet config: run the static-vs-autoscaled elastic A/B "
@@ -2430,32 +1472,9 @@ def main() -> None:
             "replica sweep"
         ),
     )
-    parser.add_argument(
-        "--overload", action="store_true",
-        help=(
-            "serve config: add the hold-until-free vs suspend-to-host "
-            "KV oversubscription A/B (seeded overload trace, peak "
-            "streams + interactive TTFT p95 + swap counters)"
-        ),
-    )
-    parser.add_argument(
-        "--disagg", action="store_true",
-        help=(
-            "serve config: add the local vs disaggregated prefill A/B "
-            "(bimodal trace through a real prefill replica over HTTP; "
-            "TTFT p95, streams_match_local, fp-vs-int8 wire bytes)"
-        ),
-    )
     args = parser.parse_args()
     if args.cpu:
         os.environ["TPU_YARN_PLATFORM"] = "cpu"  # explicit flag wins over env
-    if args.tp:
-        # The tp A/B needs >= 2 devices; on a CPU rig that means forcing
-        # virtual host-platform devices BEFORE jax initializes
-        # (parallel.mesh.select_devices reads this env and appends the
-        # XLA flag). A real slice already has its chips; the setdefault
-        # is harmless there.
-        os.environ.setdefault("TPU_YARN_VIRTUAL_DEVICES", "4")
     unknown = [name for name in args.configs if name not in CONFIGS]
     if unknown:
         parser.error(
@@ -2471,11 +1490,6 @@ def main() -> None:
     for name in args.configs:
         if name == "decode":
             result = CONFIGS[name](tpu, spec=args.spec)
-        elif name == "serve":
-            result = CONFIGS[name](
-                tpu, tp=args.tp, chunked=args.chunked,
-                overload=args.overload, disagg=args.disagg,
-            )
         elif name == "fleet":
             result = CONFIGS[name](tpu, autoscale=args.autoscale)
         else:

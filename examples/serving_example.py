@@ -75,15 +75,15 @@ def main(spec: bool = False, tp: bool = False) -> None:
         mesh=mesh,
     )
 
-    # Paged KV slots: a global pool of 8-token blocks instead of one
-    # full max_seq_len cache per slot — 11 blocks here vs the dense
-    # equivalent of 17, with a prefix cache sharing repeated prompt
-    # prefixes (docs/Serving.md "Paged KV & prefix cache"). --spec adds
-    # speculative decoding: 3 n-gram drafts per slot per tick, verified
-    # in one windowed program (docs/Serving.md "Speculative decoding").
+    # Paged KV slots: a global pool of 8-token blocks — 11 blocks here
+    # where two slots at full context would hold 17, with a prefix cache
+    # sharing repeated prompt prefixes (docs/Serving.md "Paged KV &
+    # prefix cache"). --spec adds speculative decoding: 3 n-gram drafts
+    # per slot per tick, verified in one windowed program
+    # (docs/Serving.md "Speculative decoding").
     scheduler = SlotScheduler(
         engine, params, max_slots=2,
-        kv_layout="paged", block_size=8, num_blocks=11,
+        block_size=8, num_blocks=11,
         spec_k=3 if spec else 0,
     )
     scheduler.start()

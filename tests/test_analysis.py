@@ -144,16 +144,16 @@ def test_repo_passes_its_own_checker():
         f["code"] == "TYA311" for f in payload["suppressed_findings"]
     ), "expected the advisory-counter suppressions to surface"
     # The headline manifest ran (8 CPU devices are forced in this env):
-    # sharded_step's census is present, with its exact all-reduce count
+    # sharded_paged_step's census is present, with its exact all-reduce count
     # and zero above-floor all-gathers baked into the manifest check.
     census = payload["hlo_census"]
-    assert "models.decode_engine.sharded_step" in census
+    assert "models.decode_engine.sharded_paged_step" in census
     assert (
-        census["models.decode_engine.sharded_step"]["collectives"][
+        census["models.decode_engine.sharded_paged_step"]["collectives"][
             "all-reduce"]["count"] == 3
     )
     assert "all-gather" not in (
-        census["models.decode_engine.sharded_step"]["collectives"]
+        census["models.decode_engine.sharded_paged_step"]["collectives"]
     )
 
 
@@ -325,13 +325,10 @@ def test_jaxpr_engine_default_entries_clean_on_this_build():
     assert counts["models.decode_engine.decode_loop"]["while"] >= 1
     # the continuous-batching slot step traced too: it runs once per
     # generated token across the whole serving grid, so it is exactly
-    # where a smuggled host callback would hurt most.
-    assert "models.decode_engine.step" in counts
-    assert counts["models.decode_engine.step"]["dot_general"] > 0
-    # the PAGED serving programs: the step must contain the block-table
-    # gather AND the scatter-append (the whole point of the layout),
-    # with the same host-callback-free bar — findings == [] above
-    # already asserts both paged entries trace clean.
+    # where a smuggled host callback would hurt most. It must contain
+    # the block-table gather AND the scatter-append (the whole point of
+    # the pool) — findings == [] above already asserts both paged
+    # entries trace clean.
     assert "models.decode_engine.paged_step" in counts
     paged = counts["models.decode_engine.paged_step"]
     assert paged["dot_general"] > 0
@@ -340,13 +337,15 @@ def test_jaxpr_engine_default_entries_clean_on_this_build():
     assert "models.decode_engine.paged_prefill" in counts
     assert counts["models.decode_engine.paged_prefill"][
         "dynamic_update_slice"] > 0
-    # The SPECULATIVE ticks: the windowed verify (accept/reject masking
-    # fully traced) and the FUSED paged verify — findings == [] above
+    # The WINDOWED ticks: the verify over the gathered view (accept/reject
+    # masking fully traced; the chunk-apply) and the FUSED verify —
+    # findings == [] above
     # already asserts both are host-callback-free; the fused entry must
     # actually contain the pallas kernel call (the paged int8 decode-
     # attention wire-up this gate exists to pin).
-    assert "models.decode_engine.spec_step" in counts
-    assert counts["models.decode_engine.spec_step"]["dot_general"] > 0
+    window = counts["models.decode_engine.chunk_apply"]
+    assert window["dot_general"] > 0
+    assert window.get("gather", 0) > 0
     fused = counts["models.decode_engine.paged_spec_step"]
     assert fused["dot_general"] > 0
     assert fused.get("pallas_call", 0) > 0
@@ -527,13 +526,13 @@ def test_hlo_budget_file_is_checked_in_and_current_schema():
     )
     entries = budget["entries"]
     # the headline baselines are pinned: the tp=2 serving ticks
-    assert entries["models.decode_engine.sharded_step"]["collectives"][
-        "all-reduce"]["count"] == 3
-    assert "all-gather" not in (
-        entries["models.decode_engine.sharded_step"]["collectives"]
-    )
     assert entries["models.decode_engine.sharded_paged_step"][
         "collectives"]["all-reduce"]["count"] == 3
+    assert "all-gather" not in (
+        entries["models.decode_engine.sharded_paged_step"]["collectives"]
+    )
+    for gone in ("step", "spec_step", "sharded_step"):
+        assert "models.decode_engine." + gone not in entries
 
 
 # --- concurrency engine: static lint (TYA301-303) ------------------------

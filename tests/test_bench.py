@@ -22,7 +22,7 @@ def _run(script, *args):
 
 
 @pytest.mark.parametrize(
-    "script,args", [("bench.py", ()), ("benchmarks/run.py", ("serve",))]
+    "script,args", [("bench.py", ()), ("benchmarks/run.py", ("decode",))]
 )
 def test_measurement_without_a_chip_is_an_error(script, args):
     proc = _run(script, *args)

@@ -17,8 +17,8 @@ Three layers, matching the serving test house style:
   incrementally as chunks complete.
 * **Real engine on CPU** — the acceptance bars: chunked greedy AND
   sampled streams are BIT-IDENTICAL to ``generate_legacy`` (tier-1
-  dense representative), with the paged / int8 / prefix-hit / spec
-  compositions and the long-prompt e2e in the slow sweep.
+  representative), with the int8 / prefix-hit / spec compositions and
+  the long-prompt e2e beside it.
 """
 
 import time
@@ -26,191 +26,10 @@ import time
 import numpy as np
 import pytest
 
+from tests.fakes import FakePagedWindowedEngine, fake_scheduler
 from tf_yarn_tpu import telemetry
 from tf_yarn_tpu.serving import SamplingParams, SlotScheduler
 from tf_yarn_tpu.serving.request import FINISH_DEADLINE
-
-
-# --------------------------------------------------------------------------
-# deterministic fakes: FakeEngine's sum%97 arithmetic, windowed
-# --------------------------------------------------------------------------
-
-class FakeWindowedEngine:
-    """Dense fake with BOTH the exact and windowed step contracts, so
-    one class drives the blocking reference and the chunked run: a
-    slot's cache is the running sum of consumed tokens, an emitting
-    position emits ``sum % 97``, a draft is accepted iff it equals that
-    emission."""
-
-    def __init__(self, buckets=(4, 8)):
-        self.prompt_buckets = tuple(sorted(buckets))
-        self.calls = []
-
-    def slot_prefill_len(self, prompt_len):
-        best = 0
-        for bucket in self.prompt_buckets:
-            if bucket <= prompt_len - 1:
-                best = bucket
-        return best
-
-    def make_slot_cache(self, params, max_slots):
-        return np.zeros((max_slots,), np.int64)
-
-    def prefill(self, params, prompt):
-        self.calls.append(("prefill", prompt.shape))
-        return np.asarray([prompt.sum()], np.int64), None
-
-    def insert_slot(self, cache, slot, row):
-        self.calls.append(("insert", slot))
-        cache = cache.copy()
-        cache[slot] = row[0]
-        return cache
-
-    def evict_slot(self, cache, slot):
-        self.calls.append(("evict", slot))
-        cache = cache.copy()
-        cache[slot] = 0
-        return cache
-
-    def step(self, params, cache, tokens, rngs, sample_mask,
-             temperature=0.0, top_k=None, top_p=None):
-        self.calls.append(("step",))
-        cache = cache + np.asarray(tokens, np.int64)
-        emitted = np.where(
-            np.asarray(sample_mask), cache % 97, np.asarray(tokens)
-        ).astype(np.int32)
-        return cache, emitted, rngs
-
-    def spec_step(self, params, cache, tokens, n_known, eos_ids, rngs,
-                  active, temperature=0.0, top_k=None, top_p=None):
-        tokens = np.asarray(tokens)
-        slots, width = tokens.shape
-        self.calls.append(("spec_step", tokens.copy(),
-                           np.asarray(n_known).copy(),
-                           np.asarray(active).copy()))
-        cache = cache.copy()
-        emitted = np.zeros((slots, width), np.int32)
-        counts = np.zeros((slots,), np.int32)
-        for s in range(slots):
-            if not active[s]:
-                continue
-            total = cache[s]
-            out_prev, alive = None, True
-            n = 0
-            for i in range(width):
-                if i > int(n_known[s]):
-                    alive = alive and tokens[s, i] == out_prev \
-                        and out_prev != eos_ids[s]
-                if i >= int(n_known[s]) and not alive:
-                    break
-                total += int(tokens[s, i])
-                if i >= int(n_known[s]):
-                    out_prev = int(total % 97)
-                    emitted[s, n] = out_prev
-                    n += 1
-                    if out_prev == eos_ids[s]:
-                        break
-            cache[s] = total
-            counts[s] = n
-        return cache, emitted, counts, rngs
-
-
-class FakePagedWindowedEngine:
-    """Paged twin: the pool is a (num_blocks, block_size) int64 token
-    store gathered through the block table — same arithmetic, so a
-    table/length/registration bug changes the emission and fails the
-    stream assertions."""
-
-    def __init__(self, buckets=(4, 8), max_seq_len=32):
-        self.prompt_buckets = tuple(sorted(buckets))
-        self.max_seq_len = max_seq_len
-        self.calls = []
-
-    def slot_prefill_len(self, prompt_len):
-        best = 0
-        for bucket in self.prompt_buckets:
-            if bucket <= prompt_len - 1:
-                best = bucket
-        return best
-
-    def make_paged_pool(self, params, num_blocks, block_size):
-        return np.zeros((num_blocks, block_size), np.int64)
-
-    def prefill(self, params, prompt):
-        self.calls.append(("prefill", prompt.shape))
-        return np.asarray(prompt[0], np.int64), None
-
-    def pack_prefill(self, pool, block_ids, row_cache, prefill_len,
-                     block_size):
-        self.calls.append(("pack", tuple(int(b) for b in block_ids)))
-        pool = pool.copy()
-        for pos in range(prefill_len):
-            block = block_ids[pos // block_size]
-            pool[block, pos % block_size] = row_cache[pos]
-        return pool
-
-    def paged_step(self, params, pool, tables, lengths, tokens, rngs,
-                   sample_mask, block_size, temperature=0.0, top_k=None,
-                   top_p=None):
-        self.calls.append(("paged_step",))
-        pool = np.array(pool)
-        tables = np.asarray(tables)
-        lengths = np.asarray(lengths)
-        emitted = np.array(tokens, np.int32)
-        for s in range(len(tokens)):
-            length = int(lengths[s])
-            pool[tables[s, length // block_size],
-                 length % block_size] = tokens[s]
-            if sample_mask[s]:
-                total = 0
-                for pos in range(length + 1):
-                    total += pool[tables[s, pos // block_size],
-                                  pos % block_size]
-                emitted[s] = total % 97
-        return pool, emitted, rngs
-
-    def paged_spec_step(self, params, pool, tables, lengths, tokens,
-                        n_known, eos_ids, rngs, active, block_size,
-                        temperature=0.0, top_k=None, top_p=None,
-                        decode_attention="gather"):
-        tokens = np.asarray(tokens)
-        slots, width = tokens.shape
-        self.calls.append(("paged_spec_step", tokens.copy(),
-                           np.asarray(n_known).copy(),
-                           np.asarray(active).copy()))
-        pool = np.array(pool)
-        tables = np.asarray(tables)
-        lengths = np.asarray(lengths)
-        emitted = np.zeros((slots, width), np.int32)
-        counts = np.zeros((slots,), np.int32)
-        for s in range(slots):
-            if not active[s]:
-                continue
-            length = int(lengths[s])
-            total = 0
-            for pos in range(length):
-                total += pool[tables[s, pos // block_size],
-                              pos % block_size]
-            out_prev, alive = None, True
-            n = 0
-            for i in range(width):
-                if i > int(n_known[s]):
-                    alive = alive and tokens[s, i] == out_prev \
-                        and out_prev != eos_ids[s]
-                if i >= int(n_known[s]) and not alive:
-                    break
-                pos = length + i
-                pool[tables[s, pos // block_size],
-                     pos % block_size] = tokens[s, i]
-                total += int(tokens[s, i])
-                if i >= int(n_known[s]):
-                    out_prev = int(total % 97)
-                    emitted[s, n] = out_prev
-                    n += 1
-                    if out_prev == eos_ids[s]:
-                        break
-            counts[s] = n
-        return pool, emitted, counts, rngs
 
 
 def _drive(scheduler, responses, max_ticks=3000):
@@ -234,39 +53,35 @@ def _run_streams(scheduler, workload):
 # --------------------------------------------------------------------------
 
 def test_scheduler_validates_chunked_knobs():
-    engine = FakeWindowedEngine()
+    engine = FakePagedWindowedEngine()
     with pytest.raises(ValueError, match="prefill_chunk"):
-        SlotScheduler(engine, params=None, prefill_chunk=-2)
+        fake_scheduler(engine, prefill_chunk=-2)
     with pytest.raises(ValueError, match="prefill_budget_per_tick"):
-        SlotScheduler(engine, params=None, prefill_budget_per_tick=8)
+        fake_scheduler(engine, prefill_budget_per_tick=8)
     with pytest.raises(ValueError, match="window width"):
-        SlotScheduler(engine, params=None, prefill_chunk=8,
-                      prefill_budget_per_tick=4)
+        fake_scheduler(engine, prefill_chunk=8, prefill_budget_per_tick=4)
     # spec widens the window past the chunk; the budget must cover it.
     with pytest.raises(ValueError, match="window width"):
-        SlotScheduler(engine, params=None, prefill_chunk=2, spec_k=5,
-                      prefill_budget_per_tick=3)
+        fake_scheduler(engine, prefill_chunk=2, spec_k=5,
+                       prefill_budget_per_tick=3)
 
 
 def test_prefill_chunk_auto_resolves_from_prompt_buckets():
-    scheduler = SlotScheduler(
-        FakeWindowedEngine(buckets=(4, 8)), params=None,
-        prefill_chunk="auto",
+    scheduler = fake_scheduler(
+        FakePagedWindowedEngine(buckets=(4, 8)), prefill_chunk="auto",
     )
     assert scheduler.prefill_chunk == 8
 
     # No buckets exposed: "auto" falls back to the spec window.
-    engine = FakeWindowedEngine()
+    engine = FakePagedWindowedEngine()
     engine.prompt_buckets = ()
-    scheduler = SlotScheduler(
-        engine, params=None, prefill_chunk="auto", spec_k=3,
-    )
+    scheduler = fake_scheduler(engine, prefill_chunk="auto", spec_k=3)
     assert scheduler.prefill_chunk == 4
 
 
 def test_context_limit_reserves_chunk_window_headroom():
-    scheduler = SlotScheduler(
-        FakeWindowedEngine(), params=None, max_slots=1, max_seq_len=32,
+    scheduler = fake_scheduler(
+        FakePagedWindowedEngine(max_seq_len=32), max_slots=1,
         prefill_chunk=8,
     )
     assert scheduler.context_limit == 32 - 7
@@ -296,7 +111,7 @@ def test_serving_experiment_chunked_fields_validate():
 
 
 # --------------------------------------------------------------------------
-# fake dense: blocking-identical streams, the no-stall contract, budget
+# the fake: blocking-identical streams, the no-stall contract, budget
 # --------------------------------------------------------------------------
 
 _WORKLOAD = [
@@ -307,24 +122,20 @@ _WORKLOAD = [
 
 
 def test_chunked_streams_match_blocking_and_skip_prefill_program():
-    blocking = SlotScheduler(
-        FakeWindowedEngine(), params=None, max_slots=3,
-    )
+    blocking = fake_scheduler(FakePagedWindowedEngine(), max_slots=3)
     expected = _run_streams(blocking, _WORKLOAD)
 
-    engine = FakeWindowedEngine()
-    chunked = SlotScheduler(
-        engine, params=None, max_slots=3, prefill_chunk=4,
-    )
+    engine = FakePagedWindowedEngine()
+    chunked = fake_scheduler(engine, max_slots=3, prefill_chunk=4)
     assert _run_streams(chunked, _WORKLOAD) == expected
-    kinds = [c[0] for c in engine.calls]
-    # Chunked admission never runs the prefill program: the slot starts
-    # from an evicted (zeroed) cache and the prompt replays in windows.
-    assert "prefill" not in kinds and "insert" not in kinds
-    assert kinds.count("evict") == 3
+    # Chunked admission runs NO device program of its own: the slot
+    # starts at length 0 of fresh blocks and the prompt replays in
+    # windows — the engine sees the pool made and windowed steps only.
+    assert {c[0] for c in engine.calls} == {"make_pool", "paged_spec_step"}
     # ONE window shape for the whole run — no recompile keys
     # tick-to-tick (the TYA205 contract, at the fake seam).
-    shapes = {c[1].shape for c in engine.calls if c[0] == "spec_step"}
+    shapes = {c[1].shape for c in engine.calls
+              if c[0] == "paged_spec_step"}
     assert shapes == {(3, 4)}
 
 
@@ -332,10 +143,9 @@ def test_decode_slots_emit_every_tick_while_2k_prompt_admits():
     """THE no-stall contract: a decoding slot keeps emitting on every
     single tick while a 2000-token prompt chunks through admission on
     the other slot."""
-    engine = FakeWindowedEngine()
-    scheduler = SlotScheduler(
-        engine, params=None, max_slots=2, prefill_chunk=8,
-        prefill_budget_per_tick=8,
+    engine = FakePagedWindowedEngine(max_seq_len=2048)
+    scheduler = fake_scheduler(
+        engine, max_slots=2, prefill_chunk=8, prefill_budget_per_tick=8,
     )
     decode = scheduler.submit([1, 2], SamplingParams(max_new_tokens=300))
     scheduler.tick()  # admits; consumes [1, 2], emits the first token
@@ -362,10 +172,9 @@ def test_decode_slots_emit_every_tick_while_2k_prompt_admits():
 
 
 def test_prefill_budget_pauses_chunking_slots_round_robin():
-    engine = FakeWindowedEngine()
-    scheduler = SlotScheduler(
-        engine, params=None, max_slots=2, prefill_chunk=4,
-        prefill_budget_per_tick=4,
+    engine = FakePagedWindowedEngine(max_seq_len=64)
+    scheduler = fake_scheduler(
+        engine, max_slots=2, prefill_chunk=4, prefill_budget_per_tick=4,
     )
     workload = [
         (list(range(1, 41)), SamplingParams(max_new_tokens=2)),
@@ -373,7 +182,8 @@ def test_prefill_budget_pauses_chunking_slots_round_robin():
     ]
     streams = _run_streams(scheduler, workload)
 
-    blocking = SlotScheduler(FakeWindowedEngine(), params=None, max_slots=2)
+    blocking = fake_scheduler(
+        FakePagedWindowedEngine(max_seq_len=64), max_slots=2)
     assert streams == _run_streams(blocking, workload)
 
     # While BOTH slots were chunking, the 4-token budget admitted
@@ -382,7 +192,7 @@ def test_prefill_budget_pauses_chunking_slots_round_robin():
     # tokens at 4/tick = at least 19 solo-advance ticks, no starvation.
     advanced = []
     for call in engine.calls:
-        if call[0] != "spec_step":
+        if call[0] != "paged_spec_step":
             continue
         _, _tokens, n_known, active = call
         if active.sum() == 1 and n_known[int(np.argmax(active))] > 0:
@@ -397,8 +207,8 @@ def test_chunked_stats_and_token_counters():
     registry = telemetry.get_registry()
     before_prefill = registry.counter("serving/prefill_tokens_total").value
     before_decode = registry.counter("serving/decode_tokens_total").value
-    scheduler = SlotScheduler(
-        FakeWindowedEngine(), params=None, max_slots=1, prefill_chunk=4,
+    scheduler = fake_scheduler(
+        FakePagedWindowedEngine(), max_slots=1, prefill_chunk=4,
         prefill_budget_per_tick=8,
     )
     prompt = list(range(1, 12))  # 11 tokens
@@ -426,16 +236,14 @@ def test_chunked_stats_and_token_counters():
 
 
 # --------------------------------------------------------------------------
-# fake paged: incremental prefix registration + exactly-once eviction
+# the pool: incremental prefix registration + exactly-once eviction
 # --------------------------------------------------------------------------
 
 def _paged_chunked(max_slots=2, num_blocks=None, **kwargs):
     engine = FakePagedWindowedEngine()
-    scheduler = SlotScheduler(
-        engine, params=None, max_slots=max_slots, kv_layout="paged",
-        block_size=4, num_blocks=num_blocks, max_seq_len=32, **kwargs,
+    return engine, fake_scheduler(
+        engine, max_slots=max_slots, num_blocks=num_blocks, **kwargs
     )
-    return engine, scheduler
 
 
 def test_paged_chunked_matches_blocking_and_registers_incrementally():
@@ -538,7 +346,7 @@ def test_mid_prefill_shutdown_eviction_releases_blocks_exactly_once():
 # --------------------------------------------------------------------------
 
 def _tiny_stack(max_slots=2, kv_cache_dtype="bf16", max_seq_len=64,
-                engine=None, **scheduler_kwargs):
+                engine=None, block_size=8, **scheduler_kwargs):
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
@@ -562,7 +370,8 @@ def _tiny_stack(max_slots=2, kv_cache_dtype="bf16", max_seq_len=64,
     model = engine.model
     params = engine._test_params
     scheduler = SlotScheduler(
-        engine, params, max_slots=max_slots, **scheduler_kwargs
+        engine, params, max_slots=max_slots, block_size=block_size,
+        **scheduler_kwargs
     )
     return model, params, engine, scheduler
 
@@ -583,7 +392,7 @@ def _legacy_stream(model, params, prompt, max_new, eos=None, **sampling):
 
 
 def test_chunked_real_engine_greedy_and_sampled_match_legacy():
-    """The tier-1 bit-identity bar (dense representative): chunked
+    """The tier-1 bit-identity bar: chunked
     prefill streams — mixed prompt lengths under a live budget — are
     IDENTICAL to generate_legacy, greedy and sampled RNG chains alike,
     with ONE windowed program compiled and the blocking prefill
@@ -608,7 +417,7 @@ def test_chunked_real_engine_greedy_and_sampled_match_legacy():
             assert response.result(timeout=1) == _legacy_stream(
                 model, params, prompt, max_new
             )
-        assert engine.stats["spec_step_compiles"] == 1
+        assert engine.stats["paged_spec_step_compiles"] == 1
         assert engine.stats["prefill_compiles"] == 0
     finally:
         scheduler.close()
@@ -636,20 +445,18 @@ def test_chunked_real_engine_greedy_and_sampled_match_legacy():
         scheduler.close()
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("layout_kwargs, kv_cache_dtype, reference", [
-    # paged fp: bit-identical to legacy, prefix hit included below.
-    ({"kv_layout": "paged", "block_size": 8}, "bf16", "legacy"),
-    # paged int8: chunked must equal the BLOCKING path bit-for-bit
-    # (int8 quantization differs from the legacy dense rounding only in
-    # layout-independent ways the blocking scheduler already carries).
-    ({"kv_layout": "paged", "block_size": 8}, "int8", "blocking"),
+@pytest.mark.parametrize("scheduler_kwargs, kv_cache_dtype, reference", [
+    # fp: bit-identical to legacy, prefix hit included below.
+    ({}, "bf16", "legacy"),
+    # int8: chunked must equal the BLOCKING path bit-for-bit (int8
+    # quantization differs from legacy's rounding only in ways the
+    # blocking scheduler already carries).
+    ({}, "int8", "blocking"),
     # spec composition: drafts ride the widened window, stream still
     # exact.
-    ({"kv_layout": "paged", "block_size": 8, "spec_k": 2}, "bf16",
-     "legacy"),
+    ({"spec_k": 2}, "bf16", "legacy"),
 ])
-def test_chunked_composition_matrix_streams_identical(layout_kwargs,
+def test_chunked_composition_matrix_streams_identical(scheduler_kwargs,
                                                       kv_cache_dtype,
                                                       reference):
     workload_rng = np.random.RandomState(7)
@@ -664,7 +471,7 @@ def test_chunked_composition_matrix_streams_identical(layout_kwargs,
     def run(**extra):
         model, params, engine, scheduler = _tiny_stack(
             max_slots=2, kv_cache_dtype=kv_cache_dtype,
-            **layout_kwargs, **extra,
+            **scheduler_kwargs, **extra,
         )
         try:
             responses = [
@@ -738,6 +545,6 @@ def test_chunked_long_prompt_e2e_no_stall_and_identical():
             t.get("accepted", {}).get(short.request.id, 0) >= 1
             for t in chunk_ticks
         )
-        assert engine.stats["spec_step_compiles"] == 1
+        assert engine.stats["paged_spec_step_compiles"] == 1
     finally:
         scheduler.close()

@@ -20,7 +20,7 @@ from tf_yarn_tpu.models.decode_engine import (
     build_decode_fn,
     build_paged_step_fn,
     build_prefill_fn,
-    build_step_fn,
+    cache_layout,
     cache_nbytes,
     clear_engines,
     get_engine,
@@ -242,137 +242,75 @@ def test_oversized_batch_chunks_through_largest_bucket():
     assert engine.stats["prefill_cache_hits"] == 1
 
 
-def test_slot_step_grid_matches_legacy_per_request():
-    """The serving grid's device contract: slots admitted at different
-    times, prompt lengths, and seeds — advanced one token per compiled
-    `step` call — reproduce generate_legacy bit-for-bit per request,
-    including the sampled RNG chain (replay steps consume no RNG)."""
+def test_pack_prefill_touches_only_its_own_blocks():
+    """A prefill packed into one slot's blocks writes exactly those
+    blocks — and again after they are freed and handed out anew, in
+    another order, to another prompt: every other block of the pool,
+    another slot's rows among them, is bit-unchanged."""
     model, params = _model_and_params()
     engine = _engine(model, batch_buckets=(1, 2, 4),
                      prompt_buckets=(4, 8, 16))
-    slots = 3
-    grid = engine.make_slot_cache(params, slots)
-    rng_np = np.random.RandomState(5)
-    prompts = [
-        rng_np.randint(0, 256, (5,)).astype(np.int32),   # prefill 4, replay 1
-        rng_np.randint(0, 256, (9,)).astype(np.int32),   # prefill 8, replay 1
-        rng_np.randint(0, 256, (3,)).astype(np.int32),   # no prefill: replay 3
-    ]
-    seeds = [0, 7, 3]
-    max_new = 6
-    sampling = dict(temperature=1.0, top_k=8, top_p=0.9)
-
-    rngs = np.zeros((slots, 2), np.uint32)
-    pending, last, emitted_all = [], np.zeros((slots,), np.int32), []
-    for slot, (prompt, seed) in enumerate(zip(prompts, seeds)):
-        prefill_len = engine.slot_prefill_len(len(prompt))
-        if prefill_len > 0:
-            row, _ = engine.prefill(params, prompt[None, :prefill_len])
-            grid = engine.insert_slot(grid, slot, row)
-        else:
-            grid = engine.evict_slot(grid, slot)
-        pending.append(list(prompt[prefill_len:]))
-        rngs[slot] = np.asarray(jax.random.PRNGKey(seed))
-        emitted_all.append([])
-
-    for _ in range(max_new + max(len(p) for p in pending)):
-        tokens = np.zeros((slots,), np.int32)
-        mask = np.zeros((slots,), bool)
-        for slot in range(slots):
-            if len(emitted_all[slot]) >= max_new:
-                continue  # finished slot rides along masked off
-            if pending[slot]:
-                tokens[slot] = pending[slot][0]
-                mask[slot] = len(pending[slot]) == 1
-            else:
-                tokens[slot] = last[slot]
-                mask[slot] = True
-        if not mask.any():
-            break
-        grid, emitted, rngs_out = engine.step(
-            params, grid, tokens, rngs, mask, **sampling
-        )
-        emitted = np.asarray(emitted)
-        rngs = np.array(rngs_out)
-        for slot in range(slots):
-            if len(emitted_all[slot]) >= max_new:
-                continue
-            if pending[slot]:
-                sampled = len(pending[slot]) == 1
-                pending[slot].pop(0)
-                if not sampled:
-                    continue
-            emitted_all[slot].append(int(emitted[slot]))
-            last[slot] = emitted[slot]
-
-    for slot, (prompt, seed) in enumerate(zip(prompts, seeds)):
-        ref = generate_legacy(
-            model, params, prompt[None], max_new, seed=seed, **sampling
-        )
-        assert emitted_all[slot] == np.asarray(
-            ref
-        )[0, len(prompt):].tolist(), f"slot {slot}"
-    # One grid configuration = ONE compiled step program, reused.
-    assert engine.stats["step_compiles"] == 1
-    assert engine.stats["step_cache_hits"] >= max_new - 1
-
-
-def test_slot_step_traces_with_zero_host_syncs():
-    """Jaxpr twin for the serving step: no host-callback or transfer
-    primitive in the per-tick program."""
-    from tf_yarn_tpu.analysis.jaxpr_engine import (
-        _HOST_CALLBACK_PRIMITIVES,
-        _walk_jaxpr,
-    )
-
-    model, params = _model_and_params()
-    row = jax.eval_shape(
+    bs, num_blocks = 8, 9
+    row_aval = jax.eval_shape(
         build_prefill_fn(model), params,
         jax.ShapeDtypeStruct((1, 1), jnp.int32),
     )[0]
-    grid = jax.tree_util.tree_map(
-        lambda leaf: jax.ShapeDtypeStruct((2,) + leaf.shape, leaf.dtype), row
-    )
-    fn = build_step_fn(model, temperature=1.0, top_k=4, top_p=0.9)
-    closed = jax.make_jaxpr(fn)(
-        params, grid,
-        jax.ShapeDtypeStruct((2,), jnp.int32),
-        jax.ShapeDtypeStruct((2, 2), jnp.uint32),
-        jax.ShapeDtypeStruct((2,), jnp.bool_),
-    )
-    prims = {eqn.primitive.name for eqn in _walk_jaxpr(closed.jaxpr)}
-    assert not prims & _HOST_CALLBACK_PRIMITIVES, sorted(
-        prims & _HOST_CALLBACK_PRIMITIVES
+    layout = cache_layout(model, row_aval)
+    # A pool full of other slots' rows: nothing in it is zero by luck.
+    rng_np = np.random.RandomState(9)
+    pool = jax.tree_util.tree_map(
+        lambda leaf: None if leaf is None else jnp.asarray(
+            rng_np.standard_normal(leaf.shape), leaf.dtype),
+        engine.make_paged_pool(params, num_blocks, bs),
+        is_leaf=lambda x: x is None,
     )
 
+    axis = {  # leaf -> its sequence axis, for the paged leaves
+        jax.tree_util.keystr(path): lay.axis
+        for path, lay in jax.tree_util.tree_leaves_with_path(layout)
+        if lay.kind == "paged"
+    }
 
-def test_insert_and_evict_slot_splice():
-    """insert_slot installs a prefilled batch-1 cache (cache_index
-    included) at exactly one slot; evict_slot zeroes exactly one slot."""
-    model, params = _model_and_params()
-    engine = _engine(model, batch_buckets=(1, 2, 4),
-                     prompt_buckets=(4, 8, 16))
-    grid = engine.make_slot_cache(params, 2)
-    prompt = jnp.arange(8, dtype=jnp.int32)[None]
-    row, _logits = engine.prefill(params, prompt)
-    grid = engine.insert_slot(grid, 1, row)
+    def blocks(tree):
+        """{leaf: host array, block axis first} of a pool."""
+        return {
+            jax.tree_util.keystr(path): np.moveaxis(
+                np.asarray(leaf), axis[jax.tree_util.keystr(path)], 0)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+        }
 
-    leaves = jax.tree_util.tree_leaves_with_path(grid)
-    row_leaves = dict(
-        (jax.tree_util.keystr(path), value)
-        for path, value in jax.tree_util.tree_leaves_with_path(row)
-    )
-    for path, leaf in leaves:
-        expected = row_leaves[jax.tree_util.keystr(path)]
-        np.testing.assert_array_equal(
-            np.asarray(leaf[1]), np.asarray(expected)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(leaf[0]), np.zeros_like(np.asarray(expected))
-        )
-    grid = engine.evict_slot(grid, 1)
-    for _path, leaf in jax.tree_util.tree_leaves_with_path(grid):
-        assert not np.asarray(leaf).any()
+    def row_blocks(row_cache, n):
+        """The first `n` blocks of a prefilled batch-1 cache, cut the
+        same way."""
+        out = {}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(row_cache):
+            name = jax.tree_util.keystr(path)
+            if name not in axis:
+                continue
+            ax, leaf = axis[name], np.asarray(leaf)
+            cut = leaf.reshape(
+                leaf.shape[:ax] + (-1, bs) + leaf.shape[ax + 1:])
+            out[name] = np.moveaxis(cut, ax, 0)[:n]
+        return out
+
+    for seed, ids in ((1, [3, 5]), (2, [5, 3])):  # freed, then reused
+        before = blocks(pool)
+        prompt = jnp.asarray(
+            np.random.RandomState(seed).randint(0, 256, (1, 16)), jnp.int32)
+        row, _logits = engine.prefill(params, prompt)
+        want = row_blocks(row, len(ids))
+        pool = engine.pack_prefill(
+            pool, np.asarray(ids, np.int32), row, 16, bs)
+        after = blocks(pool)
+        assert set(after) == set(before) == set(want) and len(after) >= 2
+        others = [b for b in range(num_blocks) if b not in ids]
+        for name, leaf in after.items():
+            np.testing.assert_array_equal(
+                leaf[others], before[name][others], err_msg=name)
+            np.testing.assert_array_equal(
+                leaf[ids], want[name], err_msg=name)
+            assert not np.array_equal(leaf[ids], before[name][ids])
+    assert engine.stats["pack_compiles"] == 1  # one bucket, ids traced
 
 
 def _drive_paged_slots(model, engine, params, prompts, seeds, max_new,
@@ -528,14 +466,18 @@ def test_int8_prefill_logits_close_to_fp():
 
 def test_paged_pool_layout_and_hbm_accounting():
     """Pool leaves replace the seq axis with (num_blocks, block_size);
-    index leaves are elided; a pool sized below dense-equivalent is
-    proportionally smaller in bytes — the layout's entire point."""
+    index leaves are elided; a pool sized below every slot at full
+    context is proportionally smaller in bytes — the layout's entire
+    point."""
     model, params = _model_and_params()
     engine = _engine(model)
     max_seq = model.config.max_seq_len  # 64
     slots, bs = 4, 8
-    dense = engine.make_slot_cache(params, slots)
-    dense_bytes = cache_nbytes(dense)
+    row = jax.eval_shape(
+        build_prefill_fn(model), params,
+        jax.ShapeDtypeStruct((1, 1), jnp.int32),
+    )[0]
+    full_context_bytes = slots * cache_nbytes(row)
     full = engine.make_paged_pool(params, slots * (max_seq // bs) + 1, bs)
     half = engine.make_paged_pool(params, slots * (max_seq // bs) // 2, bs)
     leaves = [l for l in jax.tree_util.tree_leaves(full)]
@@ -544,23 +486,16 @@ def test_paged_pool_layout_and_hbm_accounting():
         assert bs in leaf.shape
     # cache_index leaves are gone from the pool (positions travel as the
     # step's traced lengths instead).
-    n_dense_leaves = len(jax.tree_util.tree_leaves(dense))
-    assert len(leaves) < n_dense_leaves
+    assert len(leaves) < len(jax.tree_util.tree_leaves(row))
     half_bytes = cache_nbytes(half)
     full_bytes = cache_nbytes(full)
     assert half_bytes < full_bytes
     # Same token capacity costs the same KV bytes (+1 trash block);
-    # fewer blocks = proportionally less resident HBM than dense.
-    assert half_bytes < dense_bytes
+    # fewer blocks = proportionally less resident HBM than a full
+    # context a slot.
+    assert half_bytes < full_context_bytes
     # aval helper agrees with the concrete pool
-    avals = paged_pool_avals(
-        model,
-        jax.eval_shape(
-            build_prefill_fn(model), params,
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        )[0],
-        slots * (max_seq // bs) + 1, bs,
-    )
+    avals = paged_pool_avals(model, row, slots * (max_seq // bs) + 1, bs)
     concrete = jax.tree_util.tree_leaves(full)
     abstract = [a for a in jax.tree_util.tree_leaves(avals)]
     assert [l.shape for l in concrete] == [a.shape for a in abstract]

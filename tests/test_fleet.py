@@ -1001,9 +1001,22 @@ def test_fleet_observability_plane_end_to_end():
         prompts = [rng.randint(0, 256, (n,)).tolist()
                    for n in (4, 7, 3, 6, 5, 8)]
 
+        def routed_count_reaches(n):
+            """Bounded wait for the router's own histogram: it observes a
+            request after it has answered it."""
+            routed = metrics.histogram("fleet/routed_request_seconds",
+                                       path="/v1/generate", outcome="ok")
+            deadline = time.monotonic() + 30
+            while routed.count < n and time.monotonic() < deadline:
+                time.sleep(0.01)
+            return routed.count
+
         # Warm the (shared) engine through the router so compiles land
         # outside the measured window, then reset the process registry:
-        # the sketch under test starts empty.
+        # the sketch under test starts empty. The registry is the
+        # process's: earlier tests of this file have left their own
+        # routed requests in it, so the wait counts from where it stands.
+        routed_before = routed_count_reaches(0)
         warm = [threading.Thread(target=_post, args=(
             router.port, {"prompt": p, "max_new_tokens": 4}, 300,
         )) for p in prompts[:4]]
@@ -1011,14 +1024,10 @@ def test_fleet_observability_plane_end_to_end():
             t.start()
         for t in warm:
             t.join(timeout=600)
-        # The router observes a request after it has answered it: let the
-        # four warm observations land before the registry is emptied, or
-        # a late one is counted with the eight below.
-        routed = metrics.histogram("fleet/routed_request_seconds",
-                                   path="/v1/generate", outcome="ok")
-        deadline = time.monotonic() + 30
-        while routed.count < len(warm) and time.monotonic() < deadline:
-            time.sleep(0.01)
+        # Let the four warm observations land before the registry is
+        # emptied, or a late one is counted with the eight below.
+        assert routed_count_reaches(routed_before + len(warm)) \
+            == routed_before + len(warm)
         metrics.clear()
 
         # Spy on the shared TTFT histogram: every raw server-side TTFT
@@ -1048,6 +1057,9 @@ def test_fleet_observability_plane_end_to_end():
             t.join(timeout=600)
         del hist.observe  # un-spy before the final scrape settles
         assert len(results) == 8
+        # The same for the eight: the last answer can arrive before the
+        # router has observed it, and the scrapes below read the count.
+        assert routed_count_reaches(8) == 8
         rids = {}
         for index, (status, headers, raw) in results.items():
             assert status == 200, raw
@@ -1292,7 +1304,7 @@ def _paged_replica(engine, params, kv, task, max_slots=2):
     from tf_yarn_tpu.serving import ServingServer, SlotScheduler
 
     scheduler = SlotScheduler(
-        engine, params, max_slots=max_slots, kv_layout="paged",
+        engine, params, max_slots=max_slots,
         block_size=4, num_blocks=32, max_seq_len=64,
     )
     scheduler.start()

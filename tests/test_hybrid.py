@@ -288,7 +288,6 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 def _scheduler(tiny, **kwargs):
     engine = DecodeEngine(tiny["model"], prompt_buckets=BUCKETS)
-    kwargs.setdefault("kv_layout", "paged")
     kwargs.setdefault("block_size", BLOCK)
     return SlotScheduler(engine, tiny["variables"], **kwargs)
 
@@ -344,7 +343,6 @@ def test_same_prompt_twice_gets_no_prefix_hit(tiny):
     ({"kv_host_blocks": 8}, "suspend / resume"),
     ({"prefill_chunk": 4}, "chunked prefill"),
     ({"spec_k": 2}, "speculative step"),
-    ({"kv_layout": "dense"}, "kv_layout='dense'"),
 ])
 def test_what_does_not_carry_the_state_is_refused_by_name(tiny, kwargs, feature):
     with pytest.raises(ValueError) as refused:

@@ -42,9 +42,8 @@ from tf_yarn_tpu.serving import (
 )
 from tf_yarn_tpu.serving.paging import TRASH_BLOCK
 
+from tests.fakes import FakePagedEngine, fake_scheduler
 from tests.test_serving import (
-    FakeEngine,
-    FakePagedEngine,
     _drive,
     _legacy_stream,
     _paged_scheduler,
@@ -132,9 +131,8 @@ def test_http_429_retry_after_header_tracks_recent_retire_rate():
     """The 429's Retry-After must reflect queue depth over the recent
     retire rate — not the static hint — once retirements are flowing,
     and clamp back to the static floor when the rate is high."""
-    engine = FakeEngine()
-    scheduler = SlotScheduler(
-        engine, params=None, max_slots=1, queue_capacity=1,
+    scheduler = fake_scheduler(
+        FakePagedEngine(), max_slots=1, queue_capacity=1,
         retry_after_s=2.0,
     )
     server = ServingServer(scheduler, "127.0.0.1", 0)
@@ -235,11 +233,6 @@ def test_serving_experiment_validates_oversubscription_knobs():
     assert ok.kv_host_blocks == 8
     with pytest.raises(ValueError, match="kv_host_blocks"):
         ServingExperiment(model=None, model_dir="/tmp/x", kv_host_blocks=-1)
-    with pytest.raises(ValueError, match="paged"):
-        ServingExperiment(
-            model=None, model_dir="/tmp/x", kv_layout="dense",
-            kv_host_blocks=8,
-        )
     with pytest.raises(ValueError, match="tier"):
         ServingExperiment(
             model=None, model_dir="/tmp/x", tier_caps={"bulk": 4}
@@ -471,7 +464,7 @@ def test_suspend_resume_prefix_storm_keeps_refcounts_and_streams():
     at every checkpoint."""
     engine = _GuardedPagedEngine()
     scheduler = SlotScheduler(
-        engine, params=None, max_slots=2, kv_layout="paged", block_size=4,
+        engine, params=None, max_slots=2, block_size=4,
         num_blocks=5, max_seq_len=32, kv_host_blocks=8,
     )
     engine.scheduler = scheduler
@@ -583,7 +576,7 @@ def _run_oversubscribed_http(kv_cache_dtype="bf16", temperature=0.0,
         # batch needs ceil((9 + 20 - 1)/8) = 4 blocks = the whole
         # usable pool; interactive needs 2 -> displacement.
         return _tiny_serving_stack(
-            max_slots=2, kv_layout="paged", block_size=8, num_blocks=5,
+            max_slots=2, num_blocks=5,
             kv_host_blocks=8, temperature=temperature,
             kv_cache_dtype=kv_cache_dtype,
         )

@@ -55,8 +55,8 @@ from tf_yarn_tpu.serving import (
 from tf_yarn_tpu.serving.paging import prefix_keys
 from tf_yarn_tpu.serving.server import decode_block_wire, encode_block_wire
 
+from tests.fakes import FakePagedEngine
 from tests.test_serving import (
-    FakePagedEngine,
     _drive,
     _legacy_stream,
     _paged_scheduler,
@@ -503,7 +503,7 @@ def test_export_hammer_under_live_eviction_pressure():
     exceptions), and the streams must stay correct throughout."""
     engine = FakePagedEngine()
     scheduler = SlotScheduler(
-        engine, params=None, max_slots=2, kv_layout="paged",
+        engine, params=None, max_slots=2,
         block_size=4, num_blocks=7, max_seq_len=32,
         queue_capacity=64,
     )
@@ -718,7 +718,7 @@ def _run_disagg_http(bodies, kv_cache_dtype="bf16", temperature=0.0,
     local_payloads, decode_engine, client, worker, model, params)."""
     model, params, make_engine = _tiny_disagg_parts(kv_cache_dtype)
     sched_kwargs = dict(
-        kv_layout="paged", block_size=8, temperature=temperature,
+        block_size=8, temperature=temperature,
         **extra_sched_kwargs,
     )
 

@@ -3,14 +3,15 @@
 Two layers of coverage, matching the subsystem's design seam:
 
 * The :class:`SlotScheduler` is a pure host-side state machine whose
-  only device contract is the engine's five slot methods — so the unit
-  tests drive it with a deterministic fake engine and assert the
-  tick-by-tick trace (admit/prefill/step/retire ordering, free-list
-  reuse, deadline eviction, backpressure) with no device in sight.
+  only device contract is the engine's paged methods — so the unit
+  tests drive it with a deterministic fake engine (tests/fakes.py) and
+  assert the tick-by-tick trace (admit/prefill/step/retire ordering,
+  free-list reuse, deadline eviction, backpressure) with no device in
+  sight.
 * The end-to-end tests run the REAL stack on CPU: tiny f32 transformer,
-  DecodeEngine slot grid, scheduler loop, threaded HTTP frontend — and
-  hold the acceptance bar: concurrent requests' token streams are
-  bit-identical to `generate_legacy`, and a slot freed by an early-EOS
+  DecodeEngine over the paged pool, scheduler loop, threaded HTTP
+  frontend — and hold the acceptance bar: concurrent requests' token
+  streams are bit-identical to `generate_legacy`, and a slot freed by an early-EOS
   request is re-admitted before the longest request finishes.
 """
 
@@ -22,6 +23,7 @@ import time
 import numpy as np
 import pytest
 
+from tests.fakes import FakePagedEngine, fake_scheduler
 from tf_yarn_tpu.serving import (
     FINISH_DEADLINE,
     FINISH_EOS,
@@ -96,59 +98,6 @@ def test_response_streams_then_finishes():
 # scheduler unit tests: a deterministic fake engine, no device
 # --------------------------------------------------------------------------
 
-class FakeEngine:
-    """Implements the scheduler's engine contract with pure-host state.
-
-    A slot's "cache" is the running sum of every token it consumed;
-    a sampled step emits ``sum % 97``. Deterministic, so the tests can
-    precompute the exact emission sequence, and every call is logged
-    for ordering assertions.
-    """
-
-    def __init__(self, buckets=(4, 8)):
-        self.buckets = tuple(sorted(buckets))
-        self.calls = []
-
-    def slot_prefill_len(self, prompt_len):
-        best = 0
-        for bucket in self.buckets:
-            if bucket <= prompt_len - 1:
-                best = bucket
-        return best
-
-    def make_slot_cache(self, params, max_slots):
-        self.calls.append(("make", max_slots))
-        return np.zeros((max_slots,), np.int64)
-
-    def prefill(self, params, prompt):
-        self.calls.append(("prefill", prompt.shape))
-        return np.asarray([prompt.sum()], np.int64), None
-
-    def insert_slot(self, cache, slot, row):
-        self.calls.append(("insert", slot))
-        cache = cache.copy()
-        cache[slot] = row[0]
-        return cache
-
-    def evict_slot(self, cache, slot):
-        self.calls.append(("evict", slot))
-        cache = cache.copy()
-        cache[slot] = 0
-        return cache
-
-    def step(self, params, cache, tokens, rngs, sample_mask,
-             temperature=0.0, top_k=None, top_p=None):
-        self.calls.append(
-            ("step", tuple(int(t) for t in np.asarray(tokens)),
-             tuple(bool(m) for m in np.asarray(sample_mask)))
-        )
-        cache = cache + np.asarray(tokens, np.int64)
-        emitted = np.where(
-            np.asarray(sample_mask), cache % 97, np.asarray(tokens)
-        ).astype(np.int32)
-        return cache, emitted, rngs
-
-
 def _drive(scheduler, responses, max_ticks=200):
     """Tick until every response finished; returns ticks used."""
     for used in range(1, max_ticks + 1):
@@ -159,8 +108,8 @@ def _drive(scheduler, responses, max_ticks=200):
 
 
 def test_fake_engine_tick_trace_admit_prefill_step_retire_order():
-    engine = FakeEngine()
-    scheduler = SlotScheduler(engine, params=None, max_slots=2)
+    engine = FakePagedEngine()
+    scheduler = fake_scheduler(engine, max_slots=2)
     # prompt [1..5]: prefill bucket 4 -> cache 1+2+3+4=10, replay [5];
     # the first step consumes 5 -> cache 15 -> emits 15.
     response = scheduler.submit(
@@ -172,8 +121,8 @@ def test_fake_engine_tick_trace_admit_prefill_step_retire_order():
     assert response.finish_reason == FINISH_LENGTH
     kinds = [c[0] for c in engine.calls]
     # Admission device work strictly precedes the first step.
-    assert kinds[:3] == ["make", "prefill", "insert"]
-    assert kinds.count("step") == 3
+    assert kinds[:3] == ["make_pool", "prefill", "pack"]
+    assert kinds.count("paged_step") == 3
     assert scheduler.trace[0]["admitted"] == [response.request.id]
     assert scheduler.trace[-1]["retired"] == [
         (response.request.id, FINISH_LENGTH)
@@ -181,10 +130,10 @@ def test_fake_engine_tick_trace_admit_prefill_step_retire_order():
 
 
 def test_fake_engine_eos_and_whole_prompt_replay():
-    engine = FakeEngine()
-    scheduler = SlotScheduler(engine, params=None, max_slots=1)
+    engine = FakePagedEngine()
+    scheduler = fake_scheduler(engine, max_slots=1)
     # prompt [7, 8]: prompt_len-1 = 1 < min bucket -> NO prefill, whole
-    # prompt replays from an evicted (zeroed) slot: tick1 consumes 7
+    # prompt replays from length 0 of fresh blocks: tick1 consumes 7
     # (masked off), tick2 consumes 8 and emits (7+8)=15.
     response = scheduler.submit(
         [7, 8], SamplingParams(max_new_tokens=8, eos_token=30)
@@ -194,12 +143,16 @@ def test_fake_engine_eos_and_whole_prompt_replay():
     assert response.result(timeout=1) == [15, 30]
     assert response.finish_reason == FINISH_EOS
     kinds = [c[0] for c in engine.calls]
-    assert "evict" in kinds and "prefill" not in kinds
+    assert "prefill" not in kinds and "pack" not in kinds
 
 
 def test_free_list_reuses_slot_on_next_tick():
-    engine = FakeEngine()
-    scheduler = SlotScheduler(engine, params=None, max_slots=2)
+    from tf_yarn_tpu import telemetry
+
+    engine = FakePagedEngine()
+    scheduler = fake_scheduler(engine, max_slots=2)
+    reuse = telemetry.get_registry().counter("serving/slot_reuse_total")
+    reused_before = reuse.value
     # short finishes in 1 generated token; long runs for 6.
     short = scheduler.submit([1, 2, 3, 4, 5],
                              SamplingParams(max_new_tokens=1))
@@ -225,15 +178,15 @@ def test_free_list_reuses_slot_on_next_tick():
     assert long_tick > admit_tick
     # Both early requests ran in slot grid of 2 -> the third admission
     # reused a previously-used slot.
-    inserts = [c[1] for c in engine.calls if c[0] == "insert"]
-    assert len(inserts) == 3 and len(set(inserts)) == 2
+    assert len([c for c in engine.calls if c[0] == "pack"]) == 3
+    assert reuse.value - reused_before == 1
 
 
 def test_deadline_evicts_active_slot_and_queued_request():
-    engine = FakeEngine()
-    scheduler = SlotScheduler(engine, params=None, max_slots=1)
+    engine = FakePagedEngine()
+    scheduler = fake_scheduler(engine, max_slots=1)
     active = scheduler.submit(
-        [1, 2, 3, 4, 5], SamplingParams(max_new_tokens=10 ** 6),
+        [1, 2, 3, 4, 5], SamplingParams(max_new_tokens=20),
         timeout_s=0.05,
     )
     queued = scheduler.submit(
@@ -247,14 +200,15 @@ def test_deadline_evicts_active_slot_and_queued_request():
     # The queued request died in the queue without ever taking a slot.
     scheduler.tick()
     assert queued.finish_reason == FINISH_DEADLINE
-    inserts = [c for c in engine.calls if c[0] in ("insert", "evict")]
-    assert len(inserts) == 1
+    assert [rid for entry in scheduler.trace
+            for rid in entry["admitted"]] == [active.request.id]
+    assert scheduler.stats()["block_pool"]["used_blocks"] == \
+        scheduler.stats()["prefix_cache"]["cached_blocks"]
 
 
 def test_backpressure_rejection_and_sampling_mismatch():
-    engine = FakeEngine()
-    scheduler = SlotScheduler(
-        engine, params=None, max_slots=1, queue_capacity=1,
+    scheduler = fake_scheduler(
+        FakePagedEngine(), max_slots=1, queue_capacity=1,
         retry_after_s=3.0,
     )
     scheduler.submit([1, 2], SamplingParams(max_new_tokens=1))
@@ -268,10 +222,9 @@ def test_backpressure_rejection_and_sampling_mismatch():
 
 
 def test_close_fails_inflight_requests_as_shutdown():
-    engine = FakeEngine()
-    scheduler = SlotScheduler(engine, params=None, max_slots=1)
+    scheduler = fake_scheduler(FakePagedEngine(), max_slots=1)
     active = scheduler.submit([1, 2, 3, 4, 5],
-                              SamplingParams(max_new_tokens=10 ** 6))
+                              SamplingParams(max_new_tokens=20))
     queued = scheduler.submit([1, 2], SamplingParams(max_new_tokens=1))
     scheduler.tick()
     scheduler.close()
@@ -280,7 +233,7 @@ def test_close_fails_inflight_requests_as_shutdown():
 
 
 # --------------------------------------------------------------------------
-# paged layout: host-side bookkeeping + a deterministic fake paged engine
+# the block pool: host-side bookkeeping, pressure, the prefix cache
 # --------------------------------------------------------------------------
 
 def test_block_pool_refcounts_and_free_list():
@@ -325,100 +278,18 @@ def test_prefix_cache_longest_hit_register_and_lru_eviction():
     assert pool.free_blocks == 8
 
 
-class FakePagedEngine:
-    """The scheduler's PAGED device contract with pure-host state: the
-    pool is a (num_blocks, block_size) int64 token store, gathered by
-    the block table exactly like the real program; a sampled step emits
-    ``(sum of consumed tokens) % 97`` — the same arithmetic as
-    FakeEngine, so a table/length bug changes the emission and fails
-    the stream assertions."""
-
-    def __init__(self, buckets=(4, 8), max_seq_len=32):
-        self.buckets = tuple(sorted(buckets))
-        self.max_seq_len = max_seq_len
-        self.calls = []
-
-    def slot_prefill_len(self, prompt_len):
-        best = 0
-        for bucket in self.buckets:
-            if bucket <= prompt_len - 1:
-                best = bucket
-        return best
-
-    def make_paged_pool(self, params, num_blocks, block_size):
-        self.calls.append(("make_pool", num_blocks, block_size))
-        return np.zeros((num_blocks, block_size), np.int64)
-
-    def prefill(self, params, prompt):
-        self.calls.append(("prefill", prompt.shape))
-        return np.asarray(prompt[0], np.int64), None
-
-    def pack_prefill(self, pool, block_ids, row_cache, prefill_len,
-                     block_size):
-        self.calls.append(("pack", tuple(int(b) for b in block_ids)))
-        pool = pool.copy()
-        for pos in range(prefill_len):
-            block = block_ids[pos // block_size]
-            pool[block, pos % block_size] = row_cache[pos]
-        return pool
-
-    def paged_step(self, params, pool, tables, lengths, tokens, rngs,
-                   sample_mask, block_size, temperature=0.0, top_k=None,
-                   top_p=None):
-        self.calls.append(
-            ("paged_step", tuple(int(t) for t in np.asarray(tokens)),
-             tuple(bool(m) for m in np.asarray(sample_mask)))
-        )
-        pool = np.array(pool)
-        tables = np.asarray(tables)
-        lengths = np.asarray(lengths)
-        emitted = np.array(tokens, np.int32)
-        for s in range(len(tokens)):
-            length = int(lengths[s])
-            # Every slot writes its token at its length — inactive rows
-            # (all-zero table) land in the trash block, like the real
-            # program.
-            pool[tables[s, length // block_size],
-                 length % block_size] = tokens[s]
-            if sample_mask[s]:
-                total = 0
-                for pos in range(length + 1):
-                    total += pool[tables[s, pos // block_size],
-                                  pos % block_size]
-                emitted[s] = total % 97
-        return pool, emitted, rngs
-
-    def extract_blocks(self, params, pool, block_ids, block_size):
-        self.calls.append(
-            ("extract", tuple(int(b) for b in np.asarray(block_ids)))
-        )
-        return np.asarray(pool)[np.asarray(block_ids)].copy()
-
-    def inject_blocks(self, params, pool, block_ids, payload, block_size):
-        self.calls.append(
-            ("inject", tuple(int(b) for b in np.asarray(block_ids)))
-        )
-        pool = np.array(pool)
-        payload = np.asarray(payload)
-        for j, block in enumerate(np.asarray(block_ids)):
-            pool[block] = payload[j]
-        return pool
-
-
 def _paged_scheduler(max_slots=2, num_blocks=None, **kwargs):
     engine = FakePagedEngine()
-    scheduler = SlotScheduler(
-        engine, params=None, max_slots=max_slots, kv_layout="paged",
-        block_size=4, num_blocks=num_blocks, max_seq_len=32, **kwargs,
+    return engine, fake_scheduler(
+        engine, max_slots=max_slots, num_blocks=num_blocks, **kwargs
     )
-    return engine, scheduler
 
 
-def test_paged_tick_trace_matches_dense_semantics():
-    """Same request as the dense FakeEngine test, through the paged
-    plumbing: identical stream (prefill bucket 4 -> 10, replay 5 -> 15,
-    then 30, 60), with pool/pack calls instead of insert, and NO device
-    evict anywhere — retirement is host-side bookkeeping."""
+def test_paged_retirement_is_host_side_bookkeeping():
+    """The tick-trace request again (prefill bucket 4 -> 10, replay 5 ->
+    15, then 30, 60): retirement runs NO device program — the engine
+    sees the pool made, one prefill packed and three steps, nothing
+    else — and leaves only the block the prefix cache shares."""
     engine, scheduler = _paged_scheduler()
     response = scheduler.submit(
         [1, 2, 3, 4, 5], SamplingParams(max_new_tokens=3)
@@ -426,9 +297,7 @@ def test_paged_tick_trace_matches_dense_semantics():
     _drive(scheduler, [response])
     assert response.result(timeout=1) == [15, 30, 60]
     kinds = [c[0] for c in engine.calls]
-    assert kinds[:3] == ["make_pool", "prefill", "pack"]
-    assert kinds.count("paged_step") == 3
-    assert "evict" not in kinds and "insert" not in kinds
+    assert kinds == ["make_pool", "prefill", "pack"] + ["paged_step"] * 3
     # All blocks released on retire (none shareable: prefill 4 = 1 full
     # block, kept by the prefix cache).
     stats = scheduler.stats()
@@ -460,8 +329,8 @@ def test_paged_admission_holds_until_blocks_free():
     admit2 = next(t["tick"] for t in trace
                   if second.request.id in t["admitted"])
     assert admit2 == retire1 + 1
-    # Both requests decoded correctly with only 3 usable blocks —
-    # dense layout would have needed 2 full slots' worth.
+    # Both requests decoded correctly with only 3 usable blocks, where
+    # two slots at full context would reserve 16.
 
 
 def test_paged_prefix_hit_skips_prefill_and_shares_blocks():
@@ -519,10 +388,10 @@ def test_paged_submit_rejects_impossible_request():
 def test_tick_error_fails_inflight_and_loop_survives():
     """A tick exception must fail the in-flight requests as `error` and
     leave the scheduler serving — not kill the loop thread."""
-    engine = FakeEngine()
-    scheduler = SlotScheduler(engine, params=None, max_slots=1)
+    engine = FakePagedEngine()
+    scheduler = fake_scheduler(engine, max_slots=1)
     boom = {"armed": True}
-    original = engine.step
+    original = engine.paged_step
 
     def exploding_step(*args, **kwargs):
         if boom["armed"]:
@@ -530,7 +399,7 @@ def test_tick_error_fails_inflight_and_loop_survives():
             raise RuntimeError("injected device failure")
         return original(*args, **kwargs)
 
-    engine.step = exploding_step
+    engine.paged_step = exploding_step
     scheduler.start()
     try:
         failed = scheduler.submit([1, 2, 3, 4, 5],
@@ -549,7 +418,7 @@ def test_tick_error_fails_inflight_and_loop_survives():
 # end-to-end on CPU: real engine, real scheduler loop, real HTTP
 # --------------------------------------------------------------------------
 
-def _tiny_serving_stack(max_slots=2, kv_cache_dtype="bf16",
+def _tiny_serving_stack(max_slots=2, kv_cache_dtype="bf16", block_size=8,
                         **scheduler_kwargs):
     import flax.linen as nn
     import jax
@@ -570,7 +439,8 @@ def _tiny_serving_stack(max_slots=2, kv_cache_dtype="bf16",
         model, batch_buckets=(1, 2, 4), prompt_buckets=(4, 8, 16)
     )
     scheduler = SlotScheduler(
-        engine, params, max_slots=max_slots, **scheduler_kwargs
+        engine, params, max_slots=max_slots, block_size=block_size,
+        **scheduler_kwargs
     )
     return model, params, engine, scheduler
 
@@ -606,8 +476,8 @@ def _legacy_stream(model, params, prompt, max_new, eos=None):
     return row
 
 
-@pytest.mark.slow  # tier-1 budget: the dense HTTP e2e is represented by
-# test_run_serving_task_body_advertises_and_serves (dense stack through
+@pytest.mark.slow  # tier-1 budget: the HTTP e2e is represented by
+# test_run_serving_task_body_advertises_and_serves (the stack through
 # the real frontend) + the engine-level legacy parity in
 # test_whole_prompt_replay_matches_legacy; the HTTP-streams-match-legacy
 # bar stays in tier-1 via test_kv_oversubscription.py::
@@ -691,13 +561,13 @@ def test_http_end_to_end_matches_legacy_with_slot_reuse():
 
 def test_paged_http_end_to_end_matches_legacy_with_prefix_hit():
     """The paged acceptance bar: concurrent requests through the real
-    HTTP frontend over the PAGED layout — with a pool sized BELOW the
-    dense equivalent — produce token streams bit-identical to
+    HTTP frontend — with a pool sized BELOW every slot at full
+    context — produce token streams bit-identical to
     generate_legacy; a follow-up request repeating a prompt admits
     through the prefix cache (no second prefill) and still matches."""
     model, params, engine, scheduler = _tiny_serving_stack(
-        max_slots=2, kv_layout="paged", block_size=8,
-        # Dense-equivalent would be 2 * 64/8 + 1 = 17; run tighter.
+        max_slots=2,
+        # Every slot at full context would be 2 * 64/8 + 1 = 17; run tighter.
         num_blocks=11,
     )
     scheduler.start()
@@ -765,19 +635,13 @@ def test_paged_http_end_to_end_matches_legacy_with_prefix_hit():
         scheduler.close()
 
 
-@pytest.mark.parametrize("layout_kwargs", [
-    {},  # dense
-    {"kv_layout": "paged", "block_size": 8},
-])
-def test_whole_prompt_replay_matches_legacy(layout_kwargs):
+def test_whole_prompt_replay_matches_legacy():
     """Regression for the prefill_len == 0 admission path: a prompt
     shorter than the smallest prompt bucket replays ENTIRELY through
     the step program from an empty slot — previously untested. Streams
     must stay bit-equal to generate_legacy, including when the slot was
     dirtied by an earlier longer request."""
-    model, params, _engine, scheduler = _tiny_serving_stack(
-        max_slots=1, **layout_kwargs
-    )
+    model, params, _engine, scheduler = _tiny_serving_stack(max_slots=1)
     try:
         # Dirty the single slot first so the replay-from-empty path has
         # to prove it does not inherit stale cache state.
@@ -810,8 +674,7 @@ def test_paged_int8_serving_matches_int8_legacy():
     the int8 legacy path (int8-vs-fp accuracy itself is bounded by
     tests/test_decode_engine.py::test_int8_prefill_logits_close_to_fp)."""
     model, params, _engine, scheduler = _tiny_serving_stack(
-        max_slots=2, kv_cache_dtype="int8", kv_layout="paged",
-        block_size=8,
+        max_slots=2, kv_cache_dtype="int8",
     )
     try:
         rng = np.random.RandomState(4)
@@ -940,7 +803,7 @@ def test_http_streaming_backpressure_health_and_stats():
         stats = json.loads(conn.getresponse().read())
         conn.close()
         assert stats["max_slots"] == 1
-        assert stats["decode_engine"]["step_compiles"] >= 1
+        assert stats["decode_engine"]["paged_step_compiles"] >= 1
         assert stats["ticks"] >= 1
     finally:
         server.stop()
@@ -965,8 +828,7 @@ def test_healthz_reports_draining_not_ok_after_drain_notice():
         finally:
             conn.close()
 
-    engine = FakeEngine()
-    scheduler = SlotScheduler(engine, params=None, max_slots=1)
+    scheduler = fake_scheduler(FakePagedEngine(), max_slots=1)
     server = ServingServer(scheduler, "127.0.0.1", 0)
     server.start()
     try:
@@ -982,8 +844,7 @@ def test_healthz_reports_draining_not_ok_after_drain_notice():
         scheduler.close()
 
     # The raw preemption flag flips /healthz too — no poll loop needed.
-    engine = FakeEngine()
-    scheduler = SlotScheduler(engine, params=None, max_slots=1)
+    scheduler = fake_scheduler(FakePagedEngine(), max_slots=1)
     server = ServingServer(scheduler, "127.0.0.1", 0)
     server.start()
     try:
@@ -1077,8 +938,6 @@ def test_serving_experiment_validates():
         ServingExperiment(model=None, model_dir="x", queue_capacity=0)
     with pytest.raises(ValueError, match="serve_seconds"):
         ServingExperiment(model=None, model_dir="x", serve_seconds=-1)
-    with pytest.raises(ValueError, match="kv_layout"):
-        ServingExperiment(model=None, model_dir="x", kv_layout="sparse")
     with pytest.raises(ValueError, match="block_size"):
         ServingExperiment(model=None, model_dir="x", block_size=0)
     with pytest.raises(ValueError, match="num_blocks"):
@@ -1086,8 +945,29 @@ def test_serving_experiment_validates():
     with pytest.raises(ValueError, match="prefix_cache_capacity"):
         ServingExperiment(model=None, model_dir="x",
                           prefix_cache_capacity=-1)
-    # Paged is the default layout (docs/Serving.md).
-    assert ServingExperiment(model=None, model_dir="x").kv_layout == "paged"
+
+
+def test_scheduler_has_one_layout():
+    """The KV layout is not a setting: the constructors refuse the old
+    knob, the wire still says what the layout is, and the dense slot
+    grid's programs are gone from the engine."""
+    import dataclasses
+
+    from tf_yarn_tpu.experiment import ServingExperiment
+    from tf_yarn_tpu.models import decode_engine
+
+    with pytest.raises(TypeError, match="kv_layout"):
+        fake_scheduler(FakePagedEngine(), kv_layout="paged")
+    with pytest.raises(TypeError, match="kv_layout"):
+        ServingExperiment(model=None, model_dir="x", kv_layout="paged")
+    assert "kv_layout" not in {
+        f.name for f in dataclasses.fields(ServingExperiment)}
+    assert fake_scheduler(FakePagedEngine()).stats()["kv_layout"] == "paged"
+    for gone in ("build_step_fn", "build_spec_step_fn"):
+        assert not hasattr(decode_engine, gone)
+    for gone in ("make_slot_cache", "insert_slot", "evict_slot", "step",
+                 "spec_step"):
+        assert not hasattr(decode_engine.DecodeEngine, gone)
 
 
 # --------------------------------------------------------------------------

@@ -20,10 +20,12 @@ import time
 
 import pytest
 
-from tests.test_chunked_prefill import FakePagedWindowedEngine
-from tests.test_serving import (
-    FakeEngine,
+from tests.fakes import (
     FakePagedEngine,
+    FakePagedWindowedEngine,
+    fake_scheduler,
+)
+from tests.test_serving import (
     _drive,
     _post,
     _tiny_serving_stack,
@@ -33,7 +35,6 @@ from tf_yarn_tpu.serving import (
     QueueFull,
     SamplingParams,
     ServingServer,
-    SlotScheduler,
 )
 from tf_yarn_tpu.telemetry import spans as spans_lib
 
@@ -110,15 +111,15 @@ TOP_LEVEL = {"serving/control_ops", "serving/tick", "serving/publish",
 
 
 def test_scheduler_thread_spans_tile_its_time_and_step_children_tile_step():
-    engine = FakeEngine()
-    real_step = engine.step
+    engine = FakePagedEngine(max_seq_len=64)
+    real_step = engine.paged_step
 
     def slow_step(*args, **kwargs):  # a step worth measuring: 2 ms
         time.sleep(0.002)
         return real_step(*args, **kwargs)
 
-    engine.step = slow_step
-    scheduler = SlotScheduler(engine, params=None, max_slots=2)
+    engine.paged_step = slow_step
+    scheduler = fake_scheduler(engine, max_slots=2)
     scheduler.start()
     try:
         responses = [
@@ -168,8 +169,7 @@ def test_scheduler_thread_spans_tile_its_time_and_step_children_tile_step():
 
 
 def test_engine_step_args_span_sits_under_launch():
-    _model, _params, _engine, scheduler = _tiny_serving_stack(
-        max_slots=2, kv_layout="paged", block_size=8)
+    _model, _params, _engine, scheduler = _tiny_serving_stack(max_slots=2)
     response = scheduler.submit([5, 6, 7], SamplingParams(max_new_tokens=3))
     _drive(scheduler, [response])
     records = telemetry.get_tracer().records()
@@ -196,9 +196,8 @@ def _check_parts(record):
 
 
 def test_every_finish_reason_leaves_one_record_under_the_callers_id():
-    engine = FakeEngine()
-    scheduler = SlotScheduler(engine, params=None, max_slots=1,
-                              queue_capacity=2)
+    scheduler = fake_scheduler(FakePagedEngine(), max_slots=1,
+                               queue_capacity=2)
     # `length`, and `eos` at the first emission (sum of prompt % 97).
     length = scheduler.submit([1, 2, 3, 4, 5, 6],
                               SamplingParams(max_new_tokens=3),
@@ -217,7 +216,7 @@ def test_every_finish_reason_leaves_one_record_under_the_callers_id():
     _drive(scheduler, [length, eos])
     assert length.finish_reason == "length" and eos.finish_reason == "eos"
     # Deadline in the queue (never admitted), and in a slot.
-    blocker = scheduler.submit([1] * 5, SamplingParams(max_new_tokens=500),
+    blocker = scheduler.submit([1] * 5, SamplingParams(max_new_tokens=20),
                                trace_id="r-slot-deadline", timeout_s=0.05)
     queued = scheduler.submit([2, 3], SamplingParams(max_new_tokens=2),
                               trace_id="r-queue-deadline", timeout_s=0.02)
@@ -271,10 +270,7 @@ def test_every_finish_reason_leaves_one_record_under_the_callers_id():
 def test_prefix_hit_and_chunked_prefill_keep_every_request_under_its_id():
     # Prefix hit: the second request shares the first's 8-token block
     # prefix and opens no serving/prefill span — a join by order fails.
-    engine = FakePagedEngine()
-    scheduler = SlotScheduler(engine, params=None, max_slots=2,
-                              kv_layout="paged", block_size=4,
-                              max_seq_len=32)
+    scheduler = fake_scheduler(FakePagedEngine(), max_slots=2)
     shared = [3, 1, 4, 1, 5, 9, 2, 6]
     first = scheduler.submit(shared + [5, 3], SamplingParams(max_new_tokens=2),
                              trace_id="hit-a")
@@ -294,9 +290,8 @@ def test_prefix_hit_and_chunked_prefill_keep_every_request_under_its_id():
 
     # Chunked prefill: no blocking prefill at all, on any request.
     telemetry.get_tracer().clear()
-    chunked = SlotScheduler(FakePagedWindowedEngine(), params=None,
-                            max_slots=2, kv_layout="paged", block_size=4,
-                            max_seq_len=32, prefill_chunk=4)
+    chunked = fake_scheduler(FakePagedWindowedEngine(), max_slots=2,
+                             prefill_chunk=4)
     responses = [
         chunked.submit([i + 1] * (6 + i), SamplingParams(max_new_tokens=3),
                        trace_id=f"chunk-{i}")
@@ -321,14 +316,9 @@ def test_prefix_hit_and_chunked_prefill_keep_every_request_under_its_id():
 # (d) counters where the work happens
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_kv_token_steps_equals_a_hand_count(layout):
-    if layout == "dense":
-        scheduler = SlotScheduler(FakeEngine(), params=None, max_slots=2)
-    else:
-        scheduler = SlotScheduler(
-            FakePagedEngine(), params=None, max_slots=2, kv_layout="paged",
-            block_size=4, max_seq_len=32, prefix_cache_capacity=0)
+def test_kv_token_steps_equals_a_hand_count():
+    scheduler = fake_scheduler(FakePagedEngine(), max_slots=2,
+                               prefix_cache_capacity=0)
     # Prompt 6 -> 4 prefilled, 2 replayed, 3 emitted: steps read caches of
     # 4, 5 (replay; the second emits), 6, 7 (decode) tokens = 4 steps.
     # Prompt 3 -> 0 prefilled: steps read 0, 1, 2 (replay), 3 (decode).
@@ -344,8 +334,8 @@ def test_kv_token_steps_equals_a_hand_count(layout):
 
 
 def test_slow_steps_are_counted_with_their_launch_and_sync():
-    engine = FakeEngine()
-    real_step = engine.step
+    engine = FakePagedEngine()
+    real_step = engine.paged_step
     calls = {"n": 0}
 
     def step(*args, **kwargs):
@@ -353,8 +343,8 @@ def test_slow_steps_are_counted_with_their_launch_and_sync():
         time.sleep(0.1 if calls["n"] == 20 else 0.005)
         return real_step(*args, **kwargs)
 
-    engine.step = step
-    scheduler = SlotScheduler(engine, params=None, max_slots=1)
+    engine.paged_step = step
+    scheduler = fake_scheduler(engine, max_slots=1)
     response = scheduler.submit([1, 2], SamplingParams(max_new_tokens=30))
     _drive(scheduler, [response])
     stats = scheduler.stats()
@@ -446,7 +436,7 @@ def test_debug_profile_writes_an_xplane_and_refuses_a_second(tmp_path,
 
 def test_debug_profile_without_a_directory_answers_409(monkeypatch):
     monkeypatch.delenv("TPU_YARN_PROFILE", raising=False)
-    scheduler = SlotScheduler(FakeEngine(), params=None, max_slots=1)
+    scheduler = fake_scheduler(FakePagedEngine(), max_slots=1)
     server = ServingServer(scheduler, "127.0.0.1", 0)
     server.start()
     try:
@@ -458,7 +448,7 @@ def test_debug_profile_without_a_directory_answers_409(monkeypatch):
 
 
 def test_submit_span_carries_the_programs_id_where_none_came():
-    scheduler = SlotScheduler(FakeEngine(), params=None, max_slots=1)
+    scheduler = fake_scheduler(FakePagedEngine(), max_slots=1)
     scheduler.start()
     server = ServingServer(scheduler, "127.0.0.1", 0)
     server.start()

@@ -7,17 +7,17 @@ Three layers, matching the subsystem's seams:
 * **Host units** — the n-gram/prompt-lookup drafter and `plan_window`
   are pure host code with exact expected outputs.
 * **Fake engine** — the scheduler's windowed tick is driven with a
-  deterministic fake `spec_step` (FakeEngine's sum%97 arithmetic over
-  windows), pinning variable tokens/tick, draft capping at max_new,
+  deterministic fake windowed step (tests/fakes.py: sum%97 arithmetic
+  over windows), pinning variable tokens/tick, draft capping at max_new,
   eos-in-window retirement, the trace ring's `accepted` records, and
   the accept-rate-0 worst case (exactly one token per step).
 * **Real engine on CPU** — the acceptance bars: greedy speculative
-  streams are IDENTICAL to `generate_legacy` across dense and paged
-  layouts (prefix-cache hits, whole-prompt replay, early EOS inside an
+  streams are IDENTICAL to `generate_legacy` (prefix-cache hits,
+  whole-prompt replay, early EOS inside an
   accepted window included), the sampled path preserves the per-request
   RNG chain bit-for-bit, no recompiles tick-to-tick, e2e through the
   HTTP server, and the fused paged-int8 decode attention agrees with
-  the dense-gather path within quantization tolerance.
+  the gather path within quantization tolerance.
 """
 
 import http.client
@@ -27,6 +27,7 @@ import threading
 import numpy as np
 import pytest
 
+from tests.fakes import FakePagedSpecEngine, fake_scheduler
 from tf_yarn_tpu.models.spec import (
     NGramDrafter,
     make_drafter,
@@ -154,74 +155,6 @@ def test_verify_window_greedy_accept_truncate_and_eos():
 # scheduler windowed tick over a deterministic fake engine
 # --------------------------------------------------------------------------
 
-class FakeSpecEngine:
-    """test_serving.FakeEngine's sum%97 arithmetic, windowed: consuming
-    a token adds it to the slot's cache sum; an emitting position emits
-    ``sum % 97``; a draft is accepted iff it equals that emission.
-    Emissions are always < 97, so token 98 is a guaranteed-reject
-    draft and the accept-rate-0 worst case is constructible exactly."""
-
-    def __init__(self, buckets=(4, 8)):
-        self.buckets = tuple(sorted(buckets))
-        self.calls = []
-
-    def slot_prefill_len(self, prompt_len):
-        best = 0
-        for bucket in self.buckets:
-            if bucket <= prompt_len - 1:
-                best = bucket
-        return best
-
-    def make_slot_cache(self, params, max_slots):
-        return np.zeros((max_slots,), np.int64)
-
-    def prefill(self, params, prompt):
-        self.calls.append(("prefill", prompt.shape))
-        return np.asarray([prompt.sum()], np.int64), None
-
-    def insert_slot(self, cache, slot, row):
-        cache = cache.copy()
-        cache[slot] = row[0]
-        return cache
-
-    def evict_slot(self, cache, slot):
-        cache = cache.copy()
-        cache[slot] = 0
-        return cache
-
-    def spec_step(self, params, cache, tokens, n_known, eos_ids, rngs,
-                  active, temperature=0.0, top_k=None, top_p=None):
-        tokens = np.asarray(tokens)
-        slots, width = tokens.shape
-        self.calls.append(("spec_step", tokens.copy(),
-                           np.asarray(n_known).copy()))
-        cache = cache.copy()
-        emitted = np.zeros((slots, width), np.int32)
-        counts = np.zeros((slots,), np.int32)
-        for s in range(slots):
-            if not active[s]:
-                continue
-            total = cache[s]
-            out_prev, alive = None, True
-            n = 0
-            for i in range(width):
-                if i > int(n_known[s]):
-                    alive = alive and tokens[s, i] == out_prev \
-                        and out_prev != eos_ids[s]
-                if i >= int(n_known[s]) and not alive:
-                    break
-                total += int(tokens[s, i])
-                if i >= int(n_known[s]):
-                    out_prev = int(total % 97)
-                    emitted[s, n] = out_prev
-                    n += 1
-                    if out_prev == eos_ids[s]:
-                        break
-            cache[s] = total
-            counts[s] = n
-        return cache, emitted, counts, rngs
-
-
 def _drive(scheduler, responses, max_ticks=200):
     for used in range(1, max_ticks + 1):
         scheduler.tick()
@@ -231,7 +164,7 @@ def _drive(scheduler, responses, max_ticks=200):
 
 
 def test_fake_spec_engine_accepts_drafts_variable_tokens_per_tick():
-    engine = FakeSpecEngine()
+    engine = FakePagedSpecEngine()
     # Oracle drafter for the fake arithmetic: prompt [1..5] -> prefill
     # sum 10, consume 5 -> emit 15, then 30, 60, 23, 46. Proposing the
     # true continuation accepts everything.
@@ -240,8 +173,8 @@ def test_fake_spec_engine_accepts_drafts_variable_tokens_per_tick():
     def drafter(context, k):
         return oracle.get(len(context) - 5, [])[:k]
 
-    scheduler = SlotScheduler(
-        engine, params=None, max_slots=1, spec_k=3, spec_draft=drafter,
+    scheduler = fake_scheduler(
+        engine, max_slots=1, spec_k=3, spec_draft=drafter,
     )
     response = scheduler.submit([1, 2, 3, 4, 5],
                                 SamplingParams(max_new_tokens=5))
@@ -267,10 +200,10 @@ def test_fake_spec_engine_accepts_drafts_variable_tokens_per_tick():
 
 
 def test_fake_spec_engine_accept_rate_zero_degrades_to_one_token_per_step():
-    engine = FakeSpecEngine()
+    engine = FakePagedSpecEngine()
     # 98 can never be emitted (emissions are mod 97): guaranteed reject.
-    scheduler = SlotScheduler(
-        engine, params=None, max_slots=1, spec_k=3,
+    scheduler = fake_scheduler(
+        engine, max_slots=1, spec_k=3,
         spec_draft=lambda context, k: [98] * k,
     )
     response = scheduler.submit([1, 2, 3, 4, 5],
@@ -278,24 +211,24 @@ def test_fake_spec_engine_accept_rate_zero_degrades_to_one_token_per_step():
     _drive(scheduler, [response])
     # Same stream as the exact path, exactly one token per emitting
     # tick, and the window shape never changed (no recompile pressure:
-    # every spec_step call saw the same (slots, width)).
+    # every windowed step saw the same (slots, width)).
     assert response.result(timeout=1) == [15, 30, 60, 23]
     accepted = [list(t["accepted"].values())
                 for t in scheduler.trace if t.get("accepted")]
     assert accepted == [[1], [1], [1], [1]]
     shapes = {call[1].shape for call in engine.calls
-              if call[0] == "spec_step"}
+              if call[0] == "paged_spec_step"}
     assert shapes == {(1, 4)}
     assert scheduler.stats()["spec"]["accept_rate"] == 0.0
 
 
 def test_fake_spec_engine_eos_inside_accepted_window_retires():
-    engine = FakeSpecEngine()
+    engine = FakePagedSpecEngine()
     # Emissions: 15, 30, 60, ... — make 30 the eos and propose [15, 30,
     # 60]: the device truncates AT the eos, the request retires with
     # finish_reason eos, and the third (matching) draft never lands.
-    scheduler = SlotScheduler(
-        engine, params=None, max_slots=1, spec_k=3,
+    scheduler = fake_scheduler(
+        engine, max_slots=1, spec_k=3,
         spec_draft=lambda context, k: [15, 30, 60][:k],
     )
     response = scheduler.submit(
@@ -308,15 +241,15 @@ def test_fake_spec_engine_eos_inside_accepted_window_retires():
 
 
 def test_fake_spec_engine_drafts_capped_by_max_new_tokens():
-    engine = FakeSpecEngine()
+    engine = FakePagedSpecEngine()
     seen_windows = []
 
     def drafter(context, k):
         seen_windows.append(k)
         return [15, 30, 60][:k]
 
-    scheduler = SlotScheduler(
-        engine, params=None, max_slots=1, spec_k=3, spec_draft=drafter,
+    scheduler = fake_scheduler(
+        engine, max_slots=1, spec_k=3, spec_draft=drafter,
     )
     response = scheduler.submit([1, 2, 3, 4, 5],
                                 SamplingParams(max_new_tokens=2))
@@ -328,22 +261,18 @@ def test_fake_spec_engine_drafts_capped_by_max_new_tokens():
 
 
 def test_scheduler_validates_spec_arguments():
-    engine = FakeSpecEngine()
+    engine = FakePagedSpecEngine()
     with pytest.raises(ValueError, match="spec_k"):
-        SlotScheduler(engine, params=None, spec_k=-1)
+        fake_scheduler(engine, spec_k=-1)
     with pytest.raises(ValueError, match="decode_attention"):
-        SlotScheduler(engine, params=None, decode_attention="magic")
-    with pytest.raises(ValueError, match="paged"):
-        SlotScheduler(engine, params=None, decode_attention="fused")
+        fake_scheduler(engine, decode_attention="magic")
     with pytest.raises(ValueError, match="spec_draft"):
-        SlotScheduler(engine, params=None, spec_k=2, spec_draft="llama")
+        fake_scheduler(engine, spec_k=2, spec_draft="llama")
 
 
 def test_spec_context_limit_reserves_window_headroom():
-    engine = FakeSpecEngine()
-    scheduler = SlotScheduler(
-        engine, params=None, max_slots=1, spec_k=4, max_seq_len=32,
-    )
+    engine = FakePagedSpecEngine()
+    scheduler = fake_scheduler(engine, max_slots=1, spec_k=4)
     assert scheduler.context_limit == 28
     with pytest.raises(ValueError, match="headroom"):
         scheduler.submit([1] * 20, SamplingParams(max_new_tokens=9))
@@ -354,7 +283,8 @@ def test_spec_context_limit_reserves_window_headroom():
 # real engine on CPU: the acceptance bars
 # --------------------------------------------------------------------------
 
-def _tiny_stack(max_slots=2, kv_cache_dtype="bf16", **scheduler_kwargs):
+def _tiny_stack(max_slots=2, kv_cache_dtype="bf16", block_size=8,
+                **scheduler_kwargs):
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
@@ -374,7 +304,8 @@ def _tiny_stack(max_slots=2, kv_cache_dtype="bf16", **scheduler_kwargs):
         model, batch_buckets=(1, 2, 4), prompt_buckets=(4, 8, 16)
     )
     scheduler = SlotScheduler(
-        engine, params, max_slots=max_slots, **scheduler_kwargs
+        engine, params, max_slots=max_slots, block_size=block_size,
+        **scheduler_kwargs
     )
     return model, params, engine, scheduler
 
@@ -414,32 +345,19 @@ def _oracle_drafter(model, params, prompts, max_new):
     return drafter
 
 
-@pytest.mark.parametrize("layout_kwargs, kv_cache_dtype", [
-    ({}, "bf16"),  # dense — the tier-1 representative of this bar
-    # The paged and fused-int8 stream variants are slow-marked: tier-1
-    # keeps the dense representative here plus paged/fused coverage via
-    # test_paged_spec_prefix_cache_hit_stream_identical and
-    # test_fused_decode_attention_matches_gather_within_tolerance; the
-    # full matrix still runs in the non-tier-1 sweep.
-    pytest.param(
-        {"kv_layout": "paged", "block_size": 8}, "bf16",
-        marks=pytest.mark.slow,
-    ),
-    pytest.param(
-        {"kv_layout": "paged", "block_size": 8,
-         "decode_attention": "fused"}, "int8",
-        marks=pytest.mark.slow,
-    ),
+@pytest.mark.parametrize("scheduler_kwargs, kv_cache_dtype", [
+    ({}, "bf16"),
+    ({"decode_attention": "fused"}, "int8"),
 ])
-def test_greedy_spec_streams_identical_to_legacy(layout_kwargs,
+def test_greedy_spec_streams_identical_to_legacy(scheduler_kwargs,
                                                  kv_cache_dtype):
     """The tentpole bar: greedy speculative streams are IDENTICAL to
-    generate_legacy across dense, paged, and fused-paged-int8 layouts —
+    generate_legacy, through the gather verify and the fused int8 one —
     with the n-gram self-drafter live, concurrent mixed-length
     requests, and a whole-prompt-replay short prompt in the mix."""
     model, params, engine, scheduler = _tiny_stack(
         max_slots=2, kv_cache_dtype=kv_cache_dtype, spec_k=3,
-        **layout_kwargs,
+        **scheduler_kwargs,
     )
     try:
         rng = np.random.RandomState(0)
@@ -461,8 +379,7 @@ def test_greedy_spec_streams_identical_to_legacy(layout_kwargs,
             )
         # ONE windowed program compiled for the whole run — variable
         # accepts tick-to-tick never recompile.
-        assert engine.stats["spec_step_compiles"] \
-            + engine.stats["paged_spec_step_compiles"] == 1
+        assert engine.stats["paged_spec_step_compiles"] == 1
     finally:
         scheduler.close()
 
@@ -530,7 +447,7 @@ def test_spec_accept_rate_zero_real_engine_one_token_per_tick():
                     for n in t.get("accepted", {}).values()]
         assert accepted == [1] * max_new
         assert scheduler.stats()["spec"]["accept_rate"] == 0.0
-        assert engine.stats["spec_step_compiles"] == 1
+        assert engine.stats["paged_spec_step_compiles"] == 1
     finally:
         scheduler.close()
 
@@ -540,9 +457,12 @@ def test_spec_early_eos_inside_accepted_window_matches_legacy():
     request retires as `eos`, and the stream equals legacy's (which
     stops there too) — accepted tokens past the eos are discarded."""
     model, params, _engine, probe = _tiny_stack(max_slots=1)
-    prompt = [int(t) for t in np.random.RandomState(3).randint(0, 256, (5,))]
+    prompt = [int(t) for t in np.random.RandomState(6).randint(0, 256, (5,))]
     full = _legacy_stream(model, params, prompt, 12)
     eos = full[2]  # the third greedy token becomes the eos
+    # The prompt is chosen for this: a stream that repeats its third
+    # token earlier (seed 3's does) would stop at the repeat.
+    assert eos not in full[:2], full
     probe.close()
     model, params, engine, scheduler = _tiny_stack(
         max_slots=1, spec_k=3,
@@ -594,7 +514,7 @@ def test_paged_spec_prefix_cache_hit_stream_identical():
     with the same prompt admits through the shared blocks (no second
     prefill) and its speculative stream still equals legacy."""
     model, params, engine, scheduler = _tiny_stack(
-        max_slots=1, spec_k=3, kv_layout="paged", block_size=8,
+        max_slots=1, spec_k=3,
     )
     try:
         prompt = [int(t) for t in
@@ -621,8 +541,8 @@ def test_fused_decode_attention_matches_gather_within_tolerance():
     and 'fused'. Emitted tokens and counts must be identical, and the
     K/V rows the window wrote into the slot's own blocks must agree to
     quantization tolerance — the two paths differ only in attention
-    reduction order (the kernel's online softmax vs the dense-gather
-    xla reduction). Trash-block garbage is excluded by construction:
+    reduction order (the kernel's online softmax vs the gathered
+    view's xla reduction). Trash-block garbage is excluded by construction:
     writes there are unordered across colliding slots."""
     import jax
     import jax.numpy as jnp
@@ -631,7 +551,7 @@ def test_fused_decode_attention_matches_gather_within_tolerance():
     def run(mode):
         model, params, engine, scheduler = _tiny_stack(
             max_slots=2, kv_cache_dtype="int8", spec_k=2,
-            kv_layout="paged", block_size=8, decode_attention=mode,
+            decode_attention=mode,
         )
         scheduler.close()
         prompt = [int(t) for t in
@@ -702,7 +622,7 @@ def test_spec_http_end_to_end_matches_legacy_and_reports_stats():
                rng.randint(0, 256, (9,)).tolist()]
     probe.close()
     model, params, engine, scheduler = _tiny_stack(
-        max_slots=2, spec_k=3, kv_layout="paged", block_size=8,
+        max_slots=2, spec_k=3,
         spec_draft=_oracle_drafter(model, params, prompts, 12),
     )
     scheduler.start()
@@ -767,9 +687,6 @@ def test_serving_experiment_spec_fields_validate():
     with pytest.raises(ValueError, match="decode_attention"):
         ServingExperiment(model=None, model_dir="x",
                           decode_attention="magic")
-    with pytest.raises(ValueError, match="paged"):
-        ServingExperiment(model=None, model_dir="x", kv_layout="dense",
-                          decode_attention="fused")
     experiment = ServingExperiment(
         model=None, model_dir="x", spec_k=4,
         spec_draft=lambda context, k: [],

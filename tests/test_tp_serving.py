@@ -5,7 +5,7 @@ The acceptance bar, held on the forced host-platform device rig
 (conftest gives 8 virtual CPU devices): a tp=2 `DecodeEngine` behind
 the REAL serving stack produces per-request token streams BIT-IDENTICAL
 to single-device `generate_legacy` — greedy AND sampled RNG chains,
-dense grid AND paged pool, prefix-cache hit, whole-prompt replay, and
+prefix-cache hit, whole-prompt replay, and
 spec_k > 0 — while each device holds 1/tp of every slot's KV (exact)
 and ~1/tp of the weights (wk/wv and the norms replicate by the logical
 rules). The compiled tick program must contain the TP all-reduces the
@@ -37,7 +37,7 @@ def _mesh(tp=2):
 _SHARED = {}
 
 
-def _tiny_stack(mesh=None, **scheduler_kwargs):
+def _tiny_stack(mesh=None, block_size=8, **scheduler_kwargs):
     """Tiny f32 transformer + (optionally sharded) params + a FRESH
     scheduler over the module-shared engine."""
     import flax.linen as nn
@@ -67,7 +67,8 @@ def _tiny_stack(mesh=None, **scheduler_kwargs):
         _SHARED[key] = (model, params, placed, engine)
     model, params, placed, engine = _SHARED[key]
     scheduler = SlotScheduler(
-        engine, placed, max_slots=2, **scheduler_kwargs
+        engine, placed, max_slots=2, block_size=block_size,
+        **scheduler_kwargs
     )
     return model, params, engine, scheduler
 
@@ -125,8 +126,7 @@ def test_serving_experiment_rejects_bad_tp_configs():
     # The fused pallas kernel cannot read a sharded pool.
     with pytest.raises(ValueError, match="fused"):
         build(
-            mesh_spec=MeshSpec(tp=2), kv_layout="paged",
-            decode_attention="fused",
+            mesh_spec=MeshSpec(tp=2), decode_attention="fused",
         )
     # tp=1 (or None) stays valid — the single-device path.
     build(mesh_spec=MeshSpec(tp=1))
@@ -160,7 +160,7 @@ def test_engine_and_scheduler_reject_bad_tp_at_build():
 
     with pytest.raises(ValueError, match="sharded block pool"):
         SlotScheduler(
-            _TpStub(), None, max_slots=1, kv_layout="paged",
+            _TpStub(), None, max_slots=1,
             decode_attention="fused", max_seq_len=64, block_size=8,
         )
 
@@ -171,8 +171,8 @@ def test_engine_and_scheduler_reject_bad_tp_at_build():
 
 @pytest.mark.slow  # heaviest TP e2e variant; tier-1 keeps the paged
 # prefix-hit e2e + mesh-spec e2e + tp spec decode as TP representatives
-def test_tp_http_dense_greedy_and_sampled_match_legacy():
-    """tp=2 dense grid through the REAL HTTP frontend: concurrent
+def test_tp_http_sampled_streams_match_legacy():
+    """tp=2 block pool through the REAL HTTP frontend: concurrent
     SAMPLED requests (distinct seeds) stream bit-identically to
     single-device generate_legacy — the sampled chain proves the
     sharded program consumes the per-slot RNG exactly like the
@@ -181,7 +181,7 @@ def test_tp_http_dense_greedy_and_sampled_match_legacy():
 
     sampling = dict(temperature=1.0, top_k=8)
     model, params, engine, scheduler = _tiny_stack(
-        mesh=_mesh(), **sampling
+        mesh=_mesh(), num_blocks=17, **sampling
     )
     scheduler.start()
     server = ServingServer(scheduler, "127.0.0.1", 0)
@@ -232,7 +232,7 @@ def test_tp_paged_greedy_prefix_hit_and_replay_match_legacy():
     whole-prompt-replay path (prefill_len == 0) against the sharded
     trash-block pool."""
     model, params, engine, scheduler = _tiny_stack(
-        mesh=_mesh(), kv_layout="paged", block_size=8, num_blocks=17,
+        mesh=_mesh(), num_blocks=17,
     )
     scheduler.start()
     try:
@@ -256,9 +256,13 @@ def test_tp_paged_greedy_prefix_hit_and_replay_match_legacy():
         stats = scheduler.stats()
         assert stats["prefix_cache"]["hits"] >= 1
         assert stats["tp_degree"] == 2
-        # ONE paged step program for the whole run — tick-to-tick table
-        # changes never recompiled under the mesh either.
-        assert engine.stats["paged_step_compiles"] == 1
+        # ONE greedy paged step program for the whole run — tick-to-tick
+        # table changes never recompiled under the mesh either (the
+        # engine is module-shared: another test's sampling config is
+        # another key).
+        greedy = [key for key in engine.program_keys()["paged_step"]
+                  if key[3:6] == (0.0, None, None)]
+        assert len(greedy) == 1
     finally:
         scheduler.close()
 
@@ -269,7 +273,7 @@ def test_tp_spec_decode_matches_legacy():
     equals generate_legacy on a repeated-structure prompt the n-gram
     drafter can exploit."""
     model, params, engine, scheduler = _tiny_stack(
-        mesh=_mesh(), kv_layout="paged", block_size=8, num_blocks=17,
+        mesh=_mesh(), num_blocks=17,
         spec_k=2,
     )
     scheduler.start()
@@ -293,7 +297,7 @@ def test_tp_chunked_prefill_matches_legacy():
     still equals single-device generate_legacy, and a repeat of the
     prompt admits through the incrementally registered prefix blocks."""
     model, params, engine, scheduler = _tiny_stack(
-        mesh=_mesh(), kv_layout="paged", block_size=8, num_blocks=17,
+        mesh=_mesh(), num_blocks=17,
         prefill_chunk=4, prefill_budget_per_tick=8,
     )
     scheduler.start()
@@ -368,7 +372,7 @@ def test_run_serving_with_mesh_spec_serves_sharded_e2e(monkeypatch):
     runtime = _Runtime()
     experiment = ServingExperiment(
         model=model, model_dir="/nonexistent-restore-is-patched",
-        host="127.0.0.1", max_slots=2, kv_layout="paged", block_size=8,
+        host="127.0.0.1", max_slots=2, block_size=8,
         mesh_spec=MeshSpec(tp=2),
     )
     result = {}
@@ -413,10 +417,10 @@ def test_run_serving_with_mesh_spec_serves_sharded_e2e(monkeypatch):
 # --------------------------------------------------------------------------
 
 def test_tp_hbm_accounting_weights_and_kv_near_half():
-    """Per-device residency at tp=2 vs tp=1: the slot KV (dense grid
-    and paged pool) lands at EXACTLY 1/2 for the sharded leaves (the
-    per-layer cache_index scalars replicate — within one block of
-    rounding), and the weights at ~1/2 (wk/wv and the norms replicate
+    """Per-device residency at tp=2 vs tp=1: the slot KV (the block
+    pool, and the prefill's row cache that is packed into it) lands at
+    EXACTLY 1/2 for the sharded leaves (the row's per-layer cache_index
+    scalars replicate), and the weights at ~1/2 (wk/wv and the norms replicate
     by LOGICAL_RULES, a small constant fraction of a tiny config)."""
     from tf_yarn_tpu.models.decode_engine import (
         cache_nbytes,
@@ -424,13 +428,9 @@ def test_tp_hbm_accounting_weights_and_kv_near_half():
     )
 
     mesh = _mesh()
-    model, params, engine, scheduler = _tiny_stack(
-        mesh=mesh, kv_layout="paged", block_size=8,
-    )
+    model, params, engine, scheduler = _tiny_stack(mesh=mesh)
     try:
-        _model, _params, engine1, scheduler1 = _tiny_stack(
-            mesh=None, kv_layout="paged", block_size=8,
-        )
+        _model, _params, engine1, scheduler1 = _tiny_stack(mesh=None)
         try:
             tp1 = scheduler1.stats()
             tp2 = scheduler.stats()
@@ -442,11 +442,12 @@ def test_tp_hbm_accounting_weights_and_kv_near_half():
                 tp2["kv_cache_hbm_bytes_per_device"]
                 == tp1["kv_cache_hbm_bytes_per_device"] // 2
             )
-            # Dense grid: sharded KV leaves exactly halve; the index
-            # scalars (8 bytes/layer/slot) replicate.
-            grid = engine.make_slot_cache(scheduler.params, 2)
-            per_dev = tree_nbytes_per_device(grid)
-            total = cache_nbytes(grid)
+            # The prefill's row cache: sharded KV leaves exactly halve;
+            # the index scalars (4 bytes a layer) replicate.
+            row, _logits = engine.prefill(
+                scheduler.params, np.zeros((1, 8), np.int32))
+            per_dev = tree_nbytes_per_device(row)
+            total = cache_nbytes(row)
             assert total // 2 <= per_dev <= total // 2 + 1024
             # Weights: sharded by the logical rules; wk/wv + norms
             # replicate, so per-device lands near (not exactly) half.
@@ -473,7 +474,8 @@ def test_tp_step_program_has_allreduce_and_no_host_callbacks():
     )
     from tf_yarn_tpu.serving import SamplingParams
 
-    model, params, engine, scheduler = _tiny_stack(mesh=_mesh())
+    model, params, engine, scheduler = _tiny_stack(
+        mesh=_mesh(), num_blocks=17)
     scheduler.start()
     try:
         scheduler.submit(
@@ -484,8 +486,8 @@ def test_tp_step_program_has_allreduce_and_no_host_callbacks():
     # The engine is module-shared, so earlier tests' sampling configs
     # may sit in the cache too — EVERY compiled step program must carry
     # the TP collectives.
-    assert engine.stats["step_compiles"] >= 1
-    for compiled in engine._step.values():
+    assert engine.stats["paged_step_compiles"] >= 1
+    for compiled in engine._paged_step.values():
         assert "all-reduce" in compiled.as_text(), \
             "no TP collective in a sharded step program"
 
@@ -497,7 +499,6 @@ def test_tp_step_program_has_allreduce_and_no_host_callbacks():
         if "sharded" in e.name and "decode_engine" in e.name
     }
     assert set(entries) == {
-        "models.decode_engine.sharded_step",
         "models.decode_engine.sharded_paged_step",
         "models.decode_engine.sharded_chunk_apply",
     }
@@ -508,28 +509,23 @@ def test_tp_step_program_has_allreduce_and_no_host_callbacks():
 
     # Jaxpr-level host-callback check on the exact step builder.
     from tf_yarn_tpu.models.decode_engine import (
-        build_prefill_fn,
-        build_step_fn,
+        _decode_cache_aval,
+        build_paged_step_fn,
+        paged_pool_avals,
     )
 
-    row = jax.eval_shape(
-        build_prefill_fn(model),
-        jax.tree_util.tree_map(
-            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype),
-            scheduler.params,
-        ),
-        jax.ShapeDtypeStruct((1, 1), jnp.int32),
-    )[0]
-    grid = jax.tree_util.tree_map(
-        lambda leaf: jax.ShapeDtypeStruct((2,) + leaf.shape, leaf.dtype),
-        row,
+    abstract = jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype),
+        scheduler.params,
     )
-    closed = jax.make_jaxpr(build_step_fn(model, 0.0, None, None))(
-        jax.tree_util.tree_map(
-            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype),
-            scheduler.params,
-        ),
-        grid,
+    pool = paged_pool_avals(
+        model, _decode_cache_aval(model, abstract), 17, 8)
+    closed = jax.make_jaxpr(
+        build_paged_step_fn(model, 8, 0.0, None, None)
+    )(
+        abstract, pool,
+        jax.ShapeDtypeStruct((2, 8), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32),
         jax.ShapeDtypeStruct((2,), jnp.int32),
         jax.ShapeDtypeStruct((2, 2), jnp.uint32),
         jax.ShapeDtypeStruct((2,), jnp.bool_),

@@ -26,19 +26,14 @@ import json
 import numpy as np
 import pytest
 
-from tests.test_serving import (
-    FakePagedEngine,
-    FakeEngine,
-    _drive,
-    _paged_scheduler,
-)
+from tests.fakes import FakePagedEngine
+from tests.test_serving import _drive, _paged_scheduler
 from tf_yarn_tpu import telemetry
 from tf_yarn_tpu.serving import (
     BlockPool,
     PrefixCache,
     SamplingParams,
     ServingServer,
-    SlotScheduler,
 )
 from tf_yarn_tpu.serving.server import decode_block_wire, encode_block_wire
 
@@ -157,18 +152,13 @@ def test_import_clips_hot_first_when_pool_is_small():
             == len(wire["entries"]))
 
 
-def test_import_refuses_block_size_mismatch_and_dense_layout():
+def test_import_refuses_block_size_mismatch():
     _, donor, _ = _served_donor()
     wire = donor.export_hot_prefixes()
     foreign = dict(wire, block_size=16)
     _, receiver = _paged_scheduler()
     with pytest.raises(ValueError, match="block_size"):
         receiver.import_prefixes(foreign)
-    dense = SlotScheduler(FakeEngine(), params=None, max_slots=1)
-    with pytest.raises(ValueError, match="paged"):
-        dense.export_hot_prefixes()
-    with pytest.raises(ValueError, match="paged"):
-        dense.import_prefixes(wire)
 
 
 def test_block_wire_codec_roundtrips_ndarrays_and_nones():
@@ -254,19 +244,6 @@ def test_http_blocks_pull_push_between_replicas():
         receiver_server.stop()
 
 
-def test_http_blocks_409_on_dense_replica():
-    dense = SlotScheduler(FakeEngine(), params=None, max_slots=1)
-    server = ServingServer(dense, "127.0.0.1", 0)
-    server.start()
-    try:
-        status, body = _get(server.port, "/v1/blocks")
-        assert status == 409 and b"paged" in body
-        status, body = _post_raw(server.port, "/v1/blocks", b"{}")
-        assert status == 409 and b"paged" in body
-    finally:
-        server.stop()
-
-
 # --------------------------------------------------------------------------
 # real stack (slow): numeric fidelity through extract/inject + base64
 # --------------------------------------------------------------------------
@@ -279,10 +256,10 @@ def test_real_stack_warm_started_replica_streams_bit_identical():
     from tests.test_serving import _legacy_stream, _tiny_serving_stack
 
     model, params, _engine, donor = _tiny_serving_stack(
-        max_slots=2, kv_layout="paged", block_size=4, num_blocks=32,
+        max_slots=2, block_size=4, num_blocks=32,
     )
     _model2, _params2, _engine2, receiver = _tiny_serving_stack(
-        max_slots=2, kv_layout="paged", block_size=4, num_blocks=32,
+        max_slots=2, block_size=4, num_blocks=32,
     )
     donor.start()
     receiver.start()

@@ -13,7 +13,7 @@ checking the optimized HLO text against a per-entry declared manifest:
 
 * TYA201 unexpected-collective — census of all-reduce / all-gather /
   reduce-scatter / collective-permute / all-to-all kinds, counts, and
-  payload bytes vs the manifest (`sharded_step` must show exactly its
+  payload bytes vs the manifest (`sharded_paged_step` must show exactly its
   wo/w_down/embed all-reduces and ZERO all-gathers above the small
   floor);
 * TYA202 broken-donation — declared `donate_argnums` must appear as
@@ -673,10 +673,6 @@ def default_entries() -> List[HloEntry]:
             Manifest(collectives={}, donate_argnums=(1, 7)),
         ),
         _entry(
-            "models.decode_engine.step",
-            Manifest(collectives={}, donate_argnums=(1, 3)),
-        ),
-        _entry(
             "models.decode_engine.paged_step",
             Manifest(collectives={}, donate_argnums=(1, 5)),
         ),
@@ -695,36 +691,23 @@ def default_entries() -> List[HloEntry]:
             Manifest(collectives={}, donate_argnums=(0,)),
         ),
         _entry(
-            "models.decode_engine.spec_step",
-            Manifest(collectives={}, donate_argnums=(1, 5)),
-        ),
-        _entry(
             "models.decode_engine.paged_spec_step",
             Manifest(collectives={}, donate_argnums=(1, 7)),
         ),
         # The chunk-apply (the windowed program at the chunked width):
         # admission replays prompt chunks through it interleaved with
-        # decode, so it carries the same zero-collective, grid+rngs
-        # donation contract as spec_step.
+        # decode, so it carries the same zero-collective, pool+rngs
+        # donation contract as paged_spec_step.
         _entry(
             "models.decode_engine.chunk_apply",
-            Manifest(collectives={}, donate_argnums=(1, 5)),
+            Manifest(collectives={}, donate_argnums=(1, 7)),
         ),
-        # THE headline manifests: the tp=2 serving ticks. GSPMD must
+        # THE headline manifests: the tp=2 serving tick. GSPMD must
         # insert exactly the matmul-partial all-reduces (embed + wo +
         # w_down, fused per scan body) and NO all-gather above the
         # small floor — an all-gather here means a weights- or
         # KV-sized re-materialization per tick. The 16-byte argmax
         # gathers over vocab-sharded logits land in the small census.
-        _entry(
-            "models.decode_engine.sharded_step",
-            Manifest(
-                collectives={"all-reduce": 3, "all-gather": 0},
-                donate_argnums=(1, 3),
-                max_replicated_bytes=replicated_budget,
-            ),
-            requires=("multi_device",),
-        ),
         _entry(
             "models.decode_engine.sharded_paged_step",
             Manifest(
@@ -741,7 +724,7 @@ def default_entries() -> List[HloEntry]:
             "models.decode_engine.sharded_chunk_apply",
             Manifest(
                 collectives={"all-reduce": 3, "all-gather": 0},
-                donate_argnums=(1, 5),
+                donate_argnums=(1, 7),
                 max_replicated_bytes=replicated_budget,
             ),
             requires=("multi_device",),
@@ -790,7 +773,6 @@ def _decode_churn_driver() -> Callable[[], Dict[str, List[tuple]]]:
             model, batch_buckets=(2,), prompt_buckets=(8,)
         )
         slots, block_size = 2, 8
-        grid = engine.make_slot_cache(params, slots)
         pool = engine.make_paged_pool(params, 5, block_size)
         rngs = jnp.stack(
             [jax.random.PRNGKey(i) for i in range(slots)]
@@ -798,18 +780,11 @@ def _decode_churn_driver() -> Callable[[], Dict[str, List[tuple]]]:
         mask = jnp.ones((slots,), jnp.bool_)
         max_blocks = config.max_seq_len // block_size
         width = 4  # the chunked/spec window width — a fixed compile key
-        spec_grid = engine.make_slot_cache(params, slots)
         eos_ids = jnp.full((slots,), -1, jnp.int32)
-        spec_rngs = jnp.stack(
-            [jax.random.PRNGKey(10 + i) for i in range(slots)]
-        )
         for tick in range(3):
             # Every per-tick input varies: tokens, rngs, block tables,
             # lengths. A cache keyed on any of them recompiles here.
             tokens = jnp.full((slots,), tick + 3, jnp.int32)
-            grid, _emitted, rngs = engine.step(
-                params, grid, tokens, rngs, mask
-            )
             tables = jnp.full(
                 (slots, max_blocks), (tick % 3) + 1, jnp.int32
             )
@@ -823,9 +798,9 @@ def _decode_churn_driver() -> Callable[[], Dict[str, List[tuple]]]:
             # replay) is traced data, never a compile key (TYA205).
             window = jnp.full((slots, width), tick + 5, jnp.int32)
             n_known = jnp.full((slots,), min(tick * 2, width), jnp.int32)
-            spec_grid, _emitted, _counts, spec_rngs = engine.spec_step(
-                params, spec_grid, window, n_known, eos_ids, spec_rngs,
-                mask,
+            pool, _emitted, _counts, rngs = engine.paged_spec_step(
+                params, pool, tables, lengths, window, n_known, eos_ids,
+                rngs, mask, block_size=block_size,
             )
         return engine.program_keys()
 
@@ -929,9 +904,9 @@ def default_churn_entries() -> List[ChurnEntry]:
             _decode_churn_driver,
             # One compiled program per kind across 3 ticks of varying
             # tokens/rngs/tables/lengths — those are traced, never keys.
-            # spec_step covers the chunk-apply: n_known sweeps the whole
-            # decode-to-replay range without minting a second program.
-            expected={"step": 1, "paged_step": 1, "spec_step": 1},
+            # paged_spec_step covers the chunk-apply: n_known sweeps the
+            # whole decode-to-replay range without minting a second program.
+            expected={"paged_step": 1, "paged_spec_step": 1},
         ),
         ChurnEntry(
             "models.decode_engine.swap_churn",

@@ -461,39 +461,6 @@ def _decode_entries() -> List[EntryPoint]:
         )
         return fn, args, {}
 
-    def step():
-        import jax
-        import jax.numpy as jnp
-
-        from tf_yarn_tpu.models.decode_engine import (
-            build_prefill_fn,
-            build_step_fn,
-        )
-
-        model, params, _prompt, _cache = _engine_avals()
-        # The slot grid: each slot is a batch-1 cache stacked along a new
-        # leading axis (DecodeEngine.make_slot_cache), so slots sit at
-        # independent cache_index positions.
-        row = jax.eval_shape(
-            build_prefill_fn(model), params,
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        )[0]
-        slots = 2
-        grid = jax.tree_util.tree_map(
-            lambda leaf: jax.ShapeDtypeStruct(
-                (slots,) + leaf.shape, leaf.dtype
-            ),
-            row,
-        )
-        fn = build_step_fn(model, temperature=0.0, top_k=None, top_p=None)
-        args = (
-            params, grid,
-            jax.ShapeDtypeStruct((slots,), jnp.int32),   # tokens
-            jax.ShapeDtypeStruct((slots, 2), jnp.uint32),  # per-slot rngs
-            jax.ShapeDtypeStruct((slots,), jnp.bool_),   # sample mask
-        )
-        return fn, args, {}
-
     def _paged_avals(block_size=8, slots=2, num_blocks=9):
         import jax
         import jax.numpy as jnp
@@ -529,50 +496,34 @@ def _decode_entries() -> List[EntryPoint]:
         )
         return fn, args, {}
 
-    def _dense_window(width: int):
-        """The dense windowed tick at a given width: width 3 is the
-        speculative shape (spec_k=2), width 8 the chunk-apply shape
-        (prefill_chunk=8, teacher-forced prompt replay). Same program
-        builder — width is a compile-key dimension, nothing else
-        changes."""
+    def _window_avals(slots, width):
+        """What the scheduler uploads for one windowed tick, after the
+        tables and lengths."""
         import jax
         import jax.numpy as jnp
 
-        from tf_yarn_tpu.models.decode_engine import (
-            build_prefill_fn,
-            build_spec_step_fn,
-        )
-
-        model, params, _prompt, _cache = _engine_avals()
-        row = jax.eval_shape(
-            build_prefill_fn(model), params,
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        )[0]
-        slots = 2
-        grid = jax.tree_util.tree_map(
-            lambda leaf: jax.ShapeDtypeStruct(
-                (slots,) + leaf.shape, leaf.dtype
-            ),
-            row,
-        )
-        fn = build_spec_step_fn(
-            model, width, temperature=0.0, top_k=None, top_p=None
-        )
-        args = (
-            params, grid,
+        return (
             jax.ShapeDtypeStruct((slots, width), jnp.int32),  # window
             jax.ShapeDtypeStruct((slots,), jnp.int32),        # n_known
             jax.ShapeDtypeStruct((slots,), jnp.int32),        # eos ids
             jax.ShapeDtypeStruct((slots, 2), jnp.uint32),     # rngs
             jax.ShapeDtypeStruct((slots,), jnp.bool_),        # active
         )
-        return fn, args, {}
-
-    def spec_step():
-        return _dense_window(width=3)
 
     def chunk_apply():
-        return _dense_window(width=8)
+        """The windowed tick at the chunk-apply width (prefill_chunk=8,
+        teacher-forced prompt replay) over the gathered cache view. Same
+        program builder as the speculative tick — width is a compile-key
+        dimension, nothing else changes."""
+        from tf_yarn_tpu.models.decode_engine import build_paged_spec_step_fn
+
+        model, params, pool, tables, lengths, slots = _paged_avals()
+        width = 8
+        fn = build_paged_spec_step_fn(
+            model, 8, width, temperature=0.0, top_k=None, top_p=None
+        )
+        args = (params, pool, tables, lengths) + _window_avals(slots, width)
+        return fn, args, {}
 
     def paged_spec_step():
         import jax
@@ -611,12 +562,7 @@ def _decode_entries() -> List[EntryPoint]:
             params, pool,
             jax.ShapeDtypeStruct((slots, max_blocks), jnp.int32),
             jax.ShapeDtypeStruct((slots,), jnp.int32),        # lengths
-            jax.ShapeDtypeStruct((slots, width), jnp.int32),  # window
-            jax.ShapeDtypeStruct((slots,), jnp.int32),        # n_known
-            jax.ShapeDtypeStruct((slots,), jnp.int32),        # eos ids
-            jax.ShapeDtypeStruct((slots, 2), jnp.uint32),     # rngs
-            jax.ShapeDtypeStruct((slots,), jnp.bool_),        # active
-        )
+        ) + _window_avals(slots, width)
         return fn, args, {}
 
     def paged_prefill():
@@ -685,14 +631,17 @@ def _decode_entries() -> List[EntryPoint]:
         fn = build_inject_blocks_fn(model, row)
         return fn, (pool, ids, payload), {}
 
-    def _tp_sharded(paged: bool):
-        """The TENSOR-PARALLEL serving tick, lowered exactly as the
-        engine lowers it: params placed by the logical-axis rules, the
-        slot grid / block pool sharded by kv-heads over `tp`, explicit
-        in/out shardings on the jit. The TP collectives themselves are
-        inserted by the XLA partitioner at compile (they are not jaxpr
-        primitives), so this entry verifies what the trace CAN see —
-        any named-axis collective stays inside the declared tp axis
+    def _tp_sharded(build, uploads, small_outs, donate):
+        """A TENSOR-PARALLEL serving tick, lowered exactly as the engine
+        lowers it: params placed by the logical-axis rules, the block
+        pool sharded by kv-heads over `tp`, everything the scheduler
+        uploads each tick replicated, pool + rngs donated. `build(model,
+        block_size)` makes the step, `uploads(slots)` its avals after
+        the tables and lengths; the pool comes back first, then
+        `small_outs` replicated results. The TP collectives themselves
+        are inserted by the XLA partitioner at compile (they are not
+        jaxpr primitives), so the entries verify what the trace CAN see
+        — any named-axis collective stays inside the declared tp axis
         env, and the program is host-callback-free; the compiled-HLO
         all-reduce presence is pinned by tests/test_tp_serving.py."""
         import jax
@@ -701,11 +650,7 @@ def _decode_entries() -> List[EntryPoint]:
 
         from tf_yarn_tpu.models.decode_engine import (
             _decode_cache_aval,
-            build_paged_step_fn,
-            build_prefill_fn,
-            build_step_fn,
             cache_layout,
-            kv_partition_spec,
             paged_pool_avals,
             pool_partition_spec,
         )
@@ -728,168 +673,81 @@ def _decode_entries() -> List[EntryPoint]:
         )
         param_sh = sharding_lib.tree_shardings(mesh, abstract)
         params = sharding_lib.unbox_params(abstract)
-        max_seq = config.max_seq_len
-        slots = 2
-        if paged:
-            block_size = 8
-            row = _decode_cache_aval(model, params)
-            pool = paged_pool_avals(model, row, 9, block_size)
-            pool_sh = jax.tree_util.tree_map(
-                lambda aval, r, lay: (
-                    None if aval is None else NamedSharding(
-                        mesh, pool_partition_spec(tuple(r.shape), lay, tp),
-                    )
-                ),
-                pool, row, cache_layout(model, row),
-                is_leaf=lambda x: x is None,
-            )
-            max_blocks = max_seq // block_size
-            fn = jax.jit(
-                build_paged_step_fn(
-                    model, block_size=block_size, temperature=0.0,
-                    top_k=None, top_p=None,
-                ),
-                in_shardings=(param_sh, pool_sh, rep, rep, rep, rep, rep),
-                out_shardings=(pool_sh, rep, rep),
-                # The engine donates pool + rngs (DecodeEngine.paged_step)
-                # — mirrored here so the HLO engine's TYA202 verifies the
-                # aliasing on the same lowering serving actually runs.
-                donate_argnums=(1, 5),
-            )
-            args = (
-                params, pool,
-                jax.ShapeDtypeStruct((slots, max_blocks), jnp.int32),
-                jax.ShapeDtypeStruct((slots,), jnp.int32),
-                jax.ShapeDtypeStruct((slots,), jnp.int32),
-                jax.ShapeDtypeStruct((slots, 2), jnp.uint32),
-                jax.ShapeDtypeStruct((slots,), jnp.bool_),
-            )
-            return fn, args, {}
-        row = jax.eval_shape(
-            build_prefill_fn(model), params,
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        )[0]
-        grid = jax.tree_util.tree_map(
-            lambda leaf: jax.ShapeDtypeStruct(
-                (slots,) + leaf.shape, leaf.dtype
+        slots, block_size = 2, 8
+        row = _decode_cache_aval(model, params)
+        pool = paged_pool_avals(model, row, 9, block_size)
+        pool_sh = jax.tree_util.tree_map(
+            lambda aval, r, lay: (
+                None if aval is None else NamedSharding(
+                    mesh, pool_partition_spec(tuple(r.shape), lay, tp),
+                )
             ),
-            row,
+            pool, row, cache_layout(model, row),
+            is_leaf=lambda x: x is None,
         )
-        grid_sh = jax.tree_util.tree_map(
-            lambda aval, lay: NamedSharding(
-                mesh, kv_partition_spec(tuple(aval.shape), lay, tp)
-            ),
-            grid, cache_layout(model, grid),
-        )
-        fn = jax.jit(
-            build_step_fn(model, temperature=0.0, top_k=None, top_p=None),
-            in_shardings=(param_sh, grid_sh, rep, rep, rep),
-            out_shardings=(grid_sh, rep, rep),
-            # Grid + rngs donated exactly as DecodeEngine.step lowers it.
-            donate_argnums=(1, 3),
-        )
+        max_blocks = config.max_seq_len // block_size
         args = (
-            params, grid,
-            jax.ShapeDtypeStruct((slots,), jnp.int32),
-            jax.ShapeDtypeStruct((slots, 2), jnp.uint32),
-            jax.ShapeDtypeStruct((slots,), jnp.bool_),
+            params, pool,
+            jax.ShapeDtypeStruct((slots, max_blocks), jnp.int32),  # tables
+            jax.ShapeDtypeStruct((slots,), jnp.int32),             # lengths
+        ) + uploads(slots)
+        fn = jax.jit(
+            build(model, block_size),
+            in_shardings=(param_sh, pool_sh) + (rep,) * (len(args) - 2),
+            out_shardings=(pool_sh,) + (rep,) * small_outs,
+            # Mirrored from the engine so the HLO engine's TYA202
+            # verifies the aliasing on the lowering serving actually runs.
+            donate_argnums=donate,
         )
         return fn, args, {}
-
-    def sharded_step():
-        return _tp_sharded(paged=False)
 
     def sharded_paged_step():
-        return _tp_sharded(paged=True)
-
-    def sharded_chunk_apply():
-        """The TP chunk-apply: the dense windowed program at the
-        chunked width (8), sharded exactly as DecodeEngine._spec_step
-        lowers it under a mesh — params by LOGICAL_RULES, slot grid by
-        kv-heads, window/n_known/eos/rngs/active replicated, grid +
-        rngs donated. Chunked prefill admits prompts through THIS
-        program tick by tick, so it gets the same host-callback and
-        axis-vocabulary pins as the sharded decode ticks."""
         import jax
         import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec
 
-        from tf_yarn_tpu.models.decode_engine import (
-            build_prefill_fn,
-            build_spec_step_fn,
-            cache_layout,
-            kv_partition_spec,
-        )
-        from tf_yarn_tpu.models.transformer import (
-            Transformer,
-            TransformerConfig,
-        )
-        from tf_yarn_tpu.parallel import sharding as sharding_lib
-        from tf_yarn_tpu.parallel.mesh import MeshSpec, build_mesh
+        from tf_yarn_tpu.models.decode_engine import build_paged_step_fn
 
-        tp = 2
-        config = TransformerConfig.tiny()
-        model = Transformer(config)
-        mesh = build_mesh(MeshSpec(tp=tp), jax.devices()[:tp])
-        rep = NamedSharding(mesh, PartitionSpec())
-        abstract = jax.eval_shape(
-            lambda r, t: model.init(r, t),
-            jax.ShapeDtypeStruct((2,), jnp.uint32),
-            jax.ShapeDtypeStruct((1, 8), jnp.int32),
-        )
-        param_sh = sharding_lib.tree_shardings(mesh, abstract)
-        params = sharding_lib.unbox_params(abstract)
-        slots, width = 2, 8
-        row = jax.eval_shape(
-            build_prefill_fn(model), params,
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        )[0]
-        grid = jax.tree_util.tree_map(
-            lambda leaf: jax.ShapeDtypeStruct(
-                (slots,) + leaf.shape, leaf.dtype
+        return _tp_sharded(
+            lambda model, block_size: build_paged_step_fn(
+                model, block_size=block_size, temperature=0.0,
+                top_k=None, top_p=None,
             ),
-            row,
-        )
-        grid_sh = jax.tree_util.tree_map(
-            lambda aval, lay: NamedSharding(
-                mesh, kv_partition_spec(tuple(aval.shape), lay, tp)
+            lambda slots: (
+                jax.ShapeDtypeStruct((slots,), jnp.int32),     # tokens
+                jax.ShapeDtypeStruct((slots, 2), jnp.uint32),  # rngs
+                jax.ShapeDtypeStruct((slots,), jnp.bool_),     # sample mask
             ),
-            grid, cache_layout(model, grid),
+            small_outs=2, donate=(1, 5),  # DecodeEngine.paged_step
         )
-        fn = jax.jit(
-            build_spec_step_fn(
-                model, width, temperature=0.0, top_k=None, top_p=None
+
+    def sharded_chunk_apply():
+        """The TP chunk-apply: the windowed program at the chunked width
+        (8). Chunked prefill admits prompts through THIS program tick by
+        tick, so it gets the same host-callback and axis-vocabulary pins
+        as the sharded decode tick."""
+        from tf_yarn_tpu.models.decode_engine import build_paged_spec_step_fn
+
+        width = 8
+        return _tp_sharded(
+            lambda model, block_size: build_paged_spec_step_fn(
+                model, block_size, width, temperature=0.0, top_k=None,
+                top_p=None,
             ),
-            in_shardings=(param_sh, grid_sh, rep, rep, rep, rep, rep),
-            out_shardings=(grid_sh, rep, rep, rep),
-            # Grid + rngs donated exactly as DecodeEngine._spec_step
-            # lowers it (donate=(1, 5)).
-            donate_argnums=(1, 5),
+            lambda slots: _window_avals(slots, width),
+            small_outs=3, donate=(1, 7),  # DecodeEngine.paged_spec_step
         )
-        args = (
-            params, grid,
-            jax.ShapeDtypeStruct((slots, width), jnp.int32),  # window
-            jax.ShapeDtypeStruct((slots,), jnp.int32),        # n_known
-            jax.ShapeDtypeStruct((slots,), jnp.int32),        # eos ids
-            jax.ShapeDtypeStruct((slots, 2), jnp.uint32),     # rngs
-            jax.ShapeDtypeStruct((slots,), jnp.bool_),        # active
-        )
-        return fn, args, {}
 
     from tf_yarn_tpu.parallel.mesh import AXIS_TP
 
     return [
         EntryPoint("models.decode_engine.prefill", prefill),
         EntryPoint("models.decode_engine.decode_loop", decode_loop),
-        # The serving tick's device program (continuous batching): runs
-        # once per generated token across the whole slot grid, so a host
-        # callback smuggled in here is a per-token round-trip for every
-        # in-flight request at once.
-        EntryPoint("models.decode_engine.step", step),
-        # The PAGED serving tick: gather-by-block-table, model step, and
-        # scatter-append all in one program — the acceptance bar is the
-        # same (one compiled program per tick, zero host syncs), now
-        # with table indirection that must also stay on device.
+        # The serving tick's device program (continuous batching):
+        # gather-by-block-table, model step, and scatter-append all in
+        # one program. It runs once per generated token across the whole
+        # slot grid, so a host callback smuggled in here is a per-token
+        # round-trip for every in-flight request at once, and the table
+        # indirection must stay on device too.
         EntryPoint("models.decode_engine.paged_step", paged_step),
         # Paged admission's device work: bucketed prefill + block splice.
         EntryPoint("models.decode_engine.paged_prefill", paged_prefill),
@@ -903,40 +761,33 @@ def _decode_entries() -> List[EntryPoint]:
         # a per-leaf sync instead of one bulk copy.
         EntryPoint("models.decode_engine.extract_blocks", extract_blocks),
         EntryPoint("models.decode_engine.inject_blocks", inject_blocks),
-        # The SPECULATIVE ticks: one windowed verify forward advances
+        # The SPECULATIVE tick: one windowed verify forward advances
         # every slot up to spec_k + 1 tokens. The accept/reject masking
         # must be entirely traced — a host callback here would sync the
-        # grid once per window position, not once per tick.
-        EntryPoint("models.decode_engine.spec_step", spec_step),
-        # The fused paged verify: decode attention streams the int8
-        # block pool through the pallas kernel (scalar-prefetched block
-        # tables), scatters the window's quantized K/V rows, and must
-        # stay host-callback-free like every other tick program.
+        # grid once per window position, not once per tick. This is the
+        # fused verify: decode attention streams the int8 block pool
+        # through the pallas kernel (scalar-prefetched block tables) and
+        # scatters the window's quantized K/V rows.
         EntryPoint("models.decode_engine.paged_spec_step", paged_spec_step),
-        # The CHUNK-APPLY: the same windowed program at the chunked
-        # width (8) — admission replays prompt chunks through it
-        # teacher-forced (n_known == W, zero emissions), interleaved
+        # The CHUNK-APPLY: the windowed program over the gathered cache
+        # view at the chunked width (8) — admission replays prompt chunks
+        # through it teacher-forced (n_known == W, zero emissions), interleaved
         # with decode slots in the one tick program. A host callback
         # here would stall every decode slot once per admitted chunk.
         EntryPoint("models.decode_engine.chunk_apply", chunk_apply),
-        # The TENSOR-PARALLEL serving ticks (tp=2): params placed by
-        # LOGICAL_RULES, slot KV sharded by heads, explicit in/out
+        # The TENSOR-PARALLEL serving tick (tp=2): params placed by
+        # LOGICAL_RULES, the block pool sharded by heads, explicit in/out
         # shardings — traced under the declared tp axis env so any
         # named-axis collective that appears is vocabulary-checked, and
         # host-callback-freedom is asserted like every tick program.
         # Needs >= 2 devices (skipped with a notice on 1-device rigs).
         EntryPoint(
-            "models.decode_engine.sharded_step", sharded_step,
-            axis_env=((AXIS_TP, 2),), expected_axes=(AXIS_TP,),
-            requires=("multi_device",),
-        ),
-        EntryPoint(
             "models.decode_engine.sharded_paged_step", sharded_paged_step,
             axis_env=((AXIS_TP, 2),), expected_axes=(AXIS_TP,),
             requires=("multi_device",),
         ),
-        # The sharded chunk-apply twin, pinned like sharded_step so the
-        # chunked-admission program keeps the same collective census
+        # The sharded chunk-apply twin, pinned like sharded_paged_step so
+        # the chunked-admission program keeps the same collective census
         # and donation aliasing under tp=2 as the decode tick it
         # interleaves with.
         EntryPoint(

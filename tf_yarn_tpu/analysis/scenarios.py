@@ -150,7 +150,7 @@ def make_paged_scheduler():
 
     return SlotScheduler(
         _FakePagedEngine(), params=None, max_slots=2,
-        kv_layout="paged", block_size=4, max_seq_len=32,
+        block_size=4, max_seq_len=32,
     )
 
 
@@ -229,7 +229,7 @@ def _suspend_resume(tracer: RaceTracer) -> None:
 
     scheduler = SlotScheduler(
         _FakePagedEngine(), params=None, max_slots=2,
-        kv_layout="paged", block_size=4, max_seq_len=32,
+        block_size=4, max_seq_len=32,
         num_blocks=5, kv_host_blocks=16,
         tier_caps={"batch": 2, "interactive": 2},
     )
@@ -290,7 +290,7 @@ def _prefill_ship(tracer: RaceTracer) -> None:
     worker = PrefillWorker(_FakePagedEngine(), params=None, block_size=4)
     scheduler = SlotScheduler(
         _FakePagedEngine(), params=None, max_slots=2,
-        kv_layout="paged", block_size=4, max_seq_len=32,
+        block_size=4, max_seq_len=32,
     )
 
     def post(endpoint, prompt, timeout_s):

@@ -293,18 +293,17 @@ class ServingExperiment:
     stay free). ``serve_seconds=None`` serves until the task is killed
     or a preemption notice arrives (the normal production posture).
 
-    ``kv_layout`` picks the slot KV storage (docs/Serving.md): "paged"
-    (the default — a global pool of ``block_size``-token KV blocks with
-    per-slot block tables and a shared prompt-prefix cache; fp outputs
-    stay bit-identical to the dense path) or "dense" (one full
-    ``max_seq_len`` cache per slot). ``num_blocks=None`` sizes the pool
-    at dense-equivalent capacity; shrink it to realize the HBM saving
+    The slots' KV lives in a global pool of ``block_size``-token blocks
+    with per-slot block tables and a shared prompt-prefix cache
+    (docs/Serving.md; fp outputs stay bit-identical to
+    ``generate_legacy``). ``num_blocks=None`` sizes the pool for every
+    slot at full context; shrink it to realize the HBM saving
     (``prefix_cache_capacity=0`` disables prefix sharing).
 
     ``mesh_spec`` turns on TENSOR-PARALLEL decode (docs/Serving.md
     "Tensor-parallel decode"): ``MeshSpec(tp=N)`` places the replica's
     weights by the transformer's logical-axis rules and shards the slot
-    KV (dense grid or paged block pool) by kv-heads over the ``tp``
+    KV (the paged block pool) by kv-heads over the ``tp``
     mesh axis, so a model bigger than one chip's HBM serves online —
     still ONE compiled program and one host sync per tick. Serving
     shards tensor-parallel only: every other mesh axis must stay 1 (use
@@ -326,7 +325,6 @@ class ServingExperiment:
     top_p: Optional[float] = None
     step: Optional[int] = None  # checkpoint step; None = latest
     serve_seconds: Optional[float] = None
-    kv_layout: str = "paged"
     block_size: int = 16
     num_blocks: Optional[int] = None
     prefix_cache_capacity: int = 256
@@ -338,7 +336,7 @@ class ServingExperiment:
     # to the exact path, each request just lands up to spec_k + 1
     # tokens per tick. ``decode_attention="fused"`` runs the paged
     # verify forward's attention on the paged-int8 pallas kernel
-    # (requires kv_layout="paged" and an int8 KV cache).
+    # (requires an int8 KV cache).
     spec_k: int = 0
     spec_draft: Any = "ngram"
     decode_attention: str = "gather"
@@ -403,7 +401,7 @@ class ServingExperiment:
     autoscale_warm_start: bool = True
     # Disaggregated prefill (docs/Serving.md "Disaggregated prefill"):
     # PrefillTierConfig field dict, e.g. ``{"offload_threshold": 256}``.
-    # When set (and kv_layout == "paged"), /v1/generate pulls long
+    # When set, /v1/generate pulls long
     # prompts' KV blocks from the ``prefill`` task tier before
     # submitting; None (default) = always prefill locally. Also the
     # experiment read by the ``prefill`` task itself (tasks/prefill.py).
@@ -419,11 +417,6 @@ class ServingExperiment:
         if self.serve_seconds is not None and self.serve_seconds <= 0:
             raise ValueError(
                 f"serve_seconds must be > 0 or None, got {self.serve_seconds}"
-            )
-        if self.kv_layout not in ("dense", "paged"):
-            raise ValueError(
-                f"kv_layout must be 'dense' or 'paged', got "
-                f"{self.kv_layout!r}"
             )
         if self.block_size < 1:
             raise ValueError(
@@ -451,10 +444,6 @@ class ServingExperiment:
                 f"decode_attention must be 'gather' or 'fused', got "
                 f"{self.decode_attention!r}"
             )
-        if self.decode_attention == "fused" and self.kv_layout != "paged":
-            raise ValueError(
-                "decode_attention='fused' requires kv_layout='paged'"
-            )
         chunked = self.prefill_chunk not in (0, None)
         if chunked and self.prefill_chunk != "auto" and (
             not isinstance(self.prefill_chunk, int)
@@ -480,11 +469,6 @@ class ServingExperiment:
         if self.kv_host_blocks < 0:
             raise ValueError(
                 f"kv_host_blocks must be >= 0, got {self.kv_host_blocks}"
-            )
-        if self.kv_host_blocks and self.kv_layout != "paged":
-            raise ValueError(
-                "kv_host_blocks (the host swap tier) requires "
-                "kv_layout='paged'"
             )
         if self.tier_caps is not None:
             from tf_yarn_tpu.serving.request import tier_rank

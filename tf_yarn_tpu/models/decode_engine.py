@@ -38,7 +38,7 @@ top_k / top_p are baked into the traced program) key the cache.
   (docs/Serving.md "Tensor-parallel decode"), the engine serves a model
   bigger than one chip's HBM: params place by the transformer's
   logical-axis rules (attention heads / MLP hidden / vocab over the
-  ``tp`` mesh axis), every slot KV cache and the paged block pool shard
+  ``tp`` mesh axis), the prefill's row cache and the paged block pool shard
   their kv-heads axis over ``tp`` (`kv_partition_spec` /
   `pool_partition_spec` — each device holds 1/tp of every slot and
   every block), and all the compiled programs lower with explicit
@@ -50,18 +50,16 @@ top_k / top_p are baked into the traced program) key the cache.
   the partitioned matmuls reduce in a different grouping; the emitted
   ints are the tested contract, as with speculative decoding below).
 
-* **Paged KV slots.** The serving grid's dense per-slot caches (each a
-  full `max_seq_len` allocation, mostly padding for short requests) have
-  a paged alternative: ONE global pool of fixed-size KV blocks
-  (`make_paged_pool`) plus a per-slot block table. The compiled
-  `paged_step` gathers each slot's dense cache view from the pool by its
-  block table, runs the exact same per-slot model step, and
-  scatter-appends the new K/V row into the slot's current block — all
-  inside one program, zero host syncs per tick. Because the gathered
-  view holds the identical values the dense slot cache would (positions
-  beyond a slot's length are masked to exactly-zero weight by the
-  attention mask), the fp paged path is BIT-IDENTICAL to the dense path
-  and to `generate_legacy`. Free/allocate is host-side free-list
+* **Paged KV slots.** The serving grid keeps its KV in ONE global pool
+  of fixed-size blocks (`make_paged_pool`) plus a per-slot block table.
+  The compiled `paged_step` gathers each slot's contiguous cache view
+  from the pool by its block table, runs the model's batch-1 decode step
+  on it, and scatter-appends the new K/V row into the slot's current
+  block — all inside one program, zero host syncs per tick. Because the
+  gathered view holds the values a batch-1 decode cache of that request
+  would (positions beyond a slot's length are masked to exactly-zero
+  weight by the attention mask), the fp path is BIT-IDENTICAL to
+  `generate_legacy`. Free/allocate is host-side free-list
   bookkeeping (`serving/paging.py`); there is no per-eviction device
   program at all. `pack_prefill` splices a bucketed-prefill result into
   a slot's blocks; int8 KV composes transparently (the pool stores
@@ -232,146 +230,8 @@ def build_decode_fn(model, temperature: float, top_k: Optional[int],
     return decode
 
 
-def build_step_fn(model, temperature: float, top_k: Optional[int],
-                  top_p: Optional[float]):
-    """The continuous-batching slot step, shared by the engine and the
-    analysis jaxpr entry point (`models.decode_engine.step`).
-
-        fn(params, slot_cache, tokens, rngs, sample_mask)
-            -> (slot_cache, emitted [S], rngs)
-
-    ONE compiled program advances EVERY slot of a serving grid by one
-    token. `slot_cache` is the per-slot KV grid (leading slot axis; each
-    element a batch-1 decode cache with its own `cache_index`, so slots
-    sit at independent positions — the per-slot offsets the shared batch
-    cache of `decode_loop` cannot express). `tokens` [S] are this tick's
-    inputs: a forced prompt token while a slot replays its prompt
-    remainder, else the slot's last emitted token. `sample_mask` [S] is
-    the traced active mask: masked-off slots (free, or mid-replay) run
-    the same device program — the KV append is the point for replay
-    slots, garbage for free ones — but consume no RNG and pass their
-    input token through, so each slot's split chain stays bit-aligned
-    with generate_legacy's one-split-per-sample. The step that consumes
-    a request's LAST prompt token has sample_mask on: its output is the
-    first generated token, sampled with the first split — exactly
-    generate_legacy's prefill sample.
-    """
-
-    def step(params, slot_cache, tokens, rngs, sample_mask):
-        def one_slot(cache, token, rng, do_sample):
-            logits, state = model.apply(
-                {**params, "cache": cache}, token[None, None], decode=True,
-                mutable=["cache"],
-            )
-            next_rng, sample_key = jax.random.split(rng)
-            sampled = _sample(
-                logits[:, -1], sample_key, temperature, top_k, top_p
-            )[0]
-            emitted = jnp.where(do_sample, sampled, token)
-            rng = jnp.where(do_sample, next_rng, rng)
-            return state["cache"], emitted, rng
-
-        return jax.vmap(one_slot)(slot_cache, tokens, rngs, sample_mask)
-
-    return step
-
-
 # --------------------------------------------------------------------------
-# Speculative decoding: the windowed verify steps
-# --------------------------------------------------------------------------
-#
-# One spec tick advances a slot by a VARIABLE number of tokens: the
-# target model scores all `width` window positions (replay prefix +
-# last token + drafts) in one batched forward, `verify_window`
-# (models/spec.py) keeps exactly the prefix the sequential path would
-# have emitted, and only the accepted positions become valid KV. The
-# forward writes all `width` K/V rows — rejected-draft rows land beyond
-# the slot's valid length, where every decode-attention path masks them
-# to zero weight and the next tick's window overwrites them — so
-# acceptance never needs a device-side KV rollback. Emitted token
-# streams are identical to generate_legacy (token-matching acceptance);
-# note the windowed forward compiles to a different fusion than the
-# one-token step, so float *logits* agree to roundoff, not bitwise —
-# the emitted ints are the contract, and the tests pin them.
-
-
-def _index_leaf_value(cache, layout):
-    """The slot's pre-apply position, read from any index leaf (all index
-    leaves carry the same scalar)."""
-    for leaf, lay in zip(jax.tree_util.tree_leaves(cache),
-                         jax.tree_util.tree_leaves(layout)):
-        if lay.kind == INDEX:
-            return leaf.reshape(-1)[0].astype(jnp.int32)
-    raise ValueError("cache has no index leaf — unknown cache layout")
-
-
-def _with_index(cache, new_index, layout):
-    """Rewrite every index leaf to `new_index` (the accepted length),
-    leaving the other leaves untouched."""
-
-    def leaf(value, lay):
-        if lay.kind == INDEX:
-            return jnp.full(value.shape, new_index, value.dtype)
-        return value
-
-    return jax.tree_util.tree_map(leaf, cache, layout)
-
-
-def build_spec_step_fn(model, width: int, temperature: float,
-                       top_k: Optional[int], top_p: Optional[float]):
-    """The dense speculative slot step, shared by the engine and the
-    analysis jaxpr entry point (`models.decode_engine.spec_step`).
-
-        fn(params, slot_cache, tokens [S, W], n_known [S], eos_ids [S],
-           rngs [S, 2], active [S])
-            -> (slot_cache, emitted [S, W], counts [S], rngs)
-
-    ONE compiled program advances every slot up to W tokens: per slot,
-    the target model scores the whole window in one forward (K/V for
-    all W positions appended at the slot's cache_index), verify_window
-    computes the emitted prefix, and the slot's cache_index is rewritten
-    to `old_index + n_known + n_emitted` — the accepted length — so
-    rejected rows are dead weight the next window overwrites. Inactive
-    slots (active=False) emit nothing, consume no RNG, and keep their
-    cache_index; their garbage window rows land in their own (free)
-    cache and are overwritten at the next admission. tokens / n_known /
-    eos_ids are traced, so tick-to-tick changes never recompile.
-
-    This program is ALSO the chunk-apply for chunked prefill
-    (docs/Serving.md "Chunked prefill"): a window whose tokens are all
-    pending prompt tokens (n_known == W) is a teacher-forced chunk —
-    the forward appends W prompt positions of KV and emits nothing.
-    The scheduler widens W to max(spec_k + 1, prefill_chunk); it is a
-    compile-key dimension, fixed per grid, so chunking adds zero
-    recompiles.
-    """
-    def spec_step(params, slot_cache, tokens, n_known, eos_ids, rngs,
-                  active):
-        def one_slot(cache, toks, known, eos_id, rng, act):
-            layout = cache_layout(model, cache)
-            _refuse_slot_state(layout, "the speculative / chunked window")
-            idx = _index_leaf_value(cache, layout)
-            logits, state = model.apply(
-                {**params, "cache": cache}, toks[None, :], decode=True,
-                mutable=["cache"],
-            )
-            emitted, count, rng = verify_window(
-                logits[0], toks, known, eos_id, rng, act,
-                temperature, top_k, top_p,
-            )
-            n_valid = jnp.where(act, known + count, 0)
-            cache = _with_index(state["cache"], idx + n_valid, layout)
-            return cache, emitted, count, rng
-
-        return jax.vmap(one_slot)(
-            slot_cache, tokens, n_known, eos_ids, rngs, active
-        )
-
-    return spec_step
-
-
-# --------------------------------------------------------------------------
-# Paged KV layout: pool avals + the compiled gather/scatter programs
+# Paged KV pool: its avals + the compiled gather/scatter programs
 # --------------------------------------------------------------------------
 
 PAGED, SLOT, INDEX = "paged", "slot", "index"
@@ -491,13 +351,13 @@ def _is_named_sharding(sharding) -> bool:
 
 
 def _gather_slot_cache(pool, row_aval, layout, table, length):
-    """One slot's dense cache view: KV leaves gathered from the pool by
-    the block table (and reshaped back to the dense seq axis), index
-    leaves filled with the slot's length. Values beyond `length` are
-    stale pool garbage — every decode-attention path masks positions >=
-    cache_index to exactly-zero weight, so the view is value-identical
-    to a dense slot cache where it matters (bit-identity relies on
-    this)."""
+    """One slot's batch-1 cache view: KV leaves gathered from the pool by
+    the block table (and reshaped back to one `max_seq_len` seq axis),
+    index leaves filled with the slot's length. Values beyond `length`
+    are stale pool garbage — every decode-attention path masks positions
+    >= cache_index to exactly-zero weight, so the view is value-identical
+    to the request's own decode cache where it matters (bit-identity
+    with `generate_legacy` relies on this)."""
 
     def leaf(pool_leaf, aval, lay):
         if pool_leaf is None:
@@ -519,12 +379,20 @@ def build_paged_step_fn(model, block_size: int, temperature: float,
             -> (pool, emitted [S], rngs)
 
     ONE compiled program advances every slot one token against the
-    global block pool: per slot, gather its dense cache view through its
-    block-table row, run the identical per-slot model step
-    `build_step_fn` runs (same sampling, same RNG discipline — masked
-    slots consume no RNG and pass their token through), then
-    scatter-append the freshly written K/V row into block
+    global block pool: per slot, gather its batch-1 cache view through
+    its block-table row, run the model's decode step on it and sample,
+    then scatter-append the freshly written K/V row into block
     `table[length // block_size]` at offset `length % block_size`.
+    `tokens` [S] are this tick's inputs: a forced prompt token while a
+    slot replays its prompt remainder, else the slot's last emitted
+    token. `sample_mask` [S] is the traced active mask: masked-off slots
+    (free, or mid-replay) run the same device program — the KV append is
+    the point for replay slots, garbage for free ones — but consume no
+    RNG and pass their input token through, so each slot's split chain
+    stays bit-aligned with generate_legacy's one-split-per-sample. The
+    step that consumes a request's LAST prompt token has sample_mask on:
+    its output is the first generated token, sampled with the first
+    split — exactly generate_legacy's prefill sample.
     `tables`/`lengths` are traced values — tick-to-tick table changes
     never recompile. Inactive slots carry an all-zero table row and
     length 0, so their (meaningless) write lands in the reserved trash
@@ -577,7 +445,7 @@ def _refuse_slot_state(layout, feature: str):
 
 def _new_rows(cache, layout, length, width: int):
     """The `width` rows a call just wrote at `length` into each paged leaf
-    of one slot's dense cache view; None for the other leaves."""
+    of one slot's cache view; None for the other leaves."""
 
     def leaf(value, lay):
         if lay.kind != PAGED:
@@ -698,6 +566,25 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
     return step
 
 
+# --------------------------------------------------------------------------
+# Speculative decoding: the windowed verify step
+# --------------------------------------------------------------------------
+#
+# One spec tick advances a slot by a VARIABLE number of tokens: the
+# target model scores all `width` window positions (replay prefix +
+# last token + drafts) in one batched forward, `verify_window`
+# (models/spec.py) keeps exactly the prefix the sequential path would
+# have emitted, and only the accepted positions become valid KV. The
+# forward writes all `width` K/V rows — rejected-draft rows land beyond
+# the slot's valid length, where every decode-attention path masks them
+# to zero weight and the next tick's window overwrites them — so
+# acceptance never needs a device-side KV rollback. Emitted token
+# streams are identical to generate_legacy (token-matching acceptance);
+# note the windowed forward compiles to a different fusion than the
+# one-token step, so float *logits* agree to roundoff, not bitwise —
+# the emitted ints are the contract, and the tests pin them.
+
+
 DECODE_ATTENTION_MODES = ("gather", "fused")
 
 
@@ -742,28 +629,38 @@ def build_paged_spec_step_fn(model, block_size: int, width: int,
            eos_ids [S], rngs [S, 2], active [S])
             -> (pool, emitted [S, W], counts [S], rngs)
 
-    Same verify semantics as `build_spec_step_fn` over the block pool;
-    the slot's valid length is the HOST's `lengths` bookkeeping (it
-    advances by n_known + n_emitted after the tick), so the program
+    ONE compiled program advances every slot up to W tokens: per slot,
+    the target model scores the whole window in one forward over the
+    slot's gathered cache view, and verify_window computes the emitted
+    prefix. The slot's valid length is the HOST's `lengths` bookkeeping
+    (it advances by n_known + n_emitted after the tick), so the program
     itself needs no index fixup. All `width` freshly written K/V rows
     scatter back at logical positions length..length+W-1 — rows beyond
     a slot's reserved blocks hit table entries 0 and land in the trash
-    block, so rejected drafts can never touch another slot's KV. Like
-    the dense twin, this doubles as the chunk-apply for chunked prefill:
-    an all-known window (n_known == W) writes W prompt rows through the
-    block table and emits nothing.
+    block, so rejected drafts can never touch another slot's KV.
+    Inactive slots (active=False) emit nothing and consume no RNG.
+    tokens / n_known / eos_ids are traced, so tick-to-tick changes
+    never recompile.
+
+    This program is ALSO the chunk-apply for chunked prefill
+    (docs/Serving.md "Chunked prefill"): a window whose tokens are all
+    pending prompt tokens (n_known == W) is a teacher-forced chunk —
+    the forward writes W prompt rows through the block table and emits
+    nothing. The scheduler widens W to max(spec_k + 1, prefill_chunk);
+    it is a compile-key dimension, fixed per grid, so chunking adds zero
+    recompiles.
 
     `decode_attention` picks the attention implementation inside the
     verify forward:
 
-    * ``"gather"`` — materialize each slot's dense cache view from the
+    * ``"gather"`` — materialize each slot's batch-1 cache view from the
       pool (exactly `paged_step`'s path) and run the model's standard
       decode attention over it. Reference semantics.
     * ``"fused"`` — int8 pools only: the model's decode attention reads
       the block pool DIRECTLY through `paged_int8_window_attention`
       (ops/decode_attention.py — block tables ride in SMEM via scalar
       prefetch), the window's K/V rows quantize and scatter into the
-      pool before the kernel runs, and no dense per-slot view is ever
+      pool before the kernel runs, and no gathered per-slot view is ever
       materialized. Numerics differ from the gather path only by
       reduction order (tolerance-tested).
     """
@@ -978,7 +875,7 @@ def build_inject_blocks_fn(model, row_aval):
 
 
 def cache_nbytes(tree) -> int:
-    """Resident bytes of a cache pytree (dense slot grid or paged pool;
+    """Resident bytes of a cache pytree (the paged pool, per-slot state;
     None leaves — elided index leaves — count zero). GLOBAL bytes: a
     tp-sharded tree's per-device share is `tree_nbytes_per_device`."""
     total = 0
@@ -1028,18 +925,18 @@ def tree_nbytes_per_device(tree) -> int:
 
 
 def kv_partition_spec(shape: Tuple[int, ...], lay: LeafLayout, tp: int):
-    """PartitionSpec for a DENSE cache leaf (prefill row, slot row, or
-    slot grid) whose `lay` is its leaf of `cache_layout` over the SAME
-    tree — the model declares the seq axis from the end of the shape, so
-    extra leading slot/layer axes need no special casing. Leaves that are
-    not paged by token stay replicated."""
+    """PartitionSpec for a leaf of the prefill's row cache, whose `lay`
+    is its leaf of `cache_layout` over the SAME tree — the model declares
+    the seq axis from the end of the shape, so extra leading layer axes
+    need no special casing. Leaves that are not paged by token stay
+    replicated."""
     return _heads_over_tp(len(shape), shape, lay, tp, shift=0)
 
 
 def pool_partition_spec(row_shape: Tuple[int, ...], lay: LeafLayout,
                         tp: int):
     """The same heads-axis rule for a PAGED pool leaf, whose seq axis
-    was split into (num_blocks, block_size) — computed from the dense
+    was split into (num_blocks, block_size) — computed from the
     ROW leaf's shape and layout, with every axis after the split shifted
     one right."""
     return _heads_over_tp(len(row_shape) + 1, row_shape, lay, tp, shift=1)
@@ -1094,7 +991,7 @@ class DecodeEngine:
             raise ValueError(f"token_bucket must be >= 1, got {token_bucket}")
         self.model = model
         # Tensor-parallel decode (docs/Serving.md): with a mesh, params
-        # place by the model's logical-axis annotations, slot KV shards
+        # place by the model's logical-axis annotations, the KV pool shards
         # its kv-heads axis over tp, and every compiled program lowers
         # with explicit in/out shardings so XLA inserts the TP
         # collectives — validated HERE, before any trace, so a bad tp
@@ -1153,23 +1050,18 @@ class DecodeEngine:
         self._rest_width = max(gaps) if gaps else 1
         self._prefill: Dict[tuple, Any] = {}
         self._decode: Dict[tuple, Any] = {}
-        self._step: Dict[tuple, Any] = {}
         self._lock = threading.Lock()
         self._placed_seen = None  # _placed: (treedef, weak leaves, fp)
         self.stats = {
             "calls": 0,
             "prefill_compiles": 0,
             "decode_compiles": 0,
-            "step_compiles": 0,
             "prefill_cache_hits": 0,
             "decode_cache_hits": 0,
-            "step_cache_hits": 0,
             "paged_step_compiles": 0,
             "paged_step_cache_hits": 0,
             "pack_compiles": 0,
             "pack_cache_hits": 0,
-            "spec_step_compiles": 0,
-            "spec_step_cache_hits": 0,
             "paged_spec_step_compiles": 0,
             "paged_spec_step_cache_hits": 0,
             "extract_compiles": 0,
@@ -1181,35 +1073,13 @@ class DecodeEngine:
         }
         self._paged_step: Dict[tuple, Any] = {}
         self._pack: Dict[tuple, Any] = {}
-        self._spec_step: Dict[tuple, Any] = {}
         self._paged_spec_step: Dict[tuple, Any] = {}
         self._extract: Dict[tuple, Any] = {}
         self._inject: Dict[tuple, Any] = {}
 
-        # Slot-grid splice helpers (continuous batching): donated, so the
-        # grid updates HBM in place instead of copying the whole KV store
-        # per admission/retirement.
-        def _insert(grid, row, slot):
-            return jax.tree_util.tree_map(
-                lambda buf, r: jax.lax.dynamic_update_index_in_dim(
-                    buf, r.astype(buf.dtype), slot, 0
-                ),
-                grid, row,
-            )
-
-        def _evict(grid, slot):
-            return jax.tree_util.tree_map(
-                lambda buf: jax.lax.dynamic_update_index_in_dim(
-                    buf, jnp.zeros(buf.shape[1:], buf.dtype), slot, 0
-                ),
-                grid,
-            )
-
-        self._insert_jit = jax.jit(_insert, donate_argnums=(0,))
-        self._evict_jit = jax.jit(_evict, donate_argnums=(0,))
-
-        # Per-slot state beside the block pool (write_slot_state): the
-        # same splices over a tree that is None where a leaf is paged.
+        # Per-slot state beside the block pool (write_slot_state): row
+        # splices over a tree that is None where a leaf is paged; donated,
+        # so the state updates HBM in place.
         def _write_state(state, rows, slot):
             return jax.tree_util.tree_map(
                 lambda buf, r: None if buf is None
@@ -1341,9 +1211,8 @@ class DecodeEngine:
         return jax.jit(fn, **kwargs)
 
     def _kv_shardings(self, avals):
-        """NamedSharding tree for a DENSE cache tree (row, grid, or
-        prefill output) under this engine's mesh: kv-heads axis over
-        tp (kv_partition_spec)."""
+        """NamedSharding tree for the prefill's row cache under this
+        engine's mesh: kv-heads axis over tp (kv_partition_spec)."""
         from jax.sharding import NamedSharding
 
         return jax.tree_util.tree_map(
@@ -1398,10 +1267,10 @@ class DecodeEngine:
         def build():
             out_shardings = None
             if self.mesh is not None:
-                # Pin the fresh cache SHARDED at the source: everything
-                # downstream (insert_slot, pack_prefill) then propagates
-                # the placement instead of guessing it. The eval_shape
-                # runs only on a compile miss — not per admission.
+                # Pin the fresh cache SHARDED at the source: pack_prefill
+                # downstream then propagates the placement instead of
+                # guessing it. The eval_shape runs only on a compile
+                # miss — not per admission.
                 cache_avals, _logits_aval = jax.eval_shape(
                     prefill_fn, *prefill_args
                 )
@@ -1424,15 +1293,15 @@ class DecodeEngine:
     # -- continuous-batching slot API --------------------------------------
     #
     # The serving scheduler (tf_yarn_tpu/serving/scheduler.py) keeps a
-    # fixed grid of `max_slots` decode slots, each backed by a persistent
-    # batch-1 KV cache with its own cache_index. Admission prefills a
-    # request's prompt through the SAME bucketed prefill programs
-    # `generate` uses and splices the result into a free slot; every tick
-    # then advances all slots one token in one compiled `step` program.
+    # fixed grid of `max_slots` decode slots, each a block-table row and
+    # a length over the paged pool below. Admission prefills a request's
+    # prompt through the SAME bucketed prefill programs `generate` uses
+    # and packs the result into the slot's blocks; every tick then
+    # advances all slots one token in one compiled `paged_step` program.
 
     def slot_prefill_len(self, prompt_len: int) -> int:
         """Prefill length for a slot admission: the largest prompt bucket
-        that still leaves >= 1 prompt token to replay through `step` (the
+        that still leaves >= 1 prompt token to replay through the step (the
         step consuming the LAST prompt token samples the first generated
         token — generate_legacy's prefill sample — so the final prompt
         position always goes through the step program). 0 = no prefill:
@@ -1450,148 +1319,9 @@ class DecodeEngine:
             params, prompt, self._params_fingerprint(params)
         )
 
-    def make_slot_cache(self, params, max_slots: int):
-        """Zeroed per-slot KV grid: every leaf of the model's decode
-        cache stacked along a new leading slot axis (batch-1 per slot,
-        per-slot cache_index). Shapes come from an abstract prefill —
-        nothing runs on the device except the zeros allocation."""
-        if max_slots < 1:
-            raise ValueError(f"max_slots must be >= 1, got {max_slots}")
-        params = self._place_params(params)
-        cache_avals = jax.eval_shape(
-            build_prefill_fn(self.model), params,
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        )[0]
-
-        def build():
-            return jax.tree_util.tree_map(
-                lambda leaf: jnp.zeros(
-                    (max_slots,) + leaf.shape, leaf.dtype
-                ),
-                cache_avals,
-            )
-
-        if self.mesh is None:
-            return build()
-        # Sharded zeros straight onto the mesh — each device allocates
-        # only its 1/tp shard, no full-grid staging anywhere.
-        grid_avals = jax.tree_util.tree_map(
-            lambda leaf: jax.ShapeDtypeStruct(
-                (max_slots,) + leaf.shape, leaf.dtype
-            ),
-            cache_avals,
-        )
-        return jax.jit(
-            build, out_shardings=self._kv_shardings(grid_avals)
-        )()
-
-    def insert_slot(self, slot_cache, slot: int, row_cache):
-        """Splice a freshly prefilled batch-1 cache (cache_index
-        included) into slot `slot`. The grid is donated: HBM updates in
-        place. The old grid reference is consumed — use the return."""
-        return self._insert_jit(
-            slot_cache, row_cache, jnp.asarray(slot, jnp.int32)
-        )
-
-    def evict_slot(self, slot_cache, slot: int):
-        """Zero slot `slot` (KV content and cache_index), returning the
-        donated grid. Freeing is host-side bookkeeping — this exists so
-        a retired slot's stale cache can never leak into a later
-        admission path that skips prefill (slot_prefill_len == 0)."""
-        return self._evict_jit(slot_cache, jnp.asarray(slot, jnp.int32))
-
-    def step(
-        self,
-        params,
-        slot_cache,
-        tokens,
-        rngs,
-        sample_mask,
-        temperature: float = 0.0,
-        top_k: Optional[int] = None,
-        top_p: Optional[float] = None,
-    ):
-        """Advance every slot of the grid one token in ONE compiled
-        program (build_step_fn). Compiled once per (grid size, sampling
-        config, params fingerprint); the KV grid and the per-slot rng
-        buffer are donated. Returns (slot_cache, emitted [S], rngs)."""
-        # Everything the host does before the device has anything to do
-        # (docs/Serving.md "Where a tick's time goes").
-        with telemetry.span("decode_engine/step_args"):
-            params, fp = self._placed(params)
-            tokens = jnp.asarray(tokens, jnp.int32)
-            rngs = jnp.asarray(rngs, jnp.uint32)
-            sample_mask = jnp.asarray(sample_mask, bool)
-            slots = int(tokens.shape[0])
-            step_key = (slots, float(temperature), top_k, top_p, fp)
-            step_fn = build_step_fn(self.model, temperature, top_k, top_p)
-            step_args = (params, slot_cache, tokens, rngs, sample_mask)
-            out_shardings = None
-            if self.mesh is not None:
-                out_shardings = (
-                    self._shardings_of(slot_cache), self._rep_sharding,
-                    self._rep_sharding,
-                )
-            compiled = self._compiled(
-                self._step, step_key, "step",
-                lambda: self._jit(
-                    step_fn, step_args, donate=(1, 3),
-                    out_shardings=out_shardings,
-                ).lower(*step_args).compile(),
-            )
-        with telemetry.span("decode_engine/step", slots=slots):
-            return compiled(*step_args)
-
-    def spec_step(
-        self,
-        params,
-        slot_cache,
-        tokens,
-        n_known,
-        eos_ids,
-        rngs,
-        active,
-        temperature: float = 0.0,
-        top_k: Optional[int] = None,
-        top_p: Optional[float] = None,
-    ):
-        """Advance every slot up to W = tokens.shape[1] tokens in ONE
-        compiled speculative program (build_spec_step_fn). Compiled once
-        per (grid size, window width, sampling config, params
-        fingerprint) — tokens / n_known / eos_ids are traced, so the
-        drafts changing every tick never recompiles. The KV grid and the
-        rng buffer are donated. Returns (slot_cache, emitted [S, W],
-        counts [S], rngs)."""
-        with telemetry.span("decode_engine/step_args"):
-            params, fp = self._placed(params)
-            tokens = jnp.asarray(tokens, jnp.int32)
-            n_known = jnp.asarray(n_known, jnp.int32)
-            eos_ids = jnp.asarray(eos_ids, jnp.int32)
-            rngs = jnp.asarray(rngs, jnp.uint32)
-            active = jnp.asarray(active, bool)
-            slots, width = (int(tokens.shape[0]), int(tokens.shape[1]))
-            key = ("spec", slots, width, float(temperature), top_k, top_p, fp)
-            fn = build_spec_step_fn(self.model, width, temperature, top_k, top_p)
-            args = (params, slot_cache, tokens, n_known, eos_ids, rngs, active)
-            out_shardings = None
-            if self.mesh is not None:
-                out_shardings = (
-                    self._shardings_of(slot_cache), self._rep_sharding,
-                    self._rep_sharding, self._rep_sharding,
-                )
-            compiled = self._compiled(
-                self._spec_step, key, "spec_step",
-                lambda: self._jit(
-                    fn, args, donate=(1, 5), out_shardings=out_shardings,
-                ).lower(*args).compile(),
-            )
-        with telemetry.span("decode_engine/spec_step", slots=slots,
-                            width=width):
-            return compiled(*args)
-
     # -- paged KV slot API ---------------------------------------------------
     #
-    # The paged layout (module docstring): a global pool of fixed-size
+    # The paged pool (module docstring): a global pool of fixed-size
     # KV blocks + per-slot block tables, gathered/scattered INSIDE the
     # compiled programs. The host-side free-list/refcount/prefix
     # bookkeeping lives in tf_yarn_tpu/serving/paging.py; the scheduler
@@ -1803,7 +1533,7 @@ class DecodeEngine:
     def extract_blocks(self, params, pool, block_ids, block_size: int):
         """Gather `block_ids` (traced (W,) values — W fixed at the
         block-table width keeps this at ONE compile key per pool
-        layout) pool rows into a dense payload pytree for a bulk
+        layout) pool rows into a contiguous payload pytree for a bulk
         `jax.device_get`. Read-only: the pool is NOT donated. Padding
         ids should aim at the trash block; their payload rows are
         garbage the caller discards."""
@@ -1965,10 +1695,8 @@ class DecodeEngine:
         return {
             "prefill": self._prefill,
             "decode": self._decode,
-            "step": self._step,
             "paged_step": self._paged_step,
             "pack": self._pack,
-            "spec_step": self._spec_step,
             "paged_spec_step": self._paged_spec_step,
             "extract": self._extract,
             "inject": self._inject,

@@ -6,14 +6,13 @@ online server (docs/Serving.md):
 * :mod:`~tf_yarn_tpu.serving.request` — Request/Response lifecycle, the
   bounded admission queue with backpressure, per-request deadlines.
 * :mod:`~tf_yarn_tpu.serving.scheduler` — the slot scheduler: a fixed
-  grid of persistent per-slot KV caches, one compiled device step per
-  tick, free-list slot reuse (continuous, not static, batching). Two KV
-  layouts: dense per-slot caches, or the paged block pool
-  (``kv_layout="paged"``) with int8-transparent storage and a shared
-  prompt-prefix cache.
+  grid of decode slots over one paged KV block pool, one compiled
+  device step per tick, free-list slot reuse (continuous, not static,
+  batching), int8-transparent storage and a shared prompt-prefix
+  cache.
 * :mod:`~tf_yarn_tpu.serving.paging` — host-side block-pool free list /
   refcounts, the prefix-cache LRU, and the :class:`HostBlockStore`
-  host-RAM tier behind the paged layout. With ``kv_host_blocks`` > 0
+  host-RAM tier behind the pool. With ``kv_host_blocks`` > 0
   the scheduler oversubscribes the device pool: under pressure the
   lowest-SLO-tier active stream swaps its KV blocks out to host RAM
   and resumes bit-identically when capacity frees ("KV

@@ -1,4 +1,4 @@
-"""Host-side accounting for the paged KV layout: block pool + prefix cache.
+"""Host-side accounting for the paged KV pool: block pool + prefix cache.
 
 The device half of paging lives in `models/decode_engine.py`
 (`make_paged_pool` / `pack_prefill` / `paged_step`): a global pool of
@@ -7,8 +7,7 @@ compiled step. This module is the host half — pure bookkeeping, no jax:
 
 * :class:`BlockPool` — the free-list + refcount ledger over physical
   block ids. Allocation pops from the free list; freeing a slot is
-  O(blocks-held) integer decrements (the dense layout's `evict_slot`
-  was an O(max_seq_len) device zeroing program). Physical block 0 is
+  O(blocks-held) integer decrements, no device program. Physical block 0 is
   reserved as the *trash block*: inactive slots in the compiled step
   write their (masked-off) garbage row somewhere, and block 0 is the
   somewhere — it is never allocated, so the garbage never lands in a

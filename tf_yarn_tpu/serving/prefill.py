@@ -454,11 +454,6 @@ def run_prefill(experiment, runtime=None) -> dict:
     from tf_yarn_tpu import event, fs as fs_lib, inference, preemption
     from tf_yarn_tpu.models.decode_engine import get_engine
 
-    if experiment.kv_layout != "paged":
-        raise ValueError(
-            "the prefill tier ships KV blocks; it needs "
-            f"kv_layout='paged', got {experiment.kv_layout!r}"
-        )
     tier = parse_prefill_tier(experiment.prefill_tier or {})
     telemetry_task = "prefill"
     if runtime is not None:

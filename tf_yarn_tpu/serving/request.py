@@ -169,8 +169,8 @@ class Response:
         self.finish_reason: Optional[str] = None
         self.first_token_at: Optional[float] = None
         # monotonic arrival time of every pushed token — the raw series
-        # behind TTFT and inter-token latency (benchmarks/run.py's A/B
-        # reads it; tokens landing in one tick share a timestamp).
+        # behind TTFT and inter-token latency (tokens landing in one
+        # tick share a timestamp).
         self.token_times: List[float] = []
 
     # -- producer side (the scheduler thread) ------------------------------

@@ -1,7 +1,7 @@
 """Continuous-batching slot scheduler over the compiled decode engine.
 
 The device-facing half of the serving subsystem (docs/Serving.md): a
-fixed grid of ``max_slots`` decode slots over one of two KV layouts.
+fixed grid of ``max_slots`` decode slots over one paged KV block pool.
 Every scheduler tick:
 
 1. **retire** active slots whose per-request deadline passed;
@@ -24,32 +24,26 @@ Every scheduler tick:
    tick, so decode work for in-flight requests never waits for a batch
    to drain (continuous batching, not static batching).
 
-KV layouts (``kv_layout=``):
-
-* ``"dense"`` — each slot owns a full ``max_seq_len`` batch-1 cache
-  inside a stacked grid (`make_slot_cache`/`insert_slot`/`evict_slot`/
-  `step`). Simple, but most of that HBM is padding for short requests
-  and `max_slots` is capped by it.
-* ``"paged"`` — ONE global pool of fixed-size KV blocks
-  (`make_paged_pool`) plus per-slot block tables, gathered/scattered
-  inside the compiled `paged_step`/`pack_prefill` programs. Freeing a
-  slot is O(blocks) host-side free-list bookkeeping
-  (`serving/paging.py`) — no device eviction program at all — and a
-  **prefix cache** maps requests sharing a prompt prefix onto
-  refcounted shared blocks instead of re-running prefill. Admission
-  reserves every block a request can ever need (prompt + max_new - 1
-  tokens) up front, so decode never stalls mid-request; when the pool
-  cannot cover the next request, admission *holds* it (LRU-evicting
-  prefix entries first) until retirements free blocks — or, with a
-  host tier configured (``kv_host_blocks`` > 0), **suspends** the
-  lowest-SLO-tier active stream instead: its KV blocks bulk-gather
-  through the engine's `extract_blocks` program, `device_get` to a
-  :class:`HostBlockStore`, and scatter back through `inject_blocks`
-  when retirements free capacity (FIFO within tier) — the resumed
-  stream is BIT-IDENTICAL to an uninterrupted run (replay consumes no
-  RNG; the slot's rng row is saved/restored; prefix-shared blocks are
-  never swapped, they re-attach through the normal lookup). The fp
-  paged path is BIT-IDENTICAL to the dense path and `generate_legacy`.
+The KV cache is ONE global pool of fixed-size blocks
+(`make_paged_pool`) plus per-slot block tables, gathered/scattered
+inside the compiled `paged_step`/`pack_prefill` programs. Freeing a
+slot is O(blocks) host-side free-list bookkeeping
+(`serving/paging.py`) — no device eviction program at all — and a
+**prefix cache** maps requests sharing a prompt prefix onto
+refcounted shared blocks instead of re-running prefill. Admission
+reserves every block a request can ever need (prompt + max_new - 1
+tokens) up front, so decode never stalls mid-request; when the pool
+cannot cover the next request, admission *holds* it (LRU-evicting
+prefix entries first) until retirements free blocks — or, with a
+host tier configured (``kv_host_blocks`` > 0), **suspends** the
+lowest-SLO-tier active stream instead: its KV blocks bulk-gather
+through the engine's `extract_blocks` program, `device_get` to a
+:class:`HostBlockStore`, and scatter back through `inject_blocks`
+when retirements free capacity (FIFO within tier) — the resumed
+stream is BIT-IDENTICAL to an uninterrupted run (replay consumes no
+RNG; the slot's rng row is saved/restored; prefix-shared blocks are
+never swapped, they re-attach through the normal lookup). The fp
+path is BIT-IDENTICAL to `generate_legacy`.
 
 The scheduler is a pure host-side state machine: its only device
 contract is the engine's slot methods, so the unit tests drive it with
@@ -109,7 +103,6 @@ SLOW_STEP_MIN_HISTORY = 8
 # away (queue full, tier cap, unservable): it never had a Response.
 REFUSED = "refused"
 
-KV_LAYOUTS = ("dense", "paged")
 DECODE_ATTENTION = ("gather", "fused")
 
 
@@ -138,8 +131,8 @@ class _Slot:
         self.pending: Deque[int] = collections.deque(pending)
         self.last_token = 0
         self.emitted = 0
-        # Paged layout only: the physical block ids this slot holds one
-        # reference on (shared prefix blocks included).
+        # The physical block ids this slot holds one reference on
+        # (shared prefix blocks included).
         self.blocks = blocks
         # The request's full token history (prompt + emissions) — the
         # speculative drafter's lookup corpus. Appended to only on the
@@ -149,14 +142,13 @@ class _Slot:
         # drives the chunked path's incremental prefix registration.
         self.prompt_filled = len(request.prompt) - len(self.pending)
         # Whole prompt blocks already offered to the prefix cache
-        # (chunked paged path only).
+        # (chunked path only).
         self.registered_blocks = 0
         # monotonic time of the last token push — the inter-token
         # latency histogram's reference point.
         self.last_emit_at: Optional[float] = None
-        # Tokens with valid KV in this slot's cache (the paged layout's
-        # `_lengths` row, kept for the dense layout too): what a
-        # length-aware attention would have to read.
+        # Tokens with valid KV in this slot's blocks (its `_lengths`
+        # row): what a length-aware attention would have to read.
         self.kv_len = self.prompt_filled
         # The request's time to its first token, in the parts
         # `_record_admission` and `_observe_ttft` fill in (span clock):
@@ -218,10 +210,10 @@ class SlotScheduler:
     program the grid runs; requests whose SamplingParams disagree are
     rejected at submit with ValueError (the HTTP frontend's 400).
 
-    Paged-layout knobs: ``block_size`` tokens per KV block;
-    ``num_blocks`` physical blocks in the pool (default: the
-    dense-equivalent ``max_slots * max_seq_len / block_size + 1`` —
-    shrink it to realize the HBM saving); ``prefix_cache_capacity``
+    Pool knobs: ``block_size`` tokens per KV block; ``num_blocks``
+    physical blocks in the pool (default: ``max_slots * max_seq_len /
+    block_size + 1``, every slot at full context — shrink it to realize
+    the HBM saving); ``prefix_cache_capacity``
     entries in the shared-prefix LRU (0 disables prefix sharing);
     ``max_seq_len`` overrides the engine-derived context bound (fake
     engines in tests have no model config).
@@ -252,7 +244,7 @@ class SlotScheduler:
     ``context_limit`` reserves ``window - 1`` positions of KV headroom.
 
     KV oversubscription (docs/Serving.md "KV oversubscription & SLO
-    tiers"): ``kv_host_blocks`` > 0 (paged layout only) backs the
+    tiers"): ``kv_host_blocks`` > 0 backs the
     device pool with that many host-RAM blocks; under pool pressure
     the scheduler SUSPENDS the lowest-tier active stream (swap out)
     instead of holding the new admission, and resumes it — bit-
@@ -269,7 +261,7 @@ class SlotScheduler:
     reads and writes the state in place. Everything that moves keys and
     values WITHOUT the state steps aside by name: the prefix cache
     neither registers nor hits (``prefix_skipped_stateful`` counts), and
-    the dense layout, the host swap tier, chunked prefill, the
+    the host swap tier, chunked prefill, the
     speculative / fused window, tensor parallelism and prefix export /
     import are refused with an error naming the feature and the leaves.
     """
@@ -286,7 +278,6 @@ class SlotScheduler:
         queue_capacity: int = 64,
         retry_after_s: float = 1.0,
         trace_len: int = 4096,
-        kv_layout: str = "dense",
         block_size: int = 16,
         num_blocks: Optional[int] = None,
         prefix_cache_capacity: int = 256,
@@ -301,21 +292,12 @@ class SlotScheduler:
     ):
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
-        if kv_layout not in KV_LAYOUTS:
-            raise ValueError(
-                f"kv_layout must be one of {KV_LAYOUTS}, got {kv_layout!r}"
-            )
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         if decode_attention not in DECODE_ATTENTION:
             raise ValueError(
                 f"decode_attention must be one of {DECODE_ATTENTION}, "
                 f"got {decode_attention!r}"
-            )
-        if decode_attention == "fused" and kv_layout != "paged":
-            raise ValueError(
-                "decode_attention='fused' streams the paged block pool "
-                "directly; it requires kv_layout='paged'"
             )
         # Tensor-parallel decode rides entirely inside the engine's
         # compiled programs — the scheduler's tick logic is unchanged —
@@ -340,7 +322,6 @@ class SlotScheduler:
         self.temperature = float(temperature)
         self.top_k = top_k
         self.top_p = top_p
-        self.kv_layout = kv_layout
         self.spec_k = int(spec_k)
         self.decode_attention = decode_attention
         # Speculative decoding (docs/Serving.md): window width = the
@@ -407,12 +388,6 @@ class SlotScheduler:
         if kv_host_blocks < 0:
             raise ValueError(
                 f"kv_host_blocks must be >= 0, got {kv_host_blocks}"
-            )
-        if kv_host_blocks and kv_layout != "paged":
-            raise ValueError(
-                "kv_host_blocks (the host swap tier) requires "
-                "kv_layout='paged' — dense slots have no block pool "
-                "to oversubscribe"
             )
         self.kv_host_blocks = kv_host_blocks
         self.tier_caps: Dict[str, int] = {}
@@ -488,69 +463,62 @@ class SlotScheduler:
         if self._state_leaves:
             self._refuse_for_state(kv_host_blocks)
 
-        if kv_layout == "paged":
-            if self._max_seq_len is None:
-                raise ValueError(
-                    "kv_layout='paged' needs max_seq_len (engine.model."
-                    "config.max_seq_len or the max_seq_len= argument)"
-                )
-            if self._max_seq_len % block_size:
-                raise ValueError(
-                    f"block_size={block_size} must divide "
-                    f"max_seq_len={self._max_seq_len}"
-                )
-            self._block_size = int(block_size)
-            self._blocks_per_slot = self._max_seq_len // self._block_size
-            if num_blocks is None:
-                # Dense-equivalent capacity (+ the trash block); shrink
-                # for the actual HBM saving.
-                num_blocks = max_slots * self._blocks_per_slot + 1
-            self._pool = engine.make_paged_pool(
-                params, num_blocks, self._block_size
+        if self._max_seq_len is None:
+            raise ValueError(
+                "the paged KV pool needs max_seq_len (engine.model."
+                "config.max_seq_len or the max_seq_len= argument)"
             )
-            self._blocks = BlockPool(num_blocks, self._block_size)
-            self._prefix = PrefixCache(self._blocks, prefix_cache_capacity)
-            self._host_store = (
-                HostBlockStore(kv_host_blocks, self._block_size)
-                if kv_host_blocks else None
+        if self._max_seq_len % block_size:
+            raise ValueError(
+                f"block_size={block_size} must divide "
+                f"max_seq_len={self._max_seq_len}"
             )
-            self._tables = np.zeros(
-                (max_slots, self._blocks_per_slot), np.int32
-            )
-            self._lengths = np.zeros((max_slots,), np.int32)
-            self._cache = None
-            kv_bytes = _cache_nbytes(self._pool)
-            if self._state_leaves:
-                try:
-                    self._state = engine.make_slot_state(params, max_slots)
-                except Exception as exc:
-                    raise RuntimeError(
-                        "serving cannot start: no room for the state of "
-                        f"{max_slots} slots "
-                        f"({', '.join(self._state_leaves)}) beside "
-                        f"{kv_bytes} bytes of KV pool: "
-                        f"{type(exc).__name__}: {exc}"
-                    ) from exc
-                self._state_bytes = _cache_nbytes(self._state)
-        else:
-            self._cache = engine.make_slot_cache(params, max_slots)
-            self._block_size = None
-            self._blocks = None
-            self._prefix = None
-            self._host_store = None
-            kv_bytes = _cache_nbytes(self._cache)
+        self._block_size = int(block_size)
+        self._blocks_per_slot = self._max_seq_len // self._block_size
+        if num_blocks is None:
+            # Every slot at full context (+ the trash block); shrink
+            # for the actual HBM saving.
+            num_blocks = max_slots * self._blocks_per_slot + 1
+        self._pool = engine.make_paged_pool(
+            params, num_blocks, self._block_size
+        )
+        self._blocks = BlockPool(num_blocks, self._block_size)
+        self._prefix = PrefixCache(self._blocks, prefix_cache_capacity)
+        self._host_store = (
+            HostBlockStore(kv_host_blocks, self._block_size)
+            if kv_host_blocks else None
+        )
+        self._tables = np.zeros(
+            (max_slots, self._blocks_per_slot), np.int32
+        )
+        self._lengths = np.zeros((max_slots,), np.int32)
+        kv_bytes = _cache_nbytes(self._pool)
+        if self._state_leaves:
+            try:
+                self._state = engine.make_slot_state(params, max_slots)
+            except Exception as exc:
+                raise RuntimeError(
+                    "serving cannot start: no room for the state of "
+                    f"{max_slots} slots "
+                    f"({', '.join(self._state_leaves)}) beside "
+                    f"{kv_bytes} bytes of KV pool: "
+                    f"{type(exc).__name__}: {exc}"
+                ) from exc
+            self._state_bytes = _cache_nbytes(self._state)
         self._kv_bytes = kv_bytes
         # Per-DEVICE residency: under tp sharding each device holds 1/tp
         # of every slot's KV (global bytes above are unchanged) — the
         # capacity-per-chip number the HBM planning reads.
         self._kv_bytes_per_device = _cache_nbytes_per_device(
-            self._pool if kv_layout == "paged" else self._cache
+            self._pool
         ) or kv_bytes
+        # The label stays on the wire (docs/Serving.md): a fleet registry
+        # or a dashboard may key on it.
         self._registry.gauge(
-            "serving/kv_cache_hbm_bytes", layout=kv_layout
+            "serving/kv_cache_hbm_bytes", layout="paged"
         ).set(kv_bytes)
         self._registry.gauge(
-            "serving/kv_cache_hbm_bytes_per_device", layout=kv_layout
+            "serving/kv_cache_hbm_bytes_per_device", layout="paged"
         ).set(self._kv_bytes_per_device)
         self._registry.gauge("serving/tp_degree").set(self.tp_degree)
         self._registry.gauge("serving/state_hbm_bytes").set(self._state_bytes)
@@ -563,7 +531,6 @@ class SlotScheduler:
         by name, before anything is allocated."""
         leaves = ", ".join(self._state_leaves)
         refused = {
-            "kv_layout='dense'": self.kv_layout != "paged",
             "the host swap tier (kv_host_blocks: suspend / resume)":
                 kv_host_blocks > 0,
             "chunked prefill (prefill_chunk)": self._chunked,
@@ -612,17 +579,14 @@ class SlotScheduler:
     # -- submission (any thread) -------------------------------------------
 
     @property
-    def context_limit(self) -> Optional[int]:
-        """Max prompt + max_new_tokens this grid can serve, or None when
-        unknown (fake engines without a config). The windowed paths
+    def context_limit(self) -> int:
+        """Max prompt + max_new_tokens this grid can serve. The windowed paths
         reserve ``window - 1`` positions of KV headroom per slot: a
         window writes all its rows before acceptance is known, so the
         last tick's rejected (or paused-garbage) rows must still land
         inside the cache. window = max(spec_k + 1, prefill_chunk), so
         the exact path loses nothing and the spec path loses spec_k
         exactly as before."""
-        if self._max_seq_len is None:
-            return None
         return self._max_seq_len - (self._window_width - 1)
 
     def submit(
@@ -674,10 +638,7 @@ class SlotScheduler:
             prompt=tuple(prompt), params=params, priority=priority,
             timeout_s=timeout_s, tier=tier, trace_id=trace_id,
         )
-        limit = self.context_limit
-        if limit is not None and (
-            len(request.prompt) + params.max_new_tokens > limit
-        ):
+        if len(request.prompt) + params.max_new_tokens > self.context_limit:
             headroom = (
                 f" minus the {self._window_width - 1}-token window "
                 "headroom (max(spec_k, prefill_chunk - 1))"
@@ -689,14 +650,13 @@ class SlotScheduler:
                 f"max_seq_len ({self._max_seq_len}){headroom} — the slot "
                 "KV size"
             )
-        if self.kv_layout == "paged":
-            need = self._blocks_needed(request)
-            if need > self._blocks.num_blocks - 1:
-                raise ValueError(
-                    f"request needs {need} KV blocks but the pool holds "
-                    f"{self._blocks.num_blocks - 1} — it can never be "
-                    "admitted; raise num_blocks or shorten the request"
-                )
+        need = self._blocks_needed(request)
+        if need > self._blocks.num_blocks - 1:
+            raise ValueError(
+                f"request needs {need} KV blocks but the pool holds "
+                f"{self._blocks.num_blocks - 1} — it can never be "
+                "admitted; raise num_blocks or shorten the request"
+            )
         try:
             # Tier-cap + queue admission under one lock: the cap bounds
             # the tier's whole in-system footprint (queued + active +
@@ -748,7 +708,7 @@ class SlotScheduler:
                 with telemetry.span("serving/resume"):
                     self._resume_suspended(now, admitted)
             with telemetry.span("serving/admit"):
-                self._admit(now, admitted)
+                self._admit_queued(now, admitted)
             active = [s for s in range(self.max_slots) if self._slots[s]]
             accepts = step_span = None
             if active:
@@ -818,40 +778,39 @@ class SlotScheduler:
         )
         self._registry.gauge("serving/free_slots").set(len(self._free))
         self._registry.gauge("serving/queue_depth").set(self.queue.depth)
-        if self.kv_layout == "paged":
-            self._registry.gauge("serving/block_pool_used_blocks").set(
-                self._blocks.used_blocks
+        self._registry.gauge("serving/block_pool_used_blocks").set(
+            self._blocks.used_blocks
+        )
+        self._registry.gauge("serving/block_pool_free_blocks").set(
+            self._blocks.free_blocks
+        )
+        self._registry.gauge("serving/prefix_cache_entries").set(
+            self._prefix.entries
+        )
+        self._registry.gauge("serving/prefix_cache_blocks").set(
+            self._prefix.cached_blocks
+        )
+        self._registry.gauge("serving/prefix_cache_hit_rate").set(
+            self._prefix.hit_rate
+        )
+        if self._host_store is not None:
+            self._registry.gauge("serving/host_blocks_used").set(
+                self._host_store.used_blocks
             )
-            self._registry.gauge("serving/block_pool_free_blocks").set(
-                self._blocks.free_blocks
+            self._registry.gauge("serving/host_blocks_free").set(
+                self._host_store.free_blocks
             )
-            self._registry.gauge("serving/prefix_cache_entries").set(
-                self._prefix.entries
-            )
-            self._registry.gauge("serving/prefix_cache_blocks").set(
-                self._prefix.cached_blocks
-            )
-            self._registry.gauge("serving/prefix_cache_hit_rate").set(
-                self._prefix.hit_rate
-            )
-            if self._host_store is not None:
-                self._registry.gauge("serving/host_blocks_used").set(
-                    self._host_store.used_blocks
-                )
-                self._registry.gauge("serving/host_blocks_free").set(
-                    self._host_store.free_blocks
-                )
-                counts: Dict[str, int] = {}
-                for entry in self._suspended:
-                    tier = entry.request.tier
-                    counts[tier] = counts.get(tier, 0) + 1
-                for tier in self.tier_caps:
-                    counts.setdefault(tier, 0)
-                counts.setdefault(DEFAULT_TIER, 0)
-                for tier, count in counts.items():
-                    self._registry.gauge(
-                        "serving/suspended_streams", tier=tier
-                    ).set(count)
+            counts: Dict[str, int] = {}
+            for entry in self._suspended:
+                tier = entry.request.tier
+                counts[tier] = counts.get(tier, 0) + 1
+            for tier in self.tier_caps:
+                counts.setdefault(tier, 0)
+            counts.setdefault(DEFAULT_TIER, 0)
+            for tier, count in counts.items():
+                self._registry.gauge(
+                    "serving/suspended_streams", tier=tier
+                ).set(count)
         return worked
 
     def _retire_deadlines(self, now: float, retired: List) -> None:
@@ -898,7 +857,7 @@ class SlotScheduler:
             time.monotonic() - entry.request.submitted_at
         )
 
-    def _admit(self, now: float, admitted: List[int]) -> None:
+    def _admit_queued(self, now: float, admitted: List[int]) -> None:
         while self._free:
             if self._held is not None:
                 item, self._held = self._held, None
@@ -911,26 +870,23 @@ class SlotScheduler:
                 # Died in the queue: never occupies a slot.
                 self._finish_unadmitted(response, FINISH_DEADLINE)
                 continue
-            if self.kv_layout == "paged":
-                ok = self._admit_paged(request, response, now, admitted)
-                # Pool exhausted: with a host tier, park lower-SLO-tier
-                # active streams (swap their blocks out) until this
-                # request fits or no eligible victim remains.
-                while not ok and self._suspend_victim_below(request):
-                    ok = self._admit_paged(request, response, now, admitted)
-                if not ok:
-                    # Hold the request (FIFO head) until retirements
-                    # free blocks — admission order is preserved,
-                    # decode of in-flight requests continues.
-                    self._held = (request, response)
-                    break
-            else:
-                self._admit_dense(request, response, now, admitted)
+            ok = self._admit(request, response, now, admitted)
+            # Pool exhausted: with a host tier, park lower-SLO-tier
+            # active streams (swap their blocks out) until this
+            # request fits or no eligible victim remains.
+            while not ok and self._suspend_victim_below(request):
+                ok = self._admit(request, response, now, admitted)
+            if not ok:
+                # Hold the request (FIFO head) until retirements
+                # free blocks — admission order is preserved,
+                # decode of in-flight requests continues.
+                self._held = (request, response)
+                break
 
     def _record_admission(self, slot: int, state: _Slot, now: float,
                           admitted: List[int], began: float,
                           prefilled: int = 0, hit_tokens: int = 0) -> None:
-        """Every admission path ends here (dense, paged, prefix hit,
+        """Every admission path ends here (blocking prefill, prefix hit,
         chunked). `began` is the span clock when the request took its
         slot, before any blocking prefill: the zero-length
         `serving/admission` record stands at that instant, and the
@@ -960,46 +916,8 @@ class SlotScheduler:
             prefill_ms=state.prefill_s * 1e3,
         )
 
-    def _admit_dense(self, request: Request, response: Response,
-                     now: float, admitted: List[int]) -> None:
-        slot = self._free.popleft()
-        began = spans.now()
-        if self._chunked:
-            # Chunked prefill: no blocking prefill program at all. The
-            # slot starts from a zeroed cache_index and the WHOLE prompt
-            # queues as pending replay — the windowed tick consumes it
-            # prefill_chunk tokens at a time, interleaved with decode.
-            self._cache = self.engine.evict_slot(self._cache, slot)
-            state = _Slot(request, response, list(request.prompt))
-            self._slots[slot] = state
-            self._record_admission(slot, state, now, admitted, began)
-            return
-        prefill_len = self.engine.slot_prefill_len(len(request.prompt))
-        with telemetry.span(
-            "serving/prefill", request=request.id,
-            request_id=request.public_id, prefill=prefill_len,
-        ):
-            if prefill_len > 0:
-                row_cache, _logits = self.engine.prefill(
-                    self.params,
-                    np.asarray(request.prompt[:prefill_len],
-                               np.int32)[None, :],
-                )
-                self._cache = self.engine.insert_slot(
-                    self._cache, slot, row_cache
-                )
-            else:
-                # Whole prompt replays from an empty cache: the slot
-                # must start from a ZEROED cache_index, not whatever
-                # the previous occupant left behind.
-                self._cache = self.engine.evict_slot(self._cache, slot)
-        state = _Slot(request, response, list(request.prompt[prefill_len:]))
-        self._slots[slot] = state
-        self._record_admission(slot, state, now, admitted, began,
-                               prefilled=prefill_len)
-
-    def _admit_paged(self, request: Request, response: Response,
-                     now: float, admitted: List[int]) -> bool:
+    def _admit(self, request: Request, response: Response,
+               now: float, admitted: List[int]) -> bool:
         """Reserve blocks (sharing a cached prefix when one matches),
         prefill-or-replay, and install the block table. Returns False —
         without consuming a slot — when the pool cannot cover the
@@ -1294,11 +1212,6 @@ class SlotScheduler:
         return self._control_call("import", wire, timeout_s)
 
     def _control_call(self, kind: str, arg, timeout_s: float):
-        if self.kv_layout != "paged":
-            raise ValueError(
-                "prefix warm start needs kv_layout='paged' — the dense "
-                "layout has no block pool or prefix cache to transfer"
-            )
         if self._state_leaves:
             raise ValueError(
                 f"prefix {kind} (/v1/blocks) ships keys and values and not "
@@ -1513,17 +1426,11 @@ class SlotScheduler:
                         temperature=self.temperature, top_k=self.top_k,
                         top_p=self.top_p,
                     )
-            elif self.kv_layout == "paged":
+            else:
                 self._pool, emitted, rngs = self.engine.paged_step(
                     self.params, self._pool, self._tables, self._lengths,
                     tokens, self._rngs, mask,
                     block_size=self._block_size,
-                    temperature=self.temperature, top_k=self.top_k,
-                    top_p=self.top_p,
-                )
-            else:
-                self._cache, emitted, rngs = self.engine.step(
-                    self.params, self._cache, tokens, self._rngs, mask,
                     temperature=self.temperature, top_k=self.top_k,
                     top_p=self.top_p,
                 )
@@ -1555,11 +1462,10 @@ class SlotScheduler:
             gaps = self._registry.histogram("serving/inter_token_latency_ms")
             for slot in active:
                 state = self._slots[slot]
-                if self.kv_layout == "paged":
-                    # Every active slot consumed one token this tick (a
-                    # replayed prompt token or its fed-back emission) and
-                    # wrote its K/V at the old length.
-                    self._lengths[slot] += 1
+                # Every active slot consumed one token this tick (a
+                # replayed prompt token or its fed-back emission) and
+                # wrote its K/V at the old length.
+                self._lengths[slot] += 1
                 state.kv_len += 1
                 sampled = bool(mask[slot])
                 if state.pending:
@@ -1666,7 +1572,7 @@ class SlotScheduler:
     def _count_step(self, active: List[int]) -> None:
         """Before a model step: what it will read. `kv_token_steps` over
         `slot_steps` is the mean live KV length a slot-step attends
-        over, against the `max_seq_len` the dense view holds."""
+        over, against the `max_seq_len` the gathered view holds."""
         self._slot_steps += len(active)
         self._kv_token_steps += sum(
             self._slots[slot].kv_len for slot in active
@@ -1749,22 +1655,14 @@ class SlotScheduler:
                 mask[slot] = True
                 consumed[slot] = need
                 proposed[slot] = n_prop
-            if self.kv_layout == "paged":
-                self._pool, emitted, counts, rngs = self.engine.paged_spec_step(
-                    self.params, self._pool, self._tables, self._lengths,
-                    tokens, n_known, eos_ids, self._rngs, mask,
-                    block_size=self._block_size,
-                    temperature=self.temperature, top_k=self.top_k,
-                    top_p=self.top_p,
-                    decode_attention=self.decode_attention,
-                )
-            else:
-                self._cache, emitted, counts, rngs = self.engine.spec_step(
-                    self.params, self._cache, tokens, n_known, eos_ids,
-                    self._rngs, mask,
-                    temperature=self.temperature, top_k=self.top_k,
-                    top_p=self.top_p,
-                )
+            self._pool, emitted, counts, rngs = self.engine.paged_spec_step(
+                self.params, self._pool, self._tables, self._lengths,
+                tokens, n_known, eos_ids, self._rngs, mask,
+                block_size=self._block_size,
+                temperature=self.temperature, top_k=self.top_k,
+                top_p=self.top_p,
+                decode_attention=self.decode_attention,
+            )
         with telemetry.span("serving/step_sync") as sync_span:
             # The tick's host sync: every slot's window + counts at once.
             emitted = np.asarray(emitted)
@@ -1787,12 +1685,11 @@ class SlotScheduler:
                 n = int(counts[slot])
                 decode_tokens += n
                 state.kv_len += int(n_known[slot]) + n
-                if self.kv_layout == "paged":
-                    # Valid rows this tick: the replayed prefix + the
-                    # emitted tokens; rejected window rows beyond stay dead.
-                    self._lengths[slot] += int(n_known[slot]) + n
-                    if self._chunked and consumed[slot]:
-                        self._register_chunk_prefix(state)
+                # Valid rows this tick: the replayed prefix + the
+                # emitted tokens; rejected window rows beyond stay dead.
+                self._lengths[slot] += int(n_known[slot]) + n
+                if self._chunked and consumed[slot]:
+                    self._register_chunk_prefix(state)
                 if proposed[slot]:
                     accepted_drafts = min(max(n - 1, 0), proposed[slot])
                     self._spec_proposed += proposed[slot]
@@ -1844,7 +1741,7 @@ class SlotScheduler:
 
     def _register_chunk_prefix(self, state: _Slot) -> None:
         """Offer every prompt block a chunk just completed to the prefix
-        cache (chunked paged path). `PrefixCache.register` is idempotent
+        cache (chunked path). `PrefixCache.register` is idempotent
         per prefix key and takes its OWN reference on newly shared
         blocks, so the slot's one reference (released at retire) is
         never double-counted — a mid-PREFILL eviction releases exactly
@@ -1874,16 +1771,15 @@ class SlotScheduler:
         state = self._slots[slot]
         self._slots[slot] = None
         self._free.append(slot)
-        if self.kv_layout == "paged":
-            # O(blocks) bookkeeping, no device program: shared prefix
-            # blocks survive (the prefix cache holds its own reference),
-            # exclusively-owned blocks return to the free list. The
-            # stale pool content needs no zeroing — gathers mask
-            # positions beyond each slot's length, and reallocation
-            # overwrites.
-            self._blocks.release(state.blocks)
-            self._tables[slot, :] = 0
-            self._lengths[slot] = 0
+        # O(blocks) bookkeeping, no device program: shared prefix
+        # blocks survive (the prefix cache holds its own reference),
+        # exclusively-owned blocks return to the free list. The
+        # stale pool content needs no zeroing — gathers mask
+        # positions beyond each slot's length, and reallocation
+        # overwrites.
+        self._blocks.release(state.blocks)
+        self._tables[slot, :] = 0
+        self._lengths[slot] = 0
         self._tier_dec(state.request)
         self._estimator.record_retire(
             getattr(state.request, "tier", DEFAULT_TIER)
@@ -1987,7 +1883,8 @@ class SlotScheduler:
             "temperature": self.temperature,
             "top_k": self.top_k,
             "top_p": self.top_p,
-            "kv_layout": self.kv_layout,
+            # One layout; the key stays for whoever reads it off the wire.
+            "kv_layout": "paged",
             "kv_cache_hbm_bytes": self._kv_bytes,
             "kv_cache_hbm_bytes_per_device": self._kv_bytes_per_device,
             "tp_degree": self.tp_degree,
@@ -2040,39 +1937,38 @@ class SlotScheduler:
                     self._spec_accepted / self._spec_proposed, 4
                 ) if self._spec_proposed else None,
             }
-        if self.kv_layout == "paged":
-            snap["block_size"] = self._block_size
-            snap["block_pool"] = {
-                "num_blocks": self._blocks.num_blocks,
-                "used_blocks": self._blocks.used_blocks,
-                "free_blocks": self._blocks.free_blocks,
+        snap["block_size"] = self._block_size
+        snap["block_pool"] = {
+            "num_blocks": self._blocks.num_blocks,
+            "used_blocks": self._blocks.used_blocks,
+            "free_blocks": self._blocks.free_blocks,
+        }
+        snap["prefix_cache"] = {
+            "entries": self._prefix.entries,
+            "cached_blocks": self._prefix.cached_blocks,
+            "hits": self._prefix.hits,
+            "misses": self._prefix.misses,
+            "hit_rate": round(self._prefix.hit_rate, 4),
+        }
+        if self._host_store is not None:
+            suspended_by_tier: Dict[str, int] = {}
+            for entry in self._suspended:
+                tier = entry.request.tier
+                suspended_by_tier[tier] = \
+                    suspended_by_tier.get(tier, 0) + 1
+            snap["host_block_store"] = {
+                "capacity_blocks": self._host_store.capacity_blocks,
+                "used_blocks": self._host_store.used_blocks,
+                "free_blocks": self._host_store.free_blocks,
+                "entries": self._host_store.entries,
             }
-            snap["prefix_cache"] = {
-                "entries": self._prefix.entries,
-                "cached_blocks": self._prefix.cached_blocks,
-                "hits": self._prefix.hits,
-                "misses": self._prefix.misses,
-                "hit_rate": round(self._prefix.hit_rate, 4),
+            snap["suspended_streams"] = suspended_by_tier
+            snap["swap"] = {
+                "suspends": self._suspends,
+                "resumes": self._resumes,
+                "swap_out_blocks": self._swap_out_blocks,
+                "swap_in_blocks": self._swap_in_blocks,
             }
-            if self._host_store is not None:
-                suspended_by_tier: Dict[str, int] = {}
-                for entry in self._suspended:
-                    tier = entry.request.tier
-                    suspended_by_tier[tier] = \
-                        suspended_by_tier.get(tier, 0) + 1
-                snap["host_block_store"] = {
-                    "capacity_blocks": self._host_store.capacity_blocks,
-                    "used_blocks": self._host_store.used_blocks,
-                    "free_blocks": self._host_store.free_blocks,
-                    "entries": self._host_store.entries,
-                }
-                snap["suspended_streams"] = suspended_by_tier
-                snap["swap"] = {
-                    "suspends": self._suspends,
-                    "resumes": self._resumes,
-                    "swap_out_blocks": self._swap_out_blocks,
-                    "swap_in_blocks": self._swap_in_blocks,
-                }
         engine_stats = getattr(self.engine, "stats", None)
         if isinstance(engine_stats, dict):
             snap["decode_engine"] = dict(engine_stats)

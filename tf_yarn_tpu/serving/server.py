@@ -212,7 +212,7 @@ def _make_handler(scheduler: SlotScheduler, slo_evaluator=None,
                 try:
                     wire = scheduler.export_hot_prefixes(limit)
                 except ValueError as exc:
-                    # Dense layout / no prefix machinery: the warm-start
+                    # A model with per-slot state: the warm-start
                     # protocol does not apply to this replica.
                     self._json(409, {"error": str(exc)})
                     return
@@ -278,9 +278,8 @@ def _make_handler(scheduler: SlotScheduler, slo_evaluator=None,
                 try:
                     result = scheduler.import_prefixes(wire)
                 except Exception as exc:
-                    # Layout/geometry mismatch (dense layout, different
-                    # block_size, foreign pool structure): refuse, keep
-                    # serving.
+                    # Geometry mismatch (different block_size, foreign
+                    # pool structure): refuse, keep serving.
                     self._json(409, {"error": str(exc)})
                     return
                 self._json(200, result)
@@ -314,9 +313,7 @@ def _make_handler(scheduler: SlotScheduler, slo_evaluator=None,
             # mid-tick inside the scheduler thread. 400 here keeps the
             # serving loop untouched.
             limit = scheduler.context_limit
-            if limit is not None and (
-                len(prompt) + params.max_new_tokens > limit
-            ):
+            if len(prompt) + params.max_new_tokens > limit:
                 self._json(400, {
                     "error": (
                         f"prompt ({len(prompt)}) + max_new_tokens "
@@ -514,7 +511,6 @@ def run_serving(experiment, runtime=None) -> dict:
         top_p=experiment.top_p,
         queue_capacity=experiment.queue_capacity,
         retry_after_s=experiment.retry_after_s,
-        kv_layout=experiment.kv_layout,
         block_size=experiment.block_size,
         num_blocks=experiment.num_blocks,
         prefix_cache_capacity=experiment.prefix_cache_capacity,
@@ -532,8 +528,7 @@ def run_serving(experiment, runtime=None) -> dict:
             telemetry.parse_slo(experiment.slo)
         )
     prefill_client = None
-    if getattr(experiment, "prefill_tier", None) is not None \
-            and experiment.kv_layout == "paged":
+    if getattr(experiment, "prefill_tier", None) is not None:
         from tf_yarn_tpu.serving.prefill import (
             PrefillClient,
             parse_prefill_tier,

@@ -16,8 +16,9 @@ Nothing runs, so nothing here is a result or a time of the device. Programs:
     train4  the same step sharded fsdp=2 x tp=2 over the 2x2 host
     bf16 | int8 | fused
             prefill (buckets 32/64/128), pack and the paged decode step at
-            max_slots=8, block 16: bf16 gather, int8 gather, int8 fused
-    tp4     prefill and paged step of a tp=4 replica (bf16 gather)
+            max_slots=8, block 16: the one-token step over a bf16 and an
+            int8 pool, the speculative window's fused int8 step
+    tp4     prefill and paged step of a tp=4 replica (bf16, plain read)
 
 Each line: how many Mosaic kernels (`tpu_custom_call`) and collectives the
 compiler emitted, and the bytes one device needs (temporaries + arguments).
@@ -189,7 +190,12 @@ def compile_serving(name, devices, kv_cache_dtype, attention, tp) -> None:
     began = time.monotonic()
     if attention == "gather":
         compiled = jax.jit(
-            de.build_paged_step_fn(model, BLOCK, 0.0, None, None),
+            # As DecodeEngine.paged_attention_kernel decides: the plain
+            # read where the pool is sharded, else by backend and shape
+            # (heads of 64: the bf16 kernel stands aside, the int8 one
+            # serves).
+            de.build_paged_step_fn(model, BLOCK, 0.0, None, None,
+                                   paged_kernel=None if tp == 1 else False),
             donate_argnums=(1, 5),
             out_shardings=(pool_shardings, replicated, replicated),
         ).lower(params, pool, tables, lengths, arg(jnp.int32, SLOTS), rngs,
@@ -217,13 +223,13 @@ def main(argv) -> int:
     programs = {
         "train": lambda: compile_train(one, {}),
         "train4": lambda: compile_train(four, {"fsdp": 2, "tp": 2}),
-        "bf16": lambda: compile_serving("bf16 gather", one, "bf16",
+        "bf16": lambda: compile_serving("bf16", one, "bf16",
                                         "gather", 1),
-        "int8": lambda: compile_serving("int8 gather", one, "int8",
+        "int8": lambda: compile_serving("int8", one, "int8",
                                         "gather", 1),
         "fused": lambda: compile_serving("int8 fused", one, "int8",
                                          "fused", 1),
-        "tp4": lambda: compile_serving("tp=4 bf16 gather", four, "bf16",
+        "tp4": lambda: compile_serving("tp=4 bf16", four, "bf16",
                                        "gather", 4),
     }
     for name in argv or list(programs):

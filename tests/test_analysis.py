@@ -333,7 +333,7 @@ def test_jaxpr_engine_default_entries_clean_on_this_build():
     paged = counts["models.decode_engine.paged_step"]
     assert paged["dot_general"] > 0
     assert paged.get("gather", 0) > 0
-    assert paged.get("dynamic_update_slice", 0) > 0
+    assert paged.get("scatter", 0) > 0
     assert "models.decode_engine.paged_prefill" in counts
     assert counts["models.decode_engine.paged_prefill"][
         "dynamic_update_slice"] > 0

@@ -572,3 +572,203 @@ def test_no_query_head_copy_of_the_cache_view(program, copies):
         shape[-4:] == (_G_KV, _G_REP, 1, _G_SEQ) for shape in shapes)
     found = _head_count_over_cache(jaxpr)
     assert bool(found) == copies, found
+
+
+# --------------------------------------------------------------------------
+# paged_decode_attention: the one-token paged step's read of the pool, the
+# kernel (interpret mode here) against the plain gather and against f32.
+# --------------------------------------------------------------------------
+
+_P_BLOCK, _P_TABLE = 16, 16          # 16 blocks of 16: a 256-token table
+_P_CHUNK = 8 * _P_BLOCK              # the kernel's 8 pages a loop trip
+# One slot a length: empty, one token, around a block's edge, around a
+# chunk's edge, the whole table. Slot 0 besides is a free slot: its table
+# row is all trash block.
+_P_LENGTHS = (0, 0, 1, 15, 16, 17, _P_CHUNK - 1, _P_CHUNK, _P_CHUNK + 1,
+              _P_BLOCK * _P_TABLE)
+
+
+def _paged_case(n_heads, n_kv, head_dim, garbage, seed=32):
+    """Slots of `_P_LENGTHS` over a pool whose tables point at shuffled
+    physical blocks; every position no slot holds is `garbage`."""
+    rng = np.random.RandomState(seed)
+    slots = len(_P_LENGTHS)
+    nb = slots * _P_TABLE + 1
+    tables = (rng.permutation(nb - 1)[:slots * _P_TABLE] + 1).reshape(
+        slots, _P_TABLE).astype(np.int32)
+    tables[0] = 0
+    pools = []
+    for _ in range(2):
+        pool = np.full((nb, _P_BLOCK, n_kv, head_dim), garbage, np.float32)
+        for slot, length in enumerate(_P_LENGTHS):
+            for pos in range(length):
+                pool[tables[slot, pos // _P_BLOCK], pos % _P_BLOCK] = \
+                    rng.randn(n_kv, head_dim)
+        pools.append(jnp.asarray(pool, jnp.bfloat16))
+    query = jnp.asarray(rng.randn(slots, n_heads, head_dim), jnp.bfloat16)
+    return query, pools[0], pools[1], jnp.asarray(tables), \
+        jnp.asarray(_P_LENGTHS, jnp.int32)
+
+
+def _paged_reference(query, key_pool, value_pool, tables, lengths, scale):
+    """The same attention in float32 numpy over the live positions only."""
+    q, kp, vp = (np.asarray(x, np.float32)
+                 for x in (query, key_pool, value_pool))
+    slots, n_heads, head_dim = q.shape
+    rep = n_heads // kp.shape[2]
+    out = np.zeros(q.shape, np.float32)
+    for slot in range(slots):
+        n = int(lengths[slot])
+        if not n:
+            continue
+        rows = np.asarray(tables[slot])
+        k = kp[rows].reshape(-1, *kp.shape[2:])[:n]
+        v = vp[rows].reshape(-1, *vp.shape[2:])[:n]
+        for head in range(n_heads):
+            logits = k[:, head // rep] @ q[slot, head] * scale
+            weights = np.exp(logits - logits.max())
+            out[slot, head] = weights / weights.sum() @ v[:, head // rep]
+    return out
+
+
+@pytest.mark.parametrize("scale", [None, 1.0 / 24], ids=["sqrt", "given"])
+# The plain path weights a dead row by an exact 0 and adds it: large finite
+# garbage must not reach the output, NaN would (0 * NaN). The kernel zeroes
+# dead rows of V and takes both.
+@pytest.mark.parametrize("kernel,garbage", [
+    (True, 3e4), (True, float("nan")), (False, 3e4),
+], ids=["kernel-big", "kernel-nan", "plain-big"])
+@pytest.mark.parametrize("heads", [(32, 8, 128), (4, 4, 16)],
+                         ids=["32over8", "4over4"])
+def test_paged_decode_attention_reads_live_positions_only(
+        heads, kernel, garbage, scale):
+    from tf_yarn_tpu.ops.decode_attention import paged_decode_attention
+
+    n_heads, n_kv, head_dim = heads
+    query, key_pool, value_pool, tables, lengths = _paged_case(
+        n_heads, n_kv, head_dim, garbage)
+    out = jax.jit(lambda *args: paged_decode_attention(
+        *args, scale, kernel=kernel))(
+        query, key_pool, value_pool, tables, lengths)
+    assert out.shape == query.shape and out.dtype == query.dtype
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all()
+    # Nothing to attend over: zeros, not a uniform softmax over garbage.
+    assert not out[:2].any()
+    ref = _paged_reference(
+        query, key_pool, value_pool, tables, lengths,
+        head_dim ** -0.5 if scale is None else scale)
+    # bfloat16 in and out, f32 softmax. The kernel keeps its logits in f32
+    # and rounds the weights and the output to an 8-bit mantissa (2**-8
+    # relative, of values up to ~3 at length 1): 2e-2. The plain path also
+    # rounds the logits to bfloat16 before the softmax (PR 30's cases:
+    # logits reach ~4, a weight is off by 1.6 %): 4e-2. A position past
+    # the length, a foreign KV head or another slot's block is off by ~1.
+    np.testing.assert_allclose(out, ref, atol=2e-2 if kernel else 4e-2,
+                               rtol=0)
+
+
+@pytest.mark.parametrize("heads", [(32, 8, 128), (4, 4, 16)],
+                         ids=["32over8", "4over4"])
+def test_paged_kernel_matches_plain_read(heads):
+    """The two implementations of the one op, against each other: the sum
+    of their distances to f32 above bounds it (6e-2); read here it is the
+    plain path's bfloat16 logits, 4e-2."""
+    from tf_yarn_tpu.ops.decode_attention import paged_decode_attention
+
+    args = _paged_case(*heads, garbage=3e4)
+    outs = [np.asarray(paged_decode_attention(*args, kernel=kernel),
+                       np.float32) for kernel in (True, False)]
+    np.testing.assert_allclose(outs[0], outs[1], atol=4e-2, rtol=0)
+
+
+def test_paged_kernel_serves_nothing_off_the_tpu(monkeypatch):
+    """The choice is the code's: on this CPU the plain read serves whatever
+    the pool's shape, and only a shape the kernel can tile is its."""
+    from tf_yarn_tpu.ops import _rowwise, decode_attention
+
+    leaf = jax.ShapeDtypeStruct((65, 16, 8, 128), jnp.bfloat16)
+    assert not decode_attention.paged_kernel_serves(leaf)
+    monkeypatch.setattr(_rowwise, "default_interpret", lambda: False)
+    assert decode_attention.paged_kernel_serves(leaf)
+    narrow = jax.ShapeDtypeStruct((65, 16, 8, 64), jnp.bfloat16)
+    assert not decode_attention.paged_kernel_serves(narrow)
+    f32 = jax.ShapeDtypeStruct((65, 16, 8, 128), jnp.float32)
+    assert not decode_attention.paged_kernel_serves(f32)
+    assert decode_attention.paged_chunk_tokens(16, 256) == 128
+    assert decode_attention.paged_chunk_tokens(4, 2) == 8
+
+
+def _step_logits(builder, model, extra, paged_kernel):
+    """One step of `builder`'s program over three slots (free, 5 and 21
+    tokens held) of a random pool: (logits, the pool it wrote)."""
+    from tf_yarn_tpu.models import decode_engine
+
+    rng = np.random.RandomState(7)
+    params = nn.meta.unbox(model.init(
+        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32)))
+    params = {"params": params["params"]}
+    row = decode_engine._decode_cache_aval(model, params)
+    block, slots = 4, 3
+    per_slot = model.config.max_seq_len // block
+    pool = jax.tree_util.tree_map(
+        lambda aval: None if aval is None else jnp.asarray(
+            rng.randn(*aval.shape), aval.dtype),
+        decode_engine.paged_pool_avals(
+            model, row, slots * per_slot + 1, block),
+        is_leaf=lambda x: x is None)
+    tables = (rng.permutation(slots * per_slot) + 1).reshape(
+        slots, per_slot).astype(np.int32)
+    tables[0] = 0
+    step = jax.jit(builder(model, block, 0.0, None, None, with_logits=True,
+                           paged_kernel=paged_kernel))
+    out = step(params, pool, *extra(row, slots), tables,
+               np.asarray([0, 5, 21], np.int32),
+               np.asarray([3, 4, 5], np.int32),
+               np.zeros((slots, 2), np.uint32), np.ones((slots,), bool))
+    return np.asarray(out[-1], np.float32), out[0]
+
+
+def _tiny_transformer_step():
+    from tf_yarn_tpu.models import decode_engine, transformer
+
+    model = transformer.Transformer(transformer.TransformerConfig.tiny(
+        scan_layers=False, remat=False, max_seq_len=32))
+    return decode_engine.build_paged_step_fn, model, lambda row, slots: ()
+
+
+def _tiny_hybrid_step():
+    from tf_yarn_tpu.models import decode_engine, hybrid
+
+    model = hybrid.HybridLM(hybrid.HybridConfig.tiny(max_seq_len=32))
+
+    def state(row, slots):
+        return (jax.tree_util.tree_map(
+            lambda lay, aval: jnp.zeros((slots,) + tuple(aval.shape),
+                                        aval.dtype)
+            if lay.kind == decode_engine.SLOT else None,
+            decode_engine.cache_layout(model, row), row),)
+
+    return decode_engine.build_paged_state_step_fn, model, state
+
+
+@pytest.mark.parametrize("case", [_tiny_transformer_step, _tiny_hybrid_step],
+                         ids=["paged_step", "paged_state_step"])
+def test_paged_step_logits_agree_between_kernel_and_plain_read(case):
+    """Each builder's whole step on its tiny preset (bfloat16), the kernel
+    forced (interpret mode) against the plain read: the same rows written
+    to the same blocks, and logits that differ by what two bfloat16
+    attention outputs differ by (4e-2 an output above, through a tiny
+    model's remaining layers onto logits of size ~1: 5e-2)."""
+    builder, model, extra = case()
+    (kernel_logits, kernel_pool), (plain_logits, plain_pool) = (
+        _step_logits(builder, model, extra, paged_kernel)
+        for paged_kernel in (True, False))
+    assert np.isfinite(kernel_logits).all()
+    np.testing.assert_allclose(kernel_logits[1:], plain_logits[1:],
+                               atol=5e-2, rtol=0)
+    # Layer 0 writes the same rows whoever reads them afterwards.
+    first = jax.tree_util.tree_leaves(kernel_pool)[0]
+    np.testing.assert_array_equal(
+        np.asarray(first, np.float32),
+        np.asarray(jax.tree_util.tree_leaves(plain_pool)[0], np.float32))

@@ -533,7 +533,7 @@ def test_paged_step_traces_with_zero_host_syncs():
     )
     # The table indirection is real: the program gathers and scatters.
     assert "gather" in prims
-    assert "dynamic_update_slice" in prims
+    assert "scatter" in prims
 
 
 def test_paged_pool_validates():
@@ -590,3 +590,34 @@ def test_placed_tree_is_remembered_by_its_leaves_not_by_its_shapes():
     del twin
     assert engine._placed_seen is None or any(
         ref() is None for ref in engine._placed_seen[1])
+
+
+def test_paged_attention_kernel_is_chosen_from_backend_shape_and_sharding(
+        monkeypatch):
+    """No setting picks the paged step's read: on this CPU it is the plain
+    gather; where the backend is a TPU (steered here, in the test) the
+    kernel serves a pool it can tile and stands aside for one it cannot,
+    and for any pool that is sharded over `tp`. `/stats` names the choice
+    and `paged_attention_chunk` follows it."""
+    from tf_yarn_tpu.ops import _rowwise
+
+    engine = _engine(transformer.Transformer(
+        transformer.TransformerConfig.tiny(max_seq_len=4096)))
+    leaf = lambda dim: {"k": jax.ShapeDtypeStruct(
+        (1, 65, 16, 8, dim), jnp.bfloat16)}
+    assert engine.paged_attention_kernel(leaf(128)) is False
+    assert engine.stats["paged_attention"] == "plain"
+    assert engine.paged_attention_chunk(16) == 4096
+
+    monkeypatch.setattr(_rowwise, "default_interpret", lambda: False)
+    on_tpu = _engine(transformer.Transformer(
+        transformer.TransformerConfig.tiny(max_seq_len=4096)))
+    assert on_tpu.paged_attention_kernel(leaf(64)) is False
+    assert on_tpu.paged_attention_kernel(leaf(128)) is True
+    assert on_tpu.stats["paged_attention"] == "kernel"
+    assert on_tpu.paged_attention_chunk(16) == 128  # 8 pages of 16 tokens
+    on_tpu.tp_degree = 2  # what DecodeEngine(mesh=...) reads off its mesh
+    on_tpu._paged_kernels.clear()
+    assert on_tpu.paged_attention_kernel(leaf(128)) is False
+    assert on_tpu.stats["paged_attention"] == "plain"
+    assert on_tpu.paged_attention_chunk(16) == 4096

@@ -333,6 +333,32 @@ def test_kv_token_steps_equals_a_hand_count():
     assert stats["decode_tokens"] == 3 + 2
 
 
+@pytest.mark.parametrize("chunk", [None, 4], ids=["whole_table", "chunks"])
+def test_kv_read_token_steps_follow_what_the_engine_says_it_reads(chunk):
+    """The same two requests as above. An engine that says nothing of
+    its read (the fakes, the speculative window) reads `max_seq_len` a
+    slot-step; one that reads live chunks (`paged_attention_chunk`: the
+    kernel) reads each slot's length and this token's own row, rounded
+    up to the chunk."""
+    engine = FakePagedEngine()
+    asked = []
+    if chunk:
+        engine.paged_attention_chunk = \
+            lambda block_size: asked.append(block_size) or chunk
+    scheduler = fake_scheduler(engine, max_slots=2, prefix_cache_capacity=0)
+    a = scheduler.submit([1, 2, 3, 4, 5, 6], SamplingParams(max_new_tokens=3))
+    b = scheduler.submit([7, 8, 9], SamplingParams(max_new_tokens=2))
+    _drive(scheduler, [a, b])
+    stats = scheduler.stats()
+    assert stats["kv_token_steps"] == (4 + 5 + 6 + 7) + (0 + 1 + 2 + 3)
+    if not chunk:
+        assert stats["kv_read_token_steps"] == 8 * engine.max_seq_len
+        return
+    assert asked and set(asked) == {4}  # the scheduler's block size
+    # Lengths 5, 6, 7, 8 and 1, 2, 3, 4 attended over, up to chunks of 4.
+    assert stats["kv_read_token_steps"] == (8 + 8 + 8 + 8) + (4 + 4 + 4 + 4)
+
+
 def test_slow_steps_are_counted_with_their_launch_and_sync():
     engine = FakePagedEngine()
     real_step = engine.paged_step
@@ -385,6 +411,12 @@ def test_debug_profile_writes_an_xplane_and_refuses_a_second(tmp_path,
     server.start()
     results = {}
     try:
+        # Compiled before the capture starts: the 1.5 s window is for
+        # model steps, and a cold step program can take longer than that.
+        status, _headers, _raw = _post(
+            server.port, {"prompt": [1, 2, 3], "max_new_tokens": 2})
+        assert status == 200
+
         def capture():
             results["first"] = _post_json(
                 server.port, "/debug/profile", {"seconds": 1.5})

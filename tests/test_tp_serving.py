@@ -256,6 +256,10 @@ def test_tp_paged_greedy_prefix_hit_and_replay_match_legacy():
         stats = scheduler.stats()
         assert stats["prefix_cache"]["hits"] >= 1
         assert stats["tp_degree"] == 2
+        # A sharded pool is read by the plain gather, whole tables a step.
+        assert stats["decode_engine"]["paged_attention"] == "plain"
+        assert stats["kv_read_token_steps"] == \
+            stats["slot_steps"] * model.config.max_seq_len
         # ONE greedy paged step program for the whole run — tick-to-tick
         # table changes never recompiled under the mesh either (the
         # engine is module-shared: another test's sampling config is

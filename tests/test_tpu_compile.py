@@ -1,7 +1,9 @@
 """Every pallas kernel under tf_yarn_tpu/ops/, compiled for a described
 (not attached) TPU v5e at the flagship shapes — d_model 1024, 16 query /
 8 KV heads of 64, batch 8 x seq 1024, a 2048-token cache in 16-token
-blocks. Nothing runs: the chip's own compiler (libtpu is installed on
+blocks — and the one-token paged step's bf16 kernel at the two benchmark
+cells' own shapes (32 / 8 heads of 128, 16 and 32 slots of 4096 tokens).
+Nothing runs: the chip's own compiler (libtpu is installed on
 the CPU rig) either accepts the kernel or raises what the chip would
 raise. Interpret mode cannot see a refused vector layout, a mis-tiled
 block or a kernel that outgrows VMEM; this can, at no chip time.
@@ -145,6 +147,22 @@ def _paged_decode(scale_rows, width=None):
     ]
 
 
+def _paged_bf16_decode(slots):
+    """The one-token paged step's kernel at a benchmark cell's shapes:
+    Mistral's 16 slots and granite's 32, each over its own pool leaf of
+    `slots` x 256 blocks of 16 tokens x 8 KV heads of 128 in bf16 (+ the
+    trash block), tables [slots, 256]."""
+    from tf_yarn_tpu.ops.decode_attention import paged_decode_attention
+
+    heads, kv, dim, table = 32, 8, 128, 4096 // BLOCK
+    pool = ((slots * table + 1, BLOCK, kv, dim), jnp.bfloat16)
+    return functools.partial(
+        paged_decode_attention, kernel=True, interpret=False), [
+        ((slots, heads, dim), jnp.bfloat16), pool, pool,
+        ((slots, table), jnp.int32), ((slots,), jnp.int32),
+    ]
+
+
 KERNELS = {
     "flash_fwd": lambda: _flash(grad=False),
     "flash_fwd_bwd": lambda: _flash(grad=True),
@@ -156,6 +174,8 @@ KERNELS = {
     "paged_decode_row_scales": lambda: _paged_decode(BLOCK),
     "paged_decode_block_scales": lambda: _paged_decode(1),
     "paged_window_w4": lambda: _paged_decode(BLOCK, width=4),
+    "paged_bf16_decode_mistral_16_slots": lambda: _paged_bf16_decode(16),
+    "paged_bf16_decode_granite_32_slots": lambda: _paged_bf16_decode(32),
 }
 
 

@@ -639,6 +639,7 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._peak_streams", _ADVISORY),
                 ("scheduler._prefilled_tokens", _ADVISORY),
                 ("scheduler._kv_token_steps", _ADVISORY),
+                ("scheduler._kv_read_token_steps", _ADVISORY),
                 ("scheduler._slot_steps", _ADVISORY),
                 ("scheduler._slow_steps", _ADVISORY),
                 ("scheduler._slow_step_seconds", _ADVISORY),
@@ -656,6 +657,7 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._peak_streams", _ADVISORY),
                 ("scheduler._prefilled_tokens", _ADVISORY),
                 ("scheduler._kv_token_steps", _ADVISORY),
+                ("scheduler._kv_read_token_steps", _ADVISORY),
                 ("scheduler._slot_steps", _ADVISORY),
                 ("scheduler._slow_steps", _ADVISORY),
                 ("scheduler._slow_step_seconds", _ADVISORY),

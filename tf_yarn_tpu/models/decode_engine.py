@@ -52,14 +52,17 @@ top_k / top_p are baked into the traced program) key the cache.
 
 * **Paged KV slots.** The serving grid keeps its KV in ONE global pool
   of fixed-size blocks (`make_paged_pool`) plus a per-slot block table.
-  The compiled `paged_step` gathers each slot's contiguous cache view
-  from the pool by its block table, runs the model's batch-1 decode step
-  on it, and scatter-appends the new K/V row into the slot's current
-  block — all inside one program, zero host syncs per tick. Because the
-  gathered view holds the values a batch-1 decode cache of that request
-  would (positions beyond a slot's length are masked to exactly-zero
-  weight by the attention mask), the fp path is BIT-IDENTICAL to
-  `generate_legacy`. Free/allocate is host-side free-list
+  The compiled `paged_step` runs the model once over all slots' tokens
+  with the pool as its `kv_pool` collection: each attention layer writes
+  the token's K/V row into the slot's current block and reads the slot's
+  keys and values through its block table
+  (`ops.decode_attention.paged_decode_attention`: on a TPU a kernel that
+  reads the live blocks only, elsewhere and under `tp` a gather of the
+  table + `xla_attention`) — all inside one program, zero host syncs per
+  tick. Positions beyond a slot's length get exactly-zero weight, so the
+  plain read attends over the values a batch-1 decode cache of that
+  request would hold: the fp path emits `generate_legacy`'s tokens.
+  Free/allocate is host-side free-list
   bookkeeping (`serving/paging.py`); there is no per-eviction device
   program at all. `pack_prefill` splices a bucketed-prefill result into
   a slot's blocks; int8 KV composes transparently (the pool stores
@@ -72,9 +75,9 @@ top_k / top_p are baked into the traced program) key the cache.
   given from the end of its shape), held once a ``slot`` (a recurrent
   state, a convolution's tail: an array `[max_slots, ...]` beside the
   pool, `make_slot_state`), or the slot's ``index``. A model with slot
-  leaves is stepped by `paged_state_step`: ONE call of the model over all
-  slots' tokens (`slots=True`; only its attention maps over slots), the
-  state read and written in place, and what the expert layers counted
+  leaves is stepped by `paged_state_step`: the same ONE call of the model
+  over all slots' tokens, the state as its `cache` collection beside the
+  pool, read and written in place, and what the expert layers counted
   returned beside the tokens. `write_slot_state` puts a prefill's final
   state (or zeros) into a slot at admission.
 """
@@ -93,6 +96,7 @@ import numpy as np
 from tf_yarn_tpu import telemetry
 from tf_yarn_tpu.models.generate import _sample
 from tf_yarn_tpu.models.spec import verify_window
+from tf_yarn_tpu.models.transformer import PagedContext
 
 _logger = logging.getLogger(__name__)
 
@@ -370,8 +374,28 @@ def _gather_slot_cache(pool, row_aval, layout, table, length):
         )
 
 
+def _sample_slots(logits, tokens, rngs, sample_mask, temperature: float,
+                  top_k: Optional[int], top_p: Optional[float]):
+    """Every slot's next token from its row of `logits` [S, V]: a
+    masked-off slot consumes no RNG and passes its input token through, so
+    each slot's split chain stays bit-aligned with generate_legacy's
+    one-split-per-sample. -> (emitted [S], rngs)."""
+
+    def sample(row_logits, token, rng, do_sample):
+        next_rng, sample_key = jax.random.split(rng)
+        sampled = _sample(
+            row_logits[None], sample_key, temperature, top_k, top_p
+        )[0]
+        return (jnp.where(do_sample, sampled, token),
+                jnp.where(do_sample, next_rng, rng))
+
+    return jax.vmap(sample)(logits, tokens, rngs, sample_mask)
+
+
 def build_paged_step_fn(model, block_size: int, temperature: float,
-                        top_k: Optional[int], top_p: Optional[float]):
+                        top_k: Optional[int], top_p: Optional[float],
+                        with_logits: bool = False,
+                        paged_kernel: Optional[bool] = None):
     """The paged continuous-batching step, shared by the engine and the
     analysis jaxpr entry point (`models.decode_engine.paged_step`).
 
@@ -379,10 +403,14 @@ def build_paged_step_fn(model, block_size: int, temperature: float,
             -> (pool, emitted [S], rngs)
 
     ONE compiled program advances every slot one token against the
-    global block pool: per slot, gather its batch-1 cache view through
-    its block-table row, run the model's decode step on it and sample,
-    then scatter-append the freshly written K/V row into block
-    `table[length // block_size]` at offset `length % block_size`.
+    global block pool: the model is applied once over the slots' tokens
+    [S, 1] with the pool as its `kv_pool` collection and (tables,
+    lengths) as `paged_ctx`; each attention layer writes the token's K/V
+    row into block `table[length // block_size]` at offset
+    `length % block_size` and attends through the table
+    (`paged_decode_attention`). `paged_kernel` says how the pool is read:
+    None leaves it to the backend and the pool's shape (the kernel on a
+    TPU), False is the plain gather (the engine's choice under `tp`).
     `tokens` [S] are this tick's inputs: a forced prompt token while a
     slot replays its prompt remainder, else the slot's last emitted
     token. `sample_mask` [S] is the traced active mask: masked-off slots
@@ -396,38 +424,27 @@ def build_paged_step_fn(model, block_size: int, temperature: float,
     `tables`/`lengths` are traced values — tick-to-tick table changes
     never recompile. Inactive slots carry an all-zero table row and
     length 0, so their (meaningless) write lands in the reserved trash
-    block 0 and can never corrupt a live slot.
+    block 0 and can never corrupt a live slot. `with_logits` appends the
+    step's logits [S, V] to what is returned (the tests compare the two
+    ways to read the pool on them).
     """
+    del block_size  # the pool's own shape says it
+
     def step(params, pool, tables, lengths, tokens, rngs, sample_mask):
         row_aval = _decode_cache_aval(model, params)
-        layout = cache_layout(model, row_aval)
-        _refuse_slot_state(layout, "paged_step (use paged_state_step)")
-
-        def one_slot(table, length, token, rng, do_sample):
-            cache = _gather_slot_cache(pool, row_aval, layout, table, length)
-            logits, state = model.apply(
-                {**params, "cache": cache}, token[None, None], decode=True,
-                mutable=["cache"],
-            )
-            next_rng, sample_key = jax.random.split(rng)
-            sampled = _sample(
-                logits[:, -1], sample_key, temperature, top_k, top_p
-            )[0]
-            emitted = jnp.where(do_sample, sampled, token)
-            rng = jnp.where(do_sample, next_rng, rng)
-
-            with jax.named_scope("attention/kv_write"):
-                rows = _new_rows(state["cache"], layout, length, 1)
-            return emitted, rng, rows
-
-        emitted, rngs, rows = jax.vmap(
-            one_slot, in_axes=(0, 0, 0, 0, 0)
-        )(tables, lengths, tokens, rngs, sample_mask)
-        with jax.named_scope("attention/kv_write"):
-            pool_out = _append_rows(
-                pool, rows, layout, tables, lengths, block_size
-            )
-        return pool_out, emitted, rngs
+        _refuse_slot_state(cache_layout(model, row_aval),
+                           "paged_step (use paged_state_step)")
+        logits, new = model.apply(
+            {**params, "kv_pool": _prune_none_tree(pool)}, tokens[:, None],
+            decode=True,
+            paged_ctx=PagedContext(tables, lengths, paged_kernel),
+            mutable=["kv_pool"],
+        )
+        emitted, rngs = _sample_slots(
+            logits[:, -1], tokens, rngs, sample_mask, temperature, top_k,
+            top_p)
+        out = (_merge_pool_tree(pool, dict(new["kv_pool"])), emitted, rngs)
+        return out + (logits[:, -1],) if with_logits else out
 
     return step
 
@@ -456,36 +473,10 @@ def _new_rows(cache, layout, length, width: int):
     return jax.tree_util.tree_map(leaf, cache, layout)
 
 
-def _append_rows(pool, rows, layout, tables, lengths, block_size: int):
-    """Scatter every slot's one new row (`rows`: `_new_rows` under a map
-    over slots) into block `table[length // block_size]` at offset
-    `length % block_size` of each pool leaf."""
-    slots = tables.shape[0]
-
-    def write(pool_leaf, slot_rows, lay):
-        if pool_leaf is None:
-            return None
-        ax = lay.axis
-        for s in range(slots):
-            block = tables[s, lengths[s] // block_size]
-            offset = lengths[s] % block_size
-            update = jnp.expand_dims(slot_rows[s], ax)
-            starts = [jnp.asarray(0, jnp.int32)] * pool_leaf.ndim
-            starts[ax] = block
-            starts[ax + 1] = offset
-            pool_leaf = jax.lax.dynamic_update_slice(
-                pool_leaf, update.astype(pool_leaf.dtype), tuple(starts)
-            )
-        return pool_leaf
-
-    return jax.tree_util.tree_map(
-        write, pool, rows, layout, is_leaf=_is_none
-    )
-
-
 def build_paged_state_step_fn(model, block_size: int, temperature: float,
                               top_k: Optional[int], top_p: Optional[float],
-                              with_logits: bool = False):
+                              with_logits: bool = False,
+                              paged_kernel: Optional[bool] = None):
     """The paged step of a model that also holds state once a slot
     (`cache_layout`: `slot` leaves — a recurrent state, a convolution's
     tail):
@@ -493,14 +484,13 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
         fn(params, pool, state, tables, lengths, tokens, rngs, sample_mask)
             -> (pool, state, emitted [S], rngs, counts)
 
-    The model is called ONCE, over all slots' tokens together
-    (`slots=True`: tokens [S, 1], every cache leaf with a leading slot
-    axis), so that its expert layers see the step's tokens as one batch;
-    only its attention maps over slots, around the cache view gathered
-    from the pool. `state` holds the slot leaves as `[S, ...]` arrays
-    (None elsewhere); they are read and written in place (donated). A
-    free slot runs along on whatever its state holds and writes its row to
-    the trash block; admission overwrites both (`write_slot_state`).
+    `build_paged_step_fn`'s one call of the model over all slots' tokens
+    (so that its expert layers see the step's tokens as one batch), with
+    the state as the model's `cache` collection beside the pool. `state`
+    holds the slot leaves as `[S, ...]` arrays (None elsewhere); they are
+    read and written in place (donated). A free slot runs along on
+    whatever its state holds and writes its row to the trash block;
+    admission overwrites both (`write_slot_state`).
     `counts` stacks what the model's layers counted into `moe_stats` for
     the active slots (table row not all trash) — `[layers, 1 + held
     experts]`: assignments, then tokens that reached each held expert —
@@ -508,59 +498,26 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
     `build_paged_step_fn`'s. `with_logits` appends the step's logits [S, V]
     to what is returned (the tests compare them with a reference).
     """
+    del block_size  # the pool's own shape says it
 
     def step(params, pool, state, tables, lengths, tokens, rngs, sample_mask):
-        row_aval = _decode_cache_aval(model, params)
-        layout = cache_layout(model, row_aval)
-
-        def view(lay, pool_leaf, state_leaf, aval):
-            if lay.kind == SLOT:
-                return state_leaf
-            if lay.kind == INDEX:
-                return jax.vmap(
-                    lambda length: jnp.full(aval.shape, length, aval.dtype)
-                )(lengths)
-            return jax.vmap(
-                lambda table: jnp.take(
-                    pool_leaf, table, axis=lay.axis).reshape(aval.shape)
-            )(tables)
-
-        with jax.named_scope("attention/kv_gather"):
-            cache = jax.tree_util.tree_map(
-                view, layout, pool, state, row_aval
-            )
         active = tables[:, 0] != 0
         logits, new = model.apply(
-            {**params, "cache": cache}, tokens[:, None], decode=True,
-            slots=True, count_mask=active, mutable=["cache", "moe_stats"],
+            {**params, "cache": _prune_none_tree(state),
+             "kv_pool": _prune_none_tree(pool)},
+            tokens[:, None], decode=True, count_mask=active,
+            paged_ctx=PagedContext(tables, lengths, paged_kernel),
+            mutable=["cache", "kv_pool", "moe_stats"],
         )
-
-        def sample(row_logits, token, rng, do_sample):
-            next_rng, sample_key = jax.random.split(rng)
-            sampled = _sample(
-                row_logits[None], sample_key, temperature, top_k, top_p
-            )[0]
-            return (jnp.where(do_sample, sampled, token),
-                    jnp.where(do_sample, next_rng, rng))
-
-        emitted, rngs = jax.vmap(sample)(
-            logits[:, -1], tokens, rngs, sample_mask)
-        with jax.named_scope("attention/kv_write"):
-            rows = jax.vmap(
-                lambda slot_cache, length: _new_rows(
-                    slot_cache, layout, length, 1)
-            )(new["cache"], lengths)
-            pool_out = _append_rows(
-                pool, rows, layout, tables, lengths, block_size
-            )
-        state_out = jax.tree_util.tree_map(
-            lambda lay, value: value if lay.kind == SLOT else None,
-            layout, new["cache"],
-        )
+        emitted, rngs = _sample_slots(
+            logits[:, -1], tokens, rngs, sample_mask, temperature, top_k,
+            top_p)
         counted = jax.tree_util.tree_leaves(new.get("moe_stats", {}))
         counts = jnp.stack(counted) if counted \
             else jnp.zeros((0, 0), jnp.int32)
-        out = (pool_out, state_out, emitted, rngs, counts)
+        out = (_merge_pool_tree(pool, dict(new["kv_pool"])),
+               _merge_pool_tree(state, dict(new["cache"])),
+               emitted, rngs, counts)
         return out + (logits[:, -1],) if with_logits else out
 
     return step
@@ -611,7 +568,7 @@ def _merge_pool_tree(pool, updated):
     if isinstance(pool, dict):
         return {
             key: _merge_pool_tree(
-                value, updated[key] if key in updated else None
+                value, None if updated is None else updated.get(key)
             )
             for key, value in pool.items()
         }
@@ -681,7 +638,8 @@ def build_paged_spec_step_fn(model, block_size: int, width: int,
                             n_known, eos_ids, rngs, active):
             logits, state = model.apply(
                 {**params, "kv_pool": _prune_none_tree(pool)},
-                tokens, decode=True, paged_ctx=(tables, lengths),
+                tokens, decode=True,
+                paged_ctx=PagedContext(tables, lengths, True),
                 mutable=["kv_pool"],
             )
             pool_out = _merge_pool_tree(pool, dict(state["kv_pool"]))
@@ -1072,6 +1030,7 @@ class DecodeEngine:
             "oversize_batch_chunks": 0,
         }
         self._paged_step: Dict[tuple, Any] = {}
+        self._paged_kernels: Dict[int, bool] = {}  # paged_attention_kernel
         self._pack: Dict[tuple, Any] = {}
         self._paged_spec_step: Dict[tuple, Any] = {}
         self._extract: Dict[tuple, Any] = {}
@@ -1443,12 +1402,14 @@ class DecodeEngine:
         tokens; the pool, the state and the rng buffer are donated.
         Returns (pool, state, emitted [S], rngs, counts)."""
         slots = int(jnp.shape(tokens)[0])
+        kernel = self.paged_attention_kernel(pool)
         compiled, args = self._paged_program(
             self._paged_step, "paged_step",
             ("state", slots, tuple(jnp.shape(tables)), block_size,
-             float(temperature), top_k, top_p),
+             float(temperature), top_k, top_p, kernel),
             lambda: build_paged_state_step_fn(
-                self.model, block_size, temperature, top_k, top_p),
+                self.model, block_size, temperature, top_k, top_p,
+                paged_kernel=kernel),
             params, (pool, state),
             ((tables, jnp.int32), (lengths, jnp.int32), (tokens, jnp.int32),
              (rngs, jnp.uint32), (sample_mask, bool)),
@@ -1489,6 +1450,45 @@ class DecodeEngine:
                 ).lower(*args).compile(),
             )
         return compiled, args
+
+    def paged_attention_kernel(self, pool) -> bool:
+        """Which implementation of `paged_decode_attention` the one-token
+        step over `pool` is compiled with, from what can be seen here: the
+        kernel where the backend and the pool's leaves admit it
+        (`paged_kernel_serves`) and the pool lies whole on one device;
+        the plain gather elsewhere (under `tp` XLA shards it over KV
+        heads, and a Pallas call cannot be partitioned). `/stats` names
+        it: `decode_engine.paged_attention`."""
+        from tf_yarn_tpu.ops.decode_attention import paged_kernel_serves
+
+        # Every tick: decided once a pool layout.
+        fp = self._tree_fingerprint(pool)
+        kernel = self._paged_kernels.get(fp)
+        if kernel is None:
+            kernel = self._paged_kernels[fp] = self.tp_degree == 1 and all(
+                paged_kernel_serves(
+                    jax.ShapeDtypeStruct(leaf.shape[-4:], leaf.dtype))
+                for leaf in jax.tree_util.tree_leaves(pool)
+                if leaf.shape[-1] > 1  # a scale leaf follows its values
+            )
+        with self._lock:
+            self.stats["paged_attention"] = "kernel" if kernel else "plain"
+        return kernel
+
+    def paged_attention_chunk(self, block_size: int) -> int:
+        """Tokens at a time the one-token step's attention reads a slot's
+        keys: the kernel's chunk (a slot reads its length rounded up to
+        it), or the whole table's `max_seq_len` on the plain path and
+        under the int8 kernel, whose grid visits every block of the table
+        (`/stats` `kv_read_token_steps`)."""
+        from tf_yarn_tpu.ops.decode_attention import paged_chunk_tokens
+
+        config = self.model.config
+        if self.stats.get("paged_attention") != "kernel" \
+                or getattr(config, "kv_cache_dtype", None) == "int8":
+            return config.max_seq_len
+        return paged_chunk_tokens(
+            block_size, self.max_blocks_per_slot(block_size))
 
     def max_blocks_per_slot(self, block_size: int) -> int:
         """Block-table width: a slot grown to max_seq_len holds exactly
@@ -1609,12 +1609,14 @@ class DecodeEngine:
         table changes never recompile. The pool and the rng buffer are
         donated. Returns (pool, emitted [S], rngs)."""
         slots = int(jnp.shape(tokens)[0])
+        kernel = self.paged_attention_kernel(pool)
         compiled, args = self._paged_program(
             self._paged_step, "paged_step",
             (slots, tuple(jnp.shape(tables)), block_size, float(temperature),
-             top_k, top_p),
+             top_k, top_p, kernel),
             lambda: build_paged_step_fn(
-                self.model, block_size, temperature, top_k, top_p),
+                self.model, block_size, temperature, top_k, top_p,
+                paged_kernel=kernel),
             params, (pool,),
             ((tables, jnp.int32), (lengths, jnp.int32), (tokens, jnp.int32),
              (rngs, jnp.uint32), (sample_mask, bool)),

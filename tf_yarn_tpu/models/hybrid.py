@@ -22,10 +22,11 @@ the last `d_conv - 1` rows of the conv input (`conv_state`) in the cache.
 
 The serving engine is told what each cache leaf is (`cache_leaf_kinds`):
 keys and values are paged by token, `ssm_state` and `conv_state` are held
-once a slot, `cache_index` is the slot's position. `slots=True` is the
-engine's paged step: every cache leaf leads with a slot axis over batch-1
-rows, attention runs once a slot (`nn.vmap` over its cache view), and the
-rest of the model, the experts above all, sees all slots' tokens together.
+once a slot, `cache_index` is the slot's position. A call with `paged_ctx`
+(`transformer.PagedContext`) is the engine's paged step: tokens [slots, 1],
+the state leaves lead with a slot axis over batch-1 rows, attention reads
+and writes the block pool (`kv_pool`) through the slots' tables, and the
+whole model, the experts above all, sees all slots' tokens together.
 """
 
 from __future__ import annotations
@@ -219,8 +220,9 @@ class Mamba2Mixer(nn.Module):
         dt_bias = self.param("dt_bias", nn.initializers.zeros_init(), (heads,), f32)
 
         if self.decode:
-            # One row a batch element, or (slots=True) a leading slot axis
-            # over batch-1 rows: flattened here, restored on the way out.
+            # One row a batch element, or (the paged step) a leading slot
+            # axis over batch-1 rows: flattened here, restored on the way
+            # out.
             state_var = self.variable(
                 "cache", "ssm_state",
                 lambda: jnp.zeros((batch, heads, p, n), f32))
@@ -275,29 +277,18 @@ class HybridBlock(nn.Module):
     config: HybridConfig
     kind: str
     decode: bool = False
-    slots: bool = False
 
     @nn.compact
-    def __call__(self, x, positions, count_mask=None):
+    def __call__(self, x, positions, count_mask=None, paged_ctx=None):
         cfg = self.config
         norm_cfg = cfg.norm_config()
         batch, t, d = x.shape
         normed = RMSNorm(norm_cfg, name="mixer_norm")(x)
         if self.kind == MAMBA:
             mixed = Mamba2Mixer(cfg, self.decode, name="mamba")(normed)
-        elif self.slots:
-            # Each slot attends over its own cache view at its own
-            # position: the per-slot map stays around attention alone.
-            per_slot = nn.vmap(
-                Attention, in_axes=(0, 0), out_axes=0,
-                variable_axes={"params": None, "cache": 0},
-                split_rngs={"params": False},
-            )
-            mixed = per_slot(cfg.attention_config(), self.decode, name="attn")(
-                normed[:, None], positions[:, None])[:, 0]
         else:
             mixed = Attention(cfg.attention_config(), self.decode, name="attn")(
-                normed, positions)
+                normed, positions, paged_ctx)
         x = x + (cfg.residual_multiplier * mixed).astype(x.dtype)
         normed = RMSNorm(norm_cfg, name="moe_norm")(x)
         moe = DroplessMoE(
@@ -314,9 +305,10 @@ class HybridLM(nn.Module):
     """tokens [B, S] int32 -> logits [B, S, vocab] (float32).
 
     `decode=True` keeps the cache (`models/decode_engine.py` drives it);
-    `slots=True` besides is the paged step's call: tokens [slots, 1], every
-    cache leaf with a leading slot axis. `count_mask` [B * S] marks the
-    tokens whose routing the expert layers count (`moe_stats`)."""
+    `paged_ctx` besides is the paged step's call: tokens [slots, 1], the
+    state leaves with a leading slot axis, keys and values in the `kv_pool`
+    collection. `count_mask` [B * S] marks the tokens whose routing the
+    expert layers count (`moe_stats`)."""
 
     config: HybridConfig
 
@@ -327,7 +319,7 @@ class HybridLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True,
                  return_hidden: bool = False, decode: bool = False,
-                 slots: bool = False, count_mask=None):
+                 count_mask=None, paged_ctx=None):
         cfg = self.config
         embedding = self.param(
             "embedding",
@@ -340,8 +332,8 @@ class HybridLM(nn.Module):
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape)
         for index, kind in enumerate(cfg.layer_types):
-            x = HybridBlock(cfg, kind, decode, slots, name=f"layer_{index}")(
-                x, positions, count_mask)
+            x = HybridBlock(cfg, kind, decode, name=f"layer_{index}")(
+                x, positions, count_mask, paged_ctx)
         x = RMSNorm(cfg.norm_config(), name="final_norm")(x)
         if return_hidden:
             return x

@@ -15,6 +15,7 @@ llama recipe.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -118,6 +119,24 @@ CACHE_LEAF_KINDS = {
     "cached_value_scale": ("paged", -3),
     "cache_index": ("index", None),
 }
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["tables", "lengths"], meta_fields=["kernel"],
+)
+@dataclasses.dataclass(frozen=True)
+class PagedContext:
+    """What a paged decode call knows of the slots beside the pool:
+    block `tables` [S, MB] and `lengths` [S] before this call's rows
+    (traced), and which implementation reads a bf16 pool (`kernel`,
+    static: None = `ops.decode_attention.paged_kernel_serves` decides
+    from the backend and the shapes, False = the plain gather a sharded
+    pool needs)."""
+
+    tables: Any
+    lengths: Any
+    kernel: Optional[bool] = None
 
 
 def _partitioned(names):
@@ -232,14 +251,15 @@ class Attention(nn.Module):
             k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
             v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
         if decode and paged_ctx is not None:
-            # Fused paged decode: the serving engine passed the int8 KV
-            # block pool (kv_pool collection) + per-slot (tables,
-            # lengths). Rows are the batch's slots, the s axis the
-            # speculative window; no dense cache variables exist on
-            # this path at all. NOT partitionable: the pallas kernel
-            # reads the whole pool, so tensor-parallel serving refuses
-            # this path at build (DecodeEngine.paged_spec_step).
-            out = self._fused_paged_decode(q, k, v, paged_ctx)
+            # Paged decode: the serving engine passed the KV block pool
+            # (kv_pool collection) + per-slot tables and lengths. Rows
+            # are the batch's slots, the s axis the one token (or the
+            # int8 speculative window); no dense cache variables exist
+            # on this path at all. A Pallas kernel reads the whole pool
+            # and cannot be partitioned: tensor-parallel serving asks
+            # for the plain read (`paged_ctx.kernel=False`) or refuses
+            # (DecodeEngine.paged_spec_step).
+            out = self._paged_decode(q, k, v, paged_ctx)
         elif decode:
             # KV cache for autoregressive decoding: append this call's
             # keys/values at cache_index, attend against the whole cache
@@ -358,55 +378,59 @@ class Attention(nn.Module):
             out = out.reshape(b, s, cfg.n_heads * cfg.head_dim)
             return LoraDense(cfg.d_model, (HEADS, EMBED), cfg, name="wo")(out)
 
-    def _fused_paged_decode(self, q, k, v, paged_ctx):
-        """Decode attention straight off the paged int8 KV pool: rope at
-        per-slot positions, quantize + scatter this window's K/V rows
-        into the pool, then `paged_int8_window_attention` streams the
-        pool block-by-block (tables in SMEM) — no dense per-slot cache
-        view is ever materialized, and no dense cache variables are
+    @nn.nowrap  # a helper of __call__: no scope of its own on the operations
+    def _paged_decode(self, q, k, v, paged_ctx):
+        """Decode attention straight off the paged KV pool: rope at
+        per-slot positions, scatter this call's K/V rows into their
+        blocks, then attend through the block table — no dense per-slot
+        cache view is built here, and no dense cache variables are
         created. The pool travels as the mutable ``kv_pool`` collection
         (per layer; elided index leaves stay host-side as the engine's
         ``lengths``); tables/lengths ride as the ``paged_ctx`` call
-        argument, broadcast across layers."""
+        argument, broadcast across layers.
+
+        One token a slot is read by `paged_decode_attention` (a kernel
+        that reads each slot's live blocks, or the plain gather, as
+        `paged_ctx.kernel` and the backend say); the int8 speculative
+        window by `paged_int8_window_attention`."""
         cfg = self.config
-        if not cfg.use_rope or cfg.attention_scale is not None:
+        if cfg.kv_cache_dtype not in ("bf16", "int8"):
+            raise ValueError(
+                f"kv_cache_dtype={cfg.kv_cache_dtype!r}: expected "
+                "'bf16' or 'int8'"
+            )
+        int8_pool = cfg.kv_cache_dtype == "int8"
+        if int8_pool and (not cfg.use_rope or cfg.attention_scale is not None):
             raise NotImplementedError(
-                "the fused paged decode path applies rope and scales by "
+                "the int8 paged decode kernel applies rope and scales by "
                 "head_dim**-0.5; use_rope=False / attention_scale need "
+                "kv_cache_dtype='bf16'"
+            )
+        tables, lengths = paged_ctx.tables, paged_ctx.lengths
+        slots, width = q.shape[0], q.shape[1]
+        if not int8_pool and width != 1:
+            raise NotImplementedError(
+                "a bf16 pool is read one token a slot "
+                f"(paged_decode_attention); a window of {width} needs "
                 "decode_attention='gather'"
             )
-        if cfg.kv_cache_dtype != "int8":
-            raise ValueError(
-                "the fused paged decode path reads an int8 pool "
-                "(paged_int8_window_attention); it requires "
-                "kv_cache_dtype='int8'"
-            )
-        from tf_yarn_tpu.ops.decode_attention import (
-            paged_int8_window_attention,
-        )
-        from tf_yarn_tpu.ops.quantize import quantize_int8
-
-        tables, lengths = paged_ctx
-        slots, width = q.shape[0], q.shape[1]
         positions = (
             lengths[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
         )
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-        k_q, k_s = quantize_int8(k.astype(jnp.float32))
-        v_q, v_s = quantize_int8(v.astype(jnp.float32))
+        if cfg.use_rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
 
         def _missing():
             raise ValueError(
-                "fused paged decode needs the kv_pool collection "
-                "(DecodeEngine.paged_spec_step with "
-                "decode_attention='fused' provides it)"
+                "paged decode needs the kv_pool collection (the engine's "
+                "paged step provides it)"
             )
 
+        names = ("cached_key", "cached_value") + (
+            ("cached_key_scale", "cached_value_scale") if int8_pool else ())
         pool_vars = {
-            name: self.variable("kv_pool", name, _missing)
-            for name in ("cached_key", "cached_value",
-                         "cached_key_scale", "cached_value_scale")
+            name: self.variable("kv_pool", name, _missing) for name in names
         }
         block_size = pool_vars["cached_key"].value.shape[2]
         max_blocks = tables.shape[1]
@@ -419,9 +443,11 @@ class Attention(nn.Module):
         blocks = jnp.where(logical < max_blocks, blocks, 0).reshape(-1)
         offsets = (positions % block_size).reshape(-1)
 
-        def scatter(var, rows):
+        def scatter(name, rows):
             # Pool leaves keep the slot-row cache's vestigial batch-1
-            # axis: [1, NB, bs, Hkv, *].
+            # axis: [1, NB, bs, Hkv, *]. The leaf is donated: the rows
+            # are written in place.
+            var = pool_vars[name]
             with jax.named_scope("attention/kv_write"):
                 pool = var.value[0]
                 rows = rows.reshape((slots * width,) + rows.shape[2:])
@@ -429,13 +455,29 @@ class Attention(nn.Module):
                 var.value = pool[None]
                 return pool
 
-        key_pool = scatter(pool_vars["cached_key"], k_q)
-        value_pool = scatter(pool_vars["cached_value"], v_q)
-        key_scale = scatter(pool_vars["cached_key_scale"], k_s)
-        value_scale = scatter(pool_vars["cached_value_scale"], v_s)
-        return paged_int8_window_attention(
-            q, key_pool, key_scale, value_pool, value_scale, tables,
-            lengths,
+        from tf_yarn_tpu.ops import decode_attention
+
+        if not int8_pool:
+            key_pool = scatter("cached_key", k)
+            value_pool = scatter("cached_value", v)
+            scales = {}
+        else:
+            from tf_yarn_tpu.ops.quantize import quantize_int8
+
+            k_q, k_s = quantize_int8(k.astype(jnp.float32))
+            v_q, v_s = quantize_int8(v.astype(jnp.float32))
+            key_pool = scatter("cached_key", k_q)
+            value_pool = scatter("cached_value", v_q)
+            scales = dict(key_scale=scatter("cached_key_scale", k_s),
+                          value_scale=scatter("cached_value_scale", v_s))
+        if width == 1:
+            return decode_attention.paged_decode_attention(
+                q[:, 0], key_pool, value_pool, tables, lengths + 1,
+                cfg.attention_scale, kernel=paged_ctx.kernel, **scales,
+            )[:, None]
+        return decode_attention.paged_int8_window_attention(
+            q, key_pool, scales["key_scale"], value_pool,
+            scales["value_scale"], tables, lengths,
         )
 
 
@@ -511,8 +553,8 @@ def _make_scanned(cfg: TransformerConfig):
     """
     return nn.scan(
         _ScanBody,
-        # kv_pool: the fused paged decode path's per-layer KV block pool
-        # slice (absent everywhere else — an empty collection is free).
+        # kv_pool: the paged decode path's per-layer KV block pool slice
+        # (absent everywhere else — an empty collection is free).
         variable_axes={"params": 0, "intermediates": 0, "cache": 0,
                        "kv_pool": 0},
         split_rngs={"params": True, "dropout": True},
@@ -545,10 +587,10 @@ class Transformer(nn.Module):
                  return_hidden: bool = False, decode: bool = False,
                  paged_ctx=None):
         # deterministic accepted for loss-contract uniformity (this
-        # decoder family carries no dropout). `paged_ctx` = (block
-        # tables [S, MB], lengths [S]) switches decode attention onto
-        # the fused paged path (Attention._fused_paged_decode): rows
-        # are serving slots, the kv_pool collection holds the int8
+        # decoder family carries no dropout). `paged_ctx` (a
+        # `PagedContext`: block tables [S, MB], lengths [S]) switches
+        # decode attention onto the paged path (Attention._paged_decode):
+        # rows are serving slots, the kv_pool collection holds the
         # block pool.
         cfg = self.config
         embedding = self.param(

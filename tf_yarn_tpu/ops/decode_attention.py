@@ -32,6 +32,14 @@ streams block-by-block with NO gather materializing a dense per-slot
 cache first. Scales may be per-row ([NB, bs, Hkv, 1]) or per-BLOCK
 ([NB, 1, Hkv, 1], from `quantize_int8_grouped(group_rows=block_size)`)
 — the per-block layout cuts scale storage/stream by the block size.
+
+``paged_decode_attention`` is the one-token step's read of the pool as
+the serving engine holds it: one op, two ways to execute it. The plain
+one gathers every slot's whole table into a dense view and runs the dense
+cache's attention on it; the kernel, for the bf16 pool (no scales), walks
+each slot's table a chunk of pages at a time with double-buffered DMAs
+and stops at the slot's length, so its work grows with the live blocks
+and no view is ever built (an int8 pool goes to the kernel above).
 """
 
 from __future__ import annotations
@@ -419,3 +427,289 @@ def paged_int8_window_attention(
         softmax_scale=softmax_scale, interpret=interpret,
     )
     return out.reshape(slots, width, n_heads, head_dim)
+
+
+# --------------------------------------------------------------------------
+# The one-token paged step's attention over the bf16 pool
+# --------------------------------------------------------------------------
+
+# Pages a chunk: one loop trip DMAs this many blocks of K and of V and
+# contracts them in one pair of matrix products. 8 pages of 16 tokens are
+# 128 keys a trip; a slot reads its length rounded up to that.
+PAGES_PER_CHUNK = 8
+
+
+def paged_chunk_tokens(block_size: int, max_blocks: int) -> int:
+    """Tokens the kernel reads a loop trip: a slot's read is its length
+    rounded up to a multiple of this."""
+    return min(PAGES_PER_CHUNK, max_blocks) * block_size
+
+
+def paged_kernel_serves(key_pool) -> bool:
+    """Whether a kernel reads this pool leaf [NB, bs, Hkv, D] here: on a
+    TPU (`_rowwise.default_interpret`'s rule), and for a bf16 pool where
+    one page's [bs * Hkv, D] rows tile the chip's vector registers whole
+    (lanes of 128, bf16 rows in pairs of 8). Elsewhere the plain
+    implementation serves. A pool sharded over devices is the caller's to
+    refuse: a Pallas call cannot be partitioned."""
+    from tf_yarn_tpu.ops._rowwise import default_interpret
+
+    _, block_size, n_kv, head_dim = key_pool.shape
+    if default_interpret():
+        return False
+    if key_pool.dtype == jnp.int8:
+        return True
+    return (
+        key_pool.dtype == jnp.bfloat16
+        and head_dim % 128 == 0
+        and (block_size * n_kv) % 16 == 0
+    )
+
+
+def _paged_plain(query, key_pool, value_pool, tables, lengths, softmax_scale,
+                 key_scale, value_scale):
+    """Every slot's whole table gathered into a dense [S, MB * bs, Hkv, *]
+    view, then the dense cache's attention a slot at its length
+    (`xla_attention` with the causal mask there; `int8_decode_attention`
+    where the pool is int8): the arithmetic the paged step had before the
+    kernels, bit for bit."""
+    from tf_yarn_tpu.ops.attention import xla_attention
+
+    slots = query.shape[0]
+    seq = tables.shape[1] * key_pool.shape[1]
+
+    def view(pool):
+        return jnp.take(pool, tables, axis=0).reshape(
+            (slots, seq) + pool.shape[2:])
+
+    with jax.named_scope("attention/kv_gather"):
+        keys, values = view(key_pool), view(value_pool)
+        if key_scale is not None:
+            key_scale, value_scale = view(key_scale), view(value_scale)
+
+    if key_scale is None:
+        def one_slot(q, k, v, length):
+            return xla_attention(
+                q[None, None], k[None], v[None], causal=True,
+                segment_offset=length - 1, softmax_scale=softmax_scale,
+            )[0, 0]
+
+        out = jax.vmap(one_slot)(query, keys, values, lengths)
+        # A slot with nothing to attend to: the softmax over an all-masked
+        # row is uniform over garbage. Zeros, as the kernels give.
+        return jnp.where((lengths > 0)[:, None, None], out,
+                         jnp.zeros((), out.dtype))
+
+    def one_slot_int8(q, k, ks, v, vs, length):
+        return int8_decode_attention(
+            q[None], k[None], ks[None], v[None], vs[None], length,
+            softmax_scale=softmax_scale,
+        )[0]
+
+    return jax.vmap(one_slot_int8)(
+        query, keys, key_scale, values, value_scale, lengths)
+
+
+def _paged_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, *, pages: int, max_blocks: int,
+                  block_size: int, n_kv: int, group: int,
+                  softmax_scale: float):
+    """Grid (slots,). One program is one slot: a loop over its live chunks
+    of `pages` pages, the next chunk's DMAs in flight while this one is
+    contracted.
+
+    Refs: tables [S * MB] and lengths [S] in SMEM; q, o (1, H, D) in VMEM;
+    k_hbm, v_hbm the pools [NB, bs * Hkv, D] where they live (one page =
+    rows `token * Hkv + head`, contiguous); k_buf, v_buf
+    (2, pages * bs * Hkv, D); sems (2, 2) = (K | V, buffer).
+
+    All heads ride one matrix product: q [H, D] against a chunk's rows
+    [pages * bs * Hkv, D] gives a logit for every (query head, token, KV
+    head); the columns of a foreign KV head are masked like dead
+    positions, so the second product sums over a head's own keys only.
+    Seven eighths of the first product are thrown away and it is still
+    a few microseconds: slicing one head's rows out of the page would
+    be a sublane gather a token.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    slot = pl.program_id(0)
+    length = lengths_ref[slot]
+    rows = block_size * n_kv                  # rows a page
+    chunk_tokens = pages * block_size
+    n_chunks = (length + chunk_tokens - 1) // chunk_tokens
+
+    def copies(chunk, buf):
+        first = slot * max_blocks + chunk * pages
+        out = []
+        for i in range(pages):
+            page = tables_ref[first + i]
+            dst = pl.ds(i * rows, rows)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[page], k_buf.at[buf, dst], sems.at[0, buf]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[page], v_buf.at[buf, dst], sems.at[1, buf]))
+        return out
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        for copy in copies(0, 0):
+            copy.start()
+
+    n_heads, head_dim = q_ref.shape[1], q_ref.shape[2]
+    q = q_ref[0]
+    width = pages * rows
+    # What a column of the logits is: (token of the chunk, KV head).
+    col = lax.broadcasted_iota(jnp.int32, (n_heads, width), 1)
+    own_head = (col % n_kv) == (
+        lax.broadcasted_iota(jnp.int32, (n_heads, width), 0) // group)
+    col_token = col // n_kv
+    row_token = lax.broadcasted_iota(jnp.int32, (width, 1), 0) // n_kv
+
+    def body(chunk, carry):
+        m_prev, l_prev, acc = carry
+        buf = chunk % 2
+
+        @pl.when(chunk + 1 < n_chunks)
+        def _next():
+            for copy in copies(chunk + 1, 1 - buf):
+                copy.start()
+
+        for copy in copies(chunk, buf):
+            copy.wait()
+        left = length - chunk * chunk_tokens  # live tokens from here on
+        logits = lax.dot_general(
+            q, k_buf[buf], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * softmax_scale                         # [H, width]
+        logits = jnp.where(own_head & (col_token < left), logits, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.exp(logits - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        # Dead rows of V are zeroed, not only weighted by p's exact 0:
+        # what a dead block holds may be NaN, and 0 * NaN is NaN.
+        v = v_buf[buf]
+        v = jnp.where(row_token < left, v, jnp.zeros((), v.dtype))
+        acc = acc * corr + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l_new, acc
+
+    # Every chunk the loop visits holds a live token of every head, so
+    # m is finite from the first trip on and exp(m_prev - m_new) never
+    # sees inf - inf.
+    _, l, acc = lax.fori_loop(0, n_chunks, body, (
+        jnp.full((n_heads, 1), NEG_INF, jnp.float32),
+        jnp.zeros((n_heads, 1), jnp.float32),
+        jnp.zeros((n_heads, head_dim), jnp.float32),
+    ))
+    # A slot of length 0 never entered the loop: zeros.
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def paged_decode_attention(
+    query: jax.Array,
+    key_pool: jax.Array,
+    value_pool: jax.Array,
+    block_tables: jax.Array,
+    lengths: jax.Array,
+    softmax_scale: Optional[float] = None,
+    *,
+    key_scale: Optional[jax.Array] = None,
+    value_scale: Optional[jax.Array] = None,
+    kernel: Optional[bool] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """One token a slot against the paged pool, through the block table.
+
+    query [S, H, D], pools [NB, bs, Hkv, D] (K and V: bf16 as the engine
+    holds them, or int8 with `key_scale` / `value_scale` [NB, bs, Hkv, 1]),
+    block_tables [S, MB] int32 (physical block of each logical block;
+    entries past a slot's length may point anywhere valid), lengths [S]
+    int32 (positions each slot attends over, this token's own row
+    included: the caller wrote it first) -> [S, H, D] in `query`'s dtype.
+    Softmax in f32; query head h reads KV head h // (H // Hkv); positions
+    >= length get exactly zero weight whatever the block holds; a slot of
+    length 0 returns zeros.
+
+    `kernel` picks the implementation: None lets `paged_kernel_serves`
+    decide from the backend and the pool, False is the plain gather + the
+    dense cache's attention (what a sharded pool needs), True the Pallas
+    kernel that walks the table (`_paged_kernel`; for an int8 pool
+    `paged_int8_decode_attention`), in interpret mode off the TPU unless
+    `interpret` says otherwise."""
+    slots, n_heads, head_dim = query.shape
+    nb, block_size, n_kv, _ = key_pool.shape
+    max_blocks = block_tables.shape[1]
+    if query.size == 0 or max_blocks == 0:
+        return jnp.zeros(query.shape, query.dtype)
+    if softmax_scale is None:
+        softmax_scale = head_dim**-0.5
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32).reshape((slots,))
+    if kernel is None:
+        kernel = paged_kernel_serves(key_pool)
+    if not kernel:
+        return _paged_plain(query, key_pool, value_pool, block_tables,
+                            lengths, softmax_scale, key_scale, value_scale)
+    if key_scale is not None:
+        return paged_int8_decode_attention(
+            query, key_pool, key_scale, value_pool, value_scale,
+            block_tables, lengths, softmax_scale=softmax_scale,
+            interpret=interpret,
+        )
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        from tf_yarn_tpu.ops._rowwise import default_interpret
+
+        interpret = default_interpret()
+    pages = min(PAGES_PER_CHUNK, max_blocks)
+    if max_blocks % pages:
+        # Whole chunks only: the padding points at block 0, past every
+        # slot's length.
+        pad = pages - max_blocks % pages
+        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
+        max_blocks += pad
+    rows = block_size * n_kv
+    body = functools.partial(
+        _paged_kernel, pages=pages, max_blocks=max_blocks,
+        block_size=block_size, n_kv=n_kv, group=n_heads // n_kv,
+        softmax_scale=softmax_scale,
+    )
+    q_spec = pl.BlockSpec((1, n_heads, head_dim),
+                          lambda si, tables, lengths: (si, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # tables (flat: SMEM pads 2-D rows), lengths
+        grid=(slots,),
+        in_specs=[
+            q_spec,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, pages * rows, head_dim), key_pool.dtype),
+            pltpu.VMEM((2, pages * rows, head_dim), value_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    with jax.named_scope("attention/paged_kernel"):
+        # [NB, bs, Hkv, D] -> [NB, bs * Hkv, D]: the leaf's bytes as they
+        # lie (rows of D in tiles of 8), not a copy.
+        return pl.pallas_call(
+            body,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(query.shape, query.dtype),
+            interpret=interpret,
+            name="paged_decode_attention",
+            compiler_params=(
+                None if interpret
+                else pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+            ),
+        )(block_tables.reshape(-1), lengths, query,
+          key_pool.reshape(nb, rows, head_dim),
+          value_pool.reshape(nb, rows, head_dim))

@@ -376,6 +376,7 @@ class SlotScheduler:
         # Work counters where the work happens (/stats, docs/Serving.md).
         self._prefilled_tokens = 0
         self._kv_token_steps = 0
+        self._kv_read_token_steps = 0
         self._slot_steps = 0
         self._step_seconds: Deque[float] = collections.deque(
             maxlen=SLOW_STEP_WINDOW)
@@ -1405,7 +1406,6 @@ class SlotScheduler:
         # (the host waits for the device), the per-slot bookkeeping
         # after it (the device waits for the host).
         with telemetry.span("serving/step_launch") as launch_span:
-            self._count_step(active)
             tokens = np.zeros((self.max_slots,), np.int32)
             mask = np.zeros((self.max_slots,), bool)
             for slot in active:
@@ -1440,6 +1440,10 @@ class SlotScheduler:
             for result in (emitted, rngs, counts):
                 if hasattr(result, "copy_to_host_async"):  # a device array
                     result.copy_to_host_async()
+            # After the call: the engine then knows which implementation
+            # the step was compiled with.
+            chunk = getattr(self.engine, "paged_attention_chunk", None)
+            self._count_step(active, chunk and chunk(self._block_size))
         with telemetry.span("serving/step_sync") as sync_span:
             # The tick's one host sync: every slot's token in one transfer.
             emitted = np.asarray(emitted)
@@ -1569,14 +1573,21 @@ class SlotScheduler:
             prompt_tokens=len(request.prompt), slot=slot, **parts,
         )
 
-    def _count_step(self, active: List[int]) -> None:
-        """Before a model step: what it will read. `kv_token_steps` over
+    def _count_step(self, active: List[int], chunk=None) -> None:
+        """Around a model step: what it reads. `kv_token_steps` over
         `slot_steps` is the mean live KV length a slot-step attends
-        over, against the `max_seq_len` the gathered view holds."""
+        over; `kv_read_token_steps` over `kv_token_steps` is how much of
+        what the step's attention read was live: each slot reads what it
+        holds and this step's own row, rounded up to `chunk` tokens
+        (`DecodeEngine.paged_attention_chunk`: the kernel's loop trip;
+        None = the gathered view's whole `max_seq_len`, as the
+        speculative window and the plain one-token read take it)."""
+        chunk = chunk or self._max_seq_len
         self._slot_steps += len(active)
-        self._kv_token_steps += sum(
-            self._slots[slot].kv_len for slot in active
-        )
+        for slot in active:
+            kv_len = self._slots[slot].kv_len
+            self._kv_token_steps += kv_len
+            self._kv_read_token_steps += -(-(kv_len + 1) // chunk) * chunk
 
     def _note_step_seconds(self, seconds: float, tick: int) -> None:
         """Single model steps of many times the usual length decide
@@ -1900,6 +1911,7 @@ class SlotScheduler:
             "decode_tokens": self._decode_tokens,
             "prefilled_tokens": self._prefilled_tokens,
             "kv_token_steps": self._kv_token_steps,
+            "kv_read_token_steps": self._kv_read_token_steps,
             "slot_steps": self._slot_steps,
             "slow_steps": self._slow_steps,
             "slow_step_seconds": round(self._slow_step_seconds, 6),

@@ -19,6 +19,10 @@ Nothing runs, so nothing here is a result or a time of the device. Programs:
             max_slots=8, block 16: the one-token step over a bf16 and an
             int8 pool, the speculative window's fused int8 step
     tp4     prefill and paged step of a tp=4 replica (bf16, plain read)
+    latent  the benchmark's dots3_note share at its own sizes
+            (cellbench/configs/dots3_note_serve_1chip.json): prefill at the
+            2048 and 4096 buckets, pack, and the 64-slot paged state step
+            (latent and index rows off the pool, rings held once a slot)
 
 Each line: how many Mosaic kernels (`tpu_custom_call`) and collectives the
 compiler emitted, and the bytes one device needs (temporaries + arguments).
@@ -211,6 +215,70 @@ def compile_serving(name, devices, kv_cache_dtype, attention, tp) -> None:
     report(f"{name} paged step", compiled, began, int8)
 
 
+def compile_latent(devices) -> None:
+    """The latent-attention share as the benchmark serves it: the expanded
+    prefill's peak (no [heads, bucket, context] float32 array) and the
+    one-token step's (no [slots, context, ...] view of a leaf) are what the
+    compiler has to fit beside 5.2 GB of weights and 1.3 GB of cache."""
+    import json
+
+    from cellbench import agent
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "cellbench", "configs",
+                           "dots3_note_serve_1chip.json")) as fh:
+        sizes = json.load(fh)
+    model = agent.build_model(sizes)
+    slots = sizes["serving"]["max_slots"]
+    one = jax.sharding.SingleDeviceSharding(devices[0])
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: None if a is None else jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one), tree, is_leaf=_is_none)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = place(jax.eval_shape(
+        lambda r, t: nn.meta.unbox(model.init(r, t)),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((1, 8), jnp.int32)))
+    prefill = de.build_prefill_fn(model)
+    row = jax.eval_shape(prefill, params, arg(jnp.int32, 1, 1))[0]
+    layout = de.cache_layout(model, row)
+    max_blocks = model.config.max_seq_len // BLOCK
+    pool = place(de.paged_pool_avals(model, row, slots * max_blocks + 1, BLOCK))
+    state = place(jax.tree_util.tree_map(
+        lambda a, lay: jax.ShapeDtypeStruct((slots,) + a.shape, a.dtype)
+        if lay.kind in de.HELD_A_SLOT else None, row, layout))
+    for bucket in (2048, 4096):
+        began = time.monotonic()
+        prompt = arg(jnp.int32, 1, bucket)
+        compiled = jax.jit(prefill).lower(params, prompt).compile()
+        report(f"latent prefill[{bucket}]", compiled, began, False)
+    began = time.monotonic()
+    compiled = jax.jit(
+        de.build_pack_prefill_fn(model, BLOCK, 2048), donate_argnums=(0,),
+    ).lower(pool, arg(jnp.int32, 2048 // BLOCK),
+            place(jax.eval_shape(prefill, params,
+                                 arg(jnp.int32, 1, 2048))[0])).compile()
+    report("latent pack[2048]", compiled, began, False)
+    began = time.monotonic()
+    compiled = jax.jit(
+        de.build_paged_state_step_fn(model, BLOCK, 0.0, None, None),
+        donate_argnums=(1, 2, 6),
+    ).lower(params, pool, state, arg(jnp.int32, slots, max_blocks),
+            arg(jnp.int32, slots), arg(jnp.int32, slots),
+            arg(jnp.uint32, slots, 2), arg(jnp.bool_, slots)).compile()
+    report("latent paged state step", compiled, began, False)
+    context = model.config.max_seq_len
+    views = re.findall(rf"\[{slots},{context},\d+\]", compiled.as_text())
+    if views:
+        raise AssertionError(
+            f"the step builds a [slots, context, ...] array: {set(views)}")
+
+
 def main(argv) -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     # The code under test asks jax.default_backend(), sees this host's CPU
@@ -231,6 +299,7 @@ def main(argv) -> int:
                                          "fused", 1),
         "tp4": lambda: compile_serving("tp=4 bf16", four, "bf16",
                                        "gather", 4),
+        "latent": lambda: compile_latent(one),
     }
     for name in argv or list(programs):
         programs[name]()

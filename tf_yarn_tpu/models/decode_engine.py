@@ -74,10 +74,15 @@ top_k / top_p are baked into the traced program) key the cache.
   token (keys, values: the pool above, with the leaf's sequence axis
   given from the end of its shape), held once a ``slot`` (a recurrent
   state, a convolution's tail: an array `[max_slots, ...]` beside the
-  pool, `make_slot_state`), or the slot's ``index``. A model with slot
-  leaves is stepped by `paged_state_step`: the same ONE call of the model
-  over all slots' tokens, the state as its `cache` collection beside the
-  pool, read and written in place, and what the expert layers counted
+  pool, `make_slot_state`), a ``ring`` (a window layer's last rows, row
+  `p % len` holding position p: held once a slot like state, and named
+  apart because it is a bounded span of a token sequence, not a summary of
+  it), or the slot's ``index``. A paged leaf need not have a head axis
+  (a latent row `[seq, width]`), and widths may differ leaf by leaf. A
+  model with slot or ring leaves is stepped by `paged_state_step`: the same
+  ONE call of the model over all slots' tokens, the state as its `cache`
+  collection beside the pool, read and written in place, and what the
+  expert layers (and the cache reads, where a model counts them) counted
   returned beside the tokens. `write_slot_state` puts a prefill's final
   state (or zeros) into a slot at admission.
 """
@@ -238,7 +243,9 @@ def build_decode_fn(model, temperature: float, top_k: Optional[int],
 # Paged KV pool: its avals + the compiled gather/scatter programs
 # --------------------------------------------------------------------------
 
-PAGED, SLOT, INDEX = "paged", "slot", "index"
+PAGED, SLOT, RING, INDEX = "paged", "slot", "ring", "index"
+# Kinds held once a slot, as `[max_slots, ...]` arrays beside the pool.
+HELD_A_SLOT = (SLOT, RING)
 
 
 class LeafLayout:
@@ -285,7 +292,7 @@ def cache_layout(model, row_aval):
                 f"{sorted(kinds)}"
             )
         kind, axis = kinds[name]
-        if kind not in (PAGED, SLOT, INDEX):
+        if kind not in (PAGED, SLOT, RING, INDEX):
             raise ValueError(f"cache leaf {name!r}: unknown kind {kind!r}")
         if kind != PAGED:
             return LeafLayout(kind, None, name)
@@ -302,11 +309,11 @@ def cache_layout(model, row_aval):
 
 
 def slot_state_names(layout) -> Tuple[str, ...]:
-    """Names of the leaves held once a slot, in tree order, without
-    repeats: empty for a model whose whole cache is paged."""
+    """Names of the leaves held once a slot (state and rings), in tree
+    order, without repeats: empty for a model whose whole cache is paged."""
     names = []
     for leaf in jax.tree_util.tree_leaves(layout):
-        if leaf.kind == SLOT and leaf.name not in names:
+        if leaf.kind in HELD_A_SLOT and leaf.name not in names:
             names.append(leaf.name)
     return tuple(names)
 
@@ -494,9 +501,11 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
     `counts` stacks what the model's layers counted into `moe_stats` for
     the active slots (table row not all trash) — `[layers, 1 + held
     experts]`: assignments, then tokens that reached each held expert —
-    and rides back with `emitted`. Sampling and the RNG discipline are
-    `build_paged_step_fn`'s. `with_logits` appends the step's logits [S, V]
-    to what is returned (the tests compare them with a reference).
+    and rides back with `emitted`. A model whose attention layers count
+    what they read (`cache_stats`, summed over layers: one vector, named by
+    the model's `READS`) has it appended as a sixth output. Sampling and the
+    RNG discipline are `build_paged_step_fn`'s. `with_logits` appends the
+    step's logits [S, V] last (the tests compare them with a reference).
     """
     del block_size  # the pool's own shape says it
 
@@ -507,7 +516,7 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
              "kv_pool": _prune_none_tree(pool)},
             tokens[:, None], decode=True, count_mask=active,
             paged_ctx=PagedContext(tables, lengths, paged_kernel),
-            mutable=["cache", "kv_pool", "moe_stats"],
+            mutable=["cache", "kv_pool", "moe_stats", "cache_stats"],
         )
         emitted, rngs = _sample_slots(
             logits[:, -1], tokens, rngs, sample_mask, temperature, top_k,
@@ -518,6 +527,9 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
         out = (_merge_pool_tree(pool, dict(new["kv_pool"])),
                _merge_pool_tree(state, dict(new["cache"])),
                emitted, rngs, counts)
+        reads = jax.tree_util.tree_leaves(new.get("cache_stats", {}))
+        if reads:
+            out += (jnp.sum(jnp.stack(reads), axis=0),)
         return out + (logits[:, -1],) if with_logits else out
 
     return step
@@ -874,7 +886,8 @@ def tree_nbytes_per_device(tree) -> int:
 # cache layout (scales ride as [*, seq, kv_heads, 1]) — splits over the
 # `tp` mesh axis, so each device holds 1/tp of every slot's cache (and
 # of every paged block). Index leaves and layouts whose heads dim does
-# not divide stay replicated. Weights place through the transformer's
+# not divide stay replicated; a paged leaf with no head axis at all (a
+# latent row) is refused by name. Weights place through the transformer's
 # EXISTING logical-axis rules (parallel/sharding.py LOGICAL_RULES):
 # attention heads + MLP hidden + vocab over tp, the rest replicated on
 # a serving mesh — XLA then inserts the attention-output and MLP
@@ -908,7 +921,13 @@ def _heads_over_tp(ndim: int, shape, lay: LeafLayout, tp: int, shift: int):
     if tp <= 1 or lay.kind != PAGED:
         return PartitionSpec()
     heads = lay.axis + 1
-    if heads >= len(shape) or shape[heads] % tp:
+    if heads >= len(shape) - 1:
+        raise ValueError(
+            f"cache leaf {lay.name!r} {tuple(shape)} has no head axis after "
+            f"its sequence axis to shard over tp={tp} (a latent or index "
+            "row); it is refused, not silently replicated on every device"
+        )
+    if shape[heads] % tp:
         return PartitionSpec()
     spec = [None] * ndim
     spec[heads + shift] = AXIS_TP
@@ -1030,7 +1049,7 @@ class DecodeEngine:
             "oversize_batch_chunks": 0,
         }
         self._paged_step: Dict[tuple, Any] = {}
-        self._paged_kernels: Dict[int, bool] = {}  # paged_attention_kernel
+        self._paged_kernels: Dict[int, str] = {}  # paged_attention_kernel
         self._pack: Dict[tuple, Any] = {}
         self._paged_spec_step: Dict[tuple, Any] = {}
         self._extract: Dict[tuple, Any] = {}
@@ -1346,9 +1365,9 @@ class DecodeEngine:
         )
 
     def make_slot_state(self, params, max_slots: int):
-        """Zeroed per-slot state beside the block pool: every `slot` leaf
-        of the model's decode cache as `[max_slots, *row shape]`, None for
-        the paged and index leaves."""
+        """Zeroed per-slot state beside the block pool: every `slot` and
+        `ring` leaf of the model's decode cache as `[max_slots, *row
+        shape]`, None for the paged and index leaves."""
         if self.mesh is not None:
             raise ValueError(
                 "per-slot state is not placed on a tensor-parallel mesh "
@@ -1360,10 +1379,27 @@ class DecodeEngine:
         return jax.tree_util.tree_map(
             lambda aval, lay: (
                 jnp.zeros((max_slots,) + aval.shape, aval.dtype)
-                if lay.kind == SLOT else None
+                if lay.kind in HELD_A_SLOT else None
             ),
             row_avals, cache_layout(self.model, row_avals),
         )
+
+    def cache_bytes_by_kind(self, params, pool, state=None) -> Dict[str, int]:
+        """Resident bytes of the pool and the per-slot arrays, by the kind
+        the model declares for each leaf (`paged`, `slot`, `ring`)."""
+        params = self._place_params(params)
+        layout = cache_layout(
+            self.model, _decode_cache_aval(self.model, params))
+        total: Dict[str, int] = {}
+        for tree in (pool, state):
+            # Both mirror the layout, None where a leaf lives elsewhere.
+            for lay, leaf in zip(
+                    jax.tree_util.tree_leaves(layout),
+                    jax.tree_util.tree_leaves(tree, is_leaf=_is_none)):
+                if leaf is not None:
+                    total[lay.kind] = total.get(lay.kind, 0) \
+                        + cache_nbytes(leaf)
+        return total
 
     def write_slot_state(self, state, slot: int, row_cache=None):
         """Put a prefilled batch-1 cache's `slot` leaves (its final state)
@@ -1400,7 +1436,8 @@ class DecodeEngine:
         """`paged_step` for a model with per-slot state
         (build_paged_state_step_fn): one call of the model over all slots'
         tokens; the pool, the state and the rng buffer are donated.
-        Returns (pool, state, emitted [S], rngs, counts)."""
+        Returns (pool, state, emitted [S], rngs, counts), and the model's
+        cache reads after them where it counts them."""
         slots = int(jnp.shape(tokens)[0])
         kernel = self.paged_attention_kernel(pool)
         compiled, args = self._paged_program(
@@ -1458,22 +1495,32 @@ class DecodeEngine:
         (`paged_kernel_serves`) and the pool lies whole on one device;
         the plain gather elsewhere (under `tp` XLA shards it over KV
         heads, and a Pallas call cannot be partitioned). `/stats` names
-        it: `decode_engine.paged_attention`."""
+        it: `decode_engine.paged_attention` ("model" where the pool's rows
+        have no head axis and the model's own attention reads them)."""
         from tf_yarn_tpu.ops.decode_attention import paged_kernel_serves
 
         # Every tick: decided once a pool layout.
         fp = self._tree_fingerprint(pool)
-        kernel = self._paged_kernels.get(fp)
-        if kernel is None:
-            kernel = self._paged_kernels[fp] = self.tp_degree == 1 and all(
-                paged_kernel_serves(
-                    jax.ShapeDtypeStruct(leaf.shape[-4:], leaf.dtype))
-                for leaf in jax.tree_util.tree_leaves(pool)
-                if leaf.shape[-1] > 1  # a scale leaf follows its values
-            )
+        how = self._paged_kernels.get(fp)
+        if how is None:
+            leaves = jax.tree_util.tree_leaves(pool)
+            if any(leaf.ndim < 5 for leaf in leaves):
+                # [1, NB, bs, heads, dim] has a head axis; a leaf without
+                # one is read by its model's own attention, which this
+                # choice does not reach.
+                how = "model"
+            elif self.tp_degree == 1 and all(
+                    paged_kernel_serves(
+                        jax.ShapeDtypeStruct(leaf.shape[-4:], leaf.dtype))
+                    for leaf in leaves
+                    if leaf.shape[-1] > 1):  # a scale leaf follows its values
+                how = "kernel"
+            else:
+                how = "plain"
+            self._paged_kernels[fp] = how
         with self._lock:
-            self.stats["paged_attention"] = "kernel" if kernel else "plain"
-        return kernel
+            self.stats["paged_attention"] = how
+        return how == "kernel"
 
     def paged_attention_chunk(self, block_size: int) -> int:
         """Tokens at a time the one-token step's attention reads a slot's

@@ -1,0 +1,701 @@
+"""Latent-attention decoder with a learned key selection, window layers and
+a sigmoid-routed expert layer — the `dots3_note` shape (dots3-note-prev).
+
+    h'     = h + attn_kind(RMSNorm(h))
+    h''    = h' + ffn(RMSNorm(h'))
+    logits = RMSNorm(h_L) @ W_head                          (untied head)
+
+*latent attention* (both kinds, each with its own sizes `AttentionSizes`),
+on x = RMSNorm(h): `c_q = a_q RMSNorm(x W_qa)`; `[q_n | q_r] = c_q W_qb` per
+head; `[c_raw | k_raw] = x W_kva`; `c = a_kv RMSNorm(c_raw)`; `q_r, k_r =
+rope(q_r), rope(k_raw)` (one `k_r` for all heads); `[k_n | v] = c W_kvb` per
+head; `s = (q_n.k_n + q_r.k_r) / sqrt(d_n + d_r)` over the allowed keys;
+`o = softmax(s) v`; `g = sigmoid(x W_g)`, one number a head;
+`attn = concat_h(g_h o_h) W_o`. **What is cached a token and layer is the
+row `[c | k_r]`**: `kv_rank + d_rope` numbers, no head axis, stored padded
+with zeros to whole lanes (`row_multiple`).
+
+Two paths that agree (`tests/test_latent.py`): the *expanded* one above for
+any number of tokens from an empty cache (prefill, the plain forward), a
+block of queries at a time, keys cut at the call's own tokens, so that no
+`[heads, tokens, max_seq_len]` array exists; and the *absorbed* one for one
+token against cached rows, `q~_h = W_kvb,K,h q_n,h`, `s = (q~_h.c_j +
+q_r,h.k_r,j) / sqrt(.)`, `o_h = W_kvb,V,h^T sum_j p_j c_j`, which never
+expands a cached row.
+
+*allowed keys.* `sliding_attention`: `t - j < window` (the token itself
+counts). `full_attention`: the `index_topk` largest of `I_tj = sum_i w_ti
+relu(q^I_ti . k^I_j) / sqrt(index_heads index_dim)` over `j <= t` (all of
+them while there are no more); `q^I = c_q W_iq`, `k^I = LayerNorm(x W_ik)`
+(cached a token: `index_key`), `w = x W_iw`, rope on the first
+`index_rope_dim` numbers of `q^I`, `k^I`. The selection is exact
+(`jax.lax.top_k`), and its scores, like the router's and every norm, are
+float32 at full precision: which keys and which experts are discrete
+choices.
+
+*ffn.* Layers below `first_dense`: `transformer.SwiGLU` of `d_ff_dense`.
+Later layers: `moe.DroplessMoE` with sigmoid scores, selection under the
+correction bias, the chosen scores normalised and scaled, one shared expert.
+
+The serving engine is told what each cache leaf is (`cache_leaf_kinds`): a
+full layer's `latent` and `index_key` rows are paged by token; a sliding
+layer's `window_latent` is a `ring` of `ring_len` rows held once a slot
+(row `p % ring_len` holds position p), so its bytes do not grow with the
+context. The one-token step (`paged_ctx`) reads, on a full layer, the
+slot's live index keys a chunk of blocks at a time (`indexer/scores`), picks
+(`indexer/topk`) and gathers only the chosen rows (`indexer/gather`); on a
+sliding layer the ring (`window/read`). It sows what it read into
+`cache_stats` for the slots `count_mask` marks.
+
+A call of more than one token with `decode=True` is a prefill: it starts
+from an empty cache, whatever `cache_index` held.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from tf_yarn_tpu.models.moe import DroplessMoE
+from tf_yarn_tpu.models.transformer import (
+    EMBED,
+    HEADS,
+    VOCAB,
+    RMSNorm,
+    SwiGLU,
+    TransformerConfig,
+    _partitioned,
+)
+
+HIGHEST = jax.lax.Precision.HIGHEST
+FULL, SLIDING = "full_attention", "sliding_attention"
+RING_MULTIPLE = 16
+# What an attention layer sows into `cache_stats` a step, over the counted
+# slots: rows live and rows read of each leaf, and the keys selected.
+READS = ("index_live", "index_read", "index_selected", "latent_read",
+         "window_live", "window_read")
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionSizes:
+    n_heads: int
+    q_rank: int
+    kv_rank: int
+    d_nope: int
+    d_rope: int
+    d_v: int
+    rope_theta: float
+
+    @property
+    def row_width(self) -> int:
+        """What a token caches: `[c | k_r]`."""
+        return self.kv_rank + self.d_rope
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentConfig:
+    vocab_size: int = 152064
+    d_model: int = 5120
+    layer_types: Tuple[str, ...] = (FULL, FULL, SLIDING, SLIDING, SLIDING)
+    max_seq_len: int = 6144
+    norm_eps: float = 1e-5
+    full: AttentionSizes = AttentionSizes(128, 1024, 512, 128, 64, 128, 8e7)
+    sliding: AttentionSizes = AttentionSizes(64, 1024, 1024, 192, 64, 128, 5e4)
+    rescale_latents: bool = True
+    window: int = 513
+    index_heads: int = 64
+    index_dim: int = 128
+    index_rope_dim: int = 64
+    index_topk: int = 2048
+    # ffn
+    first_dense: int = 1
+    d_ff_dense: int = 13824
+    num_experts: int = 256
+    num_experts_here: int = 256
+    expert_offset: int = 0
+    experts_per_token: int = 8
+    d_expert: int = 1536
+    d_shared: int = 1536
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    # Matrices are stored in `param_dtype`; norm scales, the LayerNorm of the
+    # index keys and the router's bias stay float32.
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+    # Only "bf16": a latent row has no head axis for the int8 kernels' scales.
+    kv_cache_dtype: str = "bf16"
+    # Queries a block of the expanded path, and tokens of index keys a
+    # chunk of the one-token step's scoring loop.
+    query_block: int = 256
+    index_chunk: int = 512
+    # A cached row is stored padded to whole lanes of the chip's vector
+    # registers: 576 -> 640, 1088 -> 1152. At 576 XLA relaid the whole pool
+    # leaf on the way into and out of every step (5 of 25 ms on a v5e).
+    row_multiple: int = 128
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def n_heads(self) -> int:
+        """What `DecodeEngine(mesh=...)` checks against `tp`."""
+        return math.gcd(self.full.n_heads, self.sliding.n_heads)
+
+    @property
+    def ring_len(self) -> int:
+        """Rows of a sliding layer's ring: the window, rounded up to whole
+        tiles of the cache's type."""
+        return -(-self.window // RING_MULTIPLE) * RING_MULTIPLE
+
+    def sizes(self, kind: str) -> AttentionSizes:
+        return self.full if kind == FULL else self.sliding
+
+    def stored_width(self, kind: str) -> int:
+        """A cached row `[c | k_r]` as it is stored: whole lanes."""
+        return -(-self.sizes(kind).row_width // self.row_multiple) \
+            * self.row_multiple
+
+    def __post_init__(self):
+        unknown = set(self.layer_types) - {FULL, SLIDING}
+        if unknown or not self.layer_types:
+            raise ValueError(f"layer_types: {self.layer_types!r}")
+        if self.kv_cache_dtype != "bf16":
+            raise ValueError(
+                f"kv_cache_dtype={self.kv_cache_dtype!r}: a latent cache row "
+                "[c | k_r] has no head axis for the int8 cache's per-head "
+                "scales, and no int8 read of it exists; it is refused until "
+                "one does (docs/Serving.md \"Latent, index and window "
+                "leaves\")"
+            )
+        if self.index_rope_dim > self.index_dim or self.window < 1:
+            raise ValueError("index_rope_dim > index_dim, or window < 1")
+
+    def norm_config(self) -> TransformerConfig:
+        """`transformer.RMSNorm` with its scale in float32."""
+        return TransformerConfig(
+            vocab_size=self.vocab_size, d_model=self.d_model,
+            n_layers=self.n_layers, d_ff=self.d_ff_dense,
+            max_seq_len=self.max_seq_len, norm_eps=self.norm_eps,
+            dtype=self.dtype, param_dtype=jnp.float32,
+        )
+
+    def dense_config(self) -> TransformerConfig:
+        """What `transformer.SwiGLU` reads."""
+        return dataclasses.replace(
+            self.norm_config(), param_dtype=self.param_dtype)
+
+    @classmethod
+    def tiny(cls, **overrides) -> "LatentConfig":
+        defaults = dict(
+            vocab_size=256, d_model=64, max_seq_len=64,
+            layer_types=(FULL, FULL, SLIDING, SLIDING, SLIDING),
+            full=AttentionSizes(4, 32, 16, 16, 8, 16, 8e7),
+            sliding=AttentionSizes(2, 32, 32, 24, 8, 16, 5e4),
+            window=5, index_heads=4, index_dim=16, index_rope_dim=8,
+            index_topk=8, d_ff_dense=96, num_experts=16, num_experts_here=16,
+            experts_per_token=3, d_expert=32, d_shared=32, query_block=8,
+            index_chunk=16, row_multiple=8,
+        )
+        defaults.update(overrides)
+        return cls(**defaults)
+
+
+def rope(x, positions, theta: float):
+    """Rotary embedding over the last dim of x [B, S, ..., n] at `positions`
+    [B, S]: the pairs (2i, 2i + 1) turned; float32."""
+    n = x.shape[-1]
+    freqs = 1.0 / theta ** (jnp.arange(0, n, 2, dtype=jnp.float32) / n)
+    angles = positions.astype(jnp.float32).reshape(
+        positions.shape + (1,) * (x.ndim - 2)) * freqs
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1).reshape(x.shape)
+
+
+def _rope_front(x, positions, theta: float, n: int):
+    return jnp.concatenate(
+        [rope(x[..., :n], positions, theta), x[..., n:].astype(jnp.float32)],
+        axis=-1)
+
+
+def index_scores(q_index, weight, keys):
+    """`I = sum_i w_i relu(q^I_i . k^I_j) / sqrt(heads dim)`, float32 at full
+    precision. q_index [..., T, Hi, Di], weight [..., T, Hi], keys
+    [..., J, Di] -> [..., T, J]."""
+    heads, dim = q_index.shape[-2:]
+    dots = jnp.einsum("...thd,...jd->...thj", q_index.astype(jnp.float32),
+                      keys.astype(jnp.float32), precision=HIGHEST)
+    return jnp.einsum("...th,...thj->...tj", weight.astype(jnp.float32),
+                      nn.relu(dots), precision=HIGHEST) * (heads * dim) ** -0.5
+
+
+def top_k_mask(score, k: int):
+    """[..., J] bool: the `k` entries of each row that `jax.lax.top_k` picks
+    (of equal scores the earlier), as a mask and without a scatter: every
+    score above the k-th largest, and of those equal to it the first few."""
+    kth = jax.lax.top_k(score, k)[0][..., -1:]
+    above, ties = score > kth, score == kth
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    return above | (ties & (jnp.cumsum(ties, axis=-1) <= room))
+
+
+def expanded_attention(q_n, q_r, rows, w_kvb, sizes: AttentionSizes, *,
+                       window: int = 0, select=None, query_block: int = 256,
+                       dtype=jnp.bfloat16):
+    """The expanded path over a call's own tokens, from position 0: q_n
+    [B, S, H, d_n], q_r [B, S, H, d_r], rows [B, S, kv_rank + d_r] (as they
+    are cached), w_kvb [kv_rank, H, d_n + d_v] -> [B, S, H, d_v] float32.
+    `window` > 0 keeps `t - j < window`; `select` (q_index, weight, keys,
+    top_k) keeps the indexer's `top_k` largest `j <= t`. A block of queries
+    at a time: the largest array is [B, H, query_block, S] float32."""
+    batch, s, heads, _ = q_n.shape
+    c = rows[..., :sizes.kv_rank]
+    k_r = rows[..., sizes.kv_rank:sizes.row_width]
+    with jax.named_scope("latent/kv"):
+        expanded = jnp.einsum("bjr,rhf->bjhf", c, w_kvb)
+        k_n, v = expanded[..., :sizes.d_nope], expanded[..., sizes.d_nope:]
+    block = min(query_block, s)
+    pad = -s % block
+    nb = (s + pad) // block
+
+    def blocks(value):
+        value = jnp.pad(value, [(0, 0), (0, pad)] + [(0, 0)] * (value.ndim - 2))
+        return jnp.moveaxis(
+            value.reshape((batch, nb, block) + value.shape[2:]), 1, 0)
+
+    scale = (sizes.d_nope + sizes.d_rope) ** -0.5
+    keys = jnp.arange(s)[None, :]
+
+    def some_rows(args):
+        start, qn_block, qr_block, *index_block = args
+        at = (start + jnp.arange(block))[:, None]
+        mask = jnp.broadcast_to(at >= keys, (batch, block, s))
+        if window:
+            mask &= at - keys < window
+        if select is not None:
+            with jax.named_scope("indexer/scores"):
+                score = jnp.where(
+                    mask, index_scores(*index_block, select[2]), -jnp.inf)
+            with jax.named_scope("indexer/topk"):
+                mask &= top_k_mask(score, min(select[3], s))
+        with jax.named_scope("latent/scores"):
+            scores = (jnp.einsum("bthd,bjhd->bhtj", qn_block, k_n,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bthd,bjd->bhtj", qr_block, k_r,
+                                   preferred_element_type=jnp.float32)) * scale
+            scores = jnp.where(mask[:, None], scores, -jnp.inf)
+            weights = jax.nn.softmax(scores, axis=-1)
+        with jax.named_scope("latent/values"):
+            return jnp.einsum("bhtj,bjhd->bthd", weights.astype(dtype), v,
+                              preferred_element_type=jnp.float32)
+
+    inputs = [jnp.arange(nb) * block, blocks(q_n.astype(dtype)),
+              blocks(q_r.astype(dtype))]
+    if select is not None:
+        inputs += [blocks(select[0]), blocks(select[1])]
+    out = jax.lax.map(some_rows, tuple(inputs))
+    return jnp.moveaxis(out, 0, 1).reshape(
+        batch, nb * block, heads, sizes.d_v)[:, :s]
+
+
+def absorbed_attention(q_n, q_r, rows, valid, w_kvb, sizes: AttentionSizes,
+                       dtype=jnp.bfloat16):
+    """One token a row against cached rows, which are never expanded: q_n
+    [B, H, d_n], q_r [B, H, d_r], rows [B, K, kv_rank + d_r], valid [B, K],
+    w_kvb [kv_rank, H, d_n + d_v] -> [B, H, d_v] float32."""
+    with jax.named_scope("latent/absorb"):
+        q_abs = jnp.einsum("bhn,rhn->bhr", q_n.astype(dtype),
+                           w_kvb[..., :sizes.d_nope],
+                           preferred_element_type=jnp.float32)
+        # A cached row is [c | k_r], so one product against [q~ | q_r]
+        # gives both terms of the score and the row is never sliced.
+        query = jnp.concatenate([q_abs, q_r], axis=-1).astype(dtype)
+        query = jnp.pad(query, [(0, 0), (0, 0),
+                                (0, rows.shape[-1] - query.shape[-1])])
+    with jax.named_scope("latent/scores"):
+        scores = jnp.einsum(
+            "bhw,bkw->bhk", query, rows, preferred_element_type=jnp.float32
+        ) * (sizes.d_nope + sizes.d_rope) ** -0.5
+        scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
+        weights = jax.nn.softmax(scores, axis=-1)
+    with jax.named_scope("latent/values"):
+        mixed = jnp.einsum("bhk,bkw->bhw", weights.astype(dtype), rows,
+                           preferred_element_type=jnp.float32
+                           )[..., :sizes.kv_rank].astype(dtype)
+        return jnp.einsum("bhr,rhv->bhv", mixed, w_kvb[..., sizes.d_nope:],
+                          preferred_element_type=jnp.float32)
+
+
+def select_rows(q_index, weight, index_pool, latent_pool, tables, lengths,
+                top_k: int, chunk_tokens: int):
+    """The two-stage read of a full layer's one-token step. Score each
+    slot's live index keys, a chunk of blocks at a time and no further than
+    the longest slot reaches; keep the `top_k` largest `j < length` (exact:
+    a stable sort, of equal scores the earlier key, as `jax.lax.top_k`
+    orders them), each score carrying its row of the pool so that no table
+    is looked up afterwards; gather those latent rows and no others.
+
+    q_index [S, Hi, Di], weight [S, Hi] float32; index_pool [NB, bs, Di],
+    latent_pool [NB, bs, W]; tables [S, MB]; lengths [S] (this step's row
+    included: the keys are `j < length`).
+    -> rows [S, K, W], valid [S, K], the chosen rows of the pool [S, K],
+    index rows read a slot."""
+    slots, max_blocks = tables.shape
+    block_size = index_pool.shape[1]
+    per_chunk = max(1, min(chunk_tokens // block_size, max_blocks))
+    n_chunks = -(-max_blocks // per_chunk)
+    chunk = per_chunk * block_size
+    tables = jnp.pad(tables, [(0, 0), (0, n_chunks * per_chunk - max_blocks)])
+    total = n_chunks * chunk
+    trips = (jnp.max(lengths) + chunk - 1) // chunk
+
+    def score_chunk(state):
+        at, scores, places = state
+        with jax.named_scope("indexer/scores"):
+            ids = jax.lax.dynamic_slice_in_dim(tables, at * per_chunk,
+                                               per_chunk, axis=1)
+            keys = index_pool[ids].reshape(slots, chunk, -1)
+            part = index_scores(q_index[:, None], weight[:, None], keys)[:, 0]
+            place = (ids[:, :, None] * block_size
+                     + jnp.arange(block_size)).reshape(slots, chunk)
+            return (at + 1,
+                    jax.lax.dynamic_update_slice_in_dim(
+                        scores, part, at * chunk, axis=1),
+                    jax.lax.dynamic_update_slice_in_dim(
+                        places, place, at * chunk, axis=1))
+
+    _, scores, places = jax.lax.while_loop(
+        lambda state: state[0] < trips, score_chunk,
+        (jnp.zeros((), jnp.int32),
+         jnp.full((slots, total), -jnp.inf, jnp.float32),
+         jnp.zeros((slots, total), jnp.int32)))
+    with jax.named_scope("indexer/topk"):
+        k = min(top_k, total)
+        dead = jnp.arange(total)[None, :] >= lengths[:, None]
+        falling, places = jax.lax.sort(
+            (jnp.where(dead, jnp.inf, -scores), places), num_keys=1,
+            is_stable=True)
+        valid, chosen = falling[:, :k] < jnp.inf, places[:, :k]
+    with jax.named_scope("indexer/gather"):
+        rows = latent_pool.reshape(-1, latent_pool.shape[-1])[chosen]
+    return rows, valid, chosen, trips * chunk
+
+
+class LatentAttention(nn.Module):
+    config: LatentConfig
+    kind: str
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, paged_ctx=None, count_mask=None):
+        cfg, sizes = self.config, self.config.sizes(self.kind)
+        full = self.kind == FULL
+        batch, s, d = x.shape
+        heads = sizes.n_heads
+        f32, dtype = jnp.float32, cfg.dtype
+        normal = nn.initializers.lecun_normal()
+        # The latents' norms hand float32 on: one rounding, after the rescale.
+        norm_cfg = dataclasses.replace(cfg.norm_config(), dtype=f32)
+        one_token = self.decode and s == 1
+        paged = one_token and paged_ctx is not None
+        if self.decode and paged_ctx is not None and s != 1:
+            raise NotImplementedError(
+                f"the paged step of {type(self).__name__} reads one token a "
+                f"slot; a window of {s} (speculation, chunked prefill) does "
+                "not carry the latent, index and ring leaves"
+            )
+
+        def matrix(name, shape, names):
+            return self.param(name, _partitioned(names)(normal), shape,
+                              cfg.param_dtype).astype(dtype)
+
+        def rescale(rank):
+            return math.sqrt(d / rank) if cfg.rescale_latents else 1.0
+
+        # Where this call's tokens stand: a slot's length in the paged step,
+        # `cache_index` in a one-token call on a dense cache, 0 otherwise.
+        index_var = None
+        if paged:
+            lengths = paged_ctx.lengths.astype(jnp.int32)
+        elif self.decode:
+            index_var = self.variable("cache", "cache_index",
+                                      lambda: jnp.zeros((), jnp.int32))
+            lengths = jnp.broadcast_to(
+                index_var.value if one_token else 0, (batch,)).astype(jnp.int32)
+        else:
+            lengths = jnp.zeros((batch,), jnp.int32)
+        positions = lengths[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+
+        with jax.named_scope("latent/q"):
+            c_q = jnp.einsum("bsd,dr->bsr", x, matrix(
+                "q_a", (d, sizes.q_rank), (EMBED, None)),
+                preferred_element_type=f32)
+            c_q = (rescale(sizes.q_rank) * RMSNorm(norm_cfg, name="q_norm")(
+                c_q)).astype(dtype)
+            q = jnp.einsum("bsr,rf->bsf", c_q, matrix(
+                "q_b", (sizes.q_rank, heads * (sizes.d_nope + sizes.d_rope)),
+                (None, HEADS)), preferred_element_type=f32).reshape(
+                batch, s, heads, sizes.d_nope + sizes.d_rope)
+            q_n = q[..., :sizes.d_nope]
+            q_r = rope(q[..., sizes.d_nope:], positions, sizes.rope_theta)
+        with jax.named_scope("latent/kv"):
+            kv = jnp.einsum("bsd,df->bsf", x, matrix(
+                "kv_a", (d, sizes.row_width), (EMBED, None)),
+                preferred_element_type=f32)
+            c = rescale(sizes.kv_rank) * RMSNorm(norm_cfg, name="kv_norm")(
+                kv[..., :sizes.kv_rank])
+            k_r = rope(kv[..., sizes.kv_rank:], positions, sizes.rope_theta)
+            rows = jnp.concatenate([c, k_r], axis=-1).astype(dtype)
+            rows = jnp.pad(rows, [(0, 0), (0, 0), (
+                0, cfg.stored_width(self.kind) - sizes.row_width)])
+            w_kvb = matrix(
+                "kv_b", (sizes.kv_rank, heads * (sizes.d_nope + sizes.d_v)),
+                (None, HEADS)).reshape(sizes.kv_rank, heads, -1)
+        if full:
+            with jax.named_scope("indexer/q"):
+                q_index = jnp.einsum("bsr,rf->bsf", c_q, matrix(
+                    "index_q", (sizes.q_rank, cfg.index_heads * cfg.index_dim),
+                    (None, None)), preferred_element_type=f32).reshape(
+                    batch, s, cfg.index_heads, cfg.index_dim)
+                q_index = _rope_front(q_index, positions, sizes.rope_theta,
+                                      cfg.index_rope_dim)
+                index_weight = jnp.einsum("bsd,dh->bsh", x, matrix(
+                    "index_w", (d, cfg.index_heads), (EMBED, None)),
+                    preferred_element_type=f32)
+            with jax.named_scope("indexer/k"):
+                k_index = jnp.einsum("bsd,df->bsf", x, matrix(
+                    "index_k", (d, cfg.index_dim), (EMBED, None)),
+                    preferred_element_type=f32)
+                k_index = nn.LayerNorm(
+                    epsilon=cfg.norm_eps, dtype=f32, param_dtype=f32,
+                    name="index_k_norm")(k_index)
+                k_index = _rope_front(k_index, positions, sizes.rope_theta,
+                                      cfg.index_rope_dim).astype(dtype)
+
+        reads = dict.fromkeys(READS, 0)
+        if one_token:
+            counted = jnp.ones((batch,), bool) if count_mask is None \
+                else count_mask
+            if full:
+                out, read = self._step_full(
+                    q_n[:, 0], q_r[:, 0], q_index[:, 0], index_weight[:, 0],
+                    rows[:, 0], k_index[:, 0], w_kvb, lengths, paged_ctx,
+                    counted)
+                reads.update(read, index_live=jnp.sum(
+                    jnp.where(counted, lengths + 1, 0)))
+            else:
+                out = self._step_window(q_n[:, 0], q_r[:, 0], rows[:, 0],
+                                        w_kvb, lengths)
+                reads.update(
+                    window_live=jnp.sum(jnp.where(
+                        counted, jnp.minimum(lengths + 1, cfg.window), 0)),
+                    window_read=jnp.sum(counted) * cfg.ring_len)
+            out = out[:, None]
+            if index_var is not None:
+                index_var.value = index_var.value + 1
+        else:
+            if self.decode:
+                self._write_prefill(rows, k_index if full else None)
+                index_var.value = jnp.asarray(s, jnp.int32)
+            select = None
+            if full and s > cfg.index_topk:
+                select = (q_index, index_weight, k_index, cfg.index_topk)
+            out = expanded_attention(
+                q_n, q_r, rows, w_kvb, sizes, select=select,
+                window=0 if full else cfg.window,
+                query_block=cfg.query_block, dtype=dtype)
+        if count_mask is not None and one_token:
+            self.sow("cache_stats", "reads", jnp.stack(
+                [jnp.asarray(reads[name], jnp.int32) for name in READS]))
+
+        with jax.named_scope("latent/gate"):
+            gate = nn.sigmoid(jnp.einsum("bsd,dh->bsh", x, matrix(
+                "gate", (d, heads), (EMBED, HEADS)),
+                preferred_element_type=f32))
+            out = (out * gate[..., None]).astype(dtype)
+        with jax.named_scope("latent/out"):
+            return jnp.einsum(
+                "bsf,fd->bsd", out.reshape(batch, s, heads * sizes.d_v),
+                matrix("o", (heads * sizes.d_v, d), (HEADS, EMBED)),
+                preferred_element_type=f32).astype(dtype)
+
+    @nn.nowrap
+    def _write_prefill(self, rows, k_index):
+        """A prefill's rows into a fresh dense cache: a full layer's at
+        [0, s) of `latent` and `index_key`; a sliding layer's last
+        `ring_len` into the ring, position p at row `p % ring_len`."""
+        cfg = self.config
+        batch, s, width = rows.shape
+
+        def put(name, value):
+            self.variable("cache", name, lambda: value).value = value
+
+        with jax.named_scope("latent/cache_write"):
+            if k_index is None:
+                ring = cfg.ring_len
+                kept = min(s, ring)
+                put("window_latent",
+                    jnp.zeros((batch, ring, width), rows.dtype).at[
+                        :, jnp.arange(s - kept, s) % ring].set(
+                        rows[:, s - kept:]))
+                return
+            for name, fresh in (("latent", rows), ("index_key", k_index)):
+                put(name, jnp.pad(
+                    fresh, [(0, 0), (0, cfg.max_seq_len - s), (0, 0)]))
+
+    @nn.nowrap
+    def _step_full(self, q_n, q_r, q_index, index_weight, row, k_index,
+                   w_kvb, lengths, paged_ctx, counted):
+        """One token a slot on a full layer: write the token's rows, score
+        the live index keys, gather the chosen latent rows, attend."""
+        cfg, sizes = self.config, self.config.full
+        batch = row.shape[0]
+        if paged_ctx is not None:
+            def _missing():
+                raise ValueError(
+                    "the paged step needs the kv_pool collection (the "
+                    "engine's paged_state_step provides it)")
+
+            pools = {name: self.variable("kv_pool", name, _missing)
+                     for name in ("latent", "index_key")}
+            tables = paged_ctx.tables
+            unwrap = {name: var.value[0] for name, var in pools.items()}
+        else:
+            # The dense cache as a pool of one block a row.
+            widths = {"latent": row.shape[-1], "index_key": cfg.index_dim}
+            pools = {name: self.variable(
+                "cache", name, lambda w=w: jnp.zeros(
+                    (batch, cfg.max_seq_len, w), cfg.dtype))
+                for name, w in widths.items()}
+            tables = jnp.arange(batch, dtype=jnp.int32)[:, None]
+            unwrap = {name: var.value for name, var in pools.items()}
+        block_size = unwrap["latent"].shape[1]
+        max_blocks = tables.shape[1]
+        logical = lengths // block_size
+        # A row past the slot's blocks goes to the trash block 0.
+        blocks = jnp.where(
+            logical < max_blocks, jnp.take_along_axis(
+                tables, jnp.clip(logical, 0, max_blocks - 1)[:, None],
+                axis=1)[:, 0], 0)
+        with jax.named_scope("latent/cache_write"):
+            fresh = {"latent": row, "index_key": k_index}
+            for name in unwrap:
+                unwrap[name] = unwrap[name].at[
+                    blocks, lengths % block_size].set(fresh[name])
+                pools[name].value = unwrap[name][None] \
+                    if paged_ctx is not None else unwrap[name]
+        rows, valid, chosen, index_read = select_rows(
+            q_index, index_weight, unwrap["index_key"], unwrap["latent"],
+            tables, lengths + 1, cfg.index_topk, cfg.index_chunk)
+        # for the tests, which ask for `intermediates`: rows of the pool
+        self.sow("intermediates", "selected", jnp.where(valid, chosen, -1))
+        out = absorbed_attention(q_n, q_r, rows, valid, w_kvb, sizes,
+                                 cfg.dtype)
+        return out, {
+            "index_read": jnp.sum(counted) * index_read,
+            "index_selected": jnp.sum(valid & counted[:, None]),
+            "latent_read": jnp.sum(counted) * rows.shape[1]}
+
+    @nn.nowrap
+    def _step_window(self, q_n, q_r, row, w_kvb, lengths):
+        """One token a slot on a sliding layer: the token's row into the
+        slot's ring, then the ring's rows that lie inside the window."""
+        cfg, sizes = self.config, self.config.sliding
+        batch, ring = row.shape[0], cfg.ring_len
+        # One row a batch element, or (the paged step) a leading slot axis
+        # over batch-1 rows: flattened here, restored on the way out.
+        width = row.shape[-1]
+        var = self.variable(
+            "cache", "window_latent",
+            lambda: jnp.zeros((batch, ring, width), cfg.dtype))
+        with jax.named_scope("latent/cache_write"):
+            held = var.value.reshape(batch, ring, width).at[
+                jnp.arange(batch), lengths % ring].set(row)
+            var.value = held.reshape(var.value.shape)
+        with jax.named_scope("window/read"):
+            # Row i holds the newest position p <= length with p % ring == i.
+            at = lengths[:, None] - (
+                lengths[:, None] - jnp.arange(ring)[None, :]) % ring
+            valid = (at >= 0) & (lengths[:, None] - at < cfg.window)
+        return absorbed_attention(q_n, q_r, held, valid, w_kvb, sizes,
+                                  cfg.dtype)
+
+
+class LatentBlock(nn.Module):
+    config: LatentConfig
+    index: int
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, count_mask=None, paged_ctx=None):
+        cfg = self.config
+        norm_cfg = cfg.norm_config()
+        batch, t, d = x.shape
+        x = x + LatentAttention(
+            cfg, cfg.layer_types[self.index], self.decode, name="attn")(
+            RMSNorm(norm_cfg, name="attn_norm")(x), paged_ctx, count_mask)
+        normed = RMSNorm(norm_cfg, name="ffn_norm")(x)
+        if self.index < cfg.first_dense:
+            with jax.named_scope("mlp"):
+                return x + SwiGLU(cfg.dense_config(), name="dense")(normed)
+        moe = DroplessMoE(
+            num_experts=cfg.num_experts, num_experts_here=cfg.num_experts_here,
+            expert_offset=cfg.expert_offset, top_k=cfg.experts_per_token,
+            d_expert=cfg.d_expert, d_shared=cfg.d_shared, scoring="sigmoid",
+            norm_topk=cfg.norm_topk, routed_scale=cfg.routed_scale,
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="moe",
+        )(normed.reshape(batch * t, d), count_mask)
+        return x + moe.reshape(batch, t, d)
+
+
+class LatentLM(nn.Module):
+    """tokens [B, S] int32 -> logits [B, S, vocab] (float32).
+
+    `decode=True` keeps the cache (`models/decode_engine.py` drives it);
+    `paged_ctx` besides is the paged step's call: tokens [slots, 1], the
+    rings with a leading slot axis in `cache`, the full layers' rows in the
+    `kv_pool` collection. `count_mask` [B * S] marks the tokens whose
+    routing and cache reads the layers count (`moe_stats`, `cache_stats`)."""
+
+    config: LatentConfig
+    # The names of what the attention layers count into `cache_stats`.
+    READS = READS
+
+    def cache_leaf_kinds(self):
+        return {"latent": ("paged", -2), "index_key": ("paged", -2),
+                "window_latent": ("ring", None), "cache_index": ("index", None)}
+
+    @nn.compact
+    def __call__(self, tokens, deterministic: bool = True,
+                 return_hidden: bool = False, decode: bool = False,
+                 count_mask=None, paged_ctx=None):
+        cfg = self.config
+        embedding = self.param(
+            "embedding",
+            _partitioned((VOCAB, EMBED))(nn.initializers.normal(stddev=0.02)),
+            (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
+        )
+        with jax.named_scope("embed"):
+            x = embedding.astype(cfg.dtype)[tokens]
+        for index in range(cfg.n_layers):
+            x = LatentBlock(cfg, index, decode, name=f"layer_{index}")(
+                x, count_mask, paged_ctx)
+        x = RMSNorm(cfg.norm_config(), name="final_norm")(x)
+        if return_hidden:
+            return x
+        with jax.named_scope("lm_head"):
+            head = self.param(
+                "lm_head",
+                _partitioned((EMBED, VOCAB))(nn.initializers.lecun_normal()),
+                (cfg.d_model, cfg.vocab_size), cfg.param_dtype,
+            )
+            return jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)

@@ -7,11 +7,13 @@ shapes), expert FFNs are batched matmuls with the expert axis annotated
 expert group per ep shard and inserts the all-to-alls itself. No analog
 exists in the reference (SURVEY.md §2.5: expert parallelism — NO).
 
-`DroplessMoE` is the other equation, the one served hybrid models use
-(models/hybrid.py): a router over all experts of the deployment in float32,
-the `top_k` largest, a softmax over those alone, no capacity and no dropped
-token, plus a shared expert every token passes. It is told which experts
-this chip holds and returns their part of the sum.
+`DroplessMoE` is the other equation, the one served models use
+(models/hybrid.py, models/latent.py): a router over all experts of the
+deployment in float32, the `top_k` largest, gates over those alone (a
+softmax of their logits, or their sigmoid scores, chosen under a correction
+bias, normalised and scaled), no capacity and no dropped token, plus a
+shared expert every token passes. It is told which experts this chip holds
+and returns their part of the sum.
 """
 
 from __future__ import annotations
@@ -111,9 +113,14 @@ class MoEMlp(nn.Module):
 
 class DroplessMoE(nn.Module):
     """`moe(x) = sum_i g_i W_out,i (silu(a_i) * b_i)`, `[a_i | b_i] = x W_in,i`
-    over the `top_k` experts of largest router logit, `g = softmax` over
-    those `top_k` logits alone; plus `shared(x)`, one SwiGLU of width
+    over the `top_k` chosen experts; plus `shared(x)`, one SwiGLU of width
     `d_shared` that every token passes (0 = none).
+
+    `scoring="softmax"`: the experts of largest router logit, `g = softmax`
+    over those `top_k` logits alone. `scoring="sigmoid"` (the `noaux_tc`
+    recipe): `s = sigmoid(logits)`; the `top_k` largest of `s + b`, `b` the
+    float32 correction bias `router_bias` (one group); `g_i = s_i`, over
+    `sum_chosen s` where `norm_topk`, times `routed_scale`.
 
     The router is as wide as the deployment (`num_experts`), and this chip
     holds `num_experts_here` of them starting at `expert_offset`: the sum
@@ -140,6 +147,9 @@ class DroplessMoE(nn.Module):
     d_expert: int
     d_shared: int = 0
     expert_offset: int = 0
+    scoring: str = "softmax"
+    norm_topk: bool = True
+    routed_scale: float = 1.0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
 
@@ -147,6 +157,8 @@ class DroplessMoE(nn.Module):
     def __call__(self, x, count_mask=None):
         t, d = x.shape
         held, width = self.num_experts_here, self.d_expert
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring: {self.scoring!r}")
         if not 0 < held <= self.num_experts - self.expert_offset:
             raise ValueError(
                 f"experts [{self.expert_offset}, {self.expert_offset + held})"
@@ -165,8 +177,18 @@ class DroplessMoE(nn.Module):
                 "td,de->te", x.astype(jnp.float32), router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,
             )
-            top_logits, top_index = jax.lax.top_k(logits, self.top_k)
-            gates = jax.nn.softmax(top_logits, axis=-1)
+            if self.scoring == "softmax":
+                top_logits, top_index = jax.lax.top_k(logits, self.top_k)
+                gates = jax.nn.softmax(top_logits, axis=-1)
+            else:
+                bias = self.param("router_bias", nn.initializers.zeros_init(),
+                                  (self.num_experts,), jnp.float32)
+                scores = nn.sigmoid(logits)
+                _, top_index = jax.lax.top_k(scores + bias, self.top_k)
+                gates = jnp.take_along_axis(scores, top_index, axis=-1)
+                if self.norm_topk:
+                    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+                gates = gates * self.routed_scale
         with jax.named_scope("moe/dispatch"):
             # weights [T, held]: a token's gate for each held expert it
             # chose, zero elsewhere.

@@ -112,6 +112,10 @@ class TransformerConfig:
 
 # The decode cache `Attention` keeps, by leaf name: (kind, sequence axis
 # from the end of the shape). Shared by every model built on `Attention`.
+# A model declares its own leaves the same way (`cache_leaf_kinds`); the
+# kinds are the engine's (`decode_engine.py`): `paged` by token (a head axis
+# may follow the sequence axis, or none: a latent row), `slot` (state held
+# once a slot), `ring` (a window layer's last rows, once a slot), `index`.
 CACHE_LEAF_KINDS = {
     "cached_key": ("paged", -3),
     "cached_value": ("paged", -3),

@@ -253,10 +253,11 @@ class SlotScheduler:
     its cap rejects with QueueFull (HTTP 429), keeping batch floods
     from ever crowding the interactive tier's queue.
 
-    Per-slot state (docs/Serving.md "State held once a slot"): a model
-    whose cache has leaves held once a slot (a recurrent state; the
-    engine's ``slot_state_leaves``) gets arrays ``[max_slots, ...]``
-    beside the block pool. Admission writes the prefill's final state, or
+    Per-slot state (docs/Serving.md "State held once a slot", "Latent,
+    index and window leaves"): a model whose cache has leaves held once a
+    slot (a recurrent state, a window layer's ring; the engine's
+    ``slot_state_leaves``) gets arrays ``[max_slots, ...]`` beside the
+    block pool. Admission writes the prefill's final state, or
     zeros, into the slot before its first replayed token, and the step
     reads and writes the state in place. Everything that moves keys and
     values WITHOUT the state steps aside by name: the prefix cache
@@ -454,6 +455,10 @@ class SlotScheduler:
         self._held: Optional[Tuple[Request, Response]] = None
         self._state = None
         self._state_bytes = 0
+        self._cache_bytes_by_kind: Dict[str, int] = {}
+        # What the attention layers counted (a model with `cache_stats`),
+        # under the model's own names (`READS`).
+        self._cache_reads: Dict[str, int] = {}
         self._state_resets = 0
         self._prefix_skipped_stateful = 0
         self._prefix_capacity = int(prefix_cache_capacity or 0)
@@ -506,6 +511,8 @@ class SlotScheduler:
                     f"{type(exc).__name__}: {exc}"
                 ) from exc
             self._state_bytes = _cache_nbytes(self._state)
+            self._cache_bytes_by_kind = engine.cache_bytes_by_kind(
+                params, self._pool, self._state)
         self._kv_bytes = kv_bytes
         # Per-DEVICE residency: under tp sharding each device holds 1/tp
         # of every slot's KV (global bytes above are unchanged) — the
@@ -558,7 +565,7 @@ class SlotScheduler:
         import jax
 
         try:
-            self._pool, self._state, emitted, _rngs, _counts = \
+            self._pool, self._state, emitted, *_ = \
                 self.engine.paged_state_step(
                     self.params, self._pool, self._state, self._tables,
                     self._lengths, np.zeros((self.max_slots,), np.int32),
@@ -1416,9 +1423,10 @@ class SlotScheduler:
                 else:
                     tokens[slot] = state.last_token
                     mask[slot] = True
-            counts = None
+            counts = reads = None
             if self._state is not None:
-                self._pool, self._state, emitted, rngs, counts = \
+                # `reads`: after the five, where the model counts them.
+                self._pool, self._state, emitted, rngs, counts, *reads = \
                     self.engine.paged_state_step(
                         self.params, self._pool, self._state, self._tables,
                         self._lengths, tokens, self._rngs, mask,
@@ -1437,13 +1445,15 @@ class SlotScheduler:
             # Asked for now, so that the copies to the host follow the
             # program with no word from the host in between: one wait
             # under `serving/step_sync` in place of one a result.
-            for result in (emitted, rngs, counts):
+            reads = reads[0] if reads else None
+            for result in (emitted, rngs, counts, reads):
                 if hasattr(result, "copy_to_host_async"):  # a device array
                     result.copy_to_host_async()
             # After the call: the engine then knows which implementation
             # the step was compiled with.
             chunk = getattr(self.engine, "paged_attention_chunk", None)
-            self._count_step(active, chunk and chunk(self._block_size))
+            self._count_step(active, chunk and chunk(self._block_size),
+                             counted_by_model=reads is not None)
         with telemetry.span("serving/step_sync") as sync_span:
             # The tick's one host sync: every slot's token in one transfer.
             emitted = np.asarray(emitted)
@@ -1453,6 +1463,8 @@ class SlotScheduler:
             if counts is not None:
                 # Ready with the tokens: the same program returned them.
                 counts = np.asarray(counts)
+            if reads is not None:
+                reads = np.asarray(reads)
             # Freed here, under this span: left to the function's return
             # the device buffer's release took 0.5-0.8 ms a tick on a v5e
             # inside `serving/step` and under none of its children.
@@ -1497,9 +1509,26 @@ class SlotScheduler:
             self._account_tokens(prefill_tokens, decode_tokens)
             if counts is not None and counts.size:
                 self._count_experts(counts)
+            if reads is not None:
+                self._count_reads(reads)
             emit_span.args.update(
                 tokens=decode_tokens, retired=len(retired) - was_retired
             )
+
+    def _count_reads(self, reads: np.ndarray) -> None:
+        """One step's cache reads as the model's attention layers counted
+        them over the active slots, summed over layers, under the model's
+        names (`READS`: rows live and rows read of each leaf, keys
+        selected). `kv_read_token_steps` takes the rows read of all leaves
+        over the number of attention layers: what a layer read of a slot's
+        sequence, to set beside `kv_token_steps`, what was live of it."""
+        names = self.engine.model.READS
+        tally = self._cache_reads
+        for name, value in zip(names, reads):
+            tally[name] = tally.get(name, 0) + int(value)
+        self._kv_read_token_steps += sum(
+            int(value) for name, value in zip(names, reads)
+            if name.endswith("_read")) // self.engine.model.config.n_layers
 
     def _count_experts(self, counts: np.ndarray) -> None:
         """One step's `[layers, 1 + held experts]`: the active slots'
@@ -1573,7 +1602,8 @@ class SlotScheduler:
             prompt_tokens=len(request.prompt), slot=slot, **parts,
         )
 
-    def _count_step(self, active: List[int], chunk=None) -> None:
+    def _count_step(self, active: List[int], chunk=None,
+                    counted_by_model: bool = False) -> None:
         """Around a model step: what it reads. `kv_token_steps` over
         `slot_steps` is the mean live KV length a slot-step attends
         over; `kv_read_token_steps` over `kv_token_steps` is how much of
@@ -1581,13 +1611,15 @@ class SlotScheduler:
         holds and this step's own row, rounded up to `chunk` tokens
         (`DecodeEngine.paged_attention_chunk`: the kernel's loop trip;
         None = the gathered view's whole `max_seq_len`, as the
-        speculative window and the plain one-token read take it)."""
+        speculative window and the plain one-token read take it). A model
+        that counts its own reads (`_count_reads`) says what was read."""
         chunk = chunk or self._max_seq_len
         self._slot_steps += len(active)
         for slot in active:
             kv_len = self._slots[slot].kv_len
             self._kv_token_steps += kv_len
-            self._kv_read_token_steps += -(-(kv_len + 1) // chunk) * chunk
+            if not counted_by_model:
+                self._kv_read_token_steps += -(-(kv_len + 1) // chunk) * chunk
 
     def _note_step_seconds(self, seconds: float, tick: int) -> None:
         """Single model steps of many times the usual length decide
@@ -1931,8 +1963,13 @@ class SlotScheduler:
         if self._state is not None:
             snap["state_leaves"] = list(self._state_leaves)
             snap["state_bytes"] = self._state_bytes
+            snap["cache_bytes_by_kind"] = dict(self._cache_bytes_by_kind)
+            snap["cache_hbm_bytes"] = self._kv_bytes + self._state_bytes
             snap["state_resets"] = self._state_resets
             snap["prefix_skipped_stateful"] = self._prefix_skipped_stateful
+        snap.update(
+            {name + "_token_steps": value
+             for name, value in self._cache_reads.items()})
         if self._moe["layer_steps"]:
             tally = self._moe
             snap.update({"moe_" + key: value for key, value in tally.items()})

@@ -242,6 +242,7 @@ def run_inference(experiment, runtime=None) -> dict:
     summary stats ({"records", "batches", "tokens_per_sec",
     "padded_tokens_per_sec", ...})."""
     from tf_yarn_tpu.data.prefetch import prefetch
+    from tf_yarn_tpu.models.decode_engine import get_engine
     from tf_yarn_tpu.models.generate import generate
 
     shard, num_shards = 0, 1
@@ -261,6 +262,8 @@ def run_inference(experiment, runtime=None) -> dict:
     fs_lib.check_model_dir_placement(experiment.model_dir)
     with telemetry.span("inference/restore_params"):
         variables, step = _restore_params(experiment.model_dir, experiment.step)
+    # The engine `generate` routes through, before its first program.
+    variables = get_engine(experiment.model).hold_params(variables)
     _logger.info(
         "inference from ckpt-%d, shard %d/%d -> %s",
         step, shard, num_shards, experiment.output_path,

@@ -1094,6 +1094,26 @@ class DecodeEngine:
             p_bucket = prompt_len
         return b_bucket, p_bucket
 
+    def hold_params(self, params):
+        """The tree a server holds and hands to every program from here
+        on: `params` as restored (and, under a mesh, placed), with each
+        leaf the model only ever reads through a convert to a narrower
+        float type converted once (`models/param_types.py`: the rule is
+        read off the model's own jaxpr). The tree handed in is used up.
+        Call it before the first program is built: the compile keys
+        carry the leaves' types."""
+        from tf_yarn_tpu.models import param_types
+
+        with telemetry.span("serving/cast_params") as cast_span:
+            held, narrowed, before, after = param_types.narrow(
+                self.model, params)
+            cast_span.args.update(
+                leaves=narrowed, bytes_before=before, bytes_after=after)
+        with self._lock:
+            self.stats["param_bytes"] = after
+            self.stats["params_narrowed"] = narrowed
+        return held
+
     def _params_fingerprint(self, params) -> int:
         leaves, treedef = jax.tree_util.tree_flatten(params)
         return hash((treedef, tuple(

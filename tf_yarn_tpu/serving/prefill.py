@@ -485,6 +485,7 @@ def run_prefill(experiment, runtime=None) -> dict:
                 experiment.model, variables, mesh
             )
     engine = get_engine(experiment.model, mesh=mesh)
+    variables = engine.hold_params(variables)
     worker = PrefillWorker(
         engine, variables,
         block_size=experiment.block_size,

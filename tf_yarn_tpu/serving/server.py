@@ -502,6 +502,9 @@ def run_serving(experiment, runtime=None) -> dict:
                 experiment.model, variables, mesh
             )
     engine = get_engine(experiment.model, mesh=mesh)
+    # Before the first program is built: each matrix in the type the
+    # step reads it in, the wide originals let go (docs/Serving.md).
+    variables = engine.hold_params(variables)
     scheduler = SlotScheduler(
         engine,
         variables,

@@ -23,6 +23,10 @@ Nothing runs, so nothing here is a result or a time of the device. Programs:
             (cellbench/configs/dots3_note_serve_1chip.json): prefill at the
             2048 and 4096 buckets, pack, and the 64-slot paged state step
             (latent and index rows off the pool, rings held once a slot)
+    longcat the benchmark's LongCat-Flash share at its own sizes
+            (cellbench/configs/longcat_flash_serve_1chip.json): prefill at
+            the 512 and 1024 buckets, pack, and the 64-slot paged state step
+            (every leaf paged, the latent rows read by the paged kernel)
 
 Each line: how many Mosaic kernels (`tpu_custom_call`) and collectives the
 compiler emitted, and the bytes one device needs (temporaries + arguments).
@@ -215,18 +219,22 @@ def compile_serving(name, devices, kv_cache_dtype, attention, tp) -> None:
     report(f"{name} paged step", compiled, began, int8)
 
 
-def compile_latent(devices) -> None:
-    """The latent-attention share as the benchmark serves it: the expanded
+def compile_latent(devices, name="latent",
+                   config="dots3_note_serve_1chip", buckets=(2048, 4096),
+                   want_kernel=False) -> None:
+    """A latent-attention share as the benchmark serves it: the expanded
     prefill's peak (no [heads, bucket, context] float32 array) and the
     one-token step's (no [slots, context, ...] view of a leaf) are what the
-    compiler has to fit beside 5.2 GB of weights and 1.3 GB of cache."""
+    compiler has to fit beside the weights and the cache (dots3: 5.2 GB and
+    1.3 GB; LongCat-Flash: 10.3 GB and 2.7 GB). `want_kernel`: the step
+    reads the pool through the paged kernel."""
     import json
 
     from cellbench import agent
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "cellbench", "configs",
-                           "dots3_note_serve_1chip.json")) as fh:
+                           config + ".json")) as fh:
         sizes = json.load(fh)
     model = agent.build_model(sizes)
     slots = sizes["serving"]["max_slots"]
@@ -252,18 +260,19 @@ def compile_latent(devices) -> None:
     state = place(jax.tree_util.tree_map(
         lambda a, lay: jax.ShapeDtypeStruct((slots,) + a.shape, a.dtype)
         if lay.kind in de.HELD_A_SLOT else None, row, layout))
-    for bucket in (2048, 4096):
+    for bucket in buckets:
         began = time.monotonic()
         prompt = arg(jnp.int32, 1, bucket)
         compiled = jax.jit(prefill).lower(params, prompt).compile()
-        report(f"latent prefill[{bucket}]", compiled, began, False)
+        report(f"{name} prefill[{bucket}]", compiled, began, False)
     began = time.monotonic()
     compiled = jax.jit(
-        de.build_pack_prefill_fn(model, BLOCK, 2048), donate_argnums=(0,),
-    ).lower(pool, arg(jnp.int32, 2048 // BLOCK),
+        de.build_pack_prefill_fn(model, BLOCK, buckets[0]),
+        donate_argnums=(0,),
+    ).lower(pool, arg(jnp.int32, buckets[0] // BLOCK),
             place(jax.eval_shape(prefill, params,
-                                 arg(jnp.int32, 1, 2048))[0])).compile()
-    report("latent pack[2048]", compiled, began, False)
+                                 arg(jnp.int32, 1, buckets[0]))[0])).compile()
+    report(f"{name} pack[{buckets[0]}]", compiled, began, False)
     began = time.monotonic()
     compiled = jax.jit(
         de.build_paged_state_step_fn(model, BLOCK, 0.0, None, None),
@@ -271,7 +280,7 @@ def compile_latent(devices) -> None:
     ).lower(params, pool, state, arg(jnp.int32, slots, max_blocks),
             arg(jnp.int32, slots), arg(jnp.int32, slots),
             arg(jnp.uint32, slots, 2), arg(jnp.bool_, slots)).compile()
-    report("latent paged state step", compiled, began, False)
+    report(f"{name} paged state step", compiled, began, want_kernel)
     context = model.config.max_seq_len
     views = re.findall(rf"\[{slots},{context},\d+\]", compiled.as_text())
     if views:
@@ -300,6 +309,8 @@ def main(argv) -> int:
         "tp4": lambda: compile_serving("tp=4 bf16", four, "bf16",
                                        "gather", 4),
         "latent": lambda: compile_latent(one),
+        "longcat": lambda: compile_latent(
+            one, "longcat", "longcat_flash_serve_1chip", (512, 1024), True),
     }
     for name in argv or list(programs):
         programs[name]()

@@ -84,7 +84,12 @@ top_k / top_p are baked into the traced program) key the cache.
   collection beside the pool, read and written in place, and what the
   expert layers (and the cache reads, where a model counts them) counted
   returned beside the tokens. `write_slot_state` puts a prefill's final
-  state (or zeros) into a slot at admission.
+  state (or zeros) into a slot at admission. Which step a model gets
+  (`counted_step`) and whether anything of it is held once a slot
+  (`slot_state_leaves`) are two questions: a model whose every leaf is
+  paged and whose layers count (`count_mask` in its call) takes the same
+  step with an empty state, and nothing that moves whole blocks stands
+  aside for it.
 """
 
 from __future__ import annotations
@@ -1373,6 +1378,18 @@ class DecodeEngine:
 
     # -- state held once a slot ---------------------------------------------
 
+    def counted_step(self, params) -> bool:
+        """Whether the one-token step of this model is `paged_state_step`:
+        it holds leaves once a slot, which only that step carries, or its
+        call takes `count_mask`, so that its layers count what they routed
+        and read and only that step returns the counts. `paged_step`
+        serves every other model."""
+        import inspect
+
+        return "count_mask" in inspect.signature(
+            type(self.model).__call__).parameters \
+            or bool(self.slot_state_leaves(params))
+
     def slot_state_leaves(self, params) -> Tuple[str, ...]:
         """Names of the cache leaves the model holds once a slot (a
         recurrent state, a convolution's tail); empty for a model whose
@@ -1516,7 +1533,9 @@ class DecodeEngine:
         the plain gather elsewhere (under `tp` XLA shards it over KV
         heads, and a Pallas call cannot be partitioned). `/stats` names
         it: `decode_engine.paged_attention` ("model" where the pool's rows
-        have no head axis and the model's own attention reads them)."""
+        have no head axis and the model's own attention reads them; a
+        model that hands such rows to the same op as one KV head says so,
+        `pool_rows_are_one_kv_head`, and gets the choice)."""
         from tf_yarn_tpu.ops.decode_attention import paged_kernel_serves
 
         # Every tick: decided once a pool layout.
@@ -1524,14 +1543,17 @@ class DecodeEngine:
         how = self._paged_kernels.get(fp)
         if how is None:
             leaves = jax.tree_util.tree_leaves(pool)
-            if any(leaf.ndim < 5 for leaf in leaves):
+            one_head = getattr(self.model, "pool_rows_are_one_kv_head", False)
+            if any(leaf.ndim < 5 for leaf in leaves) and not one_head:
                 # [1, NB, bs, heads, dim] has a head axis; a leaf without
                 # one is read by its model's own attention, which this
                 # choice does not reach.
                 how = "model"
             elif self.tp_degree == 1 and all(
-                    paged_kernel_serves(
-                        jax.ShapeDtypeStruct(leaf.shape[-4:], leaf.dtype))
+                    paged_kernel_serves(jax.ShapeDtypeStruct(
+                        # a row the model reads as one KV head of its width
+                        leaf.shape[-3:-1] + (1,) + leaf.shape[-1:]
+                        if leaf.ndim < 5 else leaf.shape[-4:], leaf.dtype))
                     for leaf in leaves
                     if leaf.shape[-1] > 1):  # a scale leaf follows its values
                 how = "kernel"

@@ -47,8 +47,17 @@ slot's live index keys a chunk of blocks at a time (`indexer/scores`), picks
 sliding layer the ring (`window/read`). It sows what it read into
 `cache_stats` for the slots `count_mask` marks.
 
-A call of more than one token with `decode=True` is a prefill: it starts
-from an empty cache, whatever `cache_index` held.
+A call of more than one token with `decode=True` is a prefill: it makes the
+cache. Over a cache that is already there (the windowed step's gathered
+view) it is refused by name.
+
+A third kind, `plain_attention` (models/longcat.py), is the full kind's
+sizes with nothing between a query and its keys: every `j <= t`, no
+indexer, no gate, one paged leaf (`latent`). Its one-token step hands the
+absorbed query `[q~ | q_r]` to `ops.decode_attention.paged_decode_attention`
+as 64 heads over one KV head whose keys and values are the same cached row
+(`latent/read`): on a TPU the kernel that walks the slot's block table no
+further than its length, elsewhere the plain gather.
 """
 
 from __future__ import annotations
@@ -74,11 +83,14 @@ from tf_yarn_tpu.models.transformer import (
 
 HIGHEST = jax.lax.Precision.HIGHEST
 FULL, SLIDING = "full_attention", "sliding_attention"
+PLAIN = "plain_attention"
 RING_MULTIPLE = 16
 # What an attention layer sows into `cache_stats` a step, over the counted
 # slots: rows live and rows read of each leaf, and the keys selected.
 READS = ("index_live", "index_read", "index_selected", "latent_read",
          "window_live", "window_read")
+# What a plain layer sows: it reads every live row.
+PLAIN_READS = ("latent_live", "latent_read")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,8 +165,14 @@ class LatentConfig:
         tiles of the cache's type."""
         return -(-self.window // RING_MULTIPLE) * RING_MULTIPLE
 
+    @property
+    def n_attention_layers(self) -> int:
+        """Attention sublayers, each with cache leaves of its own: what the
+        scheduler divides the rows read by."""
+        return self.n_layers
+
     def sizes(self, kind: str) -> AttentionSizes:
-        return self.full if kind == FULL else self.sliding
+        return self.sliding if kind == SLIDING else self.full
 
     def stored_width(self, kind: str) -> int:
         """A cached row `[c | k_r]` as it is stored: whole lanes."""
@@ -162,7 +180,7 @@ class LatentConfig:
             * self.row_multiple
 
     def __post_init__(self):
-        unknown = set(self.layer_types) - {FULL, SLIDING}
+        unknown = set(self.layer_types) - {FULL, SLIDING, PLAIN}
         if unknown or not self.layer_types:
             raise ValueError(f"layer_types: {self.layer_types!r}")
         if self.kv_cache_dtype != "bf16":
@@ -306,20 +324,36 @@ def expanded_attention(q_n, q_r, rows, w_kvb, sizes: AttentionSizes, *,
         batch, nb * block, heads, sizes.d_v)[:, :s]
 
 
+def absorb_query(q_n, q_r, w_kvb, sizes: AttentionSizes, width: int,
+                 dtype=jnp.bfloat16):
+    """`[q~ | q_r]` padded with zeros to a cached row's `width`: q_n
+    [B, H, d_n], q_r [B, H, d_r], w_kvb [kv_rank, H, d_n + d_v] ->
+    [B, H, width]. A cached row is [c | k_r], so one product against it
+    gives both terms of the score and the row is never sliced."""
+    with jax.named_scope("latent/absorb"):
+        q_abs = jnp.einsum("bhn,rhn->bhr", q_n.astype(dtype),
+                           w_kvb[..., :sizes.d_nope],
+                           preferred_element_type=jnp.float32)
+        query = jnp.concatenate([q_abs, q_r], axis=-1).astype(dtype)
+        return jnp.pad(query, [(0, 0), (0, 0), (0, width - query.shape[-1])])
+
+
+def expand_values(mixed, w_kvb, sizes: AttentionSizes, dtype=jnp.bfloat16):
+    """Values out of the attended latents: mixed [B, H, >= kv_rank] (a
+    head's softmax-weighted sum of cached rows) -> [B, H, d_v] float32."""
+    with jax.named_scope("latent/values"):
+        return jnp.einsum("bhr,rhv->bhv",
+                          mixed[..., :sizes.kv_rank].astype(dtype),
+                          w_kvb[..., sizes.d_nope:],
+                          preferred_element_type=jnp.float32)
+
+
 def absorbed_attention(q_n, q_r, rows, valid, w_kvb, sizes: AttentionSizes,
                        dtype=jnp.bfloat16):
     """One token a row against cached rows, which are never expanded: q_n
     [B, H, d_n], q_r [B, H, d_r], rows [B, K, kv_rank + d_r], valid [B, K],
     w_kvb [kv_rank, H, d_n + d_v] -> [B, H, d_v] float32."""
-    with jax.named_scope("latent/absorb"):
-        q_abs = jnp.einsum("bhn,rhn->bhr", q_n.astype(dtype),
-                           w_kvb[..., :sizes.d_nope],
-                           preferred_element_type=jnp.float32)
-        # A cached row is [c | k_r], so one product against [q~ | q_r]
-        # gives both terms of the score and the row is never sliced.
-        query = jnp.concatenate([q_abs, q_r], axis=-1).astype(dtype)
-        query = jnp.pad(query, [(0, 0), (0, 0),
-                                (0, rows.shape[-1] - query.shape[-1])])
+    query = absorb_query(q_n, q_r, w_kvb, sizes, rows.shape[-1], dtype)
     with jax.named_scope("latent/scores"):
         scores = jnp.einsum(
             "bhw,bkw->bhk", query, rows, preferred_element_type=jnp.float32
@@ -328,10 +362,8 @@ def absorbed_attention(q_n, q_r, rows, valid, w_kvb, sizes: AttentionSizes,
         weights = jax.nn.softmax(scores, axis=-1)
     with jax.named_scope("latent/values"):
         mixed = jnp.einsum("bhk,bkw->bhw", weights.astype(dtype), rows,
-                           preferred_element_type=jnp.float32
-                           )[..., :sizes.kv_rank].astype(dtype)
-        return jnp.einsum("bhr,rhv->bhv", mixed, w_kvb[..., sizes.d_nope:],
-                          preferred_element_type=jnp.float32)
+                           preferred_element_type=jnp.float32)
+    return expand_values(mixed, w_kvb, sizes, dtype)
 
 
 def select_rows(q_index, weight, index_pool, latent_pool, tables, lengths,
@@ -397,7 +429,7 @@ class LatentAttention(nn.Module):
     @nn.compact
     def __call__(self, x, paged_ctx=None, count_mask=None):
         cfg, sizes = self.config, self.config.sizes(self.kind)
-        full = self.kind == FULL
+        full, plain = self.kind == FULL, self.kind == PLAIN
         batch, s, d = x.shape
         heads = sizes.n_heads
         f32, dtype = jnp.float32, cfg.dtype
@@ -406,7 +438,11 @@ class LatentAttention(nn.Module):
         norm_cfg = dataclasses.replace(cfg.norm_config(), dtype=f32)
         one_token = self.decode and s == 1
         paged = one_token and paged_ctx is not None
-        if self.decode and paged_ctx is not None and s != 1:
+        if self.decode and s != 1 and (
+                paged_ctx is not None
+                or self.has_variable("cache", "cache_index")):
+            # Over the pool, or over a cache that is already there (the
+            # gathered view of the windowed step): not a prefill.
             raise NotImplementedError(
                 f"the paged step of {type(self).__name__} reads one token a "
                 f"slot; a window of {s} (speculation, chunked prefill) does "
@@ -480,11 +516,17 @@ class LatentAttention(nn.Module):
                 k_index = _rope_front(k_index, positions, sizes.rope_theta,
                                       cfg.index_rope_dim).astype(dtype)
 
-        reads = dict.fromkeys(READS, 0)
+        names = PLAIN_READS if plain else READS
+        reads = dict.fromkeys(names, 0)
         if one_token:
             counted = jnp.ones((batch,), bool) if count_mask is None \
                 else count_mask
-            if full:
+            if plain:
+                out, read = self._step_plain(
+                    q_n[:, 0], q_r[:, 0], rows[:, 0], w_kvb, lengths,
+                    paged_ctx, counted)
+                reads.update(read)
+            elif full:
                 out, read = self._step_full(
                     q_n[:, 0], q_r[:, 0], q_index[:, 0], index_weight[:, 0],
                     rows[:, 0], k_index[:, 0], w_kvb, lengths, paged_ctx,
@@ -503,24 +545,29 @@ class LatentAttention(nn.Module):
                 index_var.value = index_var.value + 1
         else:
             if self.decode:
-                self._write_prefill(rows, k_index if full else None)
+                self._write_prefill(
+                    {"latent": rows, "index_key": k_index} if full
+                    else {"latent": rows})
                 index_var.value = jnp.asarray(s, jnp.int32)
             select = None
             if full and s > cfg.index_topk:
                 select = (q_index, index_weight, k_index, cfg.index_topk)
             out = expanded_attention(
                 q_n, q_r, rows, w_kvb, sizes, select=select,
-                window=0 if full else cfg.window,
+                window=cfg.window if self.kind == SLIDING else 0,
                 query_block=cfg.query_block, dtype=dtype)
         if count_mask is not None and one_token:
             self.sow("cache_stats", "reads", jnp.stack(
-                [jnp.asarray(reads[name], jnp.int32) for name in READS]))
+                [jnp.asarray(reads[name], jnp.int32) for name in names]))
 
-        with jax.named_scope("latent/gate"):
-            gate = nn.sigmoid(jnp.einsum("bsd,dh->bsh", x, matrix(
-                "gate", (d, heads), (EMBED, HEADS)),
-                preferred_element_type=f32))
-            out = (out * gate[..., None]).astype(dtype)
+        if plain:
+            out = out.astype(dtype)
+        else:
+            with jax.named_scope("latent/gate"):
+                gate = nn.sigmoid(jnp.einsum("bsd,dh->bsh", x, matrix(
+                    "gate", (d, heads), (EMBED, HEADS)),
+                    preferred_element_type=f32))
+                out = (out * gate[..., None]).astype(dtype)
         with jax.named_scope("latent/out"):
             return jnp.einsum(
                 "bsf,fd->bsd", out.reshape(batch, s, heads * sizes.d_v),
@@ -528,18 +575,20 @@ class LatentAttention(nn.Module):
                 preferred_element_type=f32).astype(dtype)
 
     @nn.nowrap
-    def _write_prefill(self, rows, k_index):
-        """A prefill's rows into a fresh dense cache: a full layer's at
-        [0, s) of `latent` and `index_key`; a sliding layer's last
-        `ring_len` into the ring, position p at row `p % ring_len`."""
+    def _write_prefill(self, fresh):
+        """A prefill's rows (`fresh`: leaf name -> [B, s, width]) into a
+        fresh dense cache: a full or plain layer's at [0, s) of each leaf;
+        a sliding layer's last `ring_len` into the ring, position p at row
+        `p % ring_len`."""
         cfg = self.config
-        batch, s, width = rows.shape
 
         def put(name, value):
             self.variable("cache", name, lambda: value).value = value
 
         with jax.named_scope("latent/cache_write"):
-            if k_index is None:
+            if self.kind == SLIDING:
+                rows = fresh["latent"]
+                batch, s, width = rows.shape
                 ring = cfg.ring_len
                 kept = min(s, ring)
                 put("window_latent",
@@ -547,9 +596,9 @@ class LatentAttention(nn.Module):
                         :, jnp.arange(s - kept, s) % ring].set(
                         rows[:, s - kept:]))
                 return
-            for name, fresh in (("latent", rows), ("index_key", k_index)):
-                put(name, jnp.pad(
-                    fresh, [(0, 0), (0, cfg.max_seq_len - s), (0, 0)]))
+            for name, value in fresh.items():
+                put(name, jnp.pad(value, [
+                    (0, 0), (0, cfg.max_seq_len - value.shape[1]), (0, 0)]))
 
     @nn.nowrap
     def _step_full(self, q_n, q_r, q_index, index_weight, row, k_index,
@@ -557,41 +606,8 @@ class LatentAttention(nn.Module):
         """One token a slot on a full layer: write the token's rows, score
         the live index keys, gather the chosen latent rows, attend."""
         cfg, sizes = self.config, self.config.full
-        batch = row.shape[0]
-        if paged_ctx is not None:
-            def _missing():
-                raise ValueError(
-                    "the paged step needs the kv_pool collection (the "
-                    "engine's paged_state_step provides it)")
-
-            pools = {name: self.variable("kv_pool", name, _missing)
-                     for name in ("latent", "index_key")}
-            tables = paged_ctx.tables
-            unwrap = {name: var.value[0] for name, var in pools.items()}
-        else:
-            # The dense cache as a pool of one block a row.
-            widths = {"latent": row.shape[-1], "index_key": cfg.index_dim}
-            pools = {name: self.variable(
-                "cache", name, lambda w=w: jnp.zeros(
-                    (batch, cfg.max_seq_len, w), cfg.dtype))
-                for name, w in widths.items()}
-            tables = jnp.arange(batch, dtype=jnp.int32)[:, None]
-            unwrap = {name: var.value for name, var in pools.items()}
-        block_size = unwrap["latent"].shape[1]
-        max_blocks = tables.shape[1]
-        logical = lengths // block_size
-        # A row past the slot's blocks goes to the trash block 0.
-        blocks = jnp.where(
-            logical < max_blocks, jnp.take_along_axis(
-                tables, jnp.clip(logical, 0, max_blocks - 1)[:, None],
-                axis=1)[:, 0], 0)
-        with jax.named_scope("latent/cache_write"):
-            fresh = {"latent": row, "index_key": k_index}
-            for name in unwrap:
-                unwrap[name] = unwrap[name].at[
-                    blocks, lengths % block_size].set(fresh[name])
-                pools[name].value = unwrap[name][None] \
-                    if paged_ctx is not None else unwrap[name]
+        unwrap, tables = self._write_token(
+            {"latent": row, "index_key": k_index}, lengths, paged_ctx)
         rows, valid, chosen, index_read = select_rows(
             q_index, index_weight, unwrap["index_key"], unwrap["latent"],
             tables, lengths + 1, cfg.index_topk, cfg.index_chunk)
@@ -603,6 +619,81 @@ class LatentAttention(nn.Module):
             "index_read": jnp.sum(counted) * index_read,
             "index_selected": jnp.sum(valid & counted[:, None]),
             "latent_read": jnp.sum(counted) * rows.shape[1]}
+
+    @nn.nowrap
+    def _write_token(self, fresh, lengths, paged_ctx):
+        """This token's rows (`fresh`: leaf name -> [slots, width]) into the
+        slots' blocks at their lengths. -> (name -> the written leaf
+        [NB, bs, width], tables [slots, MB]). The leaves are the `kv_pool`
+        collection's in the paged step; elsewhere the dense cache, taken as
+        a pool of one block a row."""
+        cfg = self.config
+        batch = lengths.shape[0]
+        if paged_ctx is not None:
+            def _missing():
+                raise ValueError(
+                    "the paged step needs the kv_pool collection (the "
+                    "engine's paged_state_step provides it)")
+
+            pools = {name: self.variable("kv_pool", name, _missing)
+                     for name in fresh}
+            tables = paged_ctx.tables
+            unwrap = {name: var.value[0] for name, var in pools.items()}
+        else:
+            # The dense cache as a pool of one block a row.
+            pools = {name: self.variable(
+                "cache", name, lambda w=value.shape[-1]: jnp.zeros(
+                    (batch, cfg.max_seq_len, w), cfg.dtype))
+                for name, value in fresh.items()}
+            tables = jnp.arange(batch, dtype=jnp.int32)[:, None]
+            unwrap = {name: var.value for name, var in pools.items()}
+        block_size = unwrap["latent"].shape[1]
+        max_blocks = tables.shape[1]
+        logical = lengths // block_size
+        # A row past the slot's blocks goes to the trash block 0.
+        blocks = jnp.where(
+            logical < max_blocks, jnp.take_along_axis(
+                tables, jnp.clip(logical, 0, max_blocks - 1)[:, None],
+                axis=1)[:, 0], 0)
+        with jax.named_scope("latent/cache_write"):
+            for name in unwrap:
+                unwrap[name] = unwrap[name].at[
+                    blocks, lengths % block_size].set(fresh[name])
+                pools[name].value = unwrap[name][None] \
+                    if paged_ctx is not None else unwrap[name]
+        return unwrap, tables
+
+    @nn.nowrap
+    def _step_plain(self, q_n, q_r, row, w_kvb, lengths, paged_ctx, counted):
+        """One token a slot on a plain layer: write the token's row, then
+        every live row of the slot off the pool through its table, as 64
+        heads over one KV head whose keys and values are the same row."""
+        from tf_yarn_tpu.ops.decode_attention import (
+            paged_chunk_tokens,
+            paged_decode_attention,
+            paged_kernel_serves,
+        )
+
+        cfg, sizes = self.config, self.config.sizes(PLAIN)
+        unwrap, tables = self._write_token({"latent": row}, lengths, paged_ctx)
+        pool = unwrap["latent"][:, :, None, :]        # [NB, bs, 1, width]
+        block_size, max_blocks = pool.shape[1], tables.shape[1]
+        kernel = False if paged_ctx is None else paged_ctx.kernel
+        if kernel is None:
+            kernel = paged_kernel_serves(pool)
+        query = absorb_query(q_n, q_r, w_kvb, sizes, row.shape[-1], cfg.dtype)
+        with jax.named_scope("latent/read"):
+            mixed = paged_decode_attention(
+                query, pool, pool, tables, lengths + 1,
+                (sizes.d_nope + sizes.d_rope) ** -0.5, kernel=kernel)
+        # The kernel reads a slot's length rounded up to its chunk; the
+        # plain gather the whole table.
+        chunk = paged_chunk_tokens(block_size, max_blocks) if kernel \
+            else block_size * max_blocks
+        live = jnp.where(counted, lengths + 1, 0)
+        return expand_values(mixed, w_kvb, sizes, cfg.dtype), {
+            "latent_live": jnp.sum(live),
+            "latent_read": jnp.sum(-(-live // chunk) * chunk)}
 
     @nn.nowrap
     def _step_window(self, q_n, q_r, row, w_kvb, lengths):

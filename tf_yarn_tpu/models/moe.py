@@ -8,12 +8,14 @@ expert group per ep shard and inserts the all-to-alls itself. No analog
 exists in the reference (SURVEY.md §2.5: expert parallelism — NO).
 
 `DroplessMoE` is the other equation, the one served models use
-(models/hybrid.py, models/latent.py): a router over all experts of the
-deployment in float32, the `top_k` largest, gates over those alone (a
-softmax of their logits, or their sigmoid scores, chosen under a correction
-bias, normalised and scaled), no capacity and no dropped token, plus a
-shared expert every token passes. It is told which experts this chip holds
-and returns their part of the sum.
+(models/hybrid.py, models/latent.py, models/longcat.py): a router over all
+experts of the deployment in float32, the `top_k` largest, gates over those
+alone (a softmax of their logits, or their sigmoid scores, chosen under a
+correction bias, normalised and scaled) or a softmax over every output of
+the router, no capacity and no dropped token, plus a shared expert every
+token passes and, where the router is wider than the experts that exist,
+zero-compute experts that hand a token back. It is told which experts this
+chip holds and returns their part of the sum.
 """
 
 from __future__ import annotations
@@ -121,6 +123,17 @@ class DroplessMoE(nn.Module):
     recipe): `s = sigmoid(logits)`; the `top_k` largest of `s + b`, `b` the
     float32 correction bias `router_bias` (one group); `g_i = s_i`, over
     `sum_chosen s` where `norm_topk`, times `routed_scale`.
+    `scoring="softmax_all"`: `p = softmax(logits)` over every output of the
+    router; the `top_k` largest of `p + b`; `g_i = p_i` (over their sum where
+    `norm_topk`) times `routed_scale`.
+
+    `num_zero_experts` > 0 widens the router (and the bias) by that many
+    outputs past the `num_experts` that have matrices: a chosen output
+    `i >= num_experts` is a zero-compute expert that returns its input, so
+    the layer adds `(sum of those g_i) x` (`moe/identity`). That term needs
+    the router alone, which every chip holds whole: every chip computes it
+    alike, and where the shares of a layer are summed it counts once, as
+    the shared expert does.
 
     The router is as wide as the deployment (`num_experts`), and this chip
     holds `num_experts_here` of them starting at `expert_offset`: the sum
@@ -137,8 +150,9 @@ class DroplessMoE(nn.Module):
 
     `count_mask` [T] marks the tokens whose routing is counted into the
     mutable `moe_stats` collection (`counts` [1 + held]: their assignments
-    over all experts, then the tokens that reached each held expert);
-    without that collection nothing is counted.
+    over all experts, then the tokens that reached each held expert; with
+    `num_zero_experts`, one more at the end: their assignments to
+    zero-compute experts); without that collection nothing is counted.
     """
 
     num_experts: int
@@ -150,6 +164,7 @@ class DroplessMoE(nn.Module):
     scoring: str = "softmax"
     norm_topk: bool = True
     routed_scale: float = 1.0
+    num_zero_experts: int = 0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
 
@@ -157,8 +172,9 @@ class DroplessMoE(nn.Module):
     def __call__(self, x, count_mask=None):
         t, d = x.shape
         held, width = self.num_experts_here, self.d_expert
-        if self.scoring not in ("softmax", "sigmoid"):
+        if self.scoring not in ("softmax", "sigmoid", "softmax_all"):
             raise ValueError(f"scoring: {self.scoring!r}")
+        outputs = self.num_experts + self.num_zero_experts
         if not 0 < held <= self.num_experts - self.expert_offset:
             raise ValueError(
                 f"experts [{self.expert_offset}, {self.expert_offset + held})"
@@ -169,7 +185,7 @@ class DroplessMoE(nn.Module):
         with jax.named_scope("moe/router"):
             router = self.param(
                 "router", _partitioned((EMBED, None))(normal),
-                (d, self.num_experts), self.param_dtype,
+                (d, outputs), self.param_dtype,
             )
             # float32 at full precision: which ten are largest decides
             # whole expert outputs, not a rounding.
@@ -182,8 +198,9 @@ class DroplessMoE(nn.Module):
                 gates = jax.nn.softmax(top_logits, axis=-1)
             else:
                 bias = self.param("router_bias", nn.initializers.zeros_init(),
-                                  (self.num_experts,), jnp.float32)
-                scores = nn.sigmoid(logits)
+                                  (outputs,), jnp.float32)
+                scores = nn.sigmoid(logits) if self.scoring == "sigmoid" \
+                    else jax.nn.softmax(logits, axis=-1)
                 _, top_index = jax.lax.top_k(scores + bias, self.top_k)
                 gates = jnp.take_along_axis(scores, top_index, axis=-1)
                 if self.norm_topk:
@@ -199,9 +216,14 @@ class DroplessMoE(nn.Module):
                 # [1 + held]: the counted tokens' assignments over all the
                 # deployment's experts, then those that reached each held one.
                 counted = chose & count_mask[:, None, None]
-                self.sow("moe_stats", "counts", jnp.concatenate([
+                counts = [
                     jnp.sum(count_mask, dtype=jnp.int32)[None] * self.top_k,
-                    jnp.sum(counted, axis=(0, 1), dtype=jnp.int32)]))
+                    jnp.sum(counted, axis=(0, 1), dtype=jnp.int32)]
+                if self.num_zero_experts:
+                    counts.append(jnp.sum(
+                        (top_index >= self.num_experts) & count_mask[:, None],
+                        dtype=jnp.int32)[None])
+                self.sow("moe_stats", "counts", jnp.concatenate(counts))
         with jax.named_scope("moe/experts"):
             w_in = self.param(
                 "w_in", _partitioned((EXPERT, EMBED, MLP))(normal),
@@ -220,6 +242,11 @@ class DroplessMoE(nn.Module):
             act = (act * weights.T[:, :, None]).astype(self.dtype)
             out = jnp.einsum("etf,efd->td", act, w_out.astype(self.dtype),
                              preferred_element_type=jnp.float32)
+        if self.num_zero_experts:
+            with jax.named_scope("moe/identity"):
+                handed_back = jnp.sum(jnp.where(
+                    top_index >= self.num_experts, gates, 0.0), axis=-1)
+                out = out + handed_back[:, None] * x.astype(jnp.float32)
         if self.d_shared:
             with jax.named_scope("moe/shared"):
                 s_in = self.param(

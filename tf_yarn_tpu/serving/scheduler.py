@@ -265,6 +265,18 @@ class SlotScheduler:
     the host swap tier, chunked prefill, the
     speculative / fused window, tensor parallelism and prefix export /
     import are refused with an error naming the feature and the leaves.
+
+    Which step a model gets is another question (the engine's
+    ``counted_step``): a model whose layers count what they routed and
+    read is stepped by ``paged_state_step`` whether or not it holds
+    anything once a slot. Where every leaf is paged (docs/Serving.md "A
+    model whose every leaf is paged and latent") the state is empty, the
+    counts still come back with the tokens, and the prefix cache, the
+    host swap tier and prefix export / import, which move whole blocks
+    and nothing else, serve it as they serve keys and values. Such a
+    server proves the step its ticks will take at construction, the
+    windowed one where one is asked for, so that a model that refuses
+    it does so by name before anything is served.
     """
 
     def __init__(
@@ -320,6 +332,11 @@ class SlotScheduler:
         leaves_of = getattr(engine, "slot_state_leaves", None)
         self._state_leaves: Tuple[str, ...] = tuple(
             leaves_of(params)) if leaves_of else ()
+        # Whether the one-token step is `paged_state_step`: asked apart
+        # from whether anything is held once a slot.
+        counted_of = getattr(engine, "counted_step", None)
+        self._counted_step = bool(counted_of(params)) if counted_of \
+            else bool(self._state_leaves)
         self.temperature = float(temperature)
         self.top_k = top_k
         self.top_p = top_p
@@ -499,8 +516,9 @@ class SlotScheduler:
         )
         self._lengths = np.zeros((max_slots,), np.int32)
         kv_bytes = _cache_nbytes(self._pool)
-        if self._state_leaves:
+        if self._counted_step:
             try:
+                # All None where every leaf is paged.
                 self._state = engine.make_slot_state(params, max_slots)
             except Exception as exc:
                 raise RuntimeError(
@@ -530,8 +548,8 @@ class SlotScheduler:
         ).set(self._kv_bytes_per_device)
         self._registry.gauge("serving/tp_degree").set(self.tp_degree)
         self._registry.gauge("serving/state_hbm_bytes").set(self._state_bytes)
-        if self._state is not None:
-            self._prove_state_step()
+        if self._counted_step:
+            self._prove_step()
 
     def _refuse_for_state(self, kv_host_blocks: int) -> None:
         """A model with state held once a slot: every feature that would
@@ -555,33 +573,47 @@ class SlotScheduler:
                     "held once a slot\")"
                 )
 
-    def _prove_state_step(self) -> None:
-        """Compile and run the step once on the empty grid, so that state
-        slots that do not fit, or a step the compiler refuses, stop the
-        server at start-up with the reason — not every request with
-        `error` under a /healthz that says ok. Free slots' rows go to the
-        trash block and admission rewrites a slot's state, so the run
-        leaves nothing behind."""
+    def _prove_step(self) -> None:
+        """Compile and run once on the empty grid the step the ticks will
+        take, so that state slots that do not fit, or a step the compiler
+        or the model refuses (a window of tokens through a layer that
+        reads one a slot), stop the server at start-up with the reason —
+        not every request with `error` under a /healthz that says ok. Free
+        slots' rows go to the trash block and admission rewrites a slot's
+        state, so the run leaves nothing behind."""
         import jax
 
+        sampling = dict(block_size=self._block_size,
+                        temperature=self.temperature, top_k=self.top_k,
+                        top_p=self.top_p)
+        idle = np.zeros((self.max_slots,), bool)
         try:
-            self._pool, self._state, emitted, *_ = \
-                self.engine.paged_state_step(
-                    self.params, self._pool, self._state, self._tables,
-                    self._lengths, np.zeros((self.max_slots,), np.int32),
-                    self._rngs, np.zeros((self.max_slots,), bool),
-                    block_size=self._block_size,
-                    temperature=self.temperature, top_k=self.top_k,
-                    top_p=self.top_p,
-                )
+            if self._windowed:
+                width = self._window_width
+                self._pool, emitted, *_ = self.engine.paged_spec_step(
+                    self.params, self._pool, self._tables, self._lengths,
+                    np.zeros((self.max_slots, width), np.int32),
+                    np.zeros((self.max_slots,), np.int32),
+                    np.full((self.max_slots,), -1, np.int32), self._rngs,
+                    idle, decode_attention=self.decode_attention, **sampling)
+            else:
+                self._pool, self._state, emitted, *_ = \
+                    self.engine.paged_state_step(
+                        self.params, self._pool, self._state, self._tables,
+                        self._lengths, np.zeros((self.max_slots,), np.int32),
+                        self._rngs, idle, **sampling)
             jax.block_until_ready(emitted)
         except Exception as exc:
+            held = (f"per-slot state ({', '.join(self._state_leaves)}; "
+                    f"{self._state_bytes} bytes of state for "
+                    f"{self.max_slots} slots beside {self._kv_bytes} bytes "
+                    "of KV pool)") if self._state_leaves else (
+                f"every cache leaf paged ({self._kv_bytes} bytes of pool "
+                f"for {self.max_slots} slots)")
+            family = "windowed" if self._windowed else "paged"
             raise RuntimeError(
-                "serving cannot start: the paged step of a model with "
-                f"per-slot state ({', '.join(self._state_leaves)}; "
-                f"{self._state_bytes} bytes of state for {self.max_slots} "
-                f"slots beside {self._kv_bytes} bytes of KV pool) did not "
-                f"compile or run: {type(exc).__name__}: {exc}"
+                f"serving cannot start: the {family} step of a model with "
+                f"{held} did not compile or run: {type(exc).__name__}: {exc}"
             ) from exc
 
     # -- submission (any thread) -------------------------------------------
@@ -935,7 +967,7 @@ class SlotScheduler:
         # The step consuming the LAST prompt token samples the first
         # generated token, so at most len(prompt) - 1 tokens may come
         # from the prefix cache.
-        if self._state is not None:
+        if self._state_leaves:
             # A hit would hand over keys and values and no state: the
             # prefix cache stands aside (neither hits nor registers).
             hit_tokens, hit_ids = 0, []
@@ -985,12 +1017,12 @@ class SlotScheduler:
                         np.asarray(blocks[:n_pack], np.int32),
                         row_cache, prefill_len, self._block_size,
                     )
-                    if self._state is None:
+                    if not self._state_leaves:
                         # Offer the full-block prefix for sharing; the
                         # partial tail block stays private (the replay
                         # writes it).
                         self._prefix.register(prompt, prefill_len, blocks)
-                if self._state is not None:
+                if self._state_leaves:
                     # The prefill's final state, or zeros where nothing
                     # was prefilled, before the first replayed token: a
                     # reused slot never runs on its predecessor's state.
@@ -1424,7 +1456,7 @@ class SlotScheduler:
                     tokens[slot] = state.last_token
                     mask[slot] = True
             counts = reads = None
-            if self._state is not None:
+            if self._counted_step:
                 # `reads`: after the five, where the model counts them.
                 self._pool, self._state, emitted, rngs, counts, *reads = \
                     self.engine.paged_state_step(
@@ -1528,15 +1560,21 @@ class SlotScheduler:
             tally[name] = tally.get(name, 0) + int(value)
         self._kv_read_token_steps += sum(
             int(value) for name, value in zip(names, reads)
-            if name.endswith("_read")) // self.engine.model.config.n_layers
+            if name.endswith("_read")
+        ) // self.engine.model.config.n_attention_layers
 
     def _count_experts(self, counts: np.ndarray) -> None:
         """One step's `[layers, 1 + held experts]`: the active slots'
         assignments over all the deployment's experts, then the tokens
-        that reached each expert held here. A layer-step is one expert
-        layer in one step."""
-        load = counts[:, 1:]
+        that reached each expert held here (and, of a router with
+        zero-compute experts, the assignments to those last). A layer-step
+        is one expert layer in one step."""
         tally = self._moe
+        if getattr(self.engine.model.config, "num_zero_experts", 0):
+            tally["assignments_zero"] = tally.get("assignments_zero", 0) \
+                + int(counts[:, -1].sum())
+            counts = counts[:, :-1]
+        load = counts[:, 1:]
         tally["assignments"] += int(counts[:, 0].sum())
         tally["assignments_here"] += int(load.sum())
         tally["layer_steps"] += int(load.shape[0])
@@ -1960,7 +1998,7 @@ class SlotScheduler:
             "inflight": tier_inflight,
             "caps": dict(self.tier_caps),
         }
-        if self._state is not None:
+        if self._counted_step:
             snap["state_leaves"] = list(self._state_leaves)
             snap["state_bytes"] = self._state_bytes
             snap["cache_bytes_by_kind"] = dict(self._cache_bytes_by_kind)

@@ -206,7 +206,8 @@ def compile_serving(name, devices, kv_cache_dtype, attention, tp) -> None:
                                    paged_kernel=None if tp == 1 else False),
             donate_argnums=(1, 5),
             out_shardings=(pool_shardings, replicated, replicated),
-        ).lower(params, pool, tables, lengths, arg(jnp.int32, SLOTS), rngs,
+        ).lower(params, pool, tables, lengths,
+                *(arg(a.dtype, *a.shape) for a in de.feed_avals(SLOTS)),
                 active).compile()
     else:
         compiled = jax.jit(
@@ -278,8 +279,9 @@ def compile_latent(devices, name="latent",
         de.build_paged_state_step_fn(model, BLOCK, 0.0, None, None),
         donate_argnums=(1, 2, 6),
     ).lower(params, pool, state, arg(jnp.int32, slots, max_blocks),
-            arg(jnp.int32, slots), arg(jnp.int32, slots),
-            arg(jnp.uint32, slots, 2), arg(jnp.bool_, slots)).compile()
+            arg(jnp.int32, slots),
+            *(arg(a.dtype, *a.shape) for a in de.feed_avals(slots)),
+            arg(jnp.bool_, slots)).compile()
     report(f"{name} paged state step", compiled, began, want_kernel)
     context = model.config.max_seq_len
     views = re.findall(rf"\[{slots},{context},\d+\]", compiled.as_text())

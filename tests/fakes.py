@@ -8,6 +8,7 @@ like the real program, a slot's "cache" is the sum of the tokens it
 consumed, and a sampled position emits ``sum % 97`` — so the tests can
 precompute every stream, and a table / length / registration bug changes
 an emission and fails them. Every call is logged for ordering assertions.
+Last, one drive that the serving tests of every model share.
 """
 
 import numpy as np
@@ -47,9 +48,14 @@ class FakePagedEngine:
             pool[block, pos % block_size] = row_cache[pos]
         return pool
 
-    def paged_step(self, params, pool, tables, lengths, tokens, rngs,
-                   sample_mask, block_size, temperature=0.0, top_k=None,
-                   top_p=None):
+    def paged_step(self, params, pool, tables, lengths, emitted, rngs,
+                   tokens, rng_rows, forced, sample_mask, block_size,
+                   temperature=0.0, top_k=None, top_p=None):
+        # The real program's feed: the step before's outputs unless the
+        # host forces its own.
+        forced = np.asarray(forced)
+        tokens = np.where(forced, tokens, emitted)
+        rngs = np.where(forced[:, None], rng_rows, rngs)
         self.calls.append(
             ("paged_step", tuple(int(t) for t in np.asarray(tokens)),
              tuple(bool(m) for m in np.asarray(sample_mask)))
@@ -156,3 +162,81 @@ def fake_scheduler(engine, max_slots=2, **kwargs):
     kwargs.setdefault("max_seq_len", engine.max_seq_len)
     return SlotScheduler(engine, params=None, max_slots=max_slots, **kwargs)
 
+
+# --------------------------------------------------------------------------
+# A drive shared by the serving tests of every model
+# --------------------------------------------------------------------------
+
+def mixed_trace_streams(scheduler, settle_every_tick, eos_token=None,
+                        vocab=256):
+    """Hand-drive one trace through `scheduler` and return
+    ({name: tokens}, {name: finish reason}). Five requests over the grid's
+    slots (two, for the mix to happen): `long` (prefill + one replayed
+    token + decode to `max_new_tokens`), `replayed` (too short for a
+    prefill bucket: its whole prompt replays), `eos` (ends at `eos_token`
+    if one is given), `queued` (admitted into a freed slot) and `doomed`,
+    whose deadline falls once it has two tokens, so with a step of its own
+    in flight where the pipeline runs. `settle_every_tick` empties the
+    pipeline after each tick: the serial order on the same program, every
+    token read before anything else is decided."""
+    from tf_yarn_tpu.serving import SamplingParams
+
+    rng = np.random.RandomState(11)
+    responses = {}
+    for seed, (name, length, max_new) in enumerate(
+            [("long", 9, 7), ("replayed", 3, 3), ("eos", 5, 8),
+             ("queued", 6, 4), ("doomed", 5, 12)], start=1):
+        responses[name] = scheduler.submit(
+            rng.randint(1, vocab, length).tolist(),
+            SamplingParams(
+                max_new_tokens=max_new, seed=seed,
+                eos_token=eos_token if name == "eos" else None,
+                temperature=scheduler.temperature, top_k=scheduler.top_k,
+                top_p=scheduler.top_p))
+    doomed = responses["doomed"]
+    for _ in range(400):
+        scheduler.tick()
+        if settle_every_tick:
+            scheduler._settle("test")
+        if len(doomed.token_times) >= 2 and doomed.request.timeout_s is None:
+            doomed.request.timeout_s = 1e-9
+        if all(response.done for response in responses.values()):
+            break
+    else:
+        raise AssertionError("the trace did not drain in 400 ticks")
+    return ({name: response.result(timeout=1)
+             for name, response in responses.items()},
+            {name: response.finish_reason
+             for name, response in responses.items()})
+
+
+def assert_pipelined_equals_settled(scheduler, vocab=256):
+    """The pipelined scheduler and one settled after every tick give the
+    same streams on the same compiled program: `mixed_trace_streams` three
+    times through one scheduler (settled without an eos, to learn a token
+    to end on; settled and pipelined with it)."""
+    learned, _ = mixed_trace_streams(scheduler, True, vocab=vocab)
+    stream = learned["eos"]
+    # The first token that did not occur before it: the stream ends there.
+    cut = next((i for i in range(1, len(stream))
+                if stream[i] not in stream[:i]), 0)
+    opened = scheduler.stats()
+    settled, reasons = mixed_trace_streams(
+        scheduler, True, stream[cut], vocab=vocab)
+    between = scheduler.stats()
+    pipelined, pipelined_reasons = mixed_trace_streams(
+        scheduler, False, stream[cut], vocab=vocab)
+    closed = scheduler.stats()
+    assert pipelined == settled and pipelined_reasons == reasons
+    assert reasons == {"long": "length", "replayed": "length", "eos": "eos",
+                       "queued": "length", "doomed": "deadline"}
+    assert settled["eos"] == stream[:cut + 1]
+    assert len(settled["doomed"]) == 2
+    for name in ("long", "replayed", "queued"):
+        assert settled[name] == learned[name]
+    # Settled after every tick no launch finds a step unread; left alone
+    # nearly every launch does.
+    assert between["steps_ahead"] == opened["steps_ahead"]
+    steps = closed["steps"] - between["steps"]
+    assert closed["steps_ahead"] - between["steps_ahead"] >= 0.8 * steps
+    return closed

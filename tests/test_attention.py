@@ -493,8 +493,8 @@ def _head_count_over_cache(jaxpr):
 
 
 def _paged_avals(model):
-    """(params, pool, row, the step's five host arrays) as shapes: 3 slots
-    of 48 tokens in blocks of 4."""
+    """(params, pool, row, the step's arguments after its trees) as shapes:
+    3 slots of 48 tokens in blocks of 4."""
     from tf_yarn_tpu.models import decode_engine
 
     params = nn.meta.unbox(jax.eval_shape(
@@ -506,8 +506,7 @@ def _paged_avals(model):
         model, row, _G_SLOTS * per_slot + 1, _G_BLOCK)
     host = (jax.ShapeDtypeStruct((_G_SLOTS, per_slot), jnp.int32),  # tables
             jax.ShapeDtypeStruct((_G_SLOTS,), jnp.int32),           # lengths
-            jax.ShapeDtypeStruct((_G_SLOTS,), jnp.int32),           # tokens
-            jax.ShapeDtypeStruct((_G_SLOTS, 2), jnp.uint32),        # rngs
+            *decode_engine.feed_avals(_G_SLOTS),       # tokens and rng rows
             jax.ShapeDtypeStruct((_G_SLOTS,), bool))                # mask
     return params, pool, row, host
 
@@ -724,8 +723,9 @@ def _step_logits(builder, model, extra, paged_kernel):
                            paged_kernel=paged_kernel))
     out = step(params, pool, *extra(row, slots), tables,
                np.asarray([0, 5, 21], np.int32),
-               np.asarray([3, 4, 5], np.int32),
-               np.zeros((slots, 2), np.uint32), np.ones((slots,), bool))
+               *decode_engine.all_forced(np.asarray([3, 4, 5], np.int32),
+                                         np.zeros((slots, 2), np.uint32)),
+               np.ones((slots,), bool))
     return np.asarray(out[-1], np.float32), out[0]
 
 

@@ -66,3 +66,43 @@ def test_every_new_quantity_is_declared_in_its_cells_with_a_file():  # noqa: F81
         assert entry["moves"] == {"steady": "itl_p90_ms",
                                   "backlog": "serve_tokens_per_s"}[suffix]
         assert set(theirs.run_lib.metric_file(name)) <= {"reader", "args"}
+
+
+def test_step_ahead_share_is_declared_as_data_and_silent_without_its_counters():
+    """PR 38's per-layer metric: one data file for an accepted reader, two
+    entries at the end of `per_layer` (the steady cell's moves `itl_p90_ms`,
+    the four closed loops' `serve_tokens_per_s`). The reader gives the share
+    of one-token steps launched while the step before was unread, and nothing
+    (no error) for a program whose `/stats` has no such counters, as the
+    parent's."""
+    import json
+    import os
+
+    from cellbench.readers import stats_share
+    from cellbench.tests import test_readers_tracing as theirs
+
+    with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    steady, backlog = bench["per_layer"][-2:]
+    closed_loops = [c["name"] for c in bench["workloads"]
+                    if c["name"].endswith("_backlog")]
+    assert steady == {
+        "name": "step_ahead_share.steady", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "server and scheduler",
+        "moves": "itl_p90_ms", "workloads": ["mistral7b_chat_steady"]}
+    assert backlog == dict(
+        steady, name="step_ahead_share.backlog", moves="serve_tokens_per_s",
+        workloads=closed_loops)
+    assert len(closed_loops) == 4
+    for name in (steady["name"], backlog["name"]):
+        assert theirs.run_lib.metric_file(name) == {
+            "reader": "stats_share", "args": {
+                "numerator": ["steps_ahead"], "denominator": ["steps"]}}
+    args = theirs.run_lib.metric_file(steady["name"])["args"]
+    run = {"stats_open": {"steps": 100, "steps_ahead": 90, "ticks": 101},
+           "stats_close": {"steps": 1100, "steps_ahead": 1070, "ticks": 1111}}
+    assert stats_share.read(run, **args) == 98.0
+    parent = {"stats_open": {"ticks": 101}, "stats_close": {"ticks": 1111}}
+    assert stats_share.read(parent, **args) is None
+    idle = {"stats_open": run["stats_open"], "stats_close": run["stats_open"]}
+    assert stats_share.read(idle, **args) is None

@@ -17,12 +17,14 @@ import pytest
 from tf_yarn_tpu.models import transformer
 from tf_yarn_tpu.models.decode_engine import (
     DecodeEngine,
+    all_forced,
     build_decode_fn,
     build_paged_step_fn,
     build_prefill_fn,
     cache_layout,
     cache_nbytes,
     clear_engines,
+    feed_avals,
     get_engine,
     paged_pool_avals,
 )
@@ -314,11 +316,16 @@ def test_pack_prefill_touches_only_its_own_blocks():
 
 
 def _drive_paged_slots(model, engine, params, prompts, seeds, max_new,
-                       sampling, block_size):
+                       sampling, block_size, fed=False):
     """Drive make_paged_pool/pack_prefill/paged_step by hand (the
     scheduler's device contract) and return each slot's emitted stream.
     Physical blocks are handed out in an interleaved order on purpose —
-    correctness must come from the block TABLE, not from contiguity."""
+    correctness must come from the block TABLE, not from contiguity.
+    `fed`: a decoding slot's token and rng row stay on the device from
+    one step to the next, as the scheduler leaves them, and the host
+    forces only what it alone knows (replayed prompt tokens and the rows
+    the slots started with); otherwise every step takes all of both from
+    the host."""
     slots = len(prompts)
     max_blocks = engine.max_blocks_per_slot(block_size)
     num_blocks = 1 + slots * max_blocks
@@ -343,9 +350,11 @@ def _drive_paged_slots(model, engine, params, prompts, seeds, max_new,
         rngs[slot] = np.asarray(jax.random.PRNGKey(seed))
         emitted_all.append([])
 
+    device = (np.zeros((slots,), np.int32), np.zeros((slots, 2), np.uint32))
     for _ in range(max_new + max(len(p) for p in pending)):
         tokens = np.zeros((slots,), np.int32)
         mask = np.zeros((slots,), bool)
+        forced = np.ones((slots,), bool)
         step_lengths = np.array(lengths)
         for slot in range(slots):
             if len(emitted_all[slot]) >= max_new:
@@ -357,17 +366,21 @@ def _drive_paged_slots(model, engine, params, prompts, seeds, max_new,
             else:
                 tokens[slot] = last[slot]
                 mask[slot] = True
+                forced[slot] = False
         if not mask.any():
             break
         finished = [len(e) >= max_new for e in emitted_all]
         step_tables = np.array(tables)
         step_tables[finished] = 0  # inactive rows write the trash block
-        pool, emitted, rngs_out = engine.paged_step(
-            params, pool, step_tables, step_lengths, tokens, rngs, mask,
+        feed = (*device, tokens, rngs, forced) if fed \
+            else all_forced(tokens, rngs)
+        pool, *device = engine.paged_step(
+            params, pool, step_tables, step_lengths, *feed, mask,
             block_size=block_size, **sampling,
         )
-        emitted = np.asarray(emitted)
-        rngs = np.array(rngs_out)
+        emitted = np.asarray(device[0])
+        if not fed:
+            rngs = np.array(device[1])
         for slot in range(slots):
             if finished[slot]:
                 continue
@@ -382,11 +395,14 @@ def _drive_paged_slots(model, engine, params, prompts, seeds, max_new,
     return emitted_all
 
 
-def test_paged_step_grid_matches_legacy_per_request():
+@pytest.mark.parametrize("fed", [False, True], ids=["host", "fed_back"])
+def test_paged_step_grid_matches_legacy_per_request(fed):
     """The paged serving contract: slots at different prompt lengths and
     seeds, block tables pointing at interleaved physical blocks, prompts
     split across prefill-pack + replay — every per-request stream is
-    BIT-IDENTICAL to generate_legacy, including sampled RNG chains."""
+    BIT-IDENTICAL to generate_legacy, including sampled RNG chains,
+    whether a slot's token and rng row pass through the host between
+    steps or stay on the device."""
     model, params = _model_and_params()
     engine = _engine(model, batch_buckets=(1, 2, 4),
                      prompt_buckets=(4, 8, 16))
@@ -403,7 +419,7 @@ def test_paged_step_grid_matches_legacy_per_request():
     # path too.
     emitted_all = _drive_paged_slots(
         model, engine, params, prompts, seeds, max_new, sampling,
-        block_size=8,
+        block_size=8, fed=fed,
     )
     for slot, (prompt, seed) in enumerate(zip(prompts, seeds)):
         ref = generate_legacy(
@@ -523,8 +539,7 @@ def test_paged_step_traces_with_zero_host_syncs():
         params, pool,
         jax.ShapeDtypeStruct((slots, mb), jnp.int32),
         jax.ShapeDtypeStruct((slots,), jnp.int32),
-        jax.ShapeDtypeStruct((slots,), jnp.int32),
-        jax.ShapeDtypeStruct((slots, 2), jnp.uint32),
+        *feed_avals(slots),
         jax.ShapeDtypeStruct((slots,), jnp.bool_),
     )
     prims = {eqn.primitive.name for eqn in _walk_jaxpr(closed.jaxpr)}

@@ -29,6 +29,7 @@ from cellbench.reference import granite_hybrid as reference
 from tf_yarn_tpu.models import hybrid
 from tf_yarn_tpu.models.decode_engine import (
     DecodeEngine,
+    all_forced,
     build_paged_state_step_fn,
     _decode_cache_aval,
     cache_layout,
@@ -40,6 +41,8 @@ from tf_yarn_tpu.models.decode_engine import (
 from tf_yarn_tpu.models.moe import DroplessMoE
 from tf_yarn_tpu.serving.request import SamplingParams
 from tf_yarn_tpu.serving.scheduler import SlotScheduler
+
+from tests.fakes import assert_pipelined_equals_settled
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOLERANCE = 5e-7  # float32 both sides, sums in another order (see above)
@@ -175,8 +178,7 @@ class _Grid:
         self.pool, self.state, _emitted, self.rngs, counts, logits = self.step(
             self.tiny["variables"], self.pool, self.state,
             jnp.asarray(self.tables), jnp.asarray(self.lengths),
-            jnp.asarray(tokens), jnp.asarray(self.rngs, jnp.uint32),
-            jnp.zeros((self.slots,), bool))
+            *all_forced(tokens, self.rngs), jnp.zeros((self.slots,), bool))
         # Read (and so wait) before the host arrays change: on the CPU
         # `jnp.asarray` may alias them, and the step runs asynchronously.
         logits, counts = np.asarray(logits), np.asarray(counts)
@@ -321,12 +323,25 @@ def test_scheduler_serves_through_reused_slots(tiny):
     # per slot: ssm_state 16 x 8 x 16 and conv_state 3 x 160, float32, in
     # each of two mamba layers
     assert stats["state_bytes"] == 2 * 2 * 4 * (16 * 8 * 16 + 3 * 160)
-    assert stats["moe_layer_steps"] == 3 * stats["ticks"]
+    # a layer-step a launched step, not a tick: a tick that only reads the
+    # step in flight launches none
+    assert stats["moe_layer_steps"] == 3 * stats["steps"]
+    assert stats["steps"] < stats["ticks"]
     assert stats["moe_assignments"] == 3 * 3 * stats["slot_steps"]
     assert 0 < stats["moe_assignments_here"] < stats["moe_assignments"]
     assert 0 < stats["moe_experts_touched_per_layer_step"] <= 4
     assert stats["moe_load_max_over_mean"] >= 1.0
     together.close()
+
+
+def test_pipelined_streams_equal_settled_streams(tiny):
+    """`paged_state_step` launched before the step before is read (the state, the counts
+    ride back a step late): the streams of the serial order, sampled, on
+    one compiled program (tests/fakes.py)."""
+    scheduler = _scheduler(tiny, max_slots=2, temperature=1.0, top_k=8)
+    assert_pipelined_equals_settled(scheduler)
+    assert scheduler.engine.stats["paged_step_compiles"] == 1
+    scheduler.close()
 
 
 def test_same_prompt_twice_gets_no_prefix_hit(tiny):
@@ -366,7 +381,7 @@ def test_engine_programs_that_carry_no_state_refuse(tiny):
     zeros = np.zeros((2,), np.int32)
     with pytest.raises(ValueError, match="paged_step.*conv_state, ssm_state"):
         engine.paged_step(variables, pool, np.zeros((2, 16), np.int32), zeros,
-                          zeros, np.zeros((2, 2), np.uint32),
+                          *all_forced(zeros, np.zeros((2, 2), np.uint32)),
                           np.zeros((2,), bool), block_size=BLOCK)
     with pytest.raises(ValueError, match="extract_blocks.*ssm_state"):
         engine.extract_blocks(variables, pool, np.zeros((16,), np.int32), BLOCK)

@@ -312,6 +312,36 @@ def test_interactive_suspends_batch_then_resumes_bit_identical():
     assert stats["suspended_streams"] == {}
     kinds = [c[0] for c in engine.calls]
     assert kinds.count("extract") == 1 and kinds.count("inject") == 1
+    # The batch stream's third step was still unread when the interactive
+    # request needed its blocks: the suspension read it first, by name.
+    assert stats["pipeline_settles"]["suspend"] == 1
+
+
+def test_a_stream_suspended_mid_decode_resumes_on_its_own_token_and_row():
+    """A stream parked after its first sampled step holds its rng row on
+    the device and its last token in a step the host had not read: the
+    suspension settles, saves both, and the resumed stream's first step
+    takes them from the host again (`refeed`), not another request's
+    from the device."""
+    engine, scheduler = _oversubscribed()
+    batch = scheduler.submit(
+        BATCH_PROMPT, SamplingParams(max_new_tokens=6), tier="batch"
+    )
+    for _ in range(5):  # four replayed tokens, then one more sampled step
+        scheduler.tick()
+    assert len(batch.token_times) == 1 and scheduler._flight is not None
+    assert not scheduler._rngs.any()  # the rows the host admitted with
+    scheduler._fed[1][0] = (7, 9)  # the fake's device row, as a step left it
+    interactive = scheduler.submit(
+        INTER_PROMPT, SamplingParams(max_new_tokens=6), tier="interactive"
+    )
+    scheduler.tick()
+    (parked,) = scheduler._suspended
+    assert parked.rng.tolist() == [7, 9]
+    assert len(batch.token_times) == 2  # the settle delivered the second
+    _drive(scheduler, [batch, interactive])
+    assert batch.result(timeout=1) == _solo_stream(BATCH_PROMPT)
+    assert [7, 9] in scheduler._rngs.tolist()  # forced at the resume
 
 
 def test_without_host_blocks_pressure_holds_instead_of_suspending():

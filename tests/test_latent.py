@@ -29,6 +29,7 @@ from tf_yarn_tpu.models import latent
 from tf_yarn_tpu.models.decode_engine import (
     DecodeEngine,
     _decode_cache_aval,
+    all_forced,
     build_paged_state_step_fn,
     cache_layout,
     clear_engines,
@@ -39,6 +40,8 @@ from tf_yarn_tpu.models.decode_engine import (
 from tf_yarn_tpu.models.moe import DroplessMoE
 from tf_yarn_tpu.serving.request import SamplingParams
 from tf_yarn_tpu.serving.scheduler import SlotScheduler
+
+from tests.fakes import assert_pipelined_equals_settled
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOLERANCE = 5e-5  # float32 both sides, sums in another order (see above)
@@ -254,7 +257,7 @@ class _Grid:
             self.step(
                 self.tiny["variables"], self.pool, self.state,
                 jnp.asarray(self.tables), jnp.asarray(self.lengths),
-                jnp.asarray(tokens), jnp.asarray(self.rngs, jnp.uint32),
+                *all_forced(tokens, self.rngs),
                 jnp.zeros((self.slots,), bool))
         # Read (and so wait) before the host arrays change: on the CPU
         # `jnp.asarray` may alias them, and the step runs asynchronously.
@@ -536,7 +539,10 @@ def test_scheduler_serves_through_reused_slots(tiny):
     assert stats["state_bytes"] == ring and stats["kv_cache_hbm_bytes"] == paged
     assert stats["cache_hbm_bytes"] == ring + paged
     steps = stats["slot_steps"]
-    assert stats["moe_layer_steps"] == 4 * stats["ticks"]
+    # a layer-step a launched step, not a tick: a tick that only reads the
+    # step in flight launches none
+    assert stats["moe_layer_steps"] == 4 * stats["steps"]
+    assert stats["steps"] < stats["ticks"]
     assert stats["moe_assignments"] == 4 * 3 * steps
     # every slot-step: two full layers' live rows, three windows' rings
     assert stats["index_live_token_steps"] == 2 * (stats["kv_token_steps"] + steps)
@@ -547,8 +553,19 @@ def test_scheduler_serves_through_reused_slots(tiny):
     assert stats["window_live_token_steps"] <= 3 * WINDOW * steps
     # a layer's rows read of a slot's sequence, summed a step and floored
     read = sum(stats[k + "_read_token_steps"] for k in ("index", "latent", "window"))
-    assert read / 5 - stats["ticks"] <= stats["kv_read_token_steps"] <= read / 5
+    assert read / 5 - stats["steps"] <= stats["kv_read_token_steps"] <= read / 5
     together.close()
+
+
+def test_pipelined_streams_equal_settled_streams(tiny):
+    """`paged_state_step` launched before the step before is read (the rings, the counts and the reads
+    ride back a step late): the streams of the serial order, sampled, on
+    one compiled program (tests/fakes.py)."""
+    compiled = tiny["engine"].stats["paged_step_compiles"]  # the file's engine
+    scheduler = _scheduler(tiny, max_slots=2, temperature=1.0, top_k=8)
+    assert_pipelined_equals_settled(scheduler)
+    assert scheduler.engine.stats["paged_step_compiles"] == compiled + 1
+    scheduler.close()
 
 
 def test_same_prompt_twice_gets_no_prefix_hit(tiny):
@@ -603,7 +620,7 @@ def test_engine_programs_that_carry_no_ring_refuse(tiny):
     zeros = np.zeros((2,), np.int32)
     with pytest.raises(ValueError, match="paged_step.*window_latent"):
         engine.paged_step(variables, pool, np.zeros((2, 16), np.int32), zeros,
-                          zeros, np.zeros((2, 2), np.uint32),
+                          *all_forced(zeros, np.zeros((2, 2), np.uint32)),
                           np.zeros((2,), bool), block_size=BLOCK)
     with pytest.raises(ValueError, match="extract_blocks.*window_latent"):
         engine.extract_blocks(variables, pool, np.zeros((16,), np.int32), BLOCK)

@@ -34,6 +34,7 @@ from tf_yarn_tpu.models import latent, longcat
 from tf_yarn_tpu.models.decode_engine import (
     DecodeEngine,
     _decode_cache_aval,
+    all_forced,
     build_paged_state_step_fn,
     cache_layout,
     kv_partition_spec,
@@ -437,7 +438,7 @@ class _Grid:
             self.step(
                 self.tiny["variables"], self.pool, self.state,
                 jnp.asarray(self.tables), jnp.asarray(self.lengths),
-                jnp.asarray(tokens), jnp.asarray(self.rngs, jnp.uint32),
+                *all_forced(tokens, self.rngs),
                 jnp.zeros((self.slots,), bool))
         # Read (and so wait) before the host arrays change: on the CPU
         # `jnp.asarray` may alias them, and the step runs asynchronously.
@@ -681,7 +682,10 @@ def test_scheduler_serves_through_reused_slots(tiny):
     assert stats["cache_bytes_by_kind"] == {"paged": paged}
     assert stats["cache_hbm_bytes"] == stats["kv_cache_hbm_bytes"] == paged
     steps = stats["slot_steps"]
-    assert stats["moe_layer_steps"] == LAYERS * stats["ticks"]
+    # a layer-step a launched step, not a tick: a tick that only reads the
+    # step in flight launches none
+    assert stats["moe_layer_steps"] == LAYERS * stats["steps"]
+    assert stats["steps"] < stats["ticks"]
     assert stats["moe_assignments"] == LAYERS * TOP_K * steps
     # 8 of the router's 24 outputs are held here and 8 return their input
     assert 0 < stats["moe_assignments_here"] < stats["moe_assignments"]

@@ -187,8 +187,9 @@ def _paged_step_logits(model, params):
     step = jax.jit(decode_engine.build_paged_step_fn(
         model, block, 0.0, None, None, with_logits=True))
     out = step(params, pool, tables, np.asarray([0, 5, 21], np.int32),
-               np.asarray([3, 4, 5], np.int32),
-               np.zeros((slots, 2), np.uint32), np.ones((slots,), bool))
+               *decode_engine.all_forced(np.asarray([3, 4, 5], np.int32),
+                                         np.zeros((slots, 2), np.uint32)),
+               np.ones((slots,), bool))
     return np.asarray(out[-1])
 
 

@@ -23,7 +23,11 @@ import time
 import numpy as np
 import pytest
 
-from tests.fakes import FakePagedEngine, fake_scheduler
+from tests.fakes import (
+    FakePagedEngine,
+    assert_pipelined_equals_settled,
+    fake_scheduler,
+)
 from tf_yarn_tpu.serving import (
     FINISH_DEADLINE,
     FINISH_EOS,
@@ -412,6 +416,157 @@ def test_tick_error_fails_inflight_and_loop_survives():
         assert ok.result(timeout=30) == [15, 30, 60]
     finally:
         scheduler.close()
+
+
+# --------------------------------------------------------------------------
+# the one-token pipeline: step N+1 is launched before step N is read
+# --------------------------------------------------------------------------
+
+def test_pipelined_streams_equal_settled_streams_on_the_fake():
+    """Replay, decode, admissions into freed slots, `max_new_tokens` and
+    `eos_token` endings and a deadline with a step in flight: the streams
+    are those of the serial order (tests/fakes.py
+    `assert_pipelined_equals_settled`), here where every emission can be
+    reckoned by hand."""
+    engine = FakePagedEngine()
+    stats = assert_pipelined_equals_settled(
+        fake_scheduler(engine, max_slots=2), vocab=97)
+    assert stats["pipeline_settles"]["nothing_to_launch"] >= 1
+    assert stats["steps"] == len(
+        [c for c in engine.calls if c[0] == "paged_step"])
+
+
+def test_pipelined_streams_equal_settled_streams_on_paged_step():
+    """The same on the compiled `paged_step` of the tiny transformer,
+    sampled, so that the rng rows that stay on the device are part of
+    what is compared; one program serves both orders."""
+    _model, _params, engine, scheduler = _tiny_serving_stack(
+        max_slots=2, temperature=1.0, top_k=8)
+    assert_pipelined_equals_settled(scheduler)
+    assert engine.stats["paged_step_compiles"] == 1
+
+
+@pytest.mark.parametrize("seed, words", [
+    (0, [0, 0]), (1, [0, 1]), (2 ** 31, [0, 2 ** 31]),
+    (2 ** 32 + 5, [0, 5]), (-1, [0, 2 ** 32 - 1]),
+])
+def test_host_made_key_equals_jax_prngkey(seed, words):
+    """`_prng_key` dispatches nothing: it writes down what
+    `jax.random.PRNGKey` computes, which this holds it to (the words are
+    those of a JAX without 64-bit integers, as the tests run)."""
+    import jax
+
+    from tf_yarn_tpu.serving.scheduler import _prng_key
+
+    key = _prng_key(seed)
+    assert key.dtype == np.uint32 and type(key) is np.ndarray
+    assert key.tolist() == np.asarray(jax.random.PRNGKey(seed)).tolist()
+    assert key.tolist() == words
+
+
+@pytest.mark.parametrize("seed, words", [
+    (1, [0, 1]), (2 ** 32 + 5, [1, 5]),
+    (-1, [2 ** 32 - 1, 2 ** 32 - 1]),
+])
+def test_host_made_key_equals_jax_prngkey_with_64_bit_integers(seed, words):
+    """Where JAX holds 64-bit integers the seed's upper word is the key's
+    first: the other branch of `_prng_key`."""
+    import jax
+
+    from tf_yarn_tpu.serving.scheduler import _prng_key
+
+    with jax.enable_x64(True):
+        key = _prng_key(seed)
+        assert key.tolist() == np.asarray(jax.random.PRNGKey(seed)).tolist()
+    assert key.dtype == np.uint32 and key.tolist() == words
+
+
+def test_a_prng_the_grid_cannot_hold_is_refused_at_start_up():
+    """Not inside a tick, where it would fail every live stream once an
+    admission."""
+    import jax
+
+    with jax.default_prng_impl("rbg"):
+        with pytest.raises(ValueError, match="threefry2x32"):
+            fake_scheduler(FakePagedEngine(), max_slots=2)
+
+
+def test_a_launch_keeps_its_uploads_when_the_host_arrays_move_on():
+    """The scheduler advances `_lengths` (and at an admission `_tables`,
+    `_rngs`) right after a launch, while a backend that aliases host
+    memory may not have read the step's arguments yet: every launch is
+    handed copies of its own."""
+    engine = FakePagedEngine()
+    scheduler = fake_scheduler(engine, max_slots=2)
+    real_step, seen = engine.paged_step, []
+
+    def recording_step(params, pool, tables, lengths, emitted, rngs, tokens,
+                       rng_rows, forced, mask, **kwargs):
+        uploads = (tables, lengths, tokens, rng_rows, forced, mask)
+        seen.append((uploads, [np.array(a) for a in uploads]))
+        return real_step(params, pool, tables, lengths, emitted, rngs,
+                         tokens, rng_rows, forced, mask, **kwargs)
+
+    engine.paged_step = recording_step
+    responses = [
+        scheduler.submit([1, 2, 3, 4, 5 + i], SamplingParams(max_new_tokens=4))
+        for i in range(3)
+    ]
+    _drive(scheduler, responses)
+    assert len(seen) >= 6
+    for uploads, as_launched in seen:
+        for array, snapshot in zip(uploads, as_launched):
+            np.testing.assert_array_equal(array, snapshot)
+        for array in uploads:
+            assert not any(np.shares_memory(array, mine) for mine in (
+                scheduler._tables, scheduler._lengths, scheduler._rngs))
+
+
+def test_a_deadline_with_a_step_in_flight_drops_that_steps_token():
+    engine = FakePagedEngine()
+    scheduler = fake_scheduler(engine, max_slots=1)
+    doomed = scheduler.submit([1, 2, 3, 4, 5],
+                              SamplingParams(max_new_tokens=20))
+    waiting = scheduler.submit([2, 2, 2, 2, 2],
+                               SamplingParams(max_new_tokens=2))
+    scheduler.tick()  # launches 15
+    scheduler.tick()  # launches 30, reads 15
+    assert doomed.token_times and scheduler._flight is not None
+    doomed.request.timeout_s = 1e-9
+    # The slot and its blocks go to `waiting` in the tick that retires
+    # `doomed`, with the dropped step still unread at the admission.
+    scheduler.tick()
+    assert doomed.finish_reason == FINISH_DEADLINE
+    assert doomed.result(timeout=1) == [15]
+    assert scheduler.trace[-1]["admitted"] == [waiting.request.id]
+    _drive(scheduler, [waiting])
+    assert waiting.result(timeout=1) == [10, 20]
+
+
+def test_close_and_prefix_export_settle_a_step_in_flight():
+    """Whoever needs the slots as the device left them empties the
+    pipeline by name: block shipping between ticks, and shutdown, which
+    hands over what the device had finished before it says `shutdown`."""
+    engine, scheduler = _paged_scheduler(max_slots=1)
+    response = scheduler.submit([3, 1, 4, 1, 5, 9, 2, 6, 5],
+                                SamplingParams(max_new_tokens=20))
+    scheduler.tick()
+    scheduler.tick()
+    assert scheduler._flight is not None and len(response.token_times) == 1
+    wire = scheduler.export_hot_prefixes()
+    assert wire["n_blocks"] == 2  # the prompt's two whole blocks
+    assert scheduler._flight is None and len(response.token_times) == 2
+    assert scheduler.stats()["pipeline_settles"]["control_op"] == 1
+    scheduler.tick()
+    assert scheduler._flight is not None
+    scheduler.close()
+    assert response.finish_reason == "shutdown"
+    assert len(response.result(timeout=1)) == 3
+    stats = scheduler.stats()
+    assert stats["pipeline_settles"]["shutdown"] == 1
+    assert stats["steps"] == 3 and stats["steps_ahead"] == 1
+    # the fake's arithmetic: each emission doubles the cache's sum
+    assert response.result(timeout=1) == [36, 72, 144 % 97]
 
 
 # --------------------------------------------------------------------------

@@ -163,6 +163,22 @@ def test_scheduler_thread_spans_tile_its_time_and_step_children_tile_step():
     emits = [s for s in mine if s.name == "serving/step_emit"]
     assert sum(s.args["tokens"] for s in emits) == 4 * 40
     assert sum(s.args["retired"] for s in emits) == 4
+    # The order inside a step: the launch is of the NEXT model step, the
+    # sync and the emit are of the one launched a tick before. So the
+    # first step launches with nothing unread and emits nothing, nearly
+    # every later one launches ahead of its read, and the last reads with
+    # nothing left to launch.
+    launches = [by_parent[step.id][0] for step in steps]
+    launched = [s for s in launches if s.args["slots"]]
+    assert launches[0].args["ahead"] == 0
+    assert by_parent[steps[0].id][2].args["tokens"] == 0
+    assert launches[-1].args["slots"] == 0
+    assert by_parent[steps[-1].id][2].args["tokens"] >= 1
+    ahead = sum(s.args["ahead"] for s in launched)
+    assert ahead >= 0.9 * len(launched)
+    stats = scheduler.stats()
+    assert (stats["steps"], stats["steps_ahead"]) == (len(launched), ahead)
+    assert stats["pipeline_settles"]["nothing_to_launch"] >= 1
     # The tick histogram still observes the tick span, nothing wider.
     hist = telemetry.get_registry().histogram("serving/tick_seconds")
     assert hist.count >= len(steps)
@@ -173,7 +189,10 @@ def test_engine_step_args_span_sits_under_launch():
     response = scheduler.submit([5, 6, 7], SamplingParams(max_new_tokens=3))
     _drive(scheduler, [response])
     records = telemetry.get_tracer().records()
-    launches = {s.id for s in records if s.name == "serving/step_launch"}
+    # A tick that only reads the step in flight has a launch span that
+    # launched nothing (`slots` 0) and made no call of the engine.
+    launches = {s.id for s in records if s.name == "serving/step_launch"
+                and s.args["slots"]}
     args = [s for s in records if s.name == "decode_engine/step_args"]
     calls = [s for s in records if s.name == "decode_engine/paged_step"]
     assert len(args) == len(calls) == len(launches) >= 3
@@ -220,6 +239,10 @@ def test_every_finish_reason_leaves_one_record_under_the_callers_id():
                                trace_id="r-slot-deadline", timeout_s=0.05)
     queued = scheduler.submit([2, 3], SamplingParams(max_new_tokens=2),
                               trace_id="r-queue-deadline", timeout_s=0.02)
+    # Two ticks: the first launches the blocker's first sampled step, the
+    # second reads it. A deadline that fell between them would drop the
+    # token with the slot (tests/test_serving.py), and no first token.
+    scheduler.tick()
     scheduler.tick()
     time.sleep(0.06)
     _drive(scheduler, [blocker, queued])
@@ -530,8 +553,7 @@ def test_paged_step_operations_carry_every_scope():
         params, pool,
         jax.ShapeDtypeStruct((slots, cfg.max_seq_len // 4), jnp.int32),
         jax.ShapeDtypeStruct((slots,), jnp.int32),
-        jax.ShapeDtypeStruct((slots,), jnp.int32),
-        jax.ShapeDtypeStruct((slots, 2), jnp.uint32),
+        *decode_engine.feed_avals(slots),
         jax.ShapeDtypeStruct((slots,), bool),
     )
     import re

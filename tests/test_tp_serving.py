@@ -515,6 +515,7 @@ def test_tp_step_program_has_allreduce_and_no_host_callbacks():
     from tf_yarn_tpu.models.decode_engine import (
         _decode_cache_aval,
         build_paged_step_fn,
+        feed_avals,
         paged_pool_avals,
     )
 
@@ -530,8 +531,7 @@ def test_tp_step_program_has_allreduce_and_no_host_callbacks():
         abstract, pool,
         jax.ShapeDtypeStruct((2, 8), jnp.int32),
         jax.ShapeDtypeStruct((2,), jnp.int32),
-        jax.ShapeDtypeStruct((2,), jnp.int32),
-        jax.ShapeDtypeStruct((2, 2), jnp.uint32),
+        *feed_avals(2),
         jax.ShapeDtypeStruct((2,), jnp.bool_),
     )
     prims = {eqn.primitive.name for eqn in _walk_jaxpr(closed.jaxpr)}

@@ -756,7 +756,7 @@ def _decode_churn_driver() -> Callable[[], Dict[str, List[tuple]]]:
         import jax.numpy as jnp
         from flax import linen as nn
 
-        from tf_yarn_tpu.models.decode_engine import DecodeEngine
+        from tf_yarn_tpu.models.decode_engine import DecodeEngine, all_forced
         from tf_yarn_tpu.models.transformer import (
             Transformer,
             TransformerConfig,
@@ -790,8 +790,8 @@ def _decode_churn_driver() -> Callable[[], Dict[str, List[tuple]]]:
             )
             lengths = jnp.full((slots,), tick + 1, jnp.int32)
             pool, _emitted, rngs = engine.paged_step(
-                params, pool, tables, lengths, tokens, rngs, mask,
-                block_size=block_size,
+                params, pool, tables, lengths, *all_forced(tokens, rngs),
+                mask, block_size=block_size,
             )
             # The windowed tick doubles as chunked prefill's chunk-apply:
             # n_known sweeping 0 -> width (decode-heavy to all-known
@@ -814,7 +814,7 @@ def _swap_churn_driver() -> Callable[[], Dict[str, List[tuple]]]:
         import numpy as np
         from flax import linen as nn
 
-        from tf_yarn_tpu.models.decode_engine import DecodeEngine
+        from tf_yarn_tpu.models.decode_engine import DecodeEngine, all_forced
         from tf_yarn_tpu.models.transformer import (
             Transformer,
             TransformerConfig,
@@ -848,8 +848,8 @@ def _swap_churn_driver() -> Callable[[], Dict[str, List[tuple]]]:
             )
             lengths = jnp.full((slots,), tick + 1, jnp.int32)
             pool, _emitted, rngs = engine.paged_step(
-                params, pool, tables, lengths, tokens, rngs, mask,
-                block_size=block_size,
+                params, pool, tables, lengths, *all_forced(tokens, rngs),
+                mask, block_size=block_size,
             )
             ids = np.full((max_blocks,), TRASH_BLOCK, np.int32)
             ids[: tick + 1] = np.arange(1, tick + 2, dtype=np.int32)

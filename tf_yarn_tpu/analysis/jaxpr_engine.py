@@ -482,16 +482,17 @@ def _decode_entries() -> List[EntryPoint]:
         import jax
         import jax.numpy as jnp
 
-        from tf_yarn_tpu.models.decode_engine import build_paged_step_fn
+        from tf_yarn_tpu.models.decode_engine import (
+            build_paged_step_fn,
+            feed_avals,
+        )
 
         model, params, pool, tables, lengths, slots = _paged_avals()
         fn = build_paged_step_fn(
             model, block_size=8, temperature=0.0, top_k=None, top_p=None
         )
-        args = (
-            params, pool, tables, lengths,
-            jax.ShapeDtypeStruct((slots,), jnp.int32),     # tokens
-            jax.ShapeDtypeStruct((slots, 2), jnp.uint32),  # per-slot rngs
+        # the step before's token and rng row, the host's, the mask between
+        args = (params, pool, tables, lengths) + feed_avals(slots) + (
             jax.ShapeDtypeStruct((slots,), jnp.bool_),     # sample mask
         )
         return fn, args, {}
@@ -705,16 +706,17 @@ def _decode_entries() -> List[EntryPoint]:
         import jax
         import jax.numpy as jnp
 
-        from tf_yarn_tpu.models.decode_engine import build_paged_step_fn
+        from tf_yarn_tpu.models.decode_engine import (
+            build_paged_step_fn,
+            feed_avals,
+        )
 
         return _tp_sharded(
             lambda model, block_size: build_paged_step_fn(
                 model, block_size=block_size, temperature=0.0,
                 top_k=None, top_p=None,
             ),
-            lambda slots: (
-                jax.ShapeDtypeStruct((slots,), jnp.int32),     # tokens
-                jax.ShapeDtypeStruct((slots, 2), jnp.uint32),  # rngs
+            lambda slots: feed_avals(slots) + (
                 jax.ShapeDtypeStruct((slots,), jnp.bool_),     # sample mask
             ),
             small_outs=2, donate=(1, 5),  # DecodeEngine.paged_step

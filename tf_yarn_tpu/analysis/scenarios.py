@@ -91,9 +91,12 @@ class _FakePagedEngine:
             pool[block, pos % block_size] = row_cache[pos]
         return pool
 
-    def paged_step(self, params, pool, tables, lengths, tokens, rngs,
-                   sample_mask, block_size, temperature=0.0, top_k=None,
-                   top_p=None):
+    def paged_step(self, params, pool, tables, lengths, emitted, rngs,
+                   tokens, rng_rows, forced, sample_mask, block_size,
+                   temperature=0.0, top_k=None, top_p=None):
+        forced = np.asarray(forced)
+        tokens = np.where(forced, tokens, emitted)
+        rngs = np.where(forced[:, None], rng_rows, rngs)
         pool = np.array(pool)
         tables = np.asarray(tables)
         lengths = np.asarray(lengths)
@@ -641,6 +644,8 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._kv_token_steps", _ADVISORY),
                 ("scheduler._kv_read_token_steps", _ADVISORY),
                 ("scheduler._slot_steps", _ADVISORY),
+                ("scheduler._steps", _ADVISORY),
+                ("scheduler._steps_ahead", _ADVISORY),
                 ("scheduler._slow_steps", _ADVISORY),
                 ("scheduler._slow_step_seconds", _ADVISORY),
                 ("scheduler._slowest_step", _ADVISORY),
@@ -659,6 +664,8 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._kv_token_steps", _ADVISORY),
                 ("scheduler._kv_read_token_steps", _ADVISORY),
                 ("scheduler._slot_steps", _ADVISORY),
+                ("scheduler._steps", _ADVISORY),
+                ("scheduler._steps_ahead", _ADVISORY),
                 ("scheduler._slow_steps", _ADVISORY),
                 ("scheduler._slow_step_seconds", _ADVISORY),
                 ("scheduler._slowest_step", _ADVISORY),

@@ -62,9 +62,12 @@ top_k / top_p are baked into the traced program) key the cache.
   tick. Positions beyond a slot's length get exactly-zero weight, so the
   plain read attends over the values a batch-1 decode cache of that
   request would hold: the fp path emits `generate_legacy`'s tokens.
-  Free/allocate is host-side free-list
-  bookkeeping (`serving/paging.py`); there is no per-eviction device
-  program at all. `pack_prefill` splices a bucketed-prefill result into
+  A slot's input token and rng row are the step before's outputs, still
+  on the device, unless the host forces its own (`_feed`: a prompt token
+  in replay, the row a slot was admitted with), so the scheduler launches
+  a step before it has read the one before. Free/allocate is host-side
+  free-list bookkeeping (`serving/paging.py`); there is no per-eviction
+  device program at all. `pack_prefill` splices a bucketed-prefill result into
   a slot's blocks; int8 KV composes transparently (the pool stores
   whatever leaves the model's cache has — int8 values + scales
   included).
@@ -404,6 +407,41 @@ def _sample_slots(logits, tokens, rngs, sample_mask, temperature: float,
     return jax.vmap(sample)(logits, tokens, rngs, sample_mask)
 
 
+def all_forced(tokens, rngs):
+    """The one-token step's five feed arguments `(emitted, rngs, tokens,
+    rng_rows, forced)` for a caller that holds every slot's token and rng
+    row on the host (the tests, the analysis entries, a step run once at
+    start-up): every slot forced, so nothing is taken from a step before."""
+    tokens = np.asarray(tokens, np.int32)
+    rngs = np.asarray(rngs, np.uint32)
+    return tokens, rngs, tokens, rngs, np.ones(tokens.shape, bool)
+
+
+def feed_avals(slots: int):
+    """`all_forced`'s five as shapes and types (programs lowered without
+    arrays)."""
+    tokens = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    rows = jax.ShapeDtypeStruct((slots, 2), jnp.uint32)
+    return tokens, rows, tokens, rows, jax.ShapeDtypeStruct((slots,), bool)
+
+
+def _feed(emitted, rngs, tokens, rng_rows, forced):
+    """A step's input tokens and rng rows, from where they are: the step
+    before left `emitted` and `rngs` on the device, and the host sends
+    what only it knows under `forced` (both one-token builders)."""
+    return (jnp.where(forced, tokens, emitted),
+            jnp.where(forced[:, None], rng_rows, rngs))
+
+
+def _step_host_args(tables, lengths, emitted, rngs, tokens, rng_rows,
+                    forced, sample_mask):
+    """The one-token step's arguments after its trees, each with its type
+    (`DecodeEngine._paged_program`'s `host`)."""
+    return ((tables, jnp.int32), (lengths, jnp.int32), (emitted, jnp.int32),
+            (rngs, jnp.uint32), (tokens, jnp.int32), (rng_rows, jnp.uint32),
+            (forced, bool), (sample_mask, bool))
+
+
 def build_paged_step_fn(model, block_size: int, temperature: float,
                         top_k: Optional[int], top_p: Optional[float],
                         with_logits: bool = False,
@@ -411,8 +449,8 @@ def build_paged_step_fn(model, block_size: int, temperature: float,
     """The paged continuous-batching step, shared by the engine and the
     analysis jaxpr entry point (`models.decode_engine.paged_step`).
 
-        fn(params, pool, tables, lengths, tokens, rngs, sample_mask)
-            -> (pool, emitted [S], rngs)
+        fn(params, pool, tables, lengths, emitted, rngs, tokens, rng_rows,
+           forced, sample_mask) -> (pool, emitted [S], rngs)
 
     ONE compiled program advances every slot one token against the
     global block pool: the model is applied once over the slots' tokens
@@ -423,9 +461,17 @@ def build_paged_step_fn(model, block_size: int, temperature: float,
     (`paged_decode_attention`). `paged_kernel` says how the pool is read:
     None leaves it to the backend and the pool's shape (the kernel on a
     TPU), False is the plain gather (the engine's choice under `tp`).
-    `tokens` [S] are this tick's inputs: a forced prompt token while a
-    slot replays its prompt remainder, else the slot's last emitted
-    token. `sample_mask` [S] is the traced active mask: masked-off slots
+    A slot's input token and rng row come from where they are (`_feed`):
+    `emitted` [S] and `rngs` [S, 2] are what the step before returned,
+    still on the device, so a slot that feeds back its last token needs
+    nothing of the host and the next step can be launched before this
+    one is read; under `forced` [S] the host's `tokens` [S] and
+    `rng_rows` [S, 2] take their place: a prompt token while a slot
+    replays its prompt remainder, a resumed stream's last token, zero for
+    a free slot, and the rng row the slot was admitted or resumed with
+    (replay samples nothing, so that row is the slot's own until its
+    first sampled step, after which it is no longer forced).
+    `sample_mask` [S] is the traced active mask: masked-off slots
     (free, or mid-replay) run the same device program — the KV append is
     the point for replay slots, garbage for free ones — but consume no
     RNG and pass their input token through, so each slot's split chain
@@ -442,7 +488,9 @@ def build_paged_step_fn(model, block_size: int, temperature: float,
     """
     del block_size  # the pool's own shape says it
 
-    def step(params, pool, tables, lengths, tokens, rngs, sample_mask):
+    def step(params, pool, tables, lengths, emitted, rngs, tokens, rng_rows,
+             forced, sample_mask):
+        tokens, rngs = _feed(emitted, rngs, tokens, rng_rows, forced)
         row_aval = _decode_cache_aval(model, params)
         _refuse_slot_state(cache_layout(model, row_aval),
                            "paged_step (use paged_state_step)")
@@ -493,7 +541,8 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
     (`cache_layout`: `slot` leaves — a recurrent state, a convolution's
     tail):
 
-        fn(params, pool, state, tables, lengths, tokens, rngs, sample_mask)
+        fn(params, pool, state, tables, lengths, emitted, rngs, tokens,
+           rng_rows, forced, sample_mask)
             -> (pool, state, emitted [S], rngs, counts)
 
     `build_paged_step_fn`'s one call of the model over all slots' tokens
@@ -508,13 +557,16 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
     experts]`: assignments, then tokens that reached each held expert —
     and rides back with `emitted`. A model whose attention layers count
     what they read (`cache_stats`, summed over layers: one vector, named by
-    the model's `READS`) has it appended as a sixth output. Sampling and the
-    RNG discipline are `build_paged_step_fn`'s. `with_logits` appends the
+    the model's `READS`) has it appended as a sixth output. Where a slot's
+    token and rng row come from (`_feed`), sampling and the RNG discipline
+    are `build_paged_step_fn`'s. `with_logits` appends the
     step's logits [S, V] last (the tests compare them with a reference).
     """
     del block_size  # the pool's own shape says it
 
-    def step(params, pool, state, tables, lengths, tokens, rngs, sample_mask):
+    def step(params, pool, state, tables, lengths, emitted, rngs, tokens,
+             rng_rows, forced, sample_mask):
+        tokens, rngs = _feed(emitted, rngs, tokens, rng_rows, forced)
         active = tables[:, 0] != 0
         logits, new = model.apply(
             {**params, "cache": _prune_none_tree(state),
@@ -1462,8 +1514,11 @@ class DecodeEngine:
         state,
         tables,
         lengths,
-        tokens,
+        emitted,
         rngs,
+        tokens,
+        rng_rows,
+        forced,
         sample_mask,
         block_size: int,
         temperature: float = 0.0,
@@ -1485,8 +1540,8 @@ class DecodeEngine:
                 self.model, block_size, temperature, top_k, top_p,
                 paged_kernel=kernel),
             params, (pool, state),
-            ((tables, jnp.int32), (lengths, jnp.int32), (tokens, jnp.int32),
-             (rngs, jnp.uint32), (sample_mask, bool)),
+            _step_host_args(tables, lengths, emitted, rngs, tokens, rng_rows,
+                            forced, sample_mask),
             donate=(1, 2, 6), replicated_outs=3,
         )
         with telemetry.span("decode_engine/paged_step", slots=slots):
@@ -1498,7 +1553,9 @@ class DecodeEngine:
         every paged step alike (span `decode_engine/step_args`): place
         the params, upload the tick's host arrays (`host`: (value, dtype)
         pairs, after the device-resident `trees` in the program's
-        arguments), key the compile cache by `key` + the params' and the
+        arguments; one that is a device array already, as the one-token
+        step's fed-back `emitted` and `rngs`, stays where it is), key the
+        compile cache by `key` + the params' and the
         trees' fingerprints, and compile on a miss (`build()` makes the
         step function; the trees come back first among its outputs, then
         `replicated_outs` small ones). Returns (compiled, args)."""
@@ -1683,8 +1740,11 @@ class DecodeEngine:
         pool,
         tables,
         lengths,
-        tokens,
+        emitted,
         rngs,
+        tokens,
+        rng_rows,
+        forced,
         sample_mask,
         block_size: int,
         temperature: float = 0.0,
@@ -1695,8 +1755,11 @@ class DecodeEngine:
         compiled program (build_paged_step_fn). Compiled once per (grid
         size, pool shape, block size, sampling config, params
         fingerprint); tables/lengths/tokens are traced, so per-tick
-        table changes never recompile. The pool and the rng buffer are
-        donated. Returns (pool, emitted [S], rngs)."""
+        table changes never recompile. `emitted` and `rngs` are the step
+        before's (device arrays: nothing is uploaded for them), the rest
+        the host's; the pool and `rngs` are donated, `emitted` is not
+        (its reader comes after this launch). Returns (pool, emitted [S],
+        rngs), none of them waited for."""
         slots = int(jnp.shape(tokens)[0])
         kernel = self.paged_attention_kernel(pool)
         compiled, args = self._paged_program(
@@ -1707,8 +1770,8 @@ class DecodeEngine:
                 self.model, block_size, temperature, top_k, top_p,
                 paged_kernel=kernel),
             params, (pool,),
-            ((tables, jnp.int32), (lengths, jnp.int32), (tokens, jnp.int32),
-             (rngs, jnp.uint32), (sample_mask, bool)),
+            _step_host_args(tables, lengths, emitted, rngs, tokens, rng_rows,
+                            forced, sample_mask),
             donate=(1, 5), replicated_outs=2,
         )
         with telemetry.span("decode_engine/paged_step", slots=slots):

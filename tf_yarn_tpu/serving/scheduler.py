@@ -15,14 +15,20 @@ Every scheduler tick:
    windowed step replays ``prefill_chunk`` at a time, interleaved with
    decode under ``prefill_budget_per_tick``, so admission never stalls
    the decode tick (docs/Serving.md "Chunked prefill");
-3. **step** ALL slots one token in ONE compiled program: replaying
-   slots force their next prompt token (no RNG consumed — the split
-   chain stays bit-aligned with `generate_legacy`), emitting slots feed
-   back their last token, free slots ride along masked off;
-4. **retire** slots that emitted their eos or hit max_new_tokens,
-   pushing their slot back on the free-list — reusable on the very next
-   tick, so decode work for in-flight requests never waits for a batch
-   to drain (continuous batching, not static batching).
+3. **launch** the next step of ALL slots, one token in ONE compiled
+   program: replaying slots force their next prompt token (no RNG
+   consumed — the split chain stays bit-aligned with `generate_legacy`),
+   emitting slots feed back their last token on the device, free slots
+   ride along masked off;
+4. **read** the step launched a tick before (the tick's one host sync)
+   and hand its tokens on, with the device under the step just launched:
+   a pipeline one step deep (`_step`), emptied by name (`_settle`)
+   wherever a slot is needed as the device left it;
+5. **retire** slots whose read token was their eos or their
+   max_new_tokens-th, pushing their slot back on the free-list —
+   reusable on the next tick, so decode work for in-flight requests
+   never waits for a batch to drain (continuous batching, not static
+   batching).
 
 The KV cache is ONE global pool of fixed-size blocks
 (`make_paged_pool`) plus per-slot block tables, gathered/scattered
@@ -104,6 +110,12 @@ SLOW_STEP_MIN_HISTORY = 8
 REFUSED = "refused"
 
 DECODE_ATTENTION = ("gather", "fused")
+# Why the one-token pipeline was emptied (`/stats` `pipeline_settles`):
+# a tick found every slot's last token in flight already, a suspension or
+# block shipping needed the slots as the device left them, the grid was
+# shut down or its tick failed.
+SETTLE_REASONS = ("nothing_to_launch", "suspend", "control_op",
+                  FINISH_SHUTDOWN, FINISH_ERROR)
 
 
 class _Slot:
@@ -117,10 +129,10 @@ class _Slot:
     emptying."""
 
     __slots__ = ("request", "response", "pending", "last_token", "emitted",
-                 "blocks", "context", "prompt_filled", "registered_blocks",
-                 "last_emit_at", "kv_len", "prefilled", "hit_tokens",
-                 "replay", "queue_wait_s", "prefill_s", "admitted_clock",
-                 "replay_s")
+                 "launched", "refeed", "blocks", "context", "prompt_filled",
+                 "registered_blocks", "last_emit_at", "kv_len", "prefilled",
+                 "hit_tokens", "replay", "queue_wait_s", "prefill_s",
+                 "admitted_clock", "replay_s")
 
     def __init__(self, request: Request, response: Response,
                  pending: List[int], blocks: Optional[List[int]] = None):
@@ -131,6 +143,13 @@ class _Slot:
         self.pending: Deque[int] = collections.deque(pending)
         self.last_token = 0
         self.emitted = 0
+        # Sampled one-token steps launched for this slot: `emitted` plus
+        # the one whose token the host has not read yet. At
+        # `max_new_tokens` the slot is left out of the next launch.
+        self.launched = 0
+        # A resumed stream's next step takes `last_token` from the host:
+        # the device's fed-back token is another request's.
+        self.refeed = False
         # The physical block ids this slot holds one reference on
         # (shared prefix blocks included).
         self.blocks = blocks
@@ -185,6 +204,23 @@ class _Suspended:
     @property
     def request(self) -> Request:
         return self.state.request
+
+
+class _Flight:
+    """A one-token step that was launched and not read yet: its results,
+    still on the device, and what the launch knew of each slot it stepped
+    (`stepped`: (slot, the `_Slot` it held, whether it sampled)). A slot
+    that holds another `_Slot` at the read was retired meanwhile, and its
+    result is dropped."""
+
+    __slots__ = ("emitted", "counts", "reads", "stepped", "prefill_tokens")
+
+    def __init__(self, emitted, counts, reads, stepped, prefill_tokens):
+        self.emitted = emitted
+        self.counts = counts
+        self.reads = reads
+        self.stepped = stepped
+        self.prefill_tokens = prefill_tokens
 
 
 class _ControlOp:
@@ -434,7 +470,24 @@ class SlotScheduler:
         self._swap_out_blocks = 0
         self._swap_in_blocks = 0
         self._peak_streams = 0
+        # The rng row each slot was admitted or resumed with (the windowed
+        # step: the row it holds now, read back every tick).
         self._rngs = np.zeros((max_slots, 2), np.uint32)
+        # Refused here, at start-up, and not inside a tick at every
+        # admission: a PRNG implementation whose keys the grid cannot hold.
+        _prng_key(0)
+        # The one-token pipeline (docs/Serving.md "Where a tick's time
+        # goes"): the newest step's `emitted` and `rngs`, which the next
+        # launch takes as they are, on the device; the launched step the
+        # host has not read; requests a settle outside a tick retired.
+        self._fed = (np.zeros((max_slots,), np.int32),
+                     np.zeros((max_slots, 2), np.uint32))
+        self._flight: Optional[_Flight] = None
+        self._carried_retired: List = []
+        self._steps = 0
+        self._steps_ahead = 0
+        # Every reason from the start: `stats()` copies it on other threads.
+        self._settles: Dict[str, int] = dict.fromkeys(SETTLE_REASONS, 0)
         self._slots: List[Optional[_Slot]] = [None] * max_slots
         self._free: Deque[int] = collections.deque(range(max_slots))
         self._used_before = [False] * max_slots
@@ -597,11 +650,15 @@ class SlotScheduler:
                     np.full((self.max_slots,), -1, np.int32), self._rngs,
                     idle, decode_attention=self.decode_attention, **sampling)
             else:
+                from tf_yarn_tpu.models.decode_engine import all_forced
+
                 self._pool, self._state, emitted, *_ = \
                     self.engine.paged_state_step(
                         self.params, self._pool, self._state, self._tables,
-                        self._lengths, np.zeros((self.max_slots,), np.int32),
-                        self._rngs, idle, **sampling)
+                        self._lengths,
+                        *all_forced(np.zeros((self.max_slots,), np.int32),
+                                    self._rngs),
+                        idle, **sampling)
             jax.block_until_ready(emitted)
         except Exception as exc:
             held = (f"per-slot state ({', '.join(self._state_leaves)}; "
@@ -739,7 +796,8 @@ class SlotScheduler:
             self._run_control_ops()
         now = time.monotonic()
         admitted: List[int] = []
-        retired: List = []
+        # With whatever a settle outside a tick retired.
+        retired, self._carried_retired = self._carried_retired, []
         tick_no = self._ticks + 1  # its number, if any work happens
         with telemetry.span("serving/tick", tick=tick_no) as tick_span:
             with telemetry.span("serving/retire"):
@@ -748,10 +806,12 @@ class SlotScheduler:
                 with telemetry.span("serving/resume"):
                     self._resume_suspended(now, admitted)
             with telemetry.span("serving/admit"):
-                self._admit_queued(now, admitted)
+                self._admit_queued(now, admitted, retired)
             active = [s for s in range(self.max_slots) if self._slots[s]]
             accepts = step_span = None
-            if active:
+            # A step in flight is read even where every slot it stepped has
+            # gone (deadlines): its counts are the device's work.
+            if active or self._flight is not None:
                 # The annotation (only while a capture started through
                 # telemetry.profile runs) puts the tick's number into the
                 # profiler's own host plane, beside jit_step's executions.
@@ -897,7 +957,8 @@ class SlotScheduler:
             time.monotonic() - entry.request.submitted_at
         )
 
-    def _admit_queued(self, now: float, admitted: List[int]) -> None:
+    def _admit_queued(self, now: float, admitted: List[int],
+                      retired: List) -> None:
         while self._free:
             if self._held is not None:
                 item, self._held = self._held, None
@@ -914,7 +975,7 @@ class SlotScheduler:
             # Pool exhausted: with a host tier, park lower-SLO-tier
             # active streams (swap their blocks out) until this
             # request fits or no eligible victim remains.
-            while not ok and self._suspend_victim_below(request):
+            while not ok and self._suspend_victim_below(request, retired):
                 ok = self._admit(request, response, now, admitted)
             if not ok:
                 # Hold the request (FIFO head) until retirements
@@ -1049,12 +1110,15 @@ class SlotScheduler:
 
     # -- host-tier swap: suspend / resume ------------------------------------
 
-    def _suspend_victim_below(self, request: Request) -> bool:
+    def _suspend_victim_below(self, request: Request, retired: List) -> bool:
         """Park one active stream of a tier STRICTLY below `request`'s
         to free its slot and blocks — lowest tier first, youngest
         within a tier (the least sunk prefill work). Returns False when
         no host tier is configured, no lower-tier stream is active, or
-        the host store cannot hold any candidate's valid blocks."""
+        the host store cannot hold any candidate's valid blocks. A
+        suspension saves a stream as the host knows it (its last token,
+        its count, its rng row), so a step in flight is read first: what
+        it retires may itself make the room."""
         if self._host_store is None:
             return False
         rank = request.tier_rank
@@ -1063,6 +1127,11 @@ class SlotScheduler:
             if self._slots[slot] is not None
             and self._slots[slot].request.tier_rank < rank
         ]
+        if candidates and self._flight is not None:
+            # True without a victim: the caller tries its admission again,
+            # and the next call finds nothing in flight.
+            self._settle("suspend", retired)
+            return True
         candidates.sort(key=lambda slot: (
             self._slots[slot].request.tier_rank,
             -self._slots[slot].request.submitted_at,
@@ -1205,6 +1274,7 @@ class SlotScheduler:
         self._tables[slot, :len(blocks)] = blocks
         self._lengths[slot] = entry.length
         self._rngs[slot] = entry.rng
+        state.refeed = True
         state.blocks = blocks
         self._slots[slot] = state
         if self._used_before[slot]:
@@ -1285,6 +1355,9 @@ class SlotScheduler:
                     return
                 op = self._control.popleft()
             try:
+                # Block shipping reads and hands out pool blocks between
+                # ticks: on a pipeline that is empty.
+                self._settle("control_op")
                 if op.kind == "export":
                     op.result = self._export_prefixes_now(op.arg)
                 elif op.kind == "import":
@@ -1440,112 +1513,191 @@ class SlotScheduler:
         }
 
     def _step(self, active: List[int], retired: List) -> None:
-        # Three spans tile the step: building the inputs and the engine's
-        # call (the device starts somewhere inside), the one host sync
-        # (the host waits for the device), the per-slot bookkeeping
-        # after it (the device waits for the host).
+        """The one-token tick, a pipeline one step deep: launch step N+1,
+        then read step N. Three spans tile it: building the inputs and the
+        engine's call for the NEXT step (the device is still under the
+        step before), the tick's one host sync (the host waits for the
+        step before, which had a head start), the per-slot bookkeeping of
+        what was read (the device is under the step just launched). Where
+        nothing can be launched the step in flight is settled."""
         with telemetry.span("serving/step_launch") as launch_span:
-            tokens = np.zeros((self.max_slots,), np.int32)
-            mask = np.zeros((self.max_slots,), bool)
-            for slot in active:
-                state = self._slots[slot]
-                if state.pending:
-                    tokens[slot] = state.pending[0]
-                    mask[slot] = len(state.pending) == 1
-                else:
-                    tokens[slot] = state.last_token
-                    mask[slot] = True
-            counts = reads = None
-            if self._counted_step:
-                # `reads`: after the five, where the model counts them.
-                self._pool, self._state, emitted, rngs, counts, *reads = \
-                    self.engine.paged_state_step(
-                        self.params, self._pool, self._state, self._tables,
-                        self._lengths, tokens, self._rngs, mask,
-                        block_size=self._block_size,
-                        temperature=self.temperature, top_k=self.top_k,
-                        top_p=self.top_p,
-                    )
-            else:
-                self._pool, emitted, rngs = self.engine.paged_step(
-                    self.params, self._pool, self._tables, self._lengths,
-                    tokens, self._rngs, mask,
-                    block_size=self._block_size,
-                    temperature=self.temperature, top_k=self.top_k,
-                    top_p=self.top_p,
-                )
-            # Asked for now, so that the copies to the host follow the
-            # program with no word from the host in between: one wait
-            # under `serving/step_sync` in place of one a result.
-            reads = reads[0] if reads else None
-            for result in (emitted, rngs, counts, reads):
-                if hasattr(result, "copy_to_host_async"):  # a device array
-                    result.copy_to_host_async()
-            # After the call: the engine then knows which implementation
-            # the step was compiled with.
-            chunk = getattr(self.engine, "paged_attention_chunk", None)
-            self._count_step(active, chunk and chunk(self._block_size),
-                             counted_by_model=reads is not None)
-        with telemetry.span("serving/step_sync") as sync_span:
-            # The tick's one host sync: every slot's token in one transfer.
-            emitted = np.asarray(emitted)
-            # np.array (copy): admissions write PRNGKey rows into this
-            # buffer, and np.asarray of a device array is read-only.
-            self._rngs = np.array(rngs)
-            if counts is not None:
-                # Ready with the tokens: the same program returned them.
-                counts = np.asarray(counts)
-            if reads is not None:
-                reads = np.asarray(reads)
-            # Freed here, under this span: left to the function's return
-            # the device buffer's release took 0.5-0.8 ms a tick on a v5e
-            # inside `serving/step` and under none of its children.
-            del rngs
-        with telemetry.span("serving/step_emit") as emit_span:
-            self._step_parts = (launch_span, sync_span, emit_span)
-            was_retired = len(retired)
-            now = time.monotonic()
-            prefill_tokens = 0
-            decode_tokens = 0
-            gaps = self._registry.histogram("serving/inter_token_latency_ms")
-            for slot in active:
-                state = self._slots[slot]
-                # Every active slot consumed one token this tick (a
-                # replayed prompt token or its fed-back emission) and
-                # wrote its K/V at the old length.
-                self._lengths[slot] += 1
-                state.kv_len += 1
-                sampled = bool(mask[slot])
-                if state.pending:
-                    state.pending.popleft()
-                    state.prompt_filled += 1
-                    prefill_tokens += 1
-                if not sampled:
-                    continue
-                token = int(emitted[slot])
-                state.last_token = token
-                state.emitted += 1
-                decode_tokens += 1
-                first = state.response.first_token_at is None
-                state.response._push(token)
-                if first:
-                    self._observe_ttft(state)
-                elif state.last_emit_at is not None:
-                    gaps.observe((now - state.last_emit_at) * 1e3)
-                state.last_emit_at = now
-                eos = state.request.params.eos_token
-                if eos is not None and token == eos:
-                    self._retire(slot, FINISH_EOS, retired)
-                elif state.emitted >= state.request.params.max_new_tokens:
-                    self._retire(slot, FINISH_LENGTH, retired)
-            self._account_tokens(prefill_tokens, decode_tokens)
-            if counts is not None and counts.size:
-                self._count_experts(counts)
-            if reads is not None:
-                self._count_reads(reads)
-            emit_span.args.update(
-                tokens=decode_tokens, retired=len(retired) - was_retired
+            before = self._flight
+            budget = [s for s in active if self._slots[s].launched
+                      < self._slots[s].request.params.max_new_tokens]
+            if budget:
+                self._flight = self._launch(active, budget)
+                self._steps += 1
+                self._steps_ahead += int(before is not None)
+            launch_span.args.update(
+                slots=len(budget),
+                ahead=int(bool(budget) and before is not None),
             )
+        if budget:
+            self._step_parts = (launch_span,) + self._land(before, retired)
+        else:
+            # Every slot's last token is in flight already.
+            self._step_parts = (launch_span,) + self._settle(
+                "nothing_to_launch", retired)
+
+    def _launch(self, active: List[int], budget: List[int]) -> _Flight:
+        """Dispatch one step over the slots of `budget` and advance, from
+        what the host knows, everything the step after it needs: lengths,
+        the replay queues, the counts of what the step reads. Nothing here
+        waits for the device. A slot of `active` outside `budget` (its
+        last token is in flight) rides along as a free slot does."""
+        tokens = np.zeros((self.max_slots,), np.int32)
+        forced = np.ones((self.max_slots,), bool)
+        mask = np.zeros((self.max_slots,), bool)
+        # Its own copies: the host arrays move on below, and a backend
+        # that aliases host memory may not have consumed them yet.
+        tables, lengths = self._tables.copy(), self._lengths.copy()
+        rng_rows = self._rngs.copy()
+        if len(budget) < len(active):
+            for slot in set(active).difference(budget):
+                tables[slot, :] = 0
+                lengths[slot] = 0
+        for slot in budget:
+            state = self._slots[slot]
+            if state.pending:
+                tokens[slot] = state.pending[0]
+                mask[slot] = len(state.pending) == 1
+            else:
+                mask[slot] = True
+                if state.refeed:
+                    tokens[slot] = state.last_token
+                else:
+                    forced[slot] = False  # fed back on the device
+        sampling = dict(block_size=self._block_size,
+                        temperature=self.temperature, top_k=self.top_k,
+                        top_p=self.top_p)
+        counts = reads = None
+        if self._counted_step:
+            # `reads`: after the five, where the model counts them.
+            self._pool, self._state, emitted, rngs, counts, *reads = \
+                self.engine.paged_state_step(
+                    self.params, self._pool, self._state, tables, lengths,
+                    *self._fed, tokens, rng_rows, forced, mask, **sampling)
+            reads = reads[0] if reads else None
+        else:
+            self._pool, emitted, rngs = self.engine.paged_step(
+                self.params, self._pool, tables, lengths, *self._fed,
+                tokens, rng_rows, forced, mask, **sampling)
+        self._fed = (emitted, rngs)
+        # Asked for now, so that the copies to the host follow the
+        # program with no word from the host in between: one wait
+        # under `serving/step_sync` in place of one a result.
+        for result in (emitted, counts, reads):
+            if hasattr(result, "copy_to_host_async"):  # a device array
+                result.copy_to_host_async()
+        # After the call: the engine then knows which implementation
+        # the step was compiled with.
+        chunk = getattr(self.engine, "paged_attention_chunk", None)
+        self._count_step(budget, chunk and chunk(self._block_size),
+                         counted_by_model=reads is not None)
+        stepped = []
+        prefill_tokens = 0
+        for slot in budget:
+            state = self._slots[slot]
+            # Every stepped slot consumes one token (a replayed prompt
+            # token or its fed-back emission) and writes its K/V at the
+            # old length.
+            self._lengths[slot] += 1
+            state.kv_len += 1
+            state.refeed = False
+            if state.pending:
+                state.pending.popleft()
+                state.prompt_filled += 1
+                prefill_tokens += 1
+            sampled = bool(mask[slot])
+            state.launched += sampled
+            stepped.append((slot, state, sampled))
+        return _Flight(emitted, counts, reads, stepped, prefill_tokens)
+
+    def _land(self, flight: Optional[_Flight], retired: List) -> Tuple:
+        """Read a launched step (the one host sync) and hand its tokens
+        on: tokens, first-token and gap observations, expert counts and
+        cache reads are taken here, one step after the launch that knew
+        the rest. -> its (sync, emit) spans, empty where nothing was in
+        flight."""
+        emitted = counts = reads = None
+        with telemetry.span("serving/step_sync") as sync_span:
+            if flight is not None:
+                # Every slot's token in one transfer; the counts are ready
+                # with them: the same program returned them.
+                emitted = np.asarray(flight.emitted)
+                if flight.counts is not None:
+                    counts = np.asarray(flight.counts)
+                if flight.reads is not None:
+                    reads = np.asarray(flight.reads)
+                # The device's buffers go here, under this span.
+                flight.emitted = flight.counts = flight.reads = None
+        with telemetry.span("serving/step_emit") as emit_span:
+            was_retired = len(retired)
+            decode_tokens = dropped = 0
+            if flight is not None:
+                now = time.monotonic()
+                gaps = self._registry.histogram(
+                    "serving/inter_token_latency_ms")
+                for slot, state, sampled in flight.stepped:
+                    if self._slots[slot] is not state:
+                        # Retired since the launch (a deadline, or its eos
+                        # in the step before): the result is nobody's.
+                        dropped += 1
+                    elif sampled:
+                        decode_tokens += 1
+                        self._emit(slot, state, int(emitted[slot]), now,
+                                   gaps, retired)
+                self._account_tokens(flight.prefill_tokens, decode_tokens)
+                if counts is not None and counts.size:
+                    self._count_experts(counts)
+                if reads is not None:
+                    self._count_reads(reads)
+            emit_span.args.update(
+                tokens=decode_tokens, dropped=dropped,
+                retired=len(retired) - was_retired,
+            )
+        return sync_span, emit_span
+
+    def _emit(self, slot: int, state: _Slot, token: int, now: float, gaps,
+              retired: List) -> None:
+        """One read token to its client, and the slot's retirement where
+        the token ends the request."""
+        state.last_token = token
+        state.emitted += 1
+        first = state.response.first_token_at is None
+        state.response._push(token)
+        if first:
+            self._observe_ttft(state)
+        elif state.last_emit_at is not None:
+            gaps.observe((now - state.last_emit_at) * 1e3)
+        state.last_emit_at = now
+        eos = state.request.params.eos_token
+        if eos is not None and token == eos:
+            self._retire(slot, FINISH_EOS, retired)
+        elif state.emitted >= state.request.params.max_new_tokens:
+            self._retire(slot, FINISH_LENGTH, retired)
+
+    def _settle(self, reason: str, retired: Optional[List] = None) -> Tuple:
+        """Empty the pipeline: read and emit the step in flight, counted
+        under `reason` (`/stats` `pipeline_settles`); nothing, and no span,
+        where none is. Whoever needs a slot as the device left it calls
+        this first: a suspension, block shipping, shutdown, a tick with
+        nothing to launch. It leaves `_rngs` true for every live slot: the
+        rows of the slots that sampled are read back here, off the steady
+        tick (a slot that has not sampled yet still holds the row it was
+        admitted or resumed with). What it retires outside a tick goes
+        into the next tick's trace entry."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return ()
+        self._settles[reason] = self._settles.get(reason, 0) + 1
+        parts = self._land(
+            flight, self._carried_retired if retired is None else retired)
+        rows = np.asarray(self._fed[1])
+        for slot, state, sampled in flight.stepped:
+            if sampled and self._slots[slot] is state:
+                self._rngs[slot] = rows[slot]
+        return parts
 
     def _count_reads(self, reads: np.ndarray) -> None:
         """One step's cache reads as the model's attention layers counted
@@ -1671,12 +1823,14 @@ class SlotScheduler:
             self._slow_step_seconds += seconds
             if self._slowest_step is None or \
                     seconds * 1e3 > self._slowest_step["ms"]:
-                launch, sync, emit = self._step_parts
+                # (launch, sync, emit); a tick that had nothing to launch
+                # and nothing to read has the first alone.
+                parts = [part.duration * 1e3 for part in self._step_parts]
+                parts += [0.0] * (3 - len(parts))
                 self._slowest_step = {
                     "tick": tick, "ms": seconds * 1e3,
-                    "launch_ms": launch.duration * 1e3,
-                    "sync_ms": sync.duration * 1e3,
-                    "emit_ms": emit.duration * 1e3,
+                    "launch_ms": parts[0], "sync_ms": parts[1],
+                    "emit_ms": parts[2],
                 }
         history.append(seconds)
 
@@ -1907,13 +2061,20 @@ class SlotScheduler:
                 self._work.clear()
 
     def _fail_inflight(self, reason: str) -> None:
+        retired: List = []
+        try:
+            # What the device has finished is the clients' before the
+            # reason is. (The read that failed a tick is not read again:
+            # `_settle` let go of it first.)
+            self._settle(reason, retired)
+        except Exception:
+            _logger.exception("the step in flight could not be read")
         if self._held is not None:
             _request, response = self._held
             self._held = None
             self._finish_unadmitted(response, reason)
         for _request, response in self.queue.drain():
             self._finish_unadmitted(response, reason)
-        retired: List = []
         for entry in list(self._suspended):
             self._finish_suspended(entry, reason, retired)
         for slot in range(self.max_slots):
@@ -1983,6 +2144,11 @@ class SlotScheduler:
             "kv_token_steps": self._kv_token_steps,
             "kv_read_token_steps": self._kv_read_token_steps,
             "slot_steps": self._slot_steps,
+            # One-token steps launched; those launched while the step
+            # before was still unread; the pipeline emptied, by reason.
+            "steps": self._steps,
+            "steps_ahead": self._steps_ahead,
+            "pipeline_settles": dict(self._settles),
             "slow_steps": self._slow_steps,
             "slow_step_seconds": round(self._slow_step_seconds, 6),
             "slowest_step": self._slowest_step,
@@ -2085,11 +2251,24 @@ def _cache_nbytes_per_device(tree) -> int:
 
 
 def _prng_key(seed: int) -> np.ndarray:
-    """generate_legacy's PRNGKey(seed), as host uint32[2] for the rng
-    grid row."""
+    """generate_legacy's `jax.random.PRNGKey(seed)` as host uint32[2] for
+    the rng grid row, made here: on the device it was read back behind the
+    prefill just dispatched, 85-97 ms an admission on a v5e, and would
+    empty the pipeline. The two words are threefry's seeding: the seed as
+    a 64-bit integer where JAX holds one, its lower 32 bits alone where
+    it does not (tests/test_serving.py holds them equal)."""
     import jax
 
-    return np.asarray(jax.random.PRNGKey(int(seed)), np.uint32)
+    impl = jax.config.jax_default_prng_impl
+    if impl != "threefry2x32":
+        raise ValueError(
+            f"the serving grid holds threefry2x32 rng rows (uint32[2]); "
+            f"jax_default_prng_impl is {impl!r}"
+        )
+    seed = int(seed) + int(
+        getattr(jax.config, "jax_random_seed_offset", 0) or 0)
+    high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & 0xFFFFFFFF], np.uint32)
 
 
 def _to_host(tree):

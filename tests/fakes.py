@@ -11,6 +11,8 @@ an emission and fails them. Every call is logged for ordering assertions.
 Last, one drive that the serving tests of every model share.
 """
 
+import time
+
 import numpy as np
 
 from tf_yarn_tpu.serving import SlotScheduler
@@ -153,6 +155,51 @@ class FakePagedSpecEngine(FakePagedWindowedEngine):
 
     def paged_step(self, *args, **kwargs):
         raise AssertionError("a windowed grid must not run the exact step")
+
+
+class _Later:
+    """A device result that is ready at `ready_at` (`time.perf_counter`):
+    reading it on the host (`np.asarray`) waits until then, as a read of a
+    device array does."""
+
+    def __init__(self, value, ready_at):
+        self.value = value
+        self.ready_at = ready_at
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(max(0.0, self.ready_at - time.perf_counter()))
+        return np.asarray(self.value, dtype)
+
+
+class FakeAsyncEngine(FakePagedEngine):
+    """`FakePagedEngine` with a device that takes its time: every call
+    returns at once, and the device works through what it was handed in
+    order, `step_s` a step and `prefill_s` a prefill. A step's tokens
+    come back as a `_Later`, so the read of a step waits for that step
+    and for whatever was queued before it; the next step takes them as
+    they are, on the device."""
+
+    def __init__(self, step_s=0.0, prefill_s=0.0, **kwargs):
+        super().__init__(**kwargs)
+        self.step_s = step_s
+        self.prefill_s = prefill_s
+        self.free_at = 0.0  # when the device has done all it was handed
+
+    def _enqueue(self, seconds):
+        self.free_at = max(self.free_at, time.perf_counter()) + seconds
+        return self.free_at
+
+    def prefill(self, params, prompt):
+        self._enqueue(self.prefill_s)
+        return super().prefill(params, prompt)
+
+    def paged_step(self, params, pool, tables, lengths, emitted, rngs,
+                   *args, **kwargs):
+        if isinstance(emitted, _Later):
+            emitted = emitted.value  # fed back on the device: no wait
+        pool, emitted, rngs = super().paged_step(
+            params, pool, tables, lengths, emitted, rngs, *args, **kwargs)
+        return pool, _Later(emitted, self._enqueue(self.step_s)), rngs
 
 
 def fake_scheduler(engine, max_slots=2, **kwargs):

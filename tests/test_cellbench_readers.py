@@ -68,22 +68,38 @@ def test_every_new_quantity_is_declared_in_its_cells_with_a_file():  # noqa: F81
         assert set(theirs.run_lib.metric_file(name)) <= {"reader", "args"}
 
 
-def test_step_ahead_share_is_declared_as_data_and_silent_without_its_counters():
-    """PR 38's per-layer metric: one data file for an accepted reader, two
-    entries at the end of `per_layer` (the steady cell's moves `itl_p90_ms`,
-    the four closed loops' `serve_tokens_per_s`). The reader gives the share
-    of one-token steps launched while the step before was unread, and nothing
-    (no error) for a program whose `/stats` has no such counters, as the
-    parent's."""
+def _bench():
     import json
     import os
 
-    from cellbench.readers import stats_share
     from cellbench.tests import test_readers_tracing as theirs
 
     with open(os.path.join(theirs.ROOT, "BENCHMARK.json")) as fh:
-        bench = json.load(fh)
-    steady, backlog = bench["per_layer"][-2:]
+        return json.load(fh)
+
+
+def _side_by_side(bench, names):
+    """The entries of `names`, which stand together in `per_layer` in that
+    order wherever a later PR's entries put them: never "the last"."""
+    declared = [m["name"] for m in bench["per_layer"]]
+    first = declared.index(names[0])
+    assert declared[first:first + len(names)] == list(names)
+    return bench["per_layer"][first:first + len(names)]
+
+
+def test_step_ahead_share_is_declared_as_data_and_silent_without_its_counters():
+    """PR 38's per-layer metric: one data file for an accepted reader, two
+    entries side by side in `per_layer` (the steady cell's moves
+    `itl_p90_ms`, the four closed loops' `serve_tokens_per_s`). The reader
+    gives the share of one-token steps launched while the step before was
+    unread, and nothing (no error) for a program whose `/stats` has no such
+    counters, as the parent's."""
+    from cellbench.readers import stats_share
+    from cellbench.tests import test_readers_tracing as theirs
+
+    bench = _bench()
+    steady, backlog = _side_by_side(
+        bench, ["step_ahead_share.steady", "step_ahead_share.backlog"])
     closed_loops = [c["name"] for c in bench["workloads"]
                     if c["name"].endswith("_backlog")]
     assert steady == {
@@ -106,3 +122,184 @@ def test_step_ahead_share_is_declared_as_data_and_silent_without_its_counters():
     assert stats_share.read(parent, **args) is None
     idle = {"stats_open": run["stats_open"], "stats_close": run["stats_open"]}
     assert stats_share.read(idle, **args) is None
+
+
+# --------------------------------------------------------------------------
+# PR 39: a pipelined tick accounts for itself. Ten quantities, each read in
+# the steady cell and in the four closed loops; a data file each, three new
+# readers. Hand-made runs: a window of 50 s in which the program counted
+# 10,000 ticks, and the same run as the parent commit would give it (the
+# spans and counters it has, none of PR 39's).
+# --------------------------------------------------------------------------
+
+ACCOUNT = {
+    # quantity: (unit, better, source, reader, value on the run below)
+    "tick_host_ms": ("ms", "lower", "program_counter", "stats_share", 3.8),
+    "tick_host_paced_share": ("%", "lower", "program_counter",
+                              "stats_share", 1.5),
+    "step_interval_ms": ("ms", "lower", "program_counter", "stats_share",
+                         4.0),
+    "queued_ahead_share": ("%", "lower", "program_counter",
+                           "stats_excess_share", 19.2),
+    "stall_steps": ("count", "lower", "program_counter", "stats_count", 3.0),
+    "stall_seconds": ("s", "lower", "program_counter", "stats_count", 0.25),
+    "step_dispatch_ms": ("ms", "lower", "program_span", "span_mean_ms", 1.5),
+    "launch_inputs_ms": ("ms", "lower", "program_span", "span_mean_ms",
+                         0.25),
+    "launch_account_ms": ("ms", "lower", "program_span", "span_mean_ms",
+                          0.5),
+    "span_window_coverage": ("%", "higher", "program_span", "span_coverage",
+                             100.0),
+}
+
+
+def _account_run():
+    opened = {"ticks": 2000, "steps_read": 2000, "steps_host_paced": 40,
+              "host_seconds": 7.0, "clean_intervals": 1800,
+              "clean_interval_seconds": 7.5, "ahead_intervals": 100,
+              "ahead_interval_seconds": 2.0, "ahead_prefill_tokens": 90000,
+              "slow_steps": 1, "slow_step_seconds": 0.5, "spans_evicted": 0}
+    closed = {"ticks": 12000, "steps_read": 12000, "steps_host_paced": 190,
+              "host_seconds": 45.0, "clean_intervals": 10800,
+              "clean_interval_seconds": 43.5, "ahead_intervals": 700,
+              "ahead_interval_seconds": 14.0, "ahead_prefill_tokens": 700000,
+              "slow_steps": 4, "slow_step_seconds": 0.75,
+              "spans_evicted": 0}
+    spans = []
+    for start in (90.0, 100.0, 120.0, 149.0):
+        spans += [
+            {"name": "serving/tick", "start": start, "dur": 0.005},
+            {"name": "decode_engine/paged_step", "start": start,
+             "dur": 0.0015},
+            {"name": "serving/launch_inputs", "start": start,
+             "dur": 0.00025},
+            {"name": "serving/launch_account", "start": start,
+             "dur": 0.0005},
+        ]
+    return {"stats_open": opened, "stats_close": closed, "spans": spans,
+            "window": [100.0, 150.0]}
+
+
+def _as_the_parent(run):
+    """The same run from a program without PR 39: one ring (no
+    `spans_evicted`), the old tally under `slow_steps`, no launch children,
+    none of the account's counters; `decode_engine/paged_step` it has."""
+    kept = ("ticks", "slow_steps", "slow_step_seconds")
+    return {
+        "stats_open": {k: run["stats_open"][k] for k in kept},
+        "stats_close": {k: run["stats_close"][k] for k in kept},
+        "spans": [s for s in run["spans"]
+                  if not s["name"].startswith("serving/launch_")],
+        "window": run["window"],
+    }
+
+
+def test_the_ticks_account_is_declared_side_by_side_in_both_kinds_of_cell():
+    from cellbench.tests import test_readers_tracing as theirs
+
+    bench = _bench()
+    names = [f"{q}.{s}" for q in ACCOUNT for s in ("steady", "backlog")]
+    entries = _side_by_side(bench, names)
+    closed_loops = [c["name"] for c in bench["workloads"]
+                    if c["name"].endswith("_backlog")]
+    for entry in entries:
+        quantity, suffix = entry["name"].rsplit(".", 1)
+        unit, better, source, reader, _value = ACCOUNT[quantity]
+        assert entry == {
+            "name": entry["name"], "unit": unit, "better": better,
+            "source": source, "layer": "server and scheduler",
+            "moves": {"steady": "itl_p90_ms",
+                      "backlog": "serve_tokens_per_s"}[suffix],
+            "workloads": {"steady": ["mistral7b_chat_steady"],
+                          "backlog": closed_loops}[suffix]}
+        # One file a quantity serves both of its entries.
+        metric = theirs.run_lib.metric_file(entry["name"])
+        assert set(metric) == {"reader", "args"}
+        assert metric["reader"] == reader
+    # They follow PR 38's two, which stand where they stood.
+    declared = [m["name"] for m in bench["per_layer"]]
+    assert declared.index(names[0]) == \
+        declared.index("step_ahead_share.backlog") + 1
+
+
+import pytest  # noqa: E402
+
+
+@pytest.mark.parametrize("quantity", sorted(ACCOUNT))
+def test_a_quantity_of_the_ticks_account_reads_its_number_and_is_silent_on_the_parent(
+        quantity):
+    import importlib
+
+    from cellbench.tests import test_readers_tracing as theirs
+
+    metric = theirs.run_lib.metric_file(quantity + ".backlog")
+    reader = importlib.import_module("cellbench.readers." + metric["reader"])
+    run = _account_run()
+    assert reader.read(run, **metric["args"]) == pytest.approx(
+        ACCOUNT[quantity][4])
+    parent = reader.read(_as_the_parent(run), **metric["args"])
+    if quantity == "step_dispatch_ms":
+        # The span was there before PR 39 and no metric read it.
+        assert parent == pytest.approx(1.5)
+    else:
+        assert parent is None
+
+
+def test_stats_excess_share_is_what_the_queued_work_added_to_the_window():
+    from cellbench.readers import stats_excess_share
+
+    args = dict(seconds="ahead_interval_seconds", count="ahead_intervals",
+                base_seconds="clean_interval_seconds",
+                base_count="clean_intervals")
+    run = _account_run()
+    # 600 intervals took 12 s where 600 clean ones take 600 x 4 ms = 2.4 s:
+    # 9.6 s of a 50 s window.
+    assert stats_excess_share.read(run, **args) == pytest.approx(19.2)
+    nothing_ahead = _account_run()
+    for key in ("ahead_intervals", "ahead_interval_seconds"):
+        nothing_ahead["stats_close"][key] = nothing_ahead["stats_open"][key]
+    assert stats_excess_share.read(nothing_ahead, **args) == 0.0
+    no_base = _account_run()
+    no_base["stats_close"]["clean_intervals"] = \
+        no_base["stats_open"]["clean_intervals"]
+    assert stats_excess_share.read(no_base, **args) is None
+    assert stats_excess_share.read(_as_the_parent(run), **args) is None
+
+
+def test_stats_count_is_a_growth_and_needs_the_counters_that_gave_it_its_meaning():
+    from cellbench.readers import stats_count
+
+    run = _account_run()
+    assert stats_count.read(run, ["slow_steps"]) == 3.0
+    assert stats_count.read(run, ["slow_steps", "ahead_intervals"]) == 603.0
+    assert stats_count.read(run, ["slow_step_seconds"],
+                            needs=["steps_read"]) == pytest.approx(0.25)
+    parent = _as_the_parent(run)
+    # The parent's `slow_steps` counted admissions: without `needs` it would
+    # be read as stalls.
+    assert stats_count.read(parent, ["slow_steps"]) == 3.0
+    assert stats_count.read(parent, ["slow_steps"],
+                            needs=["steps_read"]) is None
+    assert stats_count.read(parent, ["no_such_counter"]) is None
+
+
+def test_span_coverage_says_how_much_of_the_window_the_rings_still_hold():
+    from cellbench.readers import span_coverage
+
+    run = _account_run()
+    assert span_coverage.read(run, "serving/tick") == 100.0
+    # A ring that dropped spans, but none of the window's.
+    run["stats_close"]["spans_evicted"] = 5000
+    assert span_coverage.read(run, "serving/tick") == 100.0
+    # One that dropped the window's first 20 of 50 seconds.
+    late = dict(run, spans=[s for s in run["spans"] if s["start"] >= 120.0])
+    assert span_coverage.read(late, "serving/tick") == pytest.approx(60.0)
+    # Everything it kept is from after the window: nothing of it is held.
+    after = dict(run, spans=[{"name": "serving/tick", "start": 151.0,
+                              "dur": 0.005}])
+    assert span_coverage.read(after, "serving/tick") == 0.0
+    # It dropped nothing, so nothing is missing wherever the first span is.
+    late["stats_close"] = dict(run["stats_close"], spans_evicted=0)
+    assert span_coverage.read(late, "serving/tick") == 100.0
+    assert span_coverage.read(run, "no/such_span") is None
+    assert span_coverage.read(_as_the_parent(run), "serving/tick") is None

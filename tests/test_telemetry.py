@@ -16,6 +16,7 @@ import pytest
 from tf_yarn_tpu import telemetry
 from tf_yarn_tpu.coordination import InProcessKV
 from tf_yarn_tpu.telemetry.registry import MetricsRegistry
+from tf_yarn_tpu.telemetry import spans as spans_lib
 from tf_yarn_tpu.telemetry.spans import Tracer
 
 
@@ -88,10 +89,80 @@ def test_span_exception_propagates_and_records():
 def test_ring_buffer_bounds_memory():
     tracer = Tracer(capacity=4)
     for i in range(10):
-        with tracer.span(f"s{i}"):
+        with tracer.span("s", i=i):
             pass
-    names = [s.name for s in tracer.records()]
-    assert names == ["s6", "s7", "s8", "s9"]  # newest 4 survive
+    kept = [s.args["i"] for s in tracer.records()]
+    assert kept == [6, 7, 8, 9]  # the newest 4 of a name survive
+
+
+TICK_NAMES = tuple(f"tick/part{i}" for i in range(11))
+
+
+def test_a_span_written_once_outlives_300k_spans_of_eleven_other_names(
+        monkeypatch):
+    """The serving tick writes eleven names every few milliseconds; the
+    compile it started with is what the benchmark reads after the window.
+    No name evicts another's."""
+    monkeypatch.delenv(spans_lib.TRACE_BUFFER_ENV, raising=False)
+    tracer = Tracer()
+    with tracer.span("decode_engine/compile", kind="paged_step") as first:
+        pass
+    for i in range(300_000):
+        tracer.record(TICK_NAMES[i % 11], float(i), 0.0)
+    records = tracer.records()
+    assert records[0] is first
+    # 27,273 of a name: under the bound a name, so nothing went at all.
+    assert len(records) == 300_001 and tracer.evicted_total() == 0
+    assert [s.order for s in records] == list(range(1, 300_002))
+
+
+def test_ring_memory_is_bounded_by_constants_and_the_env_means_spans_a_name(
+        monkeypatch):
+    monkeypatch.setenv(spans_lib.TRACE_BUFFER_ENV, "3")
+    tracer = Tracer()
+    assert tracer.capacity == 3
+    monkeypatch.setenv(spans_lib.TRACE_BUFFER_ENV, "not a number")
+    assert Tracer().capacity == spans_lib.DEFAULT_CAPACITY
+    # More names than rings: the names past MAX_NAMES share one ring, so
+    # whatever a caller formats into a name, at most (MAX_NAMES + 1) x
+    # capacity spans are held.
+    names = [f"n{i}" for i in range(spans_lib.MAX_NAMES + 40)]
+    for _ in range(5):
+        for name in names:
+            tracer.record(name, 0.0, 0.0)
+    records = tracer.records()
+    assert len(records) == (spans_lib.MAX_NAMES + 1) * 3
+    assert len(tracer._rings) == spans_lib.MAX_NAMES + 1
+    by_name = {}
+    for span in records:
+        by_name[span.name] = by_name.get(span.name, 0) + 1
+    assert all(by_name[name] == 3 for name in names[:spans_lib.MAX_NAMES])
+    # The shared ring holds the newest three of the 40 late names.
+    assert [s.name for s in records if s.name in names[spans_lib.MAX_NAMES:]] \
+        == names[-3:]
+    # The bound that sizes the default: the benchmark's window, lead-in
+    # and warm-up at a 3 ms tick, with room.
+    assert spans_lib.DEFAULT_CAPACITY * 0.003 >= 51 + 16 + 20
+
+
+def test_evictions_are_counted_by_name_and_survive_a_clear():
+    tracer = Tracer(capacity=4)
+    for i in range(10):
+        tracer.record("often", float(i), 0.0)
+    tracer.record("once", 0.0, 0.0)
+    assert tracer.evicted == {"often": 6, "once": 0}
+    assert tracer.evicted_total() == 6
+    assert [s.name for s in tracer.records()].count("often") == 4
+    tracer.clear()
+    assert tracer.records() == [] and tracer.evicted_total() == 6
+    tracer.record("often", 0.0, 0.0)
+    assert tracer.evicted["often"] == 6 and len(tracer.records()) == 1
+    # Past MAX_NAMES the shared ring's drops are counted under its key.
+    crowded = Tracer(capacity=1)
+    for i in range(spans_lib.MAX_NAMES + 3):
+        crowded.record(f"n{i}", 0.0, 0.0)
+    assert crowded.evicted[spans_lib.OVERFLOW] == 2
+    assert crowded.evicted_total() == 2
 
 
 def test_chrome_trace_schema_roundtrip(tmp_path):
@@ -384,7 +455,12 @@ def test_inference_trace_and_registry_end_to_end(tmp_path, monkeypatch):
     snap = telemetry.get_registry().snapshot()
     assert snap["decode_engine/calls"] == 2
     assert snap["decode_engine/compiles{kind=prefill}"] >= 1
-    assert snap["decode_engine/cache_hits{kind=prefill}"] >= 1
+    # The hits are the engine's own tally (`/stats` `decode_engine.*`);
+    # the registry holds no copy of them.
+    from tf_yarn_tpu.models.decode_engine import get_engine
+
+    assert get_engine(model).stats["prefill_cache_hits"] >= 1
+    assert not any(k.startswith("decode_engine/cache_hits") for k in snap)
     assert "inference/stage_seconds_sum{stage=decode}" in snap
     assert "decode_engine/compile_seconds_sum{kind=decode}" in snap
 

@@ -646,6 +646,16 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._slot_steps", _ADVISORY),
                 ("scheduler._steps", _ADVISORY),
                 ("scheduler._steps_ahead", _ADVISORY),
+                ("scheduler._steps_read", _ADVISORY),
+                ("scheduler._steps_host_paced", _ADVISORY),
+                ("scheduler._host_seconds", _ADVISORY),
+                ("scheduler._sync_wait_seconds", _ADVISORY),
+                ("scheduler._idle_wait_seconds", _ADVISORY),
+                ("scheduler._clean_intervals", _ADVISORY),
+                ("scheduler._clean_interval_seconds", _ADVISORY),
+                ("scheduler._ahead_intervals", _ADVISORY),
+                ("scheduler._ahead_interval_seconds", _ADVISORY),
+                ("scheduler._ahead_prefill_tokens", _ADVISORY),
                 ("scheduler._slow_steps", _ADVISORY),
                 ("scheduler._slow_step_seconds", _ADVISORY),
                 ("scheduler._slowest_step", _ADVISORY),
@@ -666,6 +676,16 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._slot_steps", _ADVISORY),
                 ("scheduler._steps", _ADVISORY),
                 ("scheduler._steps_ahead", _ADVISORY),
+                ("scheduler._steps_read", _ADVISORY),
+                ("scheduler._steps_host_paced", _ADVISORY),
+                ("scheduler._host_seconds", _ADVISORY),
+                ("scheduler._sync_wait_seconds", _ADVISORY),
+                ("scheduler._idle_wait_seconds", _ADVISORY),
+                ("scheduler._clean_intervals", _ADVISORY),
+                ("scheduler._clean_interval_seconds", _ADVISORY),
+                ("scheduler._ahead_intervals", _ADVISORY),
+                ("scheduler._ahead_interval_seconds", _ADVISORY),
+                ("scheduler._ahead_prefill_tokens", _ADVISORY),
                 ("scheduler._slow_steps", _ADVISORY),
                 ("scheduler._slow_step_seconds", _ADVISORY),
                 ("scheduler._slowest_step", _ADVISORY),

@@ -97,6 +97,7 @@ top_k / top_p are baked into the traced program) key the cache.
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import weakref
@@ -1281,14 +1282,10 @@ class DecodeEngine:
     # -- compile cache -----------------------------------------------------
 
     def _compiled(self, cache_dict, key, stat_prefix, build):
-        registry = telemetry.get_registry()
         with self._lock:
             compiled = cache_dict.get(key)
             if compiled is not None:
                 self.stats[f"{stat_prefix}_cache_hits"] += 1
-                registry.counter(
-                    "decode_engine/cache_hits", kind=stat_prefix
-                ).inc()
                 return compiled
         # Compile outside the lock (slow); a racing duplicate compile is
         # harmless — last writer wins, both executables are equivalent.
@@ -1296,6 +1293,7 @@ class DecodeEngine:
             "decode_engine/compile", kind=stat_prefix, key=str(key)
         ) as sp:
             compiled = build()
+        registry = telemetry.get_registry()
         registry.counter("decode_engine/compiles", kind=stat_prefix).inc()
         registry.histogram(
             "decode_engine/compile_seconds", kind=stat_prefix
@@ -1531,24 +1529,23 @@ class DecodeEngine:
         Returns (pool, state, emitted [S], rngs, counts), and the model's
         cache reads after them where it counts them."""
         slots = int(jnp.shape(tokens)[0])
-        kernel = self.paged_attention_kernel(pool)
         compiled, args = self._paged_program(
             self._paged_step, "paged_step",
             ("state", slots, tuple(jnp.shape(tables)), block_size,
-             float(temperature), top_k, top_p, kernel),
-            lambda: build_paged_state_step_fn(
+             float(temperature), top_k, top_p),
+            lambda kernel: build_paged_state_step_fn(
                 self.model, block_size, temperature, top_k, top_p,
                 paged_kernel=kernel),
             params, (pool, state),
             _step_host_args(tables, lengths, emitted, rngs, tokens, rng_rows,
                             forced, sample_mask),
-            donate=(1, 2, 6), replicated_outs=3,
+            donate=(1, 2, 6), replicated_outs=3, kernel_for=pool,
         )
         with telemetry.span("decode_engine/paged_step", slots=slots):
             return compiled(*args)
 
     def _paged_program(self, programs, stat, key, build, params, trees,
-                       host, donate, replicated_outs):
+                       host, donate, replicated_outs, kernel_for=None):
         """What the host does before the device has anything to do, for
         every paged step alike (span `decode_engine/step_args`): place
         the params, upload the tick's host arrays (`host`: (value, dtype)
@@ -1558,8 +1555,15 @@ class DecodeEngine:
         compile cache by `key` + the params' and the
         trees' fingerprints, and compile on a miss (`build()` makes the
         step function; the trees come back first among its outputs, then
-        `replicated_outs` small ones). Returns (compiled, args)."""
+        `replicated_outs` small ones). The one-token steps name their
+        pool as `kernel_for`: which `paged_decode_attention` the step is
+        compiled with is chosen here, under the same span, joins `key`,
+        and is what `build` is called with. Returns (compiled, args)."""
         with telemetry.span("decode_engine/step_args"):
+            if kernel_for is not None:
+                kernel = self.paged_attention_kernel(kernel_for)
+                key += (kernel,)
+                build = functools.partial(build, kernel)
             params, fp = self._placed(params)
             # Host arrays go to the program as they are: it uploads them
             # in its own call, which costs a tenth of five `jnp.asarray`s.
@@ -1761,18 +1765,17 @@ class DecodeEngine:
         (its reader comes after this launch). Returns (pool, emitted [S],
         rngs), none of them waited for."""
         slots = int(jnp.shape(tokens)[0])
-        kernel = self.paged_attention_kernel(pool)
         compiled, args = self._paged_program(
             self._paged_step, "paged_step",
             (slots, tuple(jnp.shape(tables)), block_size, float(temperature),
-             top_k, top_p, kernel),
-            lambda: build_paged_step_fn(
+             top_k, top_p),
+            lambda kernel: build_paged_step_fn(
                 self.model, block_size, temperature, top_k, top_p,
                 paged_kernel=kernel),
             params, (pool,),
             _step_host_args(tables, lengths, emitted, rngs, tokens, rng_rows,
                             forced, sample_mask),
-            donate=(1, 5), replicated_outs=2,
+            donate=(1, 5), replicated_outs=2, kernel_for=pool,
         )
         with telemetry.span("decode_engine/paged_step", slots=slots):
             return compiled(*args)

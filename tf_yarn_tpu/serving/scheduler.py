@@ -99,15 +99,31 @@ _logger = logging.getLogger(__name__)
 # deadline-expiry latency for queued-but-idle states.
 IDLE_POLL_S = 0.05
 
-# A model step is "slow" when it takes more than SLOW_STEP_FACTOR times
-# the running median of the last SLOW_STEP_WINDOW steps (judged once
-# SLOW_STEP_MIN_HISTORY of them exist).
+# A tick's `serving/step` is "slow" when it takes more than
+# SLOW_STEP_FACTOR times the running median of the last SLOW_STEP_WINDOW
+# of them (judged once SLOW_STEP_MIN_HISTORY exist). Only ticks whose read
+# had nothing queued ahead of it are judged and remembered: a read behind
+# an admission's prefill is long by that prefill, which the `ahead_*`
+# counters hold.
 SLOW_STEP_FACTOR = 2.0
 SLOW_STEP_WINDOW = 256
 SLOW_STEP_MIN_HISTORY = 8
 # `finish` of the `serving/request` record of a request `submit` turned
 # away (queue full, tier cap, unservable): it never had a Response.
 REFUSED = "refused"
+
+# A `serving/step_sync` longer than this waited for the device
+# (`paced="device"`); a shorter one found the result there already, and the
+# host set that tick's pace (`paced="host"`). Measured on a TPU v5 lite
+# (PR 39's chip runs): the read of a result known to be ready (the real
+# scheduler and engine driven by hand, the step in flight waited for and
+# 20 ms more before each tick) takes 43 us at the median and 93 us at most
+# for one array (the tokens; 220 reads), 65 us and 126 us for three (tokens,
+# expert counts, cache reads; 228 reads); in the benchmark's Mistral cells
+# the reads under this constant took 30-98 us (447 of them in one window),
+# and of the reads that waited the shortest hundredth took 1.7 ms. Twice
+# the longest ready read, a sixth of the shortest wait.
+SYNC_READY_S = 0.00025
 
 DECODE_ATTENTION = ("gather", "fused")
 # Why the one-token pipeline was emptied (`/stats` `pipeline_settles`):
@@ -211,16 +227,24 @@ class _Flight:
     still on the device, and what the launch knew of each slot it stepped
     (`stepped`: (slot, the `_Slot` it held, whether it sampled)). A slot
     that holds another `_Slot` at the read was retired meanwhile, and its
-    result is dropped."""
+    result is dropped. `step` is the model step's number, which its launch,
+    sync and emit spans share across two ticks; `ahead` is what the
+    scheduler's thread dispatched to the device between the launch before
+    and this one, and so runs before this step: (programs, prompt tokens of
+    blocking prefills)."""
 
-    __slots__ = ("emitted", "counts", "reads", "stepped", "prefill_tokens")
+    __slots__ = ("emitted", "counts", "reads", "stepped", "prefill_tokens",
+                 "step", "ahead")
 
-    def __init__(self, emitted, counts, reads, stepped, prefill_tokens):
+    def __init__(self, emitted, counts, reads, stepped, prefill_tokens,
+                 step, ahead):
         self.emitted = emitted
         self.counts = counts
         self.reads = reads
         self.stepped = stepped
         self.prefill_tokens = prefill_tokens
+        self.step = step
+        self.ahead = ahead
 
 
 class _ControlOp:
@@ -486,6 +510,32 @@ class SlotScheduler:
         self._carried_retired: List = []
         self._steps = 0
         self._steps_ahead = 0
+        # Programs (and prompt tokens of blocking prefills among them)
+        # dispatched since the last launch: the next flight's `ahead`.
+        self._ahead = [0, 0]
+        # A tick's account of itself (docs/Serving.md "Where a tick's time
+        # goes"), all from the spans' own start and duration. The thread's
+        # time in three parts: under `step_sync`, under `idle_wait`, and
+        # the host's (the rest of its top-level spans).
+        self._steps_read = 0
+        self._steps_host_paced = 0
+        self._host_seconds = 0.0
+        self._sync_wait_seconds = 0.0
+        self._idle_wait_seconds = 0.0
+        self._tick_sync_s = 0.0  # `step_sync` under this round's spans
+        # Read end to read end (`_note_read`): one model step's device
+        # time where both reads waited for the device and nothing was
+        # queued ahead of the second (`clean`), one step and what an
+        # admission put before it where something was (`ahead`).
+        self._clean_intervals = 0
+        self._clean_interval_seconds = 0.0
+        self._ahead_intervals = 0
+        self._ahead_interval_seconds = 0.0
+        self._ahead_prefill_tokens = 0
+        # When the last read ended (None once the pipeline was emptied),
+        # and whether it waited for the device.
+        self._read_end: Optional[float] = None
+        self._read_waited = False
         # Every reason from the start: `stats()` copies it on other threads.
         self._settles: Dict[str, int] = dict.fromkeys(SETTLE_REASONS, 0)
         self._slots: List[Optional[_Slot]] = [None] * max_slots
@@ -509,8 +559,22 @@ class SlotScheduler:
         self._stop = threading.Event()
         self._lifecycle = threading.Lock()
         self._thread: Optional[threading.Thread] = None
-        self._registry = telemetry.get_registry()
+        self._registry = registry = telemetry.get_registry()
         self._tracer = telemetry.get_tracer()
+        # What every tick observes or sets, looked up once (a lookup is a
+        # label key and the registry's lock).
+        self._tick_seconds = registry.histogram("serving/tick_seconds")
+        self._ticks_total = registry.counter("serving/ticks_total")
+        self._token_gaps = registry.histogram(
+            "serving/inter_token_latency_ms")
+        self._gauges = {
+            name: registry.gauge("serving/" + name)
+            for name in ("active_slots", "free_slots", "queue_depth",
+                         "block_pool_used_blocks", "block_pool_free_blocks",
+                         "prefix_cache_entries", "prefix_cache_blocks",
+                         "prefix_cache_hit_rate")
+        }
+        self._suspended_gauges: Dict[str, object] = {}
         # max context the model's KV cache can hold, when the engine
         # exposes a config (the fake engines in tests need not) or the
         # caller says so explicitly.
@@ -564,6 +628,9 @@ class SlotScheduler:
             HostBlockStore(kv_host_blocks, self._block_size)
             if kv_host_blocks else None
         )
+        if self._host_store is not None:
+            for name in ("host_blocks_used", "host_blocks_free"):
+                self._gauges[name] = registry.gauge("serving/" + name)
         self._tables = np.zeros(
             (max_slots, self._blocks_per_slot), np.int32
         )
@@ -773,7 +840,7 @@ class SlotScheduler:
             with self._trace_id_lock:
                 self._trace_ids[request.id] = trace_id
         self._registry.counter("serving/requests_total").inc()
-        self._registry.gauge("serving/queue_depth").set(self.queue.depth)
+        self._gauges["queue_depth"].set(self.queue.depth)
         self._work.set()
         return response
 
@@ -792,7 +859,8 @@ class SlotScheduler:
         # The scheduler's thread is always under a named span: control
         # ops, the tick, what follows it, the idle wait (docs/Serving.md
         # "Where a tick's time goes").
-        with telemetry.span("serving/control_ops"):
+        self._tick_sync_s = 0.0
+        with telemetry.span("serving/control_ops") as control_span:
             self._run_control_ops()
         now = time.monotonic()
         admitted: List[int] = []
@@ -824,10 +892,16 @@ class SlotScheduler:
                         accepts = self._step_spec(active, retired)
                     else:
                         self._step(active, retired)
-        with telemetry.span("serving/publish"):
-            return self._publish(
+        with telemetry.span("serving/publish") as publish_span:
+            worked = self._publish(
                 tick_span, step_span, active, admitted, retired, accepts
             )
+        # The host's part of this round: its three spans less the waits
+        # for the device under them.
+        self._host_seconds += (
+            control_span.duration + tick_span.duration
+            + publish_span.duration - self._tick_sync_s)
+        return worked
 
     def _publish(self, tick_span, step_span, active, admitted, retired,
                  accepts) -> bool:
@@ -842,10 +916,8 @@ class SlotScheduler:
         self._peak_streams = max(self._peak_streams, streams)
         if worked:
             self._ticks += 1
-            self._registry.histogram("serving/tick_seconds").observe(
-                tick_span.duration
-            )
-            self._registry.counter("serving/ticks_total").inc()
+            self._tick_seconds.observe(tick_span.duration)
+            self._ticks_total.inc()
             entry = {
                 "tick": self._ticks,
                 "admitted": admitted,
@@ -873,33 +945,19 @@ class SlotScheduler:
                     # retired this tick.
                     entry["trace"] = trace_map
             self.trace.append(entry)
-        self._registry.gauge("serving/active_slots").set(
-            len([s for s in self._slots if s is not None])
-        )
-        self._registry.gauge("serving/free_slots").set(len(self._free))
-        self._registry.gauge("serving/queue_depth").set(self.queue.depth)
-        self._registry.gauge("serving/block_pool_used_blocks").set(
-            self._blocks.used_blocks
-        )
-        self._registry.gauge("serving/block_pool_free_blocks").set(
-            self._blocks.free_blocks
-        )
-        self._registry.gauge("serving/prefix_cache_entries").set(
-            self._prefix.entries
-        )
-        self._registry.gauge("serving/prefix_cache_blocks").set(
-            self._prefix.cached_blocks
-        )
-        self._registry.gauge("serving/prefix_cache_hit_rate").set(
-            self._prefix.hit_rate
-        )
+        gauges = self._gauges
+        gauges["active_slots"].set(
+            len([s for s in self._slots if s is not None]))
+        gauges["free_slots"].set(len(self._free))
+        gauges["queue_depth"].set(self.queue.depth)
+        gauges["block_pool_used_blocks"].set(self._blocks.used_blocks)
+        gauges["block_pool_free_blocks"].set(self._blocks.free_blocks)
+        gauges["prefix_cache_entries"].set(self._prefix.entries)
+        gauges["prefix_cache_blocks"].set(self._prefix.cached_blocks)
+        gauges["prefix_cache_hit_rate"].set(self._prefix.hit_rate)
         if self._host_store is not None:
-            self._registry.gauge("serving/host_blocks_used").set(
-                self._host_store.used_blocks
-            )
-            self._registry.gauge("serving/host_blocks_free").set(
-                self._host_store.free_blocks
-            )
+            gauges["host_blocks_used"].set(self._host_store.used_blocks)
+            gauges["host_blocks_free"].set(self._host_store.free_blocks)
             counts: Dict[str, int] = {}
             for entry in self._suspended:
                 tier = entry.request.tier
@@ -908,9 +966,12 @@ class SlotScheduler:
                 counts.setdefault(tier, 0)
             counts.setdefault(DEFAULT_TIER, 0)
             for tier, count in counts.items():
-                self._registry.gauge(
-                    "serving/suspended_streams", tier=tier
-                ).set(count)
+                gauge = self._suspended_gauges.get(tier)
+                if gauge is None:
+                    gauge = self._suspended_gauges[tier] = \
+                        self._registry.gauge(
+                            "serving/suspended_streams", tier=tier)
+                gauge.set(count)
         return worked
 
     def _retire_deadlines(self, now: float, retired: List) -> None:
@@ -983,6 +1044,20 @@ class SlotScheduler:
                 # decode of in-flight requests continues.
                 self._held = (request, response)
                 break
+
+    def _queued(self, programs: int, prefill_tokens: int = 0) -> None:
+        """The scheduler's thread has just dispatched `programs` to the
+        device (a blocking prefill of `prefill_tokens`, its pack, a state
+        write, a block extract or inject): they run ahead of the next
+        step launched, whose read will wait for them (`_Flight.ahead`)."""
+        self._ahead[0] += programs
+        self._ahead[1] += prefill_tokens
+
+    def _take_ahead(self) -> Tuple[int, int]:
+        """What was dispatched since the launch before, for the step being
+        launched: it runs before this step, whose read waits for it too."""
+        ahead, self._ahead = tuple(self._ahead), [0, 0]
+        return ahead
 
     def _record_admission(self, slot: int, state: _Slot, now: float,
                           admitted: List[int], began: float,
@@ -1078,6 +1153,7 @@ class SlotScheduler:
                         np.asarray(blocks[:n_pack], np.int32),
                         row_cache, prefill_len, self._block_size,
                     )
+                    self._queued(2, prefill_len)
                     if not self._state_leaves:
                         # Offer the full-block prefix for sharing; the
                         # partial tail block stays private (the replay
@@ -1090,6 +1166,7 @@ class SlotScheduler:
                     self._state = self.engine.write_slot_state(
                         self._state, slot, row_cache
                     )
+                    self._queued(1)
                     self._state_resets += 1
                     if self._prefix_capacity:
                         self._prefix_skipped_stateful += 1
@@ -1163,6 +1240,7 @@ class SlotScheduler:
             payload = _to_host(self.engine.extract_blocks(
                 self.params, self._pool, ids, self._block_size
             ))
+            self._queued(1)
         self._host_store.put(state.request.id, n_valid, payload)
         self._blocks.release(state.blocks)
         state.blocks = None
@@ -1269,6 +1347,7 @@ class SlotScheduler:
             self._pool = self.engine.inject_blocks(
                 self.params, self._pool, ids, payload, self._block_size
             )
+            self._queued(1)
         self._suspended.remove(entry)
         self._tables[slot, :] = 0
         self._tables[slot, :len(blocks)] = blocks
@@ -1414,6 +1493,7 @@ class SlotScheduler:
                 payload = _to_host(self.engine.extract_blocks(
                     self.params, self._pool, ids_arr, self._block_size
                 ))
+                self._queued(1)
                 leaves, _ = jax.tree_util.tree_flatten(
                     payload, is_leaf=_none_leaf
                 )
@@ -1492,6 +1572,7 @@ class SlotScheduler:
                 self.params, self._pool, ids_arr, payload,
                 self._block_size,
             )
+            self._queued(1)
         registered = 0
         # Cold-to-hot so the donor's hottest entries land at the MRU
         # end of the local LRU.
@@ -1518,18 +1599,21 @@ class SlotScheduler:
         engine's call for the NEXT step (the device is still under the
         step before), the tick's one host sync (the host waits for the
         step before, which had a head start), the per-slot bookkeeping of
-        what was read (the device is under the step just launched). Where
-        nothing can be launched the step in flight is settled."""
+        what was read (the device is under the step just launched). Each
+        carries the number of the model step it launches or reads (`step`),
+        so one step's three spans join across the two ticks they lie in.
+        Where nothing can be launched the step in flight is settled."""
         with telemetry.span("serving/step_launch") as launch_span:
             before = self._flight
             budget = [s for s in active if self._slots[s].launched
                       < self._slots[s].request.params.max_new_tokens]
+            step = None
             if budget:
                 self._flight = self._launch(active, budget)
-                self._steps += 1
+                self._steps = step = self._flight.step
                 self._steps_ahead += int(before is not None)
             launch_span.args.update(
-                slots=len(budget),
+                slots=len(budget), step=step,
                 ahead=int(bool(budget) and before is not None),
             )
         if budget:
@@ -1544,74 +1628,86 @@ class SlotScheduler:
         what the host knows, everything the step after it needs: lengths,
         the replay queues, the counts of what the step reads. Nothing here
         waits for the device. A slot of `active` outside `budget` (its
-        last token is in flight) rides along as a free slot does."""
-        tokens = np.zeros((self.max_slots,), np.int32)
-        forced = np.ones((self.max_slots,), bool)
-        mask = np.zeros((self.max_slots,), bool)
-        # Its own copies: the host arrays move on below, and a backend
-        # that aliases host memory may not have consumed them yet.
-        tables, lengths = self._tables.copy(), self._lengths.copy()
-        rng_rows = self._rngs.copy()
-        if len(budget) < len(active):
-            for slot in set(active).difference(budget):
-                tables[slot, :] = 0
-                lengths[slot] = 0
-        for slot in budget:
-            state = self._slots[slot]
-            if state.pending:
-                tokens[slot] = state.pending[0]
-                mask[slot] = len(state.pending) == 1
-            else:
-                mask[slot] = True
-                if state.refeed:
-                    tokens[slot] = state.last_token
+        last token is in flight) rides along as a free slot does. Four
+        spans tile `serving/step_launch`: the host arrays
+        (`serving/launch_inputs`), the engine's two (`decode_engine/
+        step_args`, then `decode_engine/paged_step`: the dispatch, which
+        waits its turn where the device's queue is full), and what follows
+        the call (`serving/launch_account`)."""
+        with telemetry.span("serving/launch_inputs"):
+            tokens = np.zeros((self.max_slots,), np.int32)
+            forced = np.ones((self.max_slots,), bool)
+            mask = np.zeros((self.max_slots,), bool)
+            # Its own copies: the host arrays move on below, and a backend
+            # that aliases host memory may not have consumed them yet.
+            tables, lengths = self._tables.copy(), self._lengths.copy()
+            rng_rows = self._rngs.copy()
+            if len(budget) < len(active):
+                for slot in set(active).difference(budget):
+                    tables[slot, :] = 0
+                    lengths[slot] = 0
+            for slot in budget:
+                state = self._slots[slot]
+                if state.pending:
+                    tokens[slot] = state.pending[0]
+                    mask[slot] = len(state.pending) == 1
                 else:
-                    forced[slot] = False  # fed back on the device
-        sampling = dict(block_size=self._block_size,
-                        temperature=self.temperature, top_k=self.top_k,
-                        top_p=self.top_p)
-        counts = reads = None
+                    mask[slot] = True
+                    if state.refeed:
+                        tokens[slot] = state.last_token
+                    else:
+                        forced[slot] = False  # fed back on the device
+            sampling = dict(block_size=self._block_size,
+                            temperature=self.temperature, top_k=self.top_k,
+                            top_p=self.top_p)
         if self._counted_step:
-            # `reads`: after the five, where the model counts them.
-            self._pool, self._state, emitted, rngs, counts, *reads = \
-                self.engine.paged_state_step(
-                    self.params, self._pool, self._state, tables, lengths,
-                    *self._fed, tokens, rng_rows, forced, mask, **sampling)
-            reads = reads[0] if reads else None
+            launched = self.engine.paged_state_step(
+                self.params, self._pool, self._state, tables, lengths,
+                *self._fed, tokens, rng_rows, forced, mask, **sampling)
         else:
-            self._pool, emitted, rngs = self.engine.paged_step(
+            launched = self.engine.paged_step(
                 self.params, self._pool, tables, lengths, *self._fed,
                 tokens, rng_rows, forced, mask, **sampling)
-        self._fed = (emitted, rngs)
-        # Asked for now, so that the copies to the host follow the
-        # program with no word from the host in between: one wait
-        # under `serving/step_sync` in place of one a result.
-        for result in (emitted, counts, reads):
-            if hasattr(result, "copy_to_host_async"):  # a device array
-                result.copy_to_host_async()
-        # After the call: the engine then knows which implementation
-        # the step was compiled with.
-        chunk = getattr(self.engine, "paged_attention_chunk", None)
-        self._count_step(budget, chunk and chunk(self._block_size),
-                         counted_by_model=reads is not None)
-        stepped = []
-        prefill_tokens = 0
-        for slot in budget:
-            state = self._slots[slot]
-            # Every stepped slot consumes one token (a replayed prompt
-            # token or its fed-back emission) and writes its K/V at the
-            # old length.
-            self._lengths[slot] += 1
-            state.kv_len += 1
-            state.refeed = False
-            if state.pending:
-                state.pending.popleft()
-                state.prompt_filled += 1
-                prefill_tokens += 1
-            sampled = bool(mask[slot])
-            state.launched += sampled
-            stepped.append((slot, state, sampled))
-        return _Flight(emitted, counts, reads, stepped, prefill_tokens)
+        with telemetry.span("serving/launch_account"):
+            counts = reads = None
+            if self._counted_step:
+                # `reads`: after the five, where the model counts them.
+                self._pool, self._state, emitted, rngs, counts, *reads = \
+                    launched
+                reads = reads[0] if reads else None
+            else:
+                self._pool, emitted, rngs = launched
+            self._fed = (emitted, rngs)
+            # Asked for now, so that the copies to the host follow the
+            # program with no word from the host in between: one wait
+            # under `serving/step_sync` in place of one a result.
+            for result in (emitted, counts, reads):
+                if hasattr(result, "copy_to_host_async"):  # a device array
+                    result.copy_to_host_async()
+            # After the call: the engine then knows which implementation
+            # the step was compiled with.
+            chunk = getattr(self.engine, "paged_attention_chunk", None)
+            self._count_step(budget, chunk and chunk(self._block_size),
+                             counted_by_model=reads is not None)
+            stepped = []
+            prefill_tokens = 0
+            for slot in budget:
+                state = self._slots[slot]
+                # Every stepped slot consumes one token (a replayed prompt
+                # token or its fed-back emission) and writes its K/V at the
+                # old length.
+                self._lengths[slot] += 1
+                state.kv_len += 1
+                state.refeed = False
+                if state.pending:
+                    state.pending.popleft()
+                    state.prompt_filled += 1
+                    prefill_tokens += 1
+                sampled = bool(mask[slot])
+                state.launched += sampled
+                stepped.append((slot, state, sampled))
+            return _Flight(emitted, counts, reads, stepped, prefill_tokens,
+                           self._steps + 1, self._take_ahead())
 
     def _land(self, flight: Optional[_Flight], retired: List) -> Tuple:
         """Read a launched step (the one host sync) and hand its tokens
@@ -1619,7 +1715,7 @@ class SlotScheduler:
         cache reads are taken here, one step after the launch that knew
         the rest. -> its (sync, emit) spans, empty where nothing was in
         flight."""
-        emitted = counts = reads = None
+        emitted = counts = reads = paced = None
         with telemetry.span("serving/step_sync") as sync_span:
             if flight is not None:
                 # Every slot's token in one transfer; the counts are ready
@@ -1629,15 +1725,22 @@ class SlotScheduler:
                     counts = np.asarray(flight.counts)
                 if flight.reads is not None:
                     reads = np.asarray(flight.reads)
+                # Who set this tick's pace, said on the span itself (the
+                # one clock reading the account takes of its own).
+                paced = "device" if spans.now() - sync_span.start \
+                    > SYNC_READY_S else "host"
+                sync_span.args.update(
+                    step=flight.step, ahead_programs=flight.ahead[0],
+                    ahead_prefill_tokens=flight.ahead[1], paced=paced)
                 # The device's buffers go here, under this span.
                 flight.emitted = flight.counts = flight.reads = None
         with telemetry.span("serving/step_emit") as emit_span:
             was_retired = len(retired)
             decode_tokens = dropped = 0
             if flight is not None:
+                self._note_read(flight, sync_span, paced == "device")
                 now = time.monotonic()
-                gaps = self._registry.histogram(
-                    "serving/inter_token_latency_ms")
+                gaps = self._token_gaps
                 for slot, state, sampled in flight.stepped:
                     if self._slots[slot] is not state:
                         # Retired since the launch (a deadline, or its eos
@@ -1655,8 +1758,38 @@ class SlotScheduler:
             emit_span.args.update(
                 tokens=decode_tokens, dropped=dropped,
                 retired=len(retired) - was_retired,
+                step=flight.step if flight is not None else None,
             )
         return sync_span, emit_span
+
+    def _note_read(self, flight: _Flight, sync_span, device_paced: bool
+                   ) -> None:
+        """One read's place in the tick's account of itself, from the sync
+        span's own start and duration. From the end of one read that
+        waited for the device to the end of the next that did is what the
+        device ran in between: one model step (`clean_*`: the step's
+        device time with no profiler). Where programs were queued ahead of
+        the step read, the time since the read before, whoever paced that
+        one, is one step and what the queued work added to this tick
+        (`ahead_*`: the tick that admits is often the host's, and the one
+        after it waits behind the prefill). A read that found its result
+        ready says nothing of the device; a settle breaks the chain."""
+        seconds = sync_span.duration
+        self._steps_read += 1
+        self._sync_wait_seconds += seconds
+        self._tick_sync_s += seconds
+        end = sync_span.start + seconds
+        if not device_paced:
+            self._steps_host_paced += 1
+        elif self._read_end is not None:
+            if flight.ahead[0]:
+                self._ahead_intervals += 1
+                self._ahead_interval_seconds += end - self._read_end
+                self._ahead_prefill_tokens += flight.ahead[1]
+            elif self._read_waited:
+                self._clean_intervals += 1
+                self._clean_interval_seconds += end - self._read_end
+        self._read_end, self._read_waited = end, device_paced
 
     def _emit(self, slot: int, state: _Slot, token: int, now: float, gaps,
               retired: List) -> None:
@@ -1693,6 +1826,7 @@ class SlotScheduler:
         self._settles[reason] = self._settles.get(reason, 0) + 1
         parts = self._land(
             flight, self._carried_retired if retired is None else retired)
+        self._read_end = None  # the next step starts on an idle device
         rows = np.asarray(self._fed[1])
         for slot, state, sampled in flight.stepped:
             if sampled and self._slots[slot] is state:
@@ -1812,10 +1946,17 @@ class SlotScheduler:
                 self._kv_read_token_steps += -(-(kv_len + 1) // chunk) * chunk
 
     def _note_step_seconds(self, seconds: float, tick: int) -> None:
-        """Single model steps of many times the usual length decide
-        whole runs (PERF.md): count them, and keep the longest with its
-        parts, which say whether the host was stuck before the dispatch,
-        the device slow under the sync, or the host stuck after it."""
+        """Single ticks of many times the usual length decide whole runs
+        (PERF.md): count them, and keep the longest with its parts, which
+        say whether the host was stuck before the dispatch, the device
+        slow under the sync, or the host stuck after it. A tick whose read
+        had programs queued ahead of it (an admission's prefill) is long
+        by them and is no stall: it is neither judged nor remembered, and
+        the `ahead_*` counters hold its time."""
+        parts = self._step_parts
+        read = parts[1].args if len(parts) > 1 else {}
+        if read.get("ahead_programs"):
+            return
         history = self._step_seconds
         if len(history) >= SLOW_STEP_MIN_HISTORY and \
                 seconds > SLOW_STEP_FACTOR * statistics.median(history):
@@ -1824,13 +1965,14 @@ class SlotScheduler:
             if self._slowest_step is None or \
                     seconds * 1e3 > self._slowest_step["ms"]:
                 # (launch, sync, emit); a tick that had nothing to launch
-                # and nothing to read has the first alone.
-                parts = [part.duration * 1e3 for part in self._step_parts]
-                parts += [0.0] * (3 - len(parts))
+                # and nothing to read has the first alone. `step` and
+                # `paced` are of the step this tick read.
+                ms = [part.duration * 1e3 for part in parts]
+                ms += [0.0] * (3 - len(ms))
                 self._slowest_step = {
                     "tick": tick, "ms": seconds * 1e3,
-                    "launch_ms": parts[0], "sync_ms": parts[1],
-                    "emit_ms": parts[2],
+                    "launch_ms": ms[0], "sync_ms": ms[1], "emit_ms": ms[2],
+                    "step": read.get("step"), "paced": read.get("paced"),
                 }
         history.append(seconds)
 
@@ -1852,6 +1994,13 @@ class SlotScheduler:
         ring.
         """
         with telemetry.span("serving/step_launch") as launch_span:
+            # Serial: launched and read in one tick, so the spans carry the
+            # step's number and `paced="serial"` and feed none of the
+            # read-to-read counters; what was dispatched since the step
+            # before still runs ahead of this one.
+            self._steps += 1
+            ahead = self._take_ahead()
+            launch_span.args.update(step=self._steps)
             self._count_step(active)
             width = self._window_width
             tokens = np.full((self.max_slots, width), -1, np.int32)
@@ -1904,6 +2053,9 @@ class SlotScheduler:
             counts = np.asarray(counts)
             self._rngs = np.array(rngs)
             del rngs  # the device buffer goes under this span (see _step)
+            sync_span.args.update(
+                step=self._steps, ahead_programs=ahead[0],
+                ahead_prefill_tokens=ahead[1], paced="serial")
         with telemetry.span("serving/step_emit") as emit_span:
             self._step_parts = (launch_span, sync_span, emit_span)
             was_retired = len(retired)
@@ -1970,7 +2122,8 @@ class SlotScheduler:
                 )
             self._account_tokens(prefill_tokens, decode_tokens)
             emit_span.args.update(
-                tokens=decode_tokens, retired=len(retired) - was_retired
+                tokens=decode_tokens, retired=len(retired) - was_retired,
+                step=self._steps,
             )
         return accepts
 
@@ -2056,8 +2209,9 @@ class SlotScheduler:
                 self._fail_inflight(FINISH_ERROR)
                 continue
             if not worked:
-                with telemetry.span("serving/idle_wait"):
+                with telemetry.span("serving/idle_wait") as idle_span:
                     self._work.wait(IDLE_POLL_S)
+                self._idle_wait_seconds += idle_span.duration
                 self._work.clear()
 
     def _fail_inflight(self, reason: str) -> None:
@@ -2149,6 +2303,25 @@ class SlotScheduler:
             "steps": self._steps,
             "steps_ahead": self._steps_ahead,
             "pipeline_settles": dict(self._settles),
+            # The tick's account of itself (docs/Serving.md "Where a
+            # tick's time goes"): reads, and those that found their
+            # result ready; the scheduler thread's time in three parts;
+            # read end to read end, with nothing and with something
+            # queued ahead of the step read.
+            "steps_read": self._steps_read,
+            "steps_host_paced": self._steps_host_paced,
+            "host_seconds": round(self._host_seconds, 6),
+            "sync_wait_seconds": round(self._sync_wait_seconds, 6),
+            "idle_wait_seconds": round(self._idle_wait_seconds, 6),
+            "clean_intervals": self._clean_intervals,
+            "clean_interval_seconds": round(
+                self._clean_interval_seconds, 6),
+            "ahead_intervals": self._ahead_intervals,
+            "ahead_interval_seconds": round(
+                self._ahead_interval_seconds, 6),
+            "ahead_prefill_tokens": self._ahead_prefill_tokens,
+            # Ticks over twice the median, of those whose read had
+            # nothing queued ahead.
             "slow_steps": self._slow_steps,
             "slow_step_seconds": round(self._slow_step_seconds, 6),
             "slowest_step": self._slowest_step,

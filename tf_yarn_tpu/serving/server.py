@@ -245,6 +245,9 @@ def _make_handler(scheduler: SlotScheduler, slo_evaluator=None,
                     **scheduler.stats(),
                     "device": device_report(),
                     "compile_cache": compile_cache.stats(),
+                    # What the span rings dropped since the process
+                    # began: a reader of the spans knows what it lacks.
+                    "spans_evicted": telemetry.get_tracer().evicted_total(),
                     "signals": telemetry.signals_block(
                         prefixes=("serving/", "slo/", "telemetry/"),
                     ),

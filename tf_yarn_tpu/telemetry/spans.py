@@ -5,9 +5,11 @@ profiler capture, training._ProfileWindow): every host-side phase of a
 run — input wait, step dispatch, checkpoint save, inference pipeline
 stages — is wrapped in a `span(...)` context manager. Spans are
 `perf_counter`-based (monotonic — wall-clock NTP steps corrupted the
-old `time.time()` timers), nest per thread, and land in a bounded
-in-memory ring buffer so tracing is always on and can never grow a
-long run's memory.
+old `time.time()` timers), nest per thread, and land in bounded
+in-memory rings, one a span name, so tracing is always on, can never
+grow a long run's memory, and a name written every few milliseconds
+(the serving tick's) never evicts one written once (a compile, the
+restore). What a ring drops is counted (`Tracer.evicted`).
 
 Sinks/exports:
 
@@ -33,6 +35,7 @@ import collections
 import itertools
 import json
 import logging
+import operator
 import os
 import re
 import threading
@@ -44,10 +47,22 @@ _logger = logging.getLogger(__name__)
 TRACE_ENV = "TPU_YARN_TRACE"
 TRACE_JSONL_ENV = "TPU_YARN_TRACE_JSONL"
 TRACE_BUFFER_ENV = "TPU_YARN_TRACE_BUFFER"
-DEFAULT_CAPACITY = 100_000
+# Spans kept of ONE name (what `TPU_YARN_TRACE_BUFFER` overrides). The
+# serving tick writes each of its names at most once a tick, and the
+# benchmark reads a 51 s window after a 16 s lead-in and a warm-up: at a
+# 3 ms tick that is 22,400 spans of a name, held here with room (164 s
+# of a 5 ms tick, 98 s of a 3 ms one).
+DEFAULT_CAPACITY = 32_768
+# Names with a ring of their own; the names after them share one ring
+# under `OVERFLOW`, so memory is bounded by (MAX_NAMES + 1) rings
+# whatever a caller formats into a name. A serving task has 25 names, 13
+# of them written once a tick.
+MAX_NAMES = 128
+OVERFLOW = "*"
 
 _clock = time.perf_counter  # monotonic; patchable in tests
 _SPAN_IDS = itertools.count(1)  # next() is atomic under the GIL
+_SPAN_ORDER = operator.attrgetter("order")
 
 # Depth and thread of a span inserted by `Tracer.record`: it sat on no
 # thread's stack, so "the deepest span over an instant" never picks it
@@ -68,7 +83,7 @@ class Span:
 
     __slots__ = ("name", "category", "args", "start", "duration",
                  "thread_id", "thread_name", "depth", "parent", "id",
-                 "parent_id")
+                 "parent_id", "order")
 
     def __init__(self, name: str, category: str, args: Dict[str, Any],
                  depth: int, parent: Optional["Span"]) -> None:
@@ -82,6 +97,7 @@ class Span:
         self.parent = parent.name if parent is not None else None
         self.parent_id = parent.id if parent is not None else None
         self.id = next(_SPAN_IDS)
+        self.order = 0  # its place among the completed spans, once it is one
         thread = threading.current_thread()
         self.thread_id = thread.ident or 0
         self.thread_name = thread.name
@@ -129,7 +145,9 @@ class _SpanContext:
 
 class Tracer:
     """Ring-buffered span recorder; thread-safe, one per process by
-    default (module-level :func:`get_tracer`)."""
+    default (module-level :func:`get_tracer`). `capacity` is the number
+    of spans kept of one name: each of the first `MAX_NAMES` names has a
+    ring of its own and no name evicts another's."""
 
     def __init__(self, capacity: Optional[int] = None) -> None:
         if capacity is None:
@@ -139,8 +157,11 @@ class Tracer:
             except ValueError:
                 capacity = DEFAULT_CAPACITY
         self.capacity = max(1, capacity)
-        self._buffer: "collections.deque[Span]" = collections.deque(
-            maxlen=self.capacity)
+        self._rings: Dict[str, "collections.deque[Span]"] = {}
+        # Spans dropped, by ring (a name, or `OVERFLOW`): never reset,
+        # so a reader of two snapshots sees what went between them.
+        self.evicted: Dict[str, int] = {}
+        self._completed = 0
         self._lock = threading.Lock()
         self._local = threading.local()
         self._sinks: List[Callable[[Span], None]] = []
@@ -190,7 +211,20 @@ class Tracer:
 
     def _append(self, span: Span) -> None:
         with self._lock:
-            self._buffer.append(span)
+            key = span.name
+            ring = self._rings.get(key)
+            if ring is None:
+                if len(self._rings) >= MAX_NAMES:
+                    key = OVERFLOW
+                    ring = self._rings.get(key)
+                if ring is None:
+                    ring = self._rings[key] = collections.deque(
+                        maxlen=self.capacity)
+                    self.evicted.setdefault(key, 0)
+            if len(ring) == self.capacity:
+                self.evicted[key] += 1  # the append drops the oldest
+            self._completed = span.order = self._completed + 1
+            ring.append(span)
             sinks = list(self._sinks)
         for sink in sinks:
             try:
@@ -201,12 +235,22 @@ class Tracer:
     # -- inspection --------------------------------------------------------
 
     def records(self) -> List[Span]:
+        """Every span kept, in the order they were completed (the one
+        ring's order, as ever: a parent after its children)."""
         with self._lock:
-            return list(self._buffer)
+            kept = [span for ring in self._rings.values() for span in ring]
+        kept.sort(key=_SPAN_ORDER)  # outside the lock: the writers go on
+        return kept
+
+    def evicted_total(self) -> int:
+        with self._lock:
+            return sum(self.evicted.values())
 
     def clear(self) -> None:
+        """Drop the spans kept (the eviction counts are a history and
+        stay)."""
         with self._lock:
-            self._buffer.clear()
+            self._rings.clear()
 
     # -- sinks -------------------------------------------------------------
 
@@ -244,7 +288,7 @@ class Tracer:
     # -- Chrome trace_event export -----------------------------------------
 
     def chrome_events(self) -> List[Dict[str, Any]]:
-        """The ring buffer as Chrome ``trace_event`` dicts ("X" complete
+        """The spans kept as Chrome ``trace_event`` dicts ("X" complete
         events + "M" thread-name metadata)."""
         pid = os.getpid()
         events: List[Dict[str, Any]] = []
@@ -309,7 +353,7 @@ def _safe_task(task: Any) -> str:
 def export_trace(task: Any = "local",
                  tracer: Optional[Tracer] = None) -> Optional[str]:
     """Write ``<TPU_YARN_TRACE>/trace_<task>.json`` (Chrome trace_event
-    JSON) from the ring buffer; no-op (returns None) when the env var is
+    JSON) from the spans kept; no-op (returns None) when the env var is
     unset. Idempotent — later calls overwrite with the fuller buffer."""
     directory = trace_dir()
     if not directory:

@@ -25,7 +25,9 @@ Nothing runs, so nothing here is a result or a time of the device. Programs:
             (latent and index rows off the pool, rings held once a slot)
     longcat the benchmark's LongCat-Flash share at its own sizes
             (cellbench/configs/longcat_flash_serve_1chip.json): prefill at
-            the 512 and 1024 buckets, pack, and the 64-slot paged state step
+            the 512, 1024 and 2048 buckets (2048: what an admission of a
+            1026-1536 token prompt runs), pack, and the 64-slot paged state
+            step
             (every leaf paged, the latent rows read by the paged kernel)
 
 Each line: how many Mosaic kernels (`tpu_custom_call`) and collectives the
@@ -312,7 +314,8 @@ def main(argv) -> int:
                                        "gather", 4),
         "latent": lambda: compile_latent(one),
         "longcat": lambda: compile_latent(
-            one, "longcat", "longcat_flash_serve_1chip", (512, 1024), True),
+            one, "longcat", "longcat_flash_serve_1chip", (512, 1024, 2048),
+            True),
     }
     for name in argv or list(programs):
         programs[name]()

@@ -21,17 +21,27 @@ from tf_yarn_tpu.serving import SlotScheduler
 class FakePagedEngine:
     """The exact step, admission and the swap programs."""
 
-    def __init__(self, buckets=(4, 8), max_seq_len=32):
+    def __init__(self, buckets=(4, 8), max_seq_len=32, ceiling=False):
         self.prompt_buckets = tuple(sorted(buckets))
         self.max_seq_len = max_seq_len
+        self.ceiling = ceiling
         self.calls = []
 
-    def slot_prefill_len(self, prompt_len):
+    def ceiling_prefill(self, params):
+        return self.ceiling
+
+    def slot_prefill_len(self, prompt_len, ceiling=False):
+        kept = prompt_len - 1
+        if ceiling:
+            above = [b for b in self.prompt_buckets
+                     if kept <= b <= self.max_seq_len]
+            if above and kept > 0:
+                return above[0], kept
         best = 0
         for bucket in self.prompt_buckets:
-            if bucket <= prompt_len - 1:
+            if bucket <= kept:
                 best = bucket
-        return best
+        return best, best
 
     def make_paged_pool(self, params, num_blocks, block_size):
         self.calls.append(("make_pool", num_blocks, block_size))
@@ -213,6 +223,27 @@ def fake_scheduler(engine, max_slots=2, **kwargs):
 # --------------------------------------------------------------------------
 # A drive shared by the serving tests of every model
 # --------------------------------------------------------------------------
+
+def admit_prefill(engine, params, pool, prompt, blocks, block_size,
+                  ceiling, pad=0):
+    """The device half of `SlotScheduler._admit`'s blocking prefill, on
+    the engine itself: the bucket and the kept rows by the engine's rule
+    (`ceiling`: what `engine.ceiling_prefill(params)` said), the prompt
+    padded to the bucket with `pad`, the kept rows' blocks from `blocks`
+    and every block past them aimed at the trash block. Returns (pool,
+    the prefill's row cache or None, bucket, kept)."""
+    bucket, kept = engine.slot_prefill_len(len(prompt), ceiling)
+    if not bucket:
+        return pool, None, 0, 0
+    tokens = np.full((1, bucket), pad, np.int32)
+    tokens[0, :kept] = np.asarray(prompt[:kept])
+    row, _logits = engine.prefill(params, tokens)
+    owned = -(-kept // block_size)
+    ids = np.zeros((-(-bucket // block_size),), np.int32)
+    ids[:owned] = np.asarray(blocks[:owned])
+    pool = engine.pack_prefill(pool, ids, row, bucket, block_size)
+    return pool, row, bucket, kept
+
 
 def mixed_trace_streams(scheduler, settle_every_tick, eos_token=None,
                         vocab=256):

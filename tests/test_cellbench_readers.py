@@ -303,3 +303,66 @@ def test_span_coverage_says_how_much_of_the_window_the_rings_still_hold():
     assert span_coverage.read(late, "serving/tick") == 100.0
     assert span_coverage.read(run, "no/such_span") is None
     assert span_coverage.read(_as_the_parent(run), "serving/tick") is None
+
+
+# --------------------------------------------------------------------------
+# PR 41: which prefill bucket an admission takes and how much of it is kept.
+PREFILL_RULE = {
+    # quantity: (better, numerator, denominator, value on the run below)
+    "prefill_ceiling_share": (
+        "higher", ["prefills_ceiling"],
+        ["prefills_ceiling", "prefills_floor"], 100.0),
+    "prefill_pad_share": (
+        "lower", ["prefill_pad_tokens"],
+        ["prefilled_tokens", "prefill_pad_tokens"], 28.0),
+}
+
+
+@pytest.mark.parametrize("quantity", sorted(PREFILL_RULE))
+def test_a_quantity_of_the_prefill_rule_is_data_and_silent_on_the_parent(
+        quantity):
+    """Two data files for an accepted reader, two entries each at the end
+    of `per_layer` (the steady cell's moves `itl_p90_ms`, the four closed
+    loops' `serve_tokens_per_s`, as `sched_replay_share.*`, which they
+    should move). The reader gives how often an admission took the bucket
+    above its prompt and what share of the rows it ran were pad; 0 % of
+    admissions for a model on the floor rule; and nothing (no error) for a
+    program whose `/stats` has no such counters, as the parent's."""
+    from cellbench.readers import stats_share
+    from cellbench.tests import test_readers_tracing as theirs
+
+    better, numerator, denominator, value = PREFILL_RULE[quantity]
+    bench = _bench()
+    steady, backlog = _side_by_side(
+        bench, [quantity + ".steady", quantity + ".backlog"])
+    replay = {m["name"]: m for m in bench["per_layer"]
+              if m["name"].startswith("sched_replay_share.")}
+    for entry, suffix in ((steady, ".steady"), (backlog, ".backlog")):
+        assert entry == dict(
+            replay["sched_replay_share" + suffix], name=quantity + suffix,
+            better=better)
+        assert theirs.run_lib.metric_file(entry["name"]) == {
+            "reader": "stats_share",
+            "args": {"numerator": numerator, "denominator": denominator}}
+    assert len(backlog["workloads"]) == 4
+    # The four stand last, the share of admissions before the share of pad.
+    assert [m["name"] for m in bench["per_layer"][-4:]] == [
+        name + suffix for name in sorted(PREFILL_RULE)
+        for suffix in (".steady", ".backlog")]
+    args = theirs.run_lib.metric_file(steady["name"])["args"]
+    opened = {"prefills_ceiling": 10, "prefills_floor": 0,
+              "prefilled_tokens": 3000, "prefill_pad_tokens": 1000}
+    run = {"stats_open": opened,
+           "stats_close": {"prefills_ceiling": 460, "prefills_floor": 0,
+                           "prefilled_tokens": 165000,
+                           "prefill_pad_tokens": 64000}}
+    assert stats_share.read(run, **args) == pytest.approx(value)
+    floor = {"stats_open": dict(opened, prefills_ceiling=0, prefills_floor=10,
+                                prefill_pad_tokens=0),
+             "stats_close": {"prefills_ceiling": 0, "prefills_floor": 460,
+                             "prefilled_tokens": 165000,
+                             "prefill_pad_tokens": 0}}
+    assert stats_share.read(floor, **args) == 0.0
+    parent = {"stats_open": {"prefilled_tokens": 3000},
+              "stats_close": {"prefilled_tokens": 165000}}
+    assert stats_share.read(parent, **args) is None

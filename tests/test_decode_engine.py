@@ -8,6 +8,8 @@ approximations), repeated same-bucket calls must hit the compile cache
 contain zero per-token host syncs.
 """
 
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -29,6 +31,7 @@ from tf_yarn_tpu.models.decode_engine import (
     paged_pool_avals,
 )
 from tf_yarn_tpu.models.generate import generate, generate_legacy
+from tests.fakes import admit_prefill
 
 
 def _model_and_params(seed=0, **cfg_overrides):
@@ -315,10 +318,34 @@ def test_pack_prefill_touches_only_its_own_blocks():
     assert engine.stats["pack_compiles"] == 1  # one bucket, ids traced
 
 
+@pytest.mark.parametrize("bucket", [8, 32])
+def test_pack_program_is_one_scatter_a_leaf_whatever_the_bucket(bucket):
+    """The pack program does not grow with its bucket: one scatter of whole
+    blocks a paged leaf (an update a block, unrolled, took 33 s to trace
+    and compile at 2048 rows of 16 leaves, behind the first long prompt)."""
+    from tf_yarn_tpu.models.decode_engine import build_pack_prefill_fn
+
+    model, params = _model_and_params()
+    engine = _engine(model, batch_buckets=(1,))
+    bs = 4
+    pool = engine.make_paged_pool(params, 9, bs)
+    row = jax.eval_shape(
+        build_prefill_fn(model), params,
+        jax.ShapeDtypeStruct((1, bucket), jnp.int32))[0]
+    jaxpr = jax.make_jaxpr(build_pack_prefill_fn(model, bs, bucket))(
+        pool, jnp.zeros((bucket // bs,), jnp.int32), row)
+    names = [eqn.primitive.name for eqn in jaxpr.jaxpr.eqns]
+    assert names.count("scatter") == len(jax.tree_util.tree_leaves(pool))
+    assert "dynamic_update_slice" not in names
+    assert len(names) < 12 * names.count("scatter")
+
+
 def _drive_paged_slots(model, engine, params, prompts, seeds, max_new,
-                       sampling, block_size, fed=False):
+                       sampling, block_size, fed=False, pad=0):
     """Drive make_paged_pool/pack_prefill/paged_step by hand (the
-    scheduler's device contract) and return each slot's emitted stream.
+    scheduler's device contract: the prefill bucket and the rows kept of
+    it by the rule the engine reads off the model, the prompt padded to
+    the bucket with `pad`) and return each slot's emitted stream.
     Physical blocks are handed out in an interleaved order on purpose —
     correctness must come from the block TABLE, not from contiguity.
     `fed`: a decoding slot's token and rng row stay on the device from
@@ -337,14 +364,11 @@ def _drive_paged_slots(model, engine, params, prompts, seeds, max_new,
     lengths = np.zeros((slots,), np.int32)
     rngs = np.zeros((slots, 2), np.uint32)
     pending, last, emitted_all = [], np.zeros((slots,), np.int32), []
+    ceiling = engine.ceiling_prefill(params)
     for slot, (prompt, seed) in enumerate(zip(prompts, seeds)):
-        prefill_len = engine.slot_prefill_len(len(prompt))
-        if prefill_len > 0:
-            row, _ = engine.prefill(params, prompt[None, :prefill_len])
-            n_pack = -(-prefill_len // block_size)
-            pool = engine.pack_prefill(
-                pool, tables[slot, :n_pack], row, prefill_len, block_size
-            )
+        pool, _row, _bucket, prefill_len = admit_prefill(
+            engine, params, pool, prompt, tables[slot], block_size,
+            ceiling, pad)
         lengths[slot] = prefill_len
         pending.append(list(prompt[prefill_len:]))
         rngs[slot] = np.asarray(jax.random.PRNGKey(seed))
@@ -410,7 +434,7 @@ def test_paged_step_grid_matches_legacy_per_request(fed):
     prompts = [
         jnp.asarray(rng_np.randint(0, 256, (5,)), jnp.int32),  # prefill 4
         jnp.asarray(rng_np.randint(0, 256, (9,)), jnp.int32),  # prefill 8
-        jnp.asarray(rng_np.randint(0, 256, (3,)), jnp.int32),  # replay all
+        jnp.asarray(rng_np.randint(0, 256, (3,)), jnp.int32),  # 2 of 4 kept
     ]
     seeds = [0, 7, 3]
     max_new = 6
@@ -458,6 +482,151 @@ def test_paged_step_int8_matches_int8_legacy():
         assert emitted_all[slot] == np.asarray(
             ref
         )[0, len(prompt):].tolist(), f"slot {slot}"
+
+
+@pytest.mark.parametrize("prompt_len,ceiling,want", [
+    (1, True, (0, 0)),      # the one token goes through the step
+    (2, True, (8, 1)),      # under the least bucket: padded up to it
+    (8, True, (8, 7)),      # one under a bucket
+    (9, True, (8, 8)),      # b + 1 lands on b under both rules
+    (9, False, (8, 8)),
+    (10, True, (16, 9)),    # one over
+    (10, False, (8, 8)),
+    (33, True, (32, 32)),
+    (34, True, (32, 32)),   # the bucket above (128) is past the context
+    (60, True, (32, 32)),
+    (8, False, (0, 0)),     # the floor rule finds nothing under 7
+])
+def test_slot_prefill_len_takes_the_bucket_above_and_keeps_the_prompt(
+        prompt_len, ceiling, want):
+    model, _params = _model_and_params()  # context 64
+    engine = _engine(model, prompt_buckets=(8, 16, 32, 128))
+    assert engine.slot_prefill_len(prompt_len, ceiling) == want
+    if not ceiling:
+        assert engine.slot_prefill_len(prompt_len) == want  # the default
+
+
+def test_only_a_model_whose_prompt_rows_are_causal_takes_the_ceiling_rule():
+    """The engine reads the rule off the model: a dense causal transformer
+    says its prompt rows do not depend on later tokens; one with the
+    capacity-bounded `MoEMlp` (capacity is counted over the tokens of the
+    call, pads included) says they do; a model that says nothing keeps the
+    floor rule."""
+    model, params = _model_and_params()
+    assert _engine(model).ceiling_prefill(params) is True
+    moe, moe_params = _model_and_params(moe_experts=4)
+    assert moe.prompt_rows_causal is False
+    assert _engine(moe).ceiling_prefill(moe_params) is False
+
+    class Silent:
+        config = model.config
+
+    assert _engine(Silent()).ceiling_prefill(params) is False
+
+
+_CEILING_BLOCK = 4
+
+
+@functools.lru_cache(maxsize=None)
+def _ceiling_rig():
+    """One model, engine and logits step for the ceiling-rule tests below:
+    blocks of 4 under buckets of 4, 8 and 16, so that a padded bucket has
+    blocks past the kept rows."""
+    model, params = _model_and_params()
+    engine = _engine(model, batch_buckets=(1, 2), prompt_buckets=(4, 8, 16))
+    step = jax.jit(build_paged_step_fn(
+        model, _CEILING_BLOCK, 0.0, None, None, with_logits=True))
+    return model, params, engine, step
+
+
+# At, one under and one over the buckets 8 and 16 (the rows kept are the
+# prompt's less one), and one under the least.
+@pytest.mark.parametrize("prompt_len", [3, 8, 9, 10, 16, 17])
+def test_the_pad_of_a_ceiling_prefill_is_invisible(prompt_len):
+    """Two admissions of one prompt through the bucket above it, padded
+    with different tokens, leave the kept rows bitwise equal (one program;
+    no kept row sees the pad under the causal mask) and close to an
+    exact-length prefill's; the blocks past the kept rows aim at the trash
+    block, so no block but the slot's own and block 0 changes; and the
+    step that takes the prompt's last token gives the logits of one full
+    forward: the first generated token's."""
+    model, params, engine, step = _ceiling_rig()
+    bs, num_blocks = _CEILING_BLOCK, 12
+    prompt = np.random.RandomState(prompt_len).randint(0, 256, (prompt_len,))
+    owned = [7, 3, 9, 5, 2]
+    bucket, kept = engine.slot_prefill_len(prompt_len, True)
+    assert kept == prompt_len - 1 and bucket == min(
+        b for b in (4, 8, 16) if b >= kept)
+    n_owned = -(-kept // bs)
+
+    def fresh_pool():
+        # Other slots' rows everywhere: nothing is zero by luck.
+        rng_np = np.random.RandomState(9)
+        return jax.tree_util.tree_map(
+            lambda leaf: None if leaf is None else jnp.asarray(
+                rng_np.standard_normal(leaf.shape), leaf.dtype),
+            engine.make_paged_pool(params, num_blocks, bs),
+            is_leaf=lambda x: x is None)
+
+    def by_block(pool):
+        # [1, num_blocks, bs, kv_heads, head_dim] -> block axis first
+        return [np.moveaxis(np.asarray(leaf), -4, 0)
+                for leaf in jax.tree_util.tree_leaves(pool)]
+
+    before = by_block(fresh_pool())
+    pools = []
+    for pad in (0, 255):
+        pool, _row, got_bucket, got_kept = admit_prefill(
+            engine, params, fresh_pool(), prompt, owned, bs, True, pad)
+        assert (got_bucket, got_kept) == (bucket, kept)
+        pools.append(pool)
+    exact = engine.prefill(params, prompt[None, :kept])[0]
+    untouched = [b for b in range(1, num_blocks) if b not in owned[:n_owned]]
+    for first, second, was, row in zip(
+            by_block(pools[0]), by_block(pools[1]), before,
+            (leaf for leaf in jax.tree_util.tree_leaves(exact)
+             if leaf.ndim > 1)):
+        np.testing.assert_array_equal(first[untouched], was[untouched])
+        np.testing.assert_array_equal(second[untouched], was[untouched])
+        rows = [np.concatenate([tree[b] for b in owned[:n_owned]], axis=-3)
+                for tree in (first, second)]
+        np.testing.assert_array_equal(
+            rows[0][..., :kept, :, :], rows[1][..., :kept, :, :])
+        want = np.asarray(row)[..., :kept, :, :]
+        np.testing.assert_allclose(
+            rows[0][..., :kept, :, :], want, atol=1e-5 * np.abs(want).max())
+    # The prompt's last token through the step, over the padded admission.
+    tables = np.zeros((1, engine.max_blocks_per_slot(bs)), np.int32)
+    tables[0, :len(owned)] = owned
+    tokens = np.asarray([prompt[-1]], np.int32)
+    *_rest, logits = step(
+        params, pools[1], jnp.asarray(tables), jnp.asarray([kept], jnp.int32),
+        *all_forced(tokens, np.zeros((1, 2), np.uint32)),
+        jnp.ones((1,), bool))
+    want = np.asarray(model.apply(params, jnp.asarray(prompt[None])))[0, -1]
+    np.testing.assert_allclose(
+        np.asarray(logits)[0], want, atol=1e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("prompt_len", [3, 8, 9, 10, 16, 17, 18])
+def test_ceiling_prefill_streams_match_legacy(prompt_len):
+    """A slot admitted through the bucket above its prompt (18: no bucket
+    above, the floor and two replayed tokens), beside one at another
+    length, emits generate_legacy's stream, sampled chain included,
+    whatever the pad holds."""
+    model, params, engine, _step = _ceiling_rig()
+    rng_np = np.random.RandomState(100 + prompt_len)
+    prompts = [jnp.asarray(rng_np.randint(0, 256, (n,)), jnp.int32)
+               for n in (prompt_len, 6)]
+    sampling = dict(temperature=1.0, top_k=8, top_p=0.9)
+    emitted_all = _drive_paged_slots(
+        model, engine, params, prompts, [4, 2], 5, sampling,
+        block_size=_CEILING_BLOCK, fed=True, pad=255)
+    for slot, (prompt, seed) in enumerate(zip(prompts, [4, 2])):
+        ref = generate_legacy(
+            model, params, prompt[None], 5, seed=seed, **sampling)
+        assert emitted_all[slot] == np.asarray(
+            ref)[0, len(prompt):].tolist(), f"slot {slot}"
 
 
 def test_int8_prefill_logits_close_to_fp():

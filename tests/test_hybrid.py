@@ -42,7 +42,7 @@ from tf_yarn_tpu.models.moe import DroplessMoE
 from tf_yarn_tpu.serving.request import SamplingParams
 from tf_yarn_tpu.serving.scheduler import SlotScheduler
 
-from tests.fakes import assert_pipelined_equals_settled
+from tests.fakes import admit_prefill, assert_pipelined_equals_settled
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOLERANCE = 5e-7  # float32 both sides, sums in another order (see above)
@@ -153,14 +153,12 @@ class _Grid:
 
     def admit(self, slot, prompt):
         variables = self.tiny["variables"]
-        prefill = self.engine.slot_prefill_len(len(prompt))
         blocks = 1 + slot * self.per_slot + np.arange(self.per_slot)
-        row = None
-        if prefill:
-            row, _ = self.engine.prefill(
-                variables, np.asarray(prompt[:prefill], np.int32)[None])
-            self.pool = self.engine.pack_prefill(
-                self.pool, blocks[:-(-prefill // BLOCK)], row, prefill, BLOCK)
+        # The rule the engine reads off this model: the floor (the state is
+        # what a prefill left at the END of its bucket).
+        self.pool, row, _bucket, prefill = admit_prefill(
+            self.engine, variables, self.pool, prompt, blocks, BLOCK,
+            self.engine.ceiling_prefill(variables))
         self.state = self.engine.write_slot_state(self.state, slot, row)
         self.tables[slot] = blocks
         self.lengths[slot] = prefill
@@ -317,6 +315,12 @@ def test_scheduler_serves_through_reused_slots(tiny):
         assert _serve(_scheduler(tiny, max_slots=1), [prompt]) == [tokens]
     stats = together.stats()
     assert stats["state_leaves"] == ["conv_state", "ssm_state"]
+    # The state is what a prefill left at the end of its bucket: the floor
+    # rule (8, 16, nothing, 32, 16 prefilled whole, the rest replayed).
+    assert together.engine.ceiling_prefill(tiny["variables"]) is False
+    assert (stats["prefills_ceiling"], stats["prefills_floor"],
+            stats["prefill_pad_tokens"], stats["prefilled_tokens"],
+            stats["prefill_tokens"]) == (0, 4, 0, 72, 1 + 4 + 5 + 1 + 1)
     assert stats["state_resets"] == 5 and stats["prefix_skipped_stateful"] == 5
     assert stats["prefix_cache"]["entries"] == 0
     assert stats["prefix_cache"]["hits"] == 0

@@ -45,6 +45,7 @@ from tf_yarn_tpu.models.moe import DroplessMoE
 from tf_yarn_tpu.models.transformer import PagedContext
 from tf_yarn_tpu.serving.request import SamplingParams
 from tf_yarn_tpu.serving.scheduler import SlotScheduler
+from tests.fakes import admit_prefill
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DATA = os.path.join(ROOT, "cellbench", "tests", "data")
@@ -73,15 +74,25 @@ def tiny():
         (LAYERS, TOP_K, HELD, CONTEXT, WIDTH)
     # One engine and one jitted step for the whole file: every grid and
     # scheduler below would otherwise compile the same programs again.
+    variables = agent.program_variables(model, sizes, SEED)
+    engine = DecodeEngine(model, prompt_buckets=BUCKETS)
     return {
-        "sizes": sizes, "model": model,
-        "variables": agent.program_variables(model, sizes, SEED),
+        "sizes": sizes, "model": model, "variables": variables,
         "weights": weights.make(sizes, SEED),
         "forward": jax.jit(model.apply),
-        "engine": DecodeEngine(model, prompt_buckets=BUCKETS),
+        "engine": engine,
         "step": jax.jit(build_paged_state_step_fn(
             model, BLOCK, 0.0, None, None, with_logits=True)),
+        # The engine's reading of the model (every leaf paged, every row a
+        # function of the tokens before it): True, held below.
+        "ceiling": engine.ceiling_prefill(variables),
     }
+
+
+def _kept(prompt_len):
+    """Rows an admission keeps: all of the prompt but its last token where
+    a bucket (8, 16, 32) lies at or above that, else the largest bucket."""
+    return prompt_len - 1 if prompt_len - 1 <= max(BUCKETS) else max(BUCKETS)
 
 
 def _reference_logits(tiny, tokens, rows, lower=None):
@@ -404,22 +415,21 @@ class _Grid:
         self.rngs = np.zeros((slots, 2), np.uint32)
         self.step = tiny["step"]
 
-    def admit(self, slot, prompt, shared=()):
-        """Prefill into the slot's own blocks; with `shared` (blocks of
-        another slot that hold this prompt's first tokens) nothing is
-        prefilled and the slot starts at their end, as after a prefix hit."""
+    def admit(self, slot, prompt, shared=(), pad=0):
+        """Prefill into the slot's own blocks, as the scheduler admits (the
+        bucket above the prompt, padded with `pad`, all but the last token
+        kept); with `shared` (blocks of another slot that hold this
+        prompt's first tokens) nothing is prefilled and the slot starts at
+        their end, as after a prefix hit."""
         variables = self.tiny["variables"]
         blocks = 1 + slot * self.per_slot + np.arange(self.per_slot)
         if len(shared):
             blocks[:len(shared)] = shared
             prefill = len(shared) * BLOCK
         else:
-            prefill = self.engine.slot_prefill_len(len(prompt))
-            if prefill:
-                row, _ = self.engine.prefill(
-                    variables, np.asarray(prompt[:prefill], np.int32)[None])
-                self.pool = self.engine.pack_prefill(
-                    self.pool, blocks[:-(-prefill // BLOCK)], row, prefill, BLOCK)
+            self.pool, _row, self.bucket, prefill = admit_prefill(
+                self.engine, variables, self.pool, prompt, blocks, BLOCK,
+                self.tiny["ceiling"], pad)
         self.tables[slot] = blocks
         self.lengths[slot] = prefill
         return prefill
@@ -447,33 +457,64 @@ class _Grid:
             self.lengths[slot] += 1
         return logits, counts, dict(zip(longcat.LongcatLM.READS, reads.tolist()))
 
-    def run(self, slot, sequence, prompt_len, shared=()):
+    def run(self, slot, sequence, prompt_len, shared=(), pad=0):
         """Admit `sequence[:prompt_len]`, then feed the rest a token a
         step; logits of every step, for positions prefill .. len - 1."""
-        prefill = self.admit(slot, sequence[:prompt_len], shared)
+        prefill = self.admit(slot, sequence[:prompt_len], shared, pad)
         rows = [self.advance({slot: sequence[t]})[0][slot]
                 for t in range(prefill, len(sequence))]
         return prefill, np.stack(rows)
 
 
 # Prompt lengths on, just over and just under a prefill bucket (8, 16, 32:
-# the prefill takes the largest bucket below the length); 5 and 8 prefill
-# nothing. Each decodes 9 more.
+# the prefill takes the bucket at or above the length less one and keeps
+# that many rows, so the step takes up at the prompt's last token); 41 has
+# no bucket above and prefills 32, replaying 9. Each decodes 9 more.
 @pytest.mark.parametrize("prompt_len", [5, 8, 9, 10, 16, 17, 24, 32, 33, 41])
 def test_prefill_replay_decode_match_reference(tiny, prompt_len):
-    """Bucketed prefill (the expanded path) into the pool, then replay and
-    decode a token a step through the paged step (the absorbed path, read
-    through the table), against ONE full forward of the reference over the
-    same tokens; the same forward in int8 is far from both."""
+    """Bucketed prefill (the expanded path) into the pool, padded past the
+    prompt, then the prompt's last token (where no bucket lies above, its
+    remainder) and the decode a token a step through the paged step (the
+    absorbed path, read through the table), against ONE full forward of the
+    reference over the same tokens: the first row is the first generated
+    token's logits. The same forward in int8 is far from both."""
     sequence = np.random.default_rng(prompt_len).integers(0, 256, prompt_len + 9)
     grid = _Grid(tiny)
-    prefill, got = grid.run(1, sequence, prompt_len)
-    assert prefill == max([b for b in BUCKETS if b < prompt_len], default=0)
+    prefill, got = grid.run(1, sequence, prompt_len, pad=prompt_len)
+    assert prefill == _kept(prompt_len)
+    assert grid.bucket == min(b for b in BUCKETS if b >= prefill)
     rows = np.arange(prefill, len(sequence))
     want = _reference_logits(tiny, sequence, rows)
     np.testing.assert_allclose(got, want, atol=TOLERANCE, rtol=0)
     lower = _reference_logits(tiny, sequence, rows, lower="int8")
     assert np.abs(got - lower).max() > 100 * TOLERANCE
+
+
+# One under, at and one over a bucket (the rows kept are the prompt's less
+# one), and under the least bucket.
+@pytest.mark.parametrize("prompt_len", [4, 16, 17, 18])
+def test_the_pad_of_a_ceiling_prefill_is_invisible(tiny, prompt_len):
+    """Two admissions of one prompt, padded to its bucket with different
+    tokens, leave the kept rows of all four leaves bitwise equal (one
+    program; a latent row is a function of the tokens before it, and a
+    dropless expert takes each token alone); every block but the slot's
+    own and the trash block stays as it was."""
+    assert tiny["ceiling"] is True
+    prompt = np.random.default_rng(prompt_len).integers(0, 256, prompt_len)
+    grids = [_Grid(tiny), _Grid(tiny)]
+    for grid, pad in zip(grids, (0, 255)):
+        assert grid.admit(1, prompt, pad=pad) == prompt_len - 1
+    kept = prompt_len - 1
+    own = grids[0].tables[1, :-(-kept // BLOCK)]
+    others = np.setdiff1d(np.arange(1, 3 * grids[0].per_slot + 1), own)
+    leaves = [[np.asarray(leaf)[0] for leaf in
+               jax.tree_util.tree_leaves(grid.pool)] for grid in grids]
+    assert len(leaves[0]) == 2 * LAYERS
+    for first, second in zip(*leaves):
+        rows = [leaf[own].reshape(-1, WIDTH)[:kept] for leaf in (first, second)]
+        np.testing.assert_array_equal(rows[0], rows[1])
+        assert np.abs(rows[0]).max() > 0
+        assert not first[others].any() and not second[others].any()
 
 
 def test_a_long_sequence_in_a_slot_that_held_a_longer_one(tiny):
@@ -508,19 +549,20 @@ def test_slots_step_together_and_a_reused_slot_starts_clean(tiny):
     # and the zero-compute experts' share are what is left of 8 choices
     assert counts.shape == (LAYERS, 1 + HELD + 1) and (counts[:, 0] == 8).all()
     assert ((counts[:, 1:].sum(axis=1) <= 8) & (counts[:, -1] >= 0)).all()
-    # the last step: slot 0 at 41 + 1 live rows, slot 2 at 17 + 1, in each
+    # the last step: slot 0 at 41 + 1 live rows, slot 2 at 19 + 1, in each
     # of four sublayers; the plain gather reads the whole table
-    assert reads == {"latent_live": 4 * (42 + 18),
+    assert (p1, p2) == (32, 10)
+    assert reads == {"latent_live": 4 * (42 + 20),
                      "latent_read": 4 * 2 * CONTEXT}
     for got, sequence, start in ((got1, first, p1), (got2, second, p2)):
         want = _reference_logits(tiny, sequence[:start + 10],
                                  np.arange(start, start + 10))
         np.testing.assert_allclose(np.stack(got), want, atol=TOLERANCE, rtol=0)
     grid.retire(0)
-    _, reused = grid.run(0, third, 6)        # nothing prefilled
+    kept, reused = grid.run(0, third, 6)     # 5 kept of the bucket of 8
     _, alone = _Grid(tiny).run(0, third, 6)
     np.testing.assert_allclose(reused, alone, atol=1e-6, rtol=0)
-    want = _reference_logits(tiny, third, np.arange(0, len(third)))
+    want = _reference_logits(tiny, third, np.arange(kept, len(third)))
     np.testing.assert_allclose(reused, want, atol=TOLERANCE, rtol=0)
 
 
@@ -578,8 +620,10 @@ def test_a_prefix_hit_gives_the_logits_of_a_miss(tiny):
     sequence = np.concatenate([prompt[:20], tail_b])   # shares 20 tokens
     start, through_hit = grid.run(2, sequence, 20, shared=hit)
     assert start == 16
-    _, missed = _Grid(tiny).run(2, sequence, 20)       # prefills 16 itself
-    np.testing.assert_allclose(through_hit, missed, atol=1e-5, rtol=0)
+    own, missed = _Grid(tiny).run(2, sequence, 20)     # prefills 19 itself
+    assert own == 19
+    np.testing.assert_allclose(through_hit[own - start:], missed,
+                               atol=1e-5, rtol=0)
     want = _reference_logits(tiny, sequence, np.arange(16, len(sequence)))
     np.testing.assert_allclose(through_hit, want, atol=TOLERANCE, rtol=0)
     # the donor's blocks are as they were: it goes on as if alone
@@ -674,6 +718,12 @@ def test_scheduler_serves_through_reused_slots(tiny):
         assert _serve(_scheduler(tiny, max_slots=2), [prompt], 30) == [tokens]
         assert _first_choices(tiny, prompt, tokens).max() <= TOLERANCE
     stats = together.stats()
+    # 9, 5, 33 and 17 took the bucket at or above their length less one and
+    # replayed their last token; 40 has no bucket above 39: the floor, 32
+    assert (stats["prefills_ceiling"], stats["prefills_floor"]) == (4, 1)
+    assert stats["prefill_pad_tokens"] == 8 - 4
+    assert stats["prefilled_tokens"] == 8 + 32 + 4 + 32 + 16
+    assert stats["prefill_tokens"] == 4 * 1 + (40 - 32)
     assert stats["state_leaves"] == [] and stats["state_bytes"] == 0
     assert stats["state_resets"] == 0 and stats["prefix_skipped_stateful"] == 0
     assert stats["block_pool"]["used_blocks"] == \
@@ -701,6 +751,24 @@ def test_scheduler_serves_through_reused_slots(tiny):
     together.close()
 
 
+@pytest.mark.parametrize("prompt_len", [15, 16, 18])
+def test_scheduler_tokens_past_a_padded_prefill_are_the_references(
+        tiny, prompt_len):
+    """One under, at and one over a bucket: the scheduler's greedy tokens
+    behind an admission that padded the prompt to the bucket above are the
+    reference's first choices, the first generated token included."""
+    prompt = np.random.default_rng(40 + prompt_len).integers(0, 256, prompt_len)
+    scheduler = _scheduler(tiny, max_slots=2)
+    tokens, = _serve(scheduler, [prompt], new_tokens=8)
+    assert _first_choices(tiny, prompt, np.asarray(tokens)).max() <= TOLERANCE
+    stats = scheduler.stats()
+    bucket = 16 if prompt_len <= 17 else 32
+    assert (stats["prefills_ceiling"], stats["prefilled_tokens"],
+            stats["prefill_pad_tokens"], stats["prefill_tokens"]) == \
+        (1, prompt_len - 1, bucket - (prompt_len - 1), 1)
+    scheduler.close()
+
+
 def test_the_same_prompt_again_is_served_through_a_prefix_hit(tiny):
     """Nothing of this model is held once a slot, so the prefix cache is on
     for it: the second request starts on the first's blocks, prefills
@@ -713,7 +781,12 @@ def test_the_same_prompt_again_is_served_through_a_prefix_hit(tiny):
     stats = scheduler.stats()
     assert stats["prefix_cache"]["hits"] == 1
     assert stats["prefix_skipped_stateful"] == 0
-    assert stats["prefilled_tokens"] == 16  # the first; the second hit 16
+    # the first kept 23 rows of the bucket of 32 and offered their two
+    # whole blocks; the second hit those 16 tokens and replayed 8
+    assert stats["prefilled_tokens"] == 23
+    assert (stats["prefills_ceiling"], stats["prefills_floor"],
+            stats["prefill_pad_tokens"]) == (1, 0, 32 - 23)
+    assert stats["prefill_tokens"] == 1 + 8
     scheduler.close()
 
 
@@ -744,7 +817,7 @@ def test_suspend_resume_and_block_shipping_move_whole_blocks(tiny):
     donor = _scheduler(tiny, max_slots=2)
     assert _serve(donor, [urgent], 12) == [alone[1]]
     wire = donor.export_hot_prefixes()
-    assert wire["n_blocks"] == 2  # the 16 prefilled tokens' whole blocks
+    assert wire["n_blocks"] == 2  # the 19 prefilled tokens' whole blocks
     donor.close()
     other = _scheduler(tiny, max_slots=2)
     assert other.import_prefixes(wire)["imported_blocks"] == wire["n_blocks"]

@@ -361,6 +361,83 @@ def test_paged_prefix_hit_skips_prefill_and_shares_blocks():
     ).value >= 1
 
 
+class _LoudPadEngine(FakePagedEngine):
+    """The scheduler pads a prefill with token 0, which the fake's sums
+    would not show: a pad row holds 1000 here, so a step that read one
+    would emit another stream."""
+
+    def prefill(self, params, prompt):
+        row, logits = super().prefill(params, prompt)
+        return np.where(row == 0, 1000, row), logits
+
+
+# Buckets 4 and 16 over blocks of 4: at, one under and one over each (the
+# rows kept are the prompt's less one); 18 and 20 have no bucket above.
+@pytest.mark.parametrize("prompt_len", [2, 4, 5, 6, 9, 16, 17, 18, 20])
+def test_ceiling_admission_keeps_the_true_length_and_trashes_the_pad(
+        prompt_len):
+    """An engine that says its model may take the ceiling rule: the
+    admission runs the bucket above the prompt, keeps all of the prompt
+    but its last token, aims every block past the kept rows at the trash
+    block, replays one token, and serves the stream the floor rule
+    serves, to a neighbour in the next slot too; the counters say so."""
+    prompt = list(range(1, prompt_len + 1))
+    neighbour_prompt = [9, 8, 7, 6, 5]
+    streams = {}
+    for name, engine in (
+            ("floor", FakePagedEngine(buckets=(4, 16))),
+            ("ceiling", _LoudPadEngine(buckets=(4, 16), ceiling=True))):
+        scheduler = fake_scheduler(engine, max_slots=2)
+        neighbour = scheduler.submit(
+            neighbour_prompt, SamplingParams(max_new_tokens=10))
+        scheduler.tick()
+        response = scheduler.submit(prompt, SamplingParams(max_new_tokens=3))
+        scheduler.tick()
+        own = list(scheduler._slots[1].blocks)
+        _drive(scheduler, [neighbour, response])
+        streams[name] = (neighbour.result(timeout=1),
+                         response.result(timeout=1))
+    assert streams["ceiling"] == streams["floor"]
+    bucket, kept = engine.slot_prefill_len(prompt_len, True)
+    above = prompt_len - 1 <= 16
+    assert (bucket, kept) == (
+        (4 if prompt_len <= 5 else 16, prompt_len - 1) if above else (16, 16))
+    prefills = [c[1] for c in engine.calls if c[0] == "prefill"]
+    packs = [c[1] for c in engine.calls if c[0] == "pack"]
+    assert prefills == [(1, 4), (1, bucket)]  # the neighbour's, then ours
+    n_owned = -(-kept // 4)
+    assert packs[1] == tuple(own[:n_owned]) + (0,) * (bucket // 4 - n_owned)
+    assert not set(packs[1]) & set(packs[0])
+    stats = scheduler.stats()
+    # The neighbour kept 4 of the bucket of 4 and replayed its last token.
+    assert stats["prefills_ceiling"] + stats["prefills_floor"] == 2
+    assert stats["prefills_floor"] == (0 if above else 1)
+    assert stats["prefill_pad_tokens"] == bucket - kept
+    assert stats["prefilled_tokens"] == 4 + kept
+    assert stats["prefill_tokens"] == 1 + prompt_len - kept
+    assert stats["ahead_prefill_tokens"] <= 4 + bucket
+
+
+def test_a_scheduler_keeps_the_floor_rule_unless_its_engine_says_otherwise():
+    """Neither an engine that cannot say (no `ceiling_prefill`) nor one
+    that says no gets a padded prefill: the bucket below, kept whole, and
+    the rest replayed, as before."""
+
+    class Mute(FakePagedEngine):
+        ceiling_prefill = None
+
+    for engine in (FakePagedEngine(), Mute()):
+        scheduler = fake_scheduler(engine, max_slots=1)
+        response = scheduler.submit(
+            [1, 2, 3, 4, 5, 6, 7], SamplingParams(max_new_tokens=2))
+        _drive(scheduler, [response])
+        stats = scheduler.stats()
+        assert [c[1] for c in engine.calls if c[0] == "prefill"] == [(1, 4)]
+        assert (stats["prefills_ceiling"], stats["prefills_floor"],
+                stats["prefill_pad_tokens"], stats["prefilled_tokens"],
+                stats["prefill_tokens"]) == (0, 1, 0, 4, 3)
+
+
 def test_paged_prefix_eviction_under_pool_pressure():
     """A cached prefix is evicted (LRU) when a new request needs its
     blocks — the cache trades reuse for admission, never blocks it."""
@@ -634,7 +711,7 @@ def _legacy_stream(model, params, prompt, max_new, eos=None):
 @pytest.mark.slow  # tier-1 budget: the HTTP e2e is represented by
 # test_run_serving_task_body_advertises_and_serves (the stack through
 # the real frontend) + the engine-level legacy parity in
-# test_whole_prompt_replay_matches_legacy; the HTTP-streams-match-legacy
+# test_short_and_padded_admissions_match_legacy; the HTTP-streams-match-legacy
 # bar stays in tier-1 via test_kv_oversubscription.py::
 # test_http_suspend_resume_stream_matches_legacy_fp_greedy.
 def test_http_end_to_end_matches_legacy_with_slot_reuse():
@@ -790,12 +867,18 @@ def test_paged_http_end_to_end_matches_legacy_with_prefix_hit():
         scheduler.close()
 
 
-def test_whole_prompt_replay_matches_legacy():
-    """Regression for the prefill_len == 0 admission path: a prompt
-    shorter than the smallest prompt bucket replays ENTIRELY through
-    the step program from an empty slot — previously untested. Streams
-    must stay bit-equal to generate_legacy, including when the slot was
-    dirtied by an earlier longer request."""
+# [11]: the prefill_len == 0 admission path, the whole prompt (its one
+# token) through the step from an empty slot. [11, 23]: shorter than the
+# smallest bucket, so one kept row in a bucket of 4 and the last token
+# replayed. The others: one under, at and one over the bucket of 8.
+@pytest.mark.parametrize("prompt", [
+    [11], [11, 23], list(range(30, 38)), list(range(30, 39)),
+    list(range(30, 40))], ids=lambda p: f"len{len(p)}")
+def test_short_and_padded_admissions_match_legacy(prompt):
+    """The scheduler over the real engine, which reads the ceiling rule
+    off this dense causal model: streams stay bit-equal to
+    generate_legacy, including when the slot was dirtied by an earlier
+    longer request (stale rows past the kept ones, beside the pad's)."""
     model, params, _engine, scheduler = _tiny_serving_stack(max_slots=1)
     try:
         # Dirty the single slot first so the replay-from-empty path has
@@ -805,7 +888,6 @@ def test_whole_prompt_replay_matches_legacy():
             scheduler.tick()
             if dirty.done:
                 break
-        prompt = [11, 23]  # len 2 < min bucket 4 -> slot_prefill_len 0
         response = scheduler.submit(
             prompt, SamplingParams(max_new_tokens=6)
         )
@@ -816,6 +898,15 @@ def test_whole_prompt_replay_matches_legacy():
         assert response.result(timeout=1) == _legacy_stream(
             model, params, prompt, 6
         )
+        stats = scheduler.stats()
+        # The dirtying request kept 8 of the bucket of 8; then ours.
+        kept = len(prompt) - 1
+        bucket = min([b for b in (4, 8, 16) if b >= kept]) if kept else 0
+        assert stats["prefills_ceiling"] == 1 + bool(kept)
+        assert stats["prefills_floor"] == 0
+        assert stats["prefill_pad_tokens"] == bucket - kept
+        assert stats["prefilled_tokens"] == 8 + kept
+        assert stats["prefill_tokens"] == 1 + 1  # one replayed token each
     finally:
         scheduler.close()
 

@@ -398,6 +398,11 @@ def test_every_finish_reason_leaves_one_record_under_the_callers_id():
     prefill = next(s for s in _records("serving/prefill")
                    if s.args["request_id"] == "r-length")
     assert prefill.args["request"] == length.request.id
+    # The program that ran and the rows of it that were the prompt's (the
+    # fake keeps the floor rule: the bucket of 4, whole); `prefill` is what
+    # the benchmark's readers take for the tokens in the cache at admission.
+    assert (prefill.args["bucket"], prefill.args["kept"],
+            prefill.args["prefill"]) == (4, 4, 4)
 
 
 def test_prefix_hit_and_chunked_prefill_keep_every_request_under_its_id():

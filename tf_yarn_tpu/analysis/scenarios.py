@@ -70,12 +70,12 @@ class _FakePagedEngine:
         self.buckets = tuple(sorted(buckets))
         self.max_seq_len = max_seq_len
 
-    def slot_prefill_len(self, prompt_len):
+    def slot_prefill_len(self, prompt_len, ceiling=False):
         best = 0
         for bucket in self.buckets:
             if bucket <= prompt_len - 1:
                 best = bucket
-        return best
+        return best, best
 
     def make_paged_pool(self, params, num_blocks, block_size):
         return np.zeros((num_blocks, block_size), np.int64)
@@ -641,6 +641,9 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._decode_tokens", _ADVISORY),
                 ("scheduler._peak_streams", _ADVISORY),
                 ("scheduler._prefilled_tokens", _ADVISORY),
+                ("scheduler._prefills_ceiling", _ADVISORY),
+                ("scheduler._prefills_floor", _ADVISORY),
+                ("scheduler._prefill_pad_tokens", _ADVISORY),
                 ("scheduler._kv_token_steps", _ADVISORY),
                 ("scheduler._kv_read_token_steps", _ADVISORY),
                 ("scheduler._slot_steps", _ADVISORY),
@@ -671,6 +674,9 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._decode_tokens", _ADVISORY),
                 ("scheduler._peak_streams", _ADVISORY),
                 ("scheduler._prefilled_tokens", _ADVISORY),
+                ("scheduler._prefills_ceiling", _ADVISORY),
+                ("scheduler._prefills_floor", _ADVISORY),
+                ("scheduler._prefill_pad_tokens", _ADVISORY),
                 ("scheduler._kv_token_steps", _ADVISORY),
                 ("scheduler._kv_read_token_steps", _ADVISORY),
                 ("scheduler._slot_steps", _ADVISORY),

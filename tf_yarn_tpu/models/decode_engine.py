@@ -68,7 +68,10 @@ top_k / top_p are baked into the traced program) key the cache.
   a step before it has read the one before. Free/allocate is host-side
   free-list bookkeeping (`serving/paging.py`); there is no per-eviction
   device program at all. `pack_prefill` splices a bucketed-prefill result into
-  a slot's blocks; int8 KV composes transparently (the pool stores
+  a slot's blocks (which bucket, and how many of its rows are the prompt's:
+  `slot_prefill_len`; the bucket above the prompt, padded, where the model's
+  prompt rows do not depend on later tokens, `ceiling_prefill`, else the one
+  below, whole); int8 KV composes transparently (the pool stores
   whatever leaves the model's cache has — int8 values + scales
   included).
 
@@ -795,6 +798,16 @@ def build_paged_spec_step_fn(model, block_size: int, width: int,
     return spec_step
 
 
+def _write_blocks(pool_leaf, block_ids, blocks, axis: int):
+    """`blocks` [..., W, block_size, ...] into the pool's blocks
+    `block_ids` [W] along `axis`: one scatter of whole blocks a leaf,
+    whatever W (a program of one `dynamic_update_slice` a block took 33 s
+    to trace and compile for 128 blocks of 16 leaves). Ids may repeat only
+    where they aim at the trash block, whose content is garbage."""
+    at = (slice(None),) * axis + (block_ids,)
+    return pool_leaf.at[at].set(blocks.astype(pool_leaf.dtype))
+
+
 def build_pack_prefill_fn(model, block_size: int, prefill_len: int):
     """The prefill->pool splice program: write positions [0, prefill_len)
     of a freshly prefilled batch-1 cache into the slot's first
@@ -804,6 +817,9 @@ def build_pack_prefill_fn(model, block_size: int, prefill_len: int):
 
     `block_ids` values are traced (different slots reuse one compiled
     program); `prefill_len` is static (one program per prefill bucket).
+    An id may aim at the reserved trash block 0, so that its rows land
+    nowhere: the blocks past the rows an admission keeps of a padded
+    bucket (`DecodeEngine.slot_prefill_len`).
     """
     n_pack = -(-prefill_len // block_size)
 
@@ -814,22 +830,12 @@ def build_pack_prefill_fn(model, block_size: int, prefill_len: int):
             if pool_leaf is None:
                 return None
             ax = lay.axis
-            for j in range(n_pack):
-                width = min(block_size, prefill_len - j * block_size)
-                chunk = jax.lax.slice_in_dim(
-                    row_leaf, j * block_size, j * block_size + width, axis=ax
-                )
-                if width < block_size:
-                    pad = [(0, 0)] * chunk.ndim
-                    pad[ax] = (0, block_size - width)
-                    chunk = jnp.pad(chunk, pad)
-                chunk = jnp.expand_dims(chunk, ax)
-                starts = [jnp.asarray(0, jnp.int32)] * pool_leaf.ndim
-                starts[ax] = block_ids[j]
-                pool_leaf = jax.lax.dynamic_update_slice(
-                    pool_leaf, chunk.astype(pool_leaf.dtype), tuple(starts)
-                )
-            return pool_leaf
+            rows = jax.lax.slice_in_dim(row_leaf, 0, prefill_len, axis=ax)
+            pad = [(0, 0)] * rows.ndim
+            pad[ax] = (0, n_pack * block_size - prefill_len)
+            chunks = jnp.pad(rows, pad).reshape(
+                rows.shape[:ax] + (n_pack, block_size) + rows.shape[ax + 1:])
+            return _write_blocks(pool_leaf, block_ids, chunks, ax)
 
         return jax.tree_util.tree_map(
             leaf, pool, row_cache, layout, is_leaf=_is_none
@@ -873,9 +879,9 @@ def build_inject_blocks_fn(model, row_aval):
         fn(pool, block_ids, payload) -> pool
 
     Writes payload row j into physical block `block_ids[j]` (traced
-    values, static width) via the same dynamic_update_slice splice as
-    `build_pack_prefill_fn`. The pool is donated by the engine wrapper
-    so resume updates HBM in place. Rows the scheduler does not want
+    values, static width) by the same whole-block scatter as
+    `build_pack_prefill_fn` (`_write_blocks`). The pool is donated by the
+    engine wrapper so resume updates HBM in place. Rows the scheduler does not want
     re-injected (prefix-cache hits re-attached by lookup, padding) are
     aimed at the trash block, whose content is garbage by contract.
     """
@@ -886,15 +892,7 @@ def build_inject_blocks_fn(model, row_aval):
         def leaf(pool_leaf, lay, pay_leaf):
             if pool_leaf is None:
                 return None
-            ax = lay.axis
-            for j in range(block_ids.shape[0]):
-                chunk = jax.lax.slice_in_dim(pay_leaf, j, j + 1, axis=ax)
-                starts = [jnp.asarray(0, jnp.int32)] * pool_leaf.ndim
-                starts[ax] = block_ids[j]
-                pool_leaf = jax.lax.dynamic_update_slice(
-                    pool_leaf, chunk.astype(pool_leaf.dtype), tuple(starts)
-                )
-            return pool_leaf
+            return _write_blocks(pool_leaf, block_ids, pay_leaf, lay.axis)
 
         return jax.tree_util.tree_map(leaf, pool, layout, payload,
                                       is_leaf=_is_none)
@@ -1087,6 +1085,9 @@ class DecodeEngine:
         self._decode: Dict[tuple, Any] = {}
         self._lock = threading.Lock()
         self._placed_seen = None  # _placed: (treedef, weak leaves, fp)
+        # slot_state_leaves by params fingerprint: a server asks three
+        # times at construction, and each ask is a trace of the model.
+        self._state_leaf_names: Dict[int, Tuple[str, ...]] = {}
         self.stats = {
             "calls": 0,
             "prefill_compiles": 0,
@@ -1352,16 +1353,40 @@ class DecodeEngine:
     # and packs the result into the slot's blocks; every tick then
     # advances all slots one token in one compiled `paged_step` program.
 
-    def slot_prefill_len(self, prompt_len: int) -> int:
-        """Prefill length for a slot admission: the largest prompt bucket
-        that still leaves >= 1 prompt token to replay through the step (the
+    def slot_prefill_len(self, prompt_len: int,
+                         ceiling: bool = False) -> Tuple[int, int]:
+        """(bucket, kept) for a slot admission: the prefill program to run
+        (its prompt bucket) and how many of its rows are the prompt's. The
         step consuming the LAST prompt token samples the first generated
-        token — generate_legacy's prefill sample — so the final prompt
-        position always goes through the step program). 0 = no prefill:
-        the whole prompt replays token-by-token from an empty slot."""
+        token (generate_legacy's prefill sample), so at most
+        prompt_len - 1 rows are kept and the rest of the prompt replays
+        through the step. The floor rule takes the largest bucket at or
+        under prompt_len - 1 and keeps all of it. The `ceiling` rule
+        (`ceiling_prefill` says whether this model may take it) takes the
+        bucket at or above prompt_len - 1, padded past the prompt, and
+        keeps prompt_len - 1 rows: one token replays. It falls back to the
+        floor where no bucket that the cache can hold lies above. (0, 0) =
+        no prefill: the whole prompt replays from an empty slot."""
         if prompt_len <= 1:
-            return 0
-        return _floor_bucket(prompt_len - 1, self.prompt_buckets) or 0
+            return 0, 0
+        kept = prompt_len - 1
+        if ceiling:
+            bucket = _ceil_bucket(kept, self.prompt_buckets)
+            if bucket and bucket <= self.model.config.max_seq_len:
+                return bucket, kept
+        bucket = _floor_bucket(kept, self.prompt_buckets) or 0
+        return bucket, bucket
+
+    def ceiling_prefill(self, params) -> bool:
+        """Whether an admission may prefill the bucket ABOVE its prompt and
+        keep the true length (`slot_prefill_len`): only where a row of the
+        prefill's cache cannot depend on the tokens after it, so that the
+        pad leaves the kept rows what they would have been. The model says
+        so (`prompt_rows_causal`; a model that says nothing keeps the floor
+        rule), and nothing of its cache may be held once a slot: such a
+        leaf is what the prefill left at the END of its bucket."""
+        return bool(getattr(self.model, "prompt_rows_causal", False)) \
+            and not self.slot_state_leaves(params)
 
     def prefill(self, params, prompt):
         """Public compiled prefill: [B, F] prompt -> (cache, last
@@ -1447,9 +1472,11 @@ class DecodeEngine:
         if getattr(self.model, "cache_leaf_kinds", None) is None:
             return ()
         params = self._place_params(params)
-        return slot_state_names(
-            cache_layout(self.model, _decode_cache_aval(self.model, params))
-        )
+        fp = self._params_fingerprint(params)
+        if fp not in self._state_leaf_names:
+            self._state_leaf_names[fp] = slot_state_names(cache_layout(
+                self.model, _decode_cache_aval(self.model, params)))
+        return self._state_leaf_names[fp]
 
     def make_slot_state(self, params, max_slots: int):
         """Zeroed per-slot state beside the block pool: every `slot` and

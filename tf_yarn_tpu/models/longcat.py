@@ -155,6 +155,10 @@ class LongcatLM(nn.Module):
     # A cached row has no head axis and is read as one KV head of its width
     # by `paged_decode_attention`: the engine picks that op's implementation.
     pool_rows_are_one_kv_head = True
+    # Row t of a prefill's cache depends on tokens <= t alone (causal
+    # attention, per-token dropless experts, every leaf paged): the engine
+    # may pad a prompt past its true length (`ceiling_prefill`).
+    prompt_rows_causal = True
 
     def cache_leaf_kinds(self):
         return {"latent": ("paged", -2), "cache_index": ("index", None)}

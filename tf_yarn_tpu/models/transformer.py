@@ -586,6 +586,16 @@ class Transformer(nn.Module):
         the slot's position."""
         return CACHE_LEAF_KINDS
 
+    @property
+    def prompt_rows_causal(self) -> bool:
+        """Whether row t of a prefill's cache depends on tokens <= t alone,
+        so that the serving engine may pad a prompt past its true length
+        and keep the rows before the pad (`DecodeEngine.ceiling_prefill`).
+        Attention here is causal and the dense feed-forward is per token;
+        `MoEMlp` counts its capacity over the tokens of the call, pads
+        included, so a pad could push a prompt token out of its expert."""
+        return self.config.moe_experts == 0
+
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True,
                  return_hidden: bool = False, decode: bool = False,

@@ -208,7 +208,9 @@ class PrefillWorker:
 
     def _prefill_locked(self, prompt):
         self._requests += 1
-        prefill_len = int(self.engine.slot_prefill_len(len(prompt)))
+        # The floor rule: the tier ships whole blocks of a bucket it kept
+        # whole, and the decode side replays the rest.
+        prefill_len, _kept = self.engine.slot_prefill_len(len(prompt))
         whole = prefill_len // self._block_size
         if whole < 1:
             return self._empty_wire(), "short"

@@ -6,10 +6,22 @@ Every scheduler tick:
 
 1. **retire** active slots whose per-request deadline passed;
 2. **admit** queued requests into free slots — prefill the prompt
-   through the engine's existing bucketed prefill programs
-   (`slot_prefill_len` picks the largest bucket that leaves the last
-   prompt token for the step program) and queue the prompt remainder
-   for replay; with **chunked prefill** (``prefill_chunk`` > 0) the
+   through the engine's existing bucketed prefill programs and queue
+   the prompt remainder for replay. `slot_prefill_len` says which
+   program runs and how much of it is kept, by the rule the engine
+   reads off the model (`ceiling_prefill`, never an option): where no
+   row of the prefill's cache depends on the tokens after it (causal
+   attention, per-token feed-forwards and dropless experts) and nothing
+   is held once a slot, all of the prompt but its last token, padded to
+   the bucket ABOVE it (no kept row sees the pad under the causal mask;
+   the blocks past the kept rows aim at the trash block, and what of
+   the pad shares the last owned block lies past the slot's length,
+   where the slot writes as it grows), so ONE token replays; otherwise
+   the largest bucket BELOW the prompt, kept whole, and the rest
+   replayed a token a tick (`/stats` ``prefills_ceiling``,
+   ``prefills_floor``, ``prefill_pad_tokens``; ``bucket`` and ``kept``
+   on the `serving/prefill` span).
+   With **chunked prefill** (``prefill_chunk`` > 0) the
    blocking prefill program is skipped entirely — the slot installs
    immediately and the whole prompt queues as pending tokens that the
    windowed step replays ``prefill_chunk`` at a time, interleaved with
@@ -397,6 +409,10 @@ class SlotScheduler:
         counted_of = getattr(engine, "counted_step", None)
         self._counted_step = bool(counted_of(params)) if counted_of \
             else bool(self._state_leaves)
+        # Whether an admission prefills the bucket above its prompt and
+        # keeps the true length: the engine's reading of the model.
+        ceiling_of = getattr(engine, "ceiling_prefill", None)
+        self._ceiling_prefill = bool(ceiling_of and ceiling_of(params))
         self.temperature = float(temperature)
         self.top_k = top_k
         self.top_p = top_p
@@ -453,6 +469,11 @@ class SlotScheduler:
         self._decode_tokens = 0
         # Work counters where the work happens (/stats, docs/Serving.md).
         self._prefilled_tokens = 0
+        # Blocking prefills by rule, and the pad rows the device ran beside
+        # the kept ones (bucket - kept, summed).
+        self._prefills_ceiling = 0
+        self._prefills_floor = 0
+        self._prefill_pad_tokens = 0
         self._kv_token_steps = 0
         self._kv_read_token_steps = 0
         self._slot_steps = 0
@@ -1136,29 +1157,47 @@ class SlotScheduler:
             # completed whole block with the prefix cache as it fills.
             prefill_len = 0
         else:
-            prefill_len = prefilled = self.engine.slot_prefill_len(len(prompt))
+            # The program that runs (its bucket) and the rows of it that
+            # are the prompt's: all of them under the floor rule, all of
+            # the prompt but its last token under the ceiling rule.
+            bucket, kept = self.engine.slot_prefill_len(
+                len(prompt), self._ceiling_prefill)
+            prefill_len = prefilled = kept
             with telemetry.span(
                 "serving/prefill", request=request.id,
-                request_id=request.public_id, prefill=prefill_len,
+                request_id=request.public_id, prefill=kept,
+                bucket=bucket, kept=kept,
             ):
                 row_cache = None
-                if prefill_len > 0:
+                if bucket > 0:
+                    # Rows past `kept` hold the pad: no kept row sees them
+                    # under the causal mask, their blocks' ids aim at the
+                    # trash block, and what of them lands in the last
+                    # owned block lies past the slot's length, where the
+                    # slot writes as it grows.
+                    tokens = np.zeros((1, bucket), np.int32)
+                    tokens[0, :kept] = prompt[:kept]
                     row_cache, _logits = self.engine.prefill(
-                        self.params,
-                        np.asarray(prompt[:prefill_len], np.int32)[None, :],
-                    )
-                    n_pack = -(-prefill_len // self._block_size)
+                        self.params, tokens)
+                    n_owned = -(-kept // self._block_size)
+                    ids = np.full((-(-bucket // self._block_size),),
+                                  TRASH_BLOCK, np.int32)
+                    ids[:n_owned] = blocks[:n_owned]
                     self._pool = self.engine.pack_prefill(
-                        self._pool,
-                        np.asarray(blocks[:n_pack], np.int32),
-                        row_cache, prefill_len, self._block_size,
+                        self._pool, ids, row_cache, bucket,
+                        self._block_size,
                     )
-                    self._queued(2, prefill_len)
+                    self._queued(2, bucket)
+                    if self._ceiling_prefill and kept == len(prompt) - 1:
+                        self._prefills_ceiling += 1
+                        self._prefill_pad_tokens += bucket - kept
+                    else:
+                        self._prefills_floor += 1
                     if not self._state_leaves:
                         # Offer the full-block prefix for sharing; the
                         # partial tail block stays private (the replay
                         # writes it).
-                        self._prefix.register(prompt, prefill_len, blocks)
+                        self._prefix.register(prompt, kept, blocks)
                 if self._state_leaves:
                     # The prefill's final state, or zeros where nothing
                     # was prefilled, before the first replayed token: a
@@ -2295,6 +2334,12 @@ class SlotScheduler:
             "prefill_tokens": self._prefill_tokens,
             "decode_tokens": self._decode_tokens,
             "prefilled_tokens": self._prefilled_tokens,
+            # Blocking prefills that ran the bucket above the prompt and
+            # kept the true length, those that ran the bucket below and
+            # kept it whole, and the pad rows of the former.
+            "prefills_ceiling": self._prefills_ceiling,
+            "prefills_floor": self._prefills_floor,
+            "prefill_pad_tokens": self._prefill_pad_tokens,
             "kv_token_steps": self._kv_token_steps,
             "kv_read_token_steps": self._kv_read_token_steps,
             "slot_steps": self._slot_steps,

@@ -29,6 +29,14 @@ Nothing runs, so nothing here is a result or a time of the device. Programs:
             1026-1536 token prompt runs), pack, and the 64-slot paged state
             step
             (every leaf paged, the latent rows read by the paged kernel)
+    laguna  the benchmark's Laguna-XS.2 stage at its own sizes
+            (cellbench/configs/laguna_xs2_serve_1chip.json): prefill at the
+            512, 1024 and 2048 buckets (1024 and 2048: the tokens sorted by
+            expert over all 256 of a layer), pack, and the 64-slot paged
+            state step
+            (the full layers' keys and values read by the paged kernel at 6
+            query heads a KV head, the sliding layers' rings held once a
+            slot)
 
 Each line: how many Mosaic kernels (`tpu_custom_call`) and collectives the
 compiler emitted, and the bytes one device needs (temporaries + arguments).
@@ -316,6 +324,8 @@ def main(argv) -> int:
         "longcat": lambda: compile_latent(
             one, "longcat", "longcat_flash_serve_1chip", (512, 1024, 2048),
             True),
+        "laguna": lambda: compile_latent(
+            one, "laguna", "laguna_xs2_serve_1chip", (512, 1024, 2048), True),
     }
     for name in argv or list(programs):
         programs[name]()

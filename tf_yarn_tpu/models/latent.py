@@ -79,12 +79,13 @@ from tf_yarn_tpu.models.transformer import (
     SwiGLU,
     TransformerConfig,
     _partitioned,
+    ring_rows,
+    ring_valid,
 )
 
 HIGHEST = jax.lax.Precision.HIGHEST
 FULL, SLIDING = "full_attention", "sliding_attention"
 PLAIN = "plain_attention"
-RING_MULTIPLE = 16
 # What an attention layer sows into `cache_stats` a step, over the counted
 # slots: rows live and rows read of each leaf, and the keys selected.
 READS = ("index_live", "index_read", "index_selected", "latent_read",
@@ -163,7 +164,7 @@ class LatentConfig:
     def ring_len(self) -> int:
         """Rows of a sliding layer's ring: the window, rounded up to whole
         tiles of the cache's type."""
-        return -(-self.window // RING_MULTIPLE) * RING_MULTIPLE
+        return ring_rows(self.window)
 
     @property
     def n_attention_layers(self) -> int:
@@ -712,10 +713,7 @@ class LatentAttention(nn.Module):
                 jnp.arange(batch), lengths % ring].set(row)
             var.value = held.reshape(var.value.shape)
         with jax.named_scope("window/read"):
-            # Row i holds the newest position p <= length with p % ring == i.
-            at = lengths[:, None] - (
-                lengths[:, None] - jnp.arange(ring)[None, :]) % ring
-            valid = (at >= 0) & (lengths[:, None] - at < cfg.window)
+            valid = ring_valid(lengths, ring, cfg.window)
         return absorbed_attention(q_n, q_r, held, valid, w_kvb, sizes,
                                   cfg.dtype)
 

@@ -113,6 +113,98 @@ class MoEMlp(nn.Module):
         return combined.reshape(b, s, d).astype(cfg.dtype)
 
 
+# Rows of one product of the sorted form: a tile holds one expert's tokens.
+TILE = 128
+# How many times the sorted form's rows the one product over every held
+# expert may compute before `DroplessMoE` sorts its tokens by expert.
+ROWS_OVER = 4
+
+
+def sorts_by_expert(held: int, tokens: int, top_k: int) -> bool:
+    """Whether `DroplessMoE` computes `held` experts over `tokens` tokens of
+    `top_k` choices each in the sorted form (`sorted_experts`), from the
+    shapes alone: where every held expert over every token (held x tokens
+    rows) is more than `ROWS_OVER` times the sorted buffer (the assignments
+    and a tile of padding an expert). A prefill of 1024 tokens or more with
+    all 256 experts of a layer held at 8 a token does (6x, 11x at 2048); a
+    decode step does not (the padding outweighs 64 tokens), nor any call of
+    a model that holds 16-18 experts of 8-12 a token (under held / top_k,
+    2.25 at most)."""
+    return held * tokens > ROWS_OVER * (tokens * top_k + held * TILE)
+
+
+def sorted_experts(x, w_in, w_out, local, gates, dtype):
+    """`sum_j gates[t, j] E_local[t, j](x[t])` over the chosen experts that
+    are held, each token computed by its own experts alone: x [T, D], w_in
+    [held, D, 2 F], w_out [held, F, D], local [T, k] (the chosen experts'
+    places among the held; outside [0, held) = not held), gates [T, k] ->
+    [T, D] float32.
+
+    The T k assignments are sorted by expert into a buffer in which each
+    expert's tokens start on a tile of `TILE` rows (the rest of its last
+    tile is padding: a zero row with a zero gate), so a tile is one
+    expert's, and a loop over the tiles in use multiplies each by its
+    expert's two matrices, sliced where they lie. Dropless: the buffer holds
+    T k rows and `TILE - 1` of padding an expert, whatever the routing. The
+    arithmetic is the assignments', not held x T: 1 / 32 of it at 256 held
+    and 8 a token."""
+    t, d = x.shape
+    held, _, two_width = w_in.shape
+    width = two_width // 2
+    k = local.shape[1]
+    n_tiles = -(-t * k // TILE) + held
+    rows = n_tiles * TILE
+    with jax.named_scope("moe/dispatch"):
+        flat = local.reshape(-1)
+        expert = jnp.where((flat >= 0) & (flat < held), flat, held)
+        order = jnp.argsort(expert, stable=True)
+        by_expert = expert[order]
+        counts = jnp.sum(
+            expert[:, None] == jnp.arange(held)[None, :], axis=0,
+            dtype=jnp.int32)
+        padded = -(-counts // TILE) * TILE
+        ends = jnp.cumsum(padded)
+        first_row = jnp.concatenate([ends - padded, jnp.full((1,), rows)])
+        first_place = jnp.concatenate(
+            [jnp.cumsum(counts) - counts, jnp.zeros((1,), jnp.int32)])
+        # An assignment to an expert not held goes to the spare last row.
+        row = jnp.where(
+            by_expert < held,
+            first_row[by_expert] + jnp.arange(t * k) - first_place[by_expert],
+            rows)
+        row_token = jnp.full((rows + 1,), t, jnp.int32).at[row].set(
+            (order // k).astype(jnp.int32))
+        row_gate = jnp.zeros((rows + 1,), jnp.float32).at[row].set(
+            gates.reshape(-1)[order])
+        tile_expert = jnp.minimum(jnp.searchsorted(
+            ends, jnp.arange(n_tiles) * TILE, side="right"), held - 1)
+        sorted_x = jnp.concatenate(
+            [x, jnp.zeros((1, d), x.dtype)])[row_token[:rows]]
+
+    def one_tile(index, out):
+        at = index * TILE
+        w_a = jax.lax.dynamic_index_in_dim(w_in, tile_expert[index], 0, False)
+        w_b = jax.lax.dynamic_index_in_dim(w_out, tile_expert[index], 0, False)
+        with jax.named_scope("moe/experts"):
+            hidden = jnp.einsum(
+                "td,df->tf", jax.lax.dynamic_slice_in_dim(sorted_x, at, TILE),
+                w_a.astype(dtype), preferred_element_type=jnp.float32)
+            act = nn.silu(hidden[:, :width]) * hidden[:, width:]
+        with jax.named_scope("moe/combine"):
+            gate = jax.lax.dynamic_slice_in_dim(row_gate, at, TILE)
+            part = jnp.einsum(
+                "tf,fd->td", (act * gate[:, None]).astype(dtype),
+                w_b.astype(dtype), preferred_element_type=jnp.float32)
+            return jax.lax.dynamic_update_slice_in_dim(out, part, at, 0)
+
+    # The tiles in use, not all the buffer could hold: a dynamic trip count.
+    out = jax.lax.fori_loop(
+        0, ends[-1] // TILE, one_tile, jnp.zeros((rows + 1, d), jnp.float32))
+    with jax.named_scope("moe/combine"):
+        place = jnp.zeros((t * k,), jnp.int32).at[order].set(row)
+        return jnp.sum(out[place].reshape(t, k, d), axis=1)
+
+
 class DroplessMoE(nn.Module):
     """`moe(x) = sum_i g_i W_out,i (silu(a_i) * b_i)`, `[a_i | b_i] = x W_in,i`
     over the `top_k` chosen experts; plus `shared(x)`, one SwiGLU of width
@@ -147,6 +239,11 @@ class DroplessMoE(nn.Module):
     a token did not choose the expert: no token is dropped and no
     [T, E, C] dispatch tensor exists. At decode (T = slots) that streams
     each held expert's matrices once, which is what the step is bound by.
+    Where that product would be many times the assignments' rows
+    (`sorts_by_expert`: from the shapes alone, a prefill with every expert
+    of a layer held), the tokens are sorted by expert and each is computed
+    by its own experts alone (`sorted_experts`): the same sum, dropless
+    still.
 
     `count_mask` [T] marks the tokens whose routing is counted into the
     mutable `moe_stats` collection (`counts` [1 + held]: their assignments
@@ -196,6 +293,8 @@ class DroplessMoE(nn.Module):
             if self.scoring == "softmax":
                 top_logits, top_index = jax.lax.top_k(logits, self.top_k)
                 gates = jax.nn.softmax(top_logits, axis=-1)
+                if self.routed_scale != 1.0:
+                    gates = gates * self.routed_scale
             else:
                 bias = self.param("router_bias", nn.initializers.zeros_init(),
                                   (outputs,), jnp.float32)
@@ -233,15 +332,22 @@ class DroplessMoE(nn.Module):
                 "w_out", _partitioned((EXPERT, MLP, EMBED))(normal),
                 (held, width, d), self.param_dtype,
             )
-            hidden = jnp.einsum("td,edf->etf", x, w_in.astype(self.dtype),
-                                preferred_element_type=jnp.float32)
-            act = nn.silu(hidden[..., :width]) * hidden[..., width:]
-        with jax.named_scope("moe/combine"):
-            # The gate goes onto the activation, so that the weighted sum
-            # over experts is the contraction of one matmul.
-            act = (act * weights.T[:, :, None]).astype(self.dtype)
-            out = jnp.einsum("etf,efd->td", act, w_out.astype(self.dtype),
-                             preferred_element_type=jnp.float32)
+
+        if sorts_by_expert(held, t, self.top_k):
+            # Many experts over many tokens (a prefill with every expert of
+            # the layer held): each token by its own experts alone.
+            out = sorted_experts(x, w_in, w_out, local, gates, self.dtype)
+        else:
+            with jax.named_scope("moe/experts"):
+                hidden = jnp.einsum("td,edf->etf", x, w_in.astype(self.dtype),
+                                    preferred_element_type=jnp.float32)
+                act = nn.silu(hidden[..., :width]) * hidden[..., width:]
+            with jax.named_scope("moe/combine"):
+                # The gate goes onto the activation, so that the weighted
+                # sum over experts is the contraction of one matmul.
+                act = (act * weights.T[:, :, None]).astype(self.dtype)
+                out = jnp.einsum("etf,efd->td", act, w_out.astype(self.dtype),
+                                 preferred_element_type=jnp.float32)
         if self.num_zero_experts:
             with jax.named_scope("moe/identity"):
                 handed_back = jnp.sum(jnp.where(

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
@@ -125,6 +125,12 @@ CACHE_LEAF_KINDS = {
 }
 
 
+# What `Attention` sows into `cache_stats` a one-token step, over the counted
+# slots: rows live and rows read of the pool (a full layer), of a ring (a
+# window layer). A model built on it names them as its `READS`.
+ATTENTION_READS = ("pool_live", "pool_read", "window_live", "window_read")
+
+
 @functools.partial(
     jax.tree_util.register_dataclass,
     data_fields=["tables", "lengths"], meta_fields=["kernel"],
@@ -166,6 +172,64 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
         rx2 = x2 * cos + x1 * sin
         out = jnp.stack([rx1, rx2], axis=-1).reshape(x.shape)
         return out.astype(x.dtype)
+
+
+RING_MULTIPLE = 16
+
+
+def ring_rows(window: int) -> int:
+    """Rows of a window layer's ring: the window, rounded up to whole tiles
+    of the cache's type (row `p % rows` holds position p)."""
+    return -(-window // RING_MULTIPLE) * RING_MULTIPLE
+
+
+def ring_valid(lengths, rows: int, window: int):
+    """[B, rows] bool: which rows of a ring hold a position inside the window
+    of the token at `lengths` [B] (its own row, just written, included). Row
+    i holds the newest position p <= length with p % rows == i."""
+    at = lengths[:, None] - (
+        lengths[:, None] - jnp.arange(rows)[None, :]) % rows
+    return (at >= 0) & (lengths[:, None] - at < window)
+
+
+def own_token_attention(q, k, v, *, window: int = 0,
+                        softmax_scale: Optional[float] = None,
+                        query_block: int = 256):
+    """Causal attention of a call's own tokens, from position 0, a block of
+    queries at a time: q [B, S, H, D], k, v [B, S, Hkv, D] -> [B, S, H, D]
+    float32. `window` > 0 keeps the keys `t - j < window` (the query itself
+    counts). Query head h reads KV head h // (H // Hkv). The largest array
+    is the scores of one block, [B, H, query_block, S] float32 (the plain
+    masked form: scores past the window are formed and masked)."""
+    batch, s, heads, dim = q.shape
+    n_kv = k.shape[2]
+    scale = dim ** -0.5 if softmax_scale is None else softmax_scale
+    block = min(query_block, s)
+    pad = -s % block
+    nb = (s + pad) // block
+    grouped = jnp.pad(q, [(0, 0), (0, pad), (0, 0), (0, 0)]).reshape(
+        batch, nb, block, n_kv, heads // n_kv, dim)
+    keys = jnp.arange(s)[None, :]
+
+    def some_rows(args):
+        start, q_block = args
+        at = (start + jnp.arange(block))[:, None]
+        mask = at >= keys
+        if window:
+            mask &= at - keys < window
+        with jax.named_scope("attention/scores"):
+            scores = jnp.einsum("bqgrd,bkgd->bgrqk", q_block, k,
+                                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(mask, scores, -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        with jax.named_scope("attention/values"):
+            return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v,
+                              preferred_element_type=jnp.float32)
+
+    out = jax.lax.map(
+        some_rows, (jnp.arange(nb) * block, jnp.moveaxis(grouped, 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(
+        batch, nb * block, heads, dim)[:, :s]
 
 
 class RMSNorm(nn.Module):
@@ -239,22 +303,58 @@ class LoraDense(nn.Module):
 
 
 class Attention(nn.Module):
+    """Grouped-query attention of the llama recipe. What a model whose
+    attention differs by layer says of one layer (models/laguna.py) are
+    properties of the module, and their defaults are the config's and the
+    recipe's, so that a model that says nothing runs the program it ran:
+
+    `n_heads`, `head_dim`: query heads and the width of a head, where they
+    are not `config.n_heads` and `d_model // n_heads`. `rotary`: the
+    positional function `(x [B, S, H, D], positions [B, S]) -> x` in place
+    of `rope` at `config.rope_theta`. `gate`: `sigmoid(x W_g)`, one number
+    a head (`wg` [d_model, H]) from the attention's own input, on each
+    head's output before `wo`. `window` > 0: a query sees the keys
+    `t - j < window`, and a decode call keeps the last rows in a ring
+    (`window_key`, `window_value` [B, ring_rows(window), Hkv, D], position
+    p at row `p % rows`: `ring` leaves to the serving engine) in place of
+    `cached_key` / `cached_value`. `query_block` > 0: a prefill from an
+    empty cache attends over its own tokens, that many queries at a time
+    (`own_token_attention`), and not over all `max_seq_len` rows of the
+    cache it has just made.
+
+    `count_mask` [B] marks the rows of a one-token decode call whose cache
+    reads are sown into `cache_stats` (`ATTENTION_READS`: rows live and
+    rows read, of the pool and of a ring)."""
+
     config: TransformerConfig
     decode: bool = False  # static: KV-cache path (see _ScanBody note)
+    n_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    rotary: Optional[Callable] = None
+    gate: bool = False
+    window: int = 0
+    query_block: int = 0
 
     @nn.compact
-    def __call__(self, x, positions, paged_ctx=None):
+    def __call__(self, x, positions, paged_ctx=None, count_mask=None):
         cfg = self.config
         decode = self.decode
         b, s, _ = x.shape
+        n_heads = self.n_heads or cfg.n_heads
+        head_dim = self.head_dim or cfg.head_dim
         with jax.named_scope("attention/qkv"):
-            q = LoraDense(cfg.n_heads * cfg.head_dim, (EMBED, HEADS), cfg, name="wq")(x)
-            k = LoraDense(cfg.n_kv_heads * cfg.head_dim, (EMBED, KV), cfg, name="wk")(x)
-            v = LoraDense(cfg.n_kv_heads * cfg.head_dim, (EMBED, KV), cfg, name="wv")(x)
-            q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
-            k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-            v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-        if decode and paged_ctx is not None:
+            q = LoraDense(n_heads * head_dim, (EMBED, HEADS), cfg, name="wq")(x)
+            k = LoraDense(cfg.n_kv_heads * head_dim, (EMBED, KV), cfg, name="wk")(x)
+            v = LoraDense(cfg.n_kv_heads * head_dim, (EMBED, KV), cfg, name="wv")(x)
+            q = q.reshape(b, s, n_heads, head_dim)
+            k = k.reshape(b, s, cfg.n_kv_heads, head_dim)
+            v = v.reshape(b, s, cfg.n_kv_heads, head_dim)
+        own_tokens = self.window or self.query_block
+        if decode and self.window:
+            # A window layer keeps a ring and no row past it: prefill,
+            # the one-token call on a dense cache and the paged step alike.
+            out = self._ring_decode(q, k, v, paged_ctx, count_mask)
+        elif decode and paged_ctx is not None:
             # Paged decode: the serving engine passed the KV block pool
             # (kv_pool collection) + per-slot tables and lengths. Rows
             # are the batch's slots, the s axis the one token (or the
@@ -264,6 +364,8 @@ class Attention(nn.Module):
             # for the plain read (`paged_ctx.kernel=False`) or refuses
             # (DecodeEngine.paged_spec_step).
             out = self._paged_decode(q, k, v, paged_ctx)
+            if count_mask is not None and s == 1:
+                self._sow_pool_reads(paged_ctx, count_mask)
         elif decode:
             # KV cache for autoregressive decoding: append this call's
             # keys/values at cache_index, attend against the whole cache
@@ -286,8 +388,9 @@ class Attention(nn.Module):
                     "head_dim**-0.5; attention_scale needs kv_cache_dtype="
                     "'bf16'"
                 )
-            cache_shape = (b, cfg.max_seq_len, cfg.n_kv_heads, cfg.head_dim)
+            cache_shape = (b, cfg.max_seq_len, cfg.n_kv_heads, head_dim)
             store_dtype = jnp.int8 if int8_cache else cfg.dtype
+            fresh = not self.has_variable("cache", "cache_index")
             cached_k = self.variable(
                 "cache", "cached_key", lambda: jnp.zeros(cache_shape, store_dtype)
             )
@@ -311,14 +414,12 @@ class Attention(nn.Module):
             idx = cache_index.value
             positions = idx + jnp.arange(s, dtype=jnp.int32)[None, :]
             positions = jnp.broadcast_to(positions, (b, s))
-            if cfg.use_rope:
-                q = rope(q, positions, cfg.rope_theta)
-                k = rope(k, positions, cfg.rope_theta)
+            q, k = self._rotate(q, positions), self._rotate(k, positions)
 
-            def _append(var, fresh):
+            def _append(var, rows):
                 with jax.named_scope("attention/kv_write"):
                     var.value = jax.lax.dynamic_update_slice(
-                        var.value, fresh, (0, idx, 0, 0)
+                        var.value, rows, (0, idx, 0, 0)
                     )
 
             if int8_cache:
@@ -340,7 +441,14 @@ class Attention(nn.Module):
                 _append(cached_k, k.astype(cfg.dtype))
                 _append(cached_v, v.astype(cfg.dtype))
             cache_index.value = idx + s
-            if int8_cache and s == 1:
+            if self.query_block and s > 1 and fresh and not int8_cache:
+                # A prefill from an empty cache: its own tokens are all the
+                # keys there are.
+                out = own_token_attention(
+                    q, k.astype(cfg.dtype), v.astype(cfg.dtype),
+                    softmax_scale=cfg.attention_scale,
+                    query_block=self.query_block)
+            elif int8_cache and s == 1:
                 # Steady-state decode: the pallas kernel streams the int8
                 # cache directly, dequantizing tile-by-tile in VMEM
                 # instead of materializing a full bf16 copy per token
@@ -373,14 +481,134 @@ class Attention(nn.Module):
                     softmax_scale=cfg.attention_scale,
                 )
         else:
-            if cfg.use_rope:
-                q = rope(q, positions, cfg.rope_theta)
-                k = rope(k, positions, cfg.rope_theta)
-            out = attention(q, k, v, impl=cfg.attention_impl, causal=True,
-                            softmax_scale=cfg.attention_scale)
+            q, k = self._rotate(q, positions), self._rotate(k, positions)
+            if own_tokens:
+                out = own_token_attention(
+                    q, k, v, window=self.window,
+                    softmax_scale=cfg.attention_scale,
+                    query_block=self.query_block or s)
+            else:
+                out = attention(q, k, v, impl=cfg.attention_impl, causal=True,
+                                softmax_scale=cfg.attention_scale)
+        if self.gate:
+            with jax.named_scope("attention/gate"):
+                w_gate = self.param(
+                    "wg", _partitioned((EMBED, HEADS))(
+                        nn.initializers.lecun_normal()),
+                    (x.shape[-1], n_heads), cfg.param_dtype)
+                gate = nn.sigmoid(jnp.einsum(
+                    "bsd,dh->bsh", x, w_gate.astype(cfg.dtype),
+                    preferred_element_type=jnp.float32))
+                out = out * gate[..., None]
         with jax.named_scope("attention/out"):
-            out = out.reshape(b, s, cfg.n_heads * cfg.head_dim)
+            out = out.astype(cfg.dtype).reshape(b, s, n_heads * head_dim)
             return LoraDense(cfg.d_model, (HEADS, EMBED), cfg, name="wo")(out)
+
+    @nn.nowrap
+    def _rotate(self, value, positions):
+        """The layer's positional function on q or k [B, S, H, D]."""
+        if self.rotary is not None:
+            return self.rotary(value, positions)
+        if self.config.use_rope:
+            return rope(value, positions, self.config.rope_theta)
+        return value
+
+    @nn.nowrap
+    def _sow_pool_reads(self, paged_ctx, count_mask):
+        """What the one-token paged step read of the pool, over the counted
+        slots: their live rows (this step's included), and those rounded up
+        to the kernel's loop trip, or the whole table on the plain read."""
+        from tf_yarn_tpu.ops.decode_attention import (
+            paged_chunk_tokens,
+            paged_kernel_serves,
+        )
+
+        pool = self.get_variable("kv_pool", "cached_key")[0]
+        block_size, max_blocks = pool.shape[1], paged_ctx.tables.shape[1]
+        kernel = paged_ctx.kernel
+        if kernel is None:
+            kernel = paged_kernel_serves(pool)
+        chunk = paged_chunk_tokens(block_size, max_blocks) if kernel \
+            else block_size * max_blocks
+        live = jnp.where(count_mask, paged_ctx.lengths.astype(jnp.int32) + 1, 0)
+        zero = jnp.zeros((), jnp.int32)
+        self.sow("cache_stats", "reads", jnp.stack([
+            jnp.sum(live), jnp.sum(-(-live // chunk) * chunk), zero, zero]))
+
+    @nn.nowrap
+    def _ring_decode(self, q, k, v, paged_ctx, count_mask):
+        """A window layer's decode call. More than one token is a prefill
+        from an empty cache: attend over the call's own tokens inside the
+        window and leave the last rows in the ring. One token (a row of a
+        dense cache at `cache_index`, or a slot of the paged step at its
+        length: the rings then lead with a slot axis over batch-1 rows):
+        the token's row into the ring at `p % rows`, then the ring's rows
+        that lie inside the window."""
+        cfg = self.config
+        b, s, n_kv, head_dim = k.shape
+        rows = ring_rows(self.window)
+        if s != 1 and (paged_ctx is not None
+                       or self.has_variable("cache", "cache_index")):
+            raise NotImplementedError(
+                f"a window layer of {type(self).__name__} reads one token a "
+                f"slot from its ring; a window of {s} tokens over a cache "
+                "that is already there (speculation, chunked prefill) does "
+                "not carry the ring"
+            )
+        index_var = None
+        if paged_ctx is not None:
+            lengths = paged_ctx.lengths.astype(jnp.int32)
+        else:
+            index_var = self.variable("cache", "cache_index",
+                                      lambda: jnp.zeros((), jnp.int32))
+            lengths = jnp.broadcast_to(
+                index_var.value if s == 1 else 0, (b,)).astype(jnp.int32)
+        positions = lengths[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+        q, k = self._rotate(q, positions), self._rotate(k, positions)
+        k, v = k.astype(cfg.dtype), v.astype(cfg.dtype)
+        if s != 1:
+            kept = min(s, rows)
+            at = jnp.arange(s - kept, s) % rows
+            with jax.named_scope("attention/ring_write"):
+                for name, value in (("window_key", k), ("window_value", v)):
+                    ring = jnp.zeros((b, rows, n_kv, head_dim), cfg.dtype).at[
+                        :, at].set(value[:, s - kept:])
+                    self.variable("cache", name, lambda r=ring: r).value = ring
+            index_var.value = jnp.asarray(s, jnp.int32)
+            return own_token_attention(
+                q, k, v, window=self.window,
+                softmax_scale=cfg.attention_scale,
+                query_block=self.query_block or s)
+        held = {}
+        with jax.named_scope("attention/ring_write"):
+            for name, value in (("window_key", k), ("window_value", v)):
+                var = self.variable(
+                    "cache", name,
+                    lambda: jnp.zeros((b, rows, n_kv, head_dim), cfg.dtype))
+                held[name] = var.value.reshape(b, rows, n_kv, head_dim).at[
+                    jnp.arange(b), lengths % rows].set(value[:, 0])
+                var.value = held[name].reshape(var.value.shape)
+        if index_var is not None:
+            index_var.value = index_var.value + 1
+        with jax.named_scope("attention/window_read"):
+            valid = ring_valid(lengths, rows, self.window)
+            scale = head_dim ** -0.5 if cfg.attention_scale is None \
+                else cfg.attention_scale
+            grouped = q[:, 0].reshape(b, n_kv, -1, head_dim)
+            scores = jnp.einsum("bgrd,bkgd->bgrk", grouped, held["window_key"],
+                                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            out = jnp.einsum("bgrk,bkgd->bgrd", probs, held["window_value"],
+                             preferred_element_type=jnp.float32)
+        if count_mask is not None:
+            zero = jnp.zeros((), jnp.int32)
+            self.sow("cache_stats", "reads", jnp.stack([
+                zero, zero,
+                jnp.sum(jnp.where(
+                    count_mask, jnp.minimum(lengths + 1, self.window), 0)),
+                jnp.sum(count_mask, dtype=jnp.int32) * rows]))
+        return out.reshape(b, 1, -1, head_dim)
 
     @nn.nowrap  # a helper of __call__: no scope of its own on the operations
     def _paged_decode(self, q, k, v, paged_ctx):
@@ -421,9 +649,7 @@ class Attention(nn.Module):
         positions = (
             lengths[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
         )
-        if cfg.use_rope:
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+        q, k = self._rotate(q, positions), self._rotate(k, positions)
 
         def _missing():
             raise ValueError(

@@ -1893,7 +1893,8 @@ class SlotScheduler:
         assignments over all the deployment's experts, then the tokens
         that reached each expert held here (and, of a router with
         zero-compute experts, the assignments to those last). A layer-step
-        is one expert layer in one step."""
+        is one expert layer in one step. `/stats` shows the tally as
+        `moe_*`, `moe_tokens_by_expert` one number a held expert."""
         tally = self._moe
         if getattr(self.engine.model.config, "num_zero_experts", 0):
             tally["assignments_zero"] = tally.get("assignments_zero", 0) \
@@ -1906,6 +1907,9 @@ class SlotScheduler:
         tally["experts_touched"] += int((load > 0).sum())
         tally["load_max_sum"] += int(load.max(axis=1).sum())
         tally["load_mean_sum"] += float(load.mean(axis=1).sum())
+        # The tokens that reached each held expert, over layers and steps.
+        tally["tokens_by_expert"] = tally.get("tokens_by_expert", 0) \
+            + load.sum(axis=0)
 
     def _observe_ttft(self, state) -> None:
         # The unlabeled histogram is the back-compat aggregate; the
@@ -2394,7 +2398,9 @@ class SlotScheduler:
              for name, value in self._cache_reads.items()})
         if self._moe["layer_steps"]:
             tally = self._moe
-            snap.update({"moe_" + key: value for key, value in tally.items()})
+            snap.update({
+                "moe_" + key: value.tolist() if hasattr(value, "tolist")
+                else value for key, value in tally.items()})
             snap["moe_load_max_over_mean"] = round(
                 tally["load_max_sum"] / tally["load_mean_sum"], 4
             ) if tally["load_mean_sum"] else None

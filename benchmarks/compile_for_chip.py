@@ -21,7 +21,8 @@ Nothing runs, so nothing here is a result or a time of the device. Programs:
     tp4     prefill and paged step of a tp=4 replica (bf16, plain read)
     latent  the benchmark's dots3_note share at its own sizes
             (cellbench/configs/dots3_note_serve_1chip.json): prefill at the
-            2048 and 4096 buckets, pack, and the 64-slot paged state step
+            2048 and 4096 buckets (told the prompt's length, as a model
+            with rings is), pack, and the 64-slot paged state step
             (latent and index rows off the pool, rings held once a slot)
     longcat the benchmark's LongCat-Flash share at its own sizes
             (cellbench/configs/longcat_flash_serve_1chip.json): prefill at
@@ -31,9 +32,10 @@ Nothing runs, so nothing here is a result or a time of the device. Programs:
             (every leaf paged, the latent rows read by the paged kernel)
     laguna  the benchmark's Laguna-XS.2 stage at its own sizes
             (cellbench/configs/laguna_xs2_serve_1chip.json): prefill at the
-            512, 1024 and 2048 buckets (1024 and 2048: the tokens sorted by
-            expert over all 256 of a layer), pack, and the 64-slot paged
-            state step
+            512, 1024, 2048 and 4096 buckets (from 1024 on the tokens sorted
+            by expert over all 256 of a layer; 4096: what an admission of a
+            2050-4096 token prompt runs, told the prompt's length), pack,
+            and the 64-slot paged state step
             (the full layers' keys and values read by the paged kernel at 6
             query heads a KV head, the sliding layers' rings held once a
             slot)
@@ -271,10 +273,12 @@ def compile_latent(devices, name="latent",
     state = place(jax.tree_util.tree_map(
         lambda a, lay: jax.ShapeDtypeStruct((slots,) + a.shape, a.dtype)
         if lay.kind in de.HELD_A_SLOT else None, row, layout))
+    # A model with rings is told where the prompt ends: a traced scalar.
+    length = (arg(jnp.int32),) if de.takes_prompt_len(model) else ()
     for bucket in buckets:
         began = time.monotonic()
         prompt = arg(jnp.int32, 1, bucket)
-        compiled = jax.jit(prefill).lower(params, prompt).compile()
+        compiled = jax.jit(prefill).lower(params, prompt, *length).compile()
         report(f"{name} prefill[{bucket}]", compiled, began, False)
     began = time.monotonic()
     compiled = jax.jit(
@@ -325,7 +329,8 @@ def main(argv) -> int:
             one, "longcat", "longcat_flash_serve_1chip", (512, 1024, 2048),
             True),
         "laguna": lambda: compile_latent(
-            one, "laguna", "laguna_xs2_serve_1chip", (512, 1024, 2048), True),
+            one, "laguna", "laguna_xs2_serve_1chip",
+            (512, 1024, 2048, 4096), True),
     }
     for name in argv or list(programs):
         programs[name]()

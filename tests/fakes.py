@@ -47,7 +47,7 @@ class FakePagedEngine:
         self.calls.append(("make_pool", num_blocks, block_size))
         return np.zeros((num_blocks, block_size), np.int64)
 
-    def prefill(self, params, prompt):
+    def prefill(self, params, prompt, length=None):
         self.calls.append(("prefill", prompt.shape))
         return np.asarray(prompt[0], np.int64), None
 
@@ -199,9 +199,9 @@ class FakeAsyncEngine(FakePagedEngine):
         self.free_at = max(self.free_at, time.perf_counter()) + seconds
         return self.free_at
 
-    def prefill(self, params, prompt):
+    def prefill(self, params, prompt, length=None):
         self._enqueue(self.prefill_s)
-        return super().prefill(params, prompt)
+        return super().prefill(params, prompt, length)
 
     def paged_step(self, params, pool, tables, lengths, emitted, rngs,
                    *args, **kwargs):
@@ -237,7 +237,7 @@ def admit_prefill(engine, params, pool, prompt, blocks, block_size,
         return pool, None, 0, 0
     tokens = np.full((1, bucket), pad, np.int32)
     tokens[0, :kept] = np.asarray(prompt[:kept])
-    row, _logits = engine.prefill(params, tokens)
+    row, _logits = engine.prefill(params, tokens, kept)
     owned = -(-kept // block_size)
     ids = np.zeros((-(-bucket // block_size),), np.int32)
     ids[:owned] = np.asarray(blocks[:owned])

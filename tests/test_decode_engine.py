@@ -31,6 +31,7 @@ from tf_yarn_tpu.models.decode_engine import (
     paged_pool_avals,
 )
 from tf_yarn_tpu.models.generate import generate, generate_legacy
+from tf_yarn_tpu.serving.request import SamplingParams
 from tests.fakes import admit_prefill
 
 
@@ -522,6 +523,83 @@ def test_only_a_model_whose_prompt_rows_are_causal_takes_the_ceiling_rule():
         config = model.config
 
     assert _engine(Silent()).ceiling_prefill(params) is False
+
+
+def _ring_model(kind):
+    """Tiny models that hold leaves once a slot, and what they say."""
+    from tf_yarn_tpu.models import hybrid, laguna, latent
+
+    class Silent(laguna.LagunaLM):
+        prompt_rows_causal = False
+
+    class RingAsState(laguna.LagunaLM):
+        def cache_leaf_kinds(self):
+            return {**super().cache_leaf_kinds(), "window_key": ("slot", None)}
+
+    class Untold(laguna.LagunaLM):
+        def __call__(self, tokens, decode=False, count_mask=None,
+                     paged_ctx=None):
+            return laguna.LagunaLM.__call__(
+                self, tokens, decode=decode, count_mask=count_mask,
+                paged_ctx=paged_ctx)
+
+    class CausalHybrid(hybrid.HybridLM):
+        prompt_rows_causal = True
+
+    grouped = laguna.LagunaConfig.tiny(dtype=jnp.float32)
+    mixed = hybrid.HybridConfig.tiny(dtype=jnp.float32)
+    return {
+        "rings of keys and values": lambda: laguna.LagunaLM(grouped),
+        "a ring of latents": lambda: latent.LatentLM(
+            latent.LatentConfig.tiny(dtype=jnp.float32)),
+        "rings, and says nothing": lambda: Silent(grouped),
+        "a ring declared as state": lambda: RingAsState(grouped),
+        "rings, and no length in its call": lambda: Untold(grouped),
+        "a state and a tail": lambda: hybrid.HybridLM(mixed),
+        "a state and a tail, and says causal": lambda: CausalHybrid(mixed),
+    }[kind]()
+
+
+@pytest.mark.parametrize("kind,held,want", [
+    ("rings of keys and values", ("window_key", "window_value"), True),
+    ("a ring of latents", ("window_latent",), True),
+    ("rings, and says nothing", ("window_key", "window_value"), False),
+    ("a ring declared as state", ("window_key", "window_value"), False),
+    ("rings, and no length in its call", ("window_key", "window_value"),
+     False),
+    ("a state and a tail", ("conv_state", "ssm_state"), False),
+    ("a state and a tail, and says causal", ("conv_state", "ssm_state"),
+     False),
+])
+def test_a_model_that_holds_leaves_takes_the_ceiling_by_their_kind(
+        kind, held, want):
+    """The ceiling rule for a model that holds leaves once a slot: it says
+    its prompt rows are causal, every such leaf is a `ring`, and its call
+    takes the prompt's length so that the ring is written where the prompt
+    ends. A `slot` leaf (a state, a convolution's tail) keeps the floor
+    whatever the model says; nothing is read off a name."""
+    model = _ring_model(kind)
+    params = nn.meta.unbox(
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    engine = DecodeEngine(model, prompt_buckets=(8, 16, 32))
+    assert engine.slot_state_leaves(params) == held
+    assert engine.ceiling_prefill(params) is want
+    # the scheduler takes the engine's word
+    from tf_yarn_tpu.serving.scheduler import SlotScheduler
+
+    scheduler = SlotScheduler(engine, params, max_slots=2, block_size=8)
+    response = scheduler.submit(list(range(1, 12)), SamplingParams(
+        max_new_tokens=2))
+    for _ in range(50):
+        if response.done:
+            break
+        scheduler.tick()
+    stats = scheduler.stats()
+    scheduler.close()
+    # 11 tokens: 10 kept of 16 and one replayed, or 8 whole and 3 replayed
+    assert (stats["prefills_ceiling"], stats["prefills_floor"],
+            stats["prefill_pad_tokens"], stats["prefill_tokens"]) == (
+        (1, 0, 6, 1) if want else (0, 1, 0, 3))
 
 
 _CEILING_BLOCK = 4

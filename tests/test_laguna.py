@@ -89,6 +89,32 @@ def test_full_forward_matches_reference(tiny, length):
     np.testing.assert_allclose(np.asarray(got), want, atol=TOLERANCE, rtol=0)
 
 
+# The prompt ends inside the first block of 8 queries, at its end, inside a
+# later block, and at the call's end (21 rows: three blocks, the last short).
+@pytest.mark.parametrize("prompt_len", [1, 8, 13, 21])
+@pytest.mark.parametrize("window", [0, 5])
+def test_query_blocks_past_the_prompt_are_not_computed(prompt_len, window):
+    """Told where the prompt ends, a prefill's attention over its own
+    tokens gives the prompt's rows what it gives them untold, and leaves
+    the blocks that hold only pad zero."""
+    from tf_yarn_tpu.models.transformer import own_token_attention
+
+    rng = np.random.default_rng(prompt_len)
+    s, block = 21, 8
+    q = jnp.asarray(rng.normal(size=(2, s, 4, 16)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, s, 2, 16)), jnp.float32)
+            for _ in range(2))
+    untold = np.asarray(own_token_attention(
+        q, k, v, window=window, query_block=block))
+    told = np.asarray(jax.jit(lambda n: own_token_attention(
+        q, k, v, window=window, query_block=block, prompt_len=n))(prompt_len))
+    computed = min(s, -(-prompt_len // block) * block)
+    # outputs of magnitude 3, float32 sums in another order
+    np.testing.assert_allclose(told[:, :computed], untold[:, :computed],
+                               atol=1e-5, rtol=0)
+    assert not told[:, computed:].any() and untold[:, -1].any()
+
+
 def test_yarn_ramp_is_the_published_one():
     """low / high = 5 / 16 at the published values, in the program and in
     the reference; both give the same frequencies."""
@@ -131,8 +157,8 @@ class _Grid:
     def admit(self, slot, prompt):
         variables = self.tiny["variables"]
         blocks = 1 + slot * self.per_slot + np.arange(self.per_slot)
-        # The floor rule: a ring is what a prefill left at the END of its
-        # bucket.
+        # The ceiling rule: the prefill is told where the prompt ends in
+        # its bucket and writes the rings from the rows that end there.
         self.pool, row, _bucket, prefill = admit_prefill(
             self.engine, variables, self.pool, prompt, blocks, BLOCK,
             self.engine.ceiling_prefill(variables))
@@ -167,12 +193,21 @@ class _Grid:
         return prefill, np.stack(rows)
 
 
+def _kept(prompt_len):
+    """The rows an admission keeps: all of the prompt but its last token in
+    the bucket above (the ceiling rule), or, past the largest bucket, the
+    bucket below, whole."""
+    kept = prompt_len - 1
+    return kept if 0 < kept <= BUCKETS[-1] else \
+        max([b for b in BUCKETS if b <= kept], default=0)
+
+
 # Prompt lengths on, just over and just under a prefill bucket (8, 16, 32),
-# the window (8) and the ring (16); 5 and 8 prefill nothing and start from
-# zeroed rings. Each decodes 19 more: past the window and, from 9 on, past a
-# turn of the ring.
-@pytest.mark.parametrize("prompt_len", [5, 8, 9, 10, 16, 17, 18, 31, 32, 33,
-                                        41])
+# the window (8) and the ring (16); 1 prefills nothing and starts from zeroed
+# rings; 41 has no bucket above it and keeps the one below. Each decodes 19
+# more: past the window and, from 9 on, past a turn of the ring.
+@pytest.mark.parametrize("prompt_len", [1, 5, 8, 9, 10, 16, 17, 18, 31, 32,
+                                        33, 41])
 def test_prefill_replay_decode_match_reference(tiny, prompt_len):
     """Bucketed prefill into the pool and the slot's rings, then replay and
     decode a token a step through the paged step (the pool read on the full
@@ -182,9 +217,88 @@ def test_prefill_replay_decode_match_reference(tiny, prompt_len):
         0, 256, prompt_len + 19)
     grid = _Grid(tiny)
     prefill, got = grid.run(1, sequence, prompt_len)
-    assert prefill == max([b for b in BUCKETS if b < prompt_len], default=0)
+    assert prefill == _kept(prompt_len)
+    assert prefill == {1: 0, 41: 32}.get(prompt_len, prompt_len - 1)
     want = _reference_logits(tiny, sequence, np.arange(prefill, len(sequence)))
     np.testing.assert_allclose(got, want, atol=TOLERANCE, rtol=0)
+
+
+def _rings(row):
+    """name -> the ring leaves of a prefill's row cache, in layer order."""
+    found = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(row):
+        name = getattr(path[-1], "key", str(path[-1]))
+        if name in ("window_key", "window_value"):
+            found.setdefault(name, []).append(np.asarray(leaf))
+    return found
+
+
+# Rows kept below, at and above the ring of 16, in a bucket under the ring
+# (8), the ring's size (16) and over it (32), with and without pad.
+@pytest.mark.parametrize("kept,bucket", [
+    (5, 8), (8, 8), (9, 16), (15, 16), (16, 16), (17, 32), (24, 32), (32, 32)])
+def test_a_padded_prefill_leaves_the_ring_of_the_prompt_alone(
+        tiny, kept, bucket):
+    """The rings after a prefill of `kept` tokens padded to their bucket
+    are, row for row, the rings after a prefill of exactly `kept` tokens
+    (an engine whose one bucket is `kept`): position p at row p % 16 for
+    the last 16 positions below `kept`, zero where there is none, and
+    nothing that changes with what the pad holds."""
+    engine, variables = tiny["engine"], tiny["variables"]
+    prompt = np.random.default_rng(kept).integers(1, 256, kept)
+    assert engine.slot_prefill_len(kept + 1, True) == (bucket, kept)
+    padded = {}
+    for pad in (0, 255):
+        tokens = np.full((1, bucket), pad, np.int32)
+        tokens[0, :kept] = prompt
+        padded[pad] = _rings(engine.prefill(variables, tokens, kept)[0])
+    exact = _rings(DecodeEngine(tiny["model"], prompt_buckets=(kept,)).prefill(
+        variables, prompt[None].astype(np.int32))[0])
+    assert sorted(exact) == ["window_key", "window_value"]
+    held = np.zeros((RING,), bool)
+    held[np.arange(max(0, kept - RING), kept) % RING] = True
+    for name, layers in exact.items():
+        assert len(layers) == 3
+        for layer, want in enumerate(layers):
+            assert want.shape == (1, RING, 2, 16)
+            np.testing.assert_array_equal(
+                padded[0][name][layer], padded[255][name][layer])
+            np.testing.assert_allclose(
+                padded[0][name][layer], want, atol=1e-5, rtol=0)
+            rows = np.abs(padded[0][name][layer][0]).sum(axis=(1, 2)) > 0
+            np.testing.assert_array_equal(rows, held)
+
+
+class _FloorEngine(DecodeEngine):
+    """The parent's rule for a model with rings: the bucket below, whole."""
+
+    def ceiling_prefill(self, params):
+        return False
+
+
+def test_ceiling_streams_equal_floor_streams(tiny):
+    """Greedy streams of requests admitted under the ceiling rule (one
+    token replayed) equal those of the same requests under the floor rule
+    (the bucket below, the rest replayed): 41 has no bucket above it and
+    takes the floor under both."""
+    rng = np.random.default_rng(43)
+    prompts = [rng.integers(0, 256, n) for n in (5, 10, 17, 18, 31, 33, 41, 24)]
+    streams = {}
+    for rule, engine in (
+            ("ceiling", tiny["engine"]),
+            ("floor", _FloorEngine(tiny["model"], prompt_buckets=BUCKETS))):
+        scheduler = _scheduler(tiny, engine, max_slots=3)
+        streams[rule] = _serve(scheduler, prompts, new_tokens=12)
+        stats = scheduler.stats()
+        scheduler.close()
+        assert stats["prefills_ceiling"] == (7 if rule == "ceiling" else 0)
+        # (the floor rule finds no bucket under 5's four rows: no prefill)
+        assert stats["prefills_floor"] == (1 if rule == "ceiling" else 7)
+        # one token a request replays, but for 41's 9; the floor replays
+        # every token past the bucket below
+        assert stats["prefill_tokens"] == (
+            7 + 9 if rule == "ceiling" else 5 + 2 + 1 + 2 + 15 + 1 + 9 + 8)
+    assert streams["ceiling"] == streams["floor"]
 
 
 def test_a_sequence_far_past_window_and_ring_reads_nothing_stale(tiny):
@@ -217,22 +331,27 @@ def test_slots_step_together_and_a_reused_slot_starts_clean(tiny):
     # two active slots, four expert layers (layer 0 is dense), top 2 of 16
     assert counts.shape == (4, 1 + 16) and (counts[:, 0] == 4).all()
     assert (counts[:, 1:].sum(axis=1) == 4).all()
-    # the last step: slot 0 at 51 + 1 live rows, slot 2 at 27 + 1; two full
+    # the last step: slot 0 at 51 + 1 live rows (39 rows have no bucket
+    # above: 32 kept), slot 2 at 29 + 1 (10 kept of 16); two full
     # layers read the whole table of 128 (the plain read, off the TPU); three
     # window layers read their ring of 16, 8 rows of it in the window
     assert reads == {
-        "pool_live": 2 * (52 + 28), "pool_read": 2 * 2 * CONTEXT,
+        "pool_live": 2 * (52 + 30), "pool_read": 2 * 2 * CONTEXT,
         "window_live": 3 * (8 + 8), "window_read": 3 * 2 * RING}
     for got, sequence, start in ((got1, first, p1), (got2, second, p2)):
         want = _reference_logits(tiny, sequence[:start + 20],
                                  np.arange(start, start + 20))
         np.testing.assert_allclose(np.stack(got), want, atol=TOLERANCE, rtol=0)
     grid.retire(0)
-    _, reused = grid.run(0, third, 6)        # nothing prefilled: zero rings
-    _, alone = _Grid(tiny).run(0, third, 6)
-    np.testing.assert_allclose(reused, alone, atol=1e-6, rtol=0)
-    want = _reference_logits(tiny, third, np.arange(0, len(third)))
-    np.testing.assert_allclose(reused, want, atol=TOLERANCE, rtol=0)
+    # 5 rows kept of a bucket of 8: the ring's other rows are written zero;
+    # then nothing prefilled: zero rings
+    for prompt_len in (6, 1):
+        kept, reused = grid.run(0, third, prompt_len)
+        _, alone = _Grid(tiny).run(0, third, prompt_len)
+        np.testing.assert_allclose(reused, alone, atol=1e-6, rtol=0)
+        want = _reference_logits(tiny, third, np.arange(kept, len(third)))
+        np.testing.assert_allclose(reused, want, atol=TOLERANCE, rtol=0)
+        grid.retire(0)
 
 
 def _swapped(sizes):
@@ -445,7 +564,8 @@ def test_leaves_are_declared_and_a_ring_does_not_grow_with_context(tiny):
     }
     engine = tiny["engine"]
     assert engine.slot_state_leaves(variables) == ("window_key", "window_value")
-    assert engine.ceiling_prefill(variables) is False
+    # every leaf held once a slot is a ring, written where the prompt ends
+    assert engine.ceiling_prefill(variables) is True
     assert engine.counted_step(variables) is True
 
     def by_kind(context):
@@ -501,7 +621,7 @@ def test_scheduler_serves_through_reused_slots(tiny):
     assert stats["state_leaves"] == ["window_key", "window_value"]
     assert (stats["prefills_ceiling"], stats["prefills_floor"],
             stats["prefill_pad_tokens"], stats["prefilled_tokens"],
-            stats["prefill_tokens"]) == (0, 4, 0, 88, 1 + 8 + 5 + 1 + 1)
+            stats["prefill_tokens"]) == (4, 1, 4, 92, 1 + 8 + 1 + 1 + 1)
     assert stats["state_resets"] == 5 and stats["prefix_skipped_stateful"] == 5
     assert stats["prefix_cache"]["entries"] == 0
     assert stats["block_pool"]["used_blocks"] == 0
@@ -540,7 +660,7 @@ def test_same_prompt_twice_gets_no_prefix_hit(tiny):
     first, second = _serve(scheduler, [prompt]), _serve(scheduler, [prompt])
     assert first == second
     assert scheduler.stats()["prefix_skipped_stateful"] == 2
-    assert scheduler.stats()["prefilled_tokens"] == 2 * 16
+    assert scheduler.stats()["prefilled_tokens"] == 2 * 23
     scheduler.close()
 
 

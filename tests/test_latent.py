@@ -127,6 +127,32 @@ def test_absorbed_path_matches_expanded():
     assert np.abs(np.asarray(windowed - want)).max() > 1e-2
 
 
+# The prompt ends inside the first block of 8 queries, at its end, inside a
+# later block, and at the call's end (21 rows: three blocks, the last short).
+@pytest.mark.parametrize("prompt_len", [1, 8, 13, 21])
+@pytest.mark.parametrize("window", [0, 5])
+def test_query_blocks_past_the_prompt_are_not_computed(prompt_len, window):
+    """Told where the prompt ends, the expanded path gives the prompt's rows
+    what it gives them untold, and leaves the blocks that hold only pad
+    zero: their attention is not paid for."""
+    sizes = latent.AttentionSizes(4, 32, 16, 16, 8, 16, 8e7)
+    rng = np.random.default_rng(prompt_len)
+    s, block = 21, 8
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    q_n, q_r = normal(2, s, 4, 16), normal(2, s, 4, 8)
+    rows, w_kvb = normal(2, s, 24), normal(16, 4, 32) / 4
+    about = dict(window=window, query_block=block, dtype=jnp.float32)
+    untold = np.asarray(latent.expanded_attention(
+        q_n, q_r, rows, w_kvb, sizes, **about))
+    told = np.asarray(jax.jit(lambda n: latent.expanded_attention(
+        q_n, q_r, rows, w_kvb, sizes, prompt_len=n, **about))(prompt_len))
+    computed = min(s, -(-prompt_len // block) * block)
+    # outputs of magnitude 3, float32 sums in another order
+    np.testing.assert_allclose(told[:, :computed], untold[:, :computed],
+                               atol=1e-5, rtol=0)
+    assert not told[:, computed:].any() and untold[:, -1].any()
+
+
 def test_top_k_mask_is_top_k_with_its_ties():
     """The mask the expanded path keeps is `jax.lax.top_k`'s set, of equal
     scores the earlier, also where fewer than k keys are live."""
@@ -231,8 +257,9 @@ class _Grid:
     def admit(self, slot, prompt):
         variables = self.tiny["variables"]
         blocks = 1 + slot * self.per_slot + np.arange(self.per_slot)
-        # The rule the engine reads off this model: the floor (the ring is
-        # what a prefill left at the END of its bucket).
+        # The rule the engine reads off this model: the ceiling (the
+        # prefill is told where the prompt ends in its bucket and writes
+        # the ring from the rows that end there).
         self.pool, row, _bucket, prefill = admit_prefill(
             self.engine, variables, self.pool, prompt, blocks, BLOCK,
             self.engine.ceiling_prefill(variables))
@@ -273,12 +300,23 @@ class _Grid:
         return prefill, np.stack(rows)
 
 
+def _kept(prompt_len):
+    """The rows an admission keeps: all of the prompt but its last token in
+    the bucket above (the ceiling rule), or, past the largest bucket, the
+    bucket below, whole."""
+    kept = prompt_len - 1
+    return kept if 0 < kept <= BUCKETS[-1] else \
+        max([b for b in BUCKETS if b <= kept], default=0)
+
+
 # Prompt lengths on, just over and just under a prefill bucket (8, 16, 32:
-# the prefill takes the largest bucket below the length), the window (9: 8,
-# 9, 10) and `index_topk` (24: the 25th token is the first to select); 5 and
-# 8 prefill nothing and start from zeroed rings. Each decodes 9 more.
-@pytest.mark.parametrize("prompt_len", [5, 8, 9, 10, 15, 16, 17, 23, 24, 25,
-                                        26, 31, 32, 33, 41])
+# the prefill takes the bucket above the length less one and keeps that many
+# rows), the window (9: 8, 9, 10) and `index_topk` (24: the 25th token is the
+# first to select, and the bucket of 32 selects at prefill, over its pad); 1
+# prefills nothing and starts from zeroed rings; 41 has no bucket above it and
+# keeps the one below. Each decodes 9 more.
+@pytest.mark.parametrize("prompt_len", [1, 5, 8, 9, 10, 15, 16, 17, 23, 24,
+                                        25, 26, 31, 32, 33, 41])
 def test_prefill_replay_decode_match_reference(tiny, prompt_len):
     """Bucketed prefill (the expanded path) into the pool and the slot's
     rings, then replay and decode a token a step through the paged step (the
@@ -287,9 +325,82 @@ def test_prefill_replay_decode_match_reference(tiny, prompt_len):
     sequence = np.random.default_rng(prompt_len).integers(0, 256, prompt_len + 9)
     grid = _Grid(tiny)
     prefill, got = grid.run(1, sequence, prompt_len)
-    assert prefill == max([b for b in BUCKETS if b < prompt_len], default=0)
+    assert prefill == _kept(prompt_len)
+    assert prefill == {1: 0, 41: 32}.get(prompt_len, prompt_len - 1)
     want = _reference_logits(tiny, sequence, np.arange(prefill, len(sequence)))
     np.testing.assert_allclose(got, want, atol=TOLERANCE, rtol=0)
+
+
+def _rings(row):
+    """The ring leaves of a prefill's row cache, in layer order."""
+    return [np.asarray(leaf)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(row)
+            if getattr(path[-1], "key", str(path[-1])) == "window_latent"]
+
+
+# Rows kept below, at and above the ring of 16, in a bucket under the ring
+# (8), the ring's size (16) and over it (32: the prefill that selects), with
+# and without pad.
+@pytest.mark.parametrize("kept,bucket", [
+    (5, 8), (8, 8), (9, 16), (15, 16), (16, 16), (17, 32), (24, 32), (32, 32)])
+def test_a_padded_prefill_leaves_the_ring_of_the_prompt_alone(
+        tiny, kept, bucket):
+    """The rings after a prefill of `kept` tokens padded to their bucket
+    are, row for row, the rings after a prefill of exactly `kept` tokens
+    (an engine whose one bucket is `kept`): position p at row p % 16 for
+    the last 16 positions below `kept`, zero where there is none, and
+    nothing that changes with what the pad holds."""
+    engine, variables = tiny["engine"], tiny["variables"]
+    prompt = np.random.default_rng(kept).integers(1, 256, kept)
+    assert engine.slot_prefill_len(kept + 1, True) == (bucket, kept)
+    padded = {}
+    for pad in (0, 255):
+        tokens = np.full((1, bucket), pad, np.int32)
+        tokens[0, :kept] = prompt
+        padded[pad] = _rings(engine.prefill(variables, tokens, kept)[0])
+    exact = _rings(DecodeEngine(tiny["model"], prompt_buckets=(kept,)).prefill(
+        variables, prompt[None].astype(np.int32))[0])
+    held = np.zeros((RING,), bool)
+    held[np.arange(max(0, kept - RING), kept) % RING] = True
+    assert len(exact) == 3  # the three sliding layers
+    for layer, want in enumerate(exact):
+        assert want.shape[:2] == (1, RING)
+        np.testing.assert_array_equal(padded[0][layer], padded[255][layer])
+        np.testing.assert_allclose(padded[0][layer], want, atol=1e-5, rtol=0)
+        rows = np.abs(padded[0][layer][0]).sum(axis=1) > 0
+        np.testing.assert_array_equal(rows, held)
+
+
+class _FloorEngine(DecodeEngine):
+    """The parent's rule for a model with rings: the bucket below, whole."""
+
+    def ceiling_prefill(self, params):
+        return False
+
+
+def test_ceiling_streams_equal_floor_streams(tiny):
+    """Greedy streams of requests admitted under the ceiling rule (one
+    token replayed; 26 to 33 select at prefill, over the pad) equal those
+    of the same requests under the floor rule (the bucket below, the rest
+    replayed): 41 has no bucket above it and takes the floor under both."""
+    rng = np.random.default_rng(43)
+    prompts = [rng.integers(0, 256, n) for n in (5, 10, 17, 18, 26, 31, 33, 41)]
+    streams = {}
+    for rule, engine in (
+            ("ceiling", tiny["engine"]),
+            ("floor", _FloorEngine(tiny["model"], prompt_buckets=BUCKETS))):
+        scheduler = _scheduler(tiny, engine, max_slots=3)
+        streams[rule] = _serve(scheduler, prompts, new_tokens=12)
+        stats = scheduler.stats()
+        scheduler.close()
+        # (the floor rule finds no bucket under 5's four rows: no prefill)
+        assert (stats["prefills_ceiling"], stats["prefills_floor"]) == (
+            (7, 1) if rule == "ceiling" else (0, 7))
+        # one token a request replays, but for 41's 9; the floor replays
+        # every token past the bucket below
+        assert stats["prefill_tokens"] == (
+            7 + 9 if rule == "ceiling" else 5 + 2 + 1 + 2 + 10 + 15 + 1 + 9)
+    assert streams["ceiling"] == streams["floor"]
 
 
 def test_a_sequence_far_past_window_and_ring_reads_nothing_stale(tiny):
@@ -323,12 +434,13 @@ def test_slots_step_together_and_a_reused_slot_starts_clean(tiny):
         got2.append(logits[2])
     # two active slots, four expert layers (layer 0 is dense), top 3
     assert counts.shape == (4, 1 + 8) and (counts[:, 0] == 6).all()
-    # the last step: slot 0 at 41 + 1 live rows, slot 2 at 17 + 1; two full
+    # the last step: slot 0 at 41 + 1 live rows (39 rows have no bucket
+    # above: 32 kept), slot 2 at 19 + 1 (10 kept of 16); two full
     # layers select min(live, 24) and gather 24 rows a slot; the index keys
     # go a chunk of 32 at a time as far as the longest slot reaches (64);
     # three window layers read their ring of 16, 9 rows of it in the window
     assert reads == {
-        "index_live": 2 * (42 + 18), "index_selected": 2 * (24 + 18),
+        "index_live": 2 * (42 + 20), "index_selected": 2 * (24 + 20),
         "index_read": 2 * 2 * 64, "latent_read": 2 * 2 * 24,
         "window_live": 3 * (9 + 9), "window_read": 3 * 2 * 16}
     for got, sequence, start in ((got1, first, p1), (got2, second, p2)):
@@ -336,11 +448,15 @@ def test_slots_step_together_and_a_reused_slot_starts_clean(tiny):
                                  np.arange(start, start + 10))
         np.testing.assert_allclose(np.stack(got), want, atol=TOLERANCE, rtol=0)
     grid.retire(0)
-    _, reused = grid.run(0, third, 6)        # nothing prefilled: zero rings
-    _, alone = _Grid(tiny).run(0, third, 6)
-    np.testing.assert_allclose(reused, alone, atol=1e-6, rtol=0)
-    want = _reference_logits(tiny, third, np.arange(0, len(third)))
-    np.testing.assert_allclose(reused, want, atol=TOLERANCE, rtol=0)
+    # 5 rows kept of a bucket of 8: the ring's other rows are written zero;
+    # then nothing prefilled: zero rings
+    for prompt_len in (6, 1):
+        kept, reused = grid.run(0, third, prompt_len)
+        _, alone = _Grid(tiny).run(0, third, prompt_len)
+        np.testing.assert_allclose(reused, alone, atol=1e-6, rtol=0)
+        want = _reference_logits(tiny, third, np.arange(kept, len(third)))
+        np.testing.assert_allclose(reused, want, atol=TOLERANCE, rtol=0)
+        grid.retire(0)
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -474,7 +590,8 @@ def test_leaves_are_declared_and_a_ring_does_not_grow_with_context(tiny):
     }
     engine = DecodeEngine(model, prompt_buckets=BUCKETS)
     assert engine.slot_state_leaves(variables) == ("window_latent",)
-    assert engine.ceiling_prefill(variables) is False
+    # the one leaf held once a slot is a ring, written where the prompt ends
+    assert engine.ceiling_prefill(variables) is True
 
     def by_kind(context):
         sizes = dict(_sizes(), serving={"context": context, "max_slots": 4})
@@ -528,11 +645,12 @@ def test_scheduler_serves_through_reused_slots(tiny):
         assert gaps.max() <= TOLERANCE
     stats = together.stats()
     assert stats["state_leaves"] == ["window_latent"]
-    # The ring is what a prefill left at the end of its bucket: the floor
-    # rule (8, 32, nothing, 32, 16 prefilled whole, the rest replayed).
+    # The ring is written where the prompt ends: the ceiling rule (8, 4 of
+    # 8, 32, 16 kept and one token replayed; 40 has no bucket above its 39
+    # rows: 32 prefilled whole under the floor rule, 8 replayed).
     assert (stats["prefills_ceiling"], stats["prefills_floor"],
             stats["prefill_pad_tokens"], stats["prefilled_tokens"],
-            stats["prefill_tokens"]) == (0, 4, 0, 88, 1 + 8 + 5 + 1 + 1)
+            stats["prefill_tokens"]) == (4, 1, 4, 92, 1 + 8 + 1 + 1 + 1)
     assert stats["state_resets"] == 5 and stats["prefix_skipped_stateful"] == 5
     assert stats["prefix_cache"]["entries"] == 0
     assert stats["prefix_cache"]["hits"] == 0
@@ -578,7 +696,7 @@ def test_same_prompt_twice_gets_no_prefix_hit(tiny):
     first, second = _serve(scheduler, [prompt]), _serve(scheduler, [prompt])
     assert first == second
     assert scheduler.stats()["prefix_skipped_stateful"] == 2
-    assert scheduler.stats()["prefilled_tokens"] == 2 * 16
+    assert scheduler.stats()["prefilled_tokens"] == 2 * 23
     scheduler.close()
 
 

@@ -366,8 +366,8 @@ class _LoudPadEngine(FakePagedEngine):
     would not show: a pad row holds 1000 here, so a step that read one
     would emit another stream."""
 
-    def prefill(self, params, prompt):
-        row, logits = super().prefill(params, prompt)
+    def prefill(self, params, prompt, length=None):
+        row, logits = super().prefill(params, prompt, length)
         return np.where(row == 0, 1000, row), logits
 
 

@@ -80,7 +80,7 @@ class _FakePagedEngine:
     def make_paged_pool(self, params, num_blocks, block_size):
         return np.zeros((num_blocks, block_size), np.int64)
 
-    def prefill(self, params, prompt):
+    def prefill(self, params, prompt, length=None):
         return np.asarray(prompt[0], np.int64), None
 
     def pack_prefill(self, pool, block_ids, row_cache, prefill_len,

@@ -101,6 +101,7 @@ top_k / top_p are baked into the traced program) key the cache.
 from __future__ import annotations
 
 import functools
+import inspect
 import logging
 import threading
 import weakref
@@ -127,8 +128,28 @@ DEFAULT_PROMPT_BUCKETS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 DEFAULT_TOKEN_BUCKET = 64
 
 
+def takes_prompt_len(model) -> bool:
+    """Whether the model's prefill call is told where its prompt ends
+    (`prompt_len` in its call: a model that writes a `ring` from the rows
+    that end there, models/latent.py and models/laguna.py). Every other
+    model's prefill is a program of the params and the tokens alone."""
+    return "prompt_len" in inspect.signature(type(model).__call__).parameters
+
+
 def build_prefill_fn(model):
-    """(params, prompt [B, F]) -> (cache, last-position logits [B, V])."""
+    """(params, prompt [B, F]) -> (cache, last-position logits [B, V]).
+    For a model that `takes_prompt_len`, a third argument: the prompt's
+    true length, a traced scalar (None = all F tokens are the prompt's)."""
+
+    if takes_prompt_len(model):
+        def prefill(params, prompt, prompt_len=None):
+            logits, state = model.apply(
+                params, prompt, decode=True, prompt_len=prompt_len,
+                mutable=["cache"]
+            )
+            return state["cache"], logits[:, -1]
+
+        return prefill
 
     def prefill(params, prompt):
         logits, state = model.apply(
@@ -1023,6 +1044,8 @@ class DecodeEngine:
         if token_bucket < 1:
             raise ValueError(f"token_bucket must be >= 1, got {token_bucket}")
         self.model = model
+        # Whether a prefill of this model is told its prompt's length.
+        self._prefill_takes_len = takes_prompt_len(model)
         # Tensor-parallel decode (docs/Serving.md): with a mesh, params
         # place by the model's logical-axis annotations, the KV pool shards
         # its kv-heads axis over tp, and every compiled program lowers
@@ -1310,15 +1333,20 @@ class DecodeEngine:
             )
         return compiled
 
-    def _compiled_prefill(self, params, prompt, fp):
+    def _compiled_prefill(self, params, prompt, fp, length=None):
         """(cache, last-position logits) through the compile cache; the
         exact [B, F] shape keys the cache — callers pick bucketed
-        shapes."""
+        shapes. `length` (None = F) is how many of the F tokens are the
+        prompt's: a traced argument of the program, and only of a model's
+        that `takes_prompt_len`, so a bucket stays one program."""
         b, f = prompt.shape
         prefill_key = (b, f, fp)
-        prefill_fn = build_prefill_fn(self.model)
         prefill_args = (params, prompt)
+        if self._prefill_takes_len:
+            prefill_args += (
+                np.asarray(f if length is None else length, np.int32),)
         def build():
+            prefill_fn = build_prefill_fn(self.model)
             out_shardings = None
             if self.mesh is not None:
                 # Pin the fresh cache SHARDED at the source: pack_prefill
@@ -1383,18 +1411,29 @@ class DecodeEngine:
         prefill's cache cannot depend on the tokens after it, so that the
         pad leaves the kept rows what they would have been. The model says
         so (`prompt_rows_causal`; a model that says nothing keeps the floor
-        rule), and nothing of its cache may be held once a slot: such a
-        leaf is what the prefill left at the END of its bucket."""
-        return bool(getattr(self.model, "prompt_rows_causal", False)) \
-            and not self.slot_state_leaves(params)
+        rule), and what it holds once a slot must be what the prefill left
+        where the PROMPT ends: a `ring`, which a model that
+        `takes_prompt_len` writes from the rows that end there. A `slot`
+        leaf (a recurrent state, a convolution's tail) is what the prefill
+        left at the end of its bucket, and keeps the floor rule."""
+        if not getattr(self.model, "prompt_rows_causal", False):
+            return False
+        held = self.slot_state_leaves(params)
+        if not held:
+            return True
+        kinds = self.model.cache_leaf_kinds()
+        return self._prefill_takes_len \
+            and all(kinds[name][0] == RING for name in held)
 
-    def prefill(self, params, prompt):
+    def prefill(self, params, prompt, length=None):
         """Public compiled prefill: [B, F] prompt -> (cache, last
-        logits). B/F key the compile cache directly."""
+        logits). B/F key the compile cache directly. `length`: how many
+        of the F tokens are the prompt's, the rest pad (None = all); a
+        model with rings writes them where the prompt ends."""
         params = self._place_params(params)
         prompt = jnp.asarray(prompt, jnp.int32)
         return self._compiled_prefill(
-            params, prompt, self._params_fingerprint(params)
+            params, prompt, self._params_fingerprint(params), length
         )
 
     # -- paged KV slot API ---------------------------------------------------
@@ -1459,8 +1498,6 @@ class DecodeEngine:
         call takes `count_mask`, so that its layers count what they routed
         and read and only that step returns the counts. `paged_step`
         serves every other model."""
-        import inspect
-
         return "count_mask" in inspect.signature(
             type(self.model).__call__).parameters \
             or bool(self.slot_state_leaves(params))

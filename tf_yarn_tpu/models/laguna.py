@@ -23,7 +23,9 @@ The serving engine is told what each cache leaf is (`cache_leaf_kinds`): a
 full layer's `cached_key` / `cached_value` are paged by token and read by
 `ops.decode_attention.paged_decode_attention`; a sliding layer's
 `window_key` / `window_value` are `ring`s of `ring_rows(window)` rows with a
-head axis, held once a slot, so their bytes do not grow with the context.
+head axis, held once a slot, so their bytes do not grow with the context;
+a prefill writes them from the rows that end where the prompt does
+(`prompt_len`), whatever pad follows.
 The one-token step sows what it read into `cache_stats` for the slots
 `count_mask` marks (`READS`: pool rows and ring rows apart).
 """
@@ -226,7 +228,8 @@ class LagunaBlock(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x, positions, count_mask=None, paged_ctx=None):
+    def __call__(self, x, positions, count_mask=None, paged_ctx=None,
+                 prompt_len=None):
         cfg = self.config
         norm_cfg = cfg.norm_config()
         batch, t, d = x.shape
@@ -238,7 +241,7 @@ class LagunaBlock(nn.Module):
             gate=True, window=cfg.window if sliding else 0,
             query_block=cfg.query_block,
         )(RMSNorm(norm_cfg, name="attn_norm")(x), positions, paged_ctx,
-          count_mask)
+          count_mask, prompt_len)
         normed = RMSNorm(norm_cfg, name="ffn_norm")(x)
         if cfg.mlp_types[self.index] == DENSE:
             with jax.named_scope("mlp"):
@@ -263,11 +266,18 @@ class LagunaLM(nn.Module):
     rings with a leading slot axis in `cache`, the full layers' keys and
     values in the `kv_pool` collection. `count_mask` [B * S] marks the tokens
     whose routing and cache reads the layers count (`moe_stats`,
-    `cache_stats`)."""
+    `cache_stats`). `prompt_len` (a prefill's; a traced scalar) says where
+    the prompt ends in `tokens` when what follows is pad: the rings are
+    written from the rows that end there."""
 
     config: LagunaConfig
     # The names of what the attention layers count into `cache_stats`.
     READS = ATTENTION_READS
+    # Row t of a prefill's cache depends on tokens <= t alone (causal and
+    # window masks, per-token dropless experts), and a ring is written
+    # where `prompt_len` says the prompt ends: the engine may pad a prompt
+    # past its true length (`ceiling_prefill`).
+    prompt_rows_causal = True
 
     def cache_leaf_kinds(self):
         return {**CACHE_LEAF_KINDS, "window_key": ("ring", None),
@@ -276,7 +286,8 @@ class LagunaLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True,
                  return_hidden: bool = False, decode: bool = False,
-                 count_mask: Optional[jax.Array] = None, paged_ctx=None):
+                 count_mask: Optional[jax.Array] = None, paged_ctx=None,
+                 prompt_len=None):
         cfg = self.config
         embedding = self.param(
             "embedding",
@@ -289,7 +300,7 @@ class LagunaLM(nn.Module):
             jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape)
         for index in range(cfg.n_layers):
             x = LagunaBlock(cfg, index, decode, name=f"layer_{index}")(
-                x, positions, count_mask, paged_ctx)
+                x, positions, count_mask, paged_ctx, prompt_len)
         if decode and tokens.shape[1] > 1 and not return_hidden:
             # A prefill: its caller takes the last position's logits, and
             # [S, vocab] float32 of the others would be 0.8 GB at a

@@ -41,11 +41,12 @@ The serving engine is told what each cache leaf is (`cache_leaf_kinds`): a
 full layer's `latent` and `index_key` rows are paged by token; a sliding
 layer's `window_latent` is a `ring` of `ring_len` rows held once a slot
 (row `p % ring_len` holds position p), so its bytes do not grow with the
-context. The one-token step (`paged_ctx`) reads, on a full layer, the
-slot's live index keys a chunk of blocks at a time (`indexer/scores`), picks
-(`indexer/topk`) and gathers only the chosen rows (`indexer/gather`); on a
-sliding layer the ring (`window/read`). It sows what it read into
-`cache_stats` for the slots `count_mask` marks.
+context; a prefill writes it from the rows that end where the prompt does
+(`prompt_len`), whatever pad follows. The one-token step (`paged_ctx`)
+reads, on a full layer, the slot's live index keys a chunk of blocks at a
+time (`indexer/scores`), picks (`indexer/topk`) and gathers only the chosen
+rows (`indexer/gather`); on a sliding layer the ring (`window/read`). It
+sows what it read into `cache_stats` for the slots `count_mask` marks.
 
 A call of more than one token with `decode=True` is a prefill: it makes the
 cache. Over a cache that is already there (the windowed step's gathered
@@ -79,6 +80,8 @@ from tf_yarn_tpu.models.transformer import (
     SwiGLU,
     TransformerConfig,
     _partitioned,
+    map_query_blocks,
+    ring_after_prefill,
     ring_rows,
     ring_valid,
 )
@@ -268,13 +271,14 @@ def top_k_mask(score, k: int):
 
 def expanded_attention(q_n, q_r, rows, w_kvb, sizes: AttentionSizes, *,
                        window: int = 0, select=None, query_block: int = 256,
-                       dtype=jnp.bfloat16):
+                       dtype=jnp.bfloat16, prompt_len=None):
     """The expanded path over a call's own tokens, from position 0: q_n
     [B, S, H, d_n], q_r [B, S, H, d_r], rows [B, S, kv_rank + d_r] (as they
     are cached), w_kvb [kv_rank, H, d_n + d_v] -> [B, S, H, d_v] float32.
     `window` > 0 keeps `t - j < window`; `select` (q_index, weight, keys,
     top_k) keeps the indexer's `top_k` largest `j <= t`. A block of queries
-    at a time: the largest array is [B, H, query_block, S] float32."""
+    at a time: the largest array is [B, H, query_block, S] float32.
+    `prompt_len`: `map_query_blocks`' (rows past it come out zero)."""
     batch, s, heads, _ = q_n.shape
     c = rows[..., :sizes.kv_rank]
     k_r = rows[..., sizes.kv_rank:sizes.row_width]
@@ -320,7 +324,7 @@ def expanded_attention(q_n, q_r, rows, w_kvb, sizes: AttentionSizes, *,
               blocks(q_r.astype(dtype))]
     if select is not None:
         inputs += [blocks(select[0]), blocks(select[1])]
-    out = jax.lax.map(some_rows, tuple(inputs))
+    out = map_query_blocks(some_rows, tuple(inputs), block, prompt_len)
     return jnp.moveaxis(out, 0, 1).reshape(
         batch, nb * block, heads, sizes.d_v)[:, :s]
 
@@ -428,7 +432,7 @@ class LatentAttention(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x, paged_ctx=None, count_mask=None):
+    def __call__(self, x, paged_ctx=None, count_mask=None, prompt_len=None):
         cfg, sizes = self.config, self.config.sizes(self.kind)
         full, plain = self.kind == FULL, self.kind == PLAIN
         batch, s, d = x.shape
@@ -548,7 +552,7 @@ class LatentAttention(nn.Module):
             if self.decode:
                 self._write_prefill(
                     {"latent": rows, "index_key": k_index} if full
-                    else {"latent": rows})
+                    else {"latent": rows}, prompt_len)
                 index_var.value = jnp.asarray(s, jnp.int32)
             select = None
             if full and s > cfg.index_topk:
@@ -556,7 +560,8 @@ class LatentAttention(nn.Module):
             out = expanded_attention(
                 q_n, q_r, rows, w_kvb, sizes, select=select,
                 window=cfg.window if self.kind == SLIDING else 0,
-                query_block=cfg.query_block, dtype=dtype)
+                query_block=cfg.query_block, dtype=dtype,
+                prompt_len=prompt_len)
         if count_mask is not None and one_token:
             self.sow("cache_stats", "reads", jnp.stack(
                 [jnp.asarray(reads[name], jnp.int32) for name in names]))
@@ -576,10 +581,12 @@ class LatentAttention(nn.Module):
                 preferred_element_type=f32).astype(dtype)
 
     @nn.nowrap
-    def _write_prefill(self, fresh):
+    def _write_prefill(self, fresh, prompt_len=None):
         """A prefill's rows (`fresh`: leaf name -> [B, s, width]) into a
         fresh dense cache: a full or plain layer's at [0, s) of each leaf;
-        a sliding layer's last `ring_len` into the ring, position p at row
+        of a sliding layer's the last `ring_len` that are the prompt's
+        (it ends at `prompt_len`, a traced scalar, where the later rows
+        are pad; None = at s) into the ring, position p at row
         `p % ring_len`."""
         cfg = self.config
 
@@ -588,14 +595,8 @@ class LatentAttention(nn.Module):
 
         with jax.named_scope("latent/cache_write"):
             if self.kind == SLIDING:
-                rows = fresh["latent"]
-                batch, s, width = rows.shape
-                ring = cfg.ring_len
-                kept = min(s, ring)
-                put("window_latent",
-                    jnp.zeros((batch, ring, width), rows.dtype).at[
-                        :, jnp.arange(s - kept, s) % ring].set(
-                        rows[:, s - kept:]))
+                put("window_latent", ring_after_prefill(
+                    fresh["latent"], cfg.ring_len, prompt_len))
                 return
             for name, value in fresh.items():
                 put(name, jnp.pad(value, [
@@ -724,13 +725,14 @@ class LatentBlock(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x, count_mask=None, paged_ctx=None):
+    def __call__(self, x, count_mask=None, paged_ctx=None, prompt_len=None):
         cfg = self.config
         norm_cfg = cfg.norm_config()
         batch, t, d = x.shape
         x = x + LatentAttention(
             cfg, cfg.layer_types[self.index], self.decode, name="attn")(
-            RMSNorm(norm_cfg, name="attn_norm")(x), paged_ctx, count_mask)
+            RMSNorm(norm_cfg, name="attn_norm")(x), paged_ctx, count_mask,
+            prompt_len)
         normed = RMSNorm(norm_cfg, name="ffn_norm")(x)
         if self.index < cfg.first_dense:
             with jax.named_scope("mlp"):
@@ -752,11 +754,20 @@ class LatentLM(nn.Module):
     `paged_ctx` besides is the paged step's call: tokens [slots, 1], the
     rings with a leading slot axis in `cache`, the full layers' rows in the
     `kv_pool` collection. `count_mask` [B * S] marks the tokens whose
-    routing and cache reads the layers count (`moe_stats`, `cache_stats`)."""
+    routing and cache reads the layers count (`moe_stats`, `cache_stats`).
+    `prompt_len` (a prefill's; a traced scalar) says where the prompt ends
+    in `tokens` when what follows is pad: the rings are written from the
+    rows that end there."""
 
     config: LatentConfig
     # The names of what the attention layers count into `cache_stats`.
     READS = READS
+    # Row t of a prefill's cache depends on tokens <= t alone (causal and
+    # window masks, the indexer's top-k over `j <= t`, per-token dropless
+    # experts), and a ring is written where `prompt_len` says the prompt
+    # ends: the engine may pad a prompt past its true length
+    # (`ceiling_prefill`).
+    prompt_rows_causal = True
 
     def cache_leaf_kinds(self):
         return {"latent": ("paged", -2), "index_key": ("paged", -2),
@@ -765,7 +776,7 @@ class LatentLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True,
                  return_hidden: bool = False, decode: bool = False,
-                 count_mask=None, paged_ctx=None):
+                 count_mask=None, paged_ctx=None, prompt_len=None):
         cfg = self.config
         embedding = self.param(
             "embedding",
@@ -776,7 +787,7 @@ class LatentLM(nn.Module):
             x = embedding.astype(cfg.dtype)[tokens]
         for index in range(cfg.n_layers):
             x = LatentBlock(cfg, index, decode, name=f"layer_{index}")(
-                x, count_mask, paged_ctx)
+                x, count_mask, paged_ctx, prompt_len)
         x = RMSNorm(cfg.norm_config(), name="final_norm")(x)
         if return_hidden:
             return x

@@ -183,24 +183,61 @@ def ring_rows(window: int) -> int:
     return -(-window // RING_MULTIPLE) * RING_MULTIPLE
 
 
+def ring_positions(newest, rows: int):
+    """[..., rows]: the position each row of a ring holds once position
+    `newest` (a scalar, or [..., 1]) is written: row i the largest
+    p <= newest with p % rows == i, negative where there is none yet."""
+    return newest - (newest - jnp.arange(rows)) % rows
+
+
 def ring_valid(lengths, rows: int, window: int):
     """[B, rows] bool: which rows of a ring hold a position inside the window
-    of the token at `lengths` [B] (its own row, just written, included). Row
-    i holds the newest position p <= length with p % rows == i."""
-    at = lengths[:, None] - (
-        lengths[:, None] - jnp.arange(rows)[None, :]) % rows
+    of the token at `lengths` [B] (its own row, just written, included)."""
+    at = ring_positions(lengths[:, None], rows)
     return (at >= 0) & (lengths[:, None] - at < window)
+
+
+def ring_after_prefill(fresh, rows: int, prompt_len=None):
+    """The ring a prefill leaves: `fresh` [B, S, ...] are the rows of
+    positions 0 .. S - 1, of which the first `prompt_len` are the prompt's
+    and the rest pad (a traced scalar or an int; None = all S).
+    -> [B, rows, ...]: `ring_positions` at the prompt's last token, zeros
+    where a row has none: what a prefill of exactly `prompt_len` tokens
+    leaves, and nothing of the pad."""
+    last = (fresh.shape[1] if prompt_len is None else prompt_len) - 1
+    at = ring_positions(last, rows)
+    held = jnp.take(fresh, jnp.maximum(at, 0), axis=1)
+    some = (at >= 0).reshape((1, rows) + (1,) * (fresh.ndim - 2))
+    return jnp.where(some, held, jnp.zeros((), fresh.dtype))
+
+
+def map_query_blocks(fn, blocks, block: int, prompt_len=None):
+    """`jax.lax.map(fn, blocks)` over the blocks of `block` queries of a
+    prefill (each entry of `blocks` leads with the block axis). Told where
+    the prompt ends (`prompt_len`, a traced scalar), only the blocks that
+    hold a prompt row are computed and the rest, all pad, stay zero: a
+    padded prompt does not pay for its pad's attention."""
+    if prompt_len is None:
+        return jax.lax.map(fn, blocks)
+    nb = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+    at = lambda i: jax.tree_util.tree_map(lambda x: x[i], blocks)  # noqa: E731
+    one = jax.eval_shape(fn, at(0))
+    return jax.lax.fori_loop(
+        0, jnp.minimum(-(-prompt_len // block), nb),
+        lambda i, out: out.at[i].set(fn(at(i))),
+        jnp.zeros((nb,) + one.shape, one.dtype))
 
 
 def own_token_attention(q, k, v, *, window: int = 0,
                         softmax_scale: Optional[float] = None,
-                        query_block: int = 256):
+                        query_block: int = 256, prompt_len=None):
     """Causal attention of a call's own tokens, from position 0, a block of
     queries at a time: q [B, S, H, D], k, v [B, S, Hkv, D] -> [B, S, H, D]
     float32. `window` > 0 keeps the keys `t - j < window` (the query itself
     counts). Query head h reads KV head h // (H // Hkv). The largest array
     is the scores of one block, [B, H, query_block, S] float32 (the plain
-    masked form: scores past the window are formed and masked)."""
+    masked form: scores past the window are formed and masked).
+    `prompt_len`: `map_query_blocks`' (rows past it come out zero)."""
     batch, s, heads, dim = q.shape
     n_kv = k.shape[2]
     scale = dim ** -0.5 if softmax_scale is None else softmax_scale
@@ -226,8 +263,9 @@ def own_token_attention(q, k, v, *, window: int = 0,
             return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v,
                               preferred_element_type=jnp.float32)
 
-    out = jax.lax.map(
-        some_rows, (jnp.arange(nb) * block, jnp.moveaxis(grouped, 1, 0)))
+    out = map_query_blocks(
+        some_rows, (jnp.arange(nb) * block, jnp.moveaxis(grouped, 1, 0)),
+        block, prompt_len)
     return jnp.moveaxis(out, 0, 1).reshape(
         batch, nb * block, heads, dim)[:, :s]
 
@@ -336,7 +374,8 @@ class Attention(nn.Module):
     query_block: int = 0
 
     @nn.compact
-    def __call__(self, x, positions, paged_ctx=None, count_mask=None):
+    def __call__(self, x, positions, paged_ctx=None, count_mask=None,
+                 prompt_len=None):
         cfg = self.config
         decode = self.decode
         b, s, _ = x.shape
@@ -353,7 +392,8 @@ class Attention(nn.Module):
         if decode and self.window:
             # A window layer keeps a ring and no row past it: prefill,
             # the one-token call on a dense cache and the paged step alike.
-            out = self._ring_decode(q, k, v, paged_ctx, count_mask)
+            out = self._ring_decode(q, k, v, paged_ctx, count_mask,
+                                    prompt_len)
         elif decode and paged_ctx is not None:
             # Paged decode: the serving engine passed the KV block pool
             # (kv_pool collection) + per-slot tables and lengths. Rows
@@ -447,7 +487,7 @@ class Attention(nn.Module):
                 out = own_token_attention(
                     q, k.astype(cfg.dtype), v.astype(cfg.dtype),
                     softmax_scale=cfg.attention_scale,
-                    query_block=self.query_block)
+                    query_block=self.query_block, prompt_len=prompt_len)
             elif int8_cache and s == 1:
                 # Steady-state decode: the pallas kernel streams the int8
                 # cache directly, dequantizing tile-by-tile in VMEM
@@ -536,10 +576,12 @@ class Attention(nn.Module):
             jnp.sum(live), jnp.sum(-(-live // chunk) * chunk), zero, zero]))
 
     @nn.nowrap
-    def _ring_decode(self, q, k, v, paged_ctx, count_mask):
+    def _ring_decode(self, q, k, v, paged_ctx, count_mask, prompt_len=None):
         """A window layer's decode call. More than one token is a prefill
         from an empty cache: attend over the call's own tokens inside the
-        window and leave the last rows in the ring. One token (a row of a
+        window and leave the prompt's last rows in the ring (the prompt
+        ends at `prompt_len`, a traced scalar, where the call's later
+        tokens are pad; None = at the call's end). One token (a row of a
         dense cache at `cache_index`, or a slot of the paged step at its
         length: the rings then lead with a slot axis over batch-1 rows):
         the token's row into the ring at `p % rows`, then the ring's rows
@@ -567,18 +609,15 @@ class Attention(nn.Module):
         q, k = self._rotate(q, positions), self._rotate(k, positions)
         k, v = k.astype(cfg.dtype), v.astype(cfg.dtype)
         if s != 1:
-            kept = min(s, rows)
-            at = jnp.arange(s - kept, s) % rows
             with jax.named_scope("attention/ring_write"):
                 for name, value in (("window_key", k), ("window_value", v)):
-                    ring = jnp.zeros((b, rows, n_kv, head_dim), cfg.dtype).at[
-                        :, at].set(value[:, s - kept:])
+                    ring = ring_after_prefill(value, rows, prompt_len)
                     self.variable("cache", name, lambda r=ring: r).value = ring
             index_var.value = jnp.asarray(s, jnp.int32)
             return own_token_attention(
                 q, k, v, window=self.window,
                 softmax_scale=cfg.attention_scale,
-                query_block=self.query_block or s)
+                query_block=self.query_block or s, prompt_len=prompt_len)
         held = {}
         with jax.named_scope("attention/ring_write"):
             for name, value in (("window_key", k), ("window_value", v)):

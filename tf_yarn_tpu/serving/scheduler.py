@@ -1172,13 +1172,14 @@ class SlotScheduler:
                 if bucket > 0:
                     # Rows past `kept` hold the pad: no kept row sees them
                     # under the causal mask, their blocks' ids aim at the
-                    # trash block, and what of them lands in the last
-                    # owned block lies past the slot's length, where the
-                    # slot writes as it grows.
+                    # trash block, what of them lands in the last owned
+                    # block lies past the slot's length, where the slot
+                    # writes as it grows, and a ring is written from the
+                    # rows that end at `kept` (the prefill is told it).
                     tokens = np.zeros((1, bucket), np.int32)
                     tokens[0, :kept] = prompt[:kept]
                     row_cache, _logits = self.engine.prefill(
-                        self.params, tokens)
+                        self.params, tokens, kept)
                     n_owned = -(-kept // self._block_size)
                     ids = np.full((-(-bucket // self._block_size),),
                                   TRASH_BLOCK, np.int32)

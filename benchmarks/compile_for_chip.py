@@ -39,6 +39,12 @@ Nothing runs, so nothing here is a result or a time of the device. Programs:
             (the full layers' keys and values read by the paged kernel at 6
             query heads a KV head, the sliding layers' rings held once a
             slot)
+    dsv32   the benchmark's DeepSeek-V3.2 share at its own sizes
+            (cellbench/configs/deepseek_v32_serve_1chip.json): prefill at
+            the 1024 and 2048 buckets (no query of either has more than
+            `index_topk` keys: the prefill never selects), pack, and the
+            32-slot paged state step over tables of 768 blocks (12,288
+            tokens a slot: five layers score, sort and gather off the pool)
 
 Each line: how many Mosaic kernels (`tpu_custom_call`) and collectives the
 compiler emitted, and the bytes one device needs (temporaries + arguments).
@@ -331,6 +337,8 @@ def main(argv) -> int:
         "laguna": lambda: compile_latent(
             one, "laguna", "laguna_xs2_serve_1chip",
             (512, 1024, 2048, 4096), True),
+        "dsv32": lambda: compile_latent(
+            one, "dsv32", "deepseek_v32_serve_1chip", (1024, 2048)),
     }
     for name in argv or list(programs):
         programs[name]()

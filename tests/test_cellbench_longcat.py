@@ -21,7 +21,8 @@ def test_the_cell_and_its_entries():  # noqa: F811
     that adds one may not edit. Tier-1 holds them to what stays true, as
     tests/test_cellbench_granite.py does for the second architecture: the
     cell as it was, its entries side by side as they were appended, each
-    listing it alone and moving what it reports. Every other assertion of the
+    listing it alone (one of them a later cell after it, by name) and moving
+    what it reports. Every other assertion of the
     imported test stands here letter for letter."""
     from cellbench import run
     from cellbench.tests.test_longcat_flash import (
@@ -44,7 +45,12 @@ def test_the_cell_and_its_entries():  # noqa: F811
     first = names.index(REASON[0])
     assert REAL["per_layer"][first:first + len(new)] == new  # side by side
     for metric in new:
-        assert metric["workloads"] == [CELL]
+        # `step_mlp_share.reason` reads a dense layer's `mlp` scope in a step
+        # with `latent` scopes: DeepSeek-V3.2's cell, which came when the
+        # benchmark had room for three entries more, is listed after this one
+        also = ["dsv32_reasoning_backlog"] \
+            if metric["name"] == "step_mlp_share.reason" else []
+        assert metric["workloads"] == [CELL] + also
         assert metric["moves"] == "serve_tokens_per_s"
     files = {name: run.metric_file(name) for name in REASON}
     assert files["step_roofline.reason"]["args"]["opcount"] == "longcat_step"

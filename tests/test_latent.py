@@ -14,6 +14,7 @@ bfloat16 where float32 is stated moves logits by 1e-3 (a norm) to 1e-1 (the
 router's scores, the indexer's: another expert, another key) and fails every
 comparison here (`test_bfloat16_where_float32_is_stated_fails`)."""
 
+import functools
 import json
 import os
 import threading
@@ -437,11 +438,13 @@ def test_slots_step_together_and_a_reused_slot_starts_clean(tiny):
     # the last step: slot 0 at 41 + 1 live rows (39 rows have no bucket
     # above: 32 kept), slot 2 at 19 + 1 (10 kept of 16); two full
     # layers select min(live, 24) and gather 24 rows a slot; the index keys
-    # go a chunk of 32 at a time as far as the longest slot reaches (64);
+    # go a chunk of 32 at a time as far as the longest slot reaches (64)
+    # and are sorted as wide as a slot's table, the context of 128;
     # three window layers read their ring of 16, 9 rows of it in the window
     assert reads == {
         "index_live": 2 * (42 + 20), "index_selected": 2 * (24 + 20),
         "index_read": 2 * 2 * 64, "latent_read": 2 * 2 * 24,
+        "index_sorted": 2 * 2 * 128,
         "window_live": 3 * (9 + 9), "window_read": 3 * 2 * 16}
     for got, sequence, start in ((got1, first, p1), (got2, second, p2)):
         want = _reference_logits(tiny, sequence[:start + 10],
@@ -459,14 +462,22 @@ def test_slots_step_together_and_a_reused_slot_starts_clean(tiny):
         grid.retire(0)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("experts,shares,n_group,topk_group", [
+    (16, 4, 1, 1), (32, 16, 8, 4)])
+def test_the_shares_add_up_to_the_uncut_layer(experts, shares, n_group,
+                                               topk_group):
     """Four chips share a layer of 16 experts, 4 each, under sigmoid scores,
-    a correction bias, normalised gates and a scale. Each share returns its
-    own experts' part of the sum plus the shared expert, which all compute
-    alike; the four routed parts and the shared expert counted once are the
-    uncut layer of the reference."""
+    a correction bias, normalised gates and a scale; or sixteen share 32
+    experts in 8 groups of which a token chooses inside the best 4, two
+    experts (half a group) each, as DeepSeek-V3.2's sixteen share 256. Each
+    share returns its own experts' part of the sum plus the shared expert,
+    which all compute alike; the routed parts and the shared expert counted
+    once are the uncut layer of the reference."""
+    from cellbench.reference import deepseek_v32
+
     rng = np.random.default_rng(7)
-    d, experts, width, top_k, scale = 32, 16, 16, 3, 2.5
+    d, width, top_k, scale = 32, 16, 3, 2.5
+    each = experts // shares
     w = {
         "router": jnp.asarray(rng.normal(size=(d, experts)) / 5, jnp.float32),
         "router_bias": jnp.asarray(rng.normal(size=(experts,)) / 10, jnp.float32),
@@ -477,20 +488,30 @@ def test_the_shares_add_up_to_the_uncut_layer():
     }
     x = jnp.asarray(rng.normal(size=(19, d)), jnp.float32)
     about = dict(top_k=top_k, normalise=True, scale=scale)
-    uncut = np.asarray(reference.experts(x, w, offset=0, **about))
+    layer_of = reference.experts
+    if n_group > 1:
+        # the family whose router has groups keeps the reference that does
+        layer_of = functools.partial(deepseek_v32.experts, n_group=n_group,
+                                     topk_group=topk_group)
+        # and the groups decide: over all experts others are chosen
+        assert np.abs(np.asarray(layer_of(x, w, offset=0, **about))
+                      - np.asarray(reference.experts(x, w, offset=0, **about))
+                      ).max() > 1e-2
+    uncut = np.asarray(layer_of(x, w, offset=0, **about))
     # the bias decides: without it other experts are chosen
-    assert np.abs(uncut - np.asarray(reference.experts(
+    assert np.abs(uncut - np.asarray(layer_of(
         x, dict(w, router_bias=jnp.zeros((experts,))), offset=0, **about))
     ).max() > 1e-2
     only_shared = np.asarray(reference._swiglu(
         x, w["shared_in"], w["shared_out"], None))
     total = np.zeros_like(uncut)
-    for share in range(4):
-        held = slice(4 * share, 4 * share + 4)
+    for share in range(shares):
+        held = slice(each * share, each * share + each)
         layer = DroplessMoE(
-            num_experts=experts, num_experts_here=4, expert_offset=4 * share,
-            top_k=top_k, d_expert=width, d_shared=width, scoring="sigmoid",
-            norm_topk=True, routed_scale=scale,
+            num_experts=experts, num_experts_here=each,
+            expert_offset=each * share, top_k=top_k, d_expert=width,
+            d_shared=width, scoring="sigmoid", norm_topk=True,
+            routed_scale=scale, n_group=n_group, topk_group=topk_group,
             dtype=jnp.float32, param_dtype=jnp.float32)
         params = {"params": {
             "router": w["router"], "router_bias": w["router_bias"],
@@ -501,15 +522,16 @@ def test_the_shares_add_up_to_the_uncut_layer():
         counts = np.asarray(stats["moe_stats"]["counts"][0])
         assert counts[0] == 19 * top_k
         total += np.asarray(out) - only_shared
-        # and the reference's own share, given the same four experts
-        mine = reference.experts(
+        # and the reference's own share, given the same experts
+        mine = layer_of(
             x, dict(w, w_in=w["w_in"][held], w_out=w["w_out"][held]),
-            offset=4 * share, **about)
+            offset=each * share, **about)
         np.testing.assert_allclose(np.asarray(out), np.asarray(mine),
                                    atol=2e-5, rtol=0)
     # outputs of magnitude 1 here (the test's own weights), float32 sums
     # in another order: 2e-5; a bfloat16 matmul would miss by 1e-2
     np.testing.assert_allclose(total + only_shared, uncut, atol=2e-5, rtol=0)
+    assert np.abs(total).max() > 0.1
 
 
 @pytest.mark.parametrize("what", ["index_scores", "router", "norm"])

@@ -8,10 +8,15 @@ a sigmoid-routed expert layer — the `dots3_note` shape (dots3-note-prev).
 *latent attention* (both kinds, each with its own sizes `AttentionSizes`),
 on x = RMSNorm(h): `c_q = a_q RMSNorm(x W_qa)`; `[q_n | q_r] = c_q W_qb` per
 head; `[c_raw | k_raw] = x W_kva`; `c = a_kv RMSNorm(c_raw)`; `q_r, k_r =
-rope(q_r), rope(k_raw)` (one `k_r` for all heads); `[k_n | v] = c W_kvb` per
-head; `s = (q_n.k_n + q_r.k_r) / sqrt(d_n + d_r)` over the allowed keys;
-`o = softmax(s) v`; `g = sigmoid(x W_g)`, one number a head;
-`attn = concat_h(g_h o_h) W_o`. **What is cached a token and layer is the
+rope(q_r), rope(k_raw)` (one `k_r` for all heads; a rotary recipe a kind,
+`AttentionSizes.recipe`: plain RoPE at the kind's theta, or YaRN's blended
+frequencies, `transformer.RotaryRecipe`); `[k_n | v] = c W_kvb` per head;
+`s = m^2 (q_n.k_n + q_r.k_r) / sqrt(d_n + d_r)` over the allowed keys (`m`
+the kind's `mscale`: YaRN's, 1 without); `o = softmax(s) v`; on the kinds
+the configuration lists as `gated`, `g = sigmoid(x W_g)`, one number a
+head, and `attn = concat_h(g_h o_h) W_o`; on the others `attn =
+concat_h(o_h) W_o`. `a_q`, `a_kv` are the variance rescales
+(`rescale_latents`; 1 without). **What is cached a token and layer is the
 row `[c | k_r]`**: `kv_rank + d_rope` numbers, no head axis, stored padded
 with zeros to whole lanes (`row_multiple`).
 
@@ -27,7 +32,7 @@ expands a cached row.
 counts). `full_attention`: the `index_topk` largest of `I_tj = sum_i w_ti
 relu(q^I_ti . k^I_j) / sqrt(index_heads index_dim)` over `j <= t` (all of
 them while there are no more); `q^I = c_q W_iq`, `k^I = LayerNorm(x W_ik)`
-(cached a token: `index_key`), `w = x W_iw`, rope on the first
+(cached a token: `index_key`), `w = x W_iw`, the kind's rope on the first
 `index_rope_dim` numbers of `q^I`, `k^I`. The selection is exact
 (`jax.lax.top_k`), and its scores, like the router's and every norm, are
 float32 at full precision: which keys and which experts are discrete
@@ -35,7 +40,9 @@ choices.
 
 *ffn.* Layers below `first_dense`: `transformer.SwiGLU` of `d_ff_dense`.
 Later layers: `moe.DroplessMoE` with sigmoid scores, selection under the
-correction bias, the chosen scores normalised and scaled, one shared expert.
+correction bias (over all experts, or inside the `topk_group` best of
+`n_group` groups), the chosen scores normalised and scaled, one shared
+expert.
 
 The serving engine is told what each cache leaf is (`cache_leaf_kinds`): a
 full layer's `latent` and `index_key` rows are paged by token; a sliding
@@ -54,7 +61,7 @@ view) it is refused by name.
 
 A third kind, `plain_attention` (models/longcat.py), is the full kind's
 sizes with nothing between a query and its keys: every `j <= t`, no
-indexer, no gate, one paged leaf (`latent`). Its one-token step hands the
+indexer, never a gate, one paged leaf (`latent`). Its one-token step hands the
 absorbed query `[q~ | q_r]` to `ops.decode_attention.paged_decode_attention`
 as 64 heads over one KV head whose keys and values are the same cached row
 (`latent/read`): on a TPU the kernel that walks the slot's block table no
@@ -65,7 +72,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -77,6 +84,7 @@ from tf_yarn_tpu.models.transformer import (
     HEADS,
     VOCAB,
     RMSNorm,
+    RotaryRecipe,
     SwiGLU,
     TransformerConfig,
     _partitioned,
@@ -90,9 +98,10 @@ HIGHEST = jax.lax.Precision.HIGHEST
 FULL, SLIDING = "full_attention", "sliding_attention"
 PLAIN = "plain_attention"
 # What an attention layer sows into `cache_stats` a step, over the counted
-# slots: rows live and rows read of each leaf, and the keys selected.
+# slots: rows live and rows read of each leaf, the keys selected, and how
+# wide the selection's sort was (the slot's whole table, not its live keys).
 READS = ("index_live", "index_read", "index_selected", "latent_read",
-         "window_live", "window_read")
+         "window_live", "window_read", "index_sorted")
 # What a plain layer sows: it reads every live row.
 PLAIN_READS = ("latent_live", "latent_read")
 
@@ -106,11 +115,28 @@ class AttentionSizes:
     d_rope: int
     d_v: int
     rope_theta: float
+    # YaRN over `rope_theta` (its `theta`; `rotary_dim` is set where it is
+    # used, `recipe`); None = plain RoPE.
+    rotary: Optional[RotaryRecipe] = None
+    # The softmax scale carries its square: YaRN's `0.1 mscale_all_dim
+    # ln(factor) + 1`.
+    mscale: float = 1.0
 
     @property
     def row_width(self) -> int:
         """What a token caches: `[c | k_r]`."""
         return self.kv_rank + self.d_rope
+
+    @property
+    def softmax_scale(self) -> float:
+        return (self.d_nope + self.d_rope) ** -0.5 * self.mscale ** 2
+
+    def recipe(self, n: int) -> RotaryRecipe:
+        """The kind's positional function over `n` numbers: `q_r` and `k_r`
+        (`d_rope`), and the front of the indexer's queries and keys."""
+        if self.rotary is None:
+            return RotaryRecipe(self.rope_theta, n)
+        return dataclasses.replace(self.rotary, rotary_dim=n)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,6 +149,8 @@ class LatentConfig:
     full: AttentionSizes = AttentionSizes(128, 1024, 512, 128, 64, 128, 8e7)
     sliding: AttentionSizes = AttentionSizes(64, 1024, 1024, 192, 64, 128, 5e4)
     rescale_latents: bool = True
+    # The kinds whose heads pass a sigmoid gate (`plain_attention` never).
+    gated: Tuple[str, ...] = (FULL, SLIDING)
     window: int = 513
     index_heads: int = 64
     index_dim: int = 128
@@ -139,6 +167,9 @@ class LatentConfig:
     d_shared: int = 1536
     norm_topk: bool = True
     routed_scale: float = 1.0
+    # The router's groups, and how many of them a token may choose inside.
+    n_group: int = 1
+    topk_group: int = 1
     # Matrices are stored in `param_dtype`; norm scales, the LayerNorm of the
     # index keys and the router's bias stay float32.
     dtype: Any = jnp.bfloat16
@@ -185,8 +216,9 @@ class LatentConfig:
 
     def __post_init__(self):
         unknown = set(self.layer_types) - {FULL, SLIDING, PLAIN}
-        if unknown or not self.layer_types:
-            raise ValueError(f"layer_types: {self.layer_types!r}")
+        if unknown or not self.layer_types or set(self.gated) - {FULL, SLIDING}:
+            raise ValueError(
+                f"layer_types: {self.layer_types!r}, gated: {self.gated!r}")
         if self.kv_cache_dtype != "bf16":
             raise ValueError(
                 f"kv_cache_dtype={self.kv_cache_dtype!r}: a latent cache row "
@@ -228,23 +260,36 @@ class LatentConfig:
         return cls(**defaults)
 
 
-def rope(x, positions, theta: float):
+def rope(x, positions, recipe: RotaryRecipe):
     """Rotary embedding over the last dim of x [B, S, ..., n] at `positions`
-    [B, S]: the pairs (2i, 2i + 1) turned; float32."""
+    [B, S], by `recipe` (of `n` numbers): the pairs (2i, 2i + 1) turned;
+    float32."""
     n = x.shape[-1]
-    freqs = 1.0 / theta ** (jnp.arange(0, n, 2, dtype=jnp.float32) / n)
+    if recipe.rotary_dim != n:
+        raise ValueError(f"a recipe of {recipe.rotary_dim} over {n} numbers")
+    if recipe.factor:
+        freqs = recipe.inv_freq()
+    else:
+        # Plain RoPE as it always was here, in float32 on the device: the
+        # recipe's float64 table differs from it in the last bit.
+        freqs = 1.0 / recipe.theta ** (
+            jnp.arange(0, n, 2, dtype=jnp.float32) / n)
     angles = positions.astype(jnp.float32).reshape(
         positions.shape + (1,) * (x.ndim - 2)) * freqs
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if recipe.attention_factor != 1.0:
+        cos, sin = (cos * recipe.attention_factor,
+                    sin * recipe.attention_factor)
     x = x.astype(jnp.float32)
     x1, x2 = x[..., 0::2], x[..., 1::2]
     return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                      axis=-1).reshape(x.shape)
 
 
-def _rope_front(x, positions, theta: float, n: int):
+def _rope_front(x, positions, recipe: RotaryRecipe):
+    n = recipe.rotary_dim
     return jnp.concatenate(
-        [rope(x[..., :n], positions, theta), x[..., n:].astype(jnp.float32)],
+        [rope(x[..., :n], positions, recipe), x[..., n:].astype(jnp.float32)],
         axis=-1)
 
 
@@ -294,7 +339,7 @@ def expanded_attention(q_n, q_r, rows, w_kvb, sizes: AttentionSizes, *,
         return jnp.moveaxis(
             value.reshape((batch, nb, block) + value.shape[2:]), 1, 0)
 
-    scale = (sizes.d_nope + sizes.d_rope) ** -0.5
+    scale = sizes.softmax_scale
     keys = jnp.arange(s)[None, :]
 
     def some_rows(args):
@@ -362,7 +407,7 @@ def absorbed_attention(q_n, q_r, rows, valid, w_kvb, sizes: AttentionSizes,
     with jax.named_scope("latent/scores"):
         scores = jnp.einsum(
             "bhw,bkw->bhk", query, rows, preferred_element_type=jnp.float32
-        ) * (sizes.d_nope + sizes.d_rope) ** -0.5
+        ) * sizes.softmax_scale
         scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
         weights = jax.nn.softmax(scores, axis=-1)
     with jax.named_scope("latent/values"):
@@ -384,7 +429,7 @@ def select_rows(q_index, weight, index_pool, latent_pool, tables, lengths,
     latent_pool [NB, bs, W]; tables [S, MB]; lengths [S] (this step's row
     included: the keys are `j < length`).
     -> rows [S, K, W], valid [S, K], the chosen rows of the pool [S, K],
-    index rows read a slot."""
+    index rows read a slot, keys sorted a slot (the table's width)."""
     slots, max_blocks = tables.shape
     block_size = index_pool.shape[1]
     per_chunk = max(1, min(chunk_tokens // block_size, max_blocks))
@@ -423,7 +468,7 @@ def select_rows(q_index, weight, index_pool, latent_pool, tables, lengths,
         valid, chosen = falling[:, :k] < jnp.inf, places[:, :k]
     with jax.named_scope("indexer/gather"):
         rows = latent_pool.reshape(-1, latent_pool.shape[-1])[chosen]
-    return rows, valid, chosen, trips * chunk
+    return rows, valid, chosen, trips * chunk, total
 
 
 class LatentAttention(nn.Module):
@@ -474,6 +519,8 @@ class LatentAttention(nn.Module):
         else:
             lengths = jnp.zeros((batch,), jnp.int32)
         positions = lengths[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+        turn = sizes.recipe(sizes.d_rope)
+        index_turn = sizes.recipe(cfg.index_rope_dim)
 
         with jax.named_scope("latent/q"):
             c_q = jnp.einsum("bsd,dr->bsr", x, matrix(
@@ -486,14 +533,14 @@ class LatentAttention(nn.Module):
                 (None, HEADS)), preferred_element_type=f32).reshape(
                 batch, s, heads, sizes.d_nope + sizes.d_rope)
             q_n = q[..., :sizes.d_nope]
-            q_r = rope(q[..., sizes.d_nope:], positions, sizes.rope_theta)
+            q_r = rope(q[..., sizes.d_nope:], positions, turn)
         with jax.named_scope("latent/kv"):
             kv = jnp.einsum("bsd,df->bsf", x, matrix(
                 "kv_a", (d, sizes.row_width), (EMBED, None)),
                 preferred_element_type=f32)
             c = rescale(sizes.kv_rank) * RMSNorm(norm_cfg, name="kv_norm")(
                 kv[..., :sizes.kv_rank])
-            k_r = rope(kv[..., sizes.kv_rank:], positions, sizes.rope_theta)
+            k_r = rope(kv[..., sizes.kv_rank:], positions, turn)
             rows = jnp.concatenate([c, k_r], axis=-1).astype(dtype)
             rows = jnp.pad(rows, [(0, 0), (0, 0), (
                 0, cfg.stored_width(self.kind) - sizes.row_width)])
@@ -506,8 +553,7 @@ class LatentAttention(nn.Module):
                     "index_q", (sizes.q_rank, cfg.index_heads * cfg.index_dim),
                     (None, None)), preferred_element_type=f32).reshape(
                     batch, s, cfg.index_heads, cfg.index_dim)
-                q_index = _rope_front(q_index, positions, sizes.rope_theta,
-                                      cfg.index_rope_dim)
+                q_index = _rope_front(q_index, positions, index_turn)
                 index_weight = jnp.einsum("bsd,dh->bsh", x, matrix(
                     "index_w", (d, cfg.index_heads), (EMBED, None)),
                     preferred_element_type=f32)
@@ -518,8 +564,8 @@ class LatentAttention(nn.Module):
                 k_index = nn.LayerNorm(
                     epsilon=cfg.norm_eps, dtype=f32, param_dtype=f32,
                     name="index_k_norm")(k_index)
-                k_index = _rope_front(k_index, positions, sizes.rope_theta,
-                                      cfg.index_rope_dim).astype(dtype)
+                k_index = _rope_front(
+                    k_index, positions, index_turn).astype(dtype)
 
         names = PLAIN_READS if plain else READS
         reads = dict.fromkeys(names, 0)
@@ -566,7 +612,7 @@ class LatentAttention(nn.Module):
             self.sow("cache_stats", "reads", jnp.stack(
                 [jnp.asarray(reads[name], jnp.int32) for name in names]))
 
-        if plain:
+        if self.kind not in cfg.gated:
             out = out.astype(dtype)
         else:
             with jax.named_scope("latent/gate"):
@@ -610,7 +656,7 @@ class LatentAttention(nn.Module):
         cfg, sizes = self.config, self.config.full
         unwrap, tables = self._write_token(
             {"latent": row, "index_key": k_index}, lengths, paged_ctx)
-        rows, valid, chosen, index_read = select_rows(
+        rows, valid, chosen, index_read, index_sorted = select_rows(
             q_index, index_weight, unwrap["index_key"], unwrap["latent"],
             tables, lengths + 1, cfg.index_topk, cfg.index_chunk)
         # for the tests, which ask for `intermediates`: rows of the pool
@@ -619,6 +665,7 @@ class LatentAttention(nn.Module):
                                  cfg.dtype)
         return out, {
             "index_read": jnp.sum(counted) * index_read,
+            "index_sorted": jnp.sum(counted) * index_sorted,
             "index_selected": jnp.sum(valid & counted[:, None]),
             "latent_read": jnp.sum(counted) * rows.shape[1]}
 
@@ -687,7 +734,7 @@ class LatentAttention(nn.Module):
         with jax.named_scope("latent/read"):
             mixed = paged_decode_attention(
                 query, pool, pool, tables, lengths + 1,
-                (sizes.d_nope + sizes.d_rope) ** -0.5, kernel=kernel)
+                sizes.softmax_scale, kernel=kernel)
         # The kernel reads a slot's length rounded up to its chunk; the
         # plain gather the whole table.
         chunk = paged_chunk_tokens(block_size, max_blocks) if kernel \
@@ -742,6 +789,7 @@ class LatentBlock(nn.Module):
             expert_offset=cfg.expert_offset, top_k=cfg.experts_per_token,
             d_expert=cfg.d_expert, d_shared=cfg.d_shared, scoring="sigmoid",
             norm_topk=cfg.norm_topk, routed_scale=cfg.routed_scale,
+            n_group=cfg.n_group, topk_group=cfg.topk_group,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="moe",
         )(normed.reshape(batch * t, d), count_mask)
         return x + moe.reshape(batch, t, d)

@@ -11,7 +11,8 @@ exists in the reference (SURVEY.md §2.5: expert parallelism — NO).
 (models/hybrid.py, models/latent.py, models/longcat.py): a router over all
 experts of the deployment in float32, the `top_k` largest, gates over those
 alone (a softmax of their logits, or their sigmoid scores, chosen under a
-correction bias, normalised and scaled) or a softmax over every output of
+correction bias — over all experts or inside the best few of the router's
+groups — normalised and scaled) or a softmax over every output of
 the router, no capacity and no dropped token, plus a shared expert every
 token passes and, where the router is wider than the experts that exist,
 zero-compute experts that hand a token back. It is told which experts this
@@ -205,6 +206,21 @@ def sorted_experts(x, w_in, w_out, local, gates, dtype):
         return jnp.sum(out[place].reshape(t, k, d), axis=1)
 
 
+def within_best_groups(choice, n_group: int, topk_group: int):
+    """Group-limited selection (`noaux_tc` with `n_group` > 1): `choice`
+    [T, E] (`s + b`) with every entry outside the `topk_group` best groups
+    at -inf; a group's score is the sum of its two largest entries, and of
+    equal groups the earlier is kept (`jax.lax.top_k`'s order)."""
+    with jax.named_scope("moe/groups"):
+        t, outputs = choice.shape
+        grouped = choice.reshape(t, n_group, outputs // n_group)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, best = jax.lax.top_k(group_score, topk_group)
+        kept = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
+        return jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(
+            t, outputs)
+
+
 class DroplessMoE(nn.Module):
     """`moe(x) = sum_i g_i W_out,i (silu(a_i) * b_i)`, `[a_i | b_i] = x W_in,i`
     over the `top_k` chosen experts; plus `shared(x)`, one SwiGLU of width
@@ -213,8 +229,12 @@ class DroplessMoE(nn.Module):
     `scoring="softmax"`: the experts of largest router logit, `g = softmax`
     over those `top_k` logits alone. `scoring="sigmoid"` (the `noaux_tc`
     recipe): `s = sigmoid(logits)`; the `top_k` largest of `s + b`, `b` the
-    float32 correction bias `router_bias` (one group); `g_i = s_i`, over
-    `sum_chosen s` where `norm_topk`, times `routed_scale`.
+    float32 correction bias `router_bias`, taken inside groups: the router's
+    outputs lie in `n_group` groups of equal size, a group's score is the
+    sum of its two largest `s + b`, the `topk_group` best groups are kept
+    and the choice is made inside them (`moe/groups`; `n_group` 1 = over
+    all experts); `g_i = s_i`, over `sum_chosen s` where `norm_topk`, times
+    `routed_scale`.
     `scoring="softmax_all"`: `p = softmax(logits)` over every output of the
     router; the `top_k` largest of `p + b`; `g_i = p_i` (over their sum where
     `norm_topk`) times `routed_scale`.
@@ -262,6 +282,8 @@ class DroplessMoE(nn.Module):
     norm_topk: bool = True
     routed_scale: float = 1.0
     num_zero_experts: int = 0
+    n_group: int = 1
+    topk_group: int = 1
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
 
@@ -277,6 +299,15 @@ class DroplessMoE(nn.Module):
                 f"experts [{self.expert_offset}, {self.expert_offset + held})"
                 f" are not among the router's {self.num_experts}"
             )
+        if self.n_group > 1 and (
+                self.scoring != "sigmoid" or outputs % self.n_group
+                or not 0 < self.topk_group <= self.n_group
+                or self.top_k > self.topk_group * (outputs // self.n_group)):
+            raise ValueError(
+                f"n_group {self.n_group} / topk_group {self.topk_group}: "
+                f"groups are the sigmoid scoring's, divide the router's "
+                f"{outputs} outputs evenly and hold top_k {self.top_k} "
+                "in those kept")
         normal = nn.initializers.lecun_normal()
 
         with jax.named_scope("moe/router"):
@@ -300,7 +331,11 @@ class DroplessMoE(nn.Module):
                                   (outputs,), jnp.float32)
                 scores = nn.sigmoid(logits) if self.scoring == "sigmoid" \
                     else jax.nn.softmax(logits, axis=-1)
-                _, top_index = jax.lax.top_k(scores + bias, self.top_k)
+                choice = scores + bias
+                if self.n_group > 1:
+                    choice = within_best_groups(
+                        choice, self.n_group, self.topk_group)
+                _, top_index = jax.lax.top_k(choice, self.top_k)
                 gates = jnp.take_along_axis(scores, top_index, axis=-1)
                 if self.norm_topk:
                     gates = gates / jnp.sum(gates, axis=-1, keepdims=True)

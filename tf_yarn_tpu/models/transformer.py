@@ -16,11 +16,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional
+import math
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tf_yarn_tpu.ops.attention import attention, xla_attention
 
@@ -172,6 +174,48 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
         rx2 = x2 * cos + x1 * sin
         out = jnp.stack([rx1, rx2], axis=-1).reshape(x.shape)
         return out.astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class RotaryRecipe:
+    """One kind of layer's positional function: `rotary_dim` leading numbers
+    of a head turn at `theta`, the rest pass. `factor` > 0 is YaRN
+    (arXiv:2309.00071, as `transformers` computes it): the frequencies
+    between the correction dims of `beta_fast` and `beta_slow` turns over
+    `original_max` positions blend from their own to a `factor`-th of it,
+    and cos and sin carry `attention_factor`. Shared by the grouped-query
+    layers of models/laguna.py and the latent layers of models/latent.py."""
+
+    theta: float
+    rotary_dim: int
+    factor: float = 0.0
+    original_max: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def correction_range(self) -> Tuple[int, int]:
+        """(low, high): the pairs below `low` keep their frequency, those
+        from `high` on are interpolated."""
+        n = self.rotary_dim
+
+        def dim_of(turns):
+            return n * math.log(self.original_max / (turns * 2 * math.pi)) \
+                / (2 * math.log(self.theta))
+
+        return (max(math.floor(dim_of(self.beta_fast)), 0),
+                min(math.ceil(dim_of(self.beta_slow)), n - 1))
+
+    def inv_freq(self) -> np.ndarray:
+        n = self.rotary_dim
+        own = 1.0 / self.theta ** (np.arange(0, n, 2, dtype=np.float64) / n)
+        if not self.factor:
+            return own.astype(np.float32)
+        low, high = self.correction_range()
+        ramp = np.clip((np.arange(n // 2, dtype=np.float64) - low)
+                       / max(high - low, 0.001), 0.0, 1.0)
+        return (ramp * own / self.factor + (1.0 - ramp) * own).astype(
+            np.float32)
 
 
 RING_MULTIPLE = 16

@@ -7,6 +7,14 @@ programs' lowered texts have the digests recorded on the parent commit
 (tests/fixtures/programs_as_before.json; to record anew, on a `git archive`
 of the parent: `PYTHONPATH=<parent> python tests/test_programs_as_before.py
 <out.json>` with this file).
+
+Since PR 45 the fixture also holds the three programs of the models with
+rings and of the one in which every layer selects (`SPAN_CASES`, recorded on
+that PR's parent bcc1cda): the key span of a prefill's attention leaves every
+pack and step program, every prefill whose span is the whole call (these
+tiny buckets) and every selecting layer the text they had. The Llama-shaped
+prefills (`tiny_serve`, `tiny_granite`) left the cache path there and were
+recorded anew.
 """
 
 import hashlib
@@ -30,6 +38,7 @@ DATA = os.path.join(ROOT, "cellbench", "tests", "data")
 FIXTURE = os.path.join(ROOT, "tests", "fixtures", "programs_as_before.json")
 CASES = ("tiny_serve", "tiny_longcat", "tiny_granite")
 RING_CASES = ("tiny_dots3", "tiny_laguna")
+SPAN_CASES = RING_CASES + ("tiny_dsv32",)
 BLOCK, BUCKET, SLOTS = 8, 32, 2
 
 
@@ -46,7 +55,9 @@ def _texts(model, variables):
     engine = decode_engine.DecodeEngine(model, prompt_buckets=(BUCKET,))
     tokens = jax.ShapeDtypeStruct((1, BUCKET), jnp.int32)
     prefill = jax.jit(decode_engine.build_prefill_fn(model))
-    texts = {"prefill": prefill.lower(variables, tokens).as_text()}
+    told = (jnp.asarray(BUCKET - 3, jnp.int32),) \
+        if decode_engine.takes_prompt_len(model) else ()
+    texts = {"prefill": prefill.lower(variables, tokens, *told).as_text()}
     row = jax.eval_shape(prefill, variables, tokens)[0]
     per = model.config.max_seq_len // BLOCK
     pool = engine.make_paged_pool(variables, SLOTS * per + 1, BLOCK)
@@ -75,7 +86,7 @@ def _digests(case):
             for name, text in _texts(*_model(case)).items()}
 
 
-@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("case", CASES + SPAN_CASES)
 def test_programs_without_a_ring_have_the_parents_text(case):
     with open(FIXTURE) as fh:
         before = json.load(fh)
@@ -129,5 +140,6 @@ def test_the_length_is_an_argument_only_where_a_ring_is_written(case):
 if __name__ == "__main__":
     with open(sys.argv[1], "w") as out:
         json.dump({"jax": jax.__version__,
-                   "recorded": "the parent of PR 43 (e117643)",
-                   **{case: _digests(case) for case in CASES}}, out, indent=1)
+                   "recorded": "the parent commit",
+                   **{case: _digests(case) for case in CASES + SPAN_CASES}},
+                  out, indent=1)

@@ -644,6 +644,8 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._prefills_ceiling", _ADVISORY),
                 ("scheduler._prefills_floor", _ADVISORY),
                 ("scheduler._prefill_pad_tokens", _ADVISORY),
+                ("scheduler._prefill_keys_formed", _ADVISORY),
+                ("scheduler._prefill_keys_visible", _ADVISORY),
                 ("scheduler._kv_token_steps", _ADVISORY),
                 ("scheduler._kv_read_token_steps", _ADVISORY),
                 ("scheduler._slot_steps", _ADVISORY),
@@ -677,6 +679,8 @@ def default_scenarios() -> List[Scenario]:
                 ("scheduler._prefills_ceiling", _ADVISORY),
                 ("scheduler._prefills_floor", _ADVISORY),
                 ("scheduler._prefill_pad_tokens", _ADVISORY),
+                ("scheduler._prefill_keys_formed", _ADVISORY),
+                ("scheduler._prefill_keys_visible", _ADVISORY),
                 ("scheduler._kv_token_steps", _ADVISORY),
                 ("scheduler._kv_read_token_steps", _ADVISORY),
                 ("scheduler._slot_steps", _ADVISORY),

@@ -114,7 +114,7 @@ import numpy as np
 from tf_yarn_tpu import telemetry
 from tf_yarn_tpu.models.generate import _sample
 from tf_yarn_tpu.models.spec import verify_window
-from tf_yarn_tpu.models.transformer import PagedContext
+from tf_yarn_tpu.models.transformer import PagedContext, prefill_key_pairs
 
 _logger = logging.getLogger(__name__)
 
@@ -1424,6 +1424,18 @@ class DecodeEngine:
         kinds = self.model.cache_leaf_kinds()
         return self._prefill_takes_len \
             and all(kinds[name][0] == RING for name in held)
+
+    def prefill_key_pairs(self, bucket: int, kept: int) -> Tuple[int, int]:
+        """(formed, visible) query-key pairs, a head, of the attention of
+        one `prefill` of `bucket` tokens that keeps `kept`
+        (`transformer.prefill_key_pairs`, over the layers the model
+        declares: `prefill_attention_layers`; (0, 0) for a model that
+        declares none). Host arithmetic, no device read."""
+        layers = getattr(self.model, "prefill_attention_layers", None)
+        if layers is None:
+            return 0, 0
+        return prefill_key_pairs(
+            bucket, kept, layers(), told=self._prefill_takes_len)
 
     def prefill(self, params, prompt, length=None):
         """Public compiled prefill: [B, F] prompt -> (cache, last

@@ -43,6 +43,7 @@ from tf_yarn_tpu.models.transformer import (
     CACHE_LEAF_KINDS,
     EMBED,
     HEADS,
+    PREFILL_QUERY_BLOCK,
     VOCAB,
     Attention,
     RMSNorm,
@@ -315,6 +316,11 @@ class HybridLM(nn.Module):
     def cache_leaf_kinds(self):
         return {**CACHE_LEAF_KINDS,
                 "ssm_state": ("slot", None), "conv_state": ("slot", None)}
+
+    def prefill_attention_layers(self):
+        """`transformer.prefill_key_pairs`' layers: the attention layers."""
+        return tuple((0, PREFILL_QUERY_BLOCK)
+                     for kind in self.config.layer_types if kind == ATTENTION)
 
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True,

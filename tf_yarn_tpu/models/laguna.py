@@ -45,6 +45,7 @@ from tf_yarn_tpu.models.transformer import (
     ATTENTION_READS,
     CACHE_LEAF_KINDS,
     EMBED,
+    PREFILL_QUERY_BLOCK,
     VOCAB,
     Attention,
     RMSNorm,
@@ -241,6 +242,14 @@ class LagunaLM(nn.Module):
     def cache_leaf_kinds(self):
         return {**CACHE_LEAF_KINDS, "window_key": ("ring", None),
                 "window_value": ("ring", None)}
+
+    def prefill_attention_layers(self):
+        """`transformer.prefill_key_pairs`' layers."""
+        cfg = self.config
+        return tuple(
+            (cfg.window, cfg.query_block) if kind == SLIDING
+            else (0, cfg.query_block or PREFILL_QUERY_BLOCK)
+            for kind in cfg.layer_types)
 
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True,

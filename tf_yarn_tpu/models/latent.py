@@ -88,10 +88,12 @@ from tf_yarn_tpu.models.transformer import (
     SwiGLU,
     TransformerConfig,
     _partitioned,
+    key_span,
     map_query_blocks,
     ring_after_prefill,
     ring_rows,
     ring_valid,
+    span_width,
 )
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -322,7 +324,11 @@ def expanded_attention(q_n, q_r, rows, w_kvb, sizes: AttentionSizes, *,
     are cached), w_kvb [kv_rank, H, d_n + d_v] -> [B, S, H, d_v] float32.
     `window` > 0 keeps `t - j < window`; `select` (q_index, weight, keys,
     top_k) keeps the indexer's `top_k` largest `j <= t`. A block of queries
-    at a time: the largest array is [B, H, query_block, S] float32.
+    at a time, over the keys the block can see (`transformer.key_span`):
+    the largest array is [B, H, query_block, W] float32, W = `span_width`
+    (`query_block + window - 1` in whole lanes) under a window alone, S
+    without one or with `select` (the top-k needs every causal key's
+    score; the half above the diagonal is formed and masked).
     `prompt_len`: `map_query_blocks`' (rows past it come out zero)."""
     batch, s, heads, _ = q_n.shape
     c = rows[..., :sizes.kv_rank]
@@ -340,14 +346,22 @@ def expanded_attention(q_n, q_r, rows, w_kvb, sizes: AttentionSizes, *,
             value.reshape((batch, nb, block) + value.shape[2:]), 1, 0)
 
     scale = sizes.softmax_scale
-    keys = jnp.arange(s)[None, :]
+    width = s if select is not None else span_width(s, block, window)
+    keys = jnp.arange(width)[None, :]
 
     def some_rows(args):
         start, qn_block, qr_block, *index_block = args
         at = (start + jnp.arange(block))[:, None]
-        mask = jnp.broadcast_to(at >= keys, (batch, block, s))
+        kn_seen, kr_seen, v_seen, seen = k_n, k_r, v, keys
+        if width < s:
+            offset, _ = key_span(start, s, block, window)
+            kn_seen, kr_seen, v_seen = (
+                jax.lax.dynamic_slice_in_dim(value, offset, width, axis=1)
+                for value in (k_n, k_r, v))
+            seen = offset + keys
+        mask = jnp.broadcast_to(at >= seen, (batch, block, width))
         if window:
-            mask &= at - keys < window
+            mask &= at - seen < window
         if select is not None:
             with jax.named_scope("indexer/scores"):
                 score = jnp.where(
@@ -355,15 +369,15 @@ def expanded_attention(q_n, q_r, rows, w_kvb, sizes: AttentionSizes, *,
             with jax.named_scope("indexer/topk"):
                 mask &= top_k_mask(score, min(select[3], s))
         with jax.named_scope("latent/scores"):
-            scores = (jnp.einsum("bthd,bjhd->bhtj", qn_block, k_n,
+            scores = (jnp.einsum("bthd,bjhd->bhtj", qn_block, kn_seen,
                                  preferred_element_type=jnp.float32)
-                      + jnp.einsum("bthd,bjd->bhtj", qr_block, k_r,
+                      + jnp.einsum("bthd,bjd->bhtj", qr_block, kr_seen,
                                    preferred_element_type=jnp.float32)) * scale
             scores = jnp.where(mask[:, None], scores, -jnp.inf)
             weights = jax.nn.softmax(scores, axis=-1)
         with jax.named_scope("latent/values"):
-            return jnp.einsum("bhtj,bjhd->bthd", weights.astype(dtype), v,
-                              preferred_element_type=jnp.float32)
+            return jnp.einsum("bhtj,bjhd->bthd", weights.astype(dtype),
+                              v_seen, preferred_element_type=jnp.float32)
 
     inputs = [jnp.arange(nb) * block, blocks(q_n.astype(dtype)),
               blocks(q_r.astype(dtype))]
@@ -820,6 +834,12 @@ class LatentLM(nn.Module):
     def cache_leaf_kinds(self):
         return {"latent": ("paged", -2), "index_key": ("paged", -2),
                 "window_latent": ("ring", None), "cache_index": ("index", None)}
+
+    def prefill_attention_layers(self):
+        """`transformer.prefill_key_pairs`' layers."""
+        cfg = self.config
+        return tuple((cfg.window if kind == SLIDING else 0, cfg.query_block)
+                     for kind in cfg.layer_types)
 
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True,

@@ -163,6 +163,11 @@ class LongcatLM(nn.Module):
     def cache_leaf_kinds(self):
         return {"latent": ("paged", -2), "cache_index": ("index", None)}
 
+    def prefill_attention_layers(self):
+        """`transformer.prefill_key_pairs`' layers: two sublayers a layer."""
+        cfg = self.config
+        return ((0, cfg.query_block),) * cfg.n_attention_layers
+
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True,
                  return_hidden: bool = False, decode: bool = False,

@@ -272,15 +272,61 @@ def map_query_blocks(fn, blocks, block: int, prompt_len=None):
         jnp.zeros((nb,) + one.shape, one.dtype))
 
 
+SPAN_MULTIPLE = 128
+# Queries a block of a prefill's attention where a layer names no other.
+PREFILL_QUERY_BLOCK = 256
+
+
+def span_width(s: int, block: int, window: int = 0) -> int:
+    """How many keys a prefill's scores are formed over for one block of
+    `block` queries of a call of `s` tokens: the `block + window - 1` keys
+    its queries can see between them, rounded up to whole lanes; all `s`
+    where there is no window or the call is no longer than that."""
+    if not window:
+        return s
+    return min(s, -(-(block + window - 1) // SPAN_MULTIPLE) * SPAN_MULTIPLE)
+
+
+def key_span(start, s: int, block: int, window: int = 0):
+    """(offset, width): the keys `offset <= j < offset + width` hold every
+    key inside the causal mask and the window of the queries `start <= t <
+    start + block` (`start` may be traced; `width` is static, `span_width`):
+    the span ends where the block ends and is kept inside the call."""
+    width = span_width(s, block, window)
+    return jnp.clip(start + block - width, 0, s - width), width
+
+
+def prefill_key_pairs(s: int, kept: int, layers, told: bool):
+    """(formed, visible) query-key pairs, a head, of one prefill of `s`
+    tokens of which the first `kept` are the prompt's. `layers`: each
+    attention layer's `(window, query_block)` (a model's
+    `prefill_attention_layers`). Formed: the computed blocks of queries
+    times their `span_width` (`told`: the prefill is given `prompt_len`, so
+    blocks past the prompt are not computed). Visible: the pairs inside the
+    causal mask and the window of the prompt's rows. Host arithmetic on
+    static facts; the model calls the same `span_width`."""
+    formed = visible = 0
+    for window, query_block in layers:
+        block = min(query_block or s, s)
+        blocks = -(-(kept if told else s) // block)
+        formed += blocks * block * span_width(s, block, window)
+        # row t sees min(t + 1, window) keys: a triangle, then full rows
+        full = min(kept, window) if window else kept
+        visible += full * (full + 1) // 2 + (kept - full) * full
+    return formed, visible
+
+
 def own_token_attention(q, k, v, *, window: int = 0,
                         softmax_scale: Optional[float] = None,
                         query_block: int = 256, prompt_len=None):
     """Causal attention of a call's own tokens, from position 0, a block of
     queries at a time: q [B, S, H, D], k, v [B, S, Hkv, D] -> [B, S, H, D]
     float32. `window` > 0 keeps the keys `t - j < window` (the query itself
-    counts). Query head h reads KV head h // (H // Hkv). The largest array
-    is the scores of one block, [B, H, query_block, S] float32 (the plain
-    masked form: scores past the window are formed and masked).
+    counts). Query head h reads KV head h // (H // Hkv). Scores are formed
+    over the keys a block can see and no others (`key_span`): the largest
+    array is [B, H, query_block, W] float32, W = `span_width`: S without a
+    window (the causal half above the diagonal is formed and masked),
+    `query_block + window - 1` in whole lanes with one.
     `prompt_len`: `map_query_blocks`' (rows past it come out zero)."""
     batch, s, heads, dim = q.shape
     n_kv = k.shape[2]
@@ -290,21 +336,28 @@ def own_token_attention(q, k, v, *, window: int = 0,
     nb = (s + pad) // block
     grouped = jnp.pad(q, [(0, 0), (0, pad), (0, 0), (0, 0)]).reshape(
         batch, nb, block, n_kv, heads // n_kv, dim)
-    keys = jnp.arange(s)[None, :]
+    width = span_width(s, block, window)
+    keys = jnp.arange(width)[None, :]
 
     def some_rows(args):
         start, q_block = args
         at = (start + jnp.arange(block))[:, None]
-        mask = at >= keys
+        k_seen, v_seen, seen = k, v, keys
+        if width < s:
+            offset, _ = key_span(start, s, block, window)
+            k_seen = jax.lax.dynamic_slice_in_dim(k, offset, width, axis=1)
+            v_seen = jax.lax.dynamic_slice_in_dim(v, offset, width, axis=1)
+            seen = offset + keys
+        mask = at >= seen
         if window:
-            mask &= at - keys < window
+            mask &= at - seen < window
         with jax.named_scope("attention/scores"):
-            scores = jnp.einsum("bqgrd,bkgd->bgrqk", q_block, k,
+            scores = jnp.einsum("bqgrd,bkgd->bgrqk", q_block, k_seen,
                                 preferred_element_type=jnp.float32) * scale
             scores = jnp.where(mask, scores, -jnp.inf)
             probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
         with jax.named_scope("attention/values"):
-            return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v,
+            return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_seen,
                               preferred_element_type=jnp.float32)
 
     out = map_query_blocks(
@@ -399,10 +452,15 @@ class Attention(nn.Module):
     `t - j < window`, and a decode call keeps the last rows in a ring
     (`window_key`, `window_value` [B, ring_rows(window), Hkv, D], position
     p at row `p % rows`: `ring` leaves to the serving engine) in place of
-    `cached_key` / `cached_value`. `query_block` > 0: a prefill from an
-    empty cache attends over its own tokens, that many queries at a time
-    (`own_token_attention`), and not over all `max_seq_len` rows of the
-    cache it has just made.
+    `cached_key` / `cached_value`. A prefill from an empty cache (more than
+    one token, not the int8 cache) always attends over its own tokens
+    (`own_token_attention`: scores over the keys a block of queries can
+    see, never over the `max_seq_len` rows of the cache it has just made);
+    `query_block` is how many queries at a time, `PREFILL_QUERY_BLOCK` where
+    a layer says 0. Attention over the cache is for a call onto a cache
+    that is already there (a continuation, the speculative window) and for
+    the int8 cache. Outside decode, `query_block` or `window` > 0 asks for
+    `own_token_attention` in place of `config.attention_impl`.
 
     `count_mask` [B] marks the rows of a one-token decode call whose cache
     reads are sown into `cache_stats` (`ATTENTION_READS`: rows live and
@@ -525,13 +583,14 @@ class Attention(nn.Module):
                 _append(cached_k, k.astype(cfg.dtype))
                 _append(cached_v, v.astype(cfg.dtype))
             cache_index.value = idx + s
-            if self.query_block and s > 1 and fresh and not int8_cache:
+            if s > 1 and fresh and not int8_cache:
                 # A prefill from an empty cache: its own tokens are all the
                 # keys there are.
                 out = own_token_attention(
                     q, k.astype(cfg.dtype), v.astype(cfg.dtype),
                     softmax_scale=cfg.attention_scale,
-                    query_block=self.query_block, prompt_len=prompt_len)
+                    query_block=self.query_block or PREFILL_QUERY_BLOCK,
+                    prompt_len=prompt_len)
             elif int8_cache and s == 1:
                 # Steady-state decode: the pallas kernel streams the int8
                 # cache directly, dequantizing tile-by-tile in VMEM
@@ -894,6 +953,11 @@ class Transformer(nn.Module):
         the end of [..., seq, kv_heads, head_dim | 1]; `cache_index` is
         the slot's position."""
         return CACHE_LEAF_KINDS
+
+    def prefill_attention_layers(self):
+        """`(window, query_block)` of each attention layer as a prefill
+        from an empty cache runs it (`prefill_key_pairs`)."""
+        return ((0, PREFILL_QUERY_BLOCK),) * self.config.n_layers
 
     @property
     def prompt_rows_causal(self) -> bool:

@@ -474,6 +474,14 @@ class SlotScheduler:
         self._prefills_ceiling = 0
         self._prefills_floor = 0
         self._prefill_pad_tokens = 0
+        # Query-key pairs, a head, over the blocking prefills' attention
+        # layers: those the scores were formed over and those a prompt row
+        # can see (`DecodeEngine.prefill_key_pairs`; 0 on an engine
+        # without it).
+        self._prefill_key_pairs = getattr(
+            engine, "prefill_key_pairs", lambda bucket, kept: (0, 0))
+        self._prefill_keys_formed = 0
+        self._prefill_keys_visible = 0
         self._kv_token_steps = 0
         self._kv_read_token_steps = 0
         self._slot_steps = 0
@@ -1163,10 +1171,13 @@ class SlotScheduler:
             bucket, kept = self.engine.slot_prefill_len(
                 len(prompt), self._ceiling_prefill)
             prefill_len = prefilled = kept
+            formed, visible = self._prefill_key_pairs(bucket, kept) \
+                if bucket else (0, 0)
             with telemetry.span(
                 "serving/prefill", request=request.id,
                 request_id=request.public_id, prefill=kept,
-                bucket=bucket, kept=kept,
+                bucket=bucket, kept=kept, prefill_keys_formed=formed,
+                prefill_keys_visible=visible,
             ):
                 row_cache = None
                 if bucket > 0:
@@ -1189,6 +1200,8 @@ class SlotScheduler:
                         self._block_size,
                     )
                     self._queued(2, bucket)
+                    self._prefill_keys_formed += formed
+                    self._prefill_keys_visible += visible
                     if self._ceiling_prefill and kept == len(prompt) - 1:
                         self._prefills_ceiling += 1
                         self._prefill_pad_tokens += bucket - kept
@@ -2345,6 +2358,10 @@ class SlotScheduler:
             "prefills_ceiling": self._prefills_ceiling,
             "prefills_floor": self._prefills_floor,
             "prefill_pad_tokens": self._prefill_pad_tokens,
+            # Query-key pairs a head the prefills' scores were formed over,
+            # and those inside the masks of the prompts' rows.
+            "prefill_keys_formed": self._prefill_keys_formed,
+            "prefill_keys_visible": self._prefill_keys_visible,
             "kv_token_steps": self._kv_token_steps,
             "kv_read_token_steps": self._kv_read_token_steps,
             "slot_steps": self._slot_steps,

@@ -15,6 +15,13 @@ pack and step program, every prefill whose span is the whole call (these
 tiny buckets) and every selecting layer the text they had. The Llama-shaped
 prefills (`tiny_serve`, `tiny_granite`) left the cache path there and were
 recorded anew.
+
+Since PR 46 `DroplessMoE` loops over the held experts that a token reached
+where the shapes say it pays (`moe.loops_over_touched`): no tiny
+configuration's shapes do (8 to 16 small experts), so no digest was recorded
+anew, `tiny_longcat`'s and `tiny_dsv32`'s included: every expert layer here
+lowers to the one product's text, and the loop is held to the one product
+by `tests/test_moe.py`.
 """
 
 import hashlib

@@ -8,7 +8,9 @@ the CPU rig) either accepts the kernel or raises what the chip would
 raise. Interpret mode cannot see a refused vector layout, a mis-tiled
 block or a kernel that outgrows VMEM; this can, at no chip time.
 Results and times still come only from a run on the chip
-(`python chip_smoke.py`).
+(`python chip_smoke.py`). One plain-XLA loop is held here too, by its
+compiled text: `moe.touched_experts` at the two benchmark shapes that take
+it reads each expert's matrices inside its products.
 """
 
 import functools
@@ -191,3 +193,41 @@ def test_kernel_compiles_for_v5e(name, chip):
         f"{name}: compiled without a Mosaic kernel — the pallas call "
         "was lowered away or ran in interpret mode"
     )
+
+
+@pytest.mark.parametrize("tokens,d_model,width", [
+    (32, 7168, 2048),   # DeepSeek-V3.2's step: 32 slots over 88 MB experts
+    (64, 6144, 2048),   # LongCat's: 64 slots over 75 MB experts
+])
+def test_the_loop_over_touched_experts_reads_its_matrices_in_the_products(
+        tokens, d_model, width, chip):
+    """`moe.touched_experts` at the two shapes that take it: the loop is a
+    `while`, and an iteration's slice of an expert's matrices is an operand
+    of the product's fusion. No instruction outside a fusion makes an array
+    of one expert's `w_in` or `w_out` (a `copy` or a `dynamic-slice` there
+    would read and write 88 MB an iteration before the product reads it
+    again: what the loop exists to save)."""
+    import re
+
+    from tf_yarn_tpu.models import moe
+
+    held = 16
+    compiled = jax.jit(functools.partial(
+        moe.touched_experts, dtype=jnp.bfloat16)).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        for shape, dtype in [
+            ((tokens, d_model), jnp.bfloat16),
+            ((held, d_model, 2 * width), jnp.bfloat16),
+            ((held, width, d_model), jnp.bfloat16),
+            ((tokens, held), jnp.float32), ((held,), jnp.bool_)])).compile()
+    text = compiled.as_text()
+    assert re.search(r"= \([^\n]*\) while\(", text), "no loop was compiled"
+    one_expert = re.compile(
+        rf"= bf16\[(1,)?({d_model},{2 * width}|{width},{d_model})\]")
+    computation, made = "", []
+    for line in text.splitlines():
+        if line.endswith("{") and "->" in line:
+            computation = line.split()[0].lstrip("%")
+        elif one_expert.search(line) and "fused_computation" not in computation:
+            made.append(line.strip()[:160])
+    assert not made, made

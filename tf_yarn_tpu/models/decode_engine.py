@@ -558,6 +558,16 @@ def _new_rows(cache, layout, length, width: int):
     return jax.tree_util.tree_map(leaf, cache, layout)
 
 
+def _moe_stats(stats):
+    """What the expert layers sowed into `moe_stats`, a list a name in the
+    layers' order: (`counts`, `streamed`); the second is empty unless the
+    layers loop over the experts a token reached (`moe.loops_over_touched`)."""
+    by_name = {"counts": [], "streamed": []}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(stats):
+        by_name[path[-2].key].append(leaf)  # .../<name>/0: sown once a call
+    return by_name["counts"], by_name["streamed"]
+
+
 def build_paged_state_step_fn(model, block_size: int, temperature: float,
                               top_k: Optional[int], top_p: Optional[float],
                               with_logits: bool = False,
@@ -579,13 +589,16 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
     admission overwrites both (`write_slot_state`).
     `counts` stacks what the model's layers counted into `moe_stats` for
     the active slots (table row not all trash) — `[layers, 1 + held
-    experts]`: assignments, then tokens that reached each held expert —
-    and rides back with `emitted`. A model whose attention layers count
-    what they read (`cache_stats`, summed over layers: one vector, named by
-    the model's `READS`) has it appended as a sixth output. Where a slot's
-    token and rng row come from (`_feed`), sampling and the RNG discipline
-    are `build_paged_step_fn`'s. `with_logits` appends the
-    step's logits [S, V] last (the tests compare them with a reference).
+    experts]`: assignments, then tokens that reached each held expert;
+    where the layers loop over the held experts a token reached
+    (`moe.loops_over_touched`), one more at the end: how many the loop
+    multiplied — and rides back with `emitted`. A model whose attention
+    layers count what they read (`cache_stats`, summed over layers: one
+    vector, named by the model's `READS`) has it appended as a sixth
+    output. Where a slot's token and rng row come from (`_feed`), sampling
+    and the RNG discipline are `build_paged_step_fn`'s. `with_logits`
+    appends the step's logits [S, V] last (the tests compare them with a
+    reference).
     """
     del block_size  # the pool's own shape says it
 
@@ -603,9 +616,12 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
         emitted, rngs = _sample_slots(
             logits[:, -1], tokens, rngs, sample_mask, temperature, top_k,
             top_p)
-        counted = jax.tree_util.tree_leaves(new.get("moe_stats", {}))
+        counted, streamed = _moe_stats(new.get("moe_stats", {}))
         counts = jnp.stack(counted) if counted \
             else jnp.zeros((0, 0), jnp.int32)
+        if streamed:
+            counts = jnp.concatenate(
+                [counts, jnp.stack(streamed)[:, None]], axis=1)
         out = (_merge_pool_tree(pool, dict(new["kv_pool"])),
                _merge_pool_tree(state, dict(new["cache"])),
                emitted, rngs, counts)

@@ -134,6 +134,77 @@ def sorts_by_expert(held: int, tokens: int, top_k: int) -> bool:
     return held * tokens > ROWS_OVER * (tokens * top_k + held * TILE)
 
 
+# Bytes of experts' matrices that a loop over the touched experts has to be
+# expected to skip, an iteration it runs, before `DroplessMoE` takes it. An
+# iteration costs 5.8 us more than its expert's part of the one product (a
+# v5e, 32 tokens over 88 MB experts: two products that each start their
+# stream anew, at 89 % of the HBM peak where the one product holds 92 %, and
+# 2 us of the loop's own; PERF.md section 5, PR 46), in which the chip
+# streams 4.8 MB: three times that, so that the loop is taken where it is
+# expected to save twice what it costs and not where the two forms tie.
+LOOP_SKIPS_BYTES = 16 * 2**20
+
+
+def loops_over_touched(held: int, tokens: int, top_k: int, outputs: int,
+                       expert_bytes: int) -> bool:
+    """Whether `DroplessMoE` computes `held` experts of `expert_bytes` each
+    (both matrices as held) over `tokens` tokens of `top_k` choices among
+    the router's `outputs` in a loop over the experts that some token chose
+    (`touched_experts`), from the shapes alone: not where it sorts
+    (`sorts_by_expert`), and where the matrices the loop is expected to skip
+    for each iteration it runs outweigh `LOOP_SKIPS_BYTES`. Under even
+    routing an expert goes untouched with probability `u = (1 - top_k /
+    outputs) ** tokens`, so the loop runs `(1 - u) held` iterations and
+    skips `u held` experts: `u / (1 - u)` of an expert an iteration. A step
+    of 32 slots at 8 of 256 over 88 MB experts does (u 0.36: 50 MB), and one
+    of 64 at 12 of 768 over 75 MB (0.37: 43 MB), as does a prefill of as few
+    tokens; 64 slots at 8 of 256 do not (u 0.13: 7 MB of 46 MB experts, 1 MB
+    of 6 MB ones), nor 32 at 10 of 72 (0.008), nor any call of 128 tokens
+    or more (under 1 %)."""
+    if sorts_by_expert(held, tokens, top_k):
+        return False
+    untouched = (1.0 - top_k / outputs) ** tokens
+    return untouched * expert_bytes > LOOP_SKIPS_BYTES * (1.0 - untouched)
+
+
+def touched_experts(x, w_in, w_out, weights, touched, dtype):
+    """`sum_e weights[t, e] E_e(x[t])` over the held experts that `touched`
+    marks, the others' matrices never read: x [T, D], w_in [held, D, 2 F],
+    w_out [held, F, D], weights [T, held] (a token's gate for each held
+    expert it chose, zero elsewhere), touched [held] bool -> ([T, D]
+    float32, the number of experts multiplied).
+
+    A loop over the marked experts, each multiplying all T rows by its two
+    matrices, sliced where they lie: nothing is sorted, scattered or padded,
+    since T is a step's slots. The products and their types are the one
+    product's over every held expert; the experts' float32 partial sums are
+    added in another order."""
+    t, d = x.shape
+    width = w_in.shape[2] // 2
+    with jax.named_scope("moe/dispatch"):
+        # The marked experts first, in their own order.
+        order = jnp.argsort(~touched, stable=True)
+        n_touched = jnp.sum(touched, dtype=jnp.int32)
+
+    def one_expert(index, out):
+        expert = order[index]
+        w_a = jax.lax.dynamic_index_in_dim(w_in, expert, 0, False)
+        w_b = jax.lax.dynamic_index_in_dim(w_out, expert, 0, False)
+        with jax.named_scope("moe/experts"):
+            hidden = jnp.einsum("td,df->tf", x, w_a.astype(dtype),
+                                preferred_element_type=jnp.float32)
+            act = nn.silu(hidden[:, :width]) * hidden[:, width:]
+        with jax.named_scope("moe/combine"):
+            gate = jax.lax.dynamic_index_in_dim(weights, expert, 1, True)
+            return out + jnp.einsum(
+                "tf,fd->td", (act * gate).astype(dtype), w_b.astype(dtype),
+                preferred_element_type=jnp.float32)
+
+    out = jax.lax.fori_loop(
+        0, n_touched, one_expert, jnp.zeros((t, d), jnp.float32))
+    return out, n_touched
+
+
 def sorted_experts(x, w_in, w_out, local, gates, dtype):
     """`sum_j gates[t, j] E_local[t, j](x[t])` over the chosen experts that
     are held, each token computed by its own experts alone: x [T, D], w_in
@@ -259,17 +330,30 @@ class DroplessMoE(nn.Module):
     a token did not choose the expert: no token is dropped and no
     [T, E, C] dispatch tensor exists. At decode (T = slots) that streams
     each held expert's matrices once, which is what the step is bound by.
+    The same sum has two more schedules, chosen from the shapes alone.
     Where that product would be many times the assignments' rows
-    (`sorts_by_expert`: from the shapes alone, a prefill with every expert
-    of a layer held), the tokens are sorted by expert and each is computed
-    by its own experts alone (`sorted_experts`): the same sum, dropless
-    still.
+    (`sorts_by_expert`: a prefill with every expert of a layer held), the
+    tokens are sorted by expert and each is computed by its own experts
+    alone (`sorted_experts`). Where so few tokens choose among so many
+    outputs that a good part of the held experts is nobody's choice, and
+    an expert's matrices are large (`loops_over_touched`: a step of 32-64
+    slots over 16 experts of 75-88 MB), a loop multiplies all T tokens by
+    each expert that some token chose and reads no other
+    (`touched_experts`): the step streams the touched experts' matrices
+    once, two thirds of what is held. Dropless still, and the same
+    products in the same types on all three.
 
     `count_mask` [T] marks the tokens whose routing is counted into the
     mutable `moe_stats` collection (`counts` [1 + held]: their assignments
     over all experts, then the tokens that reached each held expert; with
     `num_zero_experts`, one more at the end: their assignments to
     zero-compute experts); without that collection nothing is counted.
+    A step marks its active slots. The loop then runs over the experts
+    that a marked token chose, so that what it streams is what `counts`
+    says was reached, and sows its trip count beside them (`streamed`); a
+    token left out is a free slot's, whose row is written to the trash
+    block and read by nobody: it gets the part of its sum that the marked
+    tokens' experts cover.
     """
 
     num_experts: int
@@ -372,6 +456,17 @@ class DroplessMoE(nn.Module):
             # Many experts over many tokens (a prefill with every expert of
             # the layer held): each token by its own experts alone.
             out = sorted_experts(x, w_in, w_out, local, gates, self.dtype)
+        elif loops_over_touched(held, t, self.top_k, outputs,
+                                3 * d * width * w_in.dtype.itemsize):
+            # Few tokens over few large experts (a decode step): a third of
+            # the held experts is chosen by nobody and is not read.
+            with jax.named_scope("moe/dispatch"):
+                touched = jnp.any(
+                    chose if count_mask is None else counted, axis=(0, 1))
+            out, streamed = touched_experts(
+                x, w_in, w_out, weights, touched, self.dtype)
+            if count_mask is not None:
+                self.sow("moe_stats", "streamed", streamed)
         else:
             with jax.named_scope("moe/experts"):
                 hidden = jnp.einsum("td,edf->etf", x, w_in.astype(self.dtype),

@@ -628,6 +628,7 @@ class SlotScheduler:
         # What the expert layers counted (a model with `moe_stats`).
         self._moe = {"assignments": 0, "assignments_here": 0,
                      "layer_steps": 0, "experts_touched": 0,
+                     "experts_streamed": 0,
                      "load_max_sum": 0, "load_mean_sum": 0.0}
         if self._state_leaves:
             self._refuse_for_state(kv_host_blocks)
@@ -1906,11 +1907,24 @@ class SlotScheduler:
         """One step's `[layers, 1 + held experts]`: the active slots'
         assignments over all the deployment's experts, then the tokens
         that reached each expert held here (and, of a router with
-        zero-compute experts, the assignments to those last). A layer-step
-        is one expert layer in one step. `/stats` shows the tally as
-        `moe_*`, `moe_tokens_by_expert` one number a held expert."""
+        zero-compute experts, the assignments to those after them; and,
+        of layers that loop over the held experts a token reached, how
+        many the loop multiplied last: the row is then one wider than the
+        model's shapes say). A layer-step is one expert layer in one step.
+        `/stats` shows the tally as `moe_*`, `moe_tokens_by_expert` one
+        number a held expert; `moe_experts_streamed` counts the held
+        experts whose matrices a layer-step read: the loop's trips, or
+        all of them where one product runs over every held expert."""
         tally = self._moe
-        if getattr(self.engine.model.config, "num_zero_experts", 0):
+        config = self.engine.model.config
+        held = config.num_experts_here
+        zero = bool(getattr(config, "num_zero_experts", 0))
+        if counts.shape[1] > 1 + held + zero:
+            tally["experts_streamed"] += int(counts[:, -1].sum())
+            counts = counts[:, :-1]
+        else:
+            tally["experts_streamed"] += held * counts.shape[0]
+        if zero:
             tally["assignments_zero"] = tally.get("assignments_zero", 0) \
                 + int(counts[:, -1].sum())
             counts = counts[:, :-1]
@@ -2424,6 +2438,8 @@ class SlotScheduler:
             ) if tally["load_mean_sum"] else None
             snap["moe_experts_touched_per_layer_step"] = round(
                 tally["experts_touched"] / tally["layer_steps"], 4)
+            snap["moe_experts_streamed_per_layer_step"] = round(
+                tally["experts_streamed"] / tally["layer_steps"], 4)
         if self._windowed:
             snap["spec"] = {
                 "proposed_tokens": self._spec_proposed,

@@ -280,7 +280,8 @@ def compile_latent(devices, name="latent",
         lambda a, lay: jax.ShapeDtypeStruct((slots,) + a.shape, a.dtype)
         if lay.kind in de.HELD_A_SLOT else None, row, layout))
     # A model with rings is told where the prompt ends: a traced scalar.
-    length = (arg(jnp.int32),) if de.takes_prompt_len(model) else ()
+    length = (arg(jnp.int32),) \
+        if model.serving_contract().takes_prompt_len else ()
     for bucket in buckets:
         began = time.monotonic()
         prompt = arg(jnp.int32, 1, bucket)

@@ -8,6 +8,7 @@ approximations), repeated same-bucket calls must hit the compile cache
 contain zero per-token host syncs.
 """
 
+import dataclasses
 import functools
 
 import flax.linen as nn
@@ -508,43 +509,48 @@ def test_slot_prefill_len_takes_the_bucket_above_and_keeps_the_prompt(
 
 
 def test_only_a_model_whose_prompt_rows_are_causal_takes_the_ceiling_rule():
-    """The engine reads the rule off the model: a dense causal transformer
-    says its prompt rows do not depend on later tokens; one with the
-    capacity-bounded `MoEMlp` (capacity is counted over the tokens of the
-    call, pads included) says they do; a model that says nothing keeps the
-    floor rule."""
+    """The engine reads the rule off the model's contract: a dense causal
+    transformer says its prompt rows do not depend on later tokens; one
+    with the capacity-bounded `MoEMlp` (capacity is counted over the tokens
+    of the call, pads included) says they do; a model that says nothing is
+    refused by the name of what it lacks, not served by the floor rule."""
     model, params = _model_and_params()
     assert _engine(model).ceiling_prefill(params) is True
     moe, moe_params = _model_and_params(moe_experts=4)
-    assert moe.prompt_rows_causal is False
+    assert moe.serving_contract().rows_causal is False
     assert _engine(moe).ceiling_prefill(moe_params) is False
 
     class Silent:
         config = model.config
 
-    assert _engine(Silent()).ceiling_prefill(params) is False
+    with pytest.raises(ValueError, match=r"Silent.*serving_contract\(\)"):
+        _engine(Silent())
+
+
+def _declares(base, **fields):
+    """`base` with its contract's `fields` replaced."""
+
+    class Declares(base):
+        def serving_contract(self):
+            return dataclasses.replace(
+                base.serving_contract(self), **fields)
+
+    return Declares
 
 
 def _ring_model(kind):
-    """Tiny models that hold leaves once a slot, and what they say."""
+    """Tiny models that hold leaves once a slot, and what they declare."""
     from tf_yarn_tpu.models import hybrid, laguna, latent
 
-    class Silent(laguna.LagunaLM):
-        prompt_rows_causal = False
+    Silent = _declares(laguna.LagunaLM, rows_causal=False)
+    Untold = _declares(laguna.LagunaLM, takes_prompt_len=False)
+    NotCausalHybrid = _declares(hybrid.HybridLM, rows_causal=False)
 
     class RingAsState(laguna.LagunaLM):
-        def cache_leaf_kinds(self):
-            return {**super().cache_leaf_kinds(), "window_key": ("slot", None)}
-
-    class Untold(laguna.LagunaLM):
-        def __call__(self, tokens, decode=False, count_mask=None,
-                     paged_ctx=None):
-            return laguna.LagunaLM.__call__(
-                self, tokens, decode=decode, count_mask=count_mask,
-                paged_ctx=paged_ctx)
-
-    class CausalHybrid(hybrid.HybridLM):
-        prompt_rows_causal = True
+        def serving_contract(self):
+            contract = super().serving_contract()
+            return dataclasses.replace(contract, leaf_kinds={
+                **contract.leaf_kinds, "window_key": ("slot", None)})
 
     grouped = laguna.LagunaConfig.tiny(dtype=jnp.float32)
     mixed = hybrid.HybridConfig.tiny(dtype=jnp.float32)
@@ -552,32 +558,34 @@ def _ring_model(kind):
         "rings of keys and values": lambda: laguna.LagunaLM(grouped),
         "a ring of latents": lambda: latent.LatentLM(
             latent.LatentConfig.tiny(dtype=jnp.float32)),
-        "rings, and says nothing": lambda: Silent(grouped),
+        "rings, and says its rows are not causal": lambda: Silent(grouped),
         "a ring declared as state": lambda: RingAsState(grouped),
-        "rings, and no length in its call": lambda: Untold(grouped),
+        "rings, and a prefill not told its length": lambda: Untold(grouped),
         "a state and a tail": lambda: hybrid.HybridLM(mixed),
-        "a state and a tail, and says causal": lambda: CausalHybrid(mixed),
+        "a state and a tail, rows not causal":
+            lambda: NotCausalHybrid(mixed),
     }[kind]()
 
 
 @pytest.mark.parametrize("kind,held,want", [
     ("rings of keys and values", ("window_key", "window_value"), True),
     ("a ring of latents", ("window_latent",), True),
-    ("rings, and says nothing", ("window_key", "window_value"), False),
+    ("rings, and says its rows are not causal",
+     ("window_key", "window_value"), False),
     ("a ring declared as state", ("window_key", "window_value"), False),
-    ("rings, and no length in its call", ("window_key", "window_value"),
-     False),
+    ("rings, and a prefill not told its length",
+     ("window_key", "window_value"), False),
     ("a state and a tail", ("conv_state", "ssm_state"), False),
-    ("a state and a tail, and says causal", ("conv_state", "ssm_state"),
+    ("a state and a tail, rows not causal", ("conv_state", "ssm_state"),
      False),
 ])
 def test_a_model_that_holds_leaves_takes_the_ceiling_by_their_kind(
         kind, held, want):
-    """The ceiling rule for a model that holds leaves once a slot: it says
-    its prompt rows are causal, every such leaf is a `ring`, and its call
-    takes the prompt's length so that the ring is written where the prompt
-    ends. A `slot` leaf (a state, a convolution's tail) keeps the floor
-    whatever the model says; nothing is read off a name."""
+    """The ceiling rule for a model that holds leaves once a slot: its
+    contract says its prompt rows are causal, every such leaf is a `ring`,
+    and its prefill is told the prompt's length so that the ring is written
+    where the prompt ends. A `slot` leaf (a state, a convolution's tail)
+    keeps the floor whatever the model says; nothing is read off a name."""
     model = _ring_model(kind)
     params = nn.meta.unbox(
         model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))

@@ -15,6 +15,7 @@ float32 is stated moves logits by 2e-5 (the scan's inputs or state, dt) to
 serving dtype does to those leaves is pinned by dtype, not by tolerance
 (`test_float32_where_stated_under_bfloat16`)."""
 
+import dataclasses
 import json
 import os
 import threading
@@ -395,8 +396,10 @@ def test_a_model_must_name_its_cache_leaves(tiny):
     class Unnamed:
         config = tiny["model"].config
 
-        def cache_leaf_kinds(self):
-            return {"cached_key": ("paged", -3)}
+        def serving_contract(self):
+            return dataclasses.replace(
+                tiny["model"].serving_contract(),
+                leaf_kinds={"cached_key": ("paged", -3)})
 
     row = {"attn": {"cached_key": jax.ShapeDtypeStruct((1, 128, 2, 16), jnp.float32),
                     "running_mean": jax.ShapeDtypeStruct((1, 128), jnp.float32)}}

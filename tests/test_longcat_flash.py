@@ -302,7 +302,8 @@ def test_mathematics_left_out_fails(tiny, monkeypatch, what):
             """The block with the branch added where it was computed."""
 
             @longcat.nn.compact
-            def __call__(self, h, count_mask=None, paged_ctx=None):
+            def __call__(self, h, call=longcat.LayerCall()):
+                count_mask, paged_ctx = call.count_mask, call.paged_ctx
                 cfg = self.config
                 norm = lambda name: longcat.RMSNorm(  # noqa: E731
                     cfg.norm_config(), name=name)
@@ -455,7 +456,7 @@ class _Grid:
         logits, counts, reads = (np.asarray(v) for v in (logits, counts, reads))
         for slot in tokens_by_slot:
             self.lengths[slot] += 1
-        return logits, counts, dict(zip(longcat.LongcatLM.READS, reads.tolist()))
+        return logits, counts, dict(zip(self.engine.contract.reads, reads.tolist()))
 
     def run(self, slot, sequence, prompt_len, shared=(), pad=0):
         """Admit `sequence[:prompt_len]`, then feed the rest a token a

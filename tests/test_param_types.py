@@ -21,8 +21,17 @@ import jax
 import jax.numpy as jnp
 
 from tf_yarn_tpu.models import decode_engine, param_types, transformer
+from tf_yarn_tpu.models.trunk import ServingContract
 
 BF16 = np.dtype(jnp.bfloat16)
+
+
+def _two_argument_prefill(self):
+    """The probes' contract: the rule traces `build_prefill_fn`, which asks
+    a model whether its prefill takes a length."""
+    return ServingContract(
+        leaf_kinds={"seen": ("index", None)}, prefill_layers=(),
+        rows_causal=False, takes_prompt_len=False, counts=False)
 
 
 def _tiny(**overrides):
@@ -78,6 +87,7 @@ class _BiasedDense(nn.Module):
     form `build_prefill_fn` applies a model in."""
 
     config: transformer.TransformerConfig
+    serving_contract = _two_argument_prefill
 
     @nn.compact
     def __call__(self, tokens, decode=False):
@@ -106,6 +116,7 @@ class _Probe(nn.Module):
     ever converted to bfloat16."""
 
     read: str
+    serving_contract = _two_argument_prefill
 
     @nn.compact
     def __call__(self, tokens, decode=False):

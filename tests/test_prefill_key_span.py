@@ -474,9 +474,9 @@ MODELS = {
 def test_the_hosts_count_is_the_count_by_hand(name, bucket, kept):
     model = MODELS[name]()
     engine = DecodeEngine(model, prompt_buckets=(128, 256))
-    layers = model.prefill_attention_layers()
+    layers = engine.contract.prefill_layers
     told = name in ("laguna", "dots3")
-    assert engine._prefill_takes_len == told
+    assert engine.contract.takes_prompt_len == told
     windows = {"laguna": [0, 8, 8, 8, 0], "llama": [0, 0], "hybrid": [0],
                "longcat": [0] * 4}
     if name in windows:

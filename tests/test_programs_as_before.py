@@ -1,5 +1,5 @@
 """A prefill is told its prompt's length only where the model writes a ring
-there (`decode_engine.takes_prompt_len`): the prefill, pack and step programs
+there (the contract's `takes_prompt_len`): the prefill, pack and step programs
 of the models that hold no ring — the Llama-shaped one, LongCat's, the hybrid
 — are the programs they were. Two proofs: the prefill lowers to the text of
 the two-argument form whatever length the engine is handed, and the three
@@ -63,7 +63,7 @@ def _texts(model, variables):
     tokens = jax.ShapeDtypeStruct((1, BUCKET), jnp.int32)
     prefill = jax.jit(decode_engine.build_prefill_fn(model))
     told = (jnp.asarray(BUCKET - 3, jnp.int32),) \
-        if decode_engine.takes_prompt_len(model) else ()
+        if model.serving_contract().takes_prompt_len else ()
     texts = {"prefill": prefill.lower(variables, tokens, *told).as_text()}
     row = jax.eval_shape(prefill, variables, tokens)[0]
     per = model.config.max_seq_len // BLOCK
@@ -104,16 +104,18 @@ def test_programs_without_a_ring_have_the_parents_text(case):
 
 @pytest.mark.parametrize("case", CASES + RING_CASES)
 def test_the_length_is_an_argument_only_where_a_ring_is_written(case):
-    """A model that holds no ring: `prompt_len` is not in its call, its
-    prefill is the function of (params, tokens) that it was, to the letter,
-    and the engine handed a length runs that same program. A model with
-    rings: one more argument, a scalar, and still one program a bucket."""
+    """A model that holds no ring: its contract says the prefill takes no
+    length, its prefill is the function of (params, tokens) that it was, to
+    the letter, and the engine handed a length runs that same program. A
+    model with rings: one more argument, a scalar, and still one program a
+    bucket."""
     model, variables = _model(case)
     tokens = jnp.zeros((1, BUCKET), jnp.int32)
     built = decode_engine.build_prefill_fn(model)
-    told = decode_engine.takes_prompt_len(model)
+    contract = model.serving_contract()
+    told = contract.takes_prompt_len
     assert told == (case in RING_CASES)
-    kinds = {kind for kind, _axis in model.cache_leaf_kinds().values()}
+    kinds = {kind for kind, _axis in contract.leaf_kinds.values()}
     assert told == ("ring" in kinds)
     leaves = len(jax.tree_util.tree_leaves(variables))
 

@@ -76,7 +76,7 @@ top_k / top_p are baked into the traced program) key the cache.
   included).
 
 * **Cache leaves by kind.** A model says what each leaf of its decode
-  cache is (`model.cache_leaf_kinds()`: leaf name -> kind): ``paged`` by
+  cache is (`model.serving_contract().leaf_kinds`: name -> kind): ``paged`` by
   token (keys, values: the pool above, with the leaf's sequence axis
   given from the end of its shape), held once a ``slot`` (a recurrent
   state, a convolution's tail: an array `[max_slots, ...]` beside the
@@ -93,7 +93,7 @@ top_k / top_p are baked into the traced program) key the cache.
   state (or zeros) into a slot at admission. Which step a model gets
   (`counted_step`) and whether anything of it is held once a slot
   (`slot_state_leaves`) are two questions: a model whose every leaf is
-  paged and whose layers count (`count_mask` in its call) takes the same
+  paged and whose layers count (the contract's `counts`) takes the same
   step with an empty state, and nothing that moves whole blocks stands
   aside for it.
 """
@@ -101,7 +101,6 @@ top_k / top_p are baked into the traced program) key the cache.
 from __future__ import annotations
 
 import functools
-import inspect
 import logging
 import threading
 import weakref
@@ -114,7 +113,9 @@ import numpy as np
 from tf_yarn_tpu import telemetry
 from tf_yarn_tpu.models.generate import _sample
 from tf_yarn_tpu.models.spec import verify_window
+from tf_yarn_tpu.models.moe import stack_counts
 from tf_yarn_tpu.models.transformer import PagedContext, prefill_key_pairs
+from tf_yarn_tpu.models.trunk import contract_of
 
 _logger = logging.getLogger(__name__)
 
@@ -128,35 +129,24 @@ DEFAULT_PROMPT_BUCKETS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 DEFAULT_TOKEN_BUCKET = 64
 
 
-def takes_prompt_len(model) -> bool:
-    """Whether the model's prefill call is told where its prompt ends
-    (`prompt_len` in its call: a model that writes a `ring` from the rows
-    that end there, models/latent.py and models/laguna.py). Every other
-    model's prefill is a program of the params and the tokens alone."""
-    return "prompt_len" in inspect.signature(type(model).__call__).parameters
-
-
 def build_prefill_fn(model):
     """(params, prompt [B, F]) -> (cache, last-position logits [B, V]).
-    For a model that `takes_prompt_len`, a third argument: the prompt's
-    true length, a traced scalar (None = all F tokens are the prompt's)."""
+    For a model whose contract says `takes_prompt_len` (it writes a `ring`
+    from the rows that end there), a third argument: the prompt's true
+    length, a traced scalar (None = all F tokens are the prompt's). Every
+    other model's prefill is a program of the params and the tokens alone."""
 
-    if takes_prompt_len(model):
-        def prefill(params, prompt, prompt_len=None):
-            logits, state = model.apply(
-                params, prompt, decode=True, prompt_len=prompt_len,
-                mutable=["cache"]
-            )
-            return state["cache"], logits[:, -1]
-
-        return prefill
-
-    def prefill(params, prompt):
+    def run(params, prompt, **told):
         logits, state = model.apply(
-            params, prompt, decode=True, mutable=["cache"]
-        )
+            params, prompt, decode=True, mutable=["cache"], **told)
         return state["cache"], logits[:, -1]
 
+    if contract_of(model).takes_prompt_len:
+        def prefill(params, prompt, prompt_len=None):
+            return run(params, prompt, prompt_len=prompt_len)
+    else:
+        def prefill(params, prompt):
+            return run(params, prompt)
     return prefill
 
 
@@ -301,19 +291,11 @@ def _leaf_name(path) -> str:
 
 def cache_layout(model, row_aval):
     """A tree like `row_aval` (the batch-1 decode cache) of `LeafLayout`s,
-    from what the model declares (`cache_leaf_kinds`: leaf name -> (kind,
-    sequence axis from the end of the shape)). A leaf the model does not
-    name, or a paged leaf whose declared axis is not `max_seq_len` long,
-    is an error: nothing is guessed."""
-    declare = getattr(model, "cache_leaf_kinds", None)
-    if declare is None:
-        raise ValueError(
-            f"{type(model).__name__} does not declare its cache leaves "
-            "(cache_leaf_kinds(): leaf name -> ('paged', sequence axis from "
-            "the end) | ('slot', None) | ('index', None)); the paged "
-            "layout does not guess them"
-        )
-    kinds = declare()
+    from what the model declares (its contract's `leaf_kinds`: leaf name ->
+    (kind, sequence axis from the end of the shape)). A leaf the model does
+    not name, a paged leaf whose declared axis is not `max_seq_len` long, or
+    a model without a contract is an error: nothing is guessed."""
+    kinds = contract_of(model).leaf_kinds
     max_seq_len = model.config.max_seq_len
 
     def leaf(path, aval):
@@ -321,7 +303,7 @@ def cache_layout(model, row_aval):
         if name not in kinds:
             raise ValueError(
                 f"cache leaf {name!r} {tuple(aval.shape)} is not among "
-                f"those {type(model).__name__}.cache_leaf_kinds() names: "
+                f"those {type(model).__name__}.serving_contract() names: "
                 f"{sorted(kinds)}"
             )
         kind, axis = kinds[name]
@@ -558,16 +540,6 @@ def _new_rows(cache, layout, length, width: int):
     return jax.tree_util.tree_map(leaf, cache, layout)
 
 
-def _moe_stats(stats):
-    """What the expert layers sowed into `moe_stats`, a list a name in the
-    layers' order: (`counts`, `streamed`); the second is empty unless the
-    layers loop over the experts a token reached (`moe.loops_over_touched`)."""
-    by_name = {"counts": [], "streamed": []}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(stats):
-        by_name[path[-2].key].append(leaf)  # .../<name>/0: sown once a call
-    return by_name["counts"], by_name["streamed"]
-
-
 def build_paged_state_step_fn(model, block_size: int, temperature: float,
                               top_k: Optional[int], top_p: Optional[float],
                               with_logits: bool = False,
@@ -588,14 +560,11 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
     whatever its state holds and writes its row to the trash block;
     admission overwrites both (`write_slot_state`).
     `counts` stacks what the model's layers counted into `moe_stats` for
-    the active slots (table row not all trash) — `[layers, 1 + held
-    experts]`: assignments, then tokens that reached each held expert;
-    where the layers loop over the held experts a token reached
-    (`moe.loops_over_touched`), one more at the end: how many the loop
-    multiplied — and rides back with `emitted`. A model whose attention
-    layers count what they read (`cache_stats`, summed over layers: one
-    vector, named by the model's `READS`) has it appended as a sixth
-    output. Where a slot's token and rng row come from (`_feed`), sampling
+    the active slots (table row not all trash), a row a layer
+    (`moe.stack_counts`; the columns are `moe.ExpertRow`'s), and rides back
+    with `emitted`. A model whose attention layers count what they read
+    (`cache_stats`, summed over layers: one vector, named by the contract's
+    `reads`) has it appended as a sixth output. Where a slot's token and rng row come from (`_feed`), sampling
     and the RNG discipline are `build_paged_step_fn`'s. `with_logits`
     appends the step's logits [S, V] last (the tests compare them with a
     reference).
@@ -616,15 +585,9 @@ def build_paged_state_step_fn(model, block_size: int, temperature: float,
         emitted, rngs = _sample_slots(
             logits[:, -1], tokens, rngs, sample_mask, temperature, top_k,
             top_p)
-        counted, streamed = _moe_stats(new.get("moe_stats", {}))
-        counts = jnp.stack(counted) if counted \
-            else jnp.zeros((0, 0), jnp.int32)
-        if streamed:
-            counts = jnp.concatenate(
-                [counts, jnp.stack(streamed)[:, None]], axis=1)
         out = (_merge_pool_tree(pool, dict(new["kv_pool"])),
                _merge_pool_tree(state, dict(new["cache"])),
-               emitted, rngs, counts)
+               emitted, rngs, stack_counts(new.get("moe_stats", {})))
         reads = jax.tree_util.tree_leaves(new.get("cache_stats", {}))
         if reads:
             out += (jnp.sum(jnp.stack(reads), axis=0),)
@@ -1060,8 +1023,6 @@ class DecodeEngine:
         if token_bucket < 1:
             raise ValueError(f"token_bucket must be >= 1, got {token_bucket}")
         self.model = model
-        # Whether a prefill of this model is told its prompt's length.
-        self._prefill_takes_len = takes_prompt_len(model)
         # Tensor-parallel decode (docs/Serving.md): with a mesh, params
         # place by the model's logical-axis annotations, the KV pool shards
         # its kv-heads axis over tp, and every compiled program lowers
@@ -1120,6 +1081,8 @@ class DecodeEngine:
         gaps = [b2 - b1 for b1, b2 in zip(self.prompt_buckets,
                                           self.prompt_buckets[1:])]
         self._rest_width = max(gaps) if gaps else 1
+        # All the engine reads of the model's class; refused if missing.
+        self.contract = contract_of(model)
         self._prefill: Dict[tuple, Any] = {}
         self._decode: Dict[tuple, Any] = {}
         self._lock = threading.Lock()
@@ -1358,7 +1321,7 @@ class DecodeEngine:
         b, f = prompt.shape
         prefill_key = (b, f, fp)
         prefill_args = (params, prompt)
-        if self._prefill_takes_len:
+        if self.contract.takes_prompt_len:
             prefill_args += (
                 np.asarray(f if length is None else length, np.int32),)
         def build():
@@ -1425,33 +1388,29 @@ class DecodeEngine:
         """Whether an admission may prefill the bucket ABOVE its prompt and
         keep the true length (`slot_prefill_len`): only where a row of the
         prefill's cache cannot depend on the tokens after it, so that the
-        pad leaves the kept rows what they would have been. The model says
-        so (`prompt_rows_causal`; a model that says nothing keeps the floor
-        rule), and what it holds once a slot must be what the prefill left
-        where the PROMPT ends: a `ring`, which a model that
-        `takes_prompt_len` writes from the rows that end there. A `slot`
-        leaf (a recurrent state, a convolution's tail) is what the prefill
-        left at the end of its bucket, and keeps the floor rule."""
-        if not getattr(self.model, "prompt_rows_causal", False):
+        pad leaves the kept rows what they would have been. The model's
+        contract says so (`rows_causal`), and what it holds once a slot
+        must be what the prefill left where the PROMPT ends: a `ring`,
+        which a model that `takes_prompt_len` writes there. A `slot` leaf (a
+        recurrent state, a convolution's tail) is what the prefill left at
+        the end of its bucket, and keeps the floor rule."""
+        contract = self.contract
+        if not contract.rows_causal:
             return False
         held = self.slot_state_leaves(params)
         if not held:
             return True
-        kinds = self.model.cache_leaf_kinds()
-        return self._prefill_takes_len \
-            and all(kinds[name][0] == RING for name in held)
+        return contract.takes_prompt_len and all(
+            contract.leaf_kinds[name][0] == RING for name in held)
 
     def prefill_key_pairs(self, bucket: int, kept: int) -> Tuple[int, int]:
         """(formed, visible) query-key pairs, a head, of the attention of
         one `prefill` of `bucket` tokens that keeps `kept`
-        (`transformer.prefill_key_pairs`, over the layers the model
-        declares: `prefill_attention_layers`; (0, 0) for a model that
-        declares none). Host arithmetic, no device read."""
-        layers = getattr(self.model, "prefill_attention_layers", None)
-        if layers is None:
-            return 0, 0
+        (`transformer.prefill_key_pairs`, over the contract's
+        `prefill_layers`). Host arithmetic, no device read."""
         return prefill_key_pairs(
-            bucket, kept, layers(), told=self._prefill_takes_len)
+            bucket, kept, self.contract.prefill_layers,
+            told=self.contract.takes_prompt_len)
 
     def prefill(self, params, prompt, length=None):
         """Public compiled prefill: [B, F] prompt -> (cache, last
@@ -1523,19 +1482,15 @@ class DecodeEngine:
     def counted_step(self, params) -> bool:
         """Whether the one-token step of this model is `paged_state_step`:
         it holds leaves once a slot, which only that step carries, or its
-        call takes `count_mask`, so that its layers count what they routed
-        and read and only that step returns the counts. `paged_step`
-        serves every other model."""
-        return "count_mask" in inspect.signature(
-            type(self.model).__call__).parameters \
-            or bool(self.slot_state_leaves(params))
+        contract says `counts`: its layers count what they routed and read,
+        and only that step returns the counts. `paged_step` serves every
+        other model."""
+        return self.contract.counts or bool(self.slot_state_leaves(params))
 
     def slot_state_leaves(self, params) -> Tuple[str, ...]:
         """Names of the cache leaves the model holds once a slot (a
         recurrent state, a convolution's tail); empty for a model whose
         whole cache is paged by token. Abstract: nothing runs."""
-        if getattr(self.model, "cache_leaf_kinds", None) is None:
-            return ()
         params = self._place_params(params)
         fp = self._params_fingerprint(params)
         if fp not in self._state_leaf_names:
@@ -1687,8 +1642,9 @@ class DecodeEngine:
         heads, and a Pallas call cannot be partitioned). `/stats` names
         it: `decode_engine.paged_attention` ("model" where the pool's rows
         have no head axis and the model's own attention reads them; a
-        model that hands such rows to the same op as one KV head says so,
-        `pool_rows_are_one_kv_head`, and gets the choice)."""
+        model that hands such rows to the same op as one KV head says so
+        in its contract, `pool_rows_are_one_kv_head`, and gets the
+        choice)."""
         from tf_yarn_tpu.ops.decode_attention import paged_kernel_serves
 
         # Every tick: decided once a pool layout.
@@ -1696,7 +1652,7 @@ class DecodeEngine:
         how = self._paged_kernels.get(fp)
         if how is None:
             leaves = jax.tree_util.tree_leaves(pool)
-            one_head = getattr(self.model, "pool_rows_are_one_kv_head", False)
+            one_head = self.contract.pool_rows_are_one_kv_head
             if any(leaf.ndim < 5 for leaf in leaves) and not one_head:
                 # [1, NB, bs, heads, dim] has a head axis; a leaf without
                 # one is read by its model's own attention, which this
@@ -1960,17 +1916,6 @@ class DecodeEngine:
         with self._lock:
             return {
                 kind: list(cache)
-                for kind, cache in self._program_caches().items()
-            }
-
-    def compiled_programs(self) -> Dict[str, Dict[tuple, Any]]:
-        """The compiled executables per kind keyed exactly like
-        `program_keys` — each exposes the optimized HLO via
-        `.as_text()`, which is what the TYA2xx compiled-artifact rules
-        read (input_output_alias map, collective ops)."""
-        with self._lock:
-            return {
-                kind: dict(cache)
                 for kind, cache in self._program_caches().items()
             }
 

@@ -20,7 +20,7 @@ the last `d_conv - 1` rows of the conv input (`conv_state`) in the cache.
 *attention mixer.* `transformer.Attention`, rope off, softmax scale
 `attention_multiplier`.
 
-The serving engine is told what each cache leaf is (`cache_leaf_kinds`):
+The serving engine is told what each cache leaf is (`serving_contract`):
 keys and values are paged by token, `ssm_state` and `conv_state` are held
 once a slot, `cache_index` is the slot's position. A call with `paged_ctx`
 (`transformer.PagedContext`) is the engine's paged step: tokens [slots, 1],
@@ -32,24 +32,24 @@ whole model, the experts above all, sees all slots' tokens together.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tf_yarn_tpu.models.moe import DroplessMoE
+from tf_yarn_tpu.models.moe import DroplessMoE, ExpertRow
 from tf_yarn_tpu.models.transformer import (
     CACHE_LEAF_KINDS,
     EMBED,
     HEADS,
     PREFILL_QUERY_BLOCK,
-    VOCAB,
     Attention,
     RMSNorm,
     TransformerConfig,
     _partitioned,
 )
+from tf_yarn_tpu.models.trunk import DecoderLM, LayerCall, ServingContract
 
 HIGHEST = jax.lax.Precision.HIGHEST
 MAMBA, ATTENTION = "mamba", "attention"
@@ -280,7 +280,7 @@ class HybridBlock(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x, positions, count_mask=None, paged_ctx=None):
+    def __call__(self, x, call=LayerCall()):
         cfg = self.config
         norm_cfg = cfg.norm_config()
         batch, t, d = x.shape
@@ -289,7 +289,7 @@ class HybridBlock(nn.Module):
             mixed = Mamba2Mixer(cfg, self.decode, name="mamba")(normed)
         else:
             mixed = Attention(cfg.attention_config(), self.decode, name="attn")(
-                normed, positions, paged_ctx)
+                normed, call.positions, call.paged_ctx)
         x = x + (cfg.residual_multiplier * mixed).astype(x.dtype)
         normed = RMSNorm(norm_cfg, name="moe_norm")(x)
         moe = DroplessMoE(
@@ -297,54 +297,35 @@ class HybridBlock(nn.Module):
             expert_offset=cfg.expert_offset, top_k=cfg.experts_per_token,
             d_expert=cfg.d_expert, d_shared=cfg.d_shared, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="moe",
-        )(normed.reshape(batch * t, d), count_mask)
+        )(normed.reshape(batch * t, d), call.count_mask)
         return x + (cfg.residual_multiplier * moe.reshape(batch, t, d)
                     ).astype(x.dtype)
 
 
-class HybridLM(nn.Module):
-    """tokens [B, S] int32 -> logits [B, S, vocab] (float32).
-
-    `decode=True` keeps the cache (`models/decode_engine.py` drives it);
-    `paged_ctx` besides is the paged step's call: tokens [slots, 1], the
-    state leaves with a leading slot axis, keys and values in the `kv_pool`
-    collection. `count_mask` [B * S] marks the tokens whose routing the
-    expert layers count (`moe_stats`)."""
+class HybridLM(DecoderLM):
+    """`trunk.DecoderLM` over mamba and attention layers, with granite's
+    tied head and multipliers."""
 
     config: HybridConfig
+    scaled_embedding = True
+    tied_head = True
 
-    def cache_leaf_kinds(self):
-        return {**CACHE_LEAF_KINDS,
-                "ssm_state": ("slot", None), "conv_state": ("slot", None)}
+    @nn.nowrap
+    def layer(self, index, **module):
+        return HybridBlock(
+            self.config, self.config.layer_types[index], **module)
 
-    def prefill_attention_layers(self):
-        """`transformer.prefill_key_pairs`' layers: the attention layers."""
-        return tuple((0, PREFILL_QUERY_BLOCK)
-                     for kind in self.config.layer_types if kind == ATTENTION)
-
-    @nn.compact
-    def __call__(self, tokens, deterministic: bool = True,
-                 return_hidden: bool = False, decode: bool = False,
-                 count_mask=None, paged_ctx=None):
+    def serving_contract(self):
         cfg = self.config
-        embedding = self.param(
-            "embedding",
-            _partitioned((VOCAB, EMBED))(nn.initializers.normal(stddev=0.02)),
-            (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
-        )
-        with jax.named_scope("embed"):
-            x = (embedding.astype(cfg.dtype)[tokens]
-                 * cfg.embedding_multiplier).astype(cfg.dtype)
-        positions = jnp.broadcast_to(
-            jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape)
-        for index, kind in enumerate(cfg.layer_types):
-            x = HybridBlock(cfg, kind, decode, name=f"layer_{index}")(
-                x, positions, count_mask, paged_ctx)
-        x = RMSNorm(cfg.norm_config(), name="final_norm")(x)
-        if return_hidden:
-            return x
-        with jax.named_scope("lm_head"):
-            return jnp.einsum(
-                "bsd,vd->bsv", x, embedding.astype(cfg.dtype),
-                preferred_element_type=jnp.float32,
-            ) / cfg.logits_scaling
+        # Row t depends on tokens <= t alone (causal scan, convolution and
+        # attention, per-token dropless experts); the floor rule holds all
+        # the same: the state and the tail are what the prefill left at the
+        # end of its bucket (`DecodeEngine.ceiling_prefill`).
+        return ServingContract(
+            leaf_kinds={**CACHE_LEAF_KINDS, "ssm_state": ("slot", None),
+                        "conv_state": ("slot", None)},
+            prefill_layers=tuple(
+                (0, PREFILL_QUERY_BLOCK)
+                for kind in cfg.layer_types if kind == ATTENTION),
+            rows_causal=True, takes_prompt_len=False, counts=True,
+            experts=ExpertRow.of(cfg))

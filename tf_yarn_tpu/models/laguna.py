@@ -19,7 +19,7 @@ sigmoid of the attention's own input.
 `"sparse"`: `moe.DroplessMoE`, the `top_k` largest router logits, a softmax
 over those alone times `routed_scale`, one shared expert.
 
-The serving engine is told what each cache leaf is (`cache_leaf_kinds`): a
+The serving engine is told what each cache leaf is (`serving_contract`): a
 full layer's `cached_key` / `cached_value` are paged by token and read by
 `ops.decode_attention.paged_decode_attention`; a sliding layer's
 `window_key` / `window_value` are `ring`s of `ring_rows(window)` rows with a
@@ -27,33 +27,31 @@ head axis, held once a slot, so their bytes do not grow with the context;
 a prefill writes them from the rows that end where the prompt does
 (`prompt_len`), whatever pad follows.
 The one-token step sows what it read into `cache_stats` for the slots
-`count_mask` marks (`READS`: pool rows and ring rows apart).
+`count_mask` marks (`ATTENTION_READS`: pool rows and ring rows apart).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tf_yarn_tpu.models.moe import DroplessMoE
+from tf_yarn_tpu.models.moe import DroplessMoE, ExpertRow
 from tf_yarn_tpu.models.transformer import (
     ATTENTION_READS,
     CACHE_LEAF_KINDS,
-    EMBED,
     PREFILL_QUERY_BLOCK,
-    VOCAB,
     Attention,
     RMSNorm,
     RotaryRecipe,
     SwiGLU,
     TransformerConfig,
-    _partitioned,
 )
+from tf_yarn_tpu.models.trunk import DecoderLM, LayerCall, ServingContract
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -122,12 +120,6 @@ class LagunaConfig:
         """What `DecodeEngine(mesh=...)` checks against `tp`."""
         return math.gcd(*self.heads)
 
-    @property
-    def n_attention_layers(self) -> int:
-        """Attention layers, each with cache leaves of its own: what the
-        scheduler divides the rows read by."""
-        return self.n_layers
-
     def __post_init__(self):
         if not self.layer_types or set(self.layer_types) - {FULL, SLIDING} \
                 or set(self.mlp_types) - {DENSE, SPARSE}:
@@ -188,8 +180,7 @@ class LagunaBlock(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x, positions, count_mask=None, paged_ctx=None,
-                 prompt_len=None):
+    def __call__(self, x, call=LayerCall()):
         cfg = self.config
         norm_cfg = cfg.norm_config()
         batch, t, d = x.shape
@@ -200,8 +191,8 @@ class LagunaBlock(nn.Module):
             rotary=rotary(cfg.sliding_rotary if sliding else cfg.full_rotary),
             gate=True, window=cfg.window if sliding else 0,
             query_block=cfg.query_block,
-        )(RMSNorm(norm_cfg, name="attn_norm")(x), positions, paged_ctx,
-          count_mask, prompt_len)
+        )(RMSNorm(norm_cfg, name="attn_norm")(x), call.positions,
+          call.paged_ctx, call.count_mask, call.prompt_len)
         normed = RMSNorm(norm_cfg, name="ffn_norm")(x)
         if cfg.mlp_types[self.index] == DENSE:
             with jax.named_scope("mlp"):
@@ -212,76 +203,32 @@ class LagunaBlock(nn.Module):
             d_expert=cfg.d_expert, d_shared=cfg.d_shared, scoring="softmax",
             routed_scale=cfg.routed_scale, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="moe",
-        )(normed.reshape(batch * t, d), count_mask)
+        )(normed.reshape(batch * t, d), call.count_mask)
         return x + moe.reshape(batch, t, d)
 
 
-class LagunaLM(nn.Module):
-    """tokens [B, S] int32 -> logits [B, S, vocab] (float32).
-
-    `decode=True` keeps the cache (`models/decode_engine.py` drives it); a
-    decode call of more than one token is a prefill and returns the last
-    position's logits alone, [B, 1, vocab];
-    `paged_ctx` besides is the paged step's call: tokens [slots, 1], the
-    rings with a leading slot axis in `cache`, the full layers' keys and
-    values in the `kv_pool` collection. `count_mask` [B * S] marks the tokens
-    whose routing and cache reads the layers count (`moe_stats`,
-    `cache_stats`). `prompt_len` (a prefill's; a traced scalar) says where
-    the prompt ends in `tokens` when what follows is pad: the rings are
-    written from the rows that end there."""
+class LagunaLM(DecoderLM):
+    """`trunk.DecoderLM` over `LagunaBlock`s; a prefill returns its last
+    position's logits alone and writes the rings where `prompt_len` ends."""
 
     config: LagunaConfig
-    # The names of what the attention layers count into `cache_stats`.
-    READS = ATTENTION_READS
-    # Row t of a prefill's cache depends on tokens <= t alone (causal and
-    # window masks, per-token dropless experts), and a ring is written
-    # where `prompt_len` says the prompt ends: the engine may pad a prompt
-    # past its true length (`ceiling_prefill`).
-    prompt_rows_causal = True
+    head_on_prefill_last_row = True
 
-    def cache_leaf_kinds(self):
-        return {**CACHE_LEAF_KINDS, "window_key": ("ring", None),
-                "window_value": ("ring", None)}
+    @nn.nowrap
+    def layer(self, index, **module):
+        return LagunaBlock(self.config, index, **module)
 
-    def prefill_attention_layers(self):
-        """`transformer.prefill_key_pairs`' layers."""
+    def serving_contract(self):
         cfg = self.config
-        return tuple(
-            (cfg.window, cfg.query_block) if kind == SLIDING
-            else (0, cfg.query_block or PREFILL_QUERY_BLOCK)
-            for kind in cfg.layer_types)
-
-    @nn.compact
-    def __call__(self, tokens, deterministic: bool = True,
-                 return_hidden: bool = False, decode: bool = False,
-                 count_mask: Optional[jax.Array] = None, paged_ctx=None,
-                 prompt_len=None):
-        cfg = self.config
-        embedding = self.param(
-            "embedding",
-            _partitioned((VOCAB, EMBED))(nn.initializers.normal(stddev=0.02)),
-            (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
-        )
-        with jax.named_scope("embed"):
-            x = embedding.astype(cfg.dtype)[tokens]
-        positions = jnp.broadcast_to(
-            jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape)
-        for index in range(cfg.n_layers):
-            x = LagunaBlock(cfg, index, decode, name=f"layer_{index}")(
-                x, positions, count_mask, paged_ctx, prompt_len)
-        if decode and tokens.shape[1] > 1 and not return_hidden:
-            # A prefill: its caller takes the last position's logits, and
-            # [S, vocab] float32 of the others would be 0.8 GB at a
-            # 2048-token bucket over the whole vocabulary.
-            x = x[:, -1:]
-        x = RMSNorm(cfg.norm_config(), name="final_norm")(x)
-        if return_hidden:
-            return x
-        with jax.named_scope("lm_head"):
-            head = self.param(
-                "lm_head",
-                _partitioned((EMBED, VOCAB))(nn.initializers.lecun_normal()),
-                (cfg.d_model, cfg.vocab_size), cfg.param_dtype,
-            )
-            return jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype),
-                              preferred_element_type=jnp.float32)
+        # Row t of a prefill's cache depends on tokens <= t alone (causal
+        # and window masks, per-token dropless experts), and a ring is
+        # written where `prompt_len` says the prompt ends.
+        return ServingContract(
+            leaf_kinds={**CACHE_LEAF_KINDS, "window_key": ("ring", None),
+                        "window_value": ("ring", None)},
+            prefill_layers=tuple(
+                (cfg.window, cfg.query_block) if kind == SLIDING
+                else (0, cfg.query_block or PREFILL_QUERY_BLOCK)
+                for kind in cfg.layer_types),
+            rows_causal=True, takes_prompt_len=True, counts=True,
+            reads=ATTENTION_READS, experts=ExpertRow.of(cfg))

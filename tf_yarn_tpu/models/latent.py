@@ -44,7 +44,7 @@ correction bias (over all experts, or inside the `topk_group` best of
 `n_group` groups), the chosen scores normalised and scaled, one shared
 expert.
 
-The serving engine is told what each cache leaf is (`cache_leaf_kinds`): a
+The serving engine is told what each cache leaf is (`serving_contract`): a
 full layer's `latent` and `index_key` rows are paged by token; a sliding
 layer's `window_latent` is a `ring` of `ring_len` rows held once a slot
 (row `p % ring_len` holds position p), so its bytes do not grow with the
@@ -78,11 +78,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tf_yarn_tpu.models.moe import DroplessMoE
+from tf_yarn_tpu.models.moe import DroplessMoE, ExpertRow
 from tf_yarn_tpu.models.transformer import (
     EMBED,
     HEADS,
-    VOCAB,
     RMSNorm,
     RotaryRecipe,
     SwiGLU,
@@ -95,6 +94,7 @@ from tf_yarn_tpu.models.transformer import (
     ring_valid,
     span_width,
 )
+from tf_yarn_tpu.models.trunk import DecoderLM, LayerCall, ServingContract
 
 HIGHEST = jax.lax.Precision.HIGHEST
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -201,12 +201,6 @@ class LatentConfig:
         """Rows of a sliding layer's ring: the window, rounded up to whole
         tiles of the cache's type."""
         return ring_rows(self.window)
-
-    @property
-    def n_attention_layers(self) -> int:
-        """Attention sublayers, each with cache leaves of its own: what the
-        scheduler divides the rows read by."""
-        return self.n_layers
 
     def sizes(self, kind: str) -> AttentionSizes:
         return self.sliding if kind == SLIDING else self.full
@@ -786,14 +780,14 @@ class LatentBlock(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x, count_mask=None, paged_ctx=None, prompt_len=None):
+    def __call__(self, x, call=LayerCall()):
         cfg = self.config
         norm_cfg = cfg.norm_config()
         batch, t, d = x.shape
         x = x + LatentAttention(
             cfg, cfg.layer_types[self.index], self.decode, name="attn")(
-            RMSNorm(norm_cfg, name="attn_norm")(x), paged_ctx, count_mask,
-            prompt_len)
+            RMSNorm(norm_cfg, name="attn_norm")(x), call.paged_ctx,
+            call.count_mask, call.prompt_len)
         normed = RMSNorm(norm_cfg, name="ffn_norm")(x)
         if self.index < cfg.first_dense:
             with jax.named_scope("mlp"):
@@ -805,65 +799,32 @@ class LatentBlock(nn.Module):
             norm_topk=cfg.norm_topk, routed_scale=cfg.routed_scale,
             n_group=cfg.n_group, topk_group=cfg.topk_group,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="moe",
-        )(normed.reshape(batch * t, d), count_mask)
+        )(normed.reshape(batch * t, d), call.count_mask)
         return x + moe.reshape(batch, t, d)
 
 
-class LatentLM(nn.Module):
-    """tokens [B, S] int32 -> logits [B, S, vocab] (float32).
-
-    `decode=True` keeps the cache (`models/decode_engine.py` drives it);
-    `paged_ctx` besides is the paged step's call: tokens [slots, 1], the
-    rings with a leading slot axis in `cache`, the full layers' rows in the
-    `kv_pool` collection. `count_mask` [B * S] marks the tokens whose
-    routing and cache reads the layers count (`moe_stats`, `cache_stats`).
-    `prompt_len` (a prefill's; a traced scalar) says where the prompt ends
-    in `tokens` when what follows is pad: the rings are written from the
-    rows that end there."""
+class LatentLM(DecoderLM):
+    """`trunk.DecoderLM` over `LatentBlock`s, which keep their own
+    positions; a prefill writes the rings where `prompt_len` ends."""
 
     config: LatentConfig
-    # The names of what the attention layers count into `cache_stats`.
-    READS = READS
-    # Row t of a prefill's cache depends on tokens <= t alone (causal and
-    # window masks, the indexer's top-k over `j <= t`, per-token dropless
-    # experts), and a ring is written where `prompt_len` says the prompt
-    # ends: the engine may pad a prompt past its true length
-    # (`ceiling_prefill`).
-    prompt_rows_causal = True
+    block_positions = False
 
-    def cache_leaf_kinds(self):
-        return {"latent": ("paged", -2), "index_key": ("paged", -2),
-                "window_latent": ("ring", None), "cache_index": ("index", None)}
+    @nn.nowrap
+    def layer(self, index, **module):
+        return LatentBlock(self.config, index, **module)
 
-    def prefill_attention_layers(self):
-        """`transformer.prefill_key_pairs`' layers."""
+    def serving_contract(self):
         cfg = self.config
-        return tuple((cfg.window if kind == SLIDING else 0, cfg.query_block)
-                     for kind in cfg.layer_types)
-
-    @nn.compact
-    def __call__(self, tokens, deterministic: bool = True,
-                 return_hidden: bool = False, decode: bool = False,
-                 count_mask=None, paged_ctx=None, prompt_len=None):
-        cfg = self.config
-        embedding = self.param(
-            "embedding",
-            _partitioned((VOCAB, EMBED))(nn.initializers.normal(stddev=0.02)),
-            (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
-        )
-        with jax.named_scope("embed"):
-            x = embedding.astype(cfg.dtype)[tokens]
-        for index in range(cfg.n_layers):
-            x = LatentBlock(cfg, index, decode, name=f"layer_{index}")(
-                x, count_mask, paged_ctx, prompt_len)
-        x = RMSNorm(cfg.norm_config(), name="final_norm")(x)
-        if return_hidden:
-            return x
-        with jax.named_scope("lm_head"):
-            head = self.param(
-                "lm_head",
-                _partitioned((EMBED, VOCAB))(nn.initializers.lecun_normal()),
-                (cfg.d_model, cfg.vocab_size), cfg.param_dtype,
-            )
-            return jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype),
-                              preferred_element_type=jnp.float32)
+        # Row t of a prefill's cache depends on tokens <= t alone (causal
+        # and window masks, the indexer's top-k over `j <= t`, per-token
+        # dropless experts); a ring is written where `prompt_len` ends.
+        return ServingContract(
+            leaf_kinds={"latent": ("paged", -2), "index_key": ("paged", -2),
+                        "window_latent": ("ring", None),
+                        "cache_index": ("index", None)},
+            prefill_layers=tuple(
+                (cfg.window if kind == SLIDING else 0, cfg.query_block)
+                for kind in cfg.layer_types),
+            rows_causal=True, takes_prompt_len=True, counts=True,
+            reads=READS, experts=ExpertRow.of(cfg))

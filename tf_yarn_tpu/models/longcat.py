@@ -23,9 +23,9 @@ stored 640), paged by token: nothing of this model is held once a slot.
 p_i` (not renormalised); a chosen output past `num_experts` returns its
 input, so the layer adds `(sum of those gates) u`; no shared expert.
 
-The serving engine pages every leaf (`cache_leaf_kinds`), steps the model
-with the counting step (`moe_stats`, `cache_stats`: `READS`) and reads the
-pool through `paged_decode_attention` as one KV head a row
+The serving engine pages every leaf (`serving_contract`), steps the model
+with the counting step (`moe_stats`, `cache_stats`: `PLAIN_READS`) and
+reads the pool through `paged_decode_attention` as one KV head a row
 (`pool_rows_are_one_kv_head`). A call of more than one token with
 `decode=True` is a prefill from an empty cache; the windowed paths are
 `LatentAttention`'s to refuse.
@@ -38,7 +38,6 @@ from typing import Tuple
 
 import flax.linen as nn
 import jax
-import jax.numpy as jnp
 
 from tf_yarn_tpu.models.latent import (
     PLAIN,
@@ -47,14 +46,9 @@ from tf_yarn_tpu.models.latent import (
     LatentAttention,
     LatentConfig,
 )
-from tf_yarn_tpu.models.moe import DroplessMoE
-from tf_yarn_tpu.models.transformer import (
-    EMBED,
-    VOCAB,
-    RMSNorm,
-    SwiGLU,
-    _partitioned,
-)
+from tf_yarn_tpu.models.moe import DroplessMoE, ExpertRow
+from tf_yarn_tpu.models.transformer import RMSNorm, SwiGLU
+from tf_yarn_tpu.models.trunk import DecoderLM, LayerCall, ServingContract
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,10 +80,6 @@ class LongcatConfig(LatentConfig):
                 f"layer_types: {self.layer_types!r}; every layer of this "
                 f"model is {PLAIN!r}")
 
-    @property
-    def n_attention_layers(self) -> int:
-        return 2 * self.n_layers
-
     @classmethod
     def tiny(cls, **overrides) -> "LongcatConfig":
         defaults = dict(
@@ -111,7 +101,7 @@ class ShortcutBlock(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, h, count_mask=None, paged_ctx=None):
+    def __call__(self, h, call=LayerCall()):
         cfg = self.config
         norm_cfg = cfg.norm_config()
         batch, t, d = h.shape
@@ -120,7 +110,7 @@ class ShortcutBlock(nn.Module):
             return LatentAttention(cfg, PLAIN, self.decode,
                                    name=f"attn_{index}")(
                 RMSNorm(norm_cfg, name=f"attn_norm_{index}")(stream),
-                paged_ctx, count_mask)
+                call.paged_ctx, call.count_mask)
 
         def dense(index, normed):
             with jax.named_scope("mlp"):
@@ -136,61 +126,33 @@ class ShortcutBlock(nn.Module):
             norm_topk=cfg.norm_topk, routed_scale=cfg.routed_scale,
             num_zero_experts=cfg.num_zero_experts,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="moe",
-        )(normed.reshape(batch * t, d), count_mask).reshape(batch, t, d)
+        )(normed.reshape(batch * t, d), call.count_mask).reshape(batch, t, d)
         h = h + dense(0, normed)
         h = h + attention(1, h)
         return h + dense(1, RMSNorm(norm_cfg, name="ffn_norm_1")(h)) + branch
 
 
-class LongcatLM(nn.Module):
-    """tokens [B, S] int32 -> logits [B, S, vocab] (float32); the call is
-    `latent.LatentLM`'s: `decode=True` keeps the cache, `paged_ctx` besides
-    is the paged step (tokens [slots, 1], every sublayer's rows in the
-    `kv_pool` collection), `count_mask` [B * S] marks the tokens whose
-    routing and cache reads are counted."""
+class LongcatLM(DecoderLM):
+    """`trunk.DecoderLM` over `ShortcutBlock`s, which keep their own
+    positions; every sublayer's rows are paged."""
 
     config: LongcatConfig
-    # What the attention sublayers count into `cache_stats`.
-    READS = PLAIN_READS
-    # A cached row has no head axis and is read as one KV head of its width
-    # by `paged_decode_attention`: the engine picks that op's implementation.
-    pool_rows_are_one_kv_head = True
-    # Row t of a prefill's cache depends on tokens <= t alone (causal
-    # attention, per-token dropless experts, every leaf paged): the engine
-    # may pad a prompt past its true length (`ceiling_prefill`).
-    prompt_rows_causal = True
+    block_positions = False
 
-    def cache_leaf_kinds(self):
-        return {"latent": ("paged", -2), "cache_index": ("index", None)}
+    @nn.nowrap
+    def layer(self, index, **module):
+        return ShortcutBlock(self.config, **module)
 
-    def prefill_attention_layers(self):
-        """`transformer.prefill_key_pairs`' layers: two sublayers a layer."""
+    def serving_contract(self):
         cfg = self.config
-        return ((0, cfg.query_block),) * cfg.n_attention_layers
-
-    @nn.compact
-    def __call__(self, tokens, deterministic: bool = True,
-                 return_hidden: bool = False, decode: bool = False,
-                 count_mask=None, paged_ctx=None):
-        cfg = self.config
-        embedding = self.param(
-            "embedding",
-            _partitioned((VOCAB, EMBED))(nn.initializers.normal(stddev=0.02)),
-            (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
-        )
-        with jax.named_scope("embed"):
-            x = embedding.astype(cfg.dtype)[tokens]
-        for index in range(cfg.n_layers):
-            x = ShortcutBlock(cfg, decode, name=f"layer_{index}")(
-                x, count_mask, paged_ctx)
-        x = RMSNorm(cfg.norm_config(), name="final_norm")(x)
-        if return_hidden:
-            return x
-        with jax.named_scope("lm_head"):
-            head = self.param(
-                "lm_head",
-                _partitioned((EMBED, VOCAB))(nn.initializers.lecun_normal()),
-                (cfg.d_model, cfg.vocab_size), cfg.param_dtype,
-            )
-            return jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype),
-                              preferred_element_type=jnp.float32)
+        # Row t of a prefill's cache depends on tokens <= t alone (causal
+        # attention, per-token dropless experts, every leaf paged). A cached
+        # row has no head axis and is read as one KV head of its width.
+        return ServingContract(
+            leaf_kinds={"latent": ("paged", -2),
+                        "cache_index": ("index", None)},
+            # two attention sublayers a layer
+            prefill_layers=((0, cfg.query_block),) * (2 * cfg.n_layers),
+            rows_causal=True, takes_prompt_len=False, counts=True,
+            reads=PLAIN_READS, pool_rows_are_one_kv_head=True,
+            experts=ExpertRow.of(cfg, cfg.num_zero_experts))

@@ -21,6 +21,7 @@ chip holds and returns their part of the sum.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any
 
 import flax.linen as nn
@@ -292,6 +293,73 @@ def within_best_groups(choice, n_group: int, topk_group: int):
             t, outputs)
 
 
+@dataclasses.dataclass(frozen=True)
+class ExpertRow:
+    """One expert layer's row of a step's `counts`, as a model declares it
+    (`trunk.ServingContract.experts`): `[assignments | held experts... |
+    zero? | streamed?]`, as `DroplessMoE` sows them: the counted tokens'
+    assignments over all experts; the tokens that reached each of the
+    `held`; the assignments to zero-compute outputs (`zero`); the trip count
+    of the loop over touched experts (`streamed`). `stack_counts` builds a
+    step's rows and `split_counts` names their parts: nobody else knows the
+    columns."""
+
+    held: int
+    zero: bool
+    top_k: int
+    outputs: int
+    # Bytes of one expert's matrices as a server holds them
+    # (`DecodeEngine.hold_params`: in the narrower of the two types).
+    expert_bytes: int
+
+    @classmethod
+    def of(cls, config, num_zero_experts: int = 0) -> "ExpertRow":
+        """Of a config with `DroplessMoE`'s fields under the models' names."""
+        itemsize = min(jnp.dtype(config.param_dtype).itemsize,
+                       jnp.dtype(config.dtype).itemsize)
+        return cls(
+            held=config.num_experts_here, zero=num_zero_experts > 0,
+            top_k=config.experts_per_token,
+            outputs=config.num_experts + num_zero_experts,
+            expert_bytes=3 * config.d_model * config.d_expert * itemsize)
+
+    def streamed(self, tokens: int) -> bool:
+        """Whether a step of `tokens` slots loops (`loops_over_touched`)."""
+        return loops_over_touched(
+            self.held, tokens, self.top_k, self.outputs, self.expert_bytes)
+
+
+def stack_counts(stats):
+    """A step's `counts`, a row a layer, from what the expert layers sowed
+    into `moe_stats` (`counts`, and `streamed` where they loop), in the
+    layers' order; [0, 0] where none counted."""
+    by_name = {"counts": [], "streamed": []}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(stats):
+        by_name[path[-2].key].append(leaf)  # .../<name>/0: sown once a call
+    if not by_name["counts"]:
+        return jnp.zeros((0, 0), jnp.int32)
+    counts = jnp.stack(by_name["counts"])
+    if by_name["streamed"]:
+        counts = jnp.concatenate(
+            [counts, jnp.stack(by_name["streamed"])[:, None]], axis=1)
+    return counts
+
+
+def split_counts(counts, row: ExpertRow, tokens: int):
+    """A step's `counts` (`stack_counts`, read back) of `tokens` slots by
+    name: (assignments [layers], load [layers, held], zero [layers] | None,
+    streamed [layers] | None). A width other than the declared one is an
+    error: no column is guessed."""
+    streamed = row.streamed(tokens)
+    if counts.shape[1] != 1 + row.held + row.zero + streamed:
+        raise ValueError(
+            f"a step of {tokens} slots counted rows of {counts.shape[1]}: "
+            f"not what {row} declares (streamed: {streamed})")
+    return (counts[:, 0], counts[:, 1:1 + row.held],
+            counts[:, 1 + row.held] if row.zero else None,
+            counts[:, -1] if streamed else None)
+
+
 class DroplessMoE(nn.Module):
     """`moe(x) = sum_i g_i W_out,i (silu(a_i) * b_i)`, `[a_i | b_i] = x W_in,i`
     over the `top_k` chosen experts; plus `shared(x)`, one SwiGLU of width
@@ -344,16 +412,13 @@ class DroplessMoE(nn.Module):
     products in the same types on all three.
 
     `count_mask` [T] marks the tokens whose routing is counted into the
-    mutable `moe_stats` collection (`counts` [1 + held]: their assignments
-    over all experts, then the tokens that reached each held expert; with
-    `num_zero_experts`, one more at the end: their assignments to
-    zero-compute experts); without that collection nothing is counted.
-    A step marks its active slots. The loop then runs over the experts
-    that a marked token chose, so that what it streams is what `counts`
-    says was reached, and sows its trip count beside them (`streamed`); a
-    token left out is a free slot's, whose row is written to the trash
-    block and read by nobody: it gets the part of its sum that the marked
-    tokens' experts cover.
+    mutable `moe_stats` collection (`ExpertRow`'s columns); without that
+    collection nothing is counted. A step marks its active slots. The loop
+    then runs over the experts that a marked token chose, so that what it
+    streams is what `counts` says was reached, and sows its trip count
+    beside them (`streamed`); a token left out is a free slot's, whose row
+    is written to the trash block and read by nobody: it gets the part of
+    its sum that the marked tokens' experts cover.
     """
 
     num_experts: int
@@ -431,8 +496,7 @@ class DroplessMoE(nn.Module):
             chose = local[:, :, None] == jnp.arange(held)[None, None, :]
             weights = jnp.sum(jnp.where(chose, gates[:, :, None], 0.0), axis=1)
             if count_mask is not None:
-                # [1 + held]: the counted tokens' assignments over all the
-                # deployment's experts, then those that reached each held one.
+                # `ExpertRow`'s columns, over the counted tokens.
                 counted = chose & count_mask[:, None, None]
                 counts = [
                     jnp.sum(count_mask, dtype=jnp.int32)[None] * self.top_k,

@@ -114,10 +114,10 @@ class TransformerConfig:
 
 # The decode cache `Attention` keeps, by leaf name: (kind, sequence axis
 # from the end of the shape). Shared by every model built on `Attention`.
-# A model declares its own leaves the same way (`cache_leaf_kinds`); the
-# kinds are the engine's (`decode_engine.py`): `paged` by token (a head axis
-# may follow the sequence axis, or none: a latent row), `slot` (state held
-# once a slot), `ring` (a window layer's last rows, once a slot), `index`.
+# A model declares its own leaves the same way (its contract's `leaf_kinds`);
+# the kinds are the engine's (`decode_engine.py`): `paged` by token (a head
+# axis may follow the sequence axis, or none: a latent row), `slot` (state
+# held once a slot), `ring` (a window layer's last rows, once a slot), `index`.
 CACHE_LEAF_KINDS = {
     "cached_key": ("paged", -3),
     "cached_value": ("paged", -3),
@@ -129,7 +129,7 @@ CACHE_LEAF_KINDS = {
 
 # What `Attention` sows into `cache_stats` a one-token step, over the counted
 # slots: rows live and rows read of the pool (a full layer), of a ring (a
-# window layer). A model built on it names them as its `READS`.
+# window layer). A model built on it names them as its contract's `reads`.
 ATTENTION_READS = ("pool_live", "pool_read", "window_live", "window_read")
 
 
@@ -300,7 +300,7 @@ def prefill_key_pairs(s: int, kept: int, layers, told: bool):
     """(formed, visible) query-key pairs, a head, of one prefill of `s`
     tokens of which the first `kept` are the prompt's. `layers`: each
     attention layer's `(window, query_block)` (a model's
-    `prefill_attention_layers`). Formed: the computed blocks of queries
+    `ServingContract.prefill_layers`). Formed: the computed blocks of queries
     times their `span_width` (`told`: the prefill is given `prompt_len`, so
     blocks past the prompt are not computed). Visible: the pairs inside the
     causal mask and the window of the prompt's rows. Host arithmetic on
@@ -946,28 +946,22 @@ class Transformer(nn.Module):
 
     config: TransformerConfig
 
-    def cache_leaf_kinds(self):
-        """What each leaf of the decode cache is to the serving engine
-        (models/decode_engine.py "Cache leaves by kind"): keys, values and
-        their int8 scales are paged by token, the sequence axis third from
-        the end of [..., seq, kv_heads, head_dim | 1]; `cache_index` is
-        the slot's position."""
-        return CACHE_LEAF_KINDS
+    def serving_contract(self):
+        """What the serving engine reads of this model
+        (`trunk.ServingContract`). Keys, values and their int8 scales are
+        paged by token, the sequence axis third from the end of [..., seq,
+        kv_heads, head_dim | 1]. Rows are causal under the dense per-token
+        feed-forward; `MoEMlp` counts its capacity over the tokens of the
+        call, pads included, so a pad could push a prompt token out of its
+        expert. Nothing is counted and no ring is written."""
+        from tf_yarn_tpu.models.trunk import ServingContract
 
-    def prefill_attention_layers(self):
-        """`(window, query_block)` of each attention layer as a prefill
-        from an empty cache runs it (`prefill_key_pairs`)."""
-        return ((0, PREFILL_QUERY_BLOCK),) * self.config.n_layers
-
-    @property
-    def prompt_rows_causal(self) -> bool:
-        """Whether row t of a prefill's cache depends on tokens <= t alone,
-        so that the serving engine may pad a prompt past its true length
-        and keep the rows before the pad (`DecodeEngine.ceiling_prefill`).
-        Attention here is causal and the dense feed-forward is per token;
-        `MoEMlp` counts its capacity over the tokens of the call, pads
-        included, so a pad could push a prompt token out of its expert."""
-        return self.config.moe_experts == 0
+        cfg = self.config
+        return ServingContract(
+            leaf_kinds=CACHE_LEAF_KINDS,
+            prefill_layers=((0, PREFILL_QUERY_BLOCK),) * cfg.n_layers,
+            rows_causal=cfg.moe_experts == 0, takes_prompt_len=False,
+            counts=False)
 
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True,

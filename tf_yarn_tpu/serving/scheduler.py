@@ -620,7 +620,7 @@ class SlotScheduler:
         self._state_bytes = 0
         self._cache_bytes_by_kind: Dict[str, int] = {}
         # What the attention layers counted (a model with `cache_stats`),
-        # under the model's own names (`READS`).
+        # under the names of the model's contract (`reads`).
         self._cache_reads: Dict[str, int] = {}
         self._state_resets = 0
         self._prefix_skipped_stateful = 0
@@ -1889,47 +1889,40 @@ class SlotScheduler:
 
     def _count_reads(self, reads: np.ndarray) -> None:
         """One step's cache reads as the model's attention layers counted
-        them over the active slots, summed over layers, under the model's
-        names (`READS`: rows live and rows read of each leaf, keys
+        them over the active slots, summed over layers, under its contract's
+        names (`reads`: rows live and rows read of each leaf, keys
         selected). `kv_read_token_steps` takes the rows read of all leaves
         over the number of attention layers: what a layer read of a slot's
         sequence, to set beside `kv_token_steps`, what was live of it."""
-        names = self.engine.model.READS
+        contract = self.engine.contract
+        names = contract.reads
         tally = self._cache_reads
         for name, value in zip(names, reads):
             tally[name] = tally.get(name, 0) + int(value)
         self._kv_read_token_steps += sum(
             int(value) for name, value in zip(names, reads)
             if name.endswith("_read")
-        ) // self.engine.model.config.n_attention_layers
+        ) // contract.n_attention_layers
 
     def _count_experts(self, counts: np.ndarray) -> None:
-        """One step's `[layers, 1 + held experts]`: the active slots'
-        assignments over all the deployment's experts, then the tokens
-        that reached each expert held here (and, of a router with
-        zero-compute experts, the assignments to those after them; and,
-        of layers that loop over the held experts a token reached, how
-        many the loop multiplied last: the row is then one wider than the
-        model's shapes say). A layer-step is one expert layer in one step.
-        `/stats` shows the tally as `moe_*`, `moe_tokens_by_expert` one
-        number a held expert; `moe_experts_streamed` counts the held
-        experts whose matrices a layer-step read: the loop's trips, or
-        all of them where one product runs over every held expert."""
+        """One step's `counts`, a row an expert layer, its columns named by
+        `moe.split_counts` under the model's contract (`moe.ExpertRow`). A
+        layer-step is one expert layer in one step. `/stats` shows the
+        tally as `moe_*`, `moe_tokens_by_expert` one number a held expert;
+        `moe_experts_streamed` counts the held experts whose matrices a
+        layer-step read: the loop's trips, or all of them where one product
+        runs over every held expert."""
+        from tf_yarn_tpu.models.moe import split_counts
+
         tally = self._moe
-        config = self.engine.model.config
-        held = config.num_experts_here
-        zero = bool(getattr(config, "num_zero_experts", 0))
-        if counts.shape[1] > 1 + held + zero:
-            tally["experts_streamed"] += int(counts[:, -1].sum())
-            counts = counts[:, :-1]
-        else:
-            tally["experts_streamed"] += held * counts.shape[0]
-        if zero:
+        assignments, load, zero, streamed = split_counts(
+            counts, self.engine.contract.experts, self.max_slots)
+        tally["experts_streamed"] += int(streamed.sum()) \
+            if streamed is not None else load.size
+        if zero is not None:
             tally["assignments_zero"] = tally.get("assignments_zero", 0) \
-                + int(counts[:, -1].sum())
-            counts = counts[:, :-1]
-        load = counts[:, 1:]
-        tally["assignments"] += int(counts[:, 0].sum())
+                + int(zero.sum())
+        tally["assignments"] += int(assignments.sum())
         tally["assignments_here"] += int(load.sum())
         tally["layer_steps"] += int(load.shape[0])
         tally["experts_touched"] += int((load > 0).sum())
